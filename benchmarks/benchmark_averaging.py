@@ -50,7 +50,7 @@ def main():
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_platforms", "cpu")  # host-only benchmark: pinned, and the result says so
     jax.devices()
 
     from hivemind_tpu.averaging import DecentralizedAverager
@@ -109,6 +109,7 @@ def main():
         "metric": "averaging_gbps_per_peer",
         "value": round(gbps_per_peer, 4),
         "unit": "GB/s/peer",
+        "device": {"platform": "cpu", "pinned": "host-only benchmark"},
         "extra": {
             "peers": args.num_peers, "rounds": args.num_rounds,
             "params": args.num_params, "success_rate": successes / max(attempts, 1),

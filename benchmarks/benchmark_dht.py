@@ -31,7 +31,7 @@ def main():
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_platforms", "cpu")  # host-only benchmark: pinned, and the result says so
     jax.devices()
 
     from hivemind_tpu.dht import DHT
@@ -86,6 +86,7 @@ def main():
         "metric": "dht_store_get_latency",
         "value": round(store_time / args.num_keys * 1000, 3),
         "unit": "ms/store",
+        "device": {"platform": "cpu", "pinned": "host-only benchmark"},
         "extra": {
             "peers": args.num_peers, "keys": args.num_keys,
             "store_ms": round(store_time / args.num_keys * 1000, 3),
@@ -180,6 +181,7 @@ def declare_storm(args):
         "metric": "dht_declare_storm",
         "value": round(len(uids) / declare_seconds, 1),
         "unit": "experts_declared/s",
+        "device": {"platform": "cpu", "pinned": "host-only benchmark"},
         "extra": {
             "peers": args.num_peers, "experts": len(uids), "grid": args.grid,
             "declare_seconds": round(declare_seconds, 3),

@@ -24,12 +24,10 @@ def main():
     parser.add_argument("--num_params", type=int, default=25_000_000)
     parser.add_argument("--num_leaves", type=int, default=8)
     parser.add_argument("--num_rounds", type=int, default=5)
-    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform
+    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform, describe_devices
 
     add_platform_arg(parser)
     args = parser.parse_args()
-    if args.platform is None:
-        args.platform = "cpu"  # virtual-mesh harness by default; pass --platform tpu on a pod
 
     flags = os.environ.get("XLA_FLAGS", "")
     if args.platform == "cpu" and "host_platform_device_count" not in flags:
@@ -85,6 +83,7 @@ def main():
         "metric": "ici_tier_round_rate",
         "value": round(tensor_bytes * args.num_rounds / elapsed / 1e9, 3),
         "unit": "GB/s (reduced fp32 bytes through mesh_mean+gather+scatter)",
+        "device": describe_devices(),
         "extra": {
             "devices": n, "params": args.num_params, "leaves": args.num_leaves,
             "rounds": args.num_rounds, "seconds_per_round": round(elapsed / args.num_rounds, 4),

@@ -79,6 +79,7 @@ def run_multi_client(args, checkpoint: Path) -> None:
     from hivemind_tpu.moe.server.llama_loader import load_llama_blocks
     from hivemind_tpu.moe.server.server import Server
     from hivemind_tpu.telemetry import REGISTRY
+    from hivemind_tpu.utils.platform import describe_devices
 
     backends, config = load_llama_blocks(checkpoint, uid_prefix="lb.")
     num_blocks = len(backends)
@@ -225,6 +226,7 @@ def run_multi_client(args, checkpoint: Path) -> None:
     }
     print(json.dumps({
         "metric": "llama_multi_client_decode",
+        "device": describe_devices(),
         "value": total_tok_s,
         "unit": "tok/s",
         "extra": extra,
@@ -300,7 +302,7 @@ def main():
                              "death mid-session replays the whole retained "
                              "history in one admission draw, and a burst below "
                              "that sheds the innocent client's recovery")
-    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform
+    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform, describe_devices
 
     add_platform_arg(parser)
     args = parser.parse_args()
@@ -430,6 +432,7 @@ def main():
             # decomposition — bench.py lands this under telemetry.serving
             print(json.dumps({
                 "metric": "llama_checkpoint_decode",
+                "device": describe_devices(),
                 "value": round(args.generate / elapsed, 1),
                 "unit": "tok/s",
                 "extra": {

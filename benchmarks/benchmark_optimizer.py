@@ -29,12 +29,10 @@ def main():
                       help="async local-SGD: apply every step locally, average state "
                            "in the background with the delta rule so concurrent "
                            "steps survive")
-    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform
+    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform, describe_devices
 
     add_platform_arg(parser)
     args = parser.parse_args()
-    if args.platform is None:
-        args.platform = "cpu"
     apply_platform(args)
     import jax
 
@@ -104,6 +102,7 @@ def main():
         "metric": "optimizer_loss_reduction",
         "value": round(min(r[0] / max(r[1], 1e-9) for r in results.values()), 2),
         "unit": "x",
+        "device": describe_devices(),
         "extra": {
             "peers": args.num_peers, "seconds": round(elapsed, 1),
             "mode": "dpu" if args.dpu else ("local_updates" if args.local_updates else "sync"),

@@ -34,12 +34,10 @@ def main():
                              "swarm round (models a slow-sending groupmate); the "
                              "A/B vs --delay_grad_averaging shows epochs/min "
                              "staying flat as this grows")
-    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform
+    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform, describe_devices
 
     add_platform_arg(parser)
     args = parser.parse_args()
-    if args.platform is None:
-        args.platform = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     if args.platform == "cpu" and "host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -184,6 +182,7 @@ def main():
         "metric": "slice_collaboration_epochs_per_min",
         "value": round(slice_opt.local_epoch / (elapsed / 60.0), 2),
         "unit": "collaborative epochs/min (slice peer + host peer)",
+        "device": describe_devices(),
         "extra": {
             "mesh": {"dp": dp, "tp": tp, "sp": sp},
             "epochs": slice_opt.local_epoch,

@@ -39,12 +39,10 @@ def main():
                         help="skip the two-peer overlap-efficiency probe "
                              "(ISSUE 19: real optimizer steps must emit a "
                              "nonzero comm/compute overlap ratio)")
-    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform
+    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform, describe_devices
 
     add_platform_arg(parser)
     args = parser.parse_args()
-    if args.platform is None:
-        args.platform = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     if args.platform == "cpu" and "host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -197,6 +195,7 @@ def main():
         "metric": "slice_step_decision_overhead_us",
         "value": with_broadcast["us_per_step"],
         "unit": "us/step (broadcast every step)",
+        "device": describe_devices(),
         "extra": {
             "thinned_us_per_step": thinned["us_per_step"],
             "thinned_skipped_fraction": thinned["skipped_fraction"],

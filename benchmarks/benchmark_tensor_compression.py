@@ -15,7 +15,7 @@ import numpy as np
 def main():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_platforms", "cpu")  # host-only benchmark: pinned, and the result says so
     jax.devices()
 
     from hivemind_tpu.compression import CompressionType, deserialize_tensor, serialize_tensor
@@ -42,6 +42,7 @@ def main():
         "metric": "compression_throughput_10m",
         "value": results["BLOCKWISE_8BIT"]["compress_ms"],
         "unit": "ms",
+        "device": {"platform": "cpu", "pinned": "host-only benchmark"},
         "extra": results,
     }))
 

@@ -30,13 +30,11 @@ def main():
                              "1-token streams through one block (continuous batching)")
     parser.add_argument("--decode_steps", type=int, default=64,
                         help="tokens per decode client")
-    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform
+    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform, describe_devices
 
     add_platform_arg(parser)
     args = parser.parse_args()
 
-    if args.platform is None:
-        args.platform = "cpu"
     apply_platform(args)
     import jax
 
@@ -111,6 +109,7 @@ def main():
             "metric": "moe_decode_tokens_per_sec_aggregate",
             "value": round(sum(done) / elapsed, 1),
             "unit": "tokens/s",
+            "device": describe_devices(),
             "extra": {
                 "decode_clients": args.decode_clients, "steps_per_client": args.decode_steps,
                 "hidden_dim": args.hidden_dim, "expert_cls": args.expert_cls,
@@ -153,6 +152,7 @@ def main():
         "metric": "moe_server_samples_per_sec" + ("_fwd_bwd" if args.backward else "_fwd"),
         "value": round(total / elapsed, 1),
         "unit": "samples/s",
+        "device": describe_devices(),
         "extra": {
             "experts": args.num_experts, "clients": args.num_clients,
             "hidden_dim": args.hidden_dim, "expert_cls": args.expert_cls,

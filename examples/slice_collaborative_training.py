@@ -60,6 +60,10 @@ def main():
     add_platform_arg(parser)
     args = parser.parse_args()
     if args.devices_per_proc > 0:
+        # a rehearsal of several hosts on one machine: virtual CPU devices, never the
+        # chip — the processes of one machine cannot share it
+        args.platform = "cpu"
+        print(f"rehearsal: {args.devices_per_proc} virtual devices, platform pinned to cpu", flush=True)
         kept = [
             flag for flag in os.environ.get("XLA_FLAGS", "").split()
             if not flag.startswith("--xla_force_host_platform_device_count")

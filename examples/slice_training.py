@@ -54,6 +54,10 @@ def main():
     add_platform_arg(parser)
     args = parser.parse_args()
     if args.devices_per_proc > 0:
+        # a rehearsal of several hosts on one machine: virtual CPU devices, never the
+        # chip — the processes of one machine cannot share it
+        args.platform = "cpu"
+        print(f"rehearsal: {args.devices_per_proc} virtual devices, platform pinned to cpu", flush=True)
         # replace (not prepend) any inherited device-count flag: with duplicates
         # XLA honors the last one, so an inherited value would win
         kept = [

@@ -46,11 +46,7 @@ def main():
                              "HBM/leak sampling; docs/observability.md 'Device telemetry'); "
                              "on by default — a DHT-only peer that never touches jax "
                              "pays nothing (the sampler is a no-op without a backend)")
-    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform
-
-    add_platform_arg(parser)
     args = parser.parse_args()
-    apply_platform(args)
 
     dht = DHT(
         initial_peers=args.initial_peers,

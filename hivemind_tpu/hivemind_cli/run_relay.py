@@ -37,11 +37,7 @@ def main():
                         help="persistent relay Ed25519 identity file")
     parser.add_argument("--advertise_period", type=float, default=300.0,
                         help="re-advertise at this period (records expire at 2x)")
-    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform
-
-    add_platform_arg(parser)
     args = parser.parse_args()
-    apply_platform(args)
 
     if args.announce_host is None:
         args.announce_host = "127.0.0.1"

@@ -116,11 +116,13 @@ def main():
                         help="disable device-side observability (jit compile tracking, "
                              "HBM/leak sampling on the watchdog tick, transfer counters; "
                              "docs/observability.md 'Device telemetry'); on by default")
-    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform
+    from hivemind_tpu.utils.platform import add_platform_arg, apply_platform, describe_devices
 
     add_platform_arg(parser)
     args = parser.parse_args()
     apply_platform(args)
+    # this is where the serving process meets its devices: say which it got
+    logger.info(f"devices: {json.dumps(describe_devices())}")
 
     if args.increase_file_limit:
         from hivemind_tpu.utils.limits import increase_file_limit

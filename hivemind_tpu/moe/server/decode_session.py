@@ -157,14 +157,14 @@ class DecodeSessionManager:
     def _raw_step(self, uid: str):
         """The un-jitted per-session step; shared by the direct and batched paths so
         a signature change cannot silently diverge them."""
-        module = self.backends[uid].module
+        backend = self.backends[uid]
 
         def step(params, x, cache_k, cache_v, index):
-            from hivemind_tpu.ops.quantized_params import dequantize_tree
-
             # int8 weight-only backends: materialize dense weights inside the jit
             # (identity for plain fp32 trees)
-            return module.apply({"params": dequantize_tree(params)}, x, cache_k, cache_v, index)
+            return backend.module.apply(
+                {"params": backend.dense_params(params)}, x, cache_k, cache_v, index
+            )
 
         return step
 
@@ -179,6 +179,28 @@ class DecodeSessionManager:
                 self._raw_step(uid), site="decode_session.step", donate_argnums=(2, 3)
             )
         return fn
+
+    def _advance(self, uid: str, session: _Session, backend, x: np.ndarray, chunk_len: int):
+        """Run the per-session jitted step on ``x`` (already padded to
+        ``chunk_len``) under ``session.lock``, store the new caches and return the
+        output on the host. The step DONATES the caches: if it fails (at dispatch
+        or when the result is read), what the session still points at may be
+        deleted buffers, so the session is dropped and the client's next
+        continuation gets the unknown-session KeyError (it re-prefills) instead
+        of a read of donated memory."""
+        step = self._step_fn(uid, x.shape[0], chunk_len)
+        try:
+            y, session.cache_k, session.cache_v = step(
+                backend.snapshot_params(), jnp.asarray(x), session.cache_k,
+                session.cache_v, jnp.int32(session.index),
+            )
+            return np.asarray(y)
+        except Exception:
+            with self._lock:
+                for key in [k for k, s in self._sessions.items() if s is session]:
+                    del self._sessions[key]
+                self._sample_gauges_locked()
+            raise
 
     def decode(self, uid: str, session_id: str, x: np.ndarray, reset: bool) -> np.ndarray:
         """One session step: prefill (``reset=True``, chunk = the prompt) or advance
@@ -242,12 +264,8 @@ class DecodeSessionManager:
             padded_len = new_len if new_len == 1 else min(_next_pow2(new_len), self.max_len)
             if padded_len != new_len:
                 x = np.pad(x, ((0, 0), (0, padded_len - new_len), (0, 0)))
-            step = self._step_fn(uid, batch, padded_len)
             record_transfer(x.nbytes, "host_to_device")
-            y, session.cache_k, session.cache_v = step(
-                backend.snapshot_params(), jnp.asarray(x), session.cache_k,
-                session.cache_v, jnp.int32(session.index),
-            )
+            y = self._advance(uid, session, backend, x, padded_len)
             session.index += new_len
             # re-stamp AFTER the device step: a step that hits a jit compile can
             # outlast merge_recency_s, and a session stamped only at entry would
@@ -256,7 +274,7 @@ class DecodeSessionManager:
             # Bare float store; concurrent readers just see one of two recent stamps.
             session.last_used = time.monotonic()
             _STEPS.inc(path="direct")
-            out = np.asarray(y)[:, :new_len]
+            out = y[:, :new_len]
             record_transfer(out.nbytes, "device_to_host")
             return out
 
@@ -462,18 +480,14 @@ class DecodeSessionManager:
                 # diverge); ISSUE 10 copy-free batching applied to decode
                 [i] = live
                 _future, session, x = entries[i]
-                step = self._step_fn(uid, 1, 1)
                 record_transfer(int(x.nbytes), "host_to_device")
-                y, session.cache_k, session.cache_v = step(
-                    backend.snapshot_params(), jnp.asarray(x), session.cache_k,
-                    session.cache_v, jnp.int32(session.index),
-                )
+                y = self._advance(uid, session, backend, x, 1)
                 session.index += 1
                 session.last_used = time.monotonic()
                 # counted "direct": nothing was merged/vmapped (the catalog row
                 # defines `batched` as merged into a vmapped continuous batch)
                 _STEPS.inc(path="direct")
-                results[i] = np.asarray(y)[:, :1]
+                results[i] = y[:, :1]
                 record_transfer(results[i].nbytes, "device_to_host")
                 return results
             stack = _next_pow2(len(live))

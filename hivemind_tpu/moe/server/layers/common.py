@@ -4,7 +4,7 @@ custom_experts.py:35 register_expert_class)."""
 
 from __future__ import annotations
 
-from typing import Callable, Dict
+from typing import Any, Callable, Dict, Optional
 
 import flax.linen as nn
 import jax
@@ -171,6 +171,9 @@ class LlamaBlockExpert(nn.Module):
     rope_theta: float = 10000.0
     ffn_inner: int = 0  # 0 = the 8/3 rule below; real checkpoints set intermediate_size
     rms_eps: float = 1e-6  # real checkpoints set rms_norm_eps (Llama-2: 1e-5)
+    # set when the block is served sharded over a device mesh (MeshModuleBackend):
+    # the fused attention kernel must then run per shard (mesh_attention_core)
+    mesh: Optional[Any] = None
 
     def init_decode_cache(self, batch: int, max_len: int):
         kv_heads = self.num_kv_heads or self.num_heads
@@ -179,7 +182,7 @@ class LlamaBlockExpert(nn.Module):
 
     @nn.compact
     def __call__(self, x, cache_k=None, cache_v=None, index=None):
-        from hivemind_tpu.ops.pallas_attention import attention_auto
+        from hivemind_tpu.parallel.ring_attention import mesh_attention_core
 
         batch, seq, hid = x.shape
         heads = self.num_heads
@@ -200,7 +203,7 @@ class LlamaBlockExpert(nn.Module):
             if kv_heads != heads:  # grouped-query: each KV head serves heads/kv_heads queries
                 k = jnp.repeat(k, heads // kv_heads, axis=2)
                 v = jnp.repeat(v, heads // kv_heads, axis=2)
-            attn = attention_auto(q, k, v, causal=True).reshape(batch, seq, hid)
+            attn = mesh_attention_core(self.mesh, q, k, v, causal=True).reshape(batch, seq, hid)
         else:
             context, cache_k, cache_v = _decode_attention(
                 q, k, v, cache_k, cache_v, index, groups=heads // kv_heads

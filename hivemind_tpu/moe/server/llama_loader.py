@@ -156,6 +156,7 @@ def load_llama_blocks(
             rope_theta=config.rope_theta,
             ffn_inner=config.intermediate_size,
             rms_eps=config.rms_norm_eps,
+            mesh=mesh,
         )
         common_opts = dict(
             optimizer=optimizer or optax.sgd(0.0),

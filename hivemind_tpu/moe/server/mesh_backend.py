@@ -14,10 +14,14 @@ by a slice whose aggregate HBM holds it easily (see ``plan_block_capacity``'s
 
 Sharding rule: every parameter kernel with ndim >= 2 is sharded over its LAST
 axis (the output features — Megatron-style column parallel) when divisible by
-the mesh axis size; 1-D leaves (biases, norm scales) replicate. Correctness
-never depends on the rule — GSPMD resolves any placement — the rule just keeps
-the big matmuls distributed. KV caches shard over the kv-heads axis the same
-way (``shard_decode_cache``, consulted by the decode-session manager)."""
+the mesh axis size; 1-D leaves (biases, norm scales) replicate; int8 weights
+shard by quantization block (``ops/quantized_params``). Correctness never
+depends on the rule for what XLA compiles — GSPMD resolves any placement, the
+rule just keeps the big matmuls distributed — but the Pallas kernels are not
+GSPMD's to partition: they run per shard under shard_map (attention through
+``mesh_attention_core``, the int8 codec through ``dense_params``). KV caches
+shard over the kv-heads axis the same way (``shard_decode_cache``, consulted by
+the decode-session manager)."""
 
 from __future__ import annotations
 
@@ -109,6 +113,9 @@ class MeshModuleBackend(ModuleBackend):
             jax.device_put(cache_v, cache_sharding(cache_v)),
         )
 
+    def _codec_placement(self):
+        return {"mesh": self.mesh, "axis": self.shard_axis}
+
     def load_params(self, params) -> None:
         """Checkpoint loads land each (host) leaf DIRECTLY under its sharding —
         no single-device stopover, for the same too-big-for-one-chip reason as
@@ -116,10 +123,7 @@ class MeshModuleBackend(ModuleBackend):
         so they inherit the placement."""
         with self._state_lock:
             if self.weight_quantization is not None:
-                from hivemind_tpu.ops.quantized_params import quantize_params
-
-                quantized = quantize_params(params)
-                self.params = jax.device_put(quantized, self.tree_shardings(quantized))
+                self.params = self._quantize(params)
             else:
                 self.params = jax.tree_util.tree_map(
                     lambda leaf: jax.device_put(
@@ -132,15 +136,13 @@ class MeshModuleBackend(ModuleBackend):
     # ------------------------------------------------------------------ accounting
 
     def param_bytes_per_device(self) -> int:
-        """Resident parameter bytes on EACH device of the mesh — the number that
-        must fit one chip's HBM (``param_bytes`` stays the global total)."""
-        total = 0
-        for leaf in jax.tree_util.tree_leaves(self.params):
-            nbytes = int(np.prod(leaf.shape)) * leaf.dtype.itemsize
-            if self.leaf_spec(leaf) != PartitionSpec():
-                nbytes //= self._axis_size()
-            total += nbytes
-        return total
+        """Resident parameter bytes on EACH device of the mesh, read from the
+        arrays' own shardings — the number that must fit one chip's HBM
+        (``param_bytes`` stays the global total)."""
+        return sum(
+            int(np.prod(leaf.sharding.shard_shape(leaf.shape))) * leaf.dtype.itemsize
+            for leaf in jax.tree_util.tree_leaves(self.params)
+        )
 
     def get_info(self):
         info = super().get_info()

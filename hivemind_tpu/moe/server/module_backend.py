@@ -8,6 +8,7 @@ the forward under jax.vjp and applies the optimizer update in the same jitted ca
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -69,42 +70,52 @@ class ModuleBackend:
         self.name, self.module, self.optimizer = name, module, optimizer
         self.max_batch_size = max_batch_size
         self.weight_quantization = weight_quantization
+        from hivemind_tpu.ops.quantized_params import dequantize_tree, quantize_params
+
+        placement = self._codec_placement()
+        self._quantize = functools.partial(quantize_params, **placement)
+        # the dense weights of a (possibly int8-stored) parameter tree — traced INSIDE
+        # the serving jits (forward here, the decode-session steps), so the dense
+        # copies are transient; identity for plain trees
+        self.dense_params = dense_params = functools.partial(dequantize_tree, **placement)
         samples = tuple(jnp.asarray(np.asarray(s)[:1]) for s in sample_inputs)
         self.params, self.opt_state = self._init_state(samples, rng_seed)
         self._state_lock = threading.Lock()
         self.update_count = 0
 
-        sample_out = module.apply({"params": self.params}, *samples)
+        # shapes only: nothing runs, so a mesh backend needs no eager shard_map and
+        # no backend pays a whole forward pass to learn its output schema
+        sample_out = jax.eval_shape(module.apply, {"params": self.params}, *samples)
         if weight_quantization is not None:
-            from hivemind_tpu.ops.quantized_params import quantize_params
-
-            self.params = quantize_params(self.params)
+            self.params = self._quantize(self.params)
         outs = tuple(sample_out) if isinstance(sample_out, (tuple, list)) else (sample_out,)
         self.num_inputs, self.num_outputs = len(samples), len(outs)
-        self._outputs_are_tuple = isinstance(sample_out, (tuple, list))
+        outputs_are_tuple = isinstance(sample_out, (tuple, list))
         self.forward_schema = tuple(
             BatchTensorDescriptor.from_array(np.asarray(s)) for s in sample_inputs
         )
-        self.outputs_schema = tuple(BatchTensorDescriptor.from_array(np.asarray(o)) for o in outs)
+        self.outputs_schema = tuple(BatchTensorDescriptor.from_array(o) for o in outs)
 
         def _as_tuple(value):
             return tuple(value) if isinstance(value, (tuple, list)) else (value,)
 
         # tracked_jit (ISSUE 19): per-bucket compiles show up on the compile
         # tracker (sites are fixed strings — expert names would explode label
-        # cardinality; the signature on the compile record carries the shape)
+        # cardinality; the signature on the compile record carries the shape).
+        # Neither closure may capture ``self``: a jitted function is a C++ object
+        # the garbage collector does not look through, so the cycle backend ->
+        # jitted closure -> backend would pin the expert's weights on the device
+        # for the life of the process.
         @tracked_jit(site="module_backend.forward")
         def _forward(params, *xs):
-            from hivemind_tpu.ops.quantized_params import dequantize_tree
-
-            return _as_tuple(module.apply({"params": dequantize_tree(params)}, *xs))
+            return _as_tuple(module.apply({"params": dense_params(params)}, *xs))
 
         @tracked_jit(site="module_backend.backward")
         def _backward(params, opt_state, xs, grad_outs):
             import optax
 
             out, vjp = jax.vjp(lambda p, xx: module.apply({"params": p}, *xx), params, tuple(xs))
-            cotangent = _as_tuple(grad_outs) if self._outputs_are_tuple else grad_outs[0]
+            cotangent = _as_tuple(grad_outs) if outputs_are_tuple else grad_outs[0]
             grad_params, grad_xs = vjp(cotangent)
             updates, new_opt_state = optimizer.update(grad_params, opt_state, params)
             new_params = optax.apply_updates(params, updates)
@@ -129,6 +140,12 @@ class ModuleBackend:
         opt_state = self.optimizer.init(params) if self.weight_quantization is None else None
         return params, opt_state
 
+    def _codec_placement(self) -> Dict[str, Any]:
+        """Where the int8 codec runs, as keyword arguments of `quantize_params` /
+        `dequantize_tree`: nothing to say on one device; a mesh backend names its
+        mesh, as it controls placement in `_init_state`."""
+        return {}
+
     def snapshot_params(self):
         """The current parameter pytree under the state lock (for read-only use by
         auxiliary executors, e.g. decode sessions)."""
@@ -141,9 +158,7 @@ class ModuleBackend:
         trainable ones restart optimizer statistics for the new weights."""
         with self._state_lock:
             if self.weight_quantization is not None:
-                from hivemind_tpu.ops.quantized_params import quantize_params
-
-                self.params = quantize_params(params)
+                self.params = self._quantize(params)
             else:
                 self.params = jax.tree_util.tree_map(jnp.asarray, params)
                 self.opt_state = self.optimizer.init(self.params)
@@ -212,34 +227,38 @@ class ModuleBackend:
     def state_dict(self) -> bytes:
         import flax.serialization
 
-        from hivemind_tpu.ops.quantized_params import dequantize_tree
-
         with self._state_lock:
             # quantized backends serialize the dense form (msgpack cannot carry the
             # QuantizedTensor nodes); load_state_dict re-encodes, so the round-trip
             # is exact for int8 serving
             return flax.serialization.to_bytes(
                 {
-                    "params": dequantize_tree(self.params),
+                    "params": self._dense_snapshot(),
                     "opt_state": self.opt_state if self.opt_state is not None else {},
                     "updates": self.update_count,
                 }
             )
 
+    def _dense_snapshot(self):
+        """Dense weights for (de)serialization — one jitted program, so a mesh
+        backend's per-shard decoders compile together. Rare (checkpoint / replica
+        transfer), hence no compile tracking."""
+        if self.weight_quantization is None:
+            return self.params
+        return jax.jit(self.dense_params)(self.params)  # lint: allow(jit-in-hot-path)
+
     def load_state_dict(self, blob: bytes) -> None:
         import flax.serialization
 
-        from hivemind_tpu.ops.quantized_params import dequantize_tree, quantize_params
-
         with self._state_lock:
             template = {
-                "params": dequantize_tree(self.params),
+                "params": self._dense_snapshot(),
                 "opt_state": self.opt_state if self.opt_state is not None else {},
                 "updates": 0,
             }
             restored = flax.serialization.from_bytes(template, blob)
             if self.weight_quantization is not None:
-                self.params = quantize_params(restored["params"])
+                self.params = self._quantize(restored["params"])
             else:
                 self.params = restored["params"]
                 self.opt_state = restored["opt_state"]

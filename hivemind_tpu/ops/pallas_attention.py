@@ -351,19 +351,26 @@ def _flash_forced() -> bool:
     return os.environ.get("HIVEMIND_TPU_FORCE_FLASH", "0") == "1"
 
 
-def attention_auto(q, k, v, mask=None, causal: bool = False):
-    """Backend dispatch for the attention core: fused Pallas kernel on TPU (full
-    sequences; both directions are fused kernels — set
-    HIVEMIND_TPU_FLASH_ATTENTION=0 to force the einsum core for A/B runs),
-    reference einsum path elsewhere or when a padding mask is given."""
-    # q_len != k_len (cached incremental decode) needs plain_attention's end-aligned
-    # causal mask; the flash kernel assumes square self-attention
-    if (
+def flash_applies(q, k, mask=None) -> bool:
+    """Whether the fused kernel serves this call: full unmasked sequences on a TPU
+    (or an AOT trace for one). q_len != k_len (cached incremental decode) needs
+    plain_attention's end-aligned causal mask; the kernel assumes square
+    self-attention."""
+    return (
         mask is None
         and q.shape[1] == k.shape[1]
         and (jax.default_backend() == "tpu" or _flash_forced())
         and _flash_enabled()
-    ):
+    )
+
+
+def attention_auto(q, k, v, mask=None, causal: bool = False):
+    """Backend dispatch for the attention core on ONE device: fused Pallas kernel
+    where `flash_applies` (both directions are fused kernels — set
+    HIVEMIND_TPU_FLASH_ATTENTION=0 to force the einsum core for A/B runs),
+    reference einsum path elsewhere. Operands sharded over a mesh go through
+    `parallel.ring_attention.mesh_attention_core`, which runs the kernel per shard."""
+    if flash_applies(q, k, mask):
         return flash_attention(q, k, v, causal)
     from hivemind_tpu.parallel.ring_attention import plain_attention
 

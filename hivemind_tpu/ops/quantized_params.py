@@ -13,11 +13,13 @@ resident model memory divides by ~4 while matmuls still run on the MXU in bf16.
 
 from __future__ import annotations
 
-from typing import Any, Tuple
+import functools
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from hivemind_tpu.ops.pallas_quantization import (
     blockwise_dequantize_auto,
@@ -46,10 +48,17 @@ class QuantizedTensor:
 
     @property
     def nbytes(self) -> int:
-        return int(np.asarray(self.codes).nbytes + np.asarray(self.absmax).nbytes)
+        return int(self.codes.nbytes + self.absmax.nbytes)
 
-    def dequantize(self):
-        flat = blockwise_dequantize_auto(self.codes, self.absmax, QUANT_BLOCK_SIZE)
+    def dequantize(self, mesh=None, axis: Optional[str] = None):
+        decode = lambda codes, absmax: blockwise_dequantize_auto(codes, absmax, QUANT_BLOCK_SIZE)
+        if mesh is not None:
+            rows = _row_axis(mesh, axis, self.codes.shape[0])
+            decode = _per_shard(
+                decode, mesh, in_specs=(PartitionSpec(rows, None), PartitionSpec(rows)),
+                out_specs=PartitionSpec(rows),
+            )
+        flat = decode(self.codes, self.absmax)
         return flat[: self.size].reshape(self.shape).astype(self.dtype)
 
     def __repr__(self):
@@ -60,30 +69,77 @@ def _is_quantized(leaf) -> bool:
     return isinstance(leaf, QuantizedTensor)
 
 
-def quantize_params(params: Any, min_size: int = MIN_QUANT_SIZE) -> Any:
-    """Float leaves with >= ``min_size`` elements become QuantizedTensor."""
+def _row_axis(mesh, axis: str, n_blocks: int) -> Optional[str]:
+    """The mesh axis quantization blocks (rows) are distributed over, or None when
+    the block count does not divide it (then every device holds, and works on,
+    all rows)."""
+    return axis if n_blocks % int(mesh.shape[axis]) == 0 else None
+
+
+def _per_shard(fn, mesh, in_specs, out_specs):
+    """Run ``fn`` once per device on that device's rows. Quantization blocks are
+    independent, so this needs no communication — and it is the only way a Mosaic
+    kernel runs on sharded operands at all: GSPMD cannot partition one, and jax
+    refuses to lower a bare ``pallas_call`` whose operands are sharded."""
+    from jax import shard_map
+
+    # check_vma off: the varying-axes checker cannot see through pallas_call
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+
+
+@functools.lru_cache(maxsize=8)
+def _mesh_encoder(mesh, rows: Optional[str]):
+    """The jitted per-shard encoder for blocks laid out ``[rows, None]`` on ``mesh``
+    (cached so that loading many leaves compiles once per leaf shape, not per leaf)."""
+    return jax.jit(
+        _per_shard(
+            lambda blocks: blockwise_quantize_auto(blocks.reshape(-1), QUANT_BLOCK_SIZE),
+            mesh, in_specs=PartitionSpec(rows, None),
+            out_specs=(PartitionSpec(rows, None), PartitionSpec(rows)),
+        )
+    )
+
+
+def quantize_params(
+    params: Any, min_size: int = MIN_QUANT_SIZE, mesh=None, axis: Optional[str] = None
+) -> Any:
+    """Float leaves with >= ``min_size`` elements become QuantizedTensor.
+
+    With ``mesh``, every leaf goes from where it is (host memory for a checkpoint
+    load) straight to its place on the mesh: blocks to be quantized are laid out
+    row-sharded over ``axis`` and encoded per device, exact leaves replicate — no
+    leaf ever exists whole on one device."""
 
     def convert(leaf):
-        arr = jnp.asarray(leaf)
+        xp = np if isinstance(leaf, np.ndarray) else jnp
         # only float MATRICES quantize: 1-D leaves are norm scales/biases whose
         # exactness matters far more than their bytes (a 4096-wide RMSNorm scale
         # has size == one quant block, so a pure size test would catch it)
-        if arr.ndim < 2 or arr.size < min_size or not jnp.issubdtype(arr.dtype, jnp.floating):
-            return arr
-        flat = arr.astype(jnp.float32).reshape(-1)
+        if leaf.ndim < 2 or leaf.size < min_size or not jnp.issubdtype(leaf.dtype, jnp.floating):
+            if mesh is None:
+                return jnp.asarray(leaf)
+            return jax.device_put(leaf, NamedSharding(mesh, PartitionSpec()))
+        flat = xp.reshape(leaf, -1).astype(xp.float32)
         pad = (-flat.size) % QUANT_BLOCK_SIZE
         if pad:
-            flat = jnp.pad(flat, (0, pad))
-        codes, absmax = blockwise_quantize_auto(flat, QUANT_BLOCK_SIZE)
-        return QuantizedTensor(codes, absmax, arr.shape, arr.dtype, arr.size)
+            flat = xp.pad(flat, (0, pad))
+        if mesh is None:
+            codes, absmax = blockwise_quantize_auto(flat, QUANT_BLOCK_SIZE)
+        else:
+            blocks = xp.reshape(flat, (-1, QUANT_BLOCK_SIZE))
+            rows = _row_axis(mesh, axis, blocks.shape[0])
+            blocks = jax.device_put(blocks, NamedSharding(mesh, PartitionSpec(rows, None)))
+            codes, absmax = _mesh_encoder(mesh, rows)(blocks)
+        return QuantizedTensor(codes, absmax, leaf.shape, leaf.dtype, leaf.size)
 
     return jax.tree_util.tree_map(convert, params)
 
 
-def dequantize_tree(params: Any) -> Any:
-    """Materialize a quantized tree back to dense weights (call INSIDE jit)."""
+def dequantize_tree(params: Any, mesh=None, axis: Optional[str] = None) -> Any:
+    """Materialize a quantized tree back to dense weights (call INSIDE jit).
+    ``mesh``/``axis``: as given to `quantize_params`."""
     return jax.tree_util.tree_map(
-        lambda leaf: leaf.dequantize() if _is_quantized(leaf) else leaf,
+        lambda leaf: leaf.dequantize(mesh, axis) if _is_quantized(leaf) else leaf,
         params,
         is_leaf=_is_quantized,
     )
@@ -91,12 +147,4 @@ def dequantize_tree(params: Any) -> Any:
 
 def tree_param_bytes(params: Any) -> int:
     """Resident bytes of a (possibly quantized) parameter tree."""
-    total = 0
-    for leaf in jax.tree_util.tree_leaves(
-        params, is_leaf=_is_quantized
-    ):
-        if _is_quantized(leaf):
-            total += leaf.nbytes
-        else:
-            total += int(np.asarray(leaf).nbytes)
-    return total
+    return sum(int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(params))

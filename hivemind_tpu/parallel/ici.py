@@ -30,9 +30,8 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from hivemind_tpu.parallel._compat import shard_map
 
 
 def _leaf_spec(leaf) -> P:

@@ -148,39 +148,52 @@ ring_flash_attention.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 
 def mesh_attention_core(mesh, q, k, v, mask=None, causal: bool = False):
-    """The shared attention dispatch for mesh-aware models: sequence-parallel
-    meshes (sp > 1) run (flash-)ring attention under shard_map — the fused-kernel
-    ring when the TPU flash opt-in is active — and everything else runs
-    single-device `plain_attention`. ``mask`` (key-validity) is only supported on
-    the single-device path: ring shards carry full sequences."""
-    if mesh is not None and mesh.shape.get("sp", 1) > 1:
-        from jax.sharding import PartitionSpec as P
+    """The shared attention dispatch for mesh-aware models. On a mesh of more than
+    one device the fused kernel runs PER SHARD under shard_map (batch over ``dp``,
+    heads over ``tp``): Mosaic kernels cannot be partitioned by GSPMD — jax refuses
+    to lower a bare ``pallas_call`` on sharded operands ("Mosaic kernels cannot be
+    automatically partitioned"). With ``sp`` > 1 the per-shard core is the
+    (flash-)ring over the sequence axis. Without a mesh, with one device, or when
+    the einsum core is selected, `attention_auto` decides (XLA partitions the
+    einsum core by itself). ``mask`` (key-validity) is only supported off the
+    ring: ring shards carry full sequences."""
+    from hivemind_tpu.ops.pallas_attention import attention_auto, flash_applies, flash_attention
 
-        from hivemind_tpu.parallel._compat import NO_CHECK, shard_map
-
-        from hivemind_tpu.ops.pallas_attention import _flash_enabled, _flash_forced
-
+    ring = mesh is not None and mesh.shape.get("sp", 1) > 1
+    flash = flash_applies(q, k, mask)
+    if ring:
         assert mask is None, "ring attention shards carry full sequences (no padding mask)"
-        spec = P("dp", "sp", "tp" if mesh.shape.get("tp", 1) > 1 else None, None)
-        extra = {}
-        if _flash_enabled() and (jax.default_backend() == "tpu" or _flash_forced()):
+        if flash:
             # flash core per ring step: scores stay in VMEM, shard outputs merge
-            # via log-sum-exp. check_vma off: the varying-axes checker cannot see
-            # through pallas_call outputs.
+            # via log-sum-exp
             def inner(q, k, v):
                 return ring_flash_attention(q, k, v, "sp", False, causal)
 
-            extra.update(NO_CHECK)
         else:
             inner = partial(ring_attention, axis_name="sp", causal=causal)
-        core = shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, **extra)
-        return core(q, k, v)
-    # single-device: attention_auto picks the fused flash kernel on TPU (full,
-    # unmasked sequences) and the einsum core elsewhere — the flagship train step
-    # (mask=None via loss_masked_only) gets the kernel by default this way
-    from hivemind_tpu.ops.pallas_attention import attention_auto
+    elif mesh is not None and mesh.size > 1 and flash:
 
-    return attention_auto(q, k, v, mask=mask, causal=causal)
+        def inner(q, k, v):
+            return flash_attention(q, k, v, causal)
+
+    else:
+        return attention_auto(q, k, v, mask=mask, causal=causal)
+
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(
+        "dp" if "dp" in mesh.shape else None,
+        "sp" if ring else None,
+        "tp" if mesh.shape.get("tp", 1) > 1 else None,
+        None,
+    )
+    # check_vma off for the flash cores: the varying-axes checker cannot see
+    # through pallas_call outputs
+    core = shard_map(
+        inner, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=not flash
+    )
+    return core(q, k, v)
 
 
 def plain_attention(

@@ -34,6 +34,7 @@ frames).
 
 from __future__ import annotations
 
+import math
 import sys
 import threading
 from collections import deque
@@ -65,8 +66,8 @@ _STORMS = REGISTRY.counter(
 )
 _MEMORY_BYTES = REGISTRY.gauge(
     "hivemind_device_memory_bytes",
-    "live jax buffer bytes per device (from jax.live_arrays; sharded arrays "
-    "split evenly across their devices)",
+    "live jax buffer bytes per device (from jax.live_arrays; each device counts "
+    "the shard it holds, so a replicated array counts in full on every device)",
     ("device",),
 )
 _MEMORY_PEAK_BYTES = REGISTRY.gauge(
@@ -332,17 +333,17 @@ class DeviceMemoryMonitor:
         device_objs: Dict[str, Any] = {}
         for array in arrays:
             try:
-                devices = list(array.devices())
-                nbytes = int(array.nbytes)
+                sharding = array.sharding
+                devices = sharding.addressable_devices
+                # what EACH device holds: its shard, which for a replicated array
+                # is the whole array — not an even split of the global bytes
+                shard_bytes = math.prod(sharding.shard_shape(array.shape)) * array.dtype.itemsize
             except Exception:
                 continue  # deleted/donated buffers can race the walk
-            if not devices:
-                continue
-            share = nbytes // len(devices)
             for device in devices:
                 key = str(device)
                 entry = per_device.setdefault(key, [0, 0])
-                entry[0] += share
+                entry[0] += shard_bytes
                 entry[1] += 1
                 device_objs.setdefault(key, device)
         snapshot: Dict[str, Any] = {"devices": {}, "total_bytes": 0, "buffers": 0}
@@ -642,29 +643,24 @@ def _watchdog_sampler() -> None:
 
 
 def _install_jax_monitoring() -> None:
-    """Hook ``jax.monitoring`` compile-duration events (where this jaxlib has
-    them) into the tracker. Install-once per process: jax offers registration
-    but no reliable unregistration across versions, so the trampoline stays and
-    the tracker's reset() is what tests rely on."""
+    """Hook ``jax.monitoring`` compile-duration events into the tracker.
+    Install-once per process: the trampoline stays registered and the tracker's
+    reset() is what tests rely on."""
     global _MONITORING_INSTALLED
     if _MONITORING_INSTALLED:
         return
     jax = sys.modules.get("jax")
     if jax is None:
         return  # never import jax for telemetry's sake
-    monitoring = getattr(jax, "monitoring", None)
-    register = getattr(monitoring, "register_event_duration_secs_listener", None)
-    if register is None:
-        return
+
     def _on_event(event: str, duration: float, **_kwargs) -> None:
-        if "compil" in event:  # matches compile/compilation event families
+        # trace, lowering and backend-compile time actually SPENT; not the
+        # /jax/compilation_cache/ family, which reports time a cache hit saved
+        if event.startswith("/jax/core/compile/"):
             COMPILE_TRACKER.record_jax_event(event, duration)
 
-    try:
-        register(_on_event)
-        _MONITORING_INSTALLED = True
-    except Exception as e:  # telemetry must never take the process down
-        logger.warning(f"could not install jax.monitoring listener: {e!r}")
+    jax.monitoring.register_event_duration_secs_listener(_on_event)
+    _MONITORING_INSTALLED = True
 
 
 def arm_device_telemetry() -> None:

@@ -65,8 +65,7 @@ def device_memory_stats(device=None) -> Dict[str, Any]:
     import jax
 
     device = device if device is not None else jax.devices()[0]
-    stats = getattr(device, "memory_stats", lambda: None)()
-    return dict(stats) if stats else {}
+    return dict(device.memory_stats() or {})
 
 
 def _abstract_signature(args, kwargs, limit: int = 16) -> Optional[str]:
@@ -109,9 +108,7 @@ def tracked_jit(fn=None, *, site: Optional[str] = None, **jit_kwargs):
 
         label = site or getattr(fn, "__qualname__", None) or getattr(fn, "__name__", "jit")
         jitted = jax.jit(fn, **jit_kwargs)
-        cache_size = getattr(jitted, "_cache_size", None)
-        if cache_size is None:  # exotic jaxlib: still jit, just without tracking
-            return jitted
+        cache_size = jitted._cache_size
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):

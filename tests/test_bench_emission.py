@@ -1,109 +1,74 @@
-"""The official round artifact must always carry a legible metric.
-
-The driver records only the last ~2000 characters of bench.py's output; round 4
-embedded the probe log inside the single JSON line and truncated its own metric
-away (VERDICT r4 weak #1). These tests pin the contract: whatever diagnostics a
-round accumulates, the final stdout line is compact, metric-first JSON that
-survives a 2000-char tail capture."""
+"""bench.py names the device behind every number and has no way to report one it
+did not take: no CPU fallback, no default peak, no child whose failure is skipped.
+The host-side drivers it starts are exercised here in their --smoke modes."""
 
 import importlib.util
-import io
 import json
 import os
+import subprocess
+import sys
 
-_BENCH_PATH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench.py")
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_BENCH_PATH = os.path.join(_REPO, "bench.py")
 _spec = importlib.util.spec_from_file_location("bench", _BENCH_PATH)
 bench = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(bench)
 
 
-def _bloated_result() -> dict:
-    """A worst-case round result: three probe points with verbatim hang errors,
-    repeated measurement failures, bracketing host controls — the exact shape
-    that defeated the round-4 artifact."""
-    probe_errors = [
-        {
-            "attempt": i,
-            "rc": None,
-            "stderr": "probe hung >120s (tunnel wedged); partial stderr: " + "x" * 400,
-        }
-        for i in range(3)
-    ]
-    control = {
-        "unix_time": 1753800000.0,
-        "loadavg": [3.12, 2.98, 2.5],
-        "cpu_count": 1,
-        "matmul_gflops": 10.45,
-        "aead_seal_mb_s": 1333.7,
-    }
-    return {
-        "metric": "albert_base_mlm_tokens_per_sec_per_chip",
-        "value": 1234.5,
-        "unit": "tokens/s",
-        "vs_baseline": 0.0,
-        "tpu_unavailable": True,
-        "fallback": "cpu",
-        "extra": {
-            "device": "cpu",
-            "batch_size": 4,
-            "remat": False,
-            "seq_len": 128,
-            "final_loss": 7.1234,
-            "averaging_gbps_per_peer": 0.61,
-            "averaging_extra": {"num_peers": 4, "rounds": 3, "detail": "y" * 600},
-            "host_control": {"at_start": control, "at_end": control},
-        },
-        "tpu_probe_log": [
-            {
-                "when": label,
-                "unix_time": 1753800000.0 + 600 * i,
-                "loadavg": [3.0, 3.0, 3.0],
-                "reachable": False,
-                "errors": probe_errors,
-            }
-            for i, label in enumerate(["round_start", "mid_round_post_averaging", "pre_emit"])
-        ],
-        "tpu_measure_errors": ["measurement subprocess hung >1800s (runtime wedged mid-run)"] * 2,
-    }
+class _Device:
+    platform = "tpu"
+
+    def __init__(self, device_kind):
+        self.device_kind = device_kind
 
 
-def test_final_line_survives_2000_char_tail():
-    out, err = io.StringIO(), io.StringIO()
-    bench.emit(_bloated_result(), out=out, err=err)
-
-    tail = out.getvalue()[-2000:]  # what the driver actually keeps
-    last_line = tail.strip().splitlines()[-1]
-    parsed = json.loads(last_line)
-    assert parsed["metric"] == "albert_base_mlm_tokens_per_sec_per_chip"
-    assert parsed["value"] == 1234.5
-    assert parsed["unit"] == "tokens/s"
-    assert parsed["vs_baseline"] == 0.0
-    assert parsed["tpu_unavailable"] is True
-    # probe outcomes survive in summarized form
-    probes = parsed["extra"]["tpu_probes"]
-    assert [p["reachable"] for p in probes] == [False, False, False]
-
-    # the full diagnostics are preserved, on stderr
-    full = json.loads(err.getvalue())
-    assert full["tpu_probe_log"][0]["errors"][0]["stderr"].startswith("probe hung")
+def test_peak_flops_knows_the_v5e_and_refuses_an_unknown_device():
+    assert bench.peak_flops(_Device("TPU v5 lite")) == 197e12
+    with pytest.raises(ValueError, match="no published peak for device_kind 'TPU v99'"):
+        bench.peak_flops(_Device("TPU v99"))
+    with pytest.raises(ValueError):
+        bench.peak_flops(_Device("cpu"))
 
 
-def test_compact_line_bounded_even_when_pathological():
-    result = _bloated_result()
-    # a pathologically long device string + many probes: the line must still fit
-    result["extra"]["device"] = "d" * 3000
-    result["tpu_probe_log"] = result["tpu_probe_log"] * 20
-    line = bench.compact_result(result)
-    assert len(line) <= 1500
-    parsed = json.loads(line)
-    assert parsed["metric"] == "albert_base_mlm_tokens_per_sec_per_chip"
-    assert parsed["value"] == 1234.5
+def test_bench_has_no_path_around_a_missing_chip():
+    """The device measurement fails on this CPU host — exit code non-zero, no JSON
+    result on stdout — and the source holds none of the old escape hatches."""
+    run = subprocess.run(
+        [sys.executable, "-c", "import bench; bench.measure_main()"], cwd=_REPO, timeout=240,
+        capture_output=True, text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert run.returncode != 0
+    assert "jax found platform 'cpu'" in run.stderr
+    assert not any(line.startswith("{") for line in run.stdout.splitlines())
+    source = open(_BENCH_PATH).read()
+    for gone in ("force_cpu", "tpu_unavailable", "fallback", "_tpu_probe", "compact_result", "_host_control"):
+        assert gone not in source, gone
+
+
+def test_a_failed_host_driver_fails_the_run(tmp_path, monkeypatch):
+    """A child that exits non-zero, or prints no result, raises — it used to return
+    None and let the run exit 0 with a hole in it."""
+    scripts = tmp_path / "benchmarks"
+    scripts.mkdir()
+    (scripts / "crashes.py").write_text("import sys; print('{\"value\": 1}'); sys.exit(4)")
+    (scripts / "silent.py").write_text("print('nothing to see')")
+    (scripts / "works.py").write_text(
+        "import json, os; print(json.dumps({'value': 2, 'platform': os.environ['JAX_PLATFORMS']}))"
+    )
+    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
+    with pytest.raises(RuntimeError, match="exited with code 4"):
+        bench._run_host_driver("crashes.py", [], timeout=60)
+    with pytest.raises(RuntimeError, match="printed no result"):
+        bench._run_host_driver("silent.py", [], timeout=60)
+    # and the child that works was pinned to the CPU: this process owns the chip
+    assert bench._run_host_driver("works.py", [], timeout=60) == {"value": 2, "platform": "cpu"}
 
 
 def test_bench_artifact_embeds_telemetry_snapshot():
     """ISSUE 2: every BENCH artifact carries a telemetry snapshot — the bench
-    process's registry plus the averaging swarm's (shipped via its JSON extra) —
-    while the compact stdout line stays bounded."""
+    process's registry plus the averaging swarm's (shipped via its JSON extra)."""
     from hivemind_tpu.telemetry import REGISTRY
 
     REGISTRY.counter("bench_emission_probe_total", "test counter").inc(5)
@@ -119,47 +84,13 @@ def test_bench_artifact_embeds_telemetry_snapshot():
     assert section["bench_process"]["metrics"]["bench_emission_probe_total"]["series"]["_"] == 5
     assert section["averaging_swarm"]["hivemind_averaging_matchmaking_rounds_total"]["series"][
         "outcome=assembled"] == 8
-
-    result = _bloated_result()
-    result["telemetry"] = section
-    out, err = io.StringIO(), io.StringIO()
-    bench.emit(result, out=out, err=err)
-    # the full stderr artifact carries the snapshot verbatim…
-    full = json.loads(err.getvalue())
-    assert full["telemetry"]["bench_process"]["metrics"]["bench_emission_probe_total"]
-    assert full["telemetry"]["averaging_swarm"]
-    # …and the compact driver line still fits and leads with the metric
-    last_line = out.getvalue().strip().splitlines()[-1]
-    assert len(last_line) <= 1500
-    assert json.loads(last_line)["metric"] == "albert_base_mlm_tokens_per_sec_per_chip"
+    json.dumps(section)  # the artifact is one JSON line: the section must serialize
 
 
 def test_telemetry_section_survives_missing_averaging():
     section = bench.telemetry_section(None)
     assert "bench_process" in section or "error" in section
     assert "averaging_swarm" not in section
-
-
-def test_compact_line_keeps_tpu_success_fields():
-    result = {
-        "metric": "albert_base_mlm_tokens_per_sec_per_chip",
-        "value": 30000.0,
-        "unit": "tokens/s",
-        "vs_baseline": 1.07,
-        "extra": {
-            "device": "TPU v5 lite",
-            "mfu": 0.374,
-            "batch_size": 256,
-            "remat": True,
-            "seq_len": 512,
-            "attention": "flash",
-            "attention_tokens_per_sec": {"flash": 30000.0, "plain": 21000.0},
-        },
-    }
-    parsed = json.loads(bench.compact_result(result))
-    assert parsed["extra"]["mfu"] == 0.374
-    assert parsed["extra"]["attention"] == "flash"
-    assert parsed["vs_baseline"] == 1.07
 
 
 def test_bench_artifact_embeds_ledger_and_watchdog_attribution():
@@ -186,21 +117,7 @@ def test_bench_artifact_embeds_ledger_and_watchdog_attribution():
     assert section["attribution"]["ledger"]["rounds"] == 12
     assert section["attribution"]["ledger"]["total_s"]["p95"] == 1.4
     assert section["attribution"]["watchdog"]["stalls"] == 0
-
-    result = _bloated_result()
-    result["extra"]["averaging_extra"] = dict(averaging["extra"])
-    # main() strips telemetry/attribution from the copied extra (they land once,
-    # under result["telemetry"]): mirror that here and assert the invariant
-    result["extra"]["averaging_extra"] = {
-        k: v for k, v in result["extra"]["averaging_extra"].items()
-        if k not in ("telemetry", "attribution")
-    }
-    result["telemetry"] = section
-    out, err = io.StringIO(), io.StringIO()
-    bench.emit(result, out=out, err=err)
-    full = json.loads(err.getvalue())
-    assert full["telemetry"]["attribution"]["ledger"]["stragglers"]["peerX"]["rounds_slowest"] == 7
-    assert "attribution" not in full["extra"]["averaging_extra"]
+    assert section["attribution"]["ledger"]["stragglers"]["peerX"]["rounds_slowest"] == 7
 
 
 def test_benchmark_averaging_smoke_uniform8():
@@ -330,24 +247,6 @@ def test_benchmark_swarm_sim_smoke():
     assert result["extra"]["failures"] == []
 
 
-def test_bench_artifact_compact_line_carries_swarm_sim():
-    """The swarm-sim scale numbers ride the compact driver line (and drop
-    early under pressure, before the headline metrics)."""
-    result = _bloated_result()
-    result["extra"]["swarm_sim"] = {
-        "peers": 300, "sim_seconds_per_wall_second": 0.62,
-        "recall_at_beam": 1.0, "deterministic": True, "get_success_rate": 1.0,
-    }
-    parsed = json.loads(bench.compact_result(result))
-    assert parsed["extra"]["swarm_sim"]["peers"] == 300
-    assert parsed["extra"]["swarm_sim"]["deterministic"] is True
-    # under pathological pressure the line still fits and leads with the metric
-    result["extra"]["device"] = "d" * 3000
-    line = bench.compact_result(result)
-    assert len(line) <= 1500
-    assert json.loads(line)["metric"] == "albert_base_mlm_tokens_per_sec_per_chip"
-
-
 def test_bench_artifact_embeds_serving_attribution():
     """ISSUE 9: the llama-serving swarm's per-request attribution summary rides
     the BENCH artifact under telemetry.serving — per-expert p50/p95, phase
@@ -369,12 +268,6 @@ def test_bench_artifact_embeds_serving_attribution():
     section = bench.telemetry_section(None, serving)
     assert section["serving"]["requests"] == 98
     assert section["serving"]["experts"]["lb.0"]["p95_s"] == 0.06
-
-    result = _bloated_result()
-    result["telemetry"] = section
-    out, err = io.StringIO(), io.StringIO()
-    bench.emit(result, out=out, err=err)
-    full = json.loads(err.getvalue())
-    assert full["telemetry"]["serving"]["phases"]["compute_s"]["p95"] == 0.06
+    assert section["serving"]["phases"]["compute_s"]["p95"] == 0.06
     # missing serving stays absent, never a crash
     assert "serving" not in bench.telemetry_section(None, None)

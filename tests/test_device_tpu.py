@@ -1,26 +1,40 @@
-"""On-device (TPU) kernel validation — the manual-run twin of the checks
-`bench.py` embeds in the round artifact whenever the chip answers.
+"""The interpret-mode twin of the chip's kernel check.
 
-On CPU runners this exercises the same code in interpret mode (cheap smoke);
-on a real TPU it validates Mosaic-compiled kernels. Run on hardware with:
-``python -m pytest tests/test_device_tpu.py -q`` after unsetting the CPU pin.
-"""
+`python chip_smoke.py` (phase K) runs `ops.device_check.validate_kernels` with the
+kernels compiled by Mosaic, at the main path's shapes, on the TPU — that is where
+the chip is checked. This suite pins the CPU, so the same function runs here in
+Pallas interpret mode at small shapes: it keeps the checks themselves honest."""
 
-import jax
+import pytest
+
+from hivemind_tpu.ops.device_check import (
+    AttentionShape,
+    KernelCheckError,
+    check_flash_attention,
+    validate_kernels,
+)
+
+_SMALL = (
+    AttentionShape("bidirectional", 1, 256, 2, 64, False),
+    AttentionShape("causal-padded", 1, 64, 2, 128, True),
+)
 
 
-def test_validate_on_device_report():
-    from hivemind_tpu.ops.device_check import validate_on_device
+def test_validate_kernels_interpret_mode():
+    report = validate_kernels(interpret=True, attention_shapes=_SMALL, quant_shape=(64, 4096))
+    assert report["interpret"] is True
+    for shape in _SMALL:
+        assert set(report[f"flash[{shape.name}]"]) == {"fwd", "dq", "dk", "dv"}
+    assert report["blockwise_int8[64x4096]"]["absmax_err"] == 0.0
 
-    report = validate_on_device(seq=256)
-    assert report["backend"] == jax.default_backend()
-    expected = {
-        "flash_fwd_bidir", "flash_fwd_causal", "flash_bwd_bidir", "flash_bwd_causal",
-        "blockwise_int8_roundtrip",
-    }
-    assert expected <= set(report["checks"]) | set(report["errors"]), report
-    assert report["ok"], report
-    assert report["attention_ok"], report
-    for name, err in report["checks"].items():
-        if name.startswith("flash"):
-            assert err < 2e-2, (name, err)
+
+def test_kernel_disagreement_raises(monkeypatch):
+    """A kernel that compiles but is wrong must fail the check, not fill a field."""
+    from hivemind_tpu.ops import pallas_attention
+
+    real = pallas_attention.flash_attention
+    monkeypatch.setattr(
+        pallas_attention, "flash_attention", lambda q, k, v, causal, interpret: real(q, k, v, not causal, interpret)
+    )
+    with pytest.raises(KernelCheckError, match="differs from the float32 reference"):
+        check_flash_attention(_SMALL[1], interpret=True)

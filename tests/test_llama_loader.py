@@ -390,3 +390,22 @@ def test_predicted_block_bytes_match_measured_gqa(tmp_path):
         )
     # int8 must actually shrink the block ~4x
     assert predict_block_param_bytes(config, "int8") < 0.3 * predict_block_param_bytes(config)
+
+
+def test_a_dropped_backend_releases_its_weights(tmp_path):
+    """run_server's HBM plan loads one probe block, measures it and drops it before the
+    real load. Dropping must free the device arrays there and then: a jitted closure
+    that captured the backend would pin the block (a reference cycle through a jitted
+    function is never collected)."""
+    import jax
+
+    _write_checkpoint(tmp_path)
+    live_bytes = lambda: sum(array.nbytes for array in jax.live_arrays())
+    for options in ({}, {"weight_quantization": "int8"}):
+        before = live_bytes()
+        probe, _config = load_llama_blocks(tmp_path, layers=[0], uid_prefix="_probe.", **options)
+        backend = probe["_probe.0"]
+        backend.forward(np.zeros((1, 8, HID), np.float32))
+        assert live_bytes() - before >= backend.param_bytes()
+        del probe, backend
+        assert live_bytes() == before, options

@@ -212,7 +212,8 @@ def _run_two_process_slice_workers(tmp_path, mode: str = "sync"):
         port = str(probe.getsockname()[1])
     script = tmp_path / "slice_opt_worker.py"
     script.write_text(_WORKER)
-    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+    # every process of these several-on-one-host runs is pinned to the CPU
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
         [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
         + [p for p in os.environ.get("PYTHONPATH", "").split(os.pathsep) if p]
     ))

@@ -48,7 +48,8 @@ def test_two_process_slice_trains_and_averages_with_swarm(tmp_path):
     with socket.socket() as probe:
         probe.bind(("127.0.0.1", 0))
         coord = f"127.0.0.1:{probe.getsockname()[1]}"
-    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+    # every process of these several-on-one-host runs is pinned to the CPU
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
         [_REPO] + [p for p in os.environ.get("PYTHONPATH", "").split(os.pathsep) if p]
     ))
     common = [
