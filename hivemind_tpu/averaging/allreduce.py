@@ -94,6 +94,9 @@ class AllReduceRunner:
         fall back to ``compression`` (exact pre-negotiation behavior)
     :param residuals: the averager's error-feedback store (required for links
         with ``error_feedback``; survives the runner — one round borrows it)
+    :param purpose: what the owning averager averages (``grads`` / ``state``): an
+        attribute of the ``allreduce.round`` span, so a round record says which of
+        an epoch's rounds it was
     """
 
     def __init__(
@@ -114,8 +117,9 @@ class AllReduceRunner:
         prefetch: int = 8,
         links: Optional[Dict[int, "WireLink"]] = None,
         residuals=None,
+        purpose: Optional[str] = None,
     ):
-        self.p2p, self.group_id = p2p, group_id
+        self.p2p, self.group_id, self.purpose = p2p, group_id, purpose
         # one part travels as ONE mux message: a part whose wire size exceeded
         # MAX_MESSAGE_SIZE would kill the stream mid-round and silently degrade
         # the average. The clamp uses the same formula on every peer, so senders
@@ -215,6 +219,7 @@ class AllReduceRunner:
             peer=str(self.p2p.peer_id),
             group_size=len(self.ordered_peer_ids),
             rank=self.my_index,
+            **({"purpose": self.purpose} if self.purpose else {}),
         )
         communicate_tasks = []
         if self.my_mode != AveragingMode.AUX:

@@ -97,6 +97,9 @@ class DecentralizedAverager(ServicerBase):
     """
 
     _class_handle_name = "DecentralizedAverager"  # all subclasses share the wire name
+    # what this averager's rounds average ("grads" / "state"; set by the subclass that
+    # knows): rides each `allreduce.round` span into its round record
+    round_purpose: Optional[str] = None
 
     def __init__(
         self,
@@ -613,6 +616,7 @@ class DecentralizedAverager(ServicerBase):
             part_size_bytes=self.part_size_bytes,
             sender_timeout=self.sender_timeout,
             reducer_timeout=self.reducer_timeout,
+            purpose=self.round_purpose,
         )
         async with self._allreduce_registered:
             self._running_allreduces[group_id] = runner  # lint: single-writer — holds _allreduce_registered's lock
@@ -652,6 +656,7 @@ class DecentralizedAverager(ServicerBase):
             reducer_timeout=self.reducer_timeout,
             links=links,
             residuals=self._wire_residuals,
+            purpose=self.round_purpose,
         )
 
     def _snapshot_tensors(self) -> List[np.ndarray]:

@@ -5,8 +5,9 @@ here one asyncio servicer feeding the task pools directly).
 Serving attribution (ISSUE 9): every expert RPC runs inside a ``serving.request``
 span — a child of the ``p2p.handle:`` span, which already joined the remote
 caller's trace via cross-peer propagation, so the request's phase decomposition
-(queue-wait / batch-assembly / compute stamped by the TaskPool, serialize
-stamped here) lands in the CALLER's trace and in the process-wide
+(queue-wait / batch-assembly / compute / staging stamped by the TaskPool — by
+the DecodeSessionManager for decode steps, which bypass the pools — deserialize
+and serialize stamped here) lands in the CALLER's trace and in the process-wide
 :data:`~hivemind_tpu.telemetry.serving.SERVING_LEDGER`.
 
 Serving data path (ISSUE 10, the PR 5 playbook applied to this layer):
@@ -31,7 +32,7 @@ Serving data path (ISSUE 10, the PR 5 playbook applied to this layer):
 from __future__ import annotations
 
 import time
-from typing import AsyncIterator, Dict, List, Optional
+from typing import AsyncIterator, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -155,16 +156,21 @@ class ConnectionHandler(ServicerBase):
         return list(self.forward_pools.values()) + list(self.backward_pools.values())
 
     @staticmethod
-    def _serving_trace(kind: str, uid: str, context: P2PContext, tensors=None) -> _trace:
+    def _serving_trace(kind: str, uid: str, context: P2PContext, tensors=None,
+                       deserialize_s: Optional[float] = None) -> _trace:
         """The per-request serving span (ServingLedger assembles one record per
         finished span; see telemetry/serving.py). ``client`` is the remote
-        caller — per-client attribution rides every record."""
+        caller — per-client attribution rides every record. ``deserialize_s``:
+        what the unary RPCs spent turning wire tensors into numpy before the span
+        could open (its attributes come from those tensors)."""
         attributes = {
             "kind": kind,
             "expert": uid,
             "peer": str(context.local_id),
             "client": str(context.remote_id),
         }
+        if deserialize_s is not None:
+            attributes["deserialize_s"] = round(deserialize_s, 6)
         if tensors:
             first = tensors[0]
             if getattr(first, "ndim", 0):
@@ -248,16 +254,18 @@ class ConnectionHandler(ServicerBase):
 
     # ------------------------------------------------------------------ codecs
 
-    async def _deserialize_request(self, tensors) -> List[np.ndarray]:
+    async def _deserialize_request(self, tensors) -> Tuple[List[np.ndarray], float]:
         """Parse request tensors; big payloads decode off the event loop (the
         watchdog showed inline deserialization stalling dispatch under load),
-        small ones inline (the executor hop would dominate them)."""
-        if not tensors:
-            return []
+        small ones inline (the executor hop would dominate them). Returns the
+        arrays and the seconds it took (the serving span's ``deserialize_s``)."""
+        started = time.perf_counter()
         tensor_list = list(tensors)
         if sum(len(t.buffer) for t in tensor_list) < _OFF_LOOP_CODEC_BYTES:
-            return [deserialize_tensor(t) for t in tensor_list]
-        return await run_in_executor(lambda: [deserialize_tensor(t) for t in tensor_list])
+            arrays = [deserialize_tensor(t) for t in tensor_list]
+        else:
+            arrays = await run_in_executor(lambda: [deserialize_tensor(t) for t in tensor_list])
+        return arrays, time.perf_counter() - started
 
     def _serialize_outputs(self, outputs: List[np.ndarray]) -> List[runtime_pb2.Tensor]:
         # allow_inplace: each output row range is private to its task (views of
@@ -281,8 +289,8 @@ class ConnectionHandler(ServicerBase):
 
     async def rpc_forward(self, request: runtime_pb2.ExpertRequest, context: P2PContext) -> runtime_pb2.ExpertResponse:
         _SERVER_BYTES_RECEIVED.inc(request.ByteSize())
-        inputs = await self._deserialize_request(request.tensors)
-        with self._serving_trace("forward", request.uid, context, inputs) as span:
+        inputs, deserialize_s = await self._deserialize_request(request.tensors)
+        with self._serving_trace("forward", request.uid, context, inputs, deserialize_s) as span:
             self._admit(context, inputs, "forward")
             uids = self._span_uids(request.uid, request.metadata)
             if span is not None and len(uids) > 1:
@@ -292,8 +300,8 @@ class ConnectionHandler(ServicerBase):
 
     async def rpc_backward(self, request: runtime_pb2.ExpertRequest, context: P2PContext) -> runtime_pb2.ExpertResponse:
         _SERVER_BYTES_RECEIVED.inc(request.ByteSize())
-        inputs = await self._deserialize_request(request.tensors)
-        with self._serving_trace("backward", request.uid, context, inputs) as span:
+        inputs, deserialize_s = await self._deserialize_request(request.tensors)
+        with self._serving_trace("backward", request.uid, context, inputs, deserialize_s) as span:
             self._admit(context, inputs, "backward")
             uids = self._span_uids(request.uid, request.metadata)
             if span is not None and len(uids) > 1:
@@ -313,11 +321,10 @@ class ConnectionHandler(ServicerBase):
         uids = self._span_uids(uid, metadata)
         reset = bool(meta.get("reset", False))
         for span_uid in uids:
-            step_start = time.perf_counter()
+            # decode bypasses the pools: decode_async stamps the step's queue wait
+            # (the continuous-batching flush window + the batch before it) and
+            # compute onto the serving span itself
             x = await self.decode_sessions.decode_async(span_uid, str(session_id), x, reset)
-            # decode bypasses the pools: the whole session step (incl. the
-            # continuous-batching flush window) is the compute phase
-            accrue_span_phase("compute_s", time.perf_counter() - step_start)
         return x
 
     async def rpc_decode(self, request: runtime_pb2.ExpertRequest, context: P2PContext) -> runtime_pb2.ExpertResponse:
@@ -325,8 +332,8 @@ class ConnectionHandler(ServicerBase):
         ``{"session_id": str, "reset": bool}``; sessions bypass the batching
         pools — each holds its own per-client device cache."""
         _SERVER_BYTES_RECEIVED.inc(request.ByteSize())
-        tensors = await self._deserialize_request(request.tensors)
-        with self._serving_trace("decode", request.uid, context, tensors):
+        tensors, deserialize_s = await self._deserialize_request(request.tensors)
+        with self._serving_trace("decode", request.uid, context, tensors, deserialize_s):
             self._admit(context, tensors, "decode")
             output = await self._run_decode(request.uid, request.metadata, tensors)
             return await self._respond([output])
@@ -371,9 +378,13 @@ class ConnectionHandler(ServicerBase):
 
     # NOTE on the stream RPCs below: the serving span must not wrap a `yield`
     # (an async generator's body runs in its consumer's context), so it closes
-    # after compute and the response chunks then serialize LAZILY, one tensor
-    # at a time — a multi-hundred-MB streamed response must never be
-    # materialized whole. Stream kinds therefore carry no `serialize_s` phase.
+    # before the first chunk leaves, and the response tensors serialize LAZILY,
+    # one at a time — a multi-hundred-MB streamed response must never be
+    # materialized whole. The FIRST tensor's turn is at once, so it is serialized
+    # while the span is still open (`_serialize_head`): stream kinds carry the
+    # `serialize_s` of that tensor (the whole response of a single-output expert),
+    # and `deserialize_s` as the time `_collect_stream_with_metadata` spent past
+    # waiting for the request's chunks.
 
     async def rpc_decode_stream(
         self, requests: AsyncIterator[runtime_pb2.ExpertRequest], context: P2PContext
@@ -387,7 +398,8 @@ class ConnectionHandler(ServicerBase):
                     span.set("batch", int(tensors[0].shape[0]))
             self._admit(context, tensors, "decode")
             output = await self._run_decode(uid, metadata, tensors)
-        async for message in self._stream_response([output]):
+            head = await self._serialize_head([output])
+        async for message in self._stream_response(head, []):
             yield message
 
     async def rpc_forward_stream(
@@ -401,7 +413,8 @@ class ConnectionHandler(ServicerBase):
                     span.set("batch", int(tensors[0].shape[0]))
             self._admit(context, tensors, "forward")
             outputs = await self._run_forward_span(self._span_uids(uid, metadata), tensors)
-        async for message in self._stream_response(outputs):
+            head = await self._serialize_head(outputs)
+        async for message in self._stream_response(head, outputs[1:]):
             yield message
 
     async def rpc_backward_stream(
@@ -415,44 +428,68 @@ class ConnectionHandler(ServicerBase):
                     span.set("batch", int(tensors[0].shape[0]))
             self._admit(context, tensors, "backward")
             grads = await self._run_backward_span(self._span_uids(uid, metadata), tensors)
-        async for message in self._stream_response(grads):
+            head = await self._serialize_head(grads)
+        async for message in self._stream_response(head, grads[1:]):
             yield message
 
     async def _collect_stream_with_metadata(self, requests: AsyncIterator[runtime_pb2.ExpertRequest]):
         """Collect a streamed request: uid + first message's metadata + tensors.
         Chunk reassembly/deserialization runs off-loop (one tensor at a time,
-        as the chunks arrive)."""
+        as the chunks arrive); what it took past waiting for the chunks accrues
+        onto the serving span as ``deserialize_s``."""
         uid = None
         metadata = b""
+        started = time.perf_counter()
+        waited = 0.0  # for the client's next chunk
 
         async def parts():
-            nonlocal uid, metadata
+            nonlocal uid, metadata, waited
+            asked = time.perf_counter()
             async for request in requests:
+                waited += time.perf_counter() - asked
                 _SERVER_BYTES_RECEIVED.inc(request.ByteSize())
                 if uid is None and request.uid:
                     uid = request.uid
                 if not metadata and request.metadata:
                     metadata = request.metadata
                 yield list(request.tensors)
+                asked = time.perf_counter()
+            waited += time.perf_counter() - asked
 
         tensors = await deserialize_tensor_stream(parts(), off_loop=True)
+        accrue_span_phase("deserialize_s", max(time.perf_counter() - started - waited, 0.0))
         if uid is None:
             # wire input from a remote peer: a proper error the client can read
             # (an assert would vanish under -O and crash as a bare AssertionError)
             raise ValueError("streamed expert request carried no expert uid")
         return uid, metadata, tensors
 
-    async def _stream_response(self, outputs: List[np.ndarray]):
-        """Lazy streamed response: each tensor serializes off-loop (with the
-        server's wire dtype) only when its turn comes, and its chunks are
-        zero-copy memoryview slices framed scatter-gather."""
-        for out in outputs:
-            if int(getattr(out, "nbytes", 0)) < _OFF_LOOP_CODEC_BYTES:
-                serialized = serialize_tensor(out, self.activation_codec, None, True)
-            else:
-                serialized = await run_in_executor(
-                    serialize_tensor, out, self.activation_codec, None, True
-                )
-            for chunk in split_response_for_wire(serialized, _STREAM_CHUNK):
+    async def _serialize_streamed(self, out: np.ndarray) -> runtime_pb2.Tensor:
+        """One tensor of a streamed response in the server's wire dtype (off-loop
+        past the inline threshold)."""
+        if int(getattr(out, "nbytes", 0)) < _OFF_LOOP_CODEC_BYTES:
+            return serialize_tensor(out, self.activation_codec, None, True)
+        return await run_in_executor(serialize_tensor, out, self.activation_codec, None, True)
+
+    async def _serialize_head(self, outputs: List[np.ndarray]) -> Optional[runtime_pb2.Tensor]:
+        """The first tensor of a streamed response, serialized inside the serving
+        span (see the NOTE above): its time is the span's ``serialize_s``."""
+        if not outputs:
+            return None
+        start = time.perf_counter()
+        head = await self._serialize_streamed(outputs[0])
+        accrue_span_phase("serialize_s", time.perf_counter() - start)
+        return head
+
+    async def _stream_response(self, head: Optional[runtime_pb2.Tensor], rest: List[np.ndarray]):
+        """Lazy streamed response: after the already-serialized ``head``, each
+        tensor serializes only when its turn comes, and its chunks are zero-copy
+        memoryview slices framed scatter-gather."""
+        if head is not None:
+            for chunk in split_response_for_wire(head, _STREAM_CHUNK):
+                _SERVER_BYTES_SENT.inc(chunk.nbytes)
+                yield chunk
+        for out in rest:
+            for chunk in split_response_for_wire(await self._serialize_streamed(out), _STREAM_CHUNK):
                 _SERVER_BYTES_SENT.inc(chunk.nbytes)
                 yield chunk

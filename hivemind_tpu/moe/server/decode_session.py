@@ -30,6 +30,7 @@ and the caller restarts with ``reset=True``)."""
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import os
 import threading
 import time
@@ -41,6 +42,8 @@ import numpy as np
 
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 from hivemind_tpu.telemetry.device import record_transfer
+from hivemind_tpu.telemetry.serving import accrue_span_phase
+from hivemind_tpu.telemetry.tracing import trace_sync as _trace_sync
 from hivemind_tpu.utils.logging import get_logger
 from hivemind_tpu.utils.asyncio_utils import spawn
 from hivemind_tpu.utils.profiling import tracked_jit
@@ -72,6 +75,34 @@ _STEPS = _TELEMETRY.counter(
     "batched = merged into a vmapped continuous batch)",
     ("path",),
 )
+# where a batched step's host time goes (ISSUE 24). Plain counters beside the
+# `decode.*` spans: a registry snapshot carries a histogram only as count and sum
+_PHASE_SECONDS = _TELEMETRY.counter(
+    "hivemind_moe_decode_phase_seconds_total",
+    "host seconds of vmapped decode batches, by phase (assemble = stacking rows, caches "
+    "and indices; step = the jitted call until its output is on the host; scatter = "
+    "handing each session its rows of the new caches)",
+    ("phase",),
+)
+_CALLS = _TELEMETRY.counter(
+    "hivemind_moe_decode_calls_total",
+    "device calls of the decode path (batched = one vmapped step over several sessions; "
+    "direct = one per-session step or prefill)",
+    ("path",),
+)
+_CALLS_BATCHED, _CALLS_DIRECT = _CALLS.labels("batched"), _CALLS.labels("direct")
+
+
+@contextlib.contextmanager
+def _batch_phase(phase: str):
+    """One phase of a vmapped batch: the `decode.<phase>` span (on the device
+    trace's timeline too) and its seconds on the phase counter."""
+    started = time.perf_counter()
+    try:
+        with _trace_sync("decode." + phase):
+            yield
+    finally:
+        _PHASE_SECONDS.inc(time.perf_counter() - started, phase=phase)
 
 
 def _next_pow2(n: int) -> int:
@@ -82,12 +113,15 @@ def _next_pow2(n: int) -> int:
 
 
 class _Session:
-    __slots__ = ("cache_k", "cache_v", "index", "last_used", "lock")
+    __slots__ = ("cache_k", "cache_v", "index", "last_used", "lock", "batch_started")
 
     def __init__(self, cache_k, cache_v):
         self.cache_k, self.cache_v = cache_k, cache_v
         self.index = 0
         self.last_used = time.monotonic()
+        # perf_counter at which the batch carrying this session's pending step
+        # began to run: the end of that step's queue wait (decode_async)
+        self.batch_started = 0.0
         self.lock = threading.Lock()
 
 
@@ -189,12 +223,14 @@ class DecodeSessionManager:
         continuation gets the unknown-session KeyError (it re-prefills) instead
         of a read of donated memory."""
         step = self._step_fn(uid, x.shape[0], chunk_len)
+        _CALLS_DIRECT.inc()
         try:
-            y, session.cache_k, session.cache_v = step(
-                backend.snapshot_params(), jnp.asarray(x), session.cache_k,
-                session.cache_v, jnp.int32(session.index),
-            )
-            return np.asarray(y)
+            with _trace_sync("decode.direct", uid=uid, chunk_len=chunk_len):
+                y, session.cache_k, session.cache_v = step(
+                    backend.snapshot_params(), jnp.asarray(x), session.cache_k,
+                    session.cache_v, jnp.int32(session.index),
+                )
+                return np.asarray(y)
         except Exception:
             with self._lock:
                 for key in [k for k, s in self._sessions.items() if s is session]:
@@ -283,7 +319,21 @@ class DecodeSessionManager:
     async def decode_async(self, uid: str, session_id: str, x: np.ndarray, reset: bool):
         """Asyncio entrypoint: batchable steps (continuation, chunk 1, session
         batch 1) are merged with other clients' concurrent steps into one vmapped
-        device call; everything else takes the direct per-session path."""
+        device call; everything else takes the direct per-session path.
+
+        Stamps the step's phases onto the caller's ``serving.request`` span, as
+        ``TaskPool.submit_task`` does for the pools: ``queue_wait_s`` from the
+        enqueue until the batch that carries the step starts to run (flush window
+        + the batch before it; a direct step has none), ``compute_s`` the rest."""
+        started = time.perf_counter()
+        out, queue_wait = await self._submit_step(uid, session_id, x, reset)
+        if queue_wait:
+            accrue_span_phase("queue_wait_s", queue_wait)
+        accrue_span_phase("compute_s", time.perf_counter() - started - queue_wait)
+        return out
+
+    async def _submit_step(self, uid: str, session_id: str, x: np.ndarray, reset: bool):
+        """`decode_async` without the attribution: (output, seconds queued)."""
         loop = asyncio.get_running_loop()
         x = np.asarray(x, np.float32)
         batchable = (
@@ -291,7 +341,7 @@ class DecodeSessionManager:
             and x.ndim == 3 and x.shape[0] == 1 and x.shape[1] == 1
         )
         if not batchable:
-            return await loop.run_in_executor(None, self.decode, uid, session_id, x, reset)
+            return await loop.run_in_executor(None, self.decode, uid, session_id, x, reset), 0.0
         with self._lock:
             concurrent = self._concurrent_sessions(uid)
         if not concurrent:
@@ -299,8 +349,9 @@ class DecodeSessionManager:
             # machinery has nothing to merge and costs ~ms per token — take the
             # direct per-session path (same jitted step; same-session ordering
             # is still serialized by the session lock). ISSUE 10.
-            return await loop.run_in_executor(None, self.decode, uid, session_id, x, reset)
+            return await loop.run_in_executor(None, self.decode, uid, session_id, x, reset), 0.0
 
+        enqueued = time.perf_counter()
         future = loop.create_future()
         with self._lock:
             # lookup + enqueue under ONE lock hold: releasing in between would let
@@ -317,7 +368,8 @@ class DecodeSessionManager:
             self._pending.setdefault(uid, []).append((future, session, x))
             if uid not in self._drainers or self._drainers[uid].done():
                 self._drainers[uid] = spawn(self._drain(uid), name="decode_session.drain")
-        return await future
+        out = await future
+        return out, max(session.batch_started - enqueued, 0.0)
 
     # NOTE on merge_recency_s (set in __init__; HIVEMIND_TPU_MERGE_RECENCY_S):
     # another session counts as a merge candidate only if it stepped within
@@ -453,6 +505,13 @@ class DecodeSessionManager:
     def _decode_batch(self, uid: str, entries: List) -> List:
         """Run one vmapped step over `entries` [(future, session, x)]; returns one
         result (ndarray or Exception) per entry, in order."""
+        started = time.perf_counter()
+        for _future, session, _x in entries:
+            session.batch_started = started  # ends these steps' queue wait (decode_async)
+        with _trace_sync("decode.batch", uid=uid) as span:
+            return self._decode_batch_traced(uid, entries, span)
+
+    def _decode_batch_traced(self, uid: str, entries: List, span) -> List:
         backend = self.backends[uid]
         # per-session locks in a fixed order so the direct path cannot deadlock us
         ordered = sorted(range(len(entries)), key=lambda i: id(entries[i][1]))
@@ -470,6 +529,8 @@ class DecodeSessionManager:
                     results[i] = ValueError("batched decode requires session batch 1")
                 else:
                     live.append(i)
+            if span is not None:
+                span.set("rows", len(live))
             if not live:
                 return results
             if len(live) == 1:
@@ -491,38 +552,43 @@ class DecodeSessionManager:
                 record_transfer(results[i].nbytes, "device_to_host")
                 return results
             stack = _next_pow2(len(live))
-            dummy_k, dummy_v = self._dummy_rows(uid)
-            xs, cks, cvs, idxs = [], [], [], []
-            for i in live:
-                _future, session, x = entries[i]
-                xs.append(jnp.asarray(x))
-                cks.append(session.cache_k)
-                cvs.append(session.cache_v)
-                idxs.append(session.index)
-            for _ in range(stack - len(live)):
-                xs.append(jnp.zeros_like(xs[0]))
-                cks.append(dummy_k)
-                cvs.append(dummy_v)
-                idxs.append(1)  # a valid mid-cache position; output is discarded
-            step = self._batched_fn(uid, stack)
-            # xs rows originate host-side (one per live client step); caches are
-            # already resident, so only the stacked activations count as h2d
-            record_transfer(sum(int(x.nbytes) for x in xs), "host_to_device")
-            y, new_k, new_v = step(
-                backend.snapshot_params(), jnp.stack(xs), jnp.stack(cks), jnp.stack(cvs),
-                jnp.asarray(idxs, jnp.int32),
-            )
-            y = np.asarray(y)
+            if span is not None:
+                span.set("bucket", stack)
+            _CALLS_BATCHED.inc()
+            with _batch_phase("assemble"):
+                dummy_k, dummy_v = self._dummy_rows(uid)
+                xs, cks, cvs, idxs = [], [], [], []
+                for i in live:
+                    _future, session, x = entries[i]
+                    xs.append(jnp.asarray(x))
+                    cks.append(session.cache_k)
+                    cvs.append(session.cache_v)
+                    idxs.append(session.index)
+                for _ in range(stack - len(live)):
+                    xs.append(jnp.zeros_like(xs[0]))
+                    cks.append(dummy_k)
+                    cvs.append(dummy_v)
+                    idxs.append(1)  # a valid mid-cache position; output is discarded
+                step = self._batched_fn(uid, stack)
+                # xs rows originate host-side (one per live client step); caches are
+                # already resident, so only the stacked activations count as h2d
+                record_transfer(sum(int(x.nbytes) for x in xs), "host_to_device")
+                stacked = (jnp.stack(xs), jnp.stack(cks), jnp.stack(cvs), jnp.asarray(idxs, jnp.int32))
+            with _batch_phase("step"):
+                y, new_k, new_v = step(backend.snapshot_params(), *stacked)
+                del stacked  # donated to the step
+                y = np.asarray(y)
             record_transfer(y.nbytes, "device_to_host")
             _STEPS.inc(len(live), path="batched")
-            now = time.monotonic()
-            for row, i in enumerate(live):
-                _future, session, _x = entries[i]
-                session.cache_k = new_k[row]
-                session.cache_v = new_v[row]
-                session.index += 1
-                session.last_used = now
-                results[i] = y[row]
+            with _batch_phase("scatter"):
+                now = time.monotonic()
+                for row, i in enumerate(live):
+                    _future, session, _x = entries[i]
+                    session.cache_k = new_k[row]
+                    session.cache_v = new_v[row]
+                    session.index += 1
+                    session.last_used = now
+                    results[i] = y[row]
             # (the dummy rows survive: donation frees the STACKED buffer, not the
             # per-session/dummy constituents that were copied into it)
             return results

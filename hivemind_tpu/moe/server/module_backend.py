@@ -8,6 +8,7 @@ the forward under jax.vjp and applies the optimizer update in the same jitted ca
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -18,11 +19,24 @@ import numpy as np
 
 from hivemind_tpu.compression import CompressionType
 from hivemind_tpu.telemetry.device import record_transfer
+from hivemind_tpu.telemetry.serving import accrue_span_phase
+from hivemind_tpu.telemetry.tracing import trace_sync as _trace_sync
 from hivemind_tpu.utils.logging import get_logger
 from hivemind_tpu.utils.profiling import tracked_jit
 from hivemind_tpu.utils.tensor_descr import BatchTensorDescriptor
 
 logger = get_logger(__name__)
+
+
+@contextlib.contextmanager
+def _staging(name: str):
+    """A host<->device staging span of one batch (`backend.stage_in`, `backend.fetch`);
+    its seconds accrue as ``stage_s`` onto the span around it — the TaskPool's
+    ``pool.batch``, which hands them to the batch's requests."""
+    with _trace_sync(name) as span:
+        yield
+    if span is not None:  # accrued after the span closed: onto its parent
+        accrue_span_phase("stage_s", span.duration)
 
 
 def bucket_batch_size(n: int, max_batch_size: int) -> int:
@@ -174,11 +188,14 @@ class ModuleBackend:
     def forward(self, *inputs: np.ndarray) -> List[np.ndarray]:
         """Inference on a concatenated batch (no parameter updates)."""
         assert len(inputs) == self.num_inputs, (len(inputs), self.num_inputs)
-        padded = [self._pad(np.asarray(x, np.float32)) for x in inputs]
+        with _staging("backend.stage_in"):  # widen and pad on the host, hand over for upload
+            padded = [self._pad(np.asarray(x, np.float32)) for x in inputs]
         n = padded[0][1]
         record_transfer(sum(int(p.nbytes) for p, _ in padded), "host_to_device")
-        outs = self._jit_forward(self.snapshot_params(), *(p for p, _ in padded))
-        results = [np.asarray(out)[:n] for out in outs]
+        with _trace_sync("backend.device"):  # the jitted call until its result is ready
+            outs = jax.block_until_ready(self._jit_forward(self.snapshot_params(), *(p for p, _ in padded)))
+        with _staging("backend.fetch"):
+            results = [np.asarray(out)[:n] for out in outs]
         record_transfer(sum(r.nbytes for r in results), "device_to_host")
         return results
 
@@ -194,23 +211,27 @@ class ModuleBackend:
         assert len(tensors) == self.num_inputs + self.num_outputs, (
             len(tensors), self.num_inputs, self.num_outputs,
         )
-        padded_x = [self._pad(np.asarray(x, np.float32)) for x in tensors[: self.num_inputs]]
-        padded_g = [self._pad(np.asarray(g, np.float32)) for g in tensors[self.num_inputs :]]
+        with _staging("backend.stage_in"):
+            padded_x = [self._pad(np.asarray(x, np.float32)) for x in tensors[: self.num_inputs]]
+            padded_g = [self._pad(np.asarray(g, np.float32)) for g in tensors[self.num_inputs :]]
         n = padded_x[0][1]
         record_transfer(
             sum(int(p.nbytes) for p, _ in padded_x) + sum(int(p.nbytes) for p, _ in padded_g),
             "host_to_device",
         )
-        with self._state_lock:
-            grad_xs, new_params, new_opt_state = self._jit_backward(
-                self.params,
-                self.opt_state,
-                tuple(p for p, _ in padded_x),
-                tuple(p for p, _ in padded_g),
-            )
-            self.params, self.opt_state = new_params, new_opt_state
-            self.update_count += 1
-        grads_out = [np.asarray(g)[:n] for g in grad_xs]
+        with _trace_sync("backend.device"):
+            with self._state_lock:
+                grad_xs, new_params, new_opt_state = self._jit_backward(
+                    self.params,
+                    self.opt_state,
+                    tuple(p for p, _ in padded_x),
+                    tuple(p for p, _ in padded_g),
+                )
+                self.params, self.opt_state = new_params, new_opt_state
+                self.update_count += 1
+            jax.block_until_ready(grad_xs)  # what the fetch below would wait for anyway
+        with _staging("backend.fetch"):
+            grads_out = [np.asarray(g)[:n] for g in grad_xs]
         record_transfer(sum(g.nbytes for g in grads_out), "device_to_host")
         return grads_out
 

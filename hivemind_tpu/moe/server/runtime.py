@@ -41,6 +41,14 @@ _UTILIZATION = _TELEMETRY.gauge(
 )
 
 
+_WAIT_SECONDS = _TELEMETRY.counter(
+    "hivemind_moe_runtime_wait_seconds_total",
+    "seconds the drain loop had no batch to give the device: from 'no pool holds a "
+    "task' to the next batch popped (on a profiler trace the same time is the idle "
+    "time outside every pool.batch span)",
+)
+
+
 class Runtime:
     def __init__(self, pools: Sequence[TaskPool], stats_report_interval: Optional[float] = 60.0):
         self.pools = list(pools)
@@ -98,7 +106,10 @@ class Runtime:
         )
 
     async def _run(self) -> None:
+        starved_since: Optional[float] = None  # the loop awaits here: a counter, not an annotation
         while True:
+            if starved_since is None and not any(pool.queue_size for pool in self.pools):
+                starved_since = time.perf_counter()
             if not self.pools:
                 # a replica-slot server starts empty and gains pools at runtime
                 self._pools_changed.clear()  # lint: single-writer — loop clears its own wake event
@@ -125,6 +136,9 @@ class Runtime:
             if not batch:
                 continue
             start = time.perf_counter()
+            if starved_since is not None:
+                _WAIT_SECONDS.inc(start - starved_since)
+                starved_since = None
             try:
                 await run_in_executor(pool.process_batch, batch)
             except Exception as e:

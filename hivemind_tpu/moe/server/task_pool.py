@@ -26,6 +26,7 @@ import numpy as np
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 from hivemind_tpu.telemetry.serving import accrue_span_phase
 from hivemind_tpu.telemetry.tracing import current_span
+from hivemind_tpu.telemetry.tracing import trace_sync as _trace_sync
 from hivemind_tpu.utils.logging import get_logger
 from hivemind_tpu.utils.timed_storage import get_dht_time
 
@@ -92,6 +93,7 @@ class _Task:
     popped_pc: Optional[float] = None
     assembly_s: Optional[float] = None
     compute_s: Optional[float] = None
+    stage_s: Optional[float] = None  # of compute_s: the backend's host<->device staging
     occupancy: Optional[float] = None
 
     @property
@@ -177,6 +179,8 @@ class TaskPool:
             accrue_span_phase("assembly_s", task.assembly_s)
         if task.compute_s is not None:
             accrue_span_phase("compute_s", task.compute_s)
+        if task.stage_s is not None:
+            accrue_span_phase("stage_s", task.stage_s)
         if task.occupancy is not None:
             span = current_span()
             if span is not None:
@@ -256,11 +260,15 @@ class TaskPool:
         """Run process_func on the assembled batch; split outputs per task as
         zero-copy views. Called from the Runtime's executor thread via
         call_soon_threadsafe plumbing."""
+        total = sum(t.batch_size for t in tasks)
+        with _trace_sync("pool.batch", pool=self.name, rows=total, tasks=len(tasks)) as span:
+            self._process_batch(tasks, total, span)
+
+    def _process_batch(self, tasks: List[_Task], total: int, span) -> None:
         from hivemind_tpu.moe.server.module_backend import bucket_batch_size
 
         num_args = len(tasks[0].args)
         assembly_start = time.perf_counter()
-        total = sum(t.batch_size for t in tasks)
         if len(tasks) == 1:
             # single-task batch (the per-token decode/forward common case):
             # pass the task's own arrays straight through — zero copies here
@@ -303,6 +311,8 @@ class TaskPool:
                 )
         assembly_s = compute_start - assembly_start
         compute_s = compute_end - compute_start
+        # the backend's staging spans accrue their seconds onto the batch's span
+        stage_s = (span.attributes or {}).get("stage_s") if span is not None else None
         occupancy = round(total / max(self.max_batch_size, 1), 4)
         self._occupancy_histogram.observe(occupancy)
         offset = 0
@@ -310,6 +320,7 @@ class TaskPool:
             size = task.batch_size
             task.assembly_s = assembly_s
             task.compute_s = compute_s
+            task.stage_s = stage_s
             task.occupancy = occupancy
             task_out = [np.asarray(out[offset : offset + size]) for out in outputs]
             offset += size

@@ -18,6 +18,7 @@ from hivemind_tpu.averaging.averager import DecentralizedAverager
 from hivemind_tpu.averaging.control import StepControl
 from hivemind_tpu.compression.base import as_numpy
 from hivemind_tpu.dht import DHT
+from hivemind_tpu.telemetry.tracing import trace_sync as _sync_span
 from hivemind_tpu.utils.logging import get_logger
 from hivemind_tpu.utils.timed_storage import DHTExpiration, get_dht_time
 
@@ -32,6 +33,8 @@ class GradientAverager(DecentralizedAverager):
     :param local_updates: if True, peers apply updates locally and this averager is
         used only for state averaging (reference use_local_updates)
     """
+
+    round_purpose = "grads"
 
     def __init__(
         self,
@@ -74,8 +77,11 @@ class GradientAverager(DecentralizedAverager):
         assert len(grads) == len(self._grad_accumulators), (
             f"got {len(grads)} gradient tensors, expected {len(self._grad_accumulators)}"
         )
-        for accumulator, grad in zip(self._grad_accumulators, grads):
-            accumulator += np.asarray(as_numpy(grad), dtype=np.float32) * batch_size
+        # the device->host pull of every microbatch's gradients (and the wait for
+        # the step that computes them) happens here
+        with _sync_span("optimizer.accumulate", peer=str(self.peer_id)):
+            for accumulator, grad in zip(self._grad_accumulators, grads):
+                accumulator += np.asarray(as_numpy(grad), dtype=np.float32) * batch_size
         self.local_samples_accumulated += batch_size
         self.local_times_accumulated += 1
 

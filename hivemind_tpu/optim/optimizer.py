@@ -36,7 +36,9 @@ from hivemind_tpu.optim.state_averager import TrainingStateAverager
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 from hivemind_tpu.telemetry.device import STEP_TIMELINE as _STEP_TIMELINE
 from hivemind_tpu.telemetry.ledger import LEDGER as _LEDGER
+from hivemind_tpu.telemetry.ledger import EpochPhases as _EpochPhases
 from hivemind_tpu.telemetry.tracing import trace as _tracing_span
+from hivemind_tpu.telemetry.tracing import trace_sync as _sync_span
 from hivemind_tpu.utils.logging import get_logger
 from hivemind_tpu.utils.timed_storage import get_dht_time
 
@@ -274,7 +276,7 @@ class Optimizer(ChronicFailureTracking):
         when the swarm is ready. Returns the (possibly updated) parameter pytree."""
         # layer-5 span: the whole-step host timeline — a slow step's trace shows
         # WHICH child (catch-up, averaging round, state load) ate the time
-        with _tracing_span("optimizer.step", peer=str(self.dht.peer_id), epoch=self.local_epoch):
+        with _sync_span("optimizer.step", peer=str(self.dht.peer_id), epoch=self.local_epoch):
             if self.auxiliary:
                 self._auxiliary_step()
                 return None
@@ -312,7 +314,7 @@ class Optimizer(ChronicFailureTracking):
             # the compute lane of the step timeline (ISSUE 19): a delayed
             # state-averaging round overlapping these spans is the overlap
             # efficiency being measured
-            with _tracing_span("optimizer.update", peer=str(self.dht.peer_id)):
+            with _sync_span("optimizer.update", peer=str(self.dht.peer_id)):
                 self.state_averager.apply_optimizer_step(grads)
         new_samples = self.tracker.local_progress.samples_accumulated + batch_size
         self.tracker.report_local_progress(self.local_epoch, new_samples)
@@ -385,49 +387,55 @@ class Optimizer(ChronicFailureTracking):
         next_epoch = max(self.local_epoch + 1, self.tracker.global_epoch)
 
         averaged_ok: Optional[bool] = None  # None = no round attempted (solo swarm)
+        phases = _EpochPhases(peer=str(self.dht.peer_id), epoch=next_epoch)
         # step timeline (ISSUE 19): grads are ready HERE; everything between
         # this mark and the update landing is communication to hide
         _STEP_TIMELINE.note_grad_ready(str(self.dht.peer_id))
-        if self.tracker.global_progress.num_peers > 1:
-            averaged_ok = False
-            control = None if self._scheduled_control_invalid() else self.scheduled_grads
-            self.scheduled_grads = None
-            try:
-                # keep the accumulators until the update is applied: if averaging
-                # fails we must fall back to the LOCAL gradients, not zeros
-                self.grad_averager.step(
-                    control=control,
-                    weight=self.grad_averager.local_samples_accumulated,
-                    timeout=self.averaging_timeout,
-                    reset_accumulators=False,
-                    scheduled_time=get_dht_time() + self._matchmaking_delay() if control is None else None,
-                )
-                averaged_ok = True
-            except Exception as e:
-                logger.warning(f"gradient averaging failed ({e!r}); applying local gradients")
-        if not averaged_ok:
-            # fall back to local gradients (reference optimizer.py:632-639)
-            self.grad_averager.load_accumulators_into_averager_()
+        with phases.phase("grad_round"):
+            if self.tracker.global_progress.num_peers > 1:
+                averaged_ok = False
+                control = None if self._scheduled_control_invalid() else self.scheduled_grads
+                self.scheduled_grads = None
+                try:
+                    # keep the accumulators until the update is applied: if averaging
+                    # fails we must fall back to the LOCAL gradients, not zeros
+                    self.grad_averager.step(
+                        control=control,
+                        weight=self.grad_averager.local_samples_accumulated,
+                        timeout=self.averaging_timeout,
+                        reset_accumulators=False,
+                        scheduled_time=get_dht_time() + self._matchmaking_delay() if control is None else None,
+                    )
+                    averaged_ok = True
+                except Exception as e:
+                    logger.warning(f"gradient averaging failed ({e!r}); applying local gradients")
+            if not averaged_ok:
+                # fall back to local gradients (reference optimizer.py:632-639)
+                self.grad_averager.load_accumulators_into_averager_()
 
-        with self.grad_averager.use_averaged_gradients() as averaged_grads:
-            with _tracing_span("optimizer.update", peer=str(self.dht.peer_id), epoch=next_epoch):
-                self.state_averager.apply_optimizer_step(list(averaged_grads))
+        with phases.phase("update"), self.grad_averager.use_averaged_gradients() as averaged_grads:
+            self.state_averager.apply_optimizer_step(list(averaged_grads))
         self.grad_averager.reset_accumulated_grads_()
-        self._finish_epoch_transition(next_epoch, averaged_ok)
+        self._finish_epoch_transition(next_epoch, averaged_ok, phases)
 
     # chronic counter/backoff/log members come from ChronicFailureTracking
 
-    def _finish_epoch_transition(self, next_epoch: int, averaged_ok: Optional[bool]) -> None:
+    def _finish_epoch_transition(
+        self, next_epoch: int, averaged_ok: Optional[bool], phases: _EpochPhases
+    ) -> None:
         """``averaged_ok``: True/False for an attempted swarm round, None when no
-        round was attempted (num_peers <= 1 — a solo peer is healthy, not failing)."""
+        round was attempted (num_peers <= 1 — a solo peer is healthy, not failing).
+        ``phases``: the transition's clock, started by the caller before the
+        gradient round; its seconds go into the epoch record."""
         assert self.state_averager is not None
         self._record_round_outcome(averaged_ok)
         self.state_averager.local_epoch = next_epoch
         if self.average_state_every and next_epoch % self.average_state_every == 0 and self.tracker.global_progress.num_peers > 1:
-            self.state_averager.do_averaging_round(
-                timeout=self.averaging_timeout,
-                scheduled_time=get_dht_time() + self._matchmaking_delay(),
-            )
+            with phases.phase("state_round"):
+                self.state_averager.do_averaging_round(
+                    timeout=self.averaging_timeout,
+                    scheduled_time=get_dht_time() + self._matchmaking_delay(),
+                )
         self.state_averager.state_sharing_priority = next_epoch
         # checkpoint AFTER the state-averaging round so the file holds the
         # swarm-averaged tensors this epoch actually produced
@@ -439,6 +447,7 @@ class Optimizer(ChronicFailureTracking):
             peer=str(self.dht.peer_id),
             averaged_ok=averaged_ok,
             num_peers=self.tracker.global_progress.num_peers,
+            **phases.fields(),
         )
         self.tracker.update_epoch(next_epoch)
         if self.verbose:
@@ -477,23 +486,24 @@ class Optimizer(ChronicFailureTracking):
     def _delayed_epoch_update(self, control, weight: float, next_epoch: int) -> None:
         assert self.grad_averager is not None and self.state_averager is not None
         averaged_ok: Optional[bool] = None  # None = no round attempted (solo swarm)
-        if self.tracker.global_progress.num_peers > 1:
-            averaged_ok = False
-            try:
-                self.grad_averager.step(
-                    control=control,
-                    weight=weight,
-                    timeout=self.averaging_timeout,
-                    load_accumulators=False,
-                    scheduled_time=get_dht_time() + self._matchmaking_delay() if control is None else None,
-                )
-                averaged_ok = True
-            except Exception as e:
-                logger.warning(f"delayed gradient averaging failed ({e!r}); applying local gradients")
-        with self.grad_averager.use_averaged_gradients() as averaged_grads:
-            with _tracing_span("optimizer.update", peer=str(self.dht.peer_id), epoch=next_epoch):
-                self.state_averager.apply_optimizer_step(list(averaged_grads))
-        self._finish_epoch_transition(next_epoch, averaged_ok)
+        phases = _EpochPhases(peer=str(self.dht.peer_id), epoch=next_epoch)
+        with phases.phase("grad_round"):
+            if self.tracker.global_progress.num_peers > 1:
+                averaged_ok = False
+                try:
+                    self.grad_averager.step(
+                        control=control,
+                        weight=weight,
+                        timeout=self.averaging_timeout,
+                        load_accumulators=False,
+                        scheduled_time=get_dht_time() + self._matchmaking_delay() if control is None else None,
+                    )
+                    averaged_ok = True
+                except Exception as e:
+                    logger.warning(f"delayed gradient averaging failed ({e!r}); applying local gradients")
+        with phases.phase("update"), self.grad_averager.use_averaged_gradients() as averaged_grads:
+            self.state_averager.apply_optimizer_step(list(averaged_grads))
+        self._finish_epoch_transition(next_epoch, averaged_ok, phases)
 
     def _finish_pending_update(self, timeout: Optional[float] = None) -> None:
         """Surface exceptions from a completed (or awaited) background transition."""

@@ -87,6 +87,8 @@ logger = get_logger(__name__)
 # layer-4 telemetry (docs/observability.md). The skipped-steps child is bound
 # once: it increments on the broadcast-free hot path.
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
+from hivemind_tpu.telemetry.ledger import LEDGER as _LEDGER
+from hivemind_tpu.telemetry.ledger import EpochPhases as _EpochPhases
 
 _C_SKIPPED_STEPS = _TELEMETRY.counter(
     "hivemind_optim_skipped_broadcast_steps_total",
@@ -113,6 +115,8 @@ class _SliceStateAverager(DecentralizedAverager):
     the slice's current epoch as metadata (the canonical state lives sharded on the
     mesh; mirrors are refreshed at every epoch transition, so downloads are at most
     one epoch stale — a joiner adopts them and catches up through the tracker)."""
+
+    round_purpose = "state"
 
     def __init__(self, *args, epoch_fn, **kwargs):
         self._epoch_fn = epoch_fn
@@ -307,6 +311,7 @@ class SliceOptimizer(ChronicFailureTracking):
                 **extra_opts,
                 **common,
             )
+            self.grad_averager.round_purpose = "grads"  # whichever class the factory built
             state_templates = [
                 np.zeros(leaf.shape, np.float32) for leaf in self._state_leaves()
             ]
@@ -319,6 +324,11 @@ class SliceOptimizer(ChronicFailureTracking):
                 **common,
             )
             self.tracker = ProgressTracker(self.dht, run_id, target_batch_size)
+
+    def _epoch_phases(self, epoch: int) -> _EpochPhases:
+        """The clock of one epoch transition, labelled as the host Optimizer labels its own."""
+        peer = str(self.dht.peer_id) if self.dht is not None else f"proc{self.process_index}"
+        return _EpochPhases(peer=peer, epoch=epoch)
 
     # ------------------------------------------------------------------ device trees
 
@@ -530,7 +540,10 @@ class SliceOptimizer(ChronicFailureTracking):
         inv = jnp.float32(1.0 / max(self._samples, 1))
         normalized = self._jit_normalize(self._accum, inv)
         scratch = self.bridge.gather_to_host(normalized)
-        self._pending = {"scratch": scratch, "num_peers": num_peers}
+        # the round runs beside the next epoch's steps, so this clock's phases add up
+        # to less than its transition_s (launch to adoption)
+        phases = self._epoch_phases(max(self.local_epoch + 1, global_epoch))
+        self._pending = {"scratch": scratch, "num_peers": num_peers, "phases": phases}
         # weight 0 is correct for a peer with nothing accumulated (the grace rule
         # can transition an empty peer): its zero buffers must not dilute the
         # group average — matches the host Optimizer (optimizer.py:379-383)
@@ -551,7 +564,8 @@ class SliceOptimizer(ChronicFailureTracking):
         def run_round() -> None:
             # writing the average back into process 0's scratch is race-free:
             # the adoption step reads it only after joining this thread
-            outcome["ok"] = self._run_swarm_round(scratch, weight, control)
+            with phases.phase("grad_round"):
+                outcome["ok"] = self._run_swarm_round(scratch, weight, control)
 
         self._bg_thread = threading.Thread(
             target=run_round, name="slice-delayed-round", daemon=True
@@ -579,7 +593,8 @@ class SliceOptimizer(ChronicFailureTracking):
         self._bg_thread = None
         self._bg_outcome = None
         self._apply_epoch_tail(
-            scratch, averaged_ok, num_peers, reset_accumulator=False, advance_epoch=False
+            scratch, averaged_ok, num_peers, pending["phases"],
+            reset_accumulator=False, advance_epoch=False,
         )
 
     def _discard_pending(self) -> None:
@@ -722,6 +737,17 @@ class SliceOptimizer(ChronicFailureTracking):
         stage → swarm-average (p0) → broadcast → collective optax update → state round."""
 
         _C_EPOCH_TRANSITIONS.inc(kind="synchronous")
+        phases = self._epoch_phases(max(self.local_epoch + 1, global_epoch))
+        with phases.phase("grad_round"):  # staging, the swarm round, adopting its outcome
+            scratch, averaged_ok = self._collective_grad_round(num_peers)
+        self._apply_epoch_tail(
+            scratch, averaged_ok, num_peers, phases, reset_accumulator=True, global_epoch=global_epoch
+        )
+
+    def _collective_grad_round(self, num_peers: int) -> Tuple[List[np.ndarray], Optional[bool]]:
+        """Phases A-C of the synchronous transition: every process's host copy of the
+        epoch's gradients (swarm-averaged if the round succeeded) and the round's
+        outcome (None = no round attempted)."""
         # phase A (collective): normalize the on-device accumulator and stage it to
         # identical full host copies on EVERY process (per-leaf bounded staging).
         # These doubles as the local-gradient fallback: if the swarm round fails,
@@ -751,16 +777,14 @@ class SliceOptimizer(ChronicFailureTracking):
             if averaged_ok:
                 for i in range(len(scratch)):
                     scratch[i] = _broadcast(np.ascontiguousarray(scratch[i]))
-
-        self._apply_epoch_tail(
-            scratch, averaged_ok, num_peers, reset_accumulator=True, global_epoch=global_epoch
-        )
+        return scratch, averaged_ok
 
     def _apply_epoch_tail(
         self,
         scratch: List[np.ndarray],
         averaged_ok: Optional[bool],
         num_peers: int,
+        phases: _EpochPhases,
         reset_accumulator: bool,
         advance_epoch: bool = True,
         global_epoch: int = 0,
@@ -777,15 +801,16 @@ class SliceOptimizer(ChronicFailureTracking):
         next_epoch = (
             max(self.local_epoch + 1, global_epoch) if advance_epoch else self.local_epoch
         )
-        grads_tree = jax.tree_util.tree_unflatten(
-            self._params_treedef,
-            [
-                self.bridge.scatter_leaf(leaf, value)
-                for leaf, value in zip(self._params_leaves, scratch)
-            ],
-        )
-        self.params, self.opt_state = self._jit_apply(self.params, self.opt_state, grads_tree)
-        self._refresh_param_leaves()
+        with phases.phase("update"):
+            grads_tree = jax.tree_util.tree_unflatten(
+                self._params_treedef,
+                [
+                    self.bridge.scatter_leaf(leaf, value)
+                    for leaf, value in zip(self._params_leaves, scratch)
+                ],
+            )
+            self.params, self.opt_state = self._jit_apply(self.params, self.opt_state, grads_tree)
+            self._refresh_param_leaves()
         if reset_accumulator:
             self._accum = self._jit_zeros_like()(self.params)
             self._samples = 0
@@ -793,12 +818,18 @@ class SliceOptimizer(ChronicFailureTracking):
         # record the grad-round outcome FIRST (reference order, optimizer.py:384-388):
         # the state phase's matchmaking delay must see the recovered counter
         self._record_round_outcome(averaged_ok)
-        self._collective_state_phase(next_epoch, num_peers)
+        with phases.phase("state_round"):  # the mirrors' refresh, and the swarm round when one is due
+            self._collective_state_phase(next_epoch, num_peers)
 
         self.local_epoch = next_epoch
         if self.is_network_process:
             assert self.tracker is not None and self.state_averager is not None
             self.state_averager.state_sharing_priority = next_epoch
+            # the slice is ONE swarm peer: its network process closes the epoch's record
+            _LEDGER.record_epoch(
+                next_epoch, peer=str(self.dht.peer_id), averaged_ok=averaged_ok,
+                num_peers=num_peers, **phases.fields(),
+            )
             if advance_epoch:
                 self.tracker.update_epoch(next_epoch)
         if self.verbose:

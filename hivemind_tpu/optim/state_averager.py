@@ -21,6 +21,7 @@ from hivemind_tpu.compression.base import as_numpy
 from hivemind_tpu.dht import DHT
 from hivemind_tpu.optim.recovery import _STATE_RESTORES
 from hivemind_tpu.telemetry.device import record_transfer
+from hivemind_tpu.telemetry.tracing import trace_sync as _sync_span
 from hivemind_tpu.utils.logging import get_logger
 from hivemind_tpu.utils.profiling import tracked_jit
 
@@ -40,6 +41,8 @@ class TrainingStateAverager(DecentralizedAverager):
         it, so optimizer steps taken concurrently with the round are not clobbered —
         required for delayed/local updates (reference state_averager.py:73-74)
     """
+
+    round_purpose = "state"
 
     def __init__(
         self,
@@ -111,10 +114,11 @@ class TrainingStateAverager(DecentralizedAverager):
 
     def _host_state_tensors(self) -> List[np.ndarray]:
         """The averageable view: params + chosen optimizer statistics + extras."""
-        tensors = [np.asarray(as_numpy(p), dtype=np.float32) for p in self._params_flat]
-        opt_leaves = self._opt_leaves()
-        tensors += [np.asarray(as_numpy(opt_leaves[i]), dtype=np.float32) for i in self._averaged_opt_indices]
-        tensors += [np.asarray(t, dtype=np.float32) for t in self.extra_tensors]
+        with _sync_span("state.device_get"):
+            tensors = [np.asarray(as_numpy(p), dtype=np.float32) for p in self._params_flat]
+            opt_leaves = self._opt_leaves()
+            tensors += [np.asarray(as_numpy(opt_leaves[i]), dtype=np.float32) for i in self._averaged_opt_indices]
+            tensors += [np.asarray(t, dtype=np.float32) for t in self.extra_tensors]
         # host staging IS the transport path (module docstring): every round
         # device_gets the whole averageable state — the d2h side of ISSUE 19's
         # transfer accounting on the averaging boundary
@@ -131,7 +135,7 @@ class TrainingStateAverager(DecentralizedAverager):
         n_opt = len(self._averaged_opt_indices)
         assert len(tensors) >= n_params + n_opt, "state tensor count mismatch"
         record_transfer(sum(int(t.nbytes) for t in tensors), "host_to_device")
-        with self._state_lock:
+        with _sync_span("state.load"), self._state_lock:
             self._params_flat = [
                 jnp.asarray(tensor, dtype=p.dtype)
                 for tensor, p in zip(tensors[:n_params], self._params_flat)

@@ -84,6 +84,7 @@ from hivemind_tpu.telemetry.tracing import (
     set_slow_span_threshold,
     start_span,
     trace,
+    trace_sync,
 )
 from hivemind_tpu.telemetry.monitor import (
     DEFAULT_TELEMETRY_KEY,
@@ -141,6 +142,7 @@ __all__ = [
     "Span",
     "SpanRecorder",
     "trace",
+    "trace_sync",
     "current_span",
     "start_span",
     "finish_span",

@@ -31,13 +31,21 @@ off the export path.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import threading
+import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from hivemind_tpu.telemetry.registry import REGISTRY, MetricsRegistry
-from hivemind_tpu.telemetry.tracing import Span, add_span_listener, wall_anchor, wall_time
+from hivemind_tpu.telemetry.tracing import (
+    Span,
+    add_span_listener,
+    trace_sync,
+    wall_anchor,
+    wall_time,
+)
 from hivemind_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -66,6 +74,37 @@ _MAX_PENDING_ROUNDS = 64
 # without retro-attachment the ledger would tend to drop exactly the exchange
 # it exists to attribute.
 _MAX_CLOSED_ROUNDS = 16
+
+
+class EpochPhases:
+    """The clock of ONE epoch transition (ISSUE 24): each phase is an
+    ``optimizer.<phase>`` span — on the device trace's timeline too — and a
+    ``<phase>_s`` field of the epoch record, so the time an optimizer's ``step``
+    spends closing an epoch divides into gradient round, update and state round
+    from inside. Created when the transition starts; :meth:`fields` goes to
+    :meth:`RoundLedger.record_epoch` when it ends."""
+
+    def __init__(self, **attributes: Any):
+        self._attributes = attributes  # of every phase span: peer, epoch
+        self._started = time.perf_counter()
+        # a phase that did not run (no state round this epoch) took no time, and says so
+        self._seconds = {"grad_round_s": 0.0, "update_s": 0.0, "state_round_s": 0.0}
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        """``grad_round`` / ``update`` / ``state_round``; a phase entered twice adds up."""
+        began = time.perf_counter()
+        try:
+            with trace_sync("optimizer." + name, **self._attributes):
+                yield
+        finally:
+            self._seconds[name + "_s"] += time.perf_counter() - began
+
+    def fields(self) -> Dict[str, float]:
+        """The phases' seconds, and ``transition_s`` from creation until now."""
+        fields = {key: round(value, 6) for key, value in self._seconds.items()}
+        fields["transition_s"] = round(time.perf_counter() - self._started, 6)
+        return fields
 
 
 def _percentile(values: List[float], fraction: float) -> float:
@@ -208,6 +247,8 @@ class RoundLedger:
                 "rank": attrs.get("rank"),
                 "total_s": round(span.duration, 6),
             }
+            if attrs.get("purpose"):
+                record["purpose"] = str(attrs["purpose"])  # "grads" / "state": which averager's round
             if matchmaking is not None:
                 record["matchmaking_wait_s"] = matchmaking["wait_s"]
                 record["matchmaking_outcome"] = matchmaking["outcome"]

@@ -3,7 +3,7 @@ peers' snapshots into one view.
 
 Per-peer side — :class:`TelemetryPublisher`: a daemon thread stores a compact
 snapshot of the process-wide registry (plus optional caller extras, e.g. a
-``StepProfiler.summary()``) under ``{key}`` / subkey ``peer_id`` on a timer, so
+training loop's own throughput numbers) under ``{key}`` / subkey ``peer_id`` on a timer, so
 one DHT read answers "where did this round's time go" for the whole swarm.
 
 Monitor side — :func:`fetch_swarm_telemetry` + :func:`aggregate_swarm_view` and

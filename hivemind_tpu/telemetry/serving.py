@@ -60,8 +60,12 @@ SERVING_SPAN = "serving.request"
 OVERLOAD_ERROR_NAME = "ServerOverloadedError"
 OVERLOAD_ERROR_NAMES = (OVERLOAD_ERROR_NAME, "ClientOverBudgetError")
 
-# phase attributes the TaskPool / handler stamp onto the serving span
-_PHASE_FIELDS = ("queue_wait_s", "assembly_s", "compute_s", "serialize_s")
+# phase attributes stamped onto the serving span, in the order a request meets
+# them: the handler (deserialize — on unary RPCs before the span opens, so outside
+# total_s — and serialize), the TaskPool or the DecodeSessionManager (queue wait, assembly,
+# compute), the ModuleBackend through the pool (stage: pad and widen on the way
+# in, fetch and slice on the way out; a part of compute_s, not beside it)
+_PHASE_FIELDS = ("deserialize_s", "queue_wait_s", "assembly_s", "compute_s", "stage_s", "serialize_s")
 
 # registry families the summary reads for the saturation columns (absent
 # families — a layer that never loaded — contribute nothing)
@@ -715,7 +719,7 @@ def format_slowest_phases(record: Dict[str, Any]) -> str:
     (shared by both renderers)."""
     return " ".join(
         f"{name[:-2]}={float(record[name]) * 1e3:.1f}ms"
-        for name in ("queue_wait_s", "assembly_s", "compute_s", "serialize_s")
+        for name in _PHASE_FIELDS
         if isinstance(record.get(name), (int, float))
     )
 

@@ -14,6 +14,10 @@ round slow, and which peer stalled it*. The pieces:
   buffer of *finished* spans. Always on, fixed memory, oldest-evicted. Spans
   whose duration crosses :func:`set_slow_span_threshold` are additionally kept
   in a small side ring and logged with their event chain.
+- :class:`trace_sync` — :func:`trace` for SYNCHRONOUS host code that feeds the
+  device: the same span, plus a ``jax.profiler.TraceAnnotation`` named
+  ``hivemind:<span name>``, so the interval also lies in the profiler's host
+  plane, on the device trace's clock (``docs/observability.md``).
 - :func:`render_chrome_trace` — Chrome trace-event JSON (loads directly in
   Perfetto / ``chrome://tracing``). Each distinct ``peer`` attribute becomes
   one pid row, so multi-peer-in-one-process tests and real swarm dumps both
@@ -39,6 +43,7 @@ import json
 import os
 import random
 import struct
+import sys
 import threading
 import time
 from collections import deque
@@ -487,6 +492,56 @@ class trace:
                 self.span.add_event("error", type=exc_type.__name__)
             finish_span(self.span)
         return False
+
+
+# ------------------------------------------------------------- device timeline
+
+# the prefix every program span carries in a profiler trace (`.xplane.pb` host
+# plane); perf/readers/idle_by_span.py attributes the device's idle time by it
+ANNOTATION_PREFIX = "hivemind:"
+
+
+def _profiler_annotation(name: str):
+    """``jax.profiler.TraceAnnotation("hivemind:<name>")``, or None in a process
+    that has not imported jax: DHT-only peers and CLIs must not pay for — or
+    claim — an accelerator backend because they import telemetry. With no
+    profiler session on, entering it is the inactive ``TraceMe`` check."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)  # None too while jax is half-imported
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+
+
+class trace_sync(trace):
+    """:class:`trace` for SYNCHRONOUS host code that feeds the device — batch
+    assembly, a jitted call through to its host result, staging, an epoch
+    transition's phases. One call site, two timelines: the telemetry span (ring,
+    listeners, ledgers, ``/trace``) and, on the device trace's clock, the same
+    interval as ``hivemind:<name>`` in the profiler's host plane, where it says
+    what the host did while the device sat idle.
+
+    Only for blocks that open and close on ONE thread with no ``await`` in
+    between: the annotation is thread-scoped, and interleaved asyncio tasks
+    would mis-nest it. A span that crosses an ``await`` stays a plain
+    :class:`trace` and delivers its time as a ledger field or a counter. With
+    ``HIVEMIND_TRACE=0`` this is :class:`trace`'s disabled path: no span, no
+    annotation."""
+
+    __slots__ = ("_annotation",)
+
+    def __enter__(self) -> Optional[Span]:
+        span = super().__enter__()
+        self._annotation = _profiler_annotation(self._name) if span is not None else None
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return span
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
+        return super().__exit__(exc_type, exc, tb)
 
 
 # ---------------------------------------------------------------------- export

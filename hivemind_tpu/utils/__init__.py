@@ -21,12 +21,7 @@ from hivemind_tpu.utils.nested import (
     nested_pack,
 )
 from hivemind_tpu.utils.performance_ema import PerformanceEMA
-from hivemind_tpu.utils.profiling import (
-    StepProfiler,
-    device_memory_stats,
-    profile_to,
-    trace_span,
-)
+from hivemind_tpu.utils.profiling import device_memory_stats
 from hivemind_tpu.utils.serializer import MSGPackSerializer, SerializerBase
 from hivemind_tpu.utils.streaming import combine_from_streaming, split_for_streaming
 from hivemind_tpu.utils.tensor_descr import BatchTensorDescriptor, TensorDescriptor

@@ -342,10 +342,10 @@ def test_peer_snapshot_carries_breakers_and_slow_spans():
 
 
 def test_unified_trace_span_emits_telemetry_span():
-    from hivemind_tpu.utils.profiling import trace_span
+    from hivemind_tpu.telemetry.tracing import trace_sync
 
     RECORDER.clear()
-    with trace_span("unified.step", step=7):
+    with trace_sync("unified.step", step=7):
         assert current_span() is not None and current_span().name == "unified.step"
     recorded = [s for s in RECORDER.snapshot() if s.name == "unified.step"]
     assert recorded and recorded[0].attributes["step"] == 7
