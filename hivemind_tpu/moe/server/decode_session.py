@@ -15,8 +15,12 @@ abandoned client cannot pin device memory.
 clients' sessions that arrive within a small window are merged into ONE device
 call — the per-session step is `jax.vmap`-ed over a stacked session axis (params
 broadcast; each row carries its own cache and per-row write index), with the
-session count bucketed to powers of two so the jit cache stays small. One
-dispatch serves every concurrent stream, which is what keeps a serving chip busy
+session count bucketed to powers of two so the jit cache stays small. Sessions
+keep their caches one array each; the batch's program takes them as they are,
+stacks them, steps, and hands the new caches back one array a session, so a batch
+is ONE dispatch whatever its rows: the host only collects handles before it
+(``assemble``: one `np.stack` of the activations, one array of write positions)
+and assigns them after it (``scatter``). That is what keeps a serving chip busy
 when many clients decode one token at a time. Disable with
 ``HIVEMIND_TPU_DECODE_BATCHING=0`` for A/B runs.
 
@@ -79,9 +83,10 @@ _STEPS = _TELEMETRY.counter(
 # `decode.*` spans: a registry snapshot carries a histogram only as count and sum
 _PHASE_SECONDS = _TELEMETRY.counter(
     "hivemind_moe_decode_phase_seconds_total",
-    "host seconds of vmapped decode batches, by phase (assemble = stacking rows, caches "
-    "and indices; step = the jitted call until its output is on the host; scatter = "
-    "handing each session its rows of the new caches)",
+    "host seconds of vmapped decode batches, by phase (assemble = collecting the rows' cache "
+    "handles, one host array of activations and one of write positions; step = the one jitted "
+    "call, which stacks the caches, steps and unstacks them, until its output is on the host; "
+    "scatter = assigning each session its new caches)",
     ("phase",),
 )
 _CALLS = _TELEMETRY.counter(
@@ -210,9 +215,31 @@ class DecodeSessionManager:
             # under one site — a client cycling prompt lengths past the pow2
             # buckets shows up as a recompile storm, not silent latency
             fn = self._step_fns[key] = tracked_jit(
-                self._raw_step(uid), site="decode_session.step", donate_argnums=(2, 3)
+                self._raw_step(uid), site="decode_session.step", donate_argnums=(2, 3),
+                out_shardings=(None, *self._cache_shardings(uid)),
             )
         return fn
+
+    def _cache_shardings(self, uid: str):
+        """Where `shard_decode_cache` places this block's (cache_k, cache_v), for a
+        step's `out_shardings`: left to the compiler, a mesh program's new caches
+        come back under shardings of its choosing, and every mixture of those over
+        a batch's rows is another program. (None, None), i.e. nothing pinned, for
+        caches that no backend placed."""
+        if not hasattr(self.backends[uid], "shard_decode_cache"):
+            return None, None
+        cache_k, cache_v = self._dummy_rows(uid)
+        return cache_k.sharding, cache_v.sharding
+
+    def _fresh_caches(self, backend, batch: int):
+        """Empty (cache_k, cache_v) for ``batch`` rows, placed as the backend serves them."""
+        cache_k, cache_v = backend.module.init_decode_cache(batch, self.max_len)
+        if hasattr(backend, "shard_decode_cache"):
+            # mesh-sharded serving: the session's KV lives distributed
+            # over the backend's mesh (MeshModuleBackend), so a cache
+            # that exceeds one chip's HBM still fits the slice
+            cache_k, cache_v = backend.shard_decode_cache(cache_k, cache_v)
+        return cache_k, cache_v
 
     def _advance(self, uid: str, session: _Session, backend, x: np.ndarray, chunk_len: int):
         """Run the per-session jitted step on ``x`` (already padded to
@@ -256,13 +283,7 @@ class DecodeSessionManager:
             self._evict_locked()
             session = self._sessions.get(key)
             if reset:
-                cache_k, cache_v = backend.module.init_decode_cache(batch, self.max_len)
-                if hasattr(backend, "shard_decode_cache"):
-                    # mesh-sharded serving: the session's KV lives distributed
-                    # over the backend's mesh (MeshModuleBackend), so a cache
-                    # that exceeds one chip's HBM still fits the slice
-                    cache_k, cache_v = backend.shard_decode_cache(cache_k, cache_v)
-                session = self._sessions[key] = _Session(cache_k, cache_v)
+                session = self._sessions[key] = _Session(*self._fresh_caches(backend, batch))
                 _RESETS.inc()
                 self._sample_gauges_locked()
             elif session is None:
@@ -482,24 +503,36 @@ class DecodeSessionManager:
                         self._in_flight.pop(id(session), None)
 
     def _batched_fn(self, uid: str, stack: int):
+        """The one program of a vmapped batch of ``stack`` rows: it takes the rows'
+        caches as they are kept, one array a session, stacks them, steps every row
+        and hands the new caches back one array a session, so that no operation per
+        session runs outside it. Keyed by the bucket alone; padding rows come in as
+        arguments like live ones."""
         key = (uid, stack)
         fn = self._batched_fns.get(key)
         if fn is None:
+            step = jax.vmap(self._raw_step(uid), in_axes=(None, 0, 0, 0, 0))
+
+            def batched_step(params, xs, caches_k, caches_v, indices):
+                y, new_k, new_v = step(params, xs, jnp.stack(caches_k), jnp.stack(caches_v), indices)
+                return y, tuple(jnp.unstack(new_k)), tuple(jnp.unstack(new_v))
+
+            # the caches are NOT donated: the padding pair sits in several positions
+            # of one call, and a step that fails leaves every session as it was
+            placed_k, placed_v = self._cache_shardings(uid)
             fn = self._batched_fns[key] = tracked_jit(
-                jax.vmap(self._raw_step(uid), in_axes=(None, 0, 0, 0, 0)),
-                site="decode_session.batched_step",
-                donate_argnums=(2, 3),
+                batched_step, site="decode_session.batched_step",
+                out_shardings=(None, (placed_k,) * stack, (placed_v,) * stack),
             )
         return fn
 
     def _dummy_rows(self, uid: str):
         """A throwaway (cache_k, cache_v) pair used to pad batches to the bucket
-        size; its outputs and cache writes are discarded."""
+        size; its outputs and cache writes are discarded. Placed like a session's
+        caches, so that a padded call reaches the program a full bucket compiled."""
         pair = self._dummy_caches.get(uid)
         if pair is None:
-            pair = self._dummy_caches[uid] = self.backends[uid].module.init_decode_cache(
-                1, self.max_len
-            )
+            pair = self._dummy_caches[uid] = self._fresh_caches(self.backends[uid], 1)
         return pair
 
     def _decode_batch(self, uid: str, entries: List) -> List:
@@ -556,41 +589,32 @@ class DecodeSessionManager:
                 span.set("bucket", stack)
             _CALLS_BATCHED.inc()
             with _batch_phase("assemble"):
+                # handles only: the rows' caches go in as they are, the activations
+                # and write positions as one host array each
+                sessions = [entries[i][1] for i in live]
+                padding = stack - len(live)
                 dummy_k, dummy_v = self._dummy_rows(uid)
-                xs, cks, cvs, idxs = [], [], [], []
-                for i in live:
-                    _future, session, x = entries[i]
-                    xs.append(jnp.asarray(x))
-                    cks.append(session.cache_k)
-                    cvs.append(session.cache_v)
-                    idxs.append(session.index)
-                for _ in range(stack - len(live)):
-                    xs.append(jnp.zeros_like(xs[0]))
-                    cks.append(dummy_k)
-                    cvs.append(dummy_v)
-                    idxs.append(1)  # a valid mid-cache position; output is discarded
+                rows = [entries[i][2] for i in live]
+                xs = np.stack(rows + [np.zeros_like(rows[0])] * padding)
+                # a padding row writes a valid mid-cache position; its output is discarded
+                indices = np.array([session.index for session in sessions] + [1] * padding, np.int32)
+                caches_k = tuple(session.cache_k for session in sessions) + (dummy_k,) * padding
+                caches_v = tuple(session.cache_v for session in sessions) + (dummy_v,) * padding
                 step = self._batched_fn(uid, stack)
-                # xs rows originate host-side (one per live client step); caches are
-                # already resident, so only the stacked activations count as h2d
-                record_transfer(sum(int(x.nbytes) for x in xs), "host_to_device")
-                stacked = (jnp.stack(xs), jnp.stack(cks), jnp.stack(cvs), jnp.asarray(idxs, jnp.int32))
+                # the caches are already resident: only the stacked activations count as h2d
+                record_transfer(int(xs.nbytes), "host_to_device")
             with _batch_phase("step"):
-                y, new_k, new_v = step(backend.snapshot_params(), *stacked)
-                del stacked  # donated to the step
+                y, new_k, new_v = step(backend.snapshot_params(), xs, caches_k, caches_v, indices)
                 y = np.asarray(y)
             record_transfer(y.nbytes, "device_to_host")
             _STEPS.inc(len(live), path="batched")
             with _batch_phase("scatter"):
                 now = time.monotonic()
-                for row, i in enumerate(live):
-                    _future, session, _x = entries[i]
-                    session.cache_k = new_k[row]
-                    session.cache_v = new_v[row]
+                for row, (i, session) in enumerate(zip(live, sessions)):
+                    session.cache_k, session.cache_v = new_k[row], new_v[row]
                     session.index += 1
                     session.last_used = now
                     results[i] = y[row]
-            # (the dummy rows survive: donation frees the STACKED buffer, not the
-            # per-session/dummy constituents that were copied into it)
             return results
         finally:
             for i in ordered:

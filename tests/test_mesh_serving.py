@@ -112,6 +112,38 @@ def test_mesh_sharded_server_is_token_identical_over_rpc(tmp_path):
         dht.shutdown()
 
 
+def test_mesh_sharded_sessions_share_one_batched_program(tmp_path):
+    """ISSUE 25: the batched decode program stacks the rows' caches itself, so it
+    takes mesh-sharded per-session caches and hands back caches a later step
+    accepts; and it stays keyed by the bucket alone: rows fresh from a prefill,
+    rows a batch has stepped and the padding pair all reach ONE program (left to
+    the compiler, every program's outputs come back under another sharding)."""
+    from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
+    from hivemind_tpu.telemetry.device import COMPILE_TRACKER
+
+    _write_checkpoint(tmp_path)
+    backends_mesh, _ = load_llama_blocks(tmp_path, uid_prefix="meshed.", mesh=_tp_mesh())
+    backends_single, _ = load_llama_blocks(tmp_path, uid_prefix="single.")
+    manager = DecodeSessionManager({**backends_mesh, **backends_single}, max_len=32)
+    rng = np.random.RandomState(25)
+    prompts = rng.randn(4, 1, 3, HID).astype(np.float32)
+    tokens = rng.randn(4, 4, 1, 1, HID).astype(np.float32)
+    outs = {}
+    for uid in ("meshed.0", "single.0"):
+        compiles_before = COMPILE_TRACKER.counts().get("decode_session.batched_step", 0)
+        for i in range(4):
+            manager.decode(uid, f"s{i}", prompts[i], reset=True)
+        for step, rows in enumerate((3, 4, 3, 4)):  # the fourth row joins fresh from its prefill
+            entries = [(None, manager._sessions[(uid, f"s{i}")], tokens[step, i]) for i in range(rows)]
+            outs[uid, step] = np.stack(manager._decode_batch(uid, entries))
+        assert COMPILE_TRACKER.counts()["decode_session.batched_step"] == compiles_before + 1
+    cache = manager._sessions[("meshed.0", "s0")].cache_k
+    assert cache.sharding == manager._dummy_rows("meshed.0")[0].sharding and not cache.sharding.is_fully_replicated
+    for step in range(4):
+        meshed, single = outs["meshed.0", step], outs["single.0", step]
+        assert np.linalg.norm(meshed - single) / np.linalg.norm(single) < 3e-2
+
+
 def test_hbm_planning_7b_mesh_pooling():
     """The regime the mesh tier exists for, at REAL 7B shapes: with a 600 MB
     per-chip budget one chip cannot hold even one fp32 block, but an 8-device

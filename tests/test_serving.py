@@ -262,6 +262,136 @@ def test_decode_session_ttl_eviction_and_reset_semantics():
     assert steps.labels("direct").value >= 3
 
 
+# ---------------------------------------------- the vmapped batch is one program (ISSUE 25)
+
+
+def _warmed_manager(uid="lim.0", slots=8):
+    """A manager warmed as `perf/runners/block_server.py` `warm_decode` warms a
+    block: sessions prefilled, every full bucket and one short of the largest
+    stepped through `_decode_batch`, then the sessions cleared."""
+    from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
+
+    manager = DecodeSessionManager(_decode_backend(uid), max_len=32, max_sessions=64)
+    buckets = [2**k for k in range(1, slots.bit_length())]
+    token, prompt = np.zeros((1, 1, HID), np.float32), np.zeros((1, 3, HID), np.float32)
+    manager.decode(uid, "warm-len", prompt, reset=True)
+    manager.decode(uid, "warm-len", token, reset=False)
+    names = [f"warm-row{i}" for i in range(max(buckets))]
+    for name in names:
+        manager.decode(uid, name, prompt, reset=True)
+    for rows in buckets + [max(buckets) - 1]:
+        entries = [(None, manager._sessions[(uid, name)], token) for name in names[:rows]]
+        assert not [o for o in manager._decode_batch(uid, entries) if isinstance(o, Exception)]
+    with manager._lock:
+        manager._sessions.clear()
+    return manager
+
+
+def _prefilled(manager, uid, names, rng):
+    for name in names:
+        manager.decode(uid, name, rng.randn(1, 3, HID).astype(np.float32), reset=True)
+    return [manager._sessions[(uid, name)] for name in names]
+
+
+def _batched_compiles() -> int:
+    from hivemind_tpu.telemetry.device import COMPILE_TRACKER
+
+    return COMPILE_TRACKER.counts().get("decode_session.batched_step", 0)
+
+
+@pytest.mark.parametrize("rows", [2, 5, 8])
+def test_warmed_decode_batch_dispatches_nothing_per_session(rows, monkeypatch):
+    """A warmed vmapped batch is ONE dispatched program: stacking the rows' caches
+    and handing the new ones out happen inside it, so the eager primitives around
+    it (each a dispatch of its own) number the same, none, whatever the rows."""
+    from jax._src import core as jax_core
+
+    uid = "lim.0"
+    manager = _warmed_manager(uid)
+    sessions = _prefilled(manager, uid, [f"s{i}" for i in range(rows)], np.random.RandomState(rows))
+    token = np.random.RandomState(0).randn(1, 1, HID).astype(np.float32)
+    eager = []
+    process_primitive = jax_core.EvalTrace.process_primitive
+
+    def counting(self, primitive, args, params):
+        eager.append(primitive.name)
+        return process_primitive(self, primitive, args, params)
+
+    steps = REGISTRY.get("hivemind_moe_decode_steps_total")
+    batched_before = steps.labels("batched").value
+    monkeypatch.setattr(jax_core.EvalTrace, "process_primitive", counting)
+    results = manager._decode_batch(uid, [(None, session, token) for session in sessions])
+    monkeypatch.undo()
+    assert eager == [], f"{len(eager)} eager primitives around a batch of {rows} rows: {sorted(set(eager))}"
+    assert steps.labels("batched").value == batched_before + rows
+    assert all(isinstance(out, np.ndarray) and out.shape == (1, 1, HID) for out in results)
+    assert all(session.index == 4 and session.cache_k.shape[0] == 1 for session in sessions)
+
+
+def test_decode_batch_program_is_keyed_by_the_bucket_alone():
+    """Live counts the warm-up never ran (3, 5, 6 in the bucket of 8), with rows
+    fresh from a prefill and rows a batched step has already handed their caches:
+    no new program; and the batched path computes what the direct path computes."""
+    uid = "lim.0"
+    manager = _warmed_manager(uid)
+    fns_before, compiles_before = len(manager._batched_fns), _batched_compiles()
+    rng = np.random.RandomState(25)
+    prompts = rng.randn(6, 1, 3, HID).astype(np.float32)
+    tokens = rng.randn(3, 6, 1, 1, HID).astype(np.float32)
+    for name in [f"b{i}" for i in range(6)] + [f"d{i}" for i in range(6)]:
+        manager.decode(uid, name, prompts[int(name[1:])], reset=True)
+    batched = [manager._sessions[(uid, f"b{i}")] for i in range(6)]
+    direct = [manager._sessions[(uid, f"d{i}")] for i in range(6)]
+
+    # 3 fresh rows; then 5 (three stepped, two fresh); then 6 (five stepped, one fresh)
+    outs = {}
+    for step, rows in enumerate((3, 5, 6)):
+        results = manager._decode_batch(uid, [(None, batched[i], tokens[step, i]) for i in range(rows)])
+        for i, out in enumerate(results):
+            assert not isinstance(out, Exception), out
+            outs[step, i] = out
+    assert len(manager._batched_fns) == fns_before
+    assert _batched_compiles() == compiles_before, "a live count inside a warmed bucket compiled a program"
+
+    for step, rows in enumerate((3, 5, 6)):
+        for i in range(rows):
+            want = manager.decode(uid, f"d{i}", tokens[step, i], reset=False)
+            np.testing.assert_allclose(outs[step, i], want, rtol=1e-5, atol=1e-5)
+    for got, want in zip(batched, direct):
+        assert got.index == want.index
+        for a, b in ((got.cache_k, want.cache_k), (got.cache_v, want.cache_v)):
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32)[:, :got.index], np.asarray(b, np.float32)[:, :got.index], rtol=1e-5, atol=1e-5)
+
+
+def test_failed_decode_batch_leaves_every_session_intact(monkeypatch):
+    """The batched program does not donate the rows' caches (CHANGES.md, PR 25): a
+    step that raises, at dispatch or when its output is read, leaves every session
+    where it was, and the same tokens then step as if nothing had happened."""
+    uid = "lim.0"
+    manager = _warmed_manager(uid, slots=4)
+    rng = np.random.RandomState(4)
+    sessions = _prefilled(manager, uid, ["f0", "f1", "f2"], rng)
+    twins = _prefilled(manager, uid, ["t0", "t1", "t2"], np.random.RandomState(4))
+    tokens = rng.randn(3, 1, 1, HID).astype(np.float32)
+
+    def poisoned(params, *args):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(manager, "_batched_fn", lambda uid, stack: poisoned)
+    with pytest.raises(RuntimeError, match="device lost"):
+        manager._decode_batch(uid, [(None, session, token) for session, token in zip(sessions, tokens)])
+    monkeypatch.undo()
+
+    assert all(manager._sessions[(uid, f"f{i}")] is session for i, session in enumerate(sessions))
+    assert all(session.index == 3 and not session.lock.locked() for session in sessions)
+    assert not any(cache.is_deleted() for session in sessions for cache in (session.cache_k, session.cache_v))
+    results = manager._decode_batch(uid, [(None, session, token) for session, token in zip(sessions, tokens)])
+    for i, out in enumerate(results):
+        np.testing.assert_allclose(out, manager.decode(uid, f"t{i}", tokens[i], reset=False), rtol=1e-5, atol=1e-5)
+    assert all(session.index == twin.index == 4 for session, twin in zip(sessions, twins))
+
+
 # ------------------------------------------------------------------ end-to-end
 
 
