@@ -13,12 +13,16 @@ abandoned client cannot pin device memory.
 
 **Continuous batching** (`decode_async`): single-token steps from different
 clients' sessions that arrive within a small window are merged into ONE device
-call — the per-session step is `jax.vmap`-ed over a stacked session axis (params
-broadcast; each row carries its own cache and per-row write index), with the
-session count bucketed to powers of two so the jit cache stays small. Sessions
-keep their caches one array each; the batch's program takes them as they are,
-stacks them, steps, and hands the new caches back one array a session, so a batch
-is ONE dispatch whatever its rows: the host only collects handles before it
+call — the block is applied once to all the rows, ``[rows, 1, hidden]``, with a
+VECTOR of write positions, and only its cache update and the attention over the
+cache run per row (`layers.common._decode_attention` vmaps itself over the rows):
+the block's matmuls, and a sparse expert layer above all, see the batch's rows
+together. What a block class owes this path (``index`` as a scalar in a session's
+own call, as a vector in a batched step) is written down in
+`moe/server/layers/__init__.py`. The session count is bucketed to powers of two so
+the jit cache stays small. Sessions keep their caches one array each; the batch's program takes them
+as they are, joins them, steps, and hands the new caches back one array a session,
+so a batch is ONE dispatch whatever its rows: the host only collects handles before it
 (``assemble``: one `np.stack` of the activations, one array of write positions)
 and assigns them after it (``scatter``). That is what keeps a serving chip busy
 when many clients decode one token at a time. Disable with
@@ -44,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from hivemind_tpu.moe.server.routing_stats import ROUTING_COLLECTION, record_routing
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 from hivemind_tpu.telemetry.device import record_transfer
 from hivemind_tpu.telemetry.serving import accrue_span_phase
@@ -194,16 +199,21 @@ class DecodeSessionManager:
         _SESSION_OCCUPANCY.set(round(len(self._sessions) / max(self.max_sessions, 1), 4))
 
     def _raw_step(self, uid: str):
-        """The un-jitted per-session step; shared by the direct and batched paths so
-        a signature change cannot silently diverge them."""
+        """The un-jitted block step; shared by the direct and batched paths so a
+        signature change cannot silently diverge them. ``index`` is one write
+        position (a session's prefill or step) or a vector of them, one a row (a
+        batch of sessions). Returns (y, cache_k, cache_v, routing): what the block
+        sowed into `ROUTING_COLLECTION` (empty for a block without experts)."""
         backend = self.backends[uid]
 
         def step(params, x, cache_k, cache_v, index):
             # int8 weight-only backends: materialize dense weights inside the jit
             # (identity for plain fp32 trees)
-            return backend.module.apply(
-                {"params": backend.dense_params(params)}, x, cache_k, cache_v, index
+            (y, cache_k, cache_v), routing = backend.module.apply(
+                {"params": backend.dense_params(params)}, x, cache_k, cache_v, index,
+                mutable=[ROUTING_COLLECTION],
             )
+            return y, cache_k, cache_v, routing
 
         return step
 
@@ -216,7 +226,7 @@ class DecodeSessionManager:
             # buckets shows up as a recompile storm, not silent latency
             fn = self._step_fns[key] = tracked_jit(
                 self._raw_step(uid), site="decode_session.step", donate_argnums=(2, 3),
-                out_shardings=(None, *self._cache_shardings(uid)),
+                out_shardings=(None, *self._cache_shardings(uid), None),
             )
         return fn
 
@@ -241,10 +251,10 @@ class DecodeSessionManager:
             cache_k, cache_v = backend.shard_decode_cache(cache_k, cache_v)
         return cache_k, cache_v
 
-    def _advance(self, uid: str, session: _Session, backend, x: np.ndarray, chunk_len: int):
-        """Run the per-session jitted step on ``x`` (already padded to
-        ``chunk_len``) under ``session.lock``, store the new caches and return the
-        output on the host. The step DONATES the caches: if it fails (at dispatch
+    def _advance(self, uid: str, session: _Session, backend, x: np.ndarray, chunk_len: int, new_len: int):
+        """Run the per-session jitted step on ``x`` (``new_len`` positions, already
+        padded to ``chunk_len``) under ``session.lock``, store the new caches and
+        return the output on the host. The step DONATES the caches: if it fails (at dispatch
         or when the result is read), what the session still points at may be
         deleted buffers, so the session is dropped and the client's next
         continuation gets the unknown-session KeyError (it re-prefills) instead
@@ -252,12 +262,14 @@ class DecodeSessionManager:
         step = self._step_fn(uid, x.shape[0], chunk_len)
         _CALLS_DIRECT.inc()
         try:
-            with _trace_sync("decode.direct", uid=uid, chunk_len=chunk_len):
-                y, session.cache_k, session.cache_v = step(
+            with _trace_sync("decode.direct", uid=uid, chunk_len=chunk_len) as span:
+                y, session.cache_k, session.cache_v, routing = step(
                     backend.snapshot_params(), jnp.asarray(x), session.cache_k,
                     session.cache_v, jnp.int32(session.index),
                 )
-                return np.asarray(y)
+                y = np.asarray(y)
+                record_routing(routing, "direct", span, positions=new_len)
+                return y
         except Exception:
             with self._lock:
                 for key in [k for k, s in self._sessions.items() if s is session]:
@@ -322,7 +334,7 @@ class DecodeSessionManager:
             if padded_len != new_len:
                 x = np.pad(x, ((0, 0), (0, padded_len - new_len), (0, 0)))
             record_transfer(x.nbytes, "host_to_device")
-            y = self._advance(uid, session, backend, x, padded_len)
+            y = self._advance(uid, session, backend, x, padded_len, new_len)
             session.index += new_len
             # re-stamp AFTER the device step: a step that hits a jit compile can
             # outlast merge_recency_s, and a session stamped only at entry would
@@ -503,26 +515,30 @@ class DecodeSessionManager:
                         self._in_flight.pop(id(session), None)
 
     def _batched_fn(self, uid: str, stack: int):
-        """The one program of a vmapped batch of ``stack`` rows: it takes the rows'
-        caches as they are kept, one array a session, stacks them, steps every row
-        and hands the new caches back one array a session, so that no operation per
-        session runs outside it. Keyed by the bucket alone; padding rows come in as
-        arguments like live ones."""
+        """The one program of a batch of ``stack`` rows: it takes the rows' caches
+        as they are kept, one array a session, joins them along the batch axis,
+        applies the block ONCE to all rows with the vector of their write positions
+        (`_raw_step`: the block runs its cache update and attention per row, all
+        else on the rows together) and hands the new caches back one array a
+        session, so that no operation per session runs outside it. Keyed by the
+        bucket alone; padding rows come in as arguments like live ones."""
         key = (uid, stack)
         fn = self._batched_fns.get(key)
         if fn is None:
-            step = jax.vmap(self._raw_step(uid), in_axes=(None, 0, 0, 0, 0))
+            step = self._raw_step(uid)
 
             def batched_step(params, xs, caches_k, caches_v, indices):
-                y, new_k, new_v = step(params, xs, jnp.stack(caches_k), jnp.stack(caches_v), indices)
-                return y, tuple(jnp.unstack(new_k)), tuple(jnp.unstack(new_v))
+                y, new_k, new_v, routing = step(
+                    params, xs, jnp.concatenate(caches_k), jnp.concatenate(caches_v), indices
+                )
+                return y, tuple(jnp.split(new_k, stack)), tuple(jnp.split(new_v, stack)), routing
 
             # the caches are NOT donated: the padding pair sits in several positions
             # of one call, and a step that fails leaves every session as it was
             placed_k, placed_v = self._cache_shardings(uid)
             fn = self._batched_fns[key] = tracked_jit(
                 batched_step, site="decode_session.batched_step",
-                out_shardings=(None, (placed_k,) * stack, (placed_v,) * stack),
+                out_shardings=(None, (placed_k,) * stack, (placed_v,) * stack, None),
             )
         return fn
 
@@ -575,7 +591,7 @@ class DecodeSessionManager:
                 [i] = live
                 _future, session, x = entries[i]
                 record_transfer(int(x.nbytes), "host_to_device")
-                y = self._advance(uid, session, backend, x, 1)
+                y = self._advance(uid, session, backend, x, 1, 1)
                 session.index += 1
                 session.last_used = time.monotonic()
                 # counted "direct": nothing was merged/vmapped (the catalog row
@@ -595,7 +611,7 @@ class DecodeSessionManager:
                 padding = stack - len(live)
                 dummy_k, dummy_v = self._dummy_rows(uid)
                 rows = [entries[i][2] for i in live]
-                xs = np.stack(rows + [np.zeros_like(rows[0])] * padding)
+                xs = np.concatenate(rows + [np.zeros_like(rows[0])] * padding)  # [stack, 1, hidden]
                 # a padding row writes a valid mid-cache position; its output is discarded
                 indices = np.array([session.index for session in sessions] + [1] * padding, np.int32)
                 caches_k = tuple(session.cache_k for session in sessions) + (dummy_k,) * padding
@@ -604,8 +620,9 @@ class DecodeSessionManager:
                 # the caches are already resident: only the stacked activations count as h2d
                 record_transfer(int(xs.nbytes), "host_to_device")
             with _batch_phase("step"):
-                y, new_k, new_v = step(backend.snapshot_params(), xs, caches_k, caches_v, indices)
+                y, new_k, new_v, routing = step(backend.snapshot_params(), xs, caches_k, caches_v, indices)
                 y = np.asarray(y)
+                record_routing(routing, "batched", span, rows=len(live))
             record_transfer(y.nbytes, "device_to_host")
             _STEPS.inc(len(live), path="batched")
             with _batch_phase("scatter"):
@@ -614,7 +631,7 @@ class DecodeSessionManager:
                     session.cache_k, session.cache_v = new_k[row], new_v[row]
                     session.index += 1
                     session.last_used = now
-                    results[i] = y[row]
+                    results[i] = y[row:row + 1]
             return results
         finally:
             for i in ordered:

@@ -74,9 +74,22 @@ def _decode_attention(q, k_new, v_new, cache_k, cache_v, index, groups: int = 1)
     causal within the chunk) and incremental (chunk length 1, attends everything
     ≤ index). ``groups`` > 1 repeats the (grouped-query) KV heads to match q at
     attention time — caches stay in the compact kv_heads layout.
+    ``index`` may be a vector, one write position a row: the rows are then
+    different sessions of one batched step, and this function is the boundary
+    between what a row does alone (write its cache at its own position, attend
+    over its own cache) and what the batch's rows do together (everything around
+    it in the block: projections, norms, the MLP or the expert layer).
     Returns (context, cache_k, cache_v)."""
     from hivemind_tpu.parallel.ring_attention import plain_attention
 
+    if jnp.ndim(index) == 1:
+        def one_row(q, k_new, v_new, cache_k, cache_v, index):
+            context, cache_k, cache_v = _decode_attention(
+                q[None], k_new[None], v_new[None], cache_k[None], cache_v[None], index, groups
+            )
+            return context[0], cache_k[0], cache_v[0]
+
+        return jax.vmap(one_row)(q, k_new, v_new, cache_k, cache_v, index)
     batch, new_len = q.shape[0], q.shape[1]
     max_len = cache_k.shape[1]
     cache_k = jax.lax.dynamic_update_slice(cache_k, k_new.astype(cache_k.dtype), (0, index, 0, 0))
@@ -145,15 +158,70 @@ def _rotate_half(x: jax.Array) -> jax.Array:
 def apply_rope(x: jax.Array, theta: float = 10000.0, offset=0) -> jax.Array:
     """Rotary position embedding over [batch, seq, heads, head_dim] (head_dim even).
     ``offset`` (may be traced) shifts positions — decode sessions rotate the new
-    token at its absolute position in the sequence."""
+    token at its absolute position in the sequence; a vector of offsets gives each
+    row of the batch its own (the rows of a batched decode step)."""
     seq, dim = x.shape[1], x.shape[-1]
     freqs = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    positions = offset + jnp.arange(seq, dtype=jnp.float32)
-    angles = positions[:, None] * freqs[None, :]
-    angles = jnp.concatenate([angles, angles], axis=-1)  # [seq, dim]
-    cos = jnp.cos(angles)[None, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[None, :, None, :].astype(x.dtype)
+    positions = jnp.asarray(offset, jnp.float32)[..., None] + jnp.arange(seq, dtype=jnp.float32)
+    angles = positions[..., None] * freqs
+    angles = jnp.concatenate([angles, angles], axis=-1)  # [seq, dim] or [batch, seq, dim]
+    cos = jnp.cos(angles)[..., :, None, :].astype(x.dtype)
+    sin = jnp.sin(angles)[..., :, None, :].astype(x.dtype)
     return x * cos + _rotate_half(x) * sin
+
+
+def _empty_kv_cache(batch: int, max_len: int, kv_heads: int, head_dim: int):
+    """(cache_k, cache_v) of the Llama-family blocks: bf16, compact kv-heads layout."""
+    shape = (batch, max_len, kv_heads, head_dim)
+    return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+
+
+def _plain_dense(features: int, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=jnp.bfloat16, param_dtype=jnp.float32, name=name)
+
+
+def _rope_attention_half(x, cache_k, cache_v, index, *, heads: int, kv_heads: int, rope_theta: float,
+                         rms_eps: float, mesh=None, qk_norm: bool = False, head_dim: int = 0):
+    """The attention half of the Llama-family blocks, called inside a block's
+    compact ``__call__`` (its submodules become the block's): pre-RMSNorm, q / k / v
+    projections without bias, optional RMS norms over the whole projected query and
+    key widths (``qk_norm``: OLMoE), rotary embedding, causal attention with
+    grouped KV heads (over the decode cache when one is given), output projection,
+    residual. The head size is hidden / heads; a ``head_dim`` that says otherwise
+    (a checkpoint whose heads are not hidden / heads wide) fails here, loudly,
+    and is not served at another shape. Returns (x + attention, cache_k, cache_v)."""
+    from hivemind_tpu.parallel.ring_attention import mesh_attention_core
+
+    batch, seq, hid = x.shape
+    assert heads % kv_heads == 0, (heads, kv_heads)
+    assert hid % heads == 0 and head_dim in (0, hid // heads), (
+        f"head size {head_dim or 'hidden / heads'} with hidden {hid} and {heads} heads: "
+        f"these blocks serve heads of hidden / heads only"
+    )
+    head_dim = hid // heads
+    normed = nn.RMSNorm(epsilon=rms_eps, dtype=jnp.bfloat16, name="attention_norm")(x)
+    q = _plain_dense(heads * head_dim, "query")(normed)
+    k = _plain_dense(kv_heads * head_dim, "key")(normed)
+    v = _plain_dense(kv_heads * head_dim, "value")(normed).reshape(batch, seq, kv_heads, head_dim)
+    if qk_norm:
+        q = nn.RMSNorm(epsilon=rms_eps, dtype=jnp.bfloat16, name="query_norm")(q)
+        k = nn.RMSNorm(epsilon=rms_eps, dtype=jnp.bfloat16, name="key_norm")(k)
+    q = q.reshape(batch, seq, heads, head_dim)
+    k = k.reshape(batch, seq, kv_heads, head_dim)
+    offset = 0 if cache_k is None else index  # decode: rotate at absolute position
+    q = apply_rope(q, rope_theta, offset)
+    k = apply_rope(k, rope_theta, offset)
+    if cache_k is None:
+        if kv_heads != heads:  # grouped-query: each KV head serves heads/kv_heads queries
+            k = jnp.repeat(k, heads // kv_heads, axis=2)
+            v = jnp.repeat(v, heads // kv_heads, axis=2)
+        attn = mesh_attention_core(mesh, q, k, v, causal=True).reshape(batch, seq, hid)
+    else:
+        context, cache_k, cache_v = _decode_attention(
+            q, k, v, cache_k, cache_v, index, groups=heads // kv_heads
+        )
+        attn = context.reshape(batch, seq, hid)
+    return x + _plain_dense(hid, "attention_out")(attn), cache_k, cache_v
 
 
 class LlamaBlockExpert(nn.Module):
@@ -171,50 +239,89 @@ class LlamaBlockExpert(nn.Module):
     rope_theta: float = 10000.0
     ffn_inner: int = 0  # 0 = the 8/3 rule below; real checkpoints set intermediate_size
     rms_eps: float = 1e-6  # real checkpoints set rms_norm_eps (Llama-2: 1e-5)
+    head_dim: int = 0  # 0 = hidden_dim // num_heads; anything else must equal it (asserted)
     # set when the block is served sharded over a device mesh (MeshModuleBackend):
     # the fused attention kernel must then run per shard (mesh_attention_core)
     mesh: Optional[Any] = None
 
     def init_decode_cache(self, batch: int, max_len: int):
-        kv_heads = self.num_kv_heads or self.num_heads
-        shape = (batch, max_len, kv_heads, self.hidden_dim // self.num_heads)
-        return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+        return _empty_kv_cache(batch, max_len, self.num_kv_heads or self.num_heads, self.hidden_dim // self.num_heads)
 
     @nn.compact
     def __call__(self, x, cache_k=None, cache_v=None, index=None):
-        from hivemind_tpu.parallel.ring_attention import mesh_attention_core
-
-        batch, seq, hid = x.shape
-        heads = self.num_heads
-        kv_heads = self.num_kv_heads or heads
-        assert heads % kv_heads == 0, (heads, kv_heads)
-        head_dim = hid // heads
-        dense = lambda n, name: nn.Dense(
-            n, use_bias=False, dtype=jnp.bfloat16, param_dtype=jnp.float32, name=name
+        hid = x.shape[-1]
+        x, cache_k, cache_v = _rope_attention_half(
+            x, cache_k, cache_v, index, heads=self.num_heads, kv_heads=self.num_kv_heads or self.num_heads,
+            rope_theta=self.rope_theta, rms_eps=self.rms_eps, mesh=self.mesh, head_dim=self.head_dim,
         )
-        normed = nn.RMSNorm(epsilon=self.rms_eps, dtype=jnp.bfloat16, name="attention_norm")(x)
-        q = dense(heads * head_dim, "query")(normed).reshape(batch, seq, heads, head_dim)
-        k = dense(kv_heads * head_dim, "key")(normed).reshape(batch, seq, kv_heads, head_dim)
-        v = dense(kv_heads * head_dim, "value")(normed).reshape(batch, seq, kv_heads, head_dim)
-        offset = 0 if cache_k is None else index  # decode: rotate at absolute position
-        q = apply_rope(q, self.rope_theta, offset)
-        k = apply_rope(k, self.rope_theta, offset)
-        if cache_k is None:
-            if kv_heads != heads:  # grouped-query: each KV head serves heads/kv_heads queries
-                k = jnp.repeat(k, heads // kv_heads, axis=2)
-                v = jnp.repeat(v, heads // kv_heads, axis=2)
-            attn = mesh_attention_core(self.mesh, q, k, v, causal=True).reshape(batch, seq, hid)
-        else:
-            context, cache_k, cache_v = _decode_attention(
-                q, k, v, cache_k, cache_v, index, groups=heads // kv_heads
-            )
-            attn = context.reshape(batch, seq, hid)
-        x = x + dense(hid, "attention_out")(attn)
         normed = nn.RMSNorm(epsilon=self.rms_eps, dtype=jnp.bfloat16, name="ffn_norm")(x)
         inner = self.ffn_inner or -(-8 * hid // 3 // 8) * 8  # 8/3*hid rounded up to 8
-        gate = dense(inner, "ffn_gate")(normed)
-        up = dense(inner, "ffn_up")(normed)
-        y = (x + dense(hid, "ffn_down")(jax.nn.silu(gate) * up)).astype(jnp.float32)
+        gate = _plain_dense(inner, "ffn_gate")(normed)
+        up = _plain_dense(inner, "ffn_up")(normed)
+        y = (x + _plain_dense(hid, "ffn_down")(jax.nn.silu(gate) * up)).astype(jnp.float32)
+        return y if cache_k is None else (y, cache_k, cache_v)
+
+
+# the collection a block sows its routing into (flax `sow`): one [batch, seq, k]
+# int32 leaf of chosen experts per expert-layer call. The serving paths apply
+# every block with this collection mutable and count the live rows on the host
+# (`moe/server/routing_stats.py`); a block without experts sows nothing.
+ROUTING_COLLECTION = "routing"
+
+
+class OlmoeBlockExpert(nn.Module):
+    """One OLMoE decoder block on [batch, seq, hid] (Muennighoff et al. 2024, HF
+    `OlmoeDecoderLayer`): `LlamaBlockExpert`'s attention half (pre-RMSNorm, rotary
+    embedding, causal attention, the same `(cache_k, cache_v)` decode cache) with
+    RMS norms over the whole projected query and key widths before the split into
+    heads, then a sparse expert layer in place of the MLP: float32 router over
+    ``num_experts`` SwiGLU experts of width ``expert_inner``, the
+    ``experts_per_token`` largest router probabilities used as they are (not
+    renormalised), no shared expert, no biases.
+
+    The expert layer sees every token of the call together (`ops/sparse_experts`),
+    so in a batched decode step each chosen expert's weights are read once for all
+    the sessions' rows. The chosen experts are sown into `ROUTING_COLLECTION`.
+
+    ``head_dim`` is ``hidden_dim // num_heads``, as in `LlamaBlockExpert`; OLMoE's
+    2048 / 16 = 128 is its published head size. ``head_dim``, where given, must say
+    the same (asserted in `_rope_attention_half`)."""
+
+    hidden_dim: int
+    num_heads: int = 16
+    num_kv_heads: int = 0  # 0 = as many as query heads (OLMoE-1B-7B)
+    num_experts: int = 64
+    experts_per_token: int = 8
+    expert_inner: int = 1024  # one expert's width (OLMoE's `intermediate_size`)
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-5
+    head_dim: int = 0  # 0 = hidden_dim // num_heads; anything else must equal it
+
+    def init_decode_cache(self, batch: int, max_len: int):
+        return _empty_kv_cache(batch, max_len, self.num_kv_heads or self.num_heads, self.hidden_dim // self.num_heads)
+
+    @nn.compact
+    def __call__(self, x, cache_k=None, cache_v=None, index=None):
+        from hivemind_tpu.ops.sparse_experts import route_top_k, routed_swiglu
+
+        batch, seq, hid = x.shape
+        x, cache_k, cache_v = _rope_attention_half(
+            x, cache_k, cache_v, index, heads=self.num_heads, kv_heads=self.num_kv_heads or self.num_heads,
+            rope_theta=self.rope_theta, rms_eps=self.rms_eps, qk_norm=True, head_dim=self.head_dim,
+        )
+        normed = nn.RMSNorm(epsilon=self.rms_eps, dtype=jnp.bfloat16, name="ffn_norm")(x)
+        per_expert = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+        experts, inner = self.num_experts, self.expert_inner
+        router = self.param("router", nn.initializers.lecun_normal(), (hid, experts), jnp.float32)
+        w_gate = self.param("experts_gate", per_expert, (experts, hid, inner), jnp.float32)
+        w_up = self.param("experts_up", per_expert, (experts, hid, inner), jnp.float32)
+        w_down = self.param("experts_down", per_expert, (experts, inner, hid), jnp.float32)
+        tokens = normed.reshape(batch * seq, hid)  # the call's rows together
+        top_p, top_e = route_top_k(tokens, router, self.experts_per_token)
+        self.sow(ROUTING_COLLECTION, "expert_choice", top_e.reshape(batch, seq, -1))
+        with jax.named_scope("moe_experts"):
+            routed = routed_swiglu(tokens, top_p, top_e, w_gate, w_up, w_down)
+        y = (x + routed.reshape(batch, seq, hid)).astype(jnp.float32)
         return y if cache_k is None else (y, cache_k, cache_v)
 
 
@@ -233,4 +340,5 @@ register_expert_class("ffn", lambda batch, hid: np.zeros((batch, hid), np.float3
 register_expert_class("transformer", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))(TransformerExpert)
 register_expert_class("causal_transformer", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))(CausalTransformerExpert)
 register_expert_class("llama_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))(LlamaBlockExpert)
+register_expert_class("olmoe_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))(OlmoeBlockExpert)
 register_expert_class("nop", lambda batch, hid: np.zeros((batch, hid), np.float32))(NopExpert)

@@ -18,9 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from hivemind_tpu.compression import CompressionType
+from hivemind_tpu.moe.server.routing_stats import ROUTING_COLLECTION, record_routing
 from hivemind_tpu.telemetry.device import record_transfer
 from hivemind_tpu.telemetry.serving import accrue_span_phase
-from hivemind_tpu.telemetry.tracing import trace_sync as _trace_sync
+from hivemind_tpu.telemetry.tracing import current_span, trace_sync as _trace_sync
 from hivemind_tpu.utils.logging import get_logger
 from hivemind_tpu.utils.profiling import tracked_jit
 from hivemind_tpu.utils.tensor_descr import BatchTensorDescriptor
@@ -119,21 +120,24 @@ class ModuleBackend:
         # Neither closure may capture ``self``: a jitted function is a C++ object
         # the garbage collector does not look through, so the cycle backend ->
         # jitted closure -> backend would pin the expert's weights on the device
-        # for the life of the process.
+        # for the life of the process. Both hand out what the module sowed into
+        # ROUTING_COLLECTION (a sparse expert layer's chosen experts; empty otherwise).
         @tracked_jit(site="module_backend.forward")
         def _forward(params, *xs):
-            return _as_tuple(module.apply({"params": dense_params(params)}, *xs))
+            out, routing = module.apply({"params": dense_params(params)}, *xs, mutable=[ROUTING_COLLECTION])
+            return _as_tuple(out), routing
 
         @tracked_jit(site="module_backend.backward")
         def _backward(params, opt_state, xs, grad_outs):
             import optax
 
-            out, vjp = jax.vjp(lambda p, xx: module.apply({"params": p}, *xx), params, tuple(xs))
+            apply = lambda p, xx: module.apply({"params": p}, *xx, mutable=[ROUTING_COLLECTION])
+            out, vjp, routing = jax.vjp(apply, params, tuple(xs), has_aux=True)
             cotangent = _as_tuple(grad_outs) if outputs_are_tuple else grad_outs[0]
             grad_params, grad_xs = vjp(cotangent)
             updates, new_opt_state = optimizer.update(grad_params, opt_state, params)
             new_params = optax.apply_updates(params, updates)
-            return grad_xs, new_params, new_opt_state
+            return grad_xs, new_params, new_opt_state, routing
 
         self._jit_forward, self._jit_backward = _forward, _backward
 
@@ -193,9 +197,10 @@ class ModuleBackend:
         n = padded[0][1]
         record_transfer(sum(int(p.nbytes) for p, _ in padded), "host_to_device")
         with _trace_sync("backend.device"):  # the jitted call until its result is ready
-            outs = jax.block_until_ready(self._jit_forward(self.snapshot_params(), *(p for p, _ in padded)))
+            outs, routing = jax.block_until_ready(self._jit_forward(self.snapshot_params(), *(p for p, _ in padded)))
         with _staging("backend.fetch"):
             results = [np.asarray(out)[:n] for out in outs]
+        record_routing(routing, "pool", current_span(), rows=n)  # onto the pool.batch span around this call
         record_transfer(sum(r.nbytes for r in results), "device_to_host")
         return results
 
@@ -221,7 +226,7 @@ class ModuleBackend:
         )
         with _trace_sync("backend.device"):
             with self._state_lock:
-                grad_xs, new_params, new_opt_state = self._jit_backward(
+                grad_xs, new_params, new_opt_state, routing = self._jit_backward(
                     self.params,
                     self.opt_state,
                     tuple(p for p, _ in padded_x),
@@ -232,6 +237,7 @@ class ModuleBackend:
             jax.block_until_ready(grad_xs)  # what the fetch below would wait for anyway
         with _staging("backend.fetch"):
             grads_out = [np.asarray(g)[:n] for g in grad_xs]
+        record_routing(routing, "pool", current_span(), rows=n)
         record_transfer(sum(g.nbytes for g in grads_out), "device_to_host")
         return grads_out
 

@@ -1,0 +1,59 @@
+"""Routing counts of sparse expert layers, from what a block's program hands out.
+
+A block with an expert layer sows the experts it chose into `ROUTING_COLLECTION`,
+one ``[batch, seq, k]`` int32 leaf per expert-layer call; the serving paths apply
+every block with that collection mutable and return it from their jits beside the
+block's output. Rows and positions that only pad a bucket are part of the program
+but not of the traffic: the caller says how many rows and positions are live, and
+only those are counted, here on the host. A block without experts hands out an
+empty collection and nothing is counted."""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import numpy as np
+
+from hivemind_tpu.moe.server.layers.common import ROUTING_COLLECTION
+from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
+
+__all__ = ["ROUTING_COLLECTION", "record_routing"]
+
+_PATH_HELP = "by serving path (batched / direct = decode sessions, pool = TaskPool forward and backward)"
+_LAYER_CALLS = _TELEMETRY.counter(
+    "hivemind_moe_expert_layer_calls_total", f"sparse expert-layer calls served, {_PATH_HELP}", ("path",))
+_ROUTED_PAIRS = _TELEMETRY.counter(
+    "hivemind_moe_routed_pairs_total",
+    f"(token, expert) pairs routed: live tokens x experts per token, padding excluded, {_PATH_HELP}", ("path",))
+_EXPERTS_HIT = _TELEMETRY.counter(
+    "hivemind_moe_experts_hit_total",
+    f"distinct experts with at least one live token, summed over expert-layer calls, {_PATH_HELP}", ("path",))
+_EXPERT_MAX_PAIRS = _TELEMETRY.counter(
+    "hivemind_moe_expert_max_pairs_total",
+    f"live tokens of the fullest expert, summed over expert-layer calls, {_PATH_HELP}", ("path",))
+
+
+def record_routing(routing, path: str, span=None, rows: Optional[int] = None,
+                   positions: Optional[int] = None) -> None:
+    """Count one call's routing onto the `hivemind_moe_*{path}` counters and, as
+    ``experts_hit`` and ``pairs``, onto ``span`` (the call's `decode.batch` /
+    `decode.direct` / `pool.batch`). ``rows`` / ``positions``: how many leading
+    rows and positions of each leaf are live (None = all)."""
+    leaves = jax.tree_util.tree_leaves(routing)
+    if not leaves:
+        return
+    pairs = hit = fullest = 0
+    for leaf in leaves:
+        chosen = np.asarray(leaf)[:rows, :positions].reshape(-1)
+        per_expert = np.bincount(chosen)
+        pairs += chosen.size
+        hit += int(np.count_nonzero(per_expert))
+        fullest += int(per_expert.max(initial=0))
+    _LAYER_CALLS.inc(len(leaves), path=path)
+    _ROUTED_PAIRS.inc(pairs, path=path)
+    _EXPERTS_HIT.inc(hit, path=path)
+    _EXPERT_MAX_PAIRS.inc(fullest, path=path)
+    if span is not None:
+        span.set("experts_hit", hit)
+        span.set("pairs", pairs)

@@ -1,0 +1,62 @@
+"""A sparse expert layer whose work follows the tokens routed: float32 router,
+top-k, and SwiGLU experts computed as grouped matmuls over the (token, slot) pairs
+sorted by expert (`jax.lax.ragged_dot`).
+
+The layer sees all tokens of a call together, ``[tokens, hidden]``: the pairs are
+sorted once, every expert that was chosen is one contiguous group of rows, and its
+weights are read once for the whole group, however many tokens chose it. An
+expert nobody chose is never read. The FLOPs are those of the routed pairs
+(tokens x k) up to the grouped matmul's tiling: XLA's TPU kernel walks the sorted
+rows in tiles of m rows and visits a tile once for every group that has rows in
+it, at most ``pairs / m + experts - 1`` visits of ``m x hidden x width`` each
+(v5e compiler, jax 0.9.0: m = 256 for up to 256 pairs, 512 at 16,384); rows of a
+visit that belong to another group are masked, not skipped. No group is padded by
+this code.
+
+On a TPU `ragged_dot` is the compiler's own grouped-matmul kernel
+(`ragged-dot-none`, with `ragged-dot-metadata` before it, in a device trace); it is
+differentiable (its transposes are ragged dots again), so `jax.vjp` goes through."""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+def route_top_k(tokens: jax.Array, router: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+    """Router probabilities in float32 over all experts and the k largest of them
+    per token, as they are (not renormalised): ``(top_p [tokens, k], top_e [tokens, k])``.
+    The matmul runs at the highest precision: on a TPU a float32 dot is otherwise
+    one bf16 pass, and near-ties between experts flip."""
+    logits = jnp.dot(tokens.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+
+
+def routed_swiglu(tokens: jax.Array, top_p: jax.Array, top_e: jax.Array,
+                  w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
+    """``sum_j top_p[t, j] * down_e(silu(gate_e(x_t)) * up_e(x_t))``, ``e = top_e[t, j]``.
+
+    :param tokens: [tokens, hidden]
+    :param w_gate, w_up: [experts, hidden, width]; w_down: [experts, width, hidden]
+    :returns: [tokens, hidden] float32
+
+    The matmuls take activations rounded to bf16 and the float32 weights as they are
+    kept, and accumulate in float32: a TPU's default precision multiplies float32
+    operands in one bf16 pass, so this is the block's other matmuls' arithmetic
+    without a bf16 copy of every expert's weights written to memory at each call
+    (the CPU multiplies the same operands in float32)."""
+    count, k = top_e.shape
+    experts = w_gate.shape[0]
+    flat = top_e.reshape(-1)
+    order = jnp.argsort(flat, stable=True)  # pairs sorted by expert: one group each
+    sizes = jnp.bincount(flat, length=experts).astype(jnp.int32)
+    rounded = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
+    rows = rounded(tokens)[order // k]
+    gate = jax.lax.ragged_dot(rows, w_gate, sizes)
+    up = jax.lax.ragged_dot(rows, w_up, sizes)
+    down = jax.lax.ragged_dot(rounded(jax.nn.silu(gate) * up), w_down, sizes)
+    per_pair = down[jnp.argsort(order)].reshape(count, k, -1)  # back to [token, slot]
+    return jnp.einsum("tkh,tk->th", per_pair, top_p.astype(jnp.float32))

@@ -1040,3 +1040,58 @@ def test_decode_prefill_streams_over_unary_cap():
             client_dht.shutdown()
         server.shutdown()
         server.dht.shutdown()
+
+
+def test_custom_cached_block_steps_batched():
+    """A registered block with cache code of its own (not `_decode_attention`) served
+    through the batched step: `layers/__init__.py`'s contract. In a session's own
+    call ``index`` is a scalar, in a batched step a vector, one write position a row;
+    the block vmaps its per-row cache code over that vector itself. Its output is a
+    running mean of the session's inputs, which a wrong position shows at once."""
+    import flax.linen as nn
+
+    from hivemind_tpu.moe import register_expert_class
+    from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
+    from hivemind_tpu.moe.server.module_backend import ModuleBackend
+
+    @register_expert_class("running_mean_test_block", lambda batch, hid: np.zeros((batch, 8, hid), np.float32))
+    class RunningMeanBlock(nn.Module):
+        hidden_dim: int
+
+        def init_decode_cache(self, batch: int, max_len: int):
+            # cache_k: the inputs seen so far; cache_v: how many there are, by position
+            return jnp.zeros((batch, max_len, self.hidden_dim), jnp.float32), jnp.zeros((batch, max_len), jnp.float32)
+
+        @nn.compact
+        def __call__(self, x, cache_k=None, cache_v=None, index=None):
+            scale = self.param("scale", nn.initializers.ones, ())
+            if cache_k is None:
+                return x * scale
+
+            def one_session(x, seen, count, index):  # x [new_len, hid] written at a scalar index
+                seen = jax.lax.dynamic_update_slice(seen, x, (index, 0))
+                count = jax.lax.dynamic_update_slice(count, jnp.ones(x.shape[0]), (index,))
+                sums = jnp.cumsum(seen * count[:, None], axis=0)
+                means = sums / jnp.maximum(jnp.cumsum(count), 1.0)[:, None]
+                return jax.lax.dynamic_slice(means, (index, 0), x.shape) * scale, seen, count
+
+            if jnp.ndim(index) == 1:  # a batched step: every row a session at its own position
+                return jax.vmap(one_session)(x, cache_k, cache_v, index)
+            return jax.vmap(one_session, in_axes=(0, 0, 0, None))(x, cache_k, cache_v, index)
+
+    hid, lengths = 8, [2, 5, 3]
+    backend = ModuleBackend("mean.0", name_to_block["running_mean_test_block"](hid), optimizer=optax.sgd(0.0),
+                            sample_input=np.zeros((2, 8, hid), np.float32), max_batch_size=4)
+    manager = DecodeSessionManager({"mean.0": backend}, max_len=16)
+    x = np.random.RandomState(0).randn(len(lengths), 8, hid).astype(np.float32)
+    want = np.cumsum(x, axis=1) / np.arange(1, 9)[None, :, None]
+    for row, length in enumerate(lengths):
+        out = manager.decode("mean.0", f"row{row}", x[row:row + 1, :length], reset=True)
+        np.testing.assert_allclose(out, want[row:row + 1, :length], rtol=1e-5, atol=1e-6)
+    for step in range(2):  # 3 rows in a bucket of 4: one padding row
+        entries = [(None, manager._sessions[("mean.0", f"row{row}")], x[row:row + 1, length + step:length + step + 1])
+                   for row, length in enumerate(lengths)]
+        for row, (out, length) in enumerate(zip(manager._decode_batch("mean.0", entries), lengths)):
+            assert not isinstance(out, Exception), out
+            np.testing.assert_allclose(out, want[row:row + 1, length + step:length + step + 1], rtol=1e-5, atol=1e-6)
+    assert list(manager._batched_fns) == [("mean.0", 4)]

@@ -1,0 +1,90 @@
+"""Compile for a described (not attached) TPU v5e, at published widths, what the OLMoE
+block adds to the decode path: the sparse expert layer and one batched decode step.
+Nothing runs: this guards what the chip's compiler makes of the code (ISSUE 27) —
+`jax.lax.ragged_dot` stays the compiler's own grouped-matmul kernel on float32
+weights with no converted copy of the experts written to memory, and a batched step
+with 4,096-slot caches fits the chip. Times and results come from chip runs only.
+
+The topology is described inside a fixture (never at import: one process at a time may
+load the TPU's library, and every xdist worker imports every test file)."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HIDDEN, HEADS, EXPERTS, TOP_K, INNER, MAX_LEN = 2048, 16, 64, 8, 1024, 4096
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """Such a compile is written to the persistent cache but cannot be read back
+    without a chip; the next one would warn and compile again."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("tokens", [6, 32, 2048])
+def test_expert_layer_compiles_to_the_native_grouped_matmul(one_chip, no_compile_cache, tokens):
+    from hivemind_tpu.ops.sparse_experts import route_top_k, routed_swiglu
+
+    def layer(x, router, w_gate, w_up, w_down):
+        top_p, top_e = route_top_k(x, router, TOP_K)
+        return routed_swiglu(x, top_p, top_e, w_gate, w_up, w_down)
+
+    args = (_shape((tokens, HIDDEN), jnp.bfloat16, one_chip), _shape((HIDDEN, EXPERTS), jnp.float32, one_chip),
+            _shape((EXPERTS, HIDDEN, INNER), jnp.float32, one_chip), _shape((EXPERTS, HIDDEN, INNER), jnp.float32, one_chip),
+            _shape((EXPERTS, INNER, HIDDEN), jnp.float32, one_chip))
+    compiled = jax.jit(layer).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot-none") >= 3, "the three grouped matmuls are not the compiler's ragged-dot kernel"
+    # no bf16 (or any other) copy of an expert matrix among the temporaries: one is 268 MB in bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * tokens * TOP_K * HIDDEN * 4 * 8 + 64 * 2**20
+
+
+def test_batched_decode_step_of_eight_sessions_fits_the_chip(one_chip, no_compile_cache):
+    """Lowers the program `DecodeSessionManager._batched_fn` itself builds for a
+    bucket of 8 (no copy of its body here), over a backend that holds shapes only."""
+    from types import SimpleNamespace
+
+    from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    module = name_to_block["olmoe_block"](HIDDEN, num_heads=HEADS, num_experts=EXPERTS, experts_per_token=TOP_K, expert_inner=INNER)
+    rows = 8
+    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, HIDDEN), jnp.float32))["params"])
+    params = jax.tree_util.tree_map(lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), params)
+    cache = _shape((1, MAX_LEN, HEADS, HIDDEN // HEADS), jnp.bfloat16, one_chip)
+    manager = DecodeSessionManager({"blk.0": SimpleNamespace(module=module, dense_params=lambda p: p)}, max_len=MAX_LEN)
+
+    compiled = manager._batched_fn("blk.0", rows).jitted.lower(
+        params, _shape((rows, 1, HIDDEN), jnp.float32, one_chip), (cache,) * rows, (cache,) * rows,
+        _shape((rows,), jnp.int32, one_chip)).compile()
+    assert compiled.as_text().count("ragged-dot-none") >= 3
+    memory = compiled.memory_analysis()
+    # arguments: 1.68 GB of weights + 8 x 33.5 MB of caches; the program's own temporaries and outputs stay under 1.5 GB
+    assert memory.temp_size_in_bytes + memory.output_size_in_bytes < 1.5 * 2**30
