@@ -315,17 +315,15 @@ class ConnectionHandler(ServicerBase):
         if not session_id:
             raise ValueError("rpc_decode requires a session_id in request metadata")
         [x] = tensors
-        # span execution: chain consecutive co-located pipeline blocks' session
-        # steps in ONE rpc; each per-uid step still goes through decode_async, so
-        # cross-client continuous batching applies at every block of the span
+        # span execution: consecutive co-located pipeline blocks' session steps in
+        # ONE rpc and ONE call into the session manager, which batches per span
+        # chain: the steps of different clients that wait on this chain walk its
+        # blocks together (a cohort), one batched device call a block. Decode
+        # bypasses the pools: decode_span_async stamps the step's queue wait (the
+        # flush window or the cohort before it) and compute onto the serving span
         uids = self._span_uids(uid, metadata)
         reset = bool(meta.get("reset", False))
-        for span_uid in uids:
-            # decode bypasses the pools: decode_async stamps the step's queue wait
-            # (the continuous-batching flush window + the batch before it) and
-            # compute onto the serving span itself
-            x = await self.decode_sessions.decode_async(span_uid, str(session_id), x, reset)
-        return x
+        return await self.decode_sessions.decode_span_async(uids, str(session_id), x, reset)
 
     async def rpc_decode(self, request: runtime_pb2.ExpertRequest, context: P2PContext) -> runtime_pb2.ExpertResponse:
         """One KV-cache session step (decode_session.py). Metadata carries

@@ -11,21 +11,40 @@ token. Caches live on-device in the block's compact kv-heads layout
 (uid, batch, chunk-length) signature, and sessions expire by TTL / LRU cap so an
 abandoned client cannot pin device memory.
 
-**Continuous batching** (`decode_async`): single-token steps from different
-clients' sessions that arrive within a small window are merged into ONE device
-call — the block is applied once to all the rows, ``[rows, 1, hidden]``, with a
-VECTOR of write positions, and only its cache update and the attention over the
-cache run per row (`layers.common._decode_attention` vmaps itself over the rows):
-the block's matmuls, and a sparse expert layer above all, see the batch's rows
-together. What a block class owes this path (``index`` as a scalar in a session's
-own call, as a vector in a batched step) is written down in
-`moe/server/layers/__init__.py`. The session count is bucketed to powers of two so
-the jit cache stays small. Sessions keep their caches one array each; the batch's program takes them
-as they are, joins them, steps, and hands the new caches back one array a session,
-so a batch is ONE dispatch whatever its rows: the host only collects handles before it
-(``assemble``: one `np.stack` of the activations, one array of write positions)
-and assigns them after it (``scatter``). That is what keeps a serving chip busy
-when many clients decode one token at a time. Disable with
+**Continuous batching, per span chain** (`decode_span_async`; `decode_async` is the
+chain of one): a request names the chain of this server's blocks it crosses, and the
+chain, not the block, is the unit of batching. Single-token steps of different
+clients' sessions that wait on the same chain form a **cohort**, which walks the
+chain's blocks in ONE executor call: at each block ONE device call for all the
+rows — the block is applied once to ``[rows, 1, hidden]`` with a VECTOR of write
+positions, and only its cache update and the attention over the cache run per row
+(`layers.common._decode_attention` vmaps itself over the rows): the block's matmuls,
+and a sparse expert layer above all, see the cohort's rows together — and each row's
+output is the next block's input, left on the device: the next block's program is
+dispatched while this one runs (at most one program ahead), and only the chain's
+last output comes to the host. No event-loop turn, no future, no flush window and
+no transfer lie between two blocks; the futures resolve at the chain's end.
+
+Steps that arrive while a cohort is launched wait for the next one, which starts
+when this one's last block is dispatched, with all of them up to a full bucket
+(`_cohort_rows`: a program costs by the power of two its rows are padded to). This
+cohort's output is awaited and answered beside the next one's launch, so the device
+finds the next cohort's first program queued behind this one's last. The cohort
+under way is the window; ``flush_window`` is what a chain that was idle waits.
+Chains that differ but share blocks (``[b0…b7]`` beside ``[b4…b7]``) each batch
+among their own sessions and meet only at the session locks, taken per block in
+address order: correct, merely unmerged.
+
+What a block class owes this path (``index`` as a scalar in a session's own call, as
+a vector in a batched step) is written down in `moe/server/layers/__init__.py`. The
+session count is bucketed to powers of two so the jit cache stays small. Sessions
+keep their caches one array each; a block's program takes them as they are, joins
+them, steps, and hands the new caches back one array a session, so a block's batch
+is ONE dispatch whatever its rows: the host only collects handles before it
+(``assemble``: the activations — the last block's output as it is, or one
+`np.concatenate` of host rows through the upload program — and one array of write
+positions) and assigns them after it (``scatter``). That is what keeps a serving
+chip busy when many clients decode one token at a time. Disable with
 ``HIVEMIND_TPU_DECODE_BATCHING=0`` for A/B runs.
 
 No reference equivalent (the reference serves stateless experts; Petals is its
@@ -59,6 +78,8 @@ from hivemind_tpu.utils.profiling import tracked_jit
 
 logger = get_logger(__name__)
 
+Chain = Tuple[str, ...]  # the uids of this server's blocks that one request crosses, in order
+
 # KV-cache session saturation (ISSUE 9, docs/observability.md "Serving"): the
 # session table is the serving peer's scarcest resource (each session pins
 # device cache memory) and previously had zero visibility
@@ -90,7 +111,8 @@ _PHASE_SECONDS = _TELEMETRY.counter(
     "hivemind_moe_decode_phase_seconds_total",
     "host seconds of vmapped decode batches, by phase (assemble = collecting the rows' cache "
     "handles, one host array of activations and one of write positions; step = the one jitted "
-    "call, which stacks the caches, steps and unstacks them, until its output is on the host; "
+    "call, which stacks the caches, steps and unstacks them, until its output is on the host "
+    "(a cohort's block before its last: until it is dispatched); "
     "scatter = assigning each session its new caches)",
     ("phase",),
 )
@@ -101,6 +123,11 @@ _CALLS = _TELEMETRY.counter(
     ("path",),
 )
 _CALLS_BATCHED, _CALLS_DIRECT = _CALLS.labels("batched"), _CALLS.labels("direct")
+_COHORTS = _TELEMETRY.counter(
+    "hivemind_moe_decode_cohorts_total",
+    "cohorts of decode steps run: the steps that waited on one span chain, walked through "
+    "the chain's blocks in one executor call (one batched device call a block)",
+)
 
 
 @contextlib.contextmanager
@@ -122,6 +149,17 @@ def _next_pow2(n: int) -> int:
     return power
 
 
+def _cohort_rows(waiting: int) -> int:
+    """How many of ``waiting`` rows the next cohort takes. A batched program costs
+    by its bucket, the power of two its rows are padded to, almost as if every row
+    were live (each padding row has its caches joined, written and attended over
+    like a live one): 17 rows cost what 32 do, near twice what 16 do. So a cohort
+    that would pad more than a quarter of its bucket takes the full bucket below
+    instead; the rows left over are the first of the next cohort."""
+    bucket = _next_pow2(waiting)
+    return waiting if 4 * waiting >= 3 * bucket else bucket // 2
+
+
 class _Session:
     __slots__ = ("cache_k", "cache_v", "index", "last_used", "lock", "batch_started")
 
@@ -130,9 +168,50 @@ class _Session:
         self.index = 0
         self.last_used = time.monotonic()
         # perf_counter at which the batch carrying this session's pending step
-        # began to run: the end of that step's queue wait (decode_async)
+        # began to run: the end of that step's queue wait (decode_span_async)
         self.batch_started = 0.0
         self.lock = threading.Lock()
+
+
+class _Output:
+    """What one batched program handed back: its output ``y`` ``[bucket, 1, hidden]``
+    still on the device (the leading ``rows`` rows are live, in the order of the
+    batch's live entries), the routing it sowed, and once somebody needed it on the
+    host, that copy."""
+
+    __slots__ = ("y", "routing", "rows", "on_host", "settled")
+
+    def __init__(self, y, routing, rows: int):
+        self.y, self.routing, self.rows = y, routing, rows
+        self.on_host: Optional[np.ndarray] = None
+        self.settled = False
+
+    def host(self) -> np.ndarray:
+        if self.on_host is None:
+            self.on_host = np.asarray(self.y)
+            record_transfer(self.on_host.nbytes, "device_to_host")
+        return self.on_host
+
+    def settle(self, span=None) -> None:
+        """Wait for the program and count its routing (once): the routing's values
+        are on the device until then."""
+        if not self.settled:
+            self.settled = True
+            record_routing(self.routing, "batched", span, rows=self.rows)
+            self.y.block_until_ready()
+
+
+class _Row:
+    """One live row of a batched program's output, left on the device: what a
+    cohort hands from a block to the next in place of a host array."""
+
+    __slots__ = ("output", "row")
+
+    def __init__(self, output: _Output, row: int):
+        self.output, self.row = output, row
+
+    def host(self) -> np.ndarray:
+        return self.output.host()[self.row:self.row + 1]
 
 
 class DecodeSessionManager:
@@ -157,10 +236,13 @@ class DecodeSessionManager:
         self._batched_fns: Dict[Tuple[str, int], callable] = {}
         self._dummy_caches: Dict[str, tuple] = {}  # per-uid padding rows for pow2 buckets
         self._lock = threading.Lock()
-        self._pending: Dict[str, List] = {}  # uid -> [(future, session, x), ...]
-        self._in_flight: Dict[int, int] = {}  # id(session) -> refcount, during _decode_batch
-        self._drainers: Dict[str, asyncio.Task] = {}
+        # both keyed by the span chain (the tuple of uids a request crosses)
+        self._pending: Dict[Chain, List] = {}  # chain -> [(future, [the session of each uid], x), ...]
+        self._in_flight: Dict[int, int] = {}  # id(session) -> refcount, during a cohort
+        self._drainers: Dict[Chain, asyncio.Task] = {}
         self.batching_enabled = os.environ.get("HIVEMIND_TPU_DECODE_BATCHING", "1") != "0"
+        # host activations -> the device, one program a bucket (`_device_rows`)
+        self._upload = tracked_jit(lambda xs: xs, site="decode_session.upload")
 
     def supports(self, uid: str) -> bool:
         backend = self.backends.get(uid)
@@ -172,11 +254,12 @@ class DecodeSessionManager:
         # one mid-flight would orphan its cache object — the step would "succeed"
         # against the orphan and the client's next continuation would KeyError.
         # _in_flight covers the window after _drain pops entries out of _pending but
-        # before _decode_batch finishes (the device call itself).
+        # before their cohort finishes (the device calls themselves).
         pinned = {
             id(session)
             for entries in self._pending.values()
-            for (_future, session, _x) in entries
+            for (_future, sessions, _x) in entries
+            for session in sessions
         } | set(self._in_flight)
         expired = [
             k for k, s in self._sessions.items()
@@ -350,59 +433,75 @@ class DecodeSessionManager:
     # ---- continuous batching of single-token steps across sessions ------------
 
     async def decode_async(self, uid: str, session_id: str, x: np.ndarray, reset: bool):
-        """Asyncio entrypoint: batchable steps (continuation, chunk 1, session
-        batch 1) are merged with other clients' concurrent steps into one vmapped
-        device call; everything else takes the direct per-session path.
+        """One block's step: the span chain of one (`decode_span_async`)."""
+        return await self.decode_span_async((uid,), session_id, x, reset)
+
+    async def decode_span_async(self, uids, session_id: str, x: np.ndarray, reset: bool):
+        """Asyncio entrypoint: one session's step through the span chain ``uids``
+        (this server's blocks that the request crosses, in order). Batchable steps
+        (continuation, chunk 1, session batch 1) join the chain's next cohort, which
+        takes every block of the chain as one batched device call over the steps
+        that waited together; everything else takes the direct per-session path,
+        block by block.
 
         Stamps the step's phases onto the caller's ``serving.request`` span, as
         ``TaskPool.submit_task`` does for the pools: ``queue_wait_s`` from the
-        enqueue until the batch that carries the step starts to run (flush window
-        + the batch before it; a direct step has none), ``compute_s`` the rest."""
+        enqueue until the cohort that carries the step starts to run (the flush
+        window or the cohort before it; a direct step has none), ``compute_s`` the
+        rest."""
         started = time.perf_counter()
-        out, queue_wait = await self._submit_step(uid, session_id, x, reset)
+        out, queue_wait = await self._submit_step(tuple(uids), session_id, x, reset)
         if queue_wait:
             accrue_span_phase("queue_wait_s", queue_wait)
         accrue_span_phase("compute_s", time.perf_counter() - started - queue_wait)
         return out
 
-    async def _submit_step(self, uid: str, session_id: str, x: np.ndarray, reset: bool):
-        """`decode_async` without the attribution: (output, seconds queued)."""
+    def _decode_direct(self, chain: Chain, session_id: str, x: np.ndarray, reset: bool) -> np.ndarray:
+        for uid in chain:
+            x = self.decode(uid, session_id, x, reset)
+        return x
+
+    async def _submit_step(self, chain: Chain, session_id: str, x: np.ndarray, reset: bool):
+        """`decode_span_async` without the attribution: (output, seconds queued)."""
         loop = asyncio.get_running_loop()
         x = np.asarray(x, np.float32)
         batchable = (
             self.batching_enabled and not reset
             and x.ndim == 3 and x.shape[0] == 1 and x.shape[1] == 1
         )
+        if batchable:
+            with self._lock:
+                # a chain's sessions are opened together: its first block speaks for it
+                batchable = self._concurrent_sessions(chain[0])
         if not batchable:
-            return await loop.run_in_executor(None, self.decode, uid, session_id, x, reset), 0.0
-        with self._lock:
-            concurrent = self._concurrent_sessions(uid)
-        if not concurrent:
-            # single actively-decoding stream: the drainer/future/flush-window
-            # machinery has nothing to merge and costs ~ms per token — take the
-            # direct per-session path (same jitted step; same-session ordering
-            # is still serialized by the session lock). ISSUE 10.
-            return await loop.run_in_executor(None, self.decode, uid, session_id, x, reset), 0.0
+            # prefill, reset, session batch != 1, batching off — or a single
+            # actively-decoding stream: the drainer/future/flush-window machinery
+            # has nothing to merge and costs ~ms per token, so it takes the direct
+            # per-session path (same jitted step; same-session ordering is still
+            # serialized by the session lock). ISSUE 10.
+            return await loop.run_in_executor(None, self._decode_direct, chain, session_id, x, reset), 0.0
 
         enqueued = time.perf_counter()
         future = loop.create_future()
         with self._lock:
             # lookup + enqueue under ONE lock hold: releasing in between would let
-            # _evict_locked delete the session while this step is pending, so the
+            # _evict_locked delete a session while this step is pending, so the
             # step would update an orphaned cache and the next continuation KeyErrors
             self._evict_locked()  # the direct path evicts in decode(); mirror it here
-            session = self._sessions.get((uid, session_id))
-            if session is None:
+            sessions = [self._sessions.get((uid, session_id)) for uid in chain]
+            if None in sessions:
                 raise KeyError(
-                    f"unknown or expired decode session {session_id!r} for {uid!r}; "
-                    f"restart generation with reset=True"
+                    f"unknown or expired decode session {session_id!r} for "
+                    f"{chain[sessions.index(None)]!r}; restart generation with reset=True"
                 )
-            session.last_used = time.monotonic()
-            self._pending.setdefault(uid, []).append((future, session, x))
-            if uid not in self._drainers or self._drainers[uid].done():
-                self._drainers[uid] = spawn(self._drain(uid), name="decode_session.drain")
+            now = time.monotonic()
+            for session in sessions:
+                session.last_used = now
+            self._pending.setdefault(chain, []).append((future, sessions, x))
+            if chain not in self._drainers or self._drainers[chain].done():
+                self._drainers[chain] = spawn(self._drain(chain), name="decode_session.drain")
         out = await future
-        return out, max(session.batch_started - enqueued, 0.0)
+        return out, max(sessions[0].batch_started - enqueued, 0.0)
 
     # NOTE on merge_recency_s (set in __init__; HIVEMIND_TPU_MERGE_RECENCY_S):
     # another session counts as a merge candidate only if it stepped within
@@ -428,91 +527,176 @@ class DecodeSessionManager:
                     return True
         return False
 
-    async def _drain(self, uid: str) -> None:
+    def _pin_locked(self, entries: List, step: int) -> None:
+        """Move the eviction pins of ``entries``' sessions by ``step`` (+1 as they
+        leave `_pending` for a cohort, -1 when it has resolved). Under self._lock."""
+        for _future, sessions, _x in entries:
+            for session in sessions:
+                count = self._in_flight.get(id(session), 0) + step
+                if count > 0:
+                    self._in_flight[id(session)] = count
+                else:
+                    self._in_flight.pop(id(session), None)
+
+    async def _drain(self, chain: Chain) -> None:
+        """One chain's drainer: it waits the flush window once, for a chain that
+        was idle, then launches cohort after cohort until nothing is pending. Steps
+        that arrive while a cohort is launched see a live drainer and only enqueue;
+        the next cohort takes all of them the moment this one's last block is
+        dispatched — its output is awaited and its futures resolved beside that
+        (`_resolve`), so the device finds the next cohort's first program queued
+        behind this one's last."""
         loop = asyncio.get_running_loop()
+        held: List = []  # entries out of _pending whose sessions this drainer pins
+        resolving = set()  # the `_resolve` tasks of cohorts launched and not yet answered
         try:
             # the flush window exists to merge OTHER clients' concurrent steps;
-            # with a single actively-decoding session per uid it is pure
-            # per-token latency (2 ms/step measured) — skip straight to the
-            # drain (ISSUE 10)
+            # with a single actively-decoding session it is pure per-token
+            # latency (2 ms/step measured): then one loop tick, in which
+            # same-tick submitters still merge (ISSUE 10)
             with self._lock:
-                window = self.flush_window if self._concurrent_sessions(uid) else 0.0
-            if window:
-                await asyncio.sleep(window)  # let concurrent streams pile up
-            else:
-                await asyncio.sleep(0)  # one loop tick: same-tick submitters still merge
-        except asyncio.CancelledError:
-            # cancelled before the entries were even popped (server stop during the
-            # flush window): no pins were taken yet, but the pending futures would
-            # strand forever — cancel them so callers unblock
-            with self._lock:
-                stranded = self._pending.pop(uid, [])
-            for future, _session, _x in stranded:
-                if not future.done():
-                    future.cancel()
-            raise
-        with self._lock:
-            entries = self._pending.pop(uid, [])
-            for _future, session, _x in entries:
-                # keep the eviction pin through the device call: the entries leave
-                # _pending now but their caches are updated until the batch resolves
-                self._in_flight[id(session)] = self._in_flight.get(id(session), 0) + 1
-        if not entries:
-            return
-        # one session must not appear twice in a batch (its cache would fork):
-        # later duplicates roll over to the next drain round
-        seen, batch_entries, rollover = set(), [], []
-        for entry in entries:
-            if id(entry[1]) in seen:
-                rollover.append(entry)
-            else:
-                seen.add(id(entry[1]))
-                batch_entries.append(entry)
-        try:
-            error = None
-            try:
-                results = await loop.run_in_executor(None, self._decode_batch, uid, batch_entries)
-            except Exception as e:
-                error = e
-            for i, (future, _session, _x) in enumerate(batch_entries):
-                if future.done():
+                window = self.flush_window if self._concurrent_sessions(chain[0]) else 0.0
+            await asyncio.sleep(window)  # let concurrent streams pile up
+            while True:
+                with self._lock:
+                    # the entries leave _pending now, but their caches are updated
+                    # until the cohort resolves: keep them pinned against eviction
+                    arrived = self._pending.pop(chain, [])
+                    self._pin_locked(arrived, +1)
+                    held += arrived
+                if not held:
+                    if not resolving:
+                        return  # no await since the pop: a later submitter finds this task done
+                    await asyncio.wait(resolving, return_when=asyncio.FIRST_COMPLETED)
                     continue
-                result = error if error is not None else results[i]
-                if isinstance(result, Exception):
-                    future.set_exception(result)
-                else:
-                    future.set_result(result)
-            # steps that arrived WHILE the batch was computing (decode_async saw a
-            # live drainer and only enqueued) — and any same-session rollover — need
-            # a fresh drainer now, or they would strand until some future call
-            # happens to spawn one
-            with self._lock:
-                if rollover:
-                    self._pending.setdefault(uid, []).extend(rollover)
-                if self._pending.get(uid):
-                    self._drainers[uid] = spawn(self._drain(uid), name="decode_session.drain")
+                # one session must not appear twice in a cohort (its cache would
+                # fork): later duplicates roll over to the next one, and so do the
+                # rows past a full bucket (`_cohort_rows`)
+                seen, cohort, rollover = set(), [], []
+                for entry in held:
+                    ids = {id(session) for session in entry[1]}
+                    if ids & seen:
+                        rollover.append(entry)
+                    else:
+                        seen |= ids
+                        cohort.append(entry)
+                take = _cohort_rows(len(cohort))
+                cohort, rollover = cohort[:take], cohort[take:] + rollover
+                try:
+                    finish = await loop.run_in_executor(None, self._launch_cohort, chain, cohort)
+                except Exception as e:
+                    failed = [e] * len(cohort)
+                    finish = lambda: failed  # noqa: E731
+                held = rollover  # the cohort's futures and pins are its `_resolve` task's from here
+                task = spawn(self._resolve(cohort, finish), name="decode_session.resolve")
+                resolving.add(task)
+                task.add_done_callback(resolving.discard)
         except asyncio.CancelledError:
-            # drainer killed mid-batch (loop shutdown, server stop): nothing will
-            # ever resolve these futures or re-drain the rollover — cancel them so
-            # callers unblock instead of waiting forever. Steps that arrived WHILE
-            # the batch was computing only enqueued into _pending (they saw a live
-            # drainer), so they must be swept too or they strand and pin forever.
+            # drainer killed in its flush window or mid-cohort (loop shutdown,
+            # server stop): nothing will ever resolve these futures — cancel them
+            # so callers unblock instead of waiting forever. Steps that arrived
+            # WHILE a cohort was launched only enqueued into _pending (they saw a
+            # live drainer), so they are swept too or they strand and pin forever.
             with self._lock:
-                stranded = self._pending.pop(uid, [])
-            for future, _session, _x in batch_entries + rollover + stranded:
+                stranded = self._pending.pop(chain, [])
+            for future, _sessions, _x in held + stranded:
                 if not future.done():
                     future.cancel()
+            for task in resolving:
+                task.cancel()
             raise
         finally:
             # the eviction pins MUST drop on every exit path: a leaked pin makes the
             # session permanently unevictable
             with self._lock:
-                for _future, session, _x in entries:
-                    count = self._in_flight.get(id(session), 0) - 1
-                    if count > 0:
-                        self._in_flight[id(session)] = count
-                    else:
-                        self._in_flight.pop(id(session), None)
+                self._pin_locked(held, -1)
+
+    async def _resolve(self, cohort: List, finish) -> None:
+        """Await a launched cohort's results (``finish``, on the executor) and
+        answer its futures; its pins drop here, on every exit path."""
+        try:
+            try:
+                results = await asyncio.get_running_loop().run_in_executor(None, finish)
+            except Exception as e:
+                results = [e] * len(cohort)
+            for (future, _sessions, _x), result in zip(cohort, results):
+                if future.done():
+                    continue
+                if isinstance(result, Exception):
+                    future.set_exception(result)
+                else:
+                    future.set_result(result)
+        except asyncio.CancelledError:
+            for future, _sessions, _x in cohort:
+                if not future.done():
+                    future.cancel()
+            raise
+        finally:
+            with self._lock:
+                self._pin_locked(cohort, -1)
+
+    def _launch_cohort(self, chain: Chain, entries: List):
+        """Walk ``entries`` [(future, [the session of each uid], x)] through the
+        chain: at each block the rows still alive are one `_decode_batch`, and a
+        row's output there is its input at the next block — left on the device
+        (`_Row`), so that the next block's program is dispatched while this one's
+        runs. The walk runs at most one program ahead of the device: before block
+        k+1 is dispatched, block k-1 has finished (its new caches are the only ones
+        in flight beside block k's). A row that fails at a block keeps that
+        exception and leaves the cohort; the blocks after it never see it. Returns
+        ``finish``: called once, from any thread, it waits for the chain's last
+        program and returns one result (ndarray or Exception) per entry, in order."""
+        _COHORTS.inc()
+        results: List = [x for _future, _sessions, x in entries]
+        alive = list(range(len(entries)))
+        launched: List[_Output] = []
+
+        def fail(error: Exception) -> None:
+            # a block's program failed: every row still under way fails with it. If
+            # it failed at its own dispatch, its sessions are as they were; but the
+            # sessions of the blocks before it were handed outputs of programs that
+            # were still running, and what they point at may be unreadable now.
+            # Drop those (the clients' next continuations get the unknown-session
+            # KeyError and re-prefill)
+            if launched:
+                doomed = {id(session) for i in alive for session in entries[i][1]}
+                with self._lock:
+                    for key in [k for k, session in self._sessions.items() if id(session) in doomed]:
+                        del self._sessions[key]
+                    self._sample_gauges_locked()
+            for i in alive:
+                results[i] = error
+
+        def finish() -> List:
+            with _trace_sync("decode.fetch", rows=len(alive)):
+                try:
+                    for output in launched:
+                        output.settle()
+                    for i in alive:
+                        if isinstance(results[i], _Row):
+                            results[i] = results[i].host()
+                except Exception as e:
+                    fail(e)
+            return results
+
+        with _trace_sync("decode.cohort", rows=len(entries), chain_len=len(chain)):
+            try:
+                for depth, uid in enumerate(chain):
+                    batch = [(entries[i][0], entries[i][1][depth], results[i]) for i in alive]
+                    outs = self._decode_batch(uid, batch, fetch=False)
+                    for i, out in zip(alive, outs):
+                        results[i] = out
+                    alive[:] = [i for i in alive if not isinstance(results[i], Exception)]
+                    if not alive:
+                        break
+                    if isinstance(results[alive[0]], _Row):  # one program, one output for all its rows
+                        launched.append(results[alive[0]].output)
+                        if len(launched) > 1:
+                            launched[-2].settle()
+            except Exception as e:
+                fail(e)
+                alive.clear()
+        return finish
 
     def _batched_fn(self, uid: str, stack: int):
         """The one program of a batch of ``stack`` rows: it takes the rows' caches
@@ -551,16 +735,19 @@ class DecodeSessionManager:
             pair = self._dummy_caches[uid] = self._fresh_caches(self.backends[uid], 1)
         return pair
 
-    def _decode_batch(self, uid: str, entries: List) -> List:
-        """Run one vmapped step over `entries` [(future, session, x)]; returns one
-        result (ndarray or Exception) per entry, in order."""
+    def _decode_batch(self, uid: str, entries: List, fetch: bool = True) -> List:
+        """Run one batched step over `entries` [(future, session, x)]; returns one
+        result (ndarray or Exception) per entry, in order. An ``x`` is a host array
+        ``[1, 1, hidden]`` or a `_Row` (a cohort's row, still on the device); with
+        ``fetch=False`` (a cohort, mid-chain) the live rows come back as `_Row`s of
+        a program that may still be running."""
         started = time.perf_counter()
         for _future, session, _x in entries:
-            session.batch_started = started  # ends these steps' queue wait (decode_async)
+            session.batch_started = started  # ends these steps' queue wait (decode_span_async)
         with _trace_sync("decode.batch", uid=uid) as span:
-            return self._decode_batch_traced(uid, entries, span)
+            return self._decode_batch_traced(uid, entries, span, fetch)
 
-    def _decode_batch_traced(self, uid: str, entries: List, span) -> List:
+    def _decode_batch_traced(self, uid: str, entries: List, span, fetch: bool) -> List:
         backend = self.backends[uid]
         # per-session locks in a fixed order so the direct path cannot deadlock us
         ordered = sorted(range(len(entries)), key=lambda i: id(entries[i][1]))
@@ -590,6 +777,7 @@ class DecodeSessionManager:
                 # diverge); ISSUE 10 copy-free batching applied to decode
                 [i] = live
                 _future, session, x = entries[i]
+                x = x.host() if isinstance(x, _Row) else x
                 record_transfer(int(x.nbytes), "host_to_device")
                 y = self._advance(uid, session, backend, x, 1, 1)
                 session.index += 1
@@ -605,25 +793,25 @@ class DecodeSessionManager:
                 span.set("bucket", stack)
             _CALLS_BATCHED.inc()
             with _batch_phase("assemble"):
-                # handles only: the rows' caches go in as they are, the activations
-                # and write positions as one host array each
+                # handles only: the rows' caches go in as they are, the write
+                # positions as one host array, the activations as one array on the
+                # device: the last block's output where these are its rows, else one
+                # host array through the upload program
                 sessions = [entries[i][1] for i in live]
                 padding = stack - len(live)
                 dummy_k, dummy_v = self._dummy_rows(uid)
-                rows = [entries[i][2] for i in live]
-                xs = np.concatenate(rows + [np.zeros_like(rows[0])] * padding)  # [stack, 1, hidden]
+                xs = self._device_rows([entries[i][2] for i in live], stack)
                 # a padding row writes a valid mid-cache position; its output is discarded
                 indices = np.array([session.index for session in sessions] + [1] * padding, np.int32)
                 caches_k = tuple(session.cache_k for session in sessions) + (dummy_k,) * padding
                 caches_v = tuple(session.cache_v for session in sessions) + (dummy_v,) * padding
                 step = self._batched_fn(uid, stack)
-                # the caches are already resident: only the stacked activations count as h2d
-                record_transfer(int(xs.nbytes), "host_to_device")
             with _batch_phase("step"):
                 y, new_k, new_v, routing = step(backend.snapshot_params(), xs, caches_k, caches_v, indices)
-                y = np.asarray(y)
-                record_routing(routing, "batched", span, rows=len(live))
-            record_transfer(y.nbytes, "device_to_host")
+                output = _Output(y, routing, len(live))
+                if fetch:
+                    output.host()
+                    output.settle(span)
             _STEPS.inc(len(live), path="batched")
             with _batch_phase("scatter"):
                 now = time.monotonic()
@@ -631,8 +819,26 @@ class DecodeSessionManager:
                     session.cache_k, session.cache_v = new_k[row], new_v[row]
                     session.index += 1
                     session.last_used = now
-                    results[i] = y[row:row + 1]
+                    results[i] = output.host()[row:row + 1] if fetch else _Row(output, row)
             return results
         finally:
             for i in ordered:
                 entries[i][1].lock.release()
+
+    def _device_rows(self, rows: List, stack: int):
+        """The activations of a batch, ``[stack, 1, hidden]`` on the device. Where
+        ``rows`` are, in order, the live rows of ONE program's output of this
+        bucket, that output is taken as it is (its padding rows ride along: what a
+        padding row computes is discarded at every block). Otherwise one host array,
+        zero rows as padding, through
+        the upload program, so that a block's program meets its activations on the
+        device whoever calls it: a cohort mid-chain and the first block of a chain
+        reach the same compiled entry."""
+        first = rows[0]
+        if (isinstance(first, _Row) and first.output.rows == len(rows) and first.output.y.shape[0] == stack
+                and all(isinstance(x, _Row) and x.output is first.output and x.row == at for at, x in enumerate(rows))):
+            return first.output.y
+        rows = [x.host() if isinstance(x, _Row) else x for x in rows]
+        xs = np.concatenate(rows + [np.zeros_like(rows[0])] * (stack - len(rows)))
+        record_transfer(int(xs.nbytes), "host_to_device")  # the caches are already resident
+        return self._upload(xs)

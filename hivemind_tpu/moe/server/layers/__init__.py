@@ -20,7 +20,13 @@ in one of two ranks:
   ``jax.vmap`` the block's own per-row cache code when ``jnp.ndim(index) == 1``.
   A ``dynamic_update_slice`` or a position lookup written for a scalar fails or
   broadcasts wrongly there, and only in batched steps
-  (`tests/test_moe.py::test_custom_cached_block_steps_batched` is the pattern)."""
+  (`tests/test_moe.py::test_custom_cached_block_steps_batched` is the pattern).
+
+Which rows meet in a batched step is decided per SPAN CHAIN, not per block: the steps
+of the sessions that wait on the same chain of this server's blocks walk it together
+(`DecodeSessionManager.decode_span_async`), so a block's ``[rows, 1, hidden]`` input
+is the previous block's output for those same rows, in the same order, and a block
+may rely on nothing about which sessions share its step."""
 
 from hivemind_tpu.moe.server.layers.common import (
     CausalTransformerExpert,

@@ -1,0 +1,346 @@
+"""Decode batching per span chain (ISSUE 28): the steps of different sessions that
+wait on one chain of blocks form a cohort, which walks the chain in one executor
+call, one batched device call a block. `DecodeSessionManager` alone, no network."""
+
+import asyncio
+import threading
+
+import numpy as np
+import optax
+import pytest
+
+from hivemind_tpu.telemetry import REGISTRY
+from hivemind_tpu.telemetry.tracing import add_span_listener, remove_span_listener
+
+HID = 16
+CHAIN = ("coh.0", "coh.1", "coh.2")
+
+
+def _manager(uids=CHAIN, **kwargs):
+    from hivemind_tpu.moe import ModuleBackend
+    from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
+    from hivemind_tpu.moe.server.layers.common import CausalTransformerExpert
+
+    backends = {uid: ModuleBackend(uid, CausalTransformerExpert(hidden_dim=HID, num_heads=4), optimizer=optax.sgd(1e-3),
+                                   sample_input=np.zeros((1, 4, HID), np.float32), max_batch_size=8, rng_seed=seed)
+                for seed, uid in enumerate(uids)}
+    return DecodeSessionManager(backends, **{"max_len": 32, "max_sessions": 256, **kwargs})
+
+
+def _prefill(manager, chain, names, rng, length=3):
+    """Each name twice, from one prompt: the session the cohort steps, and under
+    "twin-<name>" the one the direct path steps for comparison."""
+    for name in names:
+        prompt = rng.randn(1, length, HID).astype(np.float32)
+        for session_id in (name, "twin-" + name):
+            manager._decode_direct(chain, session_id, prompt, True)
+
+
+def _counters():
+    steps, calls = REGISTRY.get("hivemind_moe_decode_steps_total"), REGISTRY.get("hivemind_moe_decode_calls_total")
+    return {"steps": steps.labels("batched").value, "calls": calls.labels("batched").value,
+            "direct_calls": calls.labels("direct").value, "cohorts": REGISTRY.get("hivemind_moe_decode_cohorts_total").value()}
+
+
+def _moved(before):
+    return {key: value - before[key] for key, value in _counters().items()}
+
+
+def _compiles():
+    from hivemind_tpu.telemetry.device import COMPILE_TRACKER
+
+    counts = COMPILE_TRACKER.counts()
+    return sum(counts.get("decode_session." + site, 0) for site in ("batched_step", "step", "upload"))
+
+
+class _Spans:
+    """The spans of this manager's blocks that finished inside the `with`."""
+
+    def __init__(self, chain):
+        self.chain, self.spans = chain, []
+
+    def __enter__(self):
+        add_span_listener(self.spans.append)
+        return self
+
+    def __exit__(self, *exc):
+        remove_span_listener(self.spans.append)
+
+    def cohorts(self):
+        batches = [s for s in self.spans if s.name == "decode.batch" and s.attributes["uid"] in self.chain]
+        parents = {s.parent_id for s in batches}
+        return [s for s in self.spans if s.name == "decode.cohort" and s.span_id in parents], batches
+
+
+def _step_together(manager, chain, tokens, timeout=60.0):
+    """One token a session, all submitted in one loop tick; returns {name: output or exception}."""
+    async def scenario():
+        outs = await asyncio.wait_for(asyncio.gather(
+            *(manager.decode_span_async(chain, name, token, False) for name, token in tokens.items()),
+            return_exceptions=True), timeout)
+        return dict(zip(tokens, outs))
+
+    return asyncio.run(scenario())
+
+
+@pytest.mark.parametrize("rows", [2, 6])
+def test_a_cohort_is_one_batched_call_a_block(rows):
+    manager, rng = _manager(), np.random.RandomState(rows)
+    names = [f"s{i}" for i in range(rows)]
+    _prefill(manager, CHAIN, names, rng)
+    tokens = {name: rng.randn(1, 1, HID).astype(np.float32) for name in names}
+    before = _counters()
+    with _Spans(CHAIN) as seen:
+        outs = _step_together(manager, CHAIN, tokens)
+    assert _moved(before) == {"steps": 3 * rows, "calls": 3, "direct_calls": 0, "cohorts": 1}
+    cohorts, batches = seen.cohorts()
+    assert [(c.attributes["rows"], c.attributes["chain_len"]) for c in cohorts] == [(rows, 3)]
+    assert [(b.attributes["uid"], b.attributes["rows"]) for b in batches] == [(uid, rows) for uid in CHAIN]
+    for name, token in tokens.items():
+        np.testing.assert_allclose(outs[name], manager._decode_direct(CHAIN, "twin-" + name, token, False), rtol=1e-5, atol=1e-5)
+    assert manager._in_flight == {} and not manager._pending.get(CHAIN)
+
+
+@pytest.mark.parametrize("waiting, cohorts", [(1, [1]), (3, [3]), (5, [4, 1]), (8, [8]), (11, [8, 3]), (12, [12]),
+                                              (17, [16, 1]), (23, [16, 7]), (24, [24]), (32, [32]), (40, [32, 8])])
+def test_a_cohort_fills_its_bucket_or_takes_the_one_below(waiting, cohorts):
+    """Rows past a full bucket wait for the next cohort unless they fill more than
+    half of what the next bucket adds: a program costs by its bucket."""
+    from hivemind_tpu.moe.server.decode_session import _cohort_rows
+
+    taken = []
+    while waiting:
+        taken.append(_cohort_rows(waiting))
+        waiting -= taken[-1]
+    assert taken == cohorts
+
+
+def test_rows_past_a_full_bucket_are_the_next_cohort():
+    manager, rng = _manager(), np.random.RandomState(7)
+    names = [f"s{i}" for i in range(5)]
+    _prefill(manager, CHAIN, names, rng)
+    tokens = {name: rng.randn(1, 1, HID).astype(np.float32) for name in names}
+    before = _counters()
+    with _Spans(CHAIN) as seen:
+        outs = _step_together(manager, CHAIN, tokens)
+    # four rows in the bucket of four, then the fifth alone: a lone row takes the per-session program
+    assert _moved(before) == {"steps": 3 * 4, "calls": 3, "direct_calls": 3, "cohorts": 2}
+    assert [c.attributes["rows"] for c in seen.cohorts()[0]] == [4, 1]
+    for name, token in tokens.items():
+        np.testing.assert_allclose(outs[name], manager._decode_direct(CHAIN, "twin-" + name, token, False), rtol=1e-5, atol=1e-5)
+    assert manager._in_flight == {} and not manager._pending.get(CHAIN)
+
+
+def test_the_chain_of_one_is_decode_async():
+    manager, rng = _manager(), np.random.RandomState(1)
+    uid = CHAIN[0]
+    _prefill(manager, (uid,), ["a", "b"], rng)
+    tokens = {name: rng.randn(1, 1, HID).astype(np.float32) for name in ("a", "b")}
+    before = _counters()
+
+    async def scenario():
+        return await asyncio.gather(*(manager.decode_async(uid, name, token, False) for name, token in tokens.items()))
+
+    with _Spans((uid,)) as seen:
+        outs = dict(zip(tokens, asyncio.run(scenario())))
+    assert _moved(before) == {"steps": 2, "calls": 1, "direct_calls": 0, "cohorts": 1}
+    assert [(c.attributes["rows"], c.attributes["chain_len"]) for c in seen.cohorts()[0]] == [(2, 1)]
+    assert list(manager._drainers) == [(uid,)]
+    for name, token in tokens.items():
+        np.testing.assert_allclose(outs[name], manager.decode(uid, "twin-" + name, token, reset=False), rtol=1e-5, atol=1e-5)
+
+
+def test_a_row_that_fails_mid_chain_leaves_the_cohort():
+    """Block 2 of 3 finds one session full: that row gets the error, block 3 never
+    sees it, the others finish as if it had not been there, and no pin stays."""
+    manager, rng = _manager(), np.random.RandomState(3)
+    names = ["s0", "s1", "s2"]
+    _prefill(manager, CHAIN, names, rng)
+    manager._sessions[(CHAIN[1], "s1")].index = manager.max_len
+    tokens = {name: rng.randn(1, 1, HID).astype(np.float32) for name in names}
+    before = _counters()
+    with _Spans(CHAIN) as seen:
+        outs = _step_together(manager, CHAIN, tokens)
+    assert isinstance(outs["s1"], ValueError) and "full" in str(outs["s1"])
+    assert _moved(before) == {"steps": 3 + 2 + 2, "calls": 3, "direct_calls": 0, "cohorts": 1}
+    assert [b.attributes["rows"] for b in seen.cohorts()[1]] == [3, 2, 2]
+    assert [manager._sessions[(uid, "s1")].index for uid in CHAIN] == [4, manager.max_len, 3]
+    for name in ("s0", "s2"):
+        np.testing.assert_allclose(outs[name], manager._decode_direct(CHAIN, "twin-" + name, tokens[name], False), rtol=1e-5, atol=1e-5)
+    assert manager._in_flight == {} and not manager._pending.get(CHAIN)
+    assert not any(session.lock.locked() for session in manager._sessions.values())
+
+
+def test_a_program_that_fails_on_the_device_drops_the_sessions_it_was_handed_to(monkeypatch):
+    """A cohort hands each block's new caches to the sessions while the program
+    still runs. If a program then fails, what those sessions point at cannot be
+    read: the rows fail and their sessions go, so that the clients re-prefill."""
+    from hivemind_tpu.moe.server import decode_session
+
+    manager, rng = _manager(), np.random.RandomState(8)
+    _prefill(manager, CHAIN, ["s0", "s1"], rng)
+    settle, calls = decode_session._Output.settle, []
+
+    def lost_on_the_second_wait(self, span=None):
+        calls.append(self)
+        if len(calls) == 2:
+            raise RuntimeError("device lost")
+        return settle(self, span)
+
+    monkeypatch.setattr(decode_session._Output, "settle", lost_on_the_second_wait)
+    outs = _step_together(manager, CHAIN, {name: rng.randn(1, 1, HID).astype(np.float32) for name in ("s0", "s1")})
+    monkeypatch.undo()
+    assert all(isinstance(out, RuntimeError) and "device lost" in str(out) for out in outs.values())
+    assert {key[1] for key in manager._sessions} == {"twin-s0", "twin-s1"}
+    assert manager._in_flight == {} and not any(session.lock.locked() for session in manager._sessions.values())
+    with pytest.raises(KeyError, match="unknown or expired"):
+        asyncio.run(manager.decode_span_async(CHAIN, "s0", rng.randn(1, 1, HID).astype(np.float32), False))
+
+
+def test_cancelling_the_drainer_mid_cohort_cancels_every_pending_future():
+    chain = CHAIN[:2]
+    manager, rng = _manager(chain), np.random.RandomState(4)
+    _prefill(manager, chain, ["s0", "s1", "late"], rng)
+    token = rng.randn(1, 1, HID).astype(np.float32)
+    entered, release, real_batch = threading.Event(), threading.Event(), manager._decode_batch
+
+    def stuck_at_the_second_block(uid, entries, **how):
+        if uid == chain[1]:
+            entered.set()
+            release.wait(10)
+        return real_batch(uid, entries, **how)
+
+    manager._decode_batch = stuck_at_the_second_block
+
+    async def scenario():
+        loop = asyncio.get_running_loop()
+        steps = [asyncio.create_task(manager.decode_span_async(chain, name, token, False)) for name in ("s0", "s1")]
+        await loop.run_in_executor(None, entered.wait, 10)
+        # arrives while the cohort runs: it finds a live drainer and only enqueues
+        steps.append(asyncio.create_task(manager.decode_span_async(chain, "late", token, False)))
+        await asyncio.sleep(0.01)
+        assert [len(entry[1]) for entry in manager._pending[chain]] == [2]
+        assert len(manager._in_flight) == 4  # two sessions a row, two rows in the cohort
+        drainer = manager._drainers[chain]
+        drainer.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await drainer
+        release.set()
+        for step in steps:
+            with pytest.raises(asyncio.CancelledError):
+                await step
+        assert manager._in_flight == {} and not manager._pending.get(chain)
+
+    asyncio.run(scenario())
+
+
+def test_chains_that_share_blocks_neither_deadlock_nor_mix_rows():
+    """[b0, b1, b2] beside [b1, b2], stepping concurrently: each chain batches among
+    its own sessions, and they meet only at the session locks."""
+    whole, tail = CHAIN, CHAIN[1:]
+    manager, rng = _manager(), np.random.RandomState(5)
+    _prefill(manager, whole, ["w0", "w1", "w2"], rng)
+    _prefill(manager, tail, ["t0", "t1"], rng, length=4)
+    chain_of = {"w0": whole, "w1": whole, "w2": whole, "t0": tail, "t1": tail}
+    before = _counters()
+
+    async def scenario(tokens):
+        return await asyncio.wait_for(asyncio.gather(
+            *(manager.decode_span_async(chain_of[name], name, token, False) for name, token in tokens.items())), 60.0)
+
+    for _round in range(3):
+        tokens = {name: rng.randn(1, 1, HID).astype(np.float32) for name in chain_of}
+        outs = dict(zip(tokens, asyncio.run(scenario(tokens))))
+        for name, token in tokens.items():
+            want = manager._decode_direct(chain_of[name], "twin-" + name, token, False)
+            np.testing.assert_allclose(outs[name], want, rtol=1e-5, atol=1e-5)
+    assert set(manager._drainers) == {whole, tail}
+    assert _moved(before) == {"steps": 3 * (3 * 3 + 2 * 2), "calls": 3 * (3 + 2), "direct_calls": 3 * (3 * 3 + 2 * 2), "cohorts": 6}
+    assert manager._in_flight == {} and not any(session.lock.locked() for session in manager._sessions.values())
+
+
+@pytest.fixture(scope="module")
+def warmed():
+    """A two-block manager after the warm-up of `perf/runners/block_server.py`
+    `warm_decode`, at tiny sizes: per block a prefill of each prompt length, the
+    single-session step, every full bucket and one short of the largest through
+    `_decode_batch`; then the table cleared."""
+    chain, slots, lengths = CHAIN[:2], 8, [3, 6]
+    manager = _manager(chain)
+    buckets = [2**k for k in range(1, slots.bit_length())]
+    token, shortest = np.zeros((1, 1, HID), np.float32), min(lengths)
+    for uid in chain:
+        for length in lengths:
+            manager.decode(uid, f"warm-len{length}", np.zeros((1, length, HID), np.float32), reset=True)
+        manager.decode(uid, f"warm-len{shortest}", token, reset=False)
+        names = [f"warm-row{i}" for i in range(max(buckets))]
+        for name in names:
+            manager.decode(uid, name, np.zeros((1, shortest, HID), np.float32), reset=True)
+        for rows in buckets + [max(buckets) - 1]:
+            entries = [(None, manager._sessions[(uid, name)], token) for name in names[:rows]]
+            assert not [out for out in manager._decode_batch(uid, entries) if isinstance(out, Exception)]
+        with manager._lock:
+            manager._sessions.clear()
+    return manager, chain, lengths
+
+
+@pytest.mark.parametrize("rows", [2, 3, 4, 5, 6, 7, 8])
+def test_a_warmed_server_compiles_nothing_for_a_cohort(warmed, rows):
+    """Every program a cohort runs is one the benchmark's warm-up reaches: the
+    `(uid, bucket)` batched programs, called with host arrays for the activations
+    and write positions and the sessions' own caches."""
+    from hivemind_tpu.moe.server.decode_session import _cohort_rows
+
+    manager, chain, lengths = warmed
+    rng = np.random.RandomState(rows)
+    names = [f"r{rows}-{i}" for i in range(rows)]
+    _prefill(manager, chain, names, rng, length=lengths[rows % 2])
+    compiles, programs = _compiles(), (len(manager._batched_fns), len(manager._step_fns))
+    for _step in range(2):  # fresh from the prefill, then with caches a cohort handed back
+        tokens = {name: rng.randn(1, 1, HID).astype(np.float32) for name in names}
+        before = _counters()
+        outs = _step_together(manager, chain, tokens)
+        first = _cohort_rows(rows)
+        batched = [taken for taken in (first, rows - first) if taken > 1]  # a lone row takes the per-session program
+        assert _moved(before) == {"steps": 2 * sum(batched), "calls": 2 * len(batched),
+                                  "direct_calls": 2 * (rows - sum(batched)), "cohorts": 1 + (rows > first)}
+        assert _compiles() == compiles and (len(manager._batched_fns), len(manager._step_fns)) == programs
+        for name, token in tokens.items():
+            np.testing.assert_allclose(outs[name], manager._decode_direct(chain, "twin-" + name, token, False), rtol=1e-5, atol=1e-5)
+
+
+def test_closed_loop_sessions_under_a_short_switch_interval_keep_their_streams():
+    """More sessions than cores, each sending its next token when the last came
+    back, so that cohorts are launched while others are fetched and answered: every
+    session's every token must equal its twin's on the direct path, and nothing
+    may stay pinned, pending or locked."""
+    import sys
+
+    sessions, steps = 24, 6
+    manager, rng = _manager(), np.random.RandomState(9)
+    names = [f"s{i}" for i in range(sessions)]
+    _prefill(manager, CHAIN, names, rng)
+    tokens = rng.randn(sessions, steps, 1, 1, HID).astype(np.float32)
+    before = _counters()
+
+    async def stream(i):
+        return [await manager.decode_span_async(CHAIN, names[i], tokens[i, step], False) for step in range(steps)]
+
+    async def scenario():
+        return await asyncio.wait_for(asyncio.gather(*(stream(i) for i in range(sessions))), 120.0)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        outs = asyncio.run(scenario())
+    finally:
+        sys.setswitchinterval(interval)
+    moved = _moved(before)
+    assert moved["steps"] + moved["direct_calls"] == 3 * sessions * steps  # every token crossed every block once
+    for i, name in enumerate(names):
+        for step in range(steps):
+            want = manager._decode_direct(CHAIN, "twin-" + name, tokens[i, step], False)
+            np.testing.assert_allclose(outs[i][step], want, rtol=1e-5, atol=1e-5)
+    assert manager._in_flight == {} and not manager._pending.get(CHAIN)
+    assert not any(session.lock.locked() for session in manager._sessions.values())
+    assert all(session.index == 3 + steps for session in manager._sessions.values())

@@ -921,7 +921,7 @@ def test_drain_cancellation_releases_pins_and_unblocks_callers():
 
     release, entered = threading.Event(), threading.Event()
 
-    def stuck_batch(uid, entries):
+    def stuck_batch(uid, entries, **how):
         entered.set()
         release.wait(10)
         raise RuntimeError("batch aborted")
@@ -933,7 +933,7 @@ def test_drain_cancellation_releases_pins_and_unblocks_callers():
             manager.decode_async("pin.0", sid, rng.randn(1, 1, 16).astype(np.float32), False)
         )
         await asyncio.get_running_loop().run_in_executor(None, entered.wait, 10)
-        drainer = manager._drainers["pin.0"]
+        drainer = manager._drainers[("pin.0",)]
         drainer.cancel()
         with pytest.raises(asyncio.CancelledError):
             await drainer
