@@ -104,6 +104,43 @@ def metric(name: str, snapshot: dict = None) -> dict:
     return {key.split("=", 1)[-1]: value for key, value in series.items()}
 
 
+def synthesize_checkpoint(path: Path, hidden: int, heads: int, kv_heads: int,
+                          inner: int, layers: int) -> None:
+    """A sharded HF-layout Llama checkpoint of random weights from seed 0, one shard a
+    layer (numpy and safetensors only: the parent calls it). The tests of the loader
+    write their checkpoints with it too."""
+    import numpy as np
+    from safetensors.numpy import save_file
+
+    rng = np.random.RandomState(0)
+    (path / "config.json").write_text(json.dumps({
+        "hidden_size": hidden, "num_attention_heads": heads,
+        "num_key_value_heads": kv_heads, "intermediate_size": inner,
+        "num_hidden_layers": layers, "rope_theta": 10000.0,
+        "rms_norm_eps": 1e-5,  # Llama-2's value, not the loader's default: it must reach the blocks
+    }))
+    head_dim = hidden // heads
+    weight_map = {}
+    scale = 1.0 / np.sqrt(hidden)
+    for layer in range(layers):
+        prefix = f"model.layers.{layer}."
+        tensors = {
+            prefix + "self_attn.q_proj.weight": rng.randn(heads * head_dim, hidden) * scale,
+            prefix + "self_attn.k_proj.weight": rng.randn(kv_heads * head_dim, hidden) * scale,
+            prefix + "self_attn.v_proj.weight": rng.randn(kv_heads * head_dim, hidden) * scale,
+            prefix + "self_attn.o_proj.weight": rng.randn(hidden, hidden) * scale,
+            prefix + "mlp.gate_proj.weight": rng.randn(inner, hidden) * scale,
+            prefix + "mlp.up_proj.weight": rng.randn(inner, hidden) * scale,
+            prefix + "mlp.down_proj.weight": rng.randn(hidden, inner) * scale,
+            prefix + "input_layernorm.weight": np.ones(hidden),
+            prefix + "post_attention_layernorm.weight": np.ones(hidden),
+        }
+        shard = f"model-{layer:05d}-of-{layers:05d}.safetensors"
+        save_file({k: v.astype(np.float32) for k, v in tensors.items()}, path / shard)
+        weight_map.update({name: shard for name in tensors})
+    (path / "model.safetensors.index.json").write_text(json.dumps({"weight_map": weight_map}))
+
+
 def rel_err(got, want) -> float:
     import numpy as np
 
@@ -219,8 +256,6 @@ def run_parent(args) -> int:
         device = report["device"]
         mesh_phase = "M" in phases and device["count"] >= 4
         if "C" in phases or mesh_phase:
-            from benchmarks.benchmark_llama_serving import synthesize_checkpoint  # numpy only
-
             (WORK / "checkpoint").mkdir()
             llama = sz["llama"]
             synthesize_checkpoint(WORK / "checkpoint", llama["hidden"], llama["heads"], llama["heads"],

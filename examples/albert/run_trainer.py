@@ -170,7 +170,7 @@ def main():
                 "time": time.time(),
             })
 
-    # reached max_steps (benchmarks/smoke runs): leave the swarm cleanly so the
+    # reached max_steps (smoke runs): leave the swarm cleanly so the
     # process actually exits instead of hanging on background threads
     final_text = f"{loss_ema:.4f}" if loss_ema is not None else "n/a"
     logger.info(f"training finished after {step} steps at epoch {opt.local_epoch}, final loss {final_text}")

@@ -55,9 +55,7 @@ _BANNED_SENDERS = _TELEMETRY.counter(
 # wire accounting for the averaging data path (docs/observability.md): serialized
 # tensor-part payload bytes crossing this peer's wall in each direction (parts it
 # ships + deltas it returns vs parts it receives as a reducer + deltas it gets
-# back), and the per-round effective throughput using the same fp32-equivalent
-# formula as benchmarks/benchmark_averaging.py — so the bench's headline number
-# can be cross-checked against internal accounting
+# back), and the per-round effective throughput in fp32-equivalent bytes
 _AVG_BYTES_SENT = _TELEMETRY.counter(
     "hivemind_averaging_bytes_sent_total", "serialized averaging payload bytes sent"
 )

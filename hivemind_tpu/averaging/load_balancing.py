@@ -15,6 +15,11 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+# at load, not inside optimize_parts_lp: the import takes a second or more, and the
+# first call is made on the averager's event loop in the middle of a round, which
+# the stall then loses to PROTOCOL_VIOLATION
+from scipy.optimize import linprog
+
 from hivemind_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -31,8 +36,6 @@ def optimize_parts_lp(vector_size: int, bandwidths: np.ndarray, min_size: int = 
 
     # variables: [f_0 … f_{n-1}, t]; minimize t
     # constraints: ((n-2)·f_i + 1) / b_i ≤ t  for active i;  Σf = 1;  f_i ≥ 0; f_inactive = 0
-    from scipy.optimize import linprog
-
     n = group_size
     c = np.zeros(n + 1)
     c[-1] = 1.0

@@ -119,7 +119,7 @@ class Matchmaking:
         # EVER been seen. Backoff applies to EVERY window expiry — under a
         # 32-peer declare storm that unconditional stretch is what lets the
         # swarm converge (gating it on per-window observations regressed the
-        # storm case to success 0.48, RESULTS.md) — but FIRST CONTACT resets it:
+        # storm case to success 0.48) — but FIRST CONTACT resets it:
         # a peer that started before its swarm may have ratcheted to the cap
         # while alone (harmless: nobody to match with), and must form its first
         # real group at the base lead time, not 30 s later (advisor r4)

@@ -45,9 +45,8 @@ from hivemind_tpu.utils.logging import get_logger
 logger = get_logger(__name__)
 
 # pre-compression part size. The reference default is 512 KiB (partition.py:17);
-# 2 MiB measures ~35% faster end-to-end on the loopback averaging benchmark (fewer
-# per-part serialize/frame/seal round trips for the same bytes — benchmarks/RESULTS.md
-# ISSUE 6) and still fits the mux message cap with fp32 headroom after compression.
+# 2 MiB means fewer per-part serialize/frame/seal round trips for the same bytes
+# (ISSUE 6) and still fits the mux message cap with fp32 headroom after compression.
 # Part boundaries do not affect numerics: per-element accumulation order is the same.
 DEFAULT_PART_SIZE_BYTES = 2**21
 

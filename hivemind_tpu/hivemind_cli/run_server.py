@@ -41,7 +41,7 @@ def main():
                              "serving RPC path (float16 halves wire bytes; "
                              "'none' = bit-identical fp32). Published in expert "
                              "info + DHT declarations so clients negotiate the "
-                             "same codec for requests; see docs/benchmarks.md")
+                             "same codec for requests")
     parser.add_argument("--client_rate", type=float, default=None,
                         help="fair-share admission (ISSUE 13): per-client token "
                              "budget in samples/s — a hot client past its bucket "

@@ -205,7 +205,7 @@ def make_train_step(config: AlbertConfig, optimizer, masked_loss_fraction: Optio
 
 
 def make_synthetic_mlm_batch(rng: jax.Array, config: AlbertConfig, batch_size: int, seq_len: int):
-    """Deterministic synthetic MLM data for benchmarks/tests (15% masking)."""
+    """Deterministic synthetic MLM data for the benchmark and tests (15% masking)."""
     ids_key, mask_key = jax.random.split(rng)
     labels = jax.random.randint(ids_key, (batch_size, seq_len), 0, config.vocab_size)
     mlm_mask = jax.random.bernoulli(mask_key, 0.15, (batch_size, seq_len))

@@ -138,6 +138,6 @@ def make_train_step(config: CausalLMConfig, optimizer):
 
 
 def make_synthetic_lm_batch(rng: jax.Array, config: CausalLMConfig, batch_size: int, seq_len: int):
-    """Deterministic synthetic token stream for benchmarks/tests."""
+    """Deterministic synthetic token stream for tests."""
     input_ids = jax.random.randint(rng, (batch_size, seq_len), 0, config.vocab_size)
     return {"input_ids": input_ids}

@@ -96,7 +96,7 @@ class RemoteSequential:
         self.dht, self.prefix, self.num_blocks = dht, prefix, num_blocks
         self.update_period, self.max_retries = update_period, max_retries
         # wire-dtype override for every block request; None = negotiate each
-        # server's advertised codec (ISSUE 10 — see docs/benchmarks.md)
+        # server's advertised codec (ISSUE 10)
         self.request_compression = request_compression
         # decode failover retains each session's input history for re-prefill; the
         # cap bounds client memory (past it, failover degrades to the pre-r4

@@ -30,7 +30,7 @@ when this one's last block is dispatched, with all of them up to a full bucket
 (`_cohort_rows`: a program costs by the power of two its rows are padded to). This
 cohort's output is awaited and answered beside the next one's launch, so the device
 finds the next cohort's first program queued behind this one's last. The cohort
-under way is the window; ``flush_window`` is what a chain that was idle waits.
+under way is the window; `FLUSH_WINDOW_S` is what a chain that was idle waits.
 Chains that differ but share blocks (``[b0…b7]`` beside ``[b4…b7]``) each batch
 among their own sessions and meet only at the session locks, taken per block in
 address order: correct, merely unmerged.
@@ -44,8 +44,7 @@ is ONE dispatch whatever its rows: the host only collects handles before it
 (``assemble``: the activations — the last block's output as it is, or one
 `np.concatenate` of host rows through the upload program — and one array of write
 positions) and assigns them after it (``scatter``). That is what keeps a serving
-chip busy when many clients decode one token at a time. Disable with
-``HIVEMIND_TPU_DECODE_BATCHING=0`` for A/B runs.
+chip busy when many clients decode one token at a time.
 
 No reference equivalent (the reference serves stateless experts; Petals is its
 downstream project — README.md:35-40). Fault note: decode sessions are sticky to
@@ -58,7 +57,6 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
-import os
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -79,6 +77,19 @@ from hivemind_tpu.utils.profiling import tracked_jit
 logger = get_logger(__name__)
 
 Chain = Tuple[str, ...]  # the uids of this server's blocks that one request crosses, in order
+
+# What the drainer of a chain that was idle waits for other clients' steps to pile
+# up: it exists only to merge concurrent streams, so a lone stream skips it.
+FLUSH_WINDOW_S = 0.002
+# Another session is a merge candidate only if it stepped within this window: an
+# actively decoding stream touches its session every token (tens of ms on one
+# serving hop), while an abandoned session would otherwise tax every single-stream
+# token with the flush window until TTL eviction. Tradeoff: in a DEEP pipeline each
+# server sees a session once per pipeline round, so with few concurrent streams and
+# a round time past this window, steps route direct and never merge (a rising
+# `path="direct"` share of hivemind_moe_decode_steps_total under concurrent load
+# is the telltale).
+MERGE_RECENCY_S = 0.25
 
 # KV-cache session saturation (ISSUE 9, docs/observability.md "Serving"): the
 # session table is the serving peer's scarcest resource (each session pins
@@ -223,14 +234,9 @@ class DecodeSessionManager:
     """
 
     def __init__(self, backends, max_len: int = 256, session_ttl: float = 600.0,
-                 max_sessions: int = 64, flush_window: float = 0.002,
-                 merge_recency_s: Optional[float] = None):
+                 max_sessions: int = 64):
         self.backends = backends
         self.max_len, self.session_ttl, self.max_sessions = max_len, session_ttl, max_sessions
-        self.flush_window = flush_window  # how long a drainer waits for stragglers
-        if merge_recency_s is None:
-            merge_recency_s = float(os.environ.get("HIVEMIND_TPU_MERGE_RECENCY_S", "0.25"))
-        self.merge_recency_s = merge_recency_s
         self._sessions: Dict[Tuple[str, str], _Session] = {}
         self._step_fns: Dict[Tuple[str, int, int], callable] = {}
         self._batched_fns: Dict[Tuple[str, int], callable] = {}
@@ -240,7 +246,6 @@ class DecodeSessionManager:
         self._pending: Dict[Chain, List] = {}  # chain -> [(future, [the session of each uid], x), ...]
         self._in_flight: Dict[int, int] = {}  # id(session) -> refcount, during a cohort
         self._drainers: Dict[Chain, asyncio.Task] = {}
-        self.batching_enabled = os.environ.get("HIVEMIND_TPU_DECODE_BATCHING", "1") != "0"
         # host activations -> the device, one program a bucket (`_device_rows`)
         self._upload = tracked_jit(lambda xs: xs, site="decode_session.upload")
 
@@ -420,7 +425,7 @@ class DecodeSessionManager:
             y = self._advance(uid, session, backend, x, padded_len, new_len)
             session.index += new_len
             # re-stamp AFTER the device step: a step that hits a jit compile can
-            # outlast merge_recency_s, and a session stamped only at entry would
+            # outlast MERGE_RECENCY_S, and a session stamped only at entry would
             # look stale to _concurrent_sessions the instant its own prefill
             # returns — so two freshly-prefilled streams never engage batching.
             # Bare float store; concurrent readers just see one of two recent stamps.
@@ -465,10 +470,7 @@ class DecodeSessionManager:
         """`decode_span_async` without the attribution: (output, seconds queued)."""
         loop = asyncio.get_running_loop()
         x = np.asarray(x, np.float32)
-        batchable = (
-            self.batching_enabled and not reset
-            and x.ndim == 3 and x.shape[0] == 1 and x.shape[1] == 1
-        )
+        batchable = not reset and x.ndim == 3 and x.shape[0] == 1 and x.shape[1] == 1
         if batchable:
             with self._lock:
                 # a chain's sessions are opened together: its first block speaks for it
@@ -503,17 +505,6 @@ class DecodeSessionManager:
         out = await future
         return out, max(sessions[0].batch_started - enqueued, 0.0)
 
-    # NOTE on merge_recency_s (set in __init__; HIVEMIND_TPU_MERGE_RECENCY_S):
-    # another session counts as a merge candidate only if it stepped within
-    # this window — an actively decoding stream touches its session every
-    # token (tens of ms on one serving hop), while an abandoned session would
-    # otherwise tax every single-stream token with the full flush window until
-    # TTL eviction. Tradeoff: in a DEEP pipeline each server sees a session
-    # once per pipeline round, so with few concurrent streams and a round time
-    # past this window, steps route direct and never merge — raise the env var
-    # there (a rising `path="direct"` share of hivemind_moe_decode_steps_total
-    # under concurrent load is the telltale).
-
     def _concurrent_sessions(self, uid: str) -> bool:
         """True when MORE THAN ONE recently-active session exists on this uid
         (so waiting the flush window could actually merge steps). Called under
@@ -521,7 +512,7 @@ class DecodeSessionManager:
         now = time.monotonic()
         count = 0
         for key, session in self._sessions.items():
-            if key[0] == uid and now - session.last_used < self.merge_recency_s:
+            if key[0] == uid and now - session.last_used < MERGE_RECENCY_S:
                 count += 1
                 if count > 1:
                     return True
@@ -555,7 +546,7 @@ class DecodeSessionManager:
             # latency (2 ms/step measured): then one loop tick, in which
             # same-tick submitters still merge (ISSUE 10)
             with self._lock:
-                window = self.flush_window if self._concurrent_sessions(chain[0]) else 0.0
+                window = FLUSH_WINDOW_S if self._concurrent_sessions(chain[0]) else 0.0
             await asyncio.sleep(window)  # let concurrent streams pile up
             while True:
                 with self._lock:

@@ -16,23 +16,14 @@ from hivemind_tpu.utils.asyncio_utils import spawn
 
 logger = get_logger(__name__)
 
-# layer-5 telemetry (docs/observability.md): per-pool throughput, batch latency
-# and drain-loop utilization — the registry replaces the old private per-Runtime
-# _stats dict, so one scrape sees the same numbers the periodic log line reports
-# (queue depth/age gauges live in task_pool.py, sampled on submit AND drain)
+# layer-5 telemetry (docs/observability.md): batch failures and drain-loop
+# utilization. Per-pool throughput and batch latency are counted by the pools
+# (task_pool.py, with the queue gauges), so one scrape sees the same numbers the
+# periodic log line reports
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 
-_BATCHES = _TELEMETRY.counter(
-    "hivemind_moe_batches_total", "batches processed by the runtime", ("pool",)
-)
-_SAMPLES = _TELEMETRY.counter(
-    "hivemind_moe_samples_total", "samples processed by the runtime", ("pool",)
-)
 _BATCH_FAILURES = _TELEMETRY.counter(
     "hivemind_moe_batch_failures_total", "batches whose processing function raised", ("pool",)
-)
-_BATCH_LATENCY = _TELEMETRY.histogram(
-    "hivemind_moe_batch_latency_seconds", "device time of one batch", ("pool",)
 )
 _UTILIZATION = _TELEMETRY.gauge(
     "hivemind_moe_runtime_utilization",
@@ -47,6 +38,12 @@ _WAIT_SECONDS = _TELEMETRY.counter(
     "task' to the next batch popped (on a profiler trace the same time is the idle "
     "time outside every pool.batch span)",
 )
+
+
+def _totals(pool: TaskPool) -> Tuple[float, float, float]:
+    """(batches, samples, seconds) the pool has counted; it counts them itself,
+    before a batch's callers see their results (task_pool.py)."""
+    return (pool.batches_counter.value, pool.samples_counter.value, pool.latency_histogram.sum)
 
 
 class Runtime:
@@ -64,23 +61,13 @@ class Runtime:
         self._utilization_window = 5.0
         self._busy_s = 0.0
         self._busy_anchor = time.perf_counter()
-        # cached metric children: pool names are stable for the Runtime's lifetime
-        self._children = {
-            pool.name: (
-                _BATCHES.labels(pool.name),
-                _SAMPLES.labels(pool.name),
-                _BATCH_LATENCY.labels(pool.name),
-            )
-            for pool in self.pools
-        }
         # cumulative (batches, samples, seconds) at the last report, per pool —
-        # the registry holds process-lifetime totals; the log line shows deltas.
+        # the pools' counters hold process-lifetime totals; the log line shows deltas.
         # Seeded from the CURRENT totals: the counters are process-global, so a
         # second Runtime reusing a pool name must not replay its predecessor's
         # work as one giant first interval.
         self._reported: Dict[str, Tuple[float, float, float]] = {
-            name: (batches.value, samples.value, latency.sum)
-            for name, (batches, samples, latency) in self._children.items()
+            pool.name: _totals(pool) for pool in self.pools
         }
 
     def start(self) -> None:
@@ -95,15 +82,7 @@ class Runtime:
             return
         self.pools.append(pool)
         self._pools_changed.set()
-        children = (
-            _BATCHES.labels(pool.name),
-            _SAMPLES.labels(pool.name),
-            _BATCH_LATENCY.labels(pool.name),
-        )
-        self._children[pool.name] = children
-        self._reported.setdefault(
-            pool.name, (children[0].value, children[1].value, children[2].sum)
-        )
+        self._reported.setdefault(pool.name, _totals(pool))
 
     async def _run(self) -> None:
         starved_since: Optional[float] = None  # the loop awaits here: a counter, not an annotation
@@ -132,7 +111,6 @@ class Runtime:
                 await asyncio.sleep(0.001)
                 continue
             batch = pool.pop_batch()
-            batches_c, samples_c, latency_h = self._children[pool.name]
             if not batch:
                 continue
             start = time.perf_counter()
@@ -147,11 +125,7 @@ class Runtime:
                 pool.fail_batch(batch, e)
                 self._account_busy(time.perf_counter() - start)
                 continue
-            elapsed = time.perf_counter() - start
-            batches_c.inc()
-            samples_c.inc(sum(t.batch_size for t in batch))
-            latency_h.observe(elapsed)
-            self._account_busy(elapsed)
+            self._account_busy(time.perf_counter() - start)
             self._maybe_report_stats()
 
     def _account_busy(self, elapsed: float) -> None:
@@ -174,9 +148,8 @@ class Runtime:
         if now - self._last_report < self.stats_report_interval:
             return
         self._last_report = now
-        for name in sorted(self._children):
-            batches_c, samples_c, latency_h = self._children[name]
-            totals = (batches_c.value, samples_c.value, latency_h.sum)
+        for pool in sorted(self.pools, key=lambda pool: pool.name):
+            name, totals = pool.name, _totals(pool)
             last = self._reported.get(name, (0.0, 0.0, 0.0))
             batches, samples, seconds = (t - l for t, l in zip(totals, last))
             self._reported[name] = totals

@@ -56,6 +56,18 @@ _OCCUPANCY = _TELEMETRY.histogram(
     ("pool",),
     buckets=(0.05, 0.1, 0.25, 0.5, 0.75, 0.9, 1.0),
 )
+# counted by the pool in its executor thread BEFORE a batch's futures resolve: a
+# caller that has its result always finds its batch counted, whatever becomes of
+# the Runtime's loop in between (a shutdown cancels it)
+_BATCHES = _TELEMETRY.counter(
+    "hivemind_moe_batches_total", "batches processed", ("pool",)
+)
+_SAMPLES = _TELEMETRY.counter(
+    "hivemind_moe_samples_total", "samples processed", ("pool",)
+)
+_BATCH_LATENCY = _TELEMETRY.histogram(
+    "hivemind_moe_batch_latency_seconds", "device time of one batch", ("pool",)
+)
 _CANCELLED_SKIPPED = _TELEMETRY.counter(
     "hivemind_moe_pool_cancelled_skipped_total",
     "queued tasks dropped at drain time because their caller already gave up "
@@ -136,6 +148,9 @@ class TaskPool:
         self._shed_counter = _SHEDS.labels(name)
         self._occupancy_histogram = _OCCUPANCY.labels(name)
         self._cancelled_counter = _CANCELLED_SKIPPED.labels(name)
+        self.batches_counter = _BATCHES.labels(name)
+        self.samples_counter = _SAMPLES.labels(name)
+        self.latency_histogram = _BATCH_LATENCY.labels(name)
         _LIVE_POOLS.add(self)
 
     def _event(self) -> asyncio.Event:
@@ -315,6 +330,9 @@ class TaskPool:
         stage_s = (span.attributes or {}).get("stage_s") if span is not None else None
         occupancy = round(total / max(self.max_batch_size, 1), 4)
         self._occupancy_histogram.observe(occupancy)
+        self.batches_counter.inc()
+        self.samples_counter.inc(total)
+        self.latency_histogram.observe(compute_end - assembly_start)
         offset = 0
         for task in tasks:
             size = task.batch_size

@@ -4,8 +4,8 @@ gate.
 The CPU test suite covers the kernels in interpret mode, but Mosaic compilation on
 a real TPU is a different code path (layout inference, VMEM allocation, tiling and
 dtype rules). `validate_kernels` runs the same checks either way: compiled
-(``interpret=False``: `chip_smoke.py` phase K and `bench.py`, at the shapes the main
-path feeds the kernels) or interpreted (`tests/test_device_tpu.py`, small shapes).
+(``interpret=False``: `chip_smoke.py` phase K, at the shapes the main path feeds the
+kernels) or interpreted (`tests/test_device_tpu.py`, small shapes).
 Nothing is caught here: a kernel that does not compile raises its own error, and a
 kernel that compiles but disagrees raises :class:`KernelCheckError`."""
 

@@ -356,6 +356,12 @@ class MuxConnection:
             except (asyncio.CancelledError, Exception):
                 pass
         await self._shutdown(None)
-        for task in list(self._handler_tasks):
+        # cancel AND await: a handler parked in an await only ends on a later loop
+        # iteration, and would otherwise outlive the connection (and P2P.shutdown).
+        # A handler may close its own connection: it cannot await itself.
+        handlers = list(self._handler_tasks)
+        for task in handlers:
             task.cancel()
+        me = asyncio.current_task()
+        await asyncio.gather(*(t for t in handlers if t is not me), return_exceptions=True)
         await self._channel.wait_closed()

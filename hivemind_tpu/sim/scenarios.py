@@ -6,7 +6,7 @@ over it, and returns a :class:`ScenarioResult` whose ``summary`` is
 **deterministic**: every value derives from virtual time, seeded RNG streams
 and message contents — never from wall clocks or memory addresses — so two
 runs with the same seed produce byte-identical canonical JSON (asserted by
-``benchmark_swarm_sim.py --smoke`` and tests/test_swarm_sim.py). Wall-time
+tests/test_swarm_sim.py). Wall-time
 facts (how fast the sim ran) live in ``diagnostics``, outside the digest.
 
 Scenarios:

@@ -494,7 +494,7 @@ class RoundLedger:
         return dict(items[:limit] if limit else items)
 
     def summary(self) -> Dict[str, Any]:
-        """Compact rollup for BENCH artifacts and the dashboard header: round
+        """Compact rollup for the dashboard header: round
         count plus mean/p95 of each phase — a perf regression's artifact then
         says WHERE the regression lives, not just the headline number."""
         records = self.records()

@@ -7,9 +7,8 @@ Zero dependencies, thread-safe, always-on. Design constraints (ISSUE 2):
   float op. Creating a child is a dict lookup under the metric lock. No string
   formatting happens until scrape/snapshot time.
 - **Always-on.** There is no enabled flag to check: recording into the registry
-  IS the disabled-exporter path, and it must stay within noise on
-  ``benchmark_slice_step_overhead.py`` (acceptance criterion). Rendering cost is
-  paid only by scrapers.
+  IS the disabled-exporter path, and it must stay within noise of a train step.
+  Rendering cost is paid only by scrapers.
 - **Prometheus-compatible.** Histograms keep cumulative ``le`` buckets plus
   ``_sum``/``_count``; the exporter (telemetry/exporter.py) renders the standard
   text exposition format.
@@ -278,7 +277,7 @@ class MetricsRegistry:
     def snapshot(self) -> Dict[str, dict]:
         """Compact JSON-able view: per metric, per label-tuple value (histograms:
         count/sum only — the swarm view aggregates totals, not shapes). This is
-        what the DHT publisher ships and what bench.py embeds in artifacts."""
+        what the DHT publisher ships and what the benchmark's readers take deltas of."""
         out: Dict[str, dict] = {}
         for metric in self.collect():
             series: Dict[str, object] = {}

@@ -372,7 +372,7 @@ class ServingLedger:
         return out
 
     def summary(self) -> Dict[str, Any]:
-        """Compact rollup for BENCH artifacts and the dashboard header: request
+        """Compact rollup for the dashboard header: request
         and shed counts, per-phase quantiles, batch occupancy, per-expert
         p50/p95 — a serving regression's artifact then says WHERE the
         regression lives (queue? device? serialize? one expert?)."""

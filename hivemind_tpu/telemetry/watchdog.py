@@ -311,7 +311,7 @@ def shutdown_all() -> None:
 
 
 def watchdog_summary() -> Dict[str, Any]:
-    """Rollup for BENCH artifacts and the dashboard: stall count, worst lag,
+    """Rollup for the dashboard: stall count, worst lag,
     and the loops being watched."""
     watchdogs = active_watchdogs()
     summary: Dict[str, Any] = {

@@ -1,12 +1,13 @@
-"""ICI tier of the two-tier backend: on-mesh reduction + host-boundary staging rate.
+"""Worker of `test_ici_averaging.py::test_streaming_staging_memory_bar_100m_params`: the
+ICI tier's host-boundary staging in a process of its own, so that its RSS is its own.
 
-Measures one full intra-peer averaging round of `MeshTensorBridge` — per-replica
-grads reduced with psum under shard_map (`mesh_mean`), one reduced fp32 copy staged
-to the host (`gather_to_host`), and the swarm-averaged result scattered back
+Runs full intra-peer averaging rounds of `MeshTensorBridge` — per-replica grads
+reduced with psum under shard_map (`mesh_mean`), one reduced fp32 copy staged to the
+host (`gather_to_host`), and the swarm-averaged result scattered back
 (`broadcast_scatter_from_host`) — the exact device↔host path `MeshAverager` runs per
-swarm round (averaging/ici.py). On real multi-chip hardware the reduce and the
-all-gather ride ICI; under `--platform cpu` with a virtual device mesh this records
-the host-emulation rate (a correctness/scaling harness, not an ICI bandwidth claim)."""
+swarm round (averaging/ici.py), and prints one JSON line with the RSS growth across
+the steady-state rounds. Under `--platform cpu` with a virtual device mesh its rate
+is the host emulation's (a correctness/scaling harness, not an ICI bandwidth claim)."""
 
 import os
 import sys

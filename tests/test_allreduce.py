@@ -116,6 +116,21 @@ def test_load_balancing():
     assert list(hagenbach_bischoff(10, np.array([0.5, 0.3, 0.2]))) == [5, 3, 2]
 
 
+@pytest.mark.parametrize("module, loads_scipy_optimize", [
+    ("hivemind_tpu.averaging.load_balancing", True), ("hivemind_tpu.moe.server.server", False)])
+def test_the_lp_solver_is_imported_when_the_averager_is_and_only_then(module, loads_scipy_optimize):
+    """`scipy.optimize` takes a second or more to import. The averager pays it as
+    it loads: inside `optimize_parts_lp` it fell on the event loop in the middle of
+    a process's first round, which that stall lost to PROTOCOL_VIOLATION. A block
+    server never loads the averager, and its start-up must not pay either."""
+    import subprocess
+    import sys
+
+    code = f"import sys, {module}; sys.exit(0 if ('scipy.optimize' in sys.modules) == {loads_scipy_optimize} else 7)"
+    run = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=120)
+    assert run.returncode == 0, run.stderr[-2000:] or f"scipy.optimize loaded: {not loads_scipy_optimize}"
+
+
 class _AllreduceHarness:
     """Minimal averager stand-in: registers rpc_aggregate_part per peer and routes
     streams to that peer's runner."""

@@ -16,22 +16,24 @@ HID = 16
 CHAIN = ("coh.0", "coh.1", "coh.2")
 
 
-def _manager(uids=CHAIN, **kwargs):
+def _manager(uids=CHAIN, block=None, hidden=HID, **kwargs):
+    """A manager over one backend a uid; ``block()`` makes a uid's module (default: the dense causal block)."""
     from hivemind_tpu.moe import ModuleBackend
     from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
     from hivemind_tpu.moe.server.layers.common import CausalTransformerExpert
 
-    backends = {uid: ModuleBackend(uid, CausalTransformerExpert(hidden_dim=HID, num_heads=4), optimizer=optax.sgd(1e-3),
-                                   sample_input=np.zeros((1, 4, HID), np.float32), max_batch_size=8, rng_seed=seed)
+    block = block or (lambda: CausalTransformerExpert(hidden_dim=hidden, num_heads=4))
+    backends = {uid: ModuleBackend(uid, block(), optimizer=optax.sgd(1e-3),
+                                   sample_input=np.zeros((1, 4, hidden), np.float32), max_batch_size=8, rng_seed=seed)
                 for seed, uid in enumerate(uids)}
     return DecodeSessionManager(backends, **{"max_len": 32, "max_sessions": 256, **kwargs})
 
 
-def _prefill(manager, chain, names, rng, length=3):
+def _prefill(manager, chain, names, rng, length=3, hidden=HID):
     """Each name twice, from one prompt: the session the cohort steps, and under
     "twin-<name>" the one the direct path steps for comparison."""
     for name in names:
-        prompt = rng.randn(1, length, HID).astype(np.float32)
+        prompt = rng.randn(1, length, hidden).astype(np.float32)
         for session_id in (name, "twin-" + name):
             manager._decode_direct(chain, session_id, prompt, True)
 
@@ -83,6 +85,20 @@ def _step_together(manager, chain, tokens, timeout=60.0):
     return asyncio.run(scenario())
 
 
+def test_decode_reads_no_environment_and_takes_no_option_that_nobody_sets():
+    """Every request takes the path the code chooses from what it observes (rows,
+    reset, recency): nothing outside the process and no caller can choose another."""
+    import inspect
+
+    from hivemind_tpu.moe.server import decode_session
+
+    assert list(inspect.signature(decode_session.DecodeSessionManager.__init__).parameters) == [
+        "self", "backends", "max_len", "session_ttl", "max_sessions"]
+    source = inspect.getsource(decode_session)
+    assert "environ" not in source and "getenv" not in source
+    assert (decode_session.FLUSH_WINDOW_S, decode_session.MERGE_RECENCY_S) == (0.002, 0.25)
+
+
 @pytest.mark.parametrize("rows", [2, 6])
 def test_a_cohort_is_one_batched_call_a_block(rows):
     manager, rng = _manager(), np.random.RandomState(rows)
@@ -129,6 +145,36 @@ def test_rows_past_a_full_bucket_are_the_next_cohort():
     for name, token in tokens.items():
         np.testing.assert_allclose(outs[name], manager._decode_direct(CHAIN, "twin-" + name, token, False), rtol=1e-5, atol=1e-5)
     assert manager._in_flight == {} and not manager._pending.get(CHAIN)
+
+
+@pytest.mark.parametrize("rows, batched_rows", [(2, 2), (3, 3), (5, 4)])
+def test_a_cohort_over_two_sparse_expert_blocks(rows, batched_rows):
+    """The OLMoE cell's path: a chain of two `olmoe_block`s. One batched call a block,
+    every row equal to the per-session path, and the routed pairs counted by live
+    rows: 3 rows ride a bucket of 4 (its padding row routes and is not counted),
+    the 5th of 5 rows is the next cohort and takes the per-session program."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, top_k, chain = 32, 2, ("moe.0", "moe.1")
+    manager = _manager(chain, hidden=hidden, block=lambda: name_to_block["olmoe_block"](
+        hidden, num_heads=4, num_experts=8, experts_per_token=top_k, expert_inner=16))
+    rng = np.random.RandomState(rows)
+    names = [f"s{i}" for i in range(rows)]
+    _prefill(manager, chain, names, rng, hidden=hidden)
+    tokens = {name: rng.randn(1, 1, hidden).astype(np.float32) for name in names}
+    pairs = REGISTRY.get("hivemind_moe_routed_pairs_total")
+    before, pairs_before = _counters(), {path: pairs.labels(path).value for path in ("batched", "direct")}
+    with _Spans(chain) as seen:
+        outs = _step_together(manager, chain, tokens)
+    lone = rows - batched_rows
+    assert _moved(before) == {"steps": 2 * batched_rows, "calls": 2, "direct_calls": 2 * lone, "cohorts": 1 + lone}
+    assert [(b.attributes["uid"], b.attributes["rows"]) for b in seen.cohorts()[1]] == (
+        [(uid, batched_rows) for uid in chain] + [(uid, 1) for uid in chain] * lone)
+    assert {path: pairs.labels(path).value - was for path, was in pairs_before.items()} == {
+        "batched": 2 * batched_rows * top_k, "direct": 2 * lone * top_k}
+    for name, token in tokens.items():
+        np.testing.assert_allclose(outs[name], manager._decode_direct(chain, "twin-" + name, token, False), rtol=2e-2, atol=2e-2)
+    assert manager._in_flight == {} and not manager._pending.get(chain)
 
 
 def test_the_chain_of_one_is_decode_async():

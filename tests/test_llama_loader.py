@@ -9,8 +9,7 @@ import time
 import numpy as np
 import optax
 import pytest
-from safetensors.numpy import save_file
-
+from chip_smoke import synthesize_checkpoint
 from hivemind_tpu.dht import DHT
 from hivemind_tpu.moe.server.llama_loader import (
     LlamaCheckpointConfig,
@@ -25,38 +24,24 @@ from hivemind_tpu.moe.server.server import Server
 HID, HEADS, KV_HEADS, INNER, LAYERS = 128, 4, 2, 352, 2
 
 
-def _write_checkpoint(tmp_path, seed=0):
+def _write_checkpoint(tmp_path):
     """A tiny sharded HF-layout Llama checkpoint: 2 layers across 2 shard files."""
-    rng = np.random.RandomState(seed)
-    cfg = {
-        "hidden_size": HID, "num_attention_heads": HEADS,
-        "num_key_value_heads": KV_HEADS, "intermediate_size": INNER,
-        "num_hidden_layers": LAYERS, "rope_theta": 10000.0,
-        "rms_norm_eps": 1e-5,  # Llama-2's value; must thread through to the blocks
-    }
-    (tmp_path / "config.json").write_text(json.dumps(cfg))
-    head_dim = HID // HEADS
-    weight_map = {}
-    for layer in range(LAYERS):
-        prefix = f"model.layers.{layer}."
-        scale = 1.0 / np.sqrt(HID)
-        tensors = {
-            prefix + "self_attn.q_proj.weight": rng.randn(HEADS * head_dim, HID) * scale,
-            prefix + "self_attn.k_proj.weight": rng.randn(KV_HEADS * head_dim, HID) * scale,
-            prefix + "self_attn.v_proj.weight": rng.randn(KV_HEADS * head_dim, HID) * scale,
-            prefix + "self_attn.o_proj.weight": rng.randn(HID, HID) * scale,
-            prefix + "mlp.gate_proj.weight": rng.randn(INNER, HID) * scale,
-            prefix + "mlp.up_proj.weight": rng.randn(INNER, HID) * scale,
-            prefix + "mlp.down_proj.weight": rng.randn(HID, INNER) * scale,
-            prefix + "input_layernorm.weight": np.ones(HID),
-            prefix + "post_attention_layernorm.weight": np.ones(HID),
-        }
-        shard = f"model-{layer:05d}-of-{LAYERS:05d}.safetensors"
-        save_file({k: v.astype(np.float32) for k, v in tensors.items()}, tmp_path / shard)
-        weight_map.update({name: shard for name in tensors})
-    (tmp_path / "model.safetensors.index.json").write_text(
-        json.dumps({"weight_map": weight_map})
-    )
+    synthesize_checkpoint(tmp_path, HID, HEADS, KV_HEADS, INNER, LAYERS)
+
+
+def test_synthesized_checkpoint_reads_as_gqa_and_sharded(tmp_path):
+    """The one writer of synthetic checkpoints (chip_smoke.py) against the loader's
+    reader: a shard a layer behind an index, and a config that loads as GQA with the
+    eps it was given."""
+    synthesize_checkpoint(tmp_path, hidden=64, heads=8, kv_heads=2, inner=96, layers=3)
+    index = json.loads((tmp_path / "model.safetensors.index.json").read_text())
+    assert len(set(index["weight_map"].values())) == 3  # genuinely sharded
+    config = LlamaCheckpointConfig.load(tmp_path)
+    assert (config.num_attention_heads, config.num_key_value_heads) == (8, 2)  # GQA
+    assert (config.num_hidden_layers, config.rms_norm_eps) == (3, 1e-5)
+    reader = ShardedSafetensorsReader(tmp_path)
+    assert sorted(reader.names()) == sorted(index["weight_map"])
+    assert reader.get("model.layers.2.self_attn.k_proj.weight").shape == (2 * 8, 64)
 
 
 def _local_reference(checkpoint_dir, x):
@@ -364,20 +349,10 @@ def test_predicted_block_bytes_match_measured_gqa(tmp_path):
     must match the measured resident bytes of a loaded block within 10%, for both
     fp32 and int8, at a GQA shape (hidden 1024, 4 layers, kv_heads < heads,
     sharded index)."""
-    import json as json_module
-
-    from benchmarks.benchmark_llama_serving import synthesize_checkpoint
-    from hivemind_tpu.moe.server.llama_loader import (
-        LlamaCheckpointConfig,
-        load_llama_blocks,
-        predict_block_param_bytes,
-    )
+    from hivemind_tpu.moe.server.llama_loader import predict_block_param_bytes
 
     synthesize_checkpoint(tmp_path, hidden=1024, heads=8, kv_heads=2, inner=2816, layers=4)
-    index = json_module.loads((tmp_path / "model.safetensors.index.json").read_text())
-    assert len(set(index["weight_map"].values())) == 4  # genuinely sharded
     config = LlamaCheckpointConfig.load(tmp_path)
-    assert config.num_key_value_heads < config.num_attention_heads  # GQA
 
     for quantization in (None, "int8"):
         predicted = predict_block_param_bytes(config, quantization)

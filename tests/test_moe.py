@@ -911,7 +911,6 @@ def test_drain_cancellation_releases_pins_and_unblocks_callers():
         sample_input=np.zeros((1, 4, 16), np.float32), max_batch_size=8,
     )
     manager = DecodeSessionManager({"pin.0": backend}, max_len=32)
-    assert manager.batching_enabled
     rng = np.random.RandomState(0)
     sid = uuid.uuid4().hex
     manager.decode("pin.0", sid, rng.randn(1, 4, 16).astype(np.float32), reset=True)
@@ -989,7 +988,6 @@ def test_decode_continuous_batching_many_clients():
         for hidden, session in zip(inputs, sessions):
             pipe.decode_step(hidden[:, :prompt], session, reset=True)
         manager = server.handler.decode_sessions
-        assert manager.batching_enabled
         fns_before = len(manager._batched_fns)
 
         def one_step(args):

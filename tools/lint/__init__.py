@@ -10,7 +10,7 @@ entry point (`hivemind-lint`, `lint.cli`) and one tier-1 pytest entry
   (``# lint: single-writer`` is an alias for ``allow(async-shared-state)``),
 - per-rule allowlist files under ``tools/lint/allowlists/<rule>.conf`` where
   every entry must carry a one-line justification,
-- ``--json`` output consumed by bench.py so lint debt lands in BENCH artifacts.
+- ``--json`` output: the machine-readable summary.
 
 See docs/static_analysis.md for the rule catalog and policy.
 """

@@ -2,8 +2,7 @@
 
 Exit status: 0 when clean; 1 on any unsuppressed finding OR any stale
 allowlist entry (an allowlist row whose finding no longer fires is debt that
-must be deleted, not carried). ``--json`` emits the machine-readable summary
-that bench.py embeds in BENCH artifacts.
+must be deleted, not carried). ``--json`` emits the machine-readable summary.
 """
 
 from __future__ import annotations
