@@ -2,6 +2,12 @@
 hivemind/moe/server/connection_handler.py:22-177 — there N forked handler processes;
 here one asyncio servicer feeding the task pools directly).
 
+The unit of pooling is the span chain that a request names (ISSUE 30): a forward or
+backward request for k consecutive co-located blocks is ONE task in the pool of that
+direction and chain, and the pool's batch walks the k blocks on the device in one
+executor call (`module_backend.forward_chain` / `backward_chain`): one upload and one
+fetch a request. A request for one block is a chain of one on the same path.
+
 Serving attribution (ISSUE 9): every expert RPC runs inside a ``serving.request``
 span — a child of the ``p2p.handle:`` span, which already joined the remote
 caller's trace via cross-peer propagation, so the request's phase decomposition
@@ -31,8 +37,9 @@ Serving data path (ISSUE 10, the PR 5 playbook applied to this layer):
 
 from __future__ import annotations
 
+import functools
 import time
-from typing import AsyncIterator, Dict, List, Optional, Tuple
+from typing import AsyncIterator, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -47,7 +54,7 @@ from hivemind_tpu.compression import (
     split_response_for_wire,
 )
 from hivemind_tpu.moe.expert_uid import IDEMPOTENT_CONNECTION_RPCS
-from hivemind_tpu.moe.server.module_backend import ModuleBackend
+from hivemind_tpu.moe.server.module_backend import ModuleBackend, backward_chain, forward_chain
 from hivemind_tpu.moe.server.task_pool import TaskPool
 from hivemind_tpu.p2p import P2P, P2PContext, ServicerBase
 from hivemind_tpu.proto import runtime_pb2
@@ -73,6 +80,9 @@ _STREAM_CHUNK = 2**20  # 1 MiB chunks inside stream replies
 # motivated off-loop codecs came from MULTI-MB payloads)
 _OFF_LOOP_CODEC_BYTES = 256 * 1024
 
+# what a pool's batch runs over its chain's backends, by the pool's direction
+_CHAIN_WALKS = {"forward": forward_chain, "backward": backward_chain}
+
 # cached metric children (one label value per role on this path)
 _SERVER_BYTES_SENT = WIRE_BYTES_SENT.labels("server")
 _SERVER_BYTES_RECEIVED = WIRE_BYTES_RECEIVED.labels("server")
@@ -92,8 +102,10 @@ class ConnectionHandler(ServicerBase):
 
         self.backends = backends
         self.activation_codec = resolve_activation_codec(activation_compression)
-        self.forward_pools: Dict[str, TaskPool] = {}
-        self.backward_pools: Dict[str, TaskPool] = {}
+        # one pool per direction and span chain, made on the chain's first request
+        # (a single block is a chain of one, made with its backend)
+        self._pools: Dict[Tuple[str, Tuple[str, ...]], TaskPool] = {}
+        self.on_new_pool: Optional[Callable[[TaskPool], None]] = None  # the Runtime's add_pool
         self._max_queue_size = max_queue_size
         self.decode_sessions = DecodeSessionManager(
             backends, max_len=decode_max_len, max_sessions=decode_max_sessions
@@ -106,28 +118,41 @@ class ConnectionHandler(ServicerBase):
             from hivemind_tpu.moe.server.admission import FairShareAdmission
 
             self.admission = FairShareAdmission(client_rate, burst=client_burst)
-        for name, backend in backends.items():
-            self._register_pools(name, backend)
+        for uid, backend in list(backends.items()):
+            self.add_backend(uid, backend)
 
-    def _register_pools(self, name: str, backend: ModuleBackend) -> None:
-        self.forward_pools[name] = TaskPool(
-            backend.forward, f"{name}_forward", max_batch_size=backend.max_batch_size,
-            max_queue_size=self._max_queue_size,
-        )
-        self.backward_pools[name] = TaskPool(
-            backend.backward, f"{name}_backward", max_batch_size=backend.max_batch_size,
-            max_queue_size=self._max_queue_size,
-        )
+    def chain_pool(self, direction: str, uids: Sequence[str]) -> TaskPool:
+        """The pool whose tasks are whole ``direction`` ("forward" / "backward")
+        requests for the chain ``uids``, and whose batches walk the chain on the
+        device in one executor call (`forward_chain` / `backward_chain`). Made on
+        the chain's first request and handed to the Runtime (``on_new_pool``).
+        Chains of consecutive co-located blocks number at most n(n+1)/2 a
+        direction; a request for a new chain beyond that many pools is refused."""
+        key = (direction, tuple(uids))
+        pool = self._pools.get(key)
+        if pool is None:
+            if len(self._pools) >= len(self.backends) * (len(self.backends) + 1):
+                raise ValueError(f"too many distinct span chains on this server ({len(self._pools)} pools)")
+            chain = [self.backends[uid] for uid in uids]
+            name = uids[0] if len(uids) == 1 else f"{uids[0]}..{uids[-1]}"
+            pool = self._pools[key] = TaskPool(
+                functools.partial(_CHAIN_WALKS[direction], chain), f"{name}_{direction}", blocks=len(chain),
+                max_batch_size=min(backend.max_batch_size for backend in chain),
+                max_queue_size=self._max_queue_size,
+            )
+            if self.on_new_pool is not None:
+                self.on_new_pool(pool)
+        return pool
 
-    def add_backend(self, uid: str, backend: ModuleBackend) -> List[TaskPool]:
-        """Register a backend acquired at runtime (expert replication): pools
-        are created here; the caller (Server.add_backend) hands them to the
-        Runtime and re-declares. Returns the new pools."""
-        if uid in self.backends and uid in self.forward_pools:
-            return []
+    def add_backend(self, uid: str, backend: ModuleBackend) -> None:
+        """Register a backend and make its single-block pools (a chain of one each
+        way). At runtime (expert replication) the caller, Server.add_backend,
+        re-declares; the pools reach the Runtime through ``on_new_pool``."""
+        if ("forward", (uid,)) in self._pools:
+            return
         self.backends[uid] = backend
-        self._register_pools(uid, backend)
-        return [self.forward_pools[uid], self.backward_pools[uid]]
+        for direction in ("forward", "backward"):
+            self.chain_pool(direction, (uid,))
 
     def _admit(self, context: P2PContext, tensors, kind: str) -> None:
         """Fair-share gate: draw this request's sample count from the calling
@@ -153,7 +178,7 @@ class ConnectionHandler(ServicerBase):
         return codec_name(self.activation_codec)
 
     def all_pools(self) -> List[TaskPool]:
-        return list(self.forward_pools.values()) + list(self.backward_pools.values())
+        return list(self._pools.values())
 
     @staticmethod
     def _serving_trace(kind: str, uid: str, context: P2PContext, tensors=None,
@@ -192,35 +217,17 @@ class ConnectionHandler(ServicerBase):
             info["decode_max_len"] = self.decode_sessions.max_len
         return runtime_pb2.ExpertInfoResponse(serialized_info=MSGPackSerializer.dumps(info))
 
-    async def _run_forward(self, uid: str, tensors: List[np.ndarray]) -> List[np.ndarray]:
-        pool = self.forward_pools.get(uid)
-        if pool is None:
-            raise KeyError(f"unknown expert {uid!r}")
-        backend = self.backends[uid]
-        assert len(tensors) == backend.num_inputs, (
-            f"expert {uid!r} takes {backend.num_inputs} tensors, got {len(tensors)}"
-        )
-        return await pool.submit_task(*tensors)
-
-    async def _run_backward(self, uid: str, tensors: List[np.ndarray]) -> List[np.ndarray]:
-        pool = self.backward_pools.get(uid)
-        if pool is None:
-            raise KeyError(f"unknown expert {uid!r}")
-        backend = self.backends[uid]
-        expected = backend.num_inputs + backend.num_outputs
-        assert len(tensors) == expected, (
-            f"expert {uid!r} backward takes {expected} tensors (inputs + output grads), got {len(tensors)}"
-        )
-        return await pool.submit_task(*tensors)
-
     def _span_uids(self, uid: str, metadata: bytes) -> List[str]:
         """Span execution: request metadata may name CONSECUTIVE co-located blocks
         (``{"uids": [...]}`` starting with the request uid) to run as one chain —
-        per-call round-trips for a pipeline drop from #blocks to #servers."""
+        one RPC, one pool task and one trip to the device and back per server
+        instead of per block. A request without the key is a chain of one."""
         meta = MSGPackSerializer.loads(metadata) if metadata else {}
         uids = meta.get("uids") or [uid]
         if uids[0] != uid:
             raise ValueError(f"span uids must start with the request uid {uid!r}, got {uids!r}")
+        if uid not in self.backends:
+            raise KeyError(f"unknown expert {uid!r}")
         for prev, nxt in zip(uids, uids[1:]):
             prev_backend, next_backend = self.backends.get(prev), self.backends.get(nxt)
             if prev_backend is None or next_backend is None:
@@ -232,25 +239,19 @@ class ConnectionHandler(ServicerBase):
                 )
         return uids
 
-    async def _run_forward_span(self, uids: List[str], tensors: List[np.ndarray]) -> List[np.ndarray]:
-        for span_uid in uids:
-            tensors = await self._run_forward(span_uid, tensors)
-        return tensors
-
-    async def _run_backward_span(self, uids: List[str], tensors: List[np.ndarray]) -> List[np.ndarray]:
-        """Chained backward: recover each block's inputs with a forward sweep, then
-        backpropagate block by block in reverse (every block's backward also steps
+    async def _run_span(self, direction: str, uids: List[str], tensors: List[np.ndarray]) -> List[np.ndarray]:
+        """One task for the whole chain: its pool's batch walks the blocks on the
+        device. Forward: the first block's inputs in, the last block's outputs out.
+        Backward: inputs and the last block's output gradients in, input gradients
+        out; the batch recovers each block's inputs with a forward sweep, then
+        backpropagates block by block in reverse (every block's backward also steps
         its optimizer — same semantics as per-block RPCs)."""
-        first = self.backends[uids[0]]
-        block_inputs, current = [], tensors[: first.num_inputs]
-        for span_uid in uids:
-            block_inputs.append(current)
-            if span_uid != uids[-1]:
-                current = await self._run_forward(span_uid, current)
-        grads = tensors[first.num_inputs:]
-        for span_uid, inputs in zip(reversed(uids), reversed(block_inputs)):
-            grads = await self._run_backward(span_uid, [*inputs, *grads])
-        return grads
+        expected = self.backends[uids[0]].num_inputs
+        if direction == "backward":
+            expected += self.backends[uids[-1]].num_outputs
+        if len(tensors) != expected:
+            raise ValueError(f"{direction} through {uids!r} takes {expected} tensors, got {len(tensors)}")
+        return await self.chain_pool(direction, uids).submit_task(*tensors)
 
     # ------------------------------------------------------------------ codecs
 
@@ -295,7 +296,7 @@ class ConnectionHandler(ServicerBase):
             uids = self._span_uids(request.uid, request.metadata)
             if span is not None and len(uids) > 1:
                 span.set("span_len", len(uids))
-            outputs = await self._run_forward_span(uids, inputs)
+            outputs = await self._run_span("forward", uids, inputs)
             return await self._respond(outputs)
 
     async def rpc_backward(self, request: runtime_pb2.ExpertRequest, context: P2PContext) -> runtime_pb2.ExpertResponse:
@@ -306,7 +307,7 @@ class ConnectionHandler(ServicerBase):
             uids = self._span_uids(request.uid, request.metadata)
             if span is not None and len(uids) > 1:
                 span.set("span_len", len(uids))
-            grads = await self._run_backward_span(uids, inputs)
+            grads = await self._run_span("backward", uids, inputs)
             return await self._respond(grads)
 
     async def _run_decode(self, uid: str, metadata: bytes, tensors: List[np.ndarray]) -> np.ndarray:
@@ -410,7 +411,7 @@ class ConnectionHandler(ServicerBase):
                 if tensors and getattr(tensors[0], "ndim", 0):
                     span.set("batch", int(tensors[0].shape[0]))
             self._admit(context, tensors, "forward")
-            outputs = await self._run_forward_span(self._span_uids(uid, metadata), tensors)
+            outputs = await self._run_span("forward", self._span_uids(uid, metadata), tensors)
             head = await self._serialize_head(outputs)
         async for message in self._stream_response(head, outputs[1:]):
             yield message
@@ -425,7 +426,7 @@ class ConnectionHandler(ServicerBase):
                 if tensors and getattr(tensors[0], "ndim", 0):
                     span.set("batch", int(tensors[0].shape[0]))
             self._admit(context, tensors, "backward")
-            grads = await self._run_backward_span(self._span_uids(uid, metadata), tensors)
+            grads = await self._run_span("backward", self._span_uids(uid, metadata), tensors)
             head = await self._serialize_head(grads)
         async for message in self._stream_response(head, grads[1:]):
             yield message

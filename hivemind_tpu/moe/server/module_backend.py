@@ -4,14 +4,22 @@ functions (capability parity: reference hivemind/moe/server/module_backend.py:19
 TPU-first: instead of the reference's dynamic torch batches, inputs are padded to
 power-of-two buckets so XLA compiles one executable per bucket; backward re-derives
 the forward under jax.vjp and applies the optimizer update in the same jitted call
-(the reference's on_backward semantics, module_backend.py:156-165)."""
+(the reference's on_backward semantics, module_backend.py:156-165).
+
+A backend has two levels of entry. `forward_on_device` / `backward_on_device` take
+and return device arrays of one bucket and wait for nothing; `forward` / `backward`
+are the numpy entry points around them (stage in, the device-level call, fetch and
+slice). A span request walks several co-located backends between ONE stage-in and
+ONE fetch (`forward_chain` / `backward_chain`, at the end of this file): the numpy
+entry points are chains of one."""
 
 from __future__ import annotations
 
 import contextlib
 import functools
 import threading
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from collections import deque
+from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +57,8 @@ def bucket_batch_size(n: int, max_batch_size: int) -> int:
 
 
 class ModuleBackend:
-    """See module docstring.
+    """See module docstring: one expert's weights, optimizer state and jitted
+    programs; the unit that a span chain walks on the device.
 
     :param module: a flax module; __call__ may take SEVERAL input arrays and return
         one array or a tuple of arrays (nested expert schemas, reference
@@ -143,14 +152,6 @@ class ModuleBackend:
 
     # ------------------------------------------------------------------ execution
 
-    def _pad(self, batch: np.ndarray) -> Tuple[jnp.ndarray, int]:
-        n = batch.shape[0]
-        bucket = bucket_batch_size(n, self.max_batch_size)
-        if bucket != n:
-            pad_width = [(0, bucket - n)] + [(0, 0)] * (batch.ndim - 1)
-            batch = np.pad(batch, pad_width)
-        return jnp.asarray(batch), n
-
     def _init_state(self, samples, rng_seed: int):
         """Create (params, opt_state); subclasses control placement (the mesh
         backend lands state directly under its shardings)."""
@@ -189,57 +190,41 @@ class ModuleBackend:
         with self._state_lock:
             return tree_param_bytes(self.params)
 
-    def forward(self, *inputs: np.ndarray) -> List[np.ndarray]:
-        """Inference on a concatenated batch (no parameter updates)."""
-        assert len(inputs) == self.num_inputs, (len(inputs), self.num_inputs)
-        with _staging("backend.stage_in"):  # widen and pad on the host, hand over for upload
-            padded = [self._pad(np.asarray(x, np.float32)) for x in inputs]
-        n = padded[0][1]
-        record_transfer(sum(int(p.nbytes) for p, _ in padded), "host_to_device")
-        with _trace_sync("backend.device"):  # the jitted call until its result is ready
-            outs, routing = jax.block_until_ready(self._jit_forward(self.snapshot_params(), *(p for p, _ in padded)))
-        with _staging("backend.fetch"):
-            results = [np.asarray(out)[:n] for out in outs]
-        record_routing(routing, "pool", current_span(), rows=n)  # onto the pool.batch span around this call
-        record_transfer(sum(r.nbytes for r in results), "device_to_host")
-        return results
-
-    def backward(self, *tensors: np.ndarray) -> List[np.ndarray]:
-        """Gradients wrt every input; ALSO applies one optimizer update to the expert
-        (reference on_backward: the server trains on every backward call).
-        ``tensors`` = the forward inputs followed by one grad per output."""
+    def check_trainable(self) -> None:
         if self.weight_quantization is not None:
             raise RuntimeError(
                 f"expert {self.name!r} serves int8 weight-only (inference-only): "
                 f"backward/training is not supported on quantized weights"
             )
-        assert len(tensors) == self.num_inputs + self.num_outputs, (
-            len(tensors), self.num_inputs, self.num_outputs,
-        )
-        with _staging("backend.stage_in"):
-            padded_x = [self._pad(np.asarray(x, np.float32)) for x in tensors[: self.num_inputs]]
-            padded_g = [self._pad(np.asarray(g, np.float32)) for g in tensors[self.num_inputs :]]
-        n = padded_x[0][1]
-        record_transfer(
-            sum(int(p.nbytes) for p, _ in padded_x) + sum(int(p.nbytes) for p, _ in padded_g),
-            "host_to_device",
-        )
-        with _trace_sync("backend.device"):
-            with self._state_lock:
-                grad_xs, new_params, new_opt_state, routing = self._jit_backward(
-                    self.params,
-                    self.opt_state,
-                    tuple(p for p, _ in padded_x),
-                    tuple(p for p, _ in padded_g),
-                )
-                self.params, self.opt_state = new_params, new_opt_state
-                self.update_count += 1
-            jax.block_until_ready(grad_xs)  # what the fetch below would wait for anyway
-        with _staging("backend.fetch"):
-            grads_out = [np.asarray(g)[:n] for g in grad_xs]
-        record_routing(routing, "pool", current_span(), rows=n)
-        record_transfer(sum(g.nbytes for g in grads_out), "device_to_host")
-        return grads_out
+
+    def forward_on_device(self, *xs):
+        """The jitted forward on device arrays of one bucket: dispatched, not waited
+        for. Returns (outputs, routing), both still on the device."""
+        return self._jit_forward(self.snapshot_params(), *xs)
+
+    def backward_on_device(self, xs, grad_outs):
+        """The jitted backward on device arrays of one bucket, dispatched and not
+        waited for; the expert's parameters and optimizer state are swapped for the
+        program's new ones and ``update_count`` steps, under the state lock.
+        Returns (input gradients, routing), both still on the device."""
+        self.check_trainable()
+        with self._state_lock:
+            grad_xs, new_params, new_opt_state, routing = self._jit_backward(
+                self.params, self.opt_state, tuple(xs), tuple(grad_outs)
+            )
+            self.params, self.opt_state = new_params, new_opt_state
+            self.update_count += 1
+        return grad_xs, routing
+
+    def forward(self, *inputs: np.ndarray) -> List[np.ndarray]:
+        """Inference on a concatenated batch (no parameter updates): a chain of one."""
+        return forward_chain((self,), *inputs)
+
+    def backward(self, *tensors: np.ndarray) -> List[np.ndarray]:
+        """Gradients wrt every input; ALSO applies one optimizer update to the expert
+        (reference on_backward: the server trains on every backward call).
+        ``tensors`` = the forward inputs followed by one grad per output. A chain of one."""
+        return backward_chain((self,), *tensors)
 
     # ------------------------------------------------------------------ metadata/state
 
@@ -290,3 +275,111 @@ class ModuleBackend:
                 self.params = restored["params"]
                 self.opt_state = restored["opt_state"]
             self.update_count = int(restored["updates"])
+
+
+# ---------------------------------------------------------------------- span chains
+#
+# A request names a chain of co-located blocks (one block is a chain of one). Its
+# tensors cross to the device once, every block's program runs on the previous
+# block's output where it lies, and the chain's result crosses back once. Activations
+# stay float32 between blocks and every block runs the very jits that its numpy entry
+# points run, so a client receives what per-block calls would have sent. Rows that
+# only pad the bucket are zeros on the way in and carry whatever the blocks make of
+# them from there on: no live row reads them, and their output gradients are zero at
+# every block, so no optimizer step does either.
+
+# How many programs a forward sweep may have dispatched beyond the one the device is
+# running. One keeps the device fed through the host's dispatch; more would only hold
+# more programs' outputs and temporaries at once. The reverse sweep runs none ahead:
+# it waits for a block's input gradient, and with it for the block's new parameters
+# (handed back beside the old ones), before it dispatches the block before, so a walk
+# holds what a per-block call holds plus the saved block inputs. One ahead there read
+# the same memory peak and the same rate on the chip (PERF.md §6, PR 30): not taken.
+_RUN_AHEAD = 1
+
+
+def _stage_in(tensors: Sequence[np.ndarray], max_batch_size: int) -> Tuple[List[jnp.ndarray], int]:
+    """Widen to float32, pad the rows to their bucket and hand over for upload;
+    counts the bytes that cross. Returns the device arrays and the live rows."""
+    staged, rows = [], tensors[0].shape[0]
+    bucket = bucket_batch_size(rows, max_batch_size)
+    for tensor in tensors:
+        batch = np.asarray(tensor, np.float32)
+        if bucket != rows:
+            batch = np.pad(batch, [(0, bucket - rows)] + [(0, 0)] * (batch.ndim - 1))
+        staged.append(jnp.asarray(batch))
+    record_transfer(sum(int(x.nbytes) for x in staged), "host_to_device")
+    return staged, rows
+
+
+def _fetch(arrays, rows: int) -> List[np.ndarray]:
+    with _staging("backend.fetch"):
+        results = [np.asarray(a)[:rows] for a in arrays]
+    record_transfer(sum(r.nbytes for r in results), "device_to_host")
+    return results
+
+
+def _dispatched(in_flight: Deque, outputs) -> None:
+    """``outputs`` belong to the forward program just dispatched: wait until the
+    device is at most `_RUN_AHEAD` programs behind it."""
+    in_flight.append(outputs)
+    while len(in_flight) > _RUN_AHEAD:
+        jax.block_until_ready(in_flight.popleft())
+
+
+def forward_chain(backends: Sequence[ModuleBackend], *inputs: np.ndarray) -> List[np.ndarray]:
+    """``inputs`` through every block of ``backends`` in turn: one upload, one
+    program a block, one fetch. Under the `pool.batch` span around it: one
+    `backend.stage_in`, one `backend.device` a block (the dispatch of its program and
+    the wait for the program before it; for the last block also the wait for the
+    chain's end), one `backend.fetch`."""
+    assert len(inputs) == backends[0].num_inputs, (len(inputs), backends[0].num_inputs)
+    with _staging("backend.stage_in"):
+        current, rows = _stage_in(inputs, min(b.max_batch_size for b in backends))
+    in_flight, routings = deque(), []
+    for backend in backends:
+        with _trace_sync("backend.device", uid=backend.name):
+            current, routing = backend.forward_on_device(*current)
+            routings.append(routing)
+            _dispatched(in_flight, current)
+            if backend is backends[-1]:
+                jax.block_until_ready(current)
+    results = _fetch(current, rows)
+    record_routing(routings, "pool", current_span(), rows=rows)  # onto the pool.batch span around this call
+    return results
+
+
+def backward_chain(backends: Sequence[ModuleBackend], *tensors: np.ndarray) -> List[np.ndarray]:
+    """Gradients wrt the chain's inputs, and one optimizer step of every block.
+    ``tensors`` = the first block's inputs followed by one gradient per output of the
+    last. A forward sweep over all blocks but the last keeps each block's input on the
+    device; the reverse sweep runs each block's backward on it, waits for the input
+    gradient and hands it on. Every block steps under its own lock, last block first,
+    as per-block calls would. A block that fails ends the walk there: the blocks behind
+    it in the chain have stepped, it and the blocks before it have not."""
+    num_inputs = backends[0].num_inputs
+    assert len(tensors) == num_inputs + backends[-1].num_outputs, (
+        len(tensors), num_inputs, backends[-1].num_outputs,
+    )
+    for backend in backends:  # before any block has stepped
+        backend.check_trainable()
+    with _staging("backend.stage_in"):
+        max_batch_size = min(b.max_batch_size for b in backends)
+        current, rows = _stage_in(tensors[:num_inputs], max_batch_size)
+        grads, _ = _stage_in(tensors[num_inputs:], max_batch_size)
+    in_flight, routings, block_inputs = deque(), [], []
+    for backend in backends[:-1]:
+        block_inputs.append(current)
+        with _trace_sync("backend.device", uid=backend.name, sweep="forward"):
+            current, routing = backend.forward_on_device(*current)
+            routings.append(routing)
+            _dispatched(in_flight, current)
+    block_inputs.append(current)
+    for backend in reversed(backends):
+        with _trace_sync("backend.device", uid=backend.name, sweep="backward"):
+            grads, routing = backend.backward_on_device(block_inputs.pop(), grads)
+            routings.append(routing)
+            jax.block_until_ready(grads)
+    results = _fetch(grads, rows)
+    record_routing(routings, "pool", current_span(), rows=rows)
+    return results

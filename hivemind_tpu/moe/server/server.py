@@ -61,6 +61,7 @@ class Server:
             client_rate=client_rate, client_burst=client_burst,
         )
         self.runtime = Runtime(self.handler.all_pools())
+        self.handler.on_new_pool = self.runtime.add_pool  # a span chain's pool is made on its first request
         self.checkpoint_saver = (
             CheckpointSaver(backends, checkpoint_dir) if checkpoint_dir is not None else None
         )
@@ -187,9 +188,7 @@ class Server:
         + runtime + an immediate declaration, so clients resolve the grown
         replica set without waiting a full update period. Runs on the server
         loop (the ReplicationManager's)."""
-        pools = self.handler.add_backend(uid, backend)
-        for pool in pools:
-            self.runtime.add_pool(pool)
+        self.handler.add_backend(uid, backend)  # its pools reach the runtime through on_new_pool
         declare_experts(
             self.dht, [uid],
             expiration_time=get_dht_time() + self.update_period * 3,

@@ -65,6 +65,11 @@ _BATCHES = _TELEMETRY.counter(
 _SAMPLES = _TELEMETRY.counter(
     "hivemind_moe_samples_total", "samples processed", ("pool",)
 )
+_BLOCKS = _TELEMETRY.counter(
+    "hivemind_moe_pool_blocks_total",
+    "blocks that the pool's batches walked: a span chain's length for every batch",
+    ("pool",),
+)
 _BATCH_LATENCY = _TELEMETRY.histogram(
     "hivemind_moe_batch_latency_seconds", "device time of one batch", ("pool",)
 )
@@ -116,7 +121,9 @@ class _Task:
 class TaskPool:
     """Collects tasks for one processing function; the Runtime drains the
     highest-priority pool (priority = oldest undispatched task, reference
-    task_pool.py:169-176)."""
+    task_pool.py:169-176). A server's pools are keyed by direction and span chain
+    (connection_handler.py): a task is one request for the whole chain, and
+    ``process_func`` walks the chain's ``blocks`` on the device in one call."""
 
     def __init__(
         self,
@@ -127,9 +134,11 @@ class TaskPool:
         min_batch_size: int = 1,
         flush_timeout: float = 0.1,
         max_queue_size: int = 1024,
+        blocks: int = 1,
     ):
         self.process_func = process_func
         self.name = name
+        self.blocks = blocks  # how many blocks one batch walks (the span chain's length)
         self.max_batch_size = max_batch_size
         self.min_batch_size = min_batch_size
         self.flush_timeout = flush_timeout  # sub-min batches run anyway after this age
@@ -150,6 +159,7 @@ class TaskPool:
         self._cancelled_counter = _CANCELLED_SKIPPED.labels(name)
         self.batches_counter = _BATCHES.labels(name)
         self.samples_counter = _SAMPLES.labels(name)
+        self._blocks_counter = _BLOCKS.labels(name)
         self.latency_histogram = _BATCH_LATENCY.labels(name)
         _LIVE_POOLS.add(self)
 
@@ -199,10 +209,9 @@ class TaskPool:
         if task.occupancy is not None:
             span = current_span()
             if span is not None:
-                # span-execution chains hit several pools: phases accumulate,
-                # but occupancy/pool keep the WORST-occupancy hop (the
-                # under-filled batch is the lever a reader wants named, and
-                # last-write-wins would point at an arbitrary hop)
+                # a request that went through several pools keeps the
+                # WORST-occupancy one (the under-filled batch is the lever a
+                # reader wants named; a span chain is one pool, so one hop)
                 previous = (span.attributes or {}).get("occupancy")
                 if previous is None or task.occupancy < float(previous):
                     span.set("occupancy", task.occupancy)
@@ -276,7 +285,7 @@ class TaskPool:
         zero-copy views. Called from the Runtime's executor thread via
         call_soon_threadsafe plumbing."""
         total = sum(t.batch_size for t in tasks)
-        with _trace_sync("pool.batch", pool=self.name, rows=total, tasks=len(tasks)) as span:
+        with _trace_sync("pool.batch", pool=self.name, rows=total, tasks=len(tasks), blocks=self.blocks) as span:
             self._process_batch(tasks, total, span)
 
     def _process_batch(self, tasks: List[_Task], total: int, span) -> None:
@@ -332,6 +341,7 @@ class TaskPool:
         self._occupancy_histogram.observe(occupancy)
         self.batches_counter.inc()
         self.samples_counter.inc(total)
+        self._blocks_counter.inc(self.blocks)
         self.latency_histogram.observe(compute_end - assembly_start)
         offset = 0
         for task in tasks:

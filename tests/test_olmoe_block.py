@@ -127,6 +127,26 @@ def test_input_gradient_through_module_backend():
     assert backend.update_count == 1
 
 
+def test_span_chain_counts_every_blocks_routing_once():
+    """A chain of two sparse blocks in one walk (ISSUE 30): the `path=pool` counters see
+    every expert-layer call of the walk, live rows only, and the values are the
+    per-block calls' own."""
+    from hivemind_tpu.moe.server.module_backend import backward_chain, forward_chain
+
+    chain = [make_backend("olmoe.0", seed=3), make_backend("olmoe.1", seed=4)]
+    x, grad = stream(3, 3, 16), stream(13, 3, 16)
+    before = counters("pool")
+    [got] = forward_chain(chain, x)
+    counted = delta(before, counters("pool"))
+    assert counted["expert_layer_calls"] == 2 and counted["routed_pairs"] == 2 * 3 * 16 * TOP_K
+    assert np.array_equal(got, chain[1].forward(chain[0].forward(x)[0])[0])
+    before = counters("pool")
+    [grad_x] = backward_chain(chain, x, grad)
+    counted = delta(before, counters("pool"))  # one forward sweep over the first block, two backwards
+    assert counted["expert_layer_calls"] == 3 and counted["routed_pairs"] == 3 * 3 * 16 * TOP_K
+    assert grad_x.shape == x.shape and [backend.update_count for backend in chain] == [1, 1]
+
+
 def test_prefill_and_single_token_steps_against_full_forward():
     backend = make_backend()
     manager = DecodeSessionManager({backend.name: backend}, max_len=32)

@@ -123,7 +123,43 @@ def test_scorecards_classify_outcomes():
     assert not is_overload_error(ValueError("fine"))
 
 
+@pytest.mark.parametrize("span_len, pool", [(None, "e.0_forward"), (8, "e.0..e.7_forward")])
+def test_serving_record_names_the_span_and_its_one_pool(span_len, pool):
+    """A span request is one task in the pool of its chain: the record carries the
+    chain's length and that pool; a single block's record has no ``span_len``."""
+    ledger = ServingLedger()
+    extra = {} if span_len is None else {"span_len": span_len}
+    ledger.on_span(_finished_span(duration=0.3, expert="e.0", kind="backward", peer="srv", client="cliA", batch=4,
+                                  occupancy=1.0, pool=pool, queue_wait_s=0.1, compute_s=0.15, stage_s=0.03, **extra))
+    [record] = ledger.records()
+    assert record.get("span_len") == span_len and record["pool"] == pool and record["occupancy"] == 1.0
+    assert record["stage_s"] == pytest.approx(0.03) and record["stage_s"] <= record["compute_s"]
+
+
 # ---------------------------------------------------------------- pool units
+
+
+async def test_request_through_two_pools_accrues_phases_and_keeps_the_worst_occupancy_pool():
+    from hivemind_tpu.moe.server.task_pool import TaskPool
+    from hivemind_tpu.telemetry.tracing import trace
+
+    full = TaskPool(lambda x: [x], "occ_full", max_batch_size=2, blocks=3)
+    thin = TaskPool(lambda x: [x], "occ_thin", max_batch_size=8)
+    blocks = REGISTRY.get("hivemind_moe_pool_blocks_total")
+    before = {name: blocks.labels(name).value for name in ("occ_full", "occ_thin")}
+    x = np.zeros((2, 4), np.float32)
+    with trace(SERVING_SPAN, kind="forward", expert="occ.0", client="cli", peer="srv") as span:
+        for pool in (full, thin, full):
+            pending = asyncio.ensure_future(pool.submit_task(x))
+            await asyncio.sleep(0.01)
+            pool.process_batch(pool.pop_batch())
+            await asyncio.wait_for(pending, timeout=10)
+    assert (span.attributes["pool"], span.attributes["occupancy"]) == ("occ_thin", 0.25)
+    assert span.attributes["queue_wait_s"] >= 0.03 and span.attributes["compute_s"] > 0  # three hops, summed
+    # every batch counts the blocks its pool walks
+    assert blocks.labels("occ_full").value - before["occ_full"] == 6
+    assert blocks.labels("occ_thin").value - before["occ_thin"] == 1
+
 
 
 async def test_task_pool_deque_semantics_and_phase_stamps():
@@ -444,7 +480,7 @@ def test_two_peer_serving_attribution_shed_breaker_and_board(capsys):
         # occupy the single drain executor with a slow batch on sobs.1, then
         # request sobs.0: its task sits in the queue behind the slow batch, so
         # queue-wait must dominate its decomposition
-        slow_pool = server.handler.forward_pools["sobs.1"]
+        slow_pool = server.handler.chain_pool("forward", ["sobs.1"])
         original_process = slow_pool.process_func
 
         def slow_process(*args):
@@ -476,7 +512,7 @@ def test_two_peer_serving_attribution_shed_breaker_and_board(capsys):
         # --- load-shed: bounded queue -> typed error -> client breaker ------
         shed_total = REGISTRY.get("hivemind_moe_shed_total")
         sheds_before = shed_total.labels("sobs.0_forward").value
-        server.handler.forward_pools["sobs.0"].max_queue_size = 0  # shed everything
+        server.handler.chain_pool("forward", ["sobs.0"]).max_queue_size = 0  # shed everything
         for _ in range(2):  # EXPERT_BREAKERS failure_threshold == 2
             with pytest.raises(Exception, match="ServerOverloadedError"):
                 expert0.forward_np(x)
@@ -484,7 +520,7 @@ def test_two_peer_serving_attribution_shed_breaker_and_board(capsys):
         assert "sobs.0" in EXPERT_BREAKERS, "sheds did not trip the expert breaker"
         card = SCORECARDS.card("sobs.0")
         assert card is not None and card["sheds"] >= 2
-        server.handler.forward_pools["sobs.0"].max_queue_size = 1024
+        server.handler.chain_pool("forward", ["sobs.0"]).max_queue_size = 1024
         shed_records = [r for r in SERVING_LEDGER.records() if r.get("error")]
         assert any(r["error"] == "ServerOverloadedError" for r in shed_records)
 

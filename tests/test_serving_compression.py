@@ -261,7 +261,7 @@ def test_shed_breaker_scorecard_unchanged_under_compression(serving_pair):
 
     shed_total = REGISTRY.get("hivemind_moe_shed_total")
     sheds_before = shed_total.labels("eq.0_forward").value
-    pool = server.handler.forward_pools["eq.0"]
+    pool = server.handler.chain_pool("forward", ["eq.0"])
     pool.max_queue_size = 0  # shed everything
     try:
         for _ in range(2):  # EXPERT_BREAKERS failure_threshold == 2

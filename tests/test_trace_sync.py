@@ -31,12 +31,12 @@ ROOT = Path(__file__).resolve().parents[1]
 HID = 16
 
 
-def _backends(uid):
+def _backends(*uids):
     from hivemind_tpu.moe import ModuleBackend
     from hivemind_tpu.moe.server.layers.common import CausalTransformerExpert
 
     return {uid: ModuleBackend(uid, CausalTransformerExpert(hidden_dim=HID, num_heads=4), optimizer=optax.sgd(1e-3),
-                               sample_input=np.zeros((1, 4, HID), np.float32), max_batch_size=8)}
+                               sample_input=np.zeros((1, 4, HID), np.float32), max_batch_size=8) for uid in uids}
 
 
 def _counter(name, *labels):
@@ -66,20 +66,24 @@ async def _decode_two_sessions(uid, client):
     assert all(out.shape == (1, 1, HID) for out in outs)
 
 
-async def _forward_through_the_handler(uid, client):
-    """One rpc_forward through ConnectionHandler -> TaskPool -> Runtime -> ModuleBackend, no network."""
+async def _forward_through_the_handler(uid, next_uid, client):
+    """One rpc_forward for the span of two blocks through ConnectionHandler -> TaskPool ->
+    Runtime -> ModuleBackend, then one streamed request for the first block alone; no network."""
     from hivemind_tpu.compression import serialize_tensor
     from hivemind_tpu.moe.server.connection_handler import ConnectionHandler
     from hivemind_tpu.moe.server.runtime import Runtime
     from hivemind_tpu.proto import runtime_pb2
+    from hivemind_tpu.utils.serializer import MSGPackSerializer
 
-    handler = ConnectionHandler(_backends(uid), activation_compression="none")
+    handler = ConnectionHandler(_backends(uid, next_uid), activation_compression="none")
     runtime = Runtime(handler.all_pools(), stats_report_interval=None)
+    handler.on_new_pool = runtime.add_pool  # the span's pool is made on its first request
     runtime.start()
     try:
         await asyncio.sleep(0.05)  # the drain loop finds its pools empty and starts to wait
         x = np.random.RandomState(1).randn(3, 4, HID).astype(np.float32)
-        request = runtime_pb2.ExpertRequest(uid=uid, tensors=[serialize_tensor(x)])
+        request = runtime_pb2.ExpertRequest(uid=uid, tensors=[serialize_tensor(x)],
+                                            metadata=MSGPackSerializer.dumps({"uids": [uid, next_uid]}))
         context = SimpleNamespace(local_id="srv", remote_id=client)
         response = await handler.rpc_forward(request, context)
         assert response.nbytes > x.nbytes  # the output of 3 x 4 x HID float32, uncompressed, and its framing
@@ -124,7 +128,7 @@ def captured(tmp_path_factory):
     from perf.trace_reduce import find_xplane, load_planes
 
     tag = uuid.uuid4().hex[:8]
-    out = SimpleNamespace(tag=tag, spans=[], epochs=[], uid=f"tsync{tag}.0", client=f"cli-{tag}")
+    out = SimpleNamespace(tag=tag, spans=[], epochs=[], uid=f"tsync{tag}.0", next_uid=f"tsync{tag}.1", client=f"cli-{tag}")
     record = lambda kind, entry: out.epochs.append(entry) if kind == "epoch" else None  # noqa: E731
     add_span_listener(out.spans.append)
     LEDGER.add_record_listener(record)
@@ -143,7 +147,7 @@ def captured(tmp_path_factory):
         try:
             loop.run_until_complete(_crossing_an_await())
             loop.run_until_complete(_decode_two_sessions(out.uid, out.client))
-            loop.run_until_complete(_forward_through_the_handler(out.uid, out.client))
+            loop.run_until_complete(_forward_through_the_handler(out.uid, out.next_uid, out.client))
         finally:
             loop.close()
         out.peer = _one_epoch_transition(f"tsync_{tag}")
@@ -266,9 +270,16 @@ def test_decode_record_divides_into_queue_wait_and_compute(captured):
 
 def test_pool_batch_opens_stage_in_device_and_fetch(captured):
     batch, streamed = [span for span in captured.spans if span.name == "pool.batch"]
-    assert batch.attributes["pool"] == streamed.attributes["pool"] == f"{captured.uid}_forward"
-    assert (batch.attributes["rows"], batch.attributes["tasks"]) == (3, 1) and streamed.attributes["rows"] == 2
+    assert batch.attributes["pool"] == f"{captured.uid}..{captured.next_uid}_forward"  # the span's own pool
+    assert streamed.attributes["pool"] == f"{captured.uid}_forward"
+    assert (batch.attributes["rows"], batch.attributes["tasks"], batch.attributes["blocks"]) == (3, 1, 2)
+    assert (streamed.attributes["rows"], streamed.attributes["blocks"]) == (2, 1)
     assert _children(captured, "pool.batch") == {"backend.stage_in", "backend.device", "backend.fetch"}
+    # one upload, one program a block, one fetch
+    under = [span.name for span in captured.spans if span.parent_id == batch.span_id]
+    assert sorted(under) == ["backend.device", "backend.device", "backend.fetch", "backend.stage_in"]
+    assert [s.attributes["uid"] for s in captured.spans if s.name == "backend.device" and s.parent_id == batch.span_id] == [
+        captured.uid, captured.next_uid]
     for name in ("pool.batch", "backend.stage_in", "backend.device", "backend.fetch"):
         assert _events(captured, name), f"{name} is not in the capture"
 
@@ -278,6 +289,7 @@ def test_forward_record_carries_staging_and_deserialization(captured):
     [batch] = [s for s in captured.spans if s.name == "pool.batch" and s.attributes["rows"] == 3]
     staged = sum(s.duration for s in captured.spans if s.name in ("backend.stage_in", "backend.fetch") and s.parent_id == batch.span_id)
     assert record["stage_s"] == pytest.approx(staged, abs=2e-3) and 0 < record["stage_s"] <= record["compute_s"]
+    assert record["span_len"] == 2 and record["pool"] == batch.attributes["pool"]  # one pool for the whole span
     assert record["deserialize_s"] > 0 and record["serialize_s"] > 0
     def waited(snapshot):  # a counter nobody has moved yet has no series
         return snapshot.get("hivemind_moe_runtime_wait_seconds_total", {}).get("series", {}).get("_", 0.0)
