@@ -31,12 +31,14 @@ class AttentionShape(NamedTuple):
     causal: bool
 
 
-# what the main path feeds the flash kernels: the ALBERT-base train step (seq 512,
-# 12 heads x 64, bidirectional) and the block server's schema batch (64 positions,
-# padded to one 128 block; 32 heads x 128, causal)
+# what the benchmark's cells feed the flash kernels (PERF.md §4): the ALBERT-base train
+# step (32 sequences of 512, 12 heads x 64, bidirectional), a fine-tuning request through
+# Mistral-7B blocks (4 x 512, 32 heads x 128, causal) and the longest prefill of the
+# decode cell (one prompt of 1024: four causal tiles a side)
 MAIN_PATH_ATTENTION = (
-    AttentionShape("albert-base", 4, 512, 12, 64, False),
-    AttentionShape("llama-block", 2, 64, 32, 128, True),
+    AttentionShape("albert-base", 32, 512, 12, 64, False),
+    AttentionShape("mistral-finetune", 4, 512, 32, 128, True),
+    AttentionShape("mistral-prefill", 1, 1024, 32, 128, True),
 )
 MAIN_PATH_QUANT_SHAPE = (4096, 11008)  # one Llama-7B MLP kernel
 
@@ -57,7 +59,7 @@ def _require(condition: bool, message: str) -> None:
 
 
 def check_flash_attention(shape: AttentionShape, interpret: bool) -> Dict[str, float]:
-    """Flash forward, dQ and dK/dV in bf16 against `plain_attention` in float32.
+    """Flash forward, dQ, dK and dV in bf16 against `plain_attention` in float32.
     Returns the max error of each, relative to the reference's largest value."""
     from hivemind_tpu.ops.pallas_attention import flash_attention
     from hivemind_tpu.parallel.ring_attention import plain_attention
@@ -141,7 +143,7 @@ def validate_kernels(
     attention_shapes: Sequence[AttentionShape] = MAIN_PATH_ATTENTION,
     quant_shape: Tuple[int, int] = MAIN_PATH_QUANT_SHAPE,
 ) -> Dict[str, Any]:
-    """Every ``pallas_call`` in the tree (flash forward, dQ, dK/dV; blockwise int8
+    """Every ``pallas_call`` in the tree (flash forward, flash backward; blockwise int8
     quantize, dequantize) against its float32 reference. Returns the measured
     errors keyed by check; raises on the first kernel that fails."""
     report: Dict[str, Any] = {"backend": jax.default_backend(), "interpret": interpret}

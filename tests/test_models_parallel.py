@@ -315,6 +315,84 @@ def test_pallas_flash_attention_matches_plain():
     )
 
 
+def _flash_against_float32(seq, head_dim, causal, dtype):
+    """How far (out, dq, dk, dv) lie from the float32 `plain_attention`'s, for the flash
+    kernels (interpret mode) and for `plain_attention`, both fed `dtype` operands: the
+    largest difference over the reference's largest value, and the l2 distance over the
+    reference's l2 norm."""
+    from hivemind_tpu.ops.pallas_attention import flash_attention
+
+    rng = np.random.RandomState(seq + head_dim)
+    q, k, v = (jnp.asarray(rng.randn(1, seq, 2, head_dim), dtype) for _ in range(3))
+    weight = jnp.asarray(np.cos(np.arange(head_dim)), jnp.float32)  # non-uniform cotangent
+
+    def results(attention, *operands):
+        loss = lambda q, k, v: (attention(q, k, v).astype(jnp.float32) * weight).sum()
+        return (attention(*operands), *jax.grad(loss, argnums=(0, 1, 2))(*operands))
+
+    exact = results(lambda q, k, v: plain_attention(q, k, v, causal=causal),
+                    *(x.astype(jnp.float32) for x in (q, k, v)))
+
+    def distances(got):
+        diffs = [(np.asarray(g, np.float32) - np.asarray(e), np.asarray(e)) for g, e in zip(got, exact)]
+        return [(float(np.abs(d).max() / np.abs(e).max()), float(np.linalg.norm(d) / np.linalg.norm(e)))
+                for d, e in diffs]
+
+    fused = results(lambda q, k, v: flash_attention(q, k, v, causal, True), q, k, v)
+    assert all(g.dtype == dtype and g.shape == q.shape for g in fused)
+    return distances(fused), distances(results(lambda q, k, v: plain_attention(q, k, v, causal=causal), q, k, v))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "seq,head_dim,causal",
+    [
+        (512, 64, False),  # ALBERT: one whole-row tile
+        (512, 128, True),  # fine-tuning through Mistral blocks
+        (128, 128, True),  # the shortest prefill: one tile
+        (1024, 128, True),  # the longest prefill: the carry and the skipped blocks
+        (200, 64, True),  # tail padding inside a causal tile
+        (640, 128, False),  # five 128s: a whole row of keys beside 128-row query blocks
+    ],
+)
+def test_pallas_flash_tiles_match_float32_plain(seq, head_dim, causal, dtype):
+    """Whatever tile the rule picks, forward and all three gradients agree with a
+    float32 `plain_attention`: float32 operands to 1e-5 of the largest value (nothing
+    was narrowed), bf16 operands no farther (l2) than `plain_attention` on the same
+    bf16 operands (its largest single difference is its output's own rounding, which
+    the two share)."""
+    fused, plain = _flash_against_float32(seq, head_dim, causal, dtype)
+    for name, (fused_max, fused_l2), (_plain_max, plain_l2) in zip(("out", "dq", "dk", "dv"), fused, plain):
+        if dtype == jnp.float32:
+            assert fused_max <= 1e-5, f"{name}: flash is {fused_max:.3g} of the largest value from float32"
+        else:
+            assert fused_l2 <= plain_l2, f"{name}: flash is {fused_l2:.3g} from float32, plain_attention {plain_l2:.3g}"
+
+
+@pytest.mark.parametrize(
+    "seq,head_dim,itemsize,causal,expected",
+    [
+        (512, 64, 2, False, (512, 512, 512)),  # ALBERT: the KV sweep is one step
+        (512, 128, 2, True, (512, 512, 512)),  # causal: one step beats three tiles of four (measured)
+        (128, 128, 2, True, (128, 128, 128)),
+        (1024, 128, 2, True, (1024, 512, 512)),  # past one tile: three of four computed
+        (640, 128, 2, False, (640, 128, 640)),  # a 640-token prompt does not pay for 1,024
+        (640, 128, 2, True, (640, 128, 640)),  # 5 steps over the square beat 25 steps over 15 tiles
+        (200, 64, 4, False, (256, 256, 256)),
+        (512, 64, 4, False, (512, 256, 512)),  # float32 operands: a smaller tile under the same budget
+        (8192, 128, 2, False, (8192, 512, 512)),  # past the budget the KV sweep and its carry come back
+    ],
+)
+def test_pallas_flash_tile_rule(seq, head_dim, itemsize, causal, expected):
+    from hivemind_tpu.ops import pallas_attention as pa
+
+    tiles = pa._tiles(seq, head_dim, itemsize, causal)
+    assert tuple(tiles) == expected
+    assert tiles.padded - seq < 128 and tiles.padded % tiles.block_q == 0 and tiles.padded % tiles.block_k == 0
+    smallest = tiles.block_q == tiles.block_k == 128
+    assert smallest or pa._step_bytes(tiles.block_q, tiles.block_k, head_dim, itemsize) <= pa._VMEM_BUDGET
+
+
 def test_pallas_flash_backward_kernels_match_plain_grads():
     """The FUSED two-pass backward (dQ / dK+dV kernels from the saved lse) must
     reproduce the einsum path's gradients for all inputs — bidirectional and

@@ -1,4 +1,6 @@
-"""Compile for a described (not attached) TPU v5e, at published widths, what the OLMoE
+"""Compile for a described (not attached) TPU v5e, at published widths: the flash
+kernels at the shapes the benchmark's cells feed them (ISSUE 33: a tile Mosaic
+refuses fails here, not on the chip), and what the OLMoE
 block adds to the decode path: the sparse expert layer and one batched decode step.
 Nothing runs: this guards what the chip's compiler makes of the code (ISSUE 27) —
 `jax.lax.ragged_dot` stays the compiler's own grouped-matmul kernel on float32
@@ -88,3 +90,28 @@ def test_batched_decode_step_of_eight_sessions_fits_the_chip(one_chip, no_compil
     memory = compiled.memory_analysis()
     # arguments: 1.68 GB of weights + 8 x 33.5 MB of caches; the program's own temporaries and outputs stay under 1.5 GB
     assert memory.temp_size_in_bytes + memory.output_size_in_bytes < 1.5 * 2**30
+
+
+@pytest.mark.parametrize(
+    "shape,causal,backward",
+    [
+        ((32, 512, 12, 64), False, True),  # albert-base.swarm2: one whole-row tile a head
+        ((4, 512, 32, 128), True, True),  # mistral-7b-span8.finetune
+        ((1, 1024, 32, 128), True, False),  # mistral-7b-span8.decode32: the longest prefill
+        ((1, 640, 32, 128), True, False),  # five 128s: a whole row of keys beside 128-row query blocks
+    ],
+)
+def test_flash_kernels_compile_under_mosaic_at_the_cells_shapes(one_chip, no_compile_cache, shape, causal, backward):
+    from hivemind_tpu.ops import pallas_attention
+
+    operand = _shape(shape, jnp.bfloat16, one_chip)
+    compiled = pallas_attention._flash_forward.lower(operand, operand, operand, causal=causal).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    # the log-sum-exp leaves in its own width: no [batch*heads, seq, 128] float32 among the outputs
+    batch, seq, heads, head_dim = shape
+    assert compiled.memory_analysis().output_size_in_bytes < 2 * batch * seq * heads * (head_dim * 2 + 4)
+    if backward:
+        lse = _shape((batch, heads, seq), jnp.float32, one_chip)
+        compiled = pallas_attention._flash_backward.lower(
+            operand, operand, operand, operand, lse, operand, causal=causal).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1

@@ -52,6 +52,32 @@ def test_flash_attention_backward_lowers_for_tpu():
     _assert_mosaic_lowered(exported)
 
 
+# the shapes the benchmark's cells feed the kernels (PERF.md §4): a tile the Pallas
+# lowering refuses must fail here, not on the chip (Mosaic's own verdict on the same
+# shapes: tests/test_tpu_compile.py)
+CELL_SHAPES = {
+    "albert-32x512x12x64-bidirectional": ((32, 512, 12, 64), False),
+    "finetune-4x512x32x128-causal": ((4, 512, 32, 128), True),
+}
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_flash_attention_lowers_for_tpu_at_the_cells_shapes(cell, direction):
+    shape, causal = CELL_SHAPES[cell]
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def loss(a, b, c):
+        return jnp.sum(flash_attention(a, b, c, causal).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if direction == "backward" else (
+        lambda a, b, c: flash_attention_lse(a, b, c, causal))
+    exported = _export_for_tpu(fn, q, q, q)
+    _assert_mosaic_lowered(exported)
+    # one forward custom call a layer application (the benchmark counts steps by them)
+    assert exported.mlir_module().count("tpu_custom_call") == (2 if direction == "backward" else 1)
+
+
 def test_blockwise_quantization_kernels_lower_for_tpu():
     flat = jax.ShapeDtypeStruct((1 << 16,), jnp.float32)
     exported = _export_for_tpu(lambda x: pallas_blockwise_quantize(x, block_size=4096), flat)
