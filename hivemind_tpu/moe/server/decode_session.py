@@ -25,6 +25,10 @@ dispatched while this one runs (at most one program ahead), and only the chain's
 last output comes to the host. No event-loop turn, no future, no flush window and
 no transfer lie between two blocks; the futures resolve at the chain's end.
 
+A prefill (or any step that cannot be batched) walks the chain the same way for its
+one session (`_decode_direct`): the padded prompt is uploaded once, each block's own
+program runs on the output of the block before, and the last output is fetched.
+
 Steps that arrive while a cohort is launched wait for the next one, which starts
 when this one's last block is dispatched, with all of them up to a full bucket
 (`_cohort_rows`: a program costs by the power of two its rows are padded to). This
@@ -65,7 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hivemind_tpu.moe.server.routing_stats import ROUTING_COLLECTION, record_routing
+from hivemind_tpu.moe.server.routing_stats import ROUTING_COLLECTION, held_range, record_routing
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 from hivemind_tpu.telemetry.device import record_transfer
 from hivemind_tpu.telemetry.serving import accrue_span_phase
@@ -134,6 +138,33 @@ _CALLS = _TELEMETRY.counter(
     ("path",),
 )
 _CALLS_BATCHED, _CALLS_DIRECT = _CALLS.labels("batched"), _CALLS.labels("direct")
+# what the session table pins on the device, by the kind of cache a block keeps
+# (`decode_cache_kind` on the block class: a sliding-window block's ring is "window";
+# a block that does not say keeps every position, "full"); kept by addition as
+# sessions enter and leave (`_count_cache_locked`): a walk of a 512-entry table at
+# every prefill cost the Mistral cell an eighth of its rate (PERF.md section 6, PR 34)
+_CACHE_BYTES = _TELEMETRY.gauge(
+    "hivemind_moe_decode_cache_bytes",
+    "bytes of decode caches that the session table holds, by kind of cache (window = a ring "
+    "of a sliding-window block's last positions, full = every position of the session)",
+    ("kind",),
+)
+_CACHE_ENTRIES = _TELEMETRY.gauge(
+    "hivemind_moe_decode_cache_entries",
+    "entries (one session at one block) of the session table, by kind of cache",
+    ("kind",),
+)
+# what a prompt costs the device at one block (ISSUE 34): a prefill holds the device while
+# every other session's step waits, and a device trace of a few seconds often holds none
+_PREFILL_SECONDS = _TELEMETRY.counter(
+    "hivemind_moe_decode_prefill_seconds_total",
+    "host seconds from the dispatch of a prefill's program at one block (a chunk of more than one "
+    "position) until it has finished, its wait behind a program already on the device included",
+)
+_PREFILL_POSITIONS = _TELEMETRY.counter(
+    "hivemind_moe_decode_prefill_positions_total",
+    "positions, padded as run, of the prefill programs, one count a block a prompt crosses",
+)
 _COHORTS = _TELEMETRY.counter(
     "hivemind_moe_decode_cohorts_total",
     "cohorts of decode steps run: the steps that waited on one span chain, walked through "
@@ -172,10 +203,11 @@ def _cohort_rows(waiting: int) -> int:
 
 
 class _Session:
-    __slots__ = ("cache_k", "cache_v", "index", "last_used", "lock", "batch_started")
+    __slots__ = ("cache_k", "cache_v", "nbytes", "index", "last_used", "lock", "batch_started")
 
     def __init__(self, cache_k, cache_v):
         self.cache_k, self.cache_v = cache_k, cache_v
+        self.nbytes = cache_k.nbytes + cache_v.nbytes  # a step hands back caches of the same shapes
         self.index = 0
         self.last_used = time.monotonic()
         # perf_counter at which the batch carrying this session's pending step
@@ -190,10 +222,10 @@ class _Output:
     batch's live entries), the routing it sowed, and once somebody needed it on the
     host, that copy."""
 
-    __slots__ = ("y", "routing", "rows", "on_host", "settled")
+    __slots__ = ("y", "routing", "rows", "held", "on_host", "settled")
 
-    def __init__(self, y, routing, rows: int):
-        self.y, self.routing, self.rows = y, routing, rows
+    def __init__(self, y, routing, rows: int, held=None):
+        self.y, self.routing, self.rows, self.held = y, routing, rows, held
         self.on_host: Optional[np.ndarray] = None
         self.settled = False
 
@@ -208,7 +240,7 @@ class _Output:
         are on the device until then."""
         if not self.settled:
             self.settled = True
-            record_routing(self.routing, "batched", span, rows=self.rows)
+            record_routing(self.routing, "batched", span, rows=self.rows, held=self.held)
             self.y.block_until_ready()
 
 
@@ -248,6 +280,7 @@ class DecodeSessionManager:
         self._drainers: Dict[Chain, asyncio.Task] = {}
         # host activations -> the device, one program a bucket (`_device_rows`)
         self._upload = tracked_jit(lambda xs: xs, site="decode_session.upload")
+        self._cache_tally: Dict[str, List[int]] = {}  # kind of cache -> [bytes, entries] of the table (`_count_cache_locked`)
 
     def supports(self, uid: str) -> bool:
         backend = self.backends.get(uid)
@@ -271,14 +304,14 @@ class DecodeSessionManager:
             if now - s.last_used > self.session_ttl and id(s) not in pinned
         ]
         for key in expired:
-            del self._sessions[key]
+            self._drop_locked(key)
         if expired:
             _EVICTIONS.inc(len(expired), reason="ttl")
         evictable = [k for k in self._sessions if id(self._sessions[k]) not in pinned]
         while len(self._sessions) > self.max_sessions and evictable:
             oldest = min(evictable, key=lambda k: self._sessions[k].last_used)
             evictable.remove(oldest)
-            del self._sessions[oldest]
+            self._drop_locked(oldest)
             _EVICTIONS.inc(reason="cap")
         self._sample_gauges_locked()
 
@@ -286,24 +319,71 @@ class DecodeSessionManager:
         _SESSIONS.set(len(self._sessions))
         _SESSION_OCCUPANCY.set(round(len(self._sessions) / max(self.max_sessions, 1), 4))
 
+    def _cache_kind(self, uid: str) -> str:
+        return getattr(self.backends[uid].module, "decode_cache_kind", "full")
+
+    def _count_cache_locked(self, uid: str, session: _Session, entries: int) -> None:
+        """A session enters (+1) or leaves (-1) the table at ``uid``: its bytes and
+        its entry onto the gauges of that block's kind of cache. One addition a
+        change of the table, and never a walk of it at a step or a prefill."""
+        kind = self._cache_kind(uid)
+        tally = self._cache_tally.setdefault(kind, [0, 0])
+        tally[0] += entries * session.nbytes
+        tally[1] += entries
+        _CACHE_BYTES.set(tally[0], kind=kind)
+        _CACHE_ENTRIES.set(tally[1], kind=kind)
+
+    def clear_sessions(self) -> None:
+        """Empty the session table and its gauges (a warm-up's or a check's sessions
+        leave the device before the traffic comes). Steps under way are not waited for."""
+        with self._lock:
+            self._sessions.clear()
+            for kind, tally in self._cache_tally.items():
+                tally[:] = [0, 0]
+                _CACHE_BYTES.set(0, kind=kind)
+                _CACHE_ENTRIES.set(0, kind=kind)
+            self._sample_gauges_locked()
+
+    def _drop_locked(self, key: Tuple[str, str]) -> None:
+        session = self._sessions.pop(key)
+        self._count_cache_locked(key[0], session, -1)
+
     def _raw_step(self, uid: str):
         """The un-jitted block step; shared by the direct and batched paths so a
         signature change cannot silently diverge them. ``index`` is one write
         position (a session's prefill or step) or a vector of them, one a row (a
-        batch of sessions). Returns (y, cache_k, cache_v, routing): what the block
-        sowed into `ROUTING_COLLECTION` (empty for a block without experts)."""
+        batch of sessions). ``length`` (at most one: the chunk's real positions,
+        the rest is padding) goes to a block that asks for it (`_takes_length`).
+        Returns (y, cache_k, cache_v, routing): what the block sowed into
+        `ROUTING_COLLECTION` (empty for a block without experts)."""
         backend = self.backends[uid]
 
-        def step(params, x, cache_k, cache_v, index):
+        def step(params, x, cache_k, cache_v, index, *length):
             # int8 weight-only backends: materialize dense weights inside the jit
             # (identity for plain fp32 trees)
             (y, cache_k, cache_v), routing = backend.module.apply(
-                {"params": backend.dense_params(params)}, x, cache_k, cache_v, index,
+                {"params": backend.dense_params(params)}, x, cache_k, cache_v, index, *length,
                 mutable=[ROUTING_COLLECTION],
             )
             return y, cache_k, cache_v, routing
 
         return step
+
+    def _named_by_kind(self, uid: str, program, name: str):
+        """A block that names its kind of cache has it in its decode programs' names
+        (`jit_batched_step_window`, `jit_step_full`, `jit_prefill_full_2048`: ``name``
+        and the kind), so that a device trace tells the kinds of one span, and a
+        prefill from a step, apart; any other block's programs keep their names."""
+        kind = getattr(self.backends[uid].module, "decode_cache_kind", None)
+        if kind:
+            program.__name__ = program.__qualname__ = name.format(kind=kind)
+        return program
+
+    def _takes_length(self, uid: str) -> bool:
+        """Whether the block is told how many positions of a right-padded chunk are
+        real (a ring cache must keep the padding out); a block that keeps every
+        position needs no telling: its padded tail lies past ``index``."""
+        return getattr(self.backends[uid].module, "decode_takes_length", False)
 
     def _step_fn(self, uid: str, batch: int, new_len: int):
         key = (uid, batch, new_len)
@@ -312,8 +392,9 @@ class DecodeSessionManager:
             # tracked_jit (ISSUE 19): every compile lands on the compile tracker
             # under one site — a client cycling prompt lengths past the pow2
             # buckets shows up as a recompile storm, not silent latency
+            name = "step_{kind}" if new_len == 1 else f"prefill_{{kind}}_{new_len}"
             fn = self._step_fns[key] = tracked_jit(
-                self._raw_step(uid), site="decode_session.step", donate_argnums=(2, 3),
+                self._named_by_kind(uid, self._raw_step(uid), name), site="decode_session.step", donate_argnums=(2, 3),
                 out_shardings=(None, *self._cache_shardings(uid), None),
             )
         return fn
@@ -339,51 +420,102 @@ class DecodeSessionManager:
             cache_k, cache_v = backend.shard_decode_cache(cache_k, cache_v)
         return cache_k, cache_v
 
-    def _advance(self, uid: str, session: _Session, backend, x: np.ndarray, chunk_len: int, new_len: int):
+    def _advance(self, uid: str, session: _Session, backend, x, chunk_len: int, new_len: int):
         """Run the per-session jitted step on ``x`` (``new_len`` positions, already
-        padded to ``chunk_len``) under ``session.lock``, store the new caches and
-        return the output on the host. The step DONATES the caches: if it fails (at dispatch
-        or when the result is read), what the session still points at may be
-        deleted buffers, so the session is dropped and the client's next
-        continuation gets the unknown-session KeyError (it re-prefills) instead
+        padded to ``chunk_len``; on the host or, mid-chain, where the block before
+        left it) under ``session.lock``, store the new caches and return the output
+        ON THE DEVICE, its program finished. The step DONATES the caches: if it
+        fails (at dispatch or when its result is awaited), what the session still
+        points at may be deleted buffers, so the session is dropped and the client's
+        next continuation gets the unknown-session KeyError (it re-prefills) instead
         of a read of donated memory."""
         step = self._step_fn(uid, x.shape[0], chunk_len)
+        length = (jnp.int32(new_len),) if self._takes_length(uid) else ()
         _CALLS_DIRECT.inc()
+        started = time.perf_counter()
         try:
             with _trace_sync("decode.direct", uid=uid, chunk_len=chunk_len) as span:
                 y, session.cache_k, session.cache_v, routing = step(
                     backend.snapshot_params(), jnp.asarray(x), session.cache_k,
-                    session.cache_v, jnp.int32(session.index),
+                    session.cache_v, jnp.int32(session.index), *length,
                 )
-                y = np.asarray(y)
-                record_routing(routing, "direct", span, positions=new_len)
+                record_routing(routing, "direct", span, positions=new_len, held=held_range(backend.module))
+                # the next block is dispatched when this one has finished: a cohort's
+                # program that arrives meanwhile waits for one block of a prefill, not
+                # for the chain
+                y.block_until_ready()
+                if chunk_len > 1:
+                    _PREFILL_SECONDS.inc(time.perf_counter() - started)
+                    _PREFILL_POSITIONS.inc(x.shape[0] * chunk_len)
                 return y
         except Exception:
             with self._lock:
                 for key in [k for k, s in self._sessions.items() if s is session]:
-                    del self._sessions[key]
+                    self._drop_locked(key)
                 self._sample_gauges_locked()
             raise
 
     def decode(self, uid: str, session_id: str, x: np.ndarray, reset: bool) -> np.ndarray:
-        """One session step: prefill (``reset=True``, chunk = the prompt) or advance
-        one token in an existing session. Returns the block output for the chunk.
-        Raises ``KeyError`` for a continuation on an unknown/evicted session."""
-        backend = self.backends.get(uid)
-        if backend is None or not self.supports(uid):
-            raise KeyError(f"expert {uid!r} does not support decode sessions")
+        """One session step at one block: the span chain of one (`_decode_direct`)."""
+        return self._decode_direct((uid,), session_id, x, reset)
+
+    def _decode_direct(self, chain: Chain, session_id: str, x: np.ndarray, reset: bool) -> np.ndarray:
+        """One session's step through the span chain, ONE chain on the device: prefill
+        (``reset=True``, chunk = the prompt) or advance one token in an existing
+        session. The chunk is padded and uploaded once, each block's own program runs
+        on the output of the block before where it lies, and only the chain's last
+        output comes to the host (a prompt of 4,096 positions at hidden 6,144 is 100 MB,
+        which crossed the host twice a block). Returns the last block's output for the
+        chunk. Raises ``KeyError`` for a continuation on an unknown/evicted session."""
+        for uid in chain:
+            if not self.supports(uid):
+                raise KeyError(f"expert {uid!r} does not support decode sessions")
         x = np.asarray(x, np.float32)
         assert x.ndim == 3, f"decode input must be [batch, chunk, hid], got {x.shape}"
         batch, new_len = x.shape[0], x.shape[1]
         if new_len > self.max_len:
             raise ValueError(f"chunk of {new_len} exceeds session max_len={self.max_len}")
+        # bucket prefill lengths to powers of two so the jit cache stays at
+        # O(log max_len) entries per (uid, batch) instead of one compile per
+        # distinct prompt length. Padded tail slots of the cache are invisible
+        # (the continuation mask stops at `index`) and are overwritten in place
+        # by subsequent single-token steps; padded prefill OUTPUTS are sliced
+        # off, and causal attention keeps real prefill positions exact (past the
+        # first block the tail holds what the block before made of the padding:
+        # finite, and as invisible).
+        padded_len = new_len if new_len == 1 else min(_next_pow2(new_len), self.max_len)
+        if padded_len != new_len:
+            x = np.pad(x, ((0, 0), (0, padded_len - new_len), (0, 0)))
+        record_transfer(x.nbytes, "host_to_device")
+        y = x
+        for uid in chain:
+            session = self._enter(uid, session_id, batch, reset)
+            with session.lock:
+                self._check_step(session, session_id, batch, new_len)
+                y = self._advance(uid, session, self.backends[uid], y, padded_len, new_len)
+                session.index += new_len
+                # re-stamp AFTER the device step: a step that hits a jit compile can
+                # outlast MERGE_RECENCY_S, and a session stamped only at entry would
+                # look stale to _concurrent_sessions the instant its own prefill
+                # returns — so two freshly-prefilled streams never engage batching.
+                # Bare float store; concurrent readers just see one of two recent stamps.
+                session.last_used = time.monotonic()
+                _STEPS.inc(path="direct")
+        out = np.asarray(y)[:, :new_len]
+        record_transfer(out.nbytes, "device_to_host")
+        return out
 
+    def _enter(self, uid: str, session_id: str, batch: int, reset: bool) -> _Session:
+        """The session of ``session_id`` at ``uid``: a fresh one for ``reset``."""
         key = (uid, session_id)
         with self._lock:
             self._evict_locked()
             session = self._sessions.get(key)
             if reset:
-                session = self._sessions[key] = _Session(*self._fresh_caches(backend, batch))
+                if session is not None:
+                    self._drop_locked(key)
+                session = self._sessions[key] = _Session(*self._fresh_caches(self.backends[uid], batch))
+                self._count_cache_locked(uid, session, +1)
                 _RESETS.inc()
                 self._sample_gauges_locked()
             elif session is None:
@@ -395,45 +527,19 @@ class DecodeSessionManager:
                     f"restart generation with reset=True"
                 )
             session.last_used = time.monotonic()
+        return session
 
-        with session.lock:
-            if session.index == 0:
-                pass  # prefill: any chunk length (causal within the chunk)
-            elif new_len != 1:
-                raise ValueError(
-                    f"session {session_id!r} already holds {session.index} positions; "
-                    f"only 1-token steps may follow the prefill (got chunk {new_len})"
-                )
-            if session.index + new_len > self.max_len:
-                raise ValueError(
-                    f"session {session_id!r} is full ({session.index}/{self.max_len})"
-                )
-            if session.cache_k.shape[0] != batch:
-                raise ValueError(
-                    f"session {session_id!r} batch is {session.cache_k.shape[0]}, got {batch}"
-                )
-            # bucket prefill lengths to powers of two so the jit cache stays at
-            # O(log max_len) entries per (uid, batch) instead of one compile per
-            # distinct prompt length. Padded tail slots of the cache are invisible
-            # (the continuation mask stops at `index`) and are overwritten in place
-            # by subsequent single-token steps; padded prefill OUTPUTS are sliced
-            # off, and causal attention keeps real prefill positions exact.
-            padded_len = new_len if new_len == 1 else min(_next_pow2(new_len), self.max_len)
-            if padded_len != new_len:
-                x = np.pad(x, ((0, 0), (0, padded_len - new_len), (0, 0)))
-            record_transfer(x.nbytes, "host_to_device")
-            y = self._advance(uid, session, backend, x, padded_len, new_len)
-            session.index += new_len
-            # re-stamp AFTER the device step: a step that hits a jit compile can
-            # outlast MERGE_RECENCY_S, and a session stamped only at entry would
-            # look stale to _concurrent_sessions the instant its own prefill
-            # returns — so two freshly-prefilled streams never engage batching.
-            # Bare float store; concurrent readers just see one of two recent stamps.
-            session.last_used = time.monotonic()
-            _STEPS.inc(path="direct")
-            out = y[:, :new_len]
-            record_transfer(out.nbytes, "device_to_host")
-            return out
+    def _check_step(self, session: _Session, session_id: str, batch: int, new_len: int) -> None:
+        """What a step must meet at a block (under ``session.lock``)."""
+        if session.index and new_len != 1:  # a prefill takes any chunk length (causal within the chunk)
+            raise ValueError(
+                f"session {session_id!r} already holds {session.index} positions; "
+                f"only 1-token steps may follow the prefill (got chunk {new_len})"
+            )
+        if session.index + new_len > self.max_len:
+            raise ValueError(f"session {session_id!r} is full ({session.index}/{self.max_len})")
+        if session.cache_k.shape[0] != batch:
+            raise ValueError(f"session {session_id!r} batch is {session.cache_k.shape[0]}, got {batch}")
 
     # ---- continuous batching of single-token steps across sessions ------------
 
@@ -447,7 +553,7 @@ class DecodeSessionManager:
         (continuation, chunk 1, session batch 1) join the chain's next cohort, which
         takes every block of the chain as one batched device call over the steps
         that waited together; everything else takes the direct per-session path,
-        block by block.
+        one chain on the device too (`_decode_direct`).
 
         Stamps the step's phases onto the caller's ``serving.request`` span, as
         ``TaskPool.submit_task`` does for the pools: ``queue_wait_s`` from the
@@ -460,11 +566,6 @@ class DecodeSessionManager:
             accrue_span_phase("queue_wait_s", queue_wait)
         accrue_span_phase("compute_s", time.perf_counter() - started - queue_wait)
         return out
-
-    def _decode_direct(self, chain: Chain, session_id: str, x: np.ndarray, reset: bool) -> np.ndarray:
-        for uid in chain:
-            x = self.decode(uid, session_id, x, reset)
-        return x
 
     async def _submit_step(self, chain: Chain, session_id: str, x: np.ndarray, reset: bool):
         """`decode_span_async` without the attribution: (output, seconds queued)."""
@@ -653,7 +754,7 @@ class DecodeSessionManager:
                 doomed = {id(session) for i in alive for session in entries[i][1]}
                 with self._lock:
                     for key in [k for k, session in self._sessions.items() if id(session) in doomed]:
-                        del self._sessions[key]
+                        self._drop_locked(key)
                     self._sample_gauges_locked()
             for i in alive:
                 results[i] = error
@@ -712,7 +813,7 @@ class DecodeSessionManager:
             # of one call, and a step that fails leaves every session as it was
             placed_k, placed_v = self._cache_shardings(uid)
             fn = self._batched_fns[key] = tracked_jit(
-                batched_step, site="decode_session.batched_step",
+                self._named_by_kind(uid, batched_step, "batched_step_{kind}"), site="decode_session.batched_step",
                 out_shardings=(None, (placed_k,) * stack, (placed_v,) * stack, None),
             )
         return fn
@@ -776,12 +877,13 @@ class DecodeSessionManager:
                 # counted "direct": nothing was merged/vmapped (the catalog row
                 # defines `batched` as merged into a vmapped continuous batch)
                 _STEPS.inc(path="direct")
-                results[i] = y[:, :1]
+                results[i] = np.asarray(y)[:, :1]
                 record_transfer(results[i].nbytes, "device_to_host")
                 return results
             stack = _next_pow2(len(live))
             if span is not None:
                 span.set("bucket", stack)
+                span.set("cache", self._cache_kind(uid))
             _CALLS_BATCHED.inc()
             with _batch_phase("assemble"):
                 # handles only: the rows' caches go in as they are, the write
@@ -799,7 +901,7 @@ class DecodeSessionManager:
                 step = self._batched_fn(uid, stack)
             with _batch_phase("step"):
                 y, new_k, new_v, routing = step(backend.snapshot_params(), xs, caches_k, caches_v, indices)
-                output = _Output(y, routing, len(live))
+                output = _Output(y, routing, len(live), held_range(backend.module))
                 if fetch:
                     output.host()
                     output.settle(span)

@@ -22,6 +22,22 @@ in one of two ranks:
   broadcasts wrongly there, and only in batched steps
   (`tests/test_moe.py::test_custom_cached_block_steps_batched` is the pattern).
 
+The two caches may have any shape with the batch axis first, and the blocks of one
+chain need not agree on it (`exaone_moe_block`: ``[batch, kv_heads, slots, head_dim]``,
+a ring of ``window`` slots for a sliding-window block beside ``max_len`` slots for a
+full-attention one): the manager joins, splits, donates and places each block's caches
+as that block's ``init_decode_cache`` made them; that a session is full stays the
+manager's to say (``max_len``). Two optional class attributes: ``decode_takes_length =
+True`` makes the manager pass a fifth argument to a per-session call, the number of
+REAL positions of the chunk (a prefill comes right-padded to a power of two; a cache
+that keeps every position needs no telling, its padded tail lies past ``index``; a
+ring must keep the padding out); ``decode_cache_kind`` (a short string) names the
+block's decode programs (`jit_batched_step_<kind>`, `jit_prefill_<kind>_<positions>`)
+and its caches in the telemetry (`hivemind_moe_decode_cache_bytes{kind}`). A block
+whose expert layer holds a share of the experts says which in ``held_experts``
+(``(lo, hi)``), and the routing counters tell the pairs it computed from the pairs
+it chose (`moe/server/routing_stats.py`).
+
 Which rows meet in a batched step is decided per SPAN CHAIN, not per block: the steps
 of the sessions that wait on the same chain of this server's blocks walk it together
 (`DecodeSessionManager.decode_span_async`), so a block's ``[rows, 1, hidden]`` input
@@ -30,6 +46,7 @@ may rely on nothing about which sessions share its step."""
 
 from hivemind_tpu.moe.server.layers.common import (
     CausalTransformerExpert,
+    ExaoneMoeBlockExpert,
     FeedforwardExpert,
     NopExpert,
     TransformerExpert,

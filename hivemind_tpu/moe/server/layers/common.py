@@ -325,6 +325,209 @@ class OlmoeBlockExpert(nn.Module):
         return y if cache_k is None else (y, cache_k, cache_v)
 
 
+def _banded_attention(q, k, v, window: int):
+    """Causal attention in which position t sees the positions s with
+    ``t - window < s <= t``, on a whole chunk: ``q`` ``[batch, seq, heads, dim]``,
+    ``k``, ``v`` ``[batch, seq, kv_heads, dim]`` (each KV head serves ``heads /
+    kv_heads`` consecutive query heads, not repeated). The chunk is cut into blocks
+    of ``window`` queries, each held against its own and the previous block's keys:
+    O(seq * 2 window) scores, in float32, where the masked square takes O(seq^2)."""
+    batch, seq, heads, dim = q.shape
+    kv_heads = k.shape[2]
+    blocks = -(-seq // window)
+    pad = ((0, 0), (0, blocks * window - seq), (0, 0), (0, 0))
+    q = jnp.pad(q, pad).reshape(batch, blocks, window, kv_heads, heads // kv_heads, dim)
+
+    def behind_previous(t):  # [batch, blocks, 2 window, kv_heads, dim]
+        t = jnp.pad(t, pad).reshape(batch, blocks, window, kv_heads, dim)
+        previous = jnp.pad(t, ((0, 0), (1, 0), (0, 0), (0, 0), (0, 0)))[:, :-1]
+        return jnp.concatenate([previous, t], axis=2)
+
+    keys, values = behind_previous(k), behind_previous(v)
+    scores = jnp.einsum("bcqkgd,bcskd->bckgqs", q, keys, preferred_element_type=jnp.float32) * dim**-0.5
+    # query a of a block is position c*window + a, key b of its pair of blocks (c-1)*window + b
+    a, b = jnp.arange(window)[:, None], jnp.arange(2 * window)[None, :]
+    seen = (b > a) & (b <= a + window)
+    seen = seen[None] & ((b >= window)[None] | (jnp.arange(blocks) > 0)[:, None, None])  # no block before the first
+    scores = jnp.where(seen[None, :, None, None], scores, jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(values.dtype)
+    context = jnp.einsum("bckgqs,bcskd->bcqkgd", probs, values)
+    return context.reshape(batch, blocks * window, heads, dim)[:, :seq]
+
+
+def _grouped_cache_step(q, k_new, v_new, cache_k, cache_v, index):
+    """One position a row through caches kept ``[rows, kv_heads, slots, dim]``: row
+    r writes its key and value at slot ``index[r] mod slots`` and attends over the
+    slots written so far, the queries of a KV head grouped against that head's
+    cache as it lies (no copy of the cache at query width). With as many slots as
+    the session may have positions the cache is the whole past; with ``window``
+    slots it is a ring that holds exactly the positions ``index - window < s <=
+    index``, so ONE validity rule serves both: slot j is live iff ``j <= index``
+    (a ring that has wrapped is live everywhere). ``q`` ``[rows, 1, heads, dim]``,
+    ``k_new``, ``v_new`` ``[rows, 1, kv_heads, dim]``, ``index`` ``[rows]``.
+    Returns (context ``[rows, 1, heads * dim]``, cache_k, cache_v)."""
+    rows, _, heads, dim = q.shape
+    kv_heads, slots = cache_k.shape[1], cache_k.shape[2]
+
+    def write(cache, new, slot):  # one row: [kv_heads, slots, dim] <- [1, kv_heads, dim]
+        return jax.lax.dynamic_update_slice(cache, jnp.swapaxes(new, 0, 1).astype(cache.dtype), (0, slot, 0))
+
+    cache_k = jax.vmap(write)(cache_k, k_new, index % slots)
+    cache_v = jax.vmap(write)(cache_v, v_new, index % slots)
+    grouped = q.reshape(rows, kv_heads, heads // kv_heads, dim).astype(cache_k.dtype)
+    scores = jnp.einsum("rkgd,rksd->rkgs", grouped, cache_k, preferred_element_type=jnp.float32) * dim**-0.5
+    live = jnp.arange(slots)[None, :] <= index[:, None]
+    scores = jnp.where(live[:, None, None, :], scores, jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cache_v.dtype)
+    context = jnp.einsum("rkgs,rksd->rkgd", probs, cache_v)
+    return context.reshape(rows, 1, heads * dim), cache_k, cache_v
+
+
+def _prefill_into_cache(cache, new, length):
+    """A session's first chunk into its cache: ``new`` ``[batch, seq, kv_heads, dim]``
+    (right-padded; ``length`` positions are real) into ``cache`` ``[batch, kv_heads,
+    slots, dim]``. A cache that holds the chunk takes all of it (the padded tail
+    lies past ``index`` and is overwritten by the steps). A ring shorter than the
+    chunk takes the last ``slots`` REAL positions, each at its position mod slots:
+    slot j gets the largest position p < length with p = j (mod slots)."""
+    seq, slots = new.shape[1], cache.shape[2]
+    new = jnp.swapaxes(new, 1, 2).astype(cache.dtype)
+    if seq <= slots:
+        return jax.lax.dynamic_update_slice(cache, new, (0, 0, 0, 0))
+    slot = jnp.arange(slots)
+    position = slot + slots * ((length - 1 - slot) // slots)  # negative where no real position lands on the slot
+    taken = jnp.take(new, jnp.clip(position, 0, seq - 1), axis=2)
+    return jnp.where((position >= 0)[None, None, :, None], taken, cache)
+
+
+class ExaoneMoeBlockExpert(nn.Module):
+    """One K-EXAONE decoder block on [batch, seq, hid] (`model_type: exaone_moe`,
+    LG AI Research 2026; the equations are in `perf/reference/k_exaone_block.py`):
+    pre-RMSNorm; q / k / v without bias at a head size that is GIVEN (``num_heads *
+    head_dim`` need not be the hidden size); an RMS norm over each head's values of
+    q and k (one learned scale of ``head_dim`` each); causal softmax attention, each
+    KV head serving ``num_heads / num_kv_heads`` consecutive query heads; then a
+    SwiGLU MLP or a sparse expert layer. What kind of block it is follows from its
+    own sizes:
+
+    - ``window`` > 0: a sliding-window block. Rotary embedding (rotate-half) on q and
+      k, position t attends ``t - window < s <= t``, and a decode session keeps a RING
+      of ``window`` slots. ``window`` = 0: a full-attention block, causal over all
+      positions, NO rotary embedding (the family's convention for its global
+      layers), a cache of ``max_len`` slots.
+    - ``ffn_inner`` > 0: a dense SwiGLU MLP of that width (the leading dense
+      blocks). ``ffn_inner`` = 0: the sparse layer: sigmoid router over
+      ``num_experts`` with a per-expert selection bias that picks and does not weigh,
+      the ``experts_per_token`` picked scores renormalised and scaled by
+      ``routed_scale`` (`ops.sparse_experts.route_sigmoid_top_k`), one shared SwiGLU
+      expert of width ``expert_inner`` for every token, and the routed experts
+      ``[held_lo, held_lo + held)`` of width ``expert_inner`` that THIS server holds
+      (``held`` = 0: all of them). The router keeps its ``num_experts`` outputs; a
+      pair routed to an expert held elsewhere adds nothing here
+      (`routed_swiglu_held`), as under expert parallelism before the exchange.
+
+    Decode caches are kept ``[batch, kv_heads, slots, head_dim]`` in bf16, so that a
+    step attends per KV head over the cache as it lies. The chosen experts (over
+    all ``num_experts``) are sown into `ROUTING_COLLECTION`; `held_experts` tells the
+    serving paths which of them were computed here."""
+
+    hidden_dim: int
+    num_heads: int = 64
+    num_kv_heads: int = 8
+    head_dim: int = 128
+    window: int = 0
+    rope_theta: float = 1000000.0
+    rms_eps: float = 1e-5
+    ffn_inner: int = 0
+    num_experts: int = 128
+    experts_per_token: int = 8
+    expert_inner: int = 2048
+    held_lo: int = 0
+    held: int = 0
+    routed_scale: float = 2.5
+
+    # a prefill chunk comes right-padded to a power of two: `DecodeSessionManager`
+    # hands a block that says so the number of real positions (``length``), which a
+    # ring needs to keep the padding out
+    decode_takes_length = True
+
+    @property
+    def decode_cache_kind(self) -> str:
+        """Names the block's decode programs and its caches in the telemetry."""
+        return "window" if self.window else "full"
+
+    @property
+    def held_experts(self):
+        """``(lo, hi)`` of the routed experts computed here; None where all are, or none exist."""
+        if self.ffn_inner or self.held in (0, self.num_experts):
+            return None
+        return self.held_lo, self.held_lo + self.held
+
+    def init_decode_cache(self, batch: int, max_len: int):
+        shape = (batch, self.num_kv_heads, self.window or max_len, self.head_dim)
+        return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+
+    def _attention_half(self, x, cache_k, cache_v, index, length):
+        from hivemind_tpu.ops.pallas_attention import attention_auto
+
+        batch, seq, _hid = x.shape
+        heads, kv_heads, dim = self.num_heads, self.num_kv_heads, self.head_dim
+        assert heads % kv_heads == 0, (heads, kv_heads)
+        normed = nn.RMSNorm(epsilon=self.rms_eps, dtype=jnp.bfloat16, name="attention_norm")(x)
+        q = _plain_dense(heads * dim, "query")(normed).reshape(batch, seq, heads, dim)
+        k = _plain_dense(kv_heads * dim, "key")(normed).reshape(batch, seq, kv_heads, dim)
+        v = _plain_dense(kv_heads * dim, "value")(normed).reshape(batch, seq, kv_heads, dim)
+        q = nn.RMSNorm(epsilon=self.rms_eps, dtype=jnp.bfloat16, name="query_norm")(q)  # over each head's values
+        k = nn.RMSNorm(epsilon=self.rms_eps, dtype=jnp.bfloat16, name="key_norm")(k)
+        if self.window:
+            offset = 0 if cache_k is None else index  # decode: rotate at the absolute position
+            q, k = apply_rope(q, self.rope_theta, offset), apply_rope(k, self.rope_theta, offset)
+        if cache_k is not None and seq == 1:
+            rows = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (batch,))
+            context, cache_k, cache_v = _grouped_cache_step(q, k, v, cache_k, cache_v, rows)
+        else:
+            # a whole chunk: the pool's forward, or a session's prefill (it starts the
+            # session: the cache holds nothing before it)
+            if cache_k is not None:
+                length = seq if length is None else length
+                cache_k, cache_v = _prefill_into_cache(cache_k, k, length), _prefill_into_cache(cache_v, v, length)
+            if self.window:
+                context = _banded_attention(q, k, v, self.window)
+            else:
+                repeat = lambda t: jnp.repeat(t, heads // kv_heads, axis=2)
+                context = attention_auto(q, repeat(k), repeat(v), causal=True)
+            context = context.reshape(batch, seq, heads * dim)
+        return x + _plain_dense(self.hidden_dim, "attention_out")(context), cache_k, cache_v
+
+    @nn.compact
+    def __call__(self, x, cache_k=None, cache_v=None, index=None, length=None):
+        from hivemind_tpu.ops.sparse_experts import route_sigmoid_top_k, routed_swiglu_held
+
+        batch, seq, hid = x.shape
+        x, cache_k, cache_v = self._attention_half(x, cache_k, cache_v, index, length)
+        normed = nn.RMSNorm(epsilon=self.rms_eps, dtype=jnp.bfloat16, name="ffn_norm")(x)
+        swiglu = lambda prefix, width: _plain_dense(hid, prefix + "_down")(
+            jax.nn.silu(_plain_dense(width, prefix + "_gate")(normed)) * _plain_dense(width, prefix + "_up")(normed))
+        if self.ffn_inner:
+            y = (x + swiglu("ffn", self.ffn_inner)).astype(jnp.float32)
+            return y if cache_k is None else (y, cache_k, cache_v)
+        per_expert = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+        held, inner = self.held or self.num_experts, self.expert_inner
+        router = self.param("router", nn.initializers.lecun_normal(), (hid, self.num_experts), jnp.float32)
+        # a checkpoint brings its own; a seeded one is drawn wide enough to change some picks
+        bias = self.param("router_bias", nn.initializers.normal(0.1), (self.num_experts,), jnp.float32)
+        w_gate = self.param("experts_gate", per_expert, (held, hid, inner), jnp.float32)
+        w_up = self.param("experts_up", per_expert, (held, hid, inner), jnp.float32)
+        w_down = self.param("experts_down", per_expert, (held, inner, hid), jnp.float32)
+        tokens = normed.reshape(batch * seq, hid)  # the call's rows together
+        weights, top_e = route_sigmoid_top_k(tokens, router, bias, self.experts_per_token, self.routed_scale)
+        self.sow(ROUTING_COLLECTION, "expert_choice", top_e.reshape(batch, seq, -1))
+        with jax.named_scope("moe_experts"):
+            routed = routed_swiglu_held(tokens, weights, top_e, w_gate, w_up, w_down, self.held_lo)
+        y = (x + swiglu("shared", inner) + routed.reshape(batch, seq, hid)).astype(jnp.float32)
+        return y if cache_k is None else (y, cache_k, cache_v)
+
+
 class NopExpert(nn.Module):
     """Identity with a dummy parameter (reference 'nop' expert for transport tests)."""
 
@@ -341,4 +544,5 @@ register_expert_class("transformer", lambda batch, hid: np.zeros((batch, 64, hid
 register_expert_class("causal_transformer", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))(CausalTransformerExpert)
 register_expert_class("llama_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))(LlamaBlockExpert)
 register_expert_class("olmoe_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))(OlmoeBlockExpert)
+register_expert_class("exaone_moe_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))(ExaoneMoeBlockExpert)
 register_expert_class("nop", lambda batch, hid: np.zeros((batch, hid), np.float32))(NopExpert)

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from hivemind_tpu.compression import CompressionType
-from hivemind_tpu.moe.server.routing_stats import ROUTING_COLLECTION, record_routing
+from hivemind_tpu.moe.server.routing_stats import ROUTING_COLLECTION, held_range, record_routing
 from hivemind_tpu.telemetry.device import record_transfer
 from hivemind_tpu.telemetry.serving import accrue_span_phase
 from hivemind_tpu.telemetry.tracing import current_span, trace_sync as _trace_sync
@@ -336,16 +336,17 @@ def forward_chain(backends: Sequence[ModuleBackend], *inputs: np.ndarray) -> Lis
     assert len(inputs) == backends[0].num_inputs, (len(inputs), backends[0].num_inputs)
     with _staging("backend.stage_in"):
         current, rows = _stage_in(inputs, min(b.max_batch_size for b in backends))
-    in_flight, routings = deque(), []
+    in_flight, routings, helds = deque(), [], []
     for backend in backends:
         with _trace_sync("backend.device", uid=backend.name):
             current, routing = backend.forward_on_device(*current)
             routings.append(routing)
+            helds.append(held_range(backend.module))
             _dispatched(in_flight, current)
             if backend is backends[-1]:
                 jax.block_until_ready(current)
     results = _fetch(current, rows)
-    record_routing(routings, "pool", current_span(), rows=rows)  # onto the pool.batch span around this call
+    record_routing(routings, "pool", current_span(), rows=rows, held=helds)  # onto the pool.batch span around this call
     return results
 
 
@@ -367,19 +368,21 @@ def backward_chain(backends: Sequence[ModuleBackend], *tensors: np.ndarray) -> L
         max_batch_size = min(b.max_batch_size for b in backends)
         current, rows = _stage_in(tensors[:num_inputs], max_batch_size)
         grads, _ = _stage_in(tensors[num_inputs:], max_batch_size)
-    in_flight, routings, block_inputs = deque(), [], []
+    in_flight, routings, helds, block_inputs = deque(), [], [], []
     for backend in backends[:-1]:
         block_inputs.append(current)
         with _trace_sync("backend.device", uid=backend.name, sweep="forward"):
             current, routing = backend.forward_on_device(*current)
             routings.append(routing)
+            helds.append(held_range(backend.module))
             _dispatched(in_flight, current)
     block_inputs.append(current)
     for backend in reversed(backends):
         with _trace_sync("backend.device", uid=backend.name, sweep="backward"):
             grads, routing = backend.backward_on_device(block_inputs.pop(), grads)
             routings.append(routing)
+            helds.append(held_range(backend.module))
             jax.block_until_ready(grads)
     results = _fetch(grads, rows)
-    record_routing(routings, "pool", current_span(), rows=rows)
+    record_routing(routings, "pool", current_span(), rows=rows, held=helds)
     return results

@@ -6,11 +6,17 @@ every block with that collection mutable and return it from their jits beside th
 block's output. Rows and positions that only pad a bucket are part of the program
 but not of the traffic: the caller says how many rows and positions are live, and
 only those are counted, here on the host. A block without experts hands out an
-empty collection and nothing is counted."""
+empty collection and nothing is counted.
+
+A layer that holds a share of the experts (`ops.sparse_experts.routed_swiglu_held`)
+chooses among all of them and computes the pairs whose expert it holds: the caller
+names the held range, the pairs chosen are counted as before, the held ones beside
+them, and the experts hit are then the held ones (the others' weights are not here
+to be read)."""
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import numpy as np
@@ -18,7 +24,7 @@ import numpy as np
 from hivemind_tpu.moe.server.layers.common import ROUTING_COLLECTION
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 
-__all__ = ["ROUTING_COLLECTION", "record_routing"]
+__all__ = ["ROUTING_COLLECTION", "held_range", "record_routing"]
 
 _PATH_HELP = "by serving path (batched / direct = decode sessions, pool = TaskPool forward and backward)"
 _LAYER_CALLS = _TELEMETRY.counter(
@@ -32,28 +38,47 @@ _EXPERTS_HIT = _TELEMETRY.counter(
 _EXPERT_MAX_PAIRS = _TELEMETRY.counter(
     "hivemind_moe_expert_max_pairs_total",
     f"live tokens of the fullest expert, summed over expert-layer calls, {_PATH_HELP}", ("path",))
+_HELD_PAIRS = _TELEMETRY.counter(
+    "hivemind_moe_held_pairs_total",
+    f"routed (token, expert) pairs whose expert this server holds, i.e. the pairs it computed: all of them "
+    f"for a layer that holds every expert, {_PATH_HELP}", ("path",))
+
+
+def held_range(module) -> Optional[Tuple[int, int]]:
+    """The experts ``[lo, hi)`` that a block's expert layer holds, as the block says
+    (``held_experts``); None for a block that holds all it routes over, or has none."""
+    return getattr(module, "held_experts", None)
 
 
 def record_routing(routing, path: str, span=None, rows: Optional[int] = None,
-                   positions: Optional[int] = None) -> None:
+                   positions: Optional[int] = None, held: Optional[Tuple[int, int]] = None) -> None:
     """Count one call's routing onto the `hivemind_moe_*{path}` counters and, as
-    ``experts_hit`` and ``pairs``, onto ``span`` (the call's `decode.batch` /
-    `decode.direct` / `pool.batch`). ``rows`` / ``positions``: how many leading
-    rows and positions of each leaf are live (None = all)."""
-    leaves = jax.tree_util.tree_leaves(routing)
+    ``experts_hit``, ``pairs`` and ``held_pairs``, onto ``span`` (the call's
+    `decode.batch` / `decode.direct` / `pool.batch`). ``rows`` / ``positions``: how
+    many leading rows and positions of each leaf are live (None = all). ``held``:
+    the range ``(lo, hi)`` of experts the layer holds (`held_range`; None = all):
+    ``experts_hit`` and the fullest expert are then taken among those. For a chain
+    of blocks, ``routing`` and ``held`` are lists, one entry a block."""
+    per_block = zip(routing, held) if isinstance(held, list) else [(routing, held)]
+    leaves = [(leaf, block_held) for block, block_held in per_block for leaf in jax.tree_util.tree_leaves(block)]
     if not leaves:
         return
-    pairs = hit = fullest = 0
-    for leaf in leaves:
+    pairs = held_pairs = hit = fullest = 0
+    for leaf, block_held in leaves:
         chosen = np.asarray(leaf)[:rows, :positions].reshape(-1)
-        per_expert = np.bincount(chosen)
         pairs += chosen.size
+        if block_held is not None:
+            chosen = chosen[(chosen >= block_held[0]) & (chosen < block_held[1])]
+        per_expert = np.bincount(chosen)
+        held_pairs += chosen.size
         hit += int(np.count_nonzero(per_expert))
         fullest += int(per_expert.max(initial=0))
     _LAYER_CALLS.inc(len(leaves), path=path)
     _ROUTED_PAIRS.inc(pairs, path=path)
     _EXPERTS_HIT.inc(hit, path=path)
     _EXPERT_MAX_PAIRS.inc(fullest, path=path)
+    _HELD_PAIRS.inc(held_pairs, path=path)
     if span is not None:
         span.set("experts_hit", hit)
         span.set("pairs", pairs)
+        span.set("held_pairs", held_pairs)

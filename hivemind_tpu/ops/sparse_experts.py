@@ -15,7 +15,11 @@ this code.
 
 On a TPU `ragged_dot` is the compiler's own grouped-matmul kernel
 (`ragged-dot-none`, with `ragged-dot-metadata` before it, in a device trace); it is
-differentiable (its transposes are ragged dots again), so `jax.vjp` goes through."""
+differentiable (its transposes are ragged dots again), so `jax.vjp` goes through.
+
+A layer may hold a SHARE of the experts (`routed_swiglu_held`: expert parallelism's
+layer without its exchange): it routes over all of them, computes the pairs whose
+expert it holds and leaves the others out."""
 
 from __future__ import annotations
 
@@ -35,6 +39,28 @@ def route_top_k(tokens: jax.Array, router: jax.Array, k: int) -> Tuple[jax.Array
     return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
 
 
+def _grouped_swiglu(tokens: jax.Array, weights: jax.Array, flat: jax.Array, w_gate: jax.Array,
+                    w_up: jax.Array, w_down: jax.Array, elsewhere: bool) -> jax.Array:
+    """The body of both entry points. ``flat``: each (token, slot) pair's group, the
+    index of its expert among ``w_gate``'s; with ``elsewhere``, the index one past
+    the last for a pair whose expert is not among them. The pairs are sorted by group
+    once, every group is one contiguous run of rows for the three grouped matmuls, and
+    the rows past the last group (``elsewhere``) add zero."""
+    count, k = weights.shape
+    groups = w_gate.shape[0]
+    order = jnp.argsort(flat, stable=True)  # pairs sorted by expert: one group each
+    sizes = jnp.bincount(flat, length=groups + elsewhere)[:groups].astype(jnp.int32)
+    rounded = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
+    rows = rounded(tokens)[order // k]
+    gate = jax.lax.ragged_dot(rows, w_gate, sizes)
+    up = jax.lax.ragged_dot(rows, w_up, sizes)
+    down = jax.lax.ragged_dot(rounded(jax.nn.silu(gate) * up), w_down, sizes)
+    if elsewhere:  # rows past the held groups belong to no group: whatever the kernel left there is not read
+        down = jnp.where((jnp.arange(count * k) < sizes.sum())[:, None], down, 0.0)
+    per_pair = down[jnp.argsort(order)].reshape(count, k, -1)  # back to [token, slot]
+    return jnp.einsum("tkh,tk->th", per_pair, weights.astype(jnp.float32))
+
+
 def routed_swiglu(tokens: jax.Array, top_p: jax.Array, top_e: jax.Array,
                   w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
     """``sum_j top_p[t, j] * down_e(silu(gate_e(x_t)) * up_e(x_t))``, ``e = top_e[t, j]``.
@@ -48,15 +74,33 @@ def routed_swiglu(tokens: jax.Array, top_p: jax.Array, top_e: jax.Array,
     operands in one bf16 pass, so this is the block's other matmuls' arithmetic
     without a bf16 copy of every expert's weights written to memory at each call
     (the CPU multiplies the same operands in float32)."""
-    count, k = top_e.shape
-    experts = w_gate.shape[0]
-    flat = top_e.reshape(-1)
-    order = jnp.argsort(flat, stable=True)  # pairs sorted by expert: one group each
-    sizes = jnp.bincount(flat, length=experts).astype(jnp.int32)
-    rounded = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
-    rows = rounded(tokens)[order // k]
-    gate = jax.lax.ragged_dot(rows, w_gate, sizes)
-    up = jax.lax.ragged_dot(rows, w_up, sizes)
-    down = jax.lax.ragged_dot(rounded(jax.nn.silu(gate) * up), w_down, sizes)
-    per_pair = down[jnp.argsort(order)].reshape(count, k, -1)  # back to [token, slot]
-    return jnp.einsum("tkh,tk->th", per_pair, top_p.astype(jnp.float32))
+    return _grouped_swiglu(tokens, top_p, top_e.reshape(-1), w_gate, w_up, w_down, elsewhere=False)
+
+
+def route_sigmoid_top_k(tokens: jax.Array, router: jax.Array, bias: jax.Array, k: int,
+                        scale: float) -> Tuple[jax.Array, jax.Array]:
+    """The DeepSeek-V3 family's router: sigmoid scores over all experts in float32,
+    the k experts with the largest ``score + bias`` (the bias picks and does not
+    weigh), each weighed by its own score over the sum of the k picked scores, times
+    ``scale``: ``(weights [tokens, k], top_e [tokens, k])``. The matmul runs at the
+    highest precision, as in `route_top_k`."""
+    logits = jnp.dot(tokens.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, top_e = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    picked = jnp.take_along_axis(scores, top_e, axis=-1)
+    return scale * picked / picked.sum(-1, keepdims=True), top_e
+
+
+def routed_swiglu_held(tokens: jax.Array, weights: jax.Array, top_e: jax.Array, w_gate: jax.Array,
+                       w_up: jax.Array, w_down: jax.Array, lo: int) -> jax.Array:
+    """`routed_swiglu` for a layer that holds the experts ``[lo, lo + held)`` of those
+    the router chooses among: ``w_gate, w_up`` are ``[held, hidden, width]``, ``w_down``
+    ``[held, width, hidden]``, ``top_e`` counts over ALL experts. The pairs are sorted
+    once, held experts first and in order, the pairs routed elsewhere last; the grouped
+    matmuls run over the held groups only, and a pair routed elsewhere adds zero (what
+    its expert would add is another chip's to compute: nothing stands in for it)."""
+    held = w_gate.shape[0]
+    local = top_e.reshape(-1) - lo
+    flat = jnp.where((local >= 0) & (local < held), local, held)  # routed elsewhere: past the last held group
+    return _grouped_swiglu(tokens, weights, flat, w_gate, w_up, w_down, elsewhere=True)

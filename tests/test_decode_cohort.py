@@ -390,3 +390,41 @@ def test_closed_loop_sessions_under_a_short_switch_interval_keep_their_streams()
     assert manager._in_flight == {} and not manager._pending.get(CHAIN)
     assert not any(session.lock.locked() for session in manager._sessions.values())
     assert all(session.index == 3 + steps for session in manager._sessions.values())
+
+
+def test_a_prefill_is_one_chain_on_the_device():
+    """A prompt walks the chain's blocks on the device (ISSUE 34): ONE upload (the
+    padded chunk) and ONE fetch (the last block's output, its real positions) whatever
+    the chain's length, one `decode.direct` a block, every session advanced, and the
+    output is what block-by-block calls give: past the first block the padded tail
+    holds what the block before made of the padding, which no real position sees."""
+    manager = _manager()
+    rng = np.random.RandomState(34)
+    prompt = rng.randn(1, 5, HID).astype(np.float32)  # pads to 8 positions
+    moved = REGISTRY.get("hivemind_device_transfer_bytes_total")
+    level = lambda: np.array([moved.labels(direction).value for direction in ("host_to_device", "device_to_host")])
+    before = level()
+    with _Spans(CHAIN) as seen:
+        chained = manager._decode_direct(CHAIN, "chained", prompt, True)
+    assert list(level() - before) == [8 * HID * 4, 5 * HID * 4]
+    assert [s.attributes["uid"] for s in seen.spans if s.name == "decode.direct"] == list(CHAIN)
+    prefilled = lambda: (REGISTRY.get("hivemind_moe_decode_prefill_positions_total").value(),
+                         REGISTRY.get("hivemind_moe_decode_prefill_seconds_total").value())
+    positions, seconds = prefilled()
+    stepwise = prompt
+    for uid in CHAIN:
+        stepwise = manager.decode(uid, "stepwise", stepwise, True)
+    assert chained.shape == (1, 5, HID)
+    assert prefilled()[0] - positions == 3 * 8 and prefilled()[1] > seconds  # the padded chunk, once a block; a step counts nothing
+    np.testing.assert_allclose(chained, stepwise, rtol=1e-5, atol=1e-6)
+    token = rng.randn(1, 1, HID).astype(np.float32)
+    before = level()
+    chained = manager._decode_direct(CHAIN, "chained", token, False)
+    assert list(level() - before) == [HID * 4, HID * 4]
+    for uid in CHAIN:
+        token = manager.decode(uid, "stepwise", token, False)
+    np.testing.assert_allclose(chained, token, rtol=1e-5, atol=1e-6)
+    assert all(session.index == 6 for session in manager._sessions.values())
+    assert prefilled()[0] - positions == 3 * 8
+    with pytest.raises(KeyError, match="unknown or expired"):
+        manager._decode_direct(CHAIN, "never-opened", token, False)
