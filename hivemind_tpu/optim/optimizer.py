@@ -14,13 +14,22 @@ Semantics match the reference: progress is measured in virtual "epochs" of
 ``target_batch_size`` samples accumulated ACROSS the swarm; when the swarm reaches the
 target, peers average their accumulated gradients (weighted by contribution), apply
 one optax update each, and advance the epoch — equivalent to large-batch synchronous
-training, invariant to swarm size (reference optimizer.py:63-69)."""
+training, invariant to swarm size (reference optimizer.py:63-69).
+
+The periodic STATE round (parameters and optimizer statistics) is never on the stepping
+thread: the transition that owes one launches it on the background worker and returns;
+it lands behind the next epoch's steps by the delta rule (``current + average −
+snapshot``), and the next transition that owes a round waits for it first. This is the
+reference's ``delay_state_averaging=True`` default with ``delta_rule_averaging``, here
+the only behaviour (docs/parity_map.md)."""
 
 from __future__ import annotations
 
 import contextlib
 import threading
+import time
 from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import wait as _wait_for_futures
 from typing import Any, Iterable, Optional
 
 import numpy as np
@@ -61,7 +70,8 @@ class Optimizer(ChronicFailureTracking):
     :param batch_size_per_step: default samples per local step (overridable per call)
     :param use_local_updates: apply optax updates locally every step and average
         PARAMETERS periodically instead of gradients (asynchronous mode)
-    :param average_state_every: average parameters/opt stats every N epochs
+    :param average_state_every: average parameters/opt stats every N epochs, in a
+        background round (module docstring)
     :param auxiliary: no data/gradients of its own; assists group averaging only.
         If no gradient schema is provided, it is bootstrapped from the swarm
         (state download from a running gradient averager) — aux peers need zero
@@ -73,15 +83,6 @@ class Optimizer(ChronicFailureTracking):
         state_averager.py:478-574 background executor)
     :param delay_grad_averaging: alias that implies delay_optimizer_step (kept for
         reference API parity; the background task always overlaps both)
-    :param delay_state_averaging: run the periodic state-averaging round on a
-        background thread (reference optimizer.py:129-130). Independent of
-        delay_optimizer_step — with ``use_local_updates`` this is the canonical
-        local-SGD combination (pair with ``delta_rule_averaging`` so local steps
-        taken during the round survive). In full DPU mode the whole transition is
-        already backgrounded, so the flag adds nothing there.
-    :param delta_rule_averaging: apply state-averaging results as deltas so optimizer
-        steps running concurrently with the round survive (required for DPU/local
-        updates; reference state_averager.py:73-74)
     :param checkpoint_dir: when set (non-auxiliary peers), keep crash-safe local
         checkpoints there: atomically-published, digest-stamped snapshots saved on
         an epoch cadence and restored at startup, so a machine reboot costs a file
@@ -112,8 +113,6 @@ class Optimizer(ChronicFailureTracking):
         use_local_updates: bool = False,
         delay_optimizer_step: bool = False,
         delay_grad_averaging: bool = False,
-        delay_state_averaging: bool = False,
-        delta_rule_averaging: bool = False,
         client_mode: bool = False,
         auxiliary: bool = False,
         grad_compression: CompressionBase = Float16Compression(),
@@ -145,7 +144,6 @@ class Optimizer(ChronicFailureTracking):
         self.use_local_updates = use_local_updates
         self.delay_optimizer_step = delay_optimizer_step or delay_grad_averaging
         self.delay_grad_averaging = delay_grad_averaging
-        self.delay_state_averaging = delay_state_averaging
         assert not (self.delay_optimizer_step and use_local_updates), (
             "delayed updates apply to collaborative (gradient-averaging) mode"
         )
@@ -154,12 +152,13 @@ class Optimizer(ChronicFailureTracking):
         self.verbose = verbose
         self.scheduled_grads: Optional[StepControl] = None
         self._step_lock = threading.Lock()
+        # ONE background worker: the state rounds, and under delay_optimizer_step the
+        # transitions too (a round is then queued behind the transition that owes it)
         self._update_executor: Optional[ThreadPoolExecutor] = (
-            ThreadPoolExecutor(max_workers=1, thread_name_prefix="hm_dpu")
-            if (self.delay_optimizer_step or delay_state_averaging)
-            else None
+            None if auxiliary else ThreadPoolExecutor(max_workers=1, thread_name_prefix="hm_dpu")
         )
         self._pending_update: Optional[Future] = None
+        self._state_round: Optional[Future] = None  # the state round in flight: never two
         # chronic-degradation tracking: every epoch that ends without a successful
         # swarm averaging round counts; after `chronic_failure_threshold` in a row
         # the condition escalates to ERROR and matchmaking backs off exponentially
@@ -182,8 +181,9 @@ class Optimizer(ChronicFailureTracking):
         )
         self.state_averager: Optional[TrainingStateAverager] = None
         if not auxiliary:
-            state_opts = dict(state_averager_opts or {})
-            state_opts.setdefault("delta_rule_averaging", delta_rule_averaging)
+            # a round lands while training goes on: only the delta rule keeps the
+            # optimizer steps taken meanwhile
+            state_opts = dict(state_averager_opts or {}, delta_rule_averaging=True)
             # local-updates peers take many optax steps per epoch, so their step
             # counters must never be rewound to the epoch number
             state_opts.setdefault("count_equals_epoch", not use_local_updates)
@@ -311,7 +311,7 @@ class Optimizer(ChronicFailureTracking):
         (reference use_local_updates, optimizer.py:143-145)."""
         assert self.state_averager is not None
         if grads is not None:
-            # the compute lane of the step timeline (ISSUE 19): a delayed
+            # the compute lane of the step timeline (ISSUE 19): a background
             # state-averaging round overlapping these spans is the overlap
             # efficiency being measured
             with _sync_span("optimizer.update", peer=str(self.dht.peer_id)):
@@ -319,28 +319,17 @@ class Optimizer(ChronicFailureTracking):
         new_samples = self.tracker.local_progress.samples_accumulated + batch_size
         self.tracker.report_local_progress(self.local_epoch, new_samples)
         if self.tracker.ready_to_update_epoch:
+            phases = _EpochPhases(peer=str(self.dht.peer_id), epoch=self.local_epoch + 1)
             self.state_averager.local_epoch += 1
-            if self.local_epoch % self.average_state_every == 0:
-                if self.delay_state_averaging and self._update_executor is not None:
-                    # overlap the round with further local steps; delta-rule
-                    # averaging makes those concurrent steps survive the merge
-                    if self._pending_update is None or self._pending_update.done():
-                        self._finish_pending_update()
-                        self._pending_update = self._update_executor.submit(
-                            self.state_averager.do_averaging_round,
-                            timeout=self.averaging_timeout,
-                            scheduled_time=get_dht_time() + self._matchmaking_delay(),
-                        )
-                else:
-                    self.state_averager.do_averaging_round(
-                        timeout=self.averaging_timeout,
-                        scheduled_time=get_dht_time() + self._matchmaking_delay(),
-                    )
+            self._hand_over_state_round(
+                self.local_epoch, self.local_epoch % self.average_state_every == 0, phases
+            )
             self._maybe_save_checkpoint(self.local_epoch)
             _LEDGER.record_epoch(
                 self.local_epoch,
                 peer=str(self.dht.peer_id),
                 num_peers=self.tracker.global_progress.num_peers,
+                **phases.fields(),
             )
             self.tracker.update_epoch(self.local_epoch)
         return self.state_averager.params
@@ -430,18 +419,18 @@ class Optimizer(ChronicFailureTracking):
         assert self.state_averager is not None
         self._record_round_outcome(averaged_ok)
         self.state_averager.local_epoch = next_epoch
-        if self.average_state_every and next_epoch % self.average_state_every == 0 and self.tracker.global_progress.num_peers > 1:
-            with phases.phase("state_round"):
-                self.state_averager.do_averaging_round(
-                    timeout=self.averaging_timeout,
-                    scheduled_time=get_dht_time() + self._matchmaking_delay(),
-                )
+        self._hand_over_state_round(
+            next_epoch,
+            bool(self.average_state_every) and next_epoch % self.average_state_every == 0
+            and self.tracker.global_progress.num_peers > 1,
+            phases,
+        )
         self.state_averager.state_sharing_priority = next_epoch
-        # checkpoint AFTER the state-averaging round so the file holds the
-        # swarm-averaged tensors this epoch actually produced
+        # the file holds the state as it stands: this epoch's update applied and every
+        # state round but the one just launched landed
         self._maybe_save_checkpoint(next_epoch)
-        # attribution ledger (ISSUE 8): close this epoch's record AFTER both
-        # averaging rounds, so the rounds-since-last-epoch rollup covers them
+        # attribution ledger (ISSUE 8): the rounds-since-last-epoch rollup covers this
+        # epoch's gradient round and the state round that landed during the epoch
         _LEDGER.record_epoch(
             next_epoch,
             peer=str(self.dht.peer_id),
@@ -455,6 +444,54 @@ class Optimizer(ChronicFailureTracking):
                 f"transitioned to epoch {next_epoch} "
                 f"(averaged={averaged_ok}, peers={self.tracker.global_progress.num_peers})"
             )
+
+    # ------------------------------------------------------------------ background state round
+
+    def _hand_over_state_round(self, epoch: int, due: bool, phases: _EpochPhases) -> None:
+        """A transition's whole dealing with state rounds. One that owes a round (``due``)
+        LAUNCHES it on the background worker and goes on; the round lands later by the
+        delta rule. Never two in flight and never one skipped: the round before is
+        waited for first (reference ``wait_for_delayed_updates``), so rounds stay one an
+        ``average_state_every`` epochs and a parameter is at most that far behind its
+        average. The length of a round that has landed since the last record goes on
+        this one (``state_round_s``), the wait on ``state_round_wait_s``."""
+        assert self._update_executor is not None
+        landed = self._state_round
+        if landed is not None and (due or landed.done()):
+            if not landed.done():
+                with phases.phase("state_round_wait"):
+                    _wait_for_futures([landed])
+            self._state_round = None
+            phases.landed("state_round", landed.result())
+        if due:
+            # both peers of a group launch at the same transition, so the lead time is
+            # reckoned here and not when the worker gets to the round
+            self._state_round = self._update_executor.submit(
+                self._background_state_round, epoch, get_dht_time() + self._matchmaking_delay()
+            )
+
+    def _background_state_round(self, epoch: int, scheduled_time: float) -> float:
+        """On the background worker: snapshot, matchmaking, all-reduce, landing. Returns
+        its own seconds. A failed round is logged (and in the ``RoundLedger``) and costs
+        no epoch, as a failed state round never did."""
+        assert self.state_averager is not None
+        began = time.perf_counter()
+        try:
+            with _sync_span("optimizer.state_round", peer=str(self.dht.peer_id), epoch=epoch):
+                self.state_averager.do_averaging_round(
+                    timeout=self.averaging_timeout, scheduled_time=scheduled_time
+                )
+        except Exception as e:  # the worker must live on; do_averaging_round logs its own
+            logger.warning(f"background state averaging round failed: {e!r}", exc_info=True)
+        return time.perf_counter() - began
+
+    def _wait_for_state_round(self, timeout: Optional[float] = None) -> None:
+        """Whoever reads or replaces the WHOLE state (a user's checkpoint, a forced save,
+        a download from the swarm) lets the round in flight land first; the transition
+        that follows still collects it."""
+        in_flight = self._state_round
+        if in_flight is not None:
+            _wait_for_futures([in_flight], timeout)
 
     # ------------------------------------------------------------------ delayed (DPU)
 
@@ -537,6 +574,7 @@ class Optimizer(ChronicFailureTracking):
         """We are behind the swarm: adopt a peer's state
         (reference _should_load_state_from_peers + load_state_from_peers)."""
         assert self.state_averager is not None
+        self._wait_for_state_round()  # its delta belongs to the state about to be replaced
         global_epoch = self.tracker.global_epoch
         logger.info(
             f"local epoch {self.local_epoch} is behind the swarm ({global_epoch}); "
@@ -575,7 +613,8 @@ class Optimizer(ChronicFailureTracking):
         recovery.LocalCheckpointStore). The epoch-consistent snapshot is captured
         here; serialize+write+fsync runs on the checkpoint executor so the
         training step is never blocked on disk (``force`` — shutdown / just after
-        a catch-up — saves synchronously for durability). A save still in flight
+        a catch-up, both of which have let the state round in flight land — saves
+        synchronously for durability). A save still in flight
         when the next cadence hits is not queued behind: that epoch is skipped.
         Failures never fail the step — a peer with a broken disk keeps training,
         loudly."""
@@ -647,20 +686,23 @@ class Optimizer(ChronicFailureTracking):
 
     def load_state_from_peers(self, timeout: Optional[float] = None) -> bool:
         assert self.state_averager is not None
+        self._wait_for_state_round()
         return self.state_averager.load_full_state_from_peers(timeout=timeout or self.load_state_timeout)
 
     # ------------------------------------------------------------------ checkpointing
 
     def state_dict(self) -> dict:
         """User-level checkpoint with the epoch embedded
-        (reference optimizer.py:719-727)."""
+        (reference optimizer.py:719-727). Waits for the state round in flight."""
         assert self.state_averager is not None
+        self._wait_for_state_round()
         return self.state_averager.state_dict()
 
     def load_state_dict(self, state: dict) -> None:
         """Restore a checkpoint: tensors + epoch, with LR schedules replayed to the
         restored epoch (reference state_averager.py:700-704)."""
         assert self.state_averager is not None
+        self._wait_for_state_round()
         self.state_averager.load_state_dict(state)
         if self.grad_averager is not None:
             self.grad_averager.reset_accumulated_grads_()
@@ -669,7 +711,7 @@ class Optimizer(ChronicFailureTracking):
         if self._pending_update is not None:
             self._finish_pending_update(timeout=self.averaging_timeout)
         if self._update_executor is not None:
-            self._update_executor.shutdown(wait=True)
+            self._update_executor.shutdown(wait=True)  # the state round in flight lands
         # final checkpoint: a clean shutdown restores exactly where it stopped
         # (drain the background writer first so the forced save is the newest)
         if self._checkpoint_executor is not None:

@@ -38,8 +38,9 @@ class TrainingStateAverager(DecentralizedAverager):
     :param extra_tensors: additional arrays averaged and shared with state downloads
     :param delta_rule_averaging: apply each averaging round's result as a DELTA
         (average − pre-round snapshot) onto the CURRENT state instead of overwriting
-        it, so optimizer steps taken concurrently with the round are not clobbered —
-        required for delayed/local updates (reference state_averager.py:73-74)
+        it, so optimizer steps taken concurrently with the round are not clobbered
+        (reference state_averager.py:73-74). ``Optimizer`` always sets it: its
+        state rounds land behind the next epoch's steps
     """
 
     round_purpose = "state"
@@ -128,6 +129,12 @@ class TrainingStateAverager(DecentralizedAverager):
     def _load_host_state_tensors(self, tensors: List[np.ndarray]) -> None:
         """Inverse of _host_state_tensors: write averaged values back to the device
         state, preserving original dtypes."""
+        with self._state_lock:
+            self._write_host_state_tensors(tensors)
+
+    def _write_host_state_tensors(self, tensors: List[np.ndarray]) -> None:
+        """The upload itself; the caller holds ``_state_lock``. Uploads only — no
+        program is compiled here, because a round may land in the middle of training."""
         import jax
         import jax.numpy as jnp
 
@@ -135,7 +142,7 @@ class TrainingStateAverager(DecentralizedAverager):
         n_opt = len(self._averaged_opt_indices)
         assert len(tensors) >= n_params + n_opt, "state tensor count mismatch"
         record_transfer(sum(int(t.nbytes) for t in tensors), "host_to_device")
-        with _sync_span("state.load"), self._state_lock:
+        with _sync_span("state.load"):
             self._params_flat = [
                 jnp.asarray(tensor, dtype=p.dtype)
                 for tensor, p in zip(tensors[:n_params], self._params_flat)
@@ -173,9 +180,11 @@ class TrainingStateAverager(DecentralizedAverager):
         success (reference state_averager averaging_round path).
 
         With ``delta_rule_averaging``, the result lands as ``current + (average −
-        snapshot)``: local optimizer steps that ran while the round was in flight
-        survive (reference state_averager.py:73-74,595-612)."""
-        snapshot = self._host_state_tensors()
+        snapshot)`` in ONE critical section with :meth:`apply_optimizer_step`: an
+        optimizer step that ran while the round was in flight survives the landing,
+        whenever it ran (reference state_averager.py:73-74,595-612)."""
+        with self._state_lock:  # parameters and statistics of the same update
+            snapshot = self._host_state_tensors()
         with self.get_tensors() as tensors:
             for tensor, fresh in zip(tensors, snapshot):
                 np.copyto(tensor, fresh)
@@ -188,12 +197,15 @@ class TrainingStateAverager(DecentralizedAverager):
             return False
         with self.get_tensors() as tensors:
             averaged = [t.copy() for t in tensors]
-        if self.delta_rule_averaging:
-            current = self._host_state_tensors()
-            merged = [cur + (avg - snap) for cur, avg, snap in zip(current, averaged, snapshot)]
-            self._load_host_state_tensors(merged)
-        else:
+        if not self.delta_rule_averaging:
             self._load_host_state_tensors(averaged)
+            return True
+        for delta, before in zip(averaged, snapshot):
+            delta -= before
+        with self._state_lock:
+            # the pulled tensors may be read-only views of device buffers: add into ours
+            merged = [np.add(current, delta, out=delta) for current, delta in zip(self._host_state_tensors(), averaged)]
+            self._write_host_state_tensors(merged)
         return True
 
     # ------------------------------------------------------------------ schedules

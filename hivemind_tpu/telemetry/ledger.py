@@ -82,23 +82,34 @@ class EpochPhases:
     ``<phase>_s`` field of the epoch record, so the time an optimizer's ``step``
     spends closing an epoch divides into gradient round, update and state round
     from inside. Created when the transition starts; :meth:`fields` goes to
-    :meth:`RoundLedger.record_epoch` when it ends."""
+    :meth:`RoundLedger.record_epoch` when it ends.
+
+    The host ``Optimizer``'s state round runs behind the next epoch's steps, outside
+    every transition: what a transition spends on it is ``state_round_wait`` (the
+    round before was still out), and the round's own length arrives through
+    :meth:`landed`, on the record of the transition that collected it."""
 
     def __init__(self, **attributes: Any):
         self._attributes = attributes  # of every phase span: peer, epoch
         self._started = time.perf_counter()
         # a phase that did not run (no state round this epoch) took no time, and says so
-        self._seconds = {"grad_round_s": 0.0, "update_s": 0.0, "state_round_s": 0.0}
+        self._seconds = {"grad_round_s": 0.0, "update_s": 0.0, "state_round_s": 0.0, "state_round_wait_s": 0.0}
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        """``grad_round`` / ``update`` / ``state_round``; a phase entered twice adds up."""
+        """``grad_round`` / ``update`` / ``state_round`` / ``state_round_wait``; a phase
+        entered twice adds up."""
         began = time.perf_counter()
         try:
             with trace_sync("optimizer." + name, **self._attributes):
                 yield
         finally:
             self._seconds[name + "_s"] += time.perf_counter() - began
+
+    def landed(self, name: str, seconds: float) -> None:
+        """A phase that ran elsewhere (its span is on that thread) and has ended since
+        the transition before: its own length goes on this transition's record."""
+        self._seconds[name + "_s"] += seconds
 
     def fields(self) -> Dict[str, float]:
         """The phases' seconds, and ``transition_s`` from creation until now."""

@@ -243,7 +243,7 @@ def test_two_peer_round_produces_overlap_records():
                 params={"w": jnp.zeros(4, jnp.float32)}, optimizer=optax.sgd(0.1),
                 batch_size_per_step=16, matchmaking_time=1.0, averaging_timeout=30,
                 average_state_every=1, target_group_size=2, verbose=False,
-                use_local_updates=True, delay_state_averaging=True,
+                use_local_updates=True,
                 tracker_opts=dict(min_refresh_period=0.3, default_refresh_period=0.5),
             )
             loss_grad = jax.jit(jax.value_and_grad(

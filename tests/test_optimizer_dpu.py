@@ -52,7 +52,7 @@ def test_dpu_overlapped_convergence():
                 params=params, optimizer=optax.sgd(0.3),
                 batch_size_per_step=16, matchmaking_time=1.5, averaging_timeout=30,
                 average_state_every=1, target_group_size=2,
-                delay_optimizer_step=True, delta_rule_averaging=True,
+                delay_optimizer_step=True,
                 tracker_opts=dict(min_refresh_period=0.3, default_refresh_period=0.5),
             )
             rng_local = np.random.RandomState(index)
@@ -235,9 +235,9 @@ def test_one_epoch_grace_reload_rule():
 
 
 def test_local_updates_with_delayed_state_averaging():
-    """The canonical local-SGD combination: use_local_updates + delay_state_averaging
-    + delta_rule_averaging. State rounds run on the background thread while local
-    steps continue; peers converge and stay in sync."""
+    """Local SGD: with use_local_updates the state rounds run on the background thread
+    (as every state round does) while local steps continue, and land by the delta
+    rule; peers converge and stay in sync."""
     features, targets, loss_and_grad = _toy_problem(seed=4)
     dhts = launch_dht_swarm(2)
     results, errors = {}, []
@@ -250,7 +250,7 @@ def test_local_updates_with_delayed_state_averaging():
                 params=params, optimizer=optax.sgd(0.2),
                 batch_size_per_step=16, matchmaking_time=1.5, averaging_timeout=30,
                 average_state_every=1, target_group_size=2,
-                use_local_updates=True, delay_state_averaging=True, delta_rule_averaging=True,
+                use_local_updates=True,
                 tracker_opts=dict(min_refresh_period=0.3, default_refresh_period=0.5),
             )
             rng_local = np.random.RandomState(index)
@@ -310,7 +310,7 @@ def test_powersgd_with_dpu_convergence():
                 params=params, optimizer=optax.sgd(0.3),
                 batch_size_per_step=16, matchmaking_time=1.5, averaging_timeout=30,
                 average_state_every=1, target_group_size=2,
-                delay_optimizer_step=True, delta_rule_averaging=True,
+                delay_optimizer_step=True,
                 grad_averager_factory=PowerSGDGradientAverager,
                 grad_averager_opts={"averager_rank": 4},
                 tracker_opts=dict(min_refresh_period=0.3, default_refresh_period=0.5),
@@ -351,3 +351,187 @@ def test_powersgd_with_dpu_convergence():
     finally:
         for dht in dhts:
             dht.shutdown()
+
+
+# ------------------------------------------------ the state round behind the next epoch's steps
+#
+# Two CPU peers with seeded gradients: in epoch e BOTH peers feed GRADS[e] at every step, so
+# the averaged gradient is GRADS[e] whatever the weights and however many steps a peer took,
+# and a plain optax LAMB loop over GRADS is what both must hold in the end. Peer 0's state
+# rounds are stretched past the next gradient round, so its transitions find one in flight.
+
+_EPOCHS = 4
+_GRADS = np.random.RandomState(7).randn(_EPOCHS + 1, 24).astype(np.float32)
+_W0 = np.random.RandomState(8).randn(24).astype(np.float32)
+
+
+@pytest.fixture(scope="module")
+def background_rounds():
+    from hivemind_tpu.telemetry.ledger import LEDGER
+
+    dhts = launch_dht_swarm(2)
+    seen = SimpleNamespace(
+        peers=[str(dht.peer_id) for dht in dhts], rounds=[], epochs=[], closing=[{}, {}],
+        open_now=[0, 0], most_open=[0, 0], final=[None, None], errors=[],
+    )
+
+    def on_record(kind, record):
+        (seen.rounds if kind == "round" else seen.epochs).append(dict(record))
+
+    def state_rounds_of(index):
+        return [r for r in seen.rounds if r.get("purpose") == "state" and r["peer"] == seen.peers[index]]
+
+    def run_peer(index, dht):
+        try:
+            opt = Optimizer(
+                dht=dht, run_id="behind_steps", target_batch_size=32, batch_size_per_step=16,
+                params={"w": jnp.asarray(_W0)}, optimizer=optax.lamb(0.05),
+                matchmaking_time=1.0, averaging_timeout=30, average_state_every=1, target_group_size=2,
+                tracker_opts=dict(min_refresh_period=0.2, default_refresh_period=0.3),
+            )
+            plain_round = opt.state_averager.do_averaging_round
+
+            def watched_round(**kwargs):
+                seen.open_now[index] += 1
+                seen.most_open[index] = max(seen.most_open[index], seen.open_now[index])
+                try:
+                    time.sleep(0.3)  # a group of two forms at once: keep the record behind the step's return
+                    done = plain_round(**kwargs)
+                    if index == 0:
+                        time.sleep(3.5)  # still out when the next transition comes for it
+                    return done
+                finally:
+                    seen.open_now[index] -= 1
+
+            opt.state_averager.do_averaging_round = watched_round
+            deadline = time.monotonic() + 150
+            while opt.local_epoch < _EPOCHS and time.monotonic() < deadline:
+                epoch = opt.local_epoch
+                opt.step({"w": jnp.asarray(_GRADS[epoch])})
+                if opt.local_epoch > epoch:
+                    in_flight = opt._state_round is not None and not opt._state_round.done()
+                    seen.closing[index][opt.local_epoch] = (len(state_rounds_of(index)), in_flight)
+                time.sleep(0.05)
+            opt._wait_for_state_round(60)
+            seen.final[index] = (opt.local_epoch, np.asarray(opt.params["w"]), len(state_rounds_of(index)))
+            opt.shutdown()
+        except Exception:
+            import traceback
+
+            seen.errors.append((index, traceback.format_exc()))
+
+    LEDGER.add_record_listener(on_record)
+    threads = [threading.Thread(target=run_peer, args=(i, d)) for i, d in enumerate(dhts)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=240)
+        assert not any(t.is_alive() for t in threads), "a peer did not finish"
+        assert not seen.errors, f"peer failures: {seen.errors}"
+        yield seen
+    finally:
+        LEDGER.remove_record_listener(on_record)
+        for dht in dhts:
+            dht.shutdown()
+
+
+def test_closing_step_returns_before_its_state_round(background_rounds):
+    """(a) The step that closes epoch n returns while the round it launched is in flight:
+    the ledger then holds the n-1 state rounds that landed, not the n-th. The round's own
+    length reaches the NEXT epoch record, beside the seconds that transition waited."""
+    seen = background_rounds
+    for index, peer in enumerate(seen.peers):
+        assert sorted(seen.closing[index]) == list(range(1, _EPOCHS + 1))
+        for epoch, (recorded, in_flight) in seen.closing[index].items():
+            assert in_flight and recorded == epoch - 1, (index, epoch, recorded, in_flight)
+        records = {e["epoch"]: e for e in seen.epochs if e["peer"] == peer}
+        assert records[1]["state_round_s"] == 0.0 and records[1]["state_round_wait_s"] == 0.0
+        for epoch in range(2, _EPOCHS + 1):
+            assert records[epoch]["state_round_s"] > 0.3, records[epoch]
+            assert records[epoch]["state_round_wait_s"] >= 0.0
+            # what the transition itself took no longer holds the round, only the wait for it
+            spent = sum(records[epoch][k] for k in ("grad_round_s", "update_s", "state_round_wait_s"))
+            assert spent == pytest.approx(records[epoch]["transition_s"], abs=0.25)
+
+
+def test_background_rounds_match_a_plain_lamb_loop(background_rounds):
+    """(b) After N epochs and a wait for the last round both peers hold what the same
+    gradients give through plain optax LAMB with an average after every step (the peers
+    start equal, so the average of two plain loops is the loop), within the fp16 wire."""
+    optimizer = optax.lamb(0.05)
+    params = {"w": jnp.asarray(_W0)}
+    state = optimizer.init(params)
+    smallest_update = np.inf
+    for epoch in range(_EPOCHS):
+        updates, state = optimizer.update({"w": jnp.asarray(_GRADS[epoch])}, state, params)
+        smallest_update = min(smallest_update, float(jnp.abs(updates["w"]).max()))
+        params = optax.apply_updates(params, updates)
+    want = np.asarray(params["w"])
+    # set from the dtype: one fp16 rounding (2**-11 of the value) a gradient round and a state
+    # round an epoch, doubled for what LAMB's trust ratio makes of a rounded gradient
+    tolerance = 2 * (2 * _EPOCHS) * 2.0**-11 * float(np.abs(want).max())
+    assert smallest_update > 3 * tolerance  # a lost or doubled update lands far outside it
+    for epoch, mine, _rounds in background_rounds.final:
+        assert epoch == _EPOCHS
+        np.testing.assert_allclose(mine, want, atol=tolerance)
+    np.testing.assert_allclose(background_rounds.final[0][1], background_rounds.final[1][1], atol=tolerance)
+
+
+def test_transition_waits_for_the_round_in_flight(background_rounds):
+    """(d) Never two in flight, never one skipped: a transition that finds the last round
+    still out waits for it, so the rounds recorded equal the epochs closed."""
+    seen = background_rounds
+    assert seen.most_open == [1, 1]
+    for index, peer in enumerate(seen.peers):
+        epoch, _params, rounds = seen.final[index]
+        assert rounds == epoch == _EPOCHS, (index, rounds, epoch)
+    waits = [e["state_round_wait_s"] for e in seen.epochs if e["peer"] == seen.peers[0] and e["epoch"] >= 2]
+    # peer 0's rounds outlast a gradient round (on a loaded machine not every one)
+    assert len(waits) == _EPOCHS - 1 and max(waits) > 0.5, waits
+
+
+def test_step_between_the_merges_takes_survives_the_landing():
+    """(c) The landing reads the current state, adds the round's delta and writes the sum
+    in ONE critical section with apply_optimizer_step. Here an optimizer step arrives
+    just when the landing has read the current state: a merge that takes the lock
+    twice (read, then write) lets it in between and then overwrites it."""
+    dht = DHT(start=True)
+    averager = None
+    try:
+        averager = TrainingStateAverager(
+            dht=dht, optimizer=optax.sgd(1.0), params={"w": jnp.full((4,), 10.0, jnp.float32)},
+            prefix="atomic_landing", start=True, delta_rule_averaging=True, average_opt_statistics=False,
+        )
+
+        def fake_step(timeout=None, wait=True, **kwargs):
+            with averager.get_tensors() as tensors:
+                tensors[0][...] = 8.0  # the group averaged 10 and 6
+            return {}
+
+        averager.step = fake_step
+        pull, pulls, stepper = averager._host_state_tensors, [], []
+
+        def pull_then_step():
+            tensors = pull()
+            pulls.append(len(pulls))
+            if len(pulls) == 2:  # the first pull is the snapshot, the second the landing's read
+                thread = threading.Thread(
+                    target=averager.apply_optimizer_step, args=({"w": jnp.full((4,), 3.0, jnp.float32)},)
+                )
+                thread.start()
+                thread.join(0.5)  # held out by the landing's lock, or through already
+                stepper.append(thread)
+            return tensors
+
+        averager._host_state_tensors = pull_then_step
+        assert averager.do_averaging_round(timeout=5)
+        stepper[0].join(10)
+        assert not stepper[0].is_alive()
+        # 10 + (8 - 10) landed, then the step: 8 - 3 = 5. A two-take merge reads 10, lets
+        # the step write 7, then writes 8 over it.
+        np.testing.assert_allclose(np.asarray(averager.params["w"]), 5.0, atol=1e-6)
+    finally:
+        if averager is not None:
+            averager.shutdown()
+        dht.shutdown()
