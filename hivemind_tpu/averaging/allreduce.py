@@ -43,12 +43,21 @@ from hivemind_tpu.telemetry.tracing import (
     start_span as _start_span,
     trace as _tracing_span,
 )
+from hivemind_tpu.telemetry.wire import wire_work as _wire_work
 
 _ALLREDUCE_PHASE = _TELEMETRY.histogram(
     "hivemind_averaging_allreduce_phase_seconds",
     "duration of one all-reduce phase",
     ("phase",),
 )
+
+
+def _observe_phase(phase: str, span, started: float) -> None:
+    """One pair of clock reads, one truth: the phase histogram takes the length of the
+    span that timed the same boundary (``started`` serves with HIVEMIND_TRACE=0)."""
+    _ALLREDUCE_PHASE.observe(span.duration if span is not None else time.perf_counter() - started, phase=phase)
+
+
 _BANNED_SENDERS = _TELEMETRY.counter(
     "hivemind_averaging_banned_senders_total", "senders banned mid-round", ("cause",)
 )
@@ -118,6 +127,8 @@ class AllReduceRunner:
         purpose: Optional[str] = None,
     ):
         self.p2p, self.group_id, self.purpose = p2p, group_id, purpose
+        # on every work span of the round (_work): whose bytes, which averager's
+        self._work_attributes = {"peer": str(p2p.peer_id), **({"purpose": purpose} if purpose else {})}
         # one part travels as ONE mux message: a part whose wire size exceeded
         # MAX_MESSAGE_SIZE would kill the stream mid-round and silently degrade
         # the average. The clamp uses the same formula on every peer, so senders
@@ -159,10 +170,10 @@ class AllReduceRunner:
         # compress → encrypt → send stages concurrently busy
         self.container = TensorPartContainer(
             tensors, peer_element_counts, compression, part_size_bytes, prefetch=prefetch,
-            peer_links=peer_links, residuals=residuals,
+            peer_links=peer_links, residuals=residuals, work=self._work,
         ) if self.my_mode != AveragingMode.AUX else None
         my_part_shapes = self._span_part_shapes(self.my_index, part_size_bytes)
-        self.reducer = TensorPartReducer(my_part_shapes, self.num_senders)
+        self.reducer = TensorPartReducer(my_part_shapes, self.num_senders, work=self._work)
         self.compression = compression
         self.part_size_bytes = part_size_bytes
         # quantized delta leg (ISSUE 11): the averaged value of each part is
@@ -208,7 +219,7 @@ class AllReduceRunner:
     async def run(self) -> AsyncIterator[np.ndarray]:
         """Send parts to all reducers, reduce own span, yield per-tensor deltas
         (AUX mode: reduces only, yields nothing)."""
-        round_started = time.perf_counter()
+        round_started, loop_cpu_started = time.perf_counter(), time.thread_time()
         # detached (run() is a generator — no contextvar install); phase spans
         # below take it as their explicit parent so the trace shows the round
         # decomposed exactly like the _ALLREDUCE_PHASE histogram labels
@@ -239,8 +250,16 @@ class AllReduceRunner:
             async for delta_tensor in self.container.iterate_output_tensors():
                 yield delta_tensor
         finally:
+            if self._round_span is not None:
+                # CPU seconds of THIS thread, the averager's event loop, over the round (both
+                # reads are taken on it): near the round's length, the loop's own Python is
+                # the round; far below, the loop waited — for the peer, the executor or the
+                # interpreter lock. The loop is shared with whatever else the process runs on it
+                self._round_span.set("loop_cpu_s", round(time.thread_time() - loop_cpu_started, 6))
             _finish_span(self._round_span)
-            round_elapsed = time.perf_counter() - round_started
+            round_elapsed = (
+                self._round_span.duration if self._round_span is not None else time.perf_counter() - round_started
+            )
             _ALLREDUCE_PHASE.observe(round_elapsed, phase="total")
             if (
                 self.my_mode != AveragingMode.AUX
@@ -267,39 +286,44 @@ class AllReduceRunner:
         """Loopback: feed own parts into own reducer without serialization."""
         assert self.container is not None
         my_rank = self.sender_ranks[self.my_index]
-        phase_started = time.perf_counter()
-        with _tracing_span(
-            "allreduce.local_reduce", parent=self._round_span, peer=str(self.p2p.peer_id)
-        ):
-            try:
-                for part_index, part in enumerate(self.container.get_raw_input_parts(self.my_index)):
-                    self._sender_last_active[my_rank] = get_dht_time()  # lint: single-writer — own rank's key only
-                    averaged = await self.reducer.accumulate_part(my_rank, part_index, part, self.weight)
-                    self.container.register_processed_part(
-                        self.my_index, part_index, averaged - part.astype(np.float32, copy=False)
-                    )
-            except AllreduceException as e:
-                logger.debug(f"local reduction failed: {e}")
-                self.container.register_failed_reducer(self.my_index)
-            finally:
-                _ALLREDUCE_PHASE.observe(time.perf_counter() - phase_started, phase="local_reduce")
+        phase_started, phase_span = time.perf_counter(), None
+        try:
+            with _tracing_span(
+                "allreduce.local_reduce", parent=self._round_span, peer=str(self.p2p.peer_id)
+            ) as phase_span:
+                try:
+                    for part_index, part in enumerate(self.container.get_raw_input_parts(self.my_index)):
+                        self._sender_last_active[my_rank] = get_dht_time()  # lint: single-writer — own rank's key only
+                        averaged = await self.reducer.accumulate_part(my_rank, part_index, part, self.weight)
+                        with self._work("reduce", averaged.nbytes):
+                            self.container.register_processed_part(
+                                self.my_index, part_index, averaged - part.astype(np.float32, copy=False)
+                            )
+                except AllreduceException as e:
+                    logger.debug(f"local reduction failed: {e}")
+                    self.container.register_failed_reducer(self.my_index)
+        finally:
+            _observe_phase("local_reduce", phase_span, phase_started)
 
     async def _communicate_with_peer(self, peer_index: int) -> None:
         """Stream our parts to one reducer and apply the deltas it returns
         (reference allreduce.py:201-245)."""
         assert self.container is not None
         peer_id = self.ordered_peer_ids[peer_index]
-        phase_started = time.perf_counter()
-        with _tracing_span(
-            "allreduce.peer_exchange",
-            parent=self._round_span,
-            peer=str(self.p2p.peer_id),
-            remote=str(peer_id),
-            codec=self._link_tier(peer_index),
-        ) as exchange_span:
-            await self._communicate_with_peer_traced(peer_index, peer_id, phase_started, exchange_span)
+        phase_started, exchange_span = time.perf_counter(), None
+        try:
+            with _tracing_span(
+                "allreduce.peer_exchange",
+                parent=self._round_span,
+                peer=str(self.p2p.peer_id),
+                remote=str(peer_id),
+                codec=self._link_tier(peer_index),
+            ) as exchange_span:
+                await self._communicate_with_peer_traced(peer_index, peer_id, exchange_span)
+        finally:
+            _observe_phase("peer_exchange", exchange_span, phase_started)
 
-    async def _communicate_with_peer_traced(self, peer_index, peer_id, phase_started, exchange_span) -> None:
+    async def _communicate_with_peer_traced(self, peer_index, peer_id, exchange_span) -> None:
         try:
             stub = self.get_stub(peer_id)
 
@@ -335,14 +359,15 @@ class AllReduceRunner:
                 _AVG_BYTES_RECEIVED.inc(response.tensor_part.ByteSize())
                 # decode off the event loop (symmetric to the serialize side) so the
                 # loop keeps shoveling frames while numpy unpacks the previous delta
-                processed = await run_in_executor(deserialize_tensor, response.tensor_part)
-                if response.absolute_part:
-                    # quantized leg: the payload is the reduced average itself
-                    # (quantized once, with the reducer's error feedback); the
-                    # delta is recovered against our own input locally
-                    self.container.register_processed_absolute(peer_index, part_index, processed)
-                else:
-                    self.container.register_processed_part(peer_index, part_index, processed)
+                processed = await run_in_executor(self._decode, response.tensor_part)
+                with self._work("reduce", processed.nbytes):
+                    if response.absolute_part:
+                        # quantized leg: the payload is the reduced average itself
+                        # (quantized once, with the reducer's error feedback); the
+                        # delta is recovered against our own input locally
+                        self.container.register_processed_absolute(peer_index, part_index, processed)
+                    else:
+                        self.container.register_processed_part(peer_index, part_index, processed)
                 part_index += 1
             if part_index < self.container.num_parts_by_peer[peer_index]:
                 raise AllreduceException(
@@ -359,8 +384,23 @@ class AllReduceRunner:
                 self.container.register_failed_reducer(peer_index)
             else:
                 raise
-        finally:
-            _ALLREDUCE_PHASE.observe(time.perf_counter() - phase_started, phase="peer_exchange")
+
+    # ------------------------------------------------------------------ work spans
+
+    def _work(self, phase: str, nbytes: int):
+        """The phase's counters and work span, a child of this round wherever the thread is;
+        like the round's other children it names its peer, which is how the RoundLedger tells
+        a round's work from a work span under some other parent."""
+        return _wire_work(phase, nbytes, parent=self._round_span, **self._work_attributes)
+
+    def _decode(self, serialized: runtime_pb2.Tensor) -> np.ndarray:
+        with self._work("decode", len(serialized.buffer)):
+            return deserialize_tensor(serialized)
+
+    def _encode(self, array: np.ndarray, codec: CompressionBase) -> runtime_pb2.Tensor:
+        """A fresh private array (a delta): the codec may clip or normalize it in place."""
+        with self._work("encode", array.nbytes):
+            return serialize_tensor(array, codec, None, True)
 
     # ------------------------------------------------------------------ reducing side
 
@@ -414,7 +454,7 @@ class AllReduceRunner:
                     yield averaging_pb2.AveragingData(code=averaging_pb2.CANCELLED)
                     return
                 _AVG_BYTES_RECEIVED.inc(message.tensor_part.ByteSize())
-                part = await run_in_executor(deserialize_tensor, message.tensor_part)
+                part = await run_in_executor(self._decode, message.tensor_part)
                 if sender_rank in self.banned_senders:
                     # re-check after the executor hop: the watchdog may have failed
                     # this sender while the decode ran, and a late part must not
@@ -464,12 +504,10 @@ class AllReduceRunner:
                         absolute_part=True,
                     )
                 else:
-                    delta = averaged - part.astype(np.float32, copy=False)
-                    # the delta is a fresh private array: the codec may clip/normalize
-                    # it in place instead of allocating another copy
+                    with self._work("reduce", averaged.nbytes):
+                        delta = averaged - part.astype(np.float32, copy=False)
                     serialized_delta = await run_in_executor(
-                        serialize_tensor, delta,
-                        link.codec if link is not None else self.compression, None, True,
+                        self._encode, delta, link.codec if link is not None else self.compression
                     )
                     if _CHAOS.enabled:  # injection point: per delta returned to a sender
                         payload = serialized_delta.buffer
@@ -535,13 +573,14 @@ class AllReduceRunner:
             self._reduce_ef_parts.add(part_index)
 
         def _quantize() -> runtime_pb2.Tensor:
-            if apply_feedback:
-                from hivemind_tpu.averaging.residual import compress_with_feedback
+            with self._work("encode", averaged.nbytes):
+                if apply_feedback:
+                    from hivemind_tpu.averaging.residual import compress_with_feedback
 
-                start = self._my_span_start + self._part_offsets[part_index]
-                residual = self.residuals.view("reduce", start, start + averaged.size)
-                return compress_with_feedback(averaged, link.codec, residual)
-            return serialize_tensor(averaged, link.codec)
+                    start = self._my_span_start + self._part_offsets[part_index]
+                    residual = self.residuals.view("reduce", start, start + averaged.size)
+                    return compress_with_feedback(averaged, link.codec, residual)
+                return serialize_tensor(averaged, link.codec)
 
         try:
             serialized = await run_in_executor(_quantize)

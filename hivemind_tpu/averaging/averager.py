@@ -72,6 +72,7 @@ GatheredData = Dict[PeerID, Any]
 # this module used to swallow silently, now logged AND counted by site
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 from hivemind_tpu.telemetry.tracing import trace as _tracing_span
+from hivemind_tpu.telemetry.tracing import trace_sync as _sync_span
 
 _AVERAGER_INTERNAL_ERRORS = _TELEMETRY.counter(
     "hivemind_averaging_internal_errors_total",
@@ -227,6 +228,7 @@ class DecentralizedAverager(ServicerBase):
             min_matchmaking_time=self.min_matchmaking_time,
             request_timeout=self.request_timeout,
             client_mode=self.client_mode,
+            purpose=self.round_purpose,
         )
         await self.add_p2p_handlers(self.p2p, namespace=self.prefix)
         if self._allow_state_sharing:
@@ -570,7 +572,7 @@ class DecentralizedAverager(ServicerBase):
                 iterator = aiter_with_timeout(iterator, self.allreduce_timeout)
             index = 0
             async for delta in iterator:
-                await self._apply_delta(index, delta)
+                await self._apply_delta(index, delta, round_span=runner._round_span)
                 index += 1
             if runner.container is not None and runner.container.failed_size:
                 logger.warning(
@@ -663,10 +665,17 @@ class DecentralizedAverager(ServicerBase):
         with self.lock_averaged_tensors:
             return [t.copy() for t in self._averaged_tensors]
 
-    async def _apply_delta(self, index: int, delta: np.ndarray) -> None:
+    async def _apply_delta(self, index: int, delta: np.ndarray, round_span=None) -> None:
         async with enter_asynchronously(self.lock_averaged_tensors):
             tensor = self._averaged_tensors[index]
-            tensor += delta.astype(tensor.dtype, copy=False)
+            # the add alone: the wait for the lock is not work on the round's bytes
+            with _sync_span("averager.collect", parent=round_span, bytes=delta.nbytes, **self._work_attributes()):
+                tensor += delta.astype(tensor.dtype, copy=False)
+
+    def _work_attributes(self) -> dict:
+        """Of the ``averager.load`` / ``averager.collect`` spans: whose tensors, which averager's."""
+        purpose = {"purpose": self.round_purpose} if self.round_purpose else {}
+        return {"peer": str(self.peer_id), **purpose}
 
     # ------------------------------------------------------------------ RPCs
 

@@ -68,8 +68,12 @@ class Matchmaking:
         min_matchmaking_time: float = 5.0,
         request_timeout: float = 3.0,
         client_mode: bool = False,
+        purpose: Optional[str] = None,
     ):
         self.p2p = p2p
+        # what the owning averager averages ("grads" / "state"), on the matchmaking span
+        # as on the round's: a round record takes the wait of its own averager
+        self.purpose = purpose
         self.peer_id = p2p.peer_id
         self.key_manager = key_manager
         self.get_stub = get_stub
@@ -198,7 +202,9 @@ class Matchmaking:
             # the with block (not manual enter/exit) so an unexpected exception
             # leaves its `error` event on the span; cleanup runs inside it — the
             # retract/disband time is part of the round's wall time
-            with _tracing_span("averaging.matchmaking", peer=str(self.peer_id)) as match_span:
+            with _tracing_span(
+                "averaging.matchmaking", peer=str(self.peer_id), **({"purpose": self.purpose} if self.purpose else {})
+            ) as match_span:
                 try:
                     group = await self._search_until_deadline()
                     outcome = "assembled" if group is not None else "expired"

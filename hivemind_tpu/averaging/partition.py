@@ -39,6 +39,7 @@ import numpy as np
 from hivemind_tpu.compression import CompressionBase, CompressionInfo, NoCompression, deserialize_tensor, serialize_tensor
 from hivemind_tpu.compression.base import as_numpy
 from hivemind_tpu.proto import runtime_pb2
+from hivemind_tpu.telemetry.wire import wire_work
 from hivemind_tpu.utils.asyncio_utils import amap_in_executor, as_aiter
 from hivemind_tpu.utils.logging import get_logger
 
@@ -92,6 +93,7 @@ class TensorPartContainer:
         prefetch: int = 4,
         peer_links: Optional[Sequence] = None,
         residuals=None,
+        work=wire_work,
     ):
         assert prefetch > 0, "prefetch must be positive"
         self.tensors = [as_numpy(t) for t in tensors]
@@ -140,6 +142,9 @@ class TensorPartContainer:
         self._peer_failed = [False] * len(self.peer_element_counts)
         self.failed_size = 0
         self._finished = asyncio.Event()
+        # ``work(phase, nbytes)`` opens a work span; an AllReduceRunner gives its own, which
+        # names its round as the parent (an executor thread inherits no span from the loop)
+        self.work = work
 
     def _stream_slices(self, start: int, stop: int) -> Iterator[Tuple[int, int, int]]:
         """Yield (tensor_index, local_start, local_stop) covering stream range
@@ -193,9 +198,10 @@ class TensorPartContainer:
 
         def _compress(item) -> runtime_pb2.Tensor:
             start, stop, part, private = item
-            if use_feedback:
-                return compress_with_feedback(part, codec, self.residuals.view("send", start, stop))
-            return serialize_tensor(part, codec, allow_inplace=private)
+            with self.work("encode", part.nbytes):
+                if use_feedback:
+                    return compress_with_feedback(part, codec, self.residuals.view("send", start, stop))
+                return serialize_tensor(part, codec, allow_inplace=private)
 
         async for serialized in amap_in_executor(_compress, as_aiter(*parts), max_prefetch=self.prefetch):
             yield serialized
@@ -288,7 +294,7 @@ class TensorPartReducer:
     """Accumulates incoming parts for the span THIS peer reduces
     (reference partition.py:179-286)."""
 
-    def __init__(self, part_shapes: Sequence[Tuple[int, ...]], num_senders: int):
+    def __init__(self, part_shapes: Sequence[Tuple[int, ...]], num_senders: int, work=wire_work):
         self.part_shapes = list(part_shapes)
         self.num_senders = num_senders
         self.sender_failed = [False] * num_senders
@@ -296,6 +302,7 @@ class TensorPartReducer:
         self._parts: Dict[int, dict] = {}
         self._closed = False
         self._scratch: Optional[np.ndarray] = None  # reusable weighted-part staging
+        self.work = work  # as on the container
 
     def _part_state(self, part_index: int) -> dict:
         if part_index not in self._parts:
@@ -328,17 +335,20 @@ class TensorPartReducer:
             # the accumulator IS the eventual result (divided in place), so a
             # laggard whose part arrives after resolution must not touch it
             accumulator = state["accumulator"]
-            part32 = part.reshape(accumulator.shape).astype(np.float32, copy=False)
-            if weight == 1.0:
-                np.add(accumulator, part32, out=accumulator)
-            else:
-                if self._scratch is None or self._scratch.size < accumulator.size:
-                    self._scratch = np.empty(max(int(np.prod(shape)) for shape in self.part_shapes), np.float32)
-                scratch = self._scratch[: accumulator.size].reshape(accumulator.shape)
-                np.multiply(part32, weight, out=scratch)
-                np.add(accumulator, scratch, out=accumulator)
-            state["total_weight"] += weight
-            self._maybe_finish(part_index)
+            # this numpy runs on the event loop itself: the add, and the divide of the
+            # part's last sender (_maybe_finish)
+            with self.work("reduce", accumulator.nbytes):
+                part32 = part.reshape(accumulator.shape).astype(np.float32, copy=False)
+                if weight == 1.0:
+                    np.add(accumulator, part32, out=accumulator)
+                else:
+                    if self._scratch is None or self._scratch.size < accumulator.size:
+                        self._scratch = np.empty(max(int(np.prod(shape)) for shape in self.part_shapes), np.float32)
+                    scratch = self._scratch[: accumulator.size].reshape(accumulator.shape)
+                    np.multiply(part32, weight, out=scratch)
+                    np.add(accumulator, scratch, out=accumulator)
+                state["total_weight"] += weight
+                self._maybe_finish(part_index)
         return await asyncio.shield(state["future"])
 
     def on_sender_failed(self, sender_index: int) -> None:

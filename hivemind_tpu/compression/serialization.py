@@ -110,12 +110,15 @@ async def deserialize_tensor_stream(
     executor — server handlers use it so a multi-MB prefill chunk cannot stall
     the event loop (ISSUE 10); chunks still decode one tensor at a time, as
     they complete."""
+    from hivemind_tpu.telemetry.wire import wire_work
     from hivemind_tpu.utils.asyncio_utils import run_in_executor
 
     def _combine(chunk_parts: List[runtime_pb2.Tensor]) -> np.ndarray:
-        combined = _clone_tensor_metadata(chunk_parts[0])
-        combined.buffer = b"".join(p.buffer for p in chunk_parts)
-        return deserialize_tensor(combined)
+        # the join of the chunks is part of the decode: it copies every byte once more
+        with wire_work("decode", sum(len(p.buffer) for p in chunk_parts)):
+            combined = _clone_tensor_metadata(chunk_parts[0])
+            combined.buffer = b"".join(p.buffer for p in chunk_parts)
+            return deserialize_tensor(combined)
 
     tensors: List[np.ndarray] = []
     parts: List[runtime_pb2.Tensor] = []

@@ -65,6 +65,7 @@ from hivemind_tpu.telemetry.serving import (
     accrue_span_phase,
 )
 from hivemind_tpu.telemetry.tracing import trace as _trace
+from hivemind_tpu.telemetry.wire import count_work, wire_work
 from hivemind_tpu.utils.asyncio_utils import run_in_executor
 from hivemind_tpu.utils.logging import get_logger
 from hivemind_tpu.utils.serializer import MSGPackSerializer
@@ -262,16 +263,30 @@ class ConnectionHandler(ServicerBase):
         arrays and the seconds it took (the serving span's ``deserialize_s``)."""
         started = time.perf_counter()
         tensor_list = list(tensors)
-        if sum(len(t.buffer) for t in tensor_list) < _OFF_LOOP_CODEC_BYTES:
+        nbytes = sum(len(t.buffer) for t in tensor_list)
+        if nbytes < _OFF_LOOP_CODEC_BYTES:
+            # a decode token's 8 KB, inline: the wire's counters take the seconds this
+            # handler measures anyway, with no clock read of their own
             arrays = [deserialize_tensor(t) for t in tensor_list]
-        else:
-            arrays = await run_in_executor(lambda: [deserialize_tensor(t) for t in tensor_list])
+            elapsed = time.perf_counter() - started
+            count_work("decode", elapsed, nbytes)
+            return arrays, elapsed
+        arrays = await run_in_executor(self._deserialize_off_loop, tensor_list, nbytes)
         return arrays, time.perf_counter() - started
+
+    @staticmethod
+    def _deserialize_off_loop(tensors: List[runtime_pb2.Tensor], nbytes: int) -> List[np.ndarray]:
+        with wire_work("decode", nbytes):  # a fine-tuning request's 33 MB: a `wire.decode` span
+            return [deserialize_tensor(t) for t in tensors]
 
     def _serialize_outputs(self, outputs: List[np.ndarray]) -> List[runtime_pb2.Tensor]:
         # allow_inplace: each output row range is private to its task (views of
         # the fresh device-transfer batch), so the fp16 clip may reuse it
         return [serialize_tensor(o, self.activation_codec, None, True) for o in outputs]
+
+    def _serialize_traced(self, outputs: List[np.ndarray], nbytes: int) -> List[runtime_pb2.Tensor]:
+        with wire_work("encode", nbytes):
+            return self._serialize_outputs(outputs)
 
     async def _respond(self, outputs: List[np.ndarray]) -> WireParts:
         """Serialize the response with the server's wire dtype (off-loop past
@@ -279,11 +294,15 @@ class ConnectionHandler(ServicerBase):
         serving span, and frame the tensors scatter-gather (buffers uncopied
         to the AEAD)."""
         start = time.perf_counter()
-        if sum(int(getattr(o, "nbytes", 0)) for o in outputs) < _OFF_LOOP_CODEC_BYTES:
+        nbytes = sum(int(getattr(o, "nbytes", 0)) for o in outputs)
+        if nbytes < _OFF_LOOP_CODEC_BYTES:
             serialized = self._serialize_outputs(outputs)
+            elapsed = time.perf_counter() - start
+            count_work("encode", elapsed, nbytes)  # as in _deserialize_request
         else:
-            serialized = await run_in_executor(self._serialize_outputs, outputs)
-        accrue_span_phase("serialize_s", time.perf_counter() - start)
+            serialized = await run_in_executor(self._serialize_traced, outputs, nbytes)
+            elapsed = time.perf_counter() - start
+        accrue_span_phase("serialize_s", elapsed)
         response = expert_response_parts(serialized)
         _SERVER_BYTES_SENT.inc(response.nbytes)
         return response
@@ -466,9 +485,10 @@ class ConnectionHandler(ServicerBase):
     async def _serialize_streamed(self, out: np.ndarray) -> runtime_pb2.Tensor:
         """One tensor of a streamed response in the server's wire dtype (off-loop
         past the inline threshold)."""
-        if int(getattr(out, "nbytes", 0)) < _OFF_LOOP_CODEC_BYTES:
-            return serialize_tensor(out, self.activation_codec, None, True)
-        return await run_in_executor(serialize_tensor, out, self.activation_codec, None, True)
+        nbytes = int(getattr(out, "nbytes", 0))
+        if nbytes < _OFF_LOOP_CODEC_BYTES:
+            return self._serialize_traced([out], nbytes)[0]
+        return (await run_in_executor(self._serialize_traced, [out], nbytes))[0]
 
     async def _serialize_head(self, outputs: List[np.ndarray]) -> Optional[runtime_pb2.Tensor]:
         """The first tensor of a streamed response, serialized inside the serving

@@ -35,8 +35,14 @@ _UTILIZATION = _TELEMETRY.gauge(
 _WAIT_SECONDS = _TELEMETRY.counter(
     "hivemind_moe_runtime_wait_seconds_total",
     "seconds the drain loop had no batch to give the device: from 'no pool holds a "
-    "task' to the next batch popped (on a profiler trace the same time is the idle "
-    "time outside every pool.batch span)",
+    "task' until one does (on a profiler trace this and the hand-over seconds are the "
+    "idle time outside every pool.batch span)",
+)
+_HANDOVER_SECONDS = _TELEMETRY.counter(
+    "hivemind_moe_runtime_handover_seconds_total",
+    "seconds from 'a pool holds a task and the executor is free' to 'its batch is handed "
+    "to the executor': the drain loop's own turn-around, on an event loop it shares with "
+    "the handlers; with the wait seconds, the time between two batches",
 )
 
 
@@ -85,7 +91,11 @@ class Runtime:
         self._reported.setdefault(pool.name, _totals(pool))
 
     async def _run(self) -> None:
-        starved_since: Optional[float] = None  # the loop awaits here: a counter, not an annotation
+        # the loop awaits here: counters, not annotations. Between two batches the executor
+        # is free since `free_since`; the seconds until a pool holds a task are wait, the
+        # seconds from then until the batch is handed over are hand-over
+        starved_since: Optional[float] = None
+        free_since = time.perf_counter()
         while True:
             if starved_since is None and not any(pool.queue_size for pool in self.pools):
                 starved_since = time.perf_counter()
@@ -114,18 +124,24 @@ class Runtime:
             if not batch:
                 continue
             start = time.perf_counter()
+            held_since = free_since
             if starved_since is not None:
-                _WAIT_SECONDS.inc(start - starved_since)
+                # the batch's oldest task ended the starvation (a task stamps its submission)
+                held_since = max(min(task.submitted_pc for task in batch), starved_since)
+                _WAIT_SECONDS.inc(held_since - starved_since)
                 starved_since = None
+            _HANDOVER_SECONDS.inc(start - held_since)
             try:
                 await run_in_executor(pool.process_batch, batch)
             except Exception as e:
                 logger.warning(f"pool {pool.name}: batch failed with {e!r}")
                 _BATCH_FAILURES.inc(pool=pool.name)
                 pool.fail_batch(batch, e)
-                self._account_busy(time.perf_counter() - start)
+                free_since = time.perf_counter()
+                self._account_busy(free_since - start)
                 continue
-            self._account_busy(time.perf_counter() - start)
+            free_since = time.perf_counter()
+            self._account_busy(free_since - start)
             self._maybe_report_stats()
 
     def _account_busy(self, elapsed: float) -> None:

@@ -133,8 +133,9 @@ class GradientAverager(DecentralizedAverager):
         )
         denominator = max(self.local_samples_accumulated, 1)
         with self.get_tensors() as tensors:
-            for tensor, accumulator in zip(tensors, self._grad_accumulators):
-                np.divide(accumulator, denominator, out=tensor)
+            with _sync_span("averager.load", bytes=sum(t.nbytes for t in tensors), **self._work_attributes()):
+                for tensor, accumulator in zip(tensors, self._grad_accumulators):
+                    np.divide(accumulator, denominator, out=tensor)
         self._new_averaged_grads = True
 
     def reset_accumulated_grads_(self) -> None:

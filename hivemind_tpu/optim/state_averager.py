@@ -186,8 +186,9 @@ class TrainingStateAverager(DecentralizedAverager):
         with self._state_lock:  # parameters and statistics of the same update
             snapshot = self._host_state_tensors()
         with self.get_tensors() as tensors:
-            for tensor, fresh in zip(tensors, snapshot):
-                np.copyto(tensor, fresh)
+            with _sync_span("averager.load", bytes=sum(t.nbytes for t in tensors), **self._work_attributes()):
+                for tensor, fresh in zip(tensors, snapshot):
+                    np.copyto(tensor, fresh)
         try:
             result = self.step(timeout=timeout, wait=True, **kwargs)
         except Exception as e:

@@ -33,6 +33,7 @@ import asyncio
 import contextlib
 import os
 import struct
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Tuple
 
@@ -53,6 +54,7 @@ except ImportError:  # no cryptography wheel on this image: system libcrypto shi
         serialization,
     )
 
+from hivemind_tpu.telemetry.wire import WORK_SPAN_BYTES, add_send_wait, count_work, wire_work
 from hivemind_tpu.utils.crypto import Ed25519PrivateKey, Ed25519PublicKey
 from hivemind_tpu.utils.serializer import MSGPackSerializer
 from hivemind_tpu.utils.asyncio_utils import spawn
@@ -140,7 +142,12 @@ class SecureChannel:
         # desynchronize AEAD nonces and poison the whole connection
         if total_len + 16 > MAX_FRAME_SIZE:  # +16: poly1305 tag
             raise ValueError(f"frame too large: {total_len} > {MAX_FRAME_SIZE - 16}")
+        # the only wait of a mux send (MuxStream.send -> send_frame -> here): for the in-flight
+        # credit, which the writer loop hands back frame by frame; with credit in hand, no clock read
+        waiting_since = time.perf_counter() if self._send_sem.locked() else None
         await self._send_sem.acquire()
+        if waiting_since is not None:
+            add_send_wait(time.perf_counter() - waiting_since)
         if self._send_error is not None:
             self._send_sem.release()
             raise self._send_failed()
@@ -151,15 +158,30 @@ class SecureChannel:
         executor = _get_aead_executor()
         if executor is not None and total_len >= _OFFLOAD_THRESHOLD:
             sealed = asyncio.get_running_loop().run_in_executor(
-                executor, self._seal, nonce, payload, extra_buffers
+                executor, self._seal, nonce, payload, extra_buffers, total_len
             )
         else:
-            sealed = self._seal(nonce, payload, extra_buffers)
+            sealed = self._seal(nonce, payload, extra_buffers, total_len)
         if self._writer_task is None:
             self._writer_task = spawn(self._writer_loop(), name="crypto_channel.writer_loop")
         self._send_queue.put_nowait(sealed)
 
-    def _seal(self, nonce: bytes, payload: bytes, extra_buffers: Tuple[bytes, ...]) -> bytes:
+    def _seal(self, nonce: bytes, payload: bytes, extra_buffers: Tuple[bytes, ...], total_len: int) -> bytes:
+        # once a frame. A small one (a decode token's, some thousand a second, inline on the
+        # event loop) is timed here and handed to the counters, as the serving handler does
+        # with its inline codec calls: with a context manager a frame in its place,
+        # mistral-7b-span8.decode32 read 2-7 % under the parent in nine pairs of nine
+        # (PERF.md §6, PR 37). From the offload size up: the counters and the profiler's
+        # annotation, no span
+        if total_len < WORK_SPAN_BYTES:
+            began = time.perf_counter()
+            sealed = self._encrypt(nonce, payload, extra_buffers)
+            count_work("seal", time.perf_counter() - began, total_len)
+            return sealed
+        with wire_work("seal", total_len, span=False):
+            return self._encrypt(nonce, payload, extra_buffers)
+
+    def _encrypt(self, nonce: bytes, payload: bytes, extra_buffers: Tuple[bytes, ...]) -> bytes:
         if not extra_buffers:
             return self._send_aead.encrypt(nonce, payload, None)
         encrypt_parts = getattr(self._send_aead, "encrypt_parts", None)
@@ -168,6 +190,15 @@ class SecureChannel:
         # cipher without multi-buffer support: one join is still cheaper than
         # making every caller concatenate ahead of the size check
         return self._send_aead.encrypt(nonce, b"".join((payload, *extra_buffers)), None)
+
+    def _open(self, nonce: bytes, ciphertext: bytes) -> bytes:
+        if len(ciphertext) < WORK_SPAN_BYTES:  # as in _seal
+            began = time.perf_counter()
+            opened = self._recv_aead.decrypt(nonce, ciphertext, None)
+            count_work("open", time.perf_counter() - began, len(ciphertext))
+            return opened
+        with wire_work("open", len(ciphertext), span=False):
+            return self._recv_aead.decrypt(nonce, ciphertext, None)
 
     def _send_failed(self) -> ConnectionError:
         error = self._send_error
@@ -281,7 +312,7 @@ class SecureChannel:
                     opened = asyncio.ensure_future(
                         self._open_offloaded(
                             asyncio.get_running_loop().run_in_executor(
-                                executor, self._recv_aead.decrypt, nonce, ciphertext, None
+                                executor, self._open, nonce, ciphertext
                             )
                         )
                     )
@@ -290,7 +321,7 @@ class SecureChannel:
                     opened.add_done_callback(lambda t: t.cancelled() or t.exception())
                 else:
                     try:
-                        opened = self._recv_aead.decrypt(nonce, ciphertext, None)
+                        opened = self._open(nonce, ciphertext)
                     except InvalidTag:
                         raise HandshakeError(
                             "AEAD authentication failed (corrupted or replayed frame)"

@@ -85,6 +85,7 @@ from hivemind_tpu.telemetry.tracing import (
     start_span,
     trace,
     trace_sync,
+    trace_work,
 )
 from hivemind_tpu.telemetry.monitor import (
     DEFAULT_TELEMETRY_KEY,
@@ -143,6 +144,7 @@ __all__ = [
     "SpanRecorder",
     "trace",
     "trace_sync",
+    "trace_work",
     "current_span",
     "start_span",
     "finish_span",

@@ -11,7 +11,10 @@ epoch transition) from signals that already exist:
   ``averaging.matchmaking`` / ``allreduce.local_reduce`` /
   ``allreduce.peer_exchange`` / ``allreduce.round`` into per-round phase
   durations, keyed by the round span's id so concurrent averagers (grad +
-  state) cannot cross-contaminate;
+  state) cannot cross-contaminate; the round's work spans (``wire.encode`` /
+  ``wire.decode`` / ``allreduce.reduce``, children of the round from whatever
+  thread ran them) add up into ``encode_s`` / ``decode_s`` / ``reduce_s``, and
+  the round span's own ``loop_cpu_s`` rides along (ISSUE 37);
 - **registry counters** — bytes in/out, retries, sender bans, breaker trips,
   chaos injections and state-sync bytes are read as deltas at round close, so
   each record carries the traffic and resilience activity of its window;
@@ -74,6 +77,10 @@ _MAX_PENDING_ROUNDS = 64
 # without retro-attachment the ledger would tend to drop exactly the exchange
 # it exists to attribute.
 _MAX_CLOSED_ROUNDS = 16
+
+# a round's work spans and the record field each adds up into: seconds of work summed
+# over threads (parts decoded side by side may add up to more than their wall time)
+_WORK_FIELDS = {"wire.encode": "encode_s", "wire.decode": "decode_s", "allreduce.reduce": "reduce_s"}
 
 
 class EpochPhases:
@@ -148,14 +155,15 @@ class RoundLedger:
         # open-round buffers keyed by the allreduce.round span id
         self._pending_exchanges: Dict[int, List[Dict[str, Any]]] = {}
         self._pending_local: Dict[int, float] = {}
+        self._pending_work: Dict[int, Dict[str, float]] = {}
         # recently-closed rounds (span id -> live record) for late exchanges,
         # plus the straggler-score contribution each record currently holds so
         # a late slower exchange can re-attribute the round
         self._closed_rounds: Dict[int, Dict[str, Any]] = {}
         self._round_contrib: Dict[int, Tuple[str, float]] = {}
-        # most recent finished matchmaking per PEER id, consumed by that
-        # peer's next round close
-        self._last_matchmaking: Dict[str, Dict[str, Any]] = {}
+        # most recent finished matchmaking per (peer id, purpose), consumed by the next
+        # round close of that peer's averager of that purpose
+        self._last_matchmaking: Dict[Tuple[str, str], Dict[str, Any]] = {}
         # delta baselines: empty until the first round SEEDS them (that round
         # reports no counters — attributing bootstrap traffic, e.g. a 2 GB
         # state download, to round 1 would be fiction). clear() re-anchors at
@@ -195,7 +203,26 @@ class RoundLedger:
     def on_span(self, span: Span) -> None:
         """Span listener: cheap name dispatch; everything else is per round."""
         name = span.name
-        if name == "allreduce.peer_exchange":
+        field = _WORK_FIELDS.get(name)
+        if field is not None:
+            parent = span.parent_id
+            # a round's work names its peer, as the round's other children do
+            # (AllReduceRunner._work); a work span under any other parent — a client's
+            # call decoding a streamed response — is no round's and is not kept
+            if parent and "peer" in (span.attributes or ()):
+                with self._lock:
+                    closed = self._closed_rounds.get(parent)
+                    if closed is not None:
+                        # work that outlived its round (a delta still being encoded for a
+                        # partner): on the live record, in the next copy that goes out
+                        closed[field] = round(closed.get(field, 0.0) + span.duration, 6)
+                    else:
+                        work = self._pending_work.setdefault(parent, {})
+                        work[field] = work.get(field, 0.0) + span.duration
+                        # a round whose span never finishes leaves its sums behind
+                        if len(self._pending_work) > _MAX_PENDING_ROUNDS:
+                            self._pending_work.pop(next(iter(self._pending_work)), None)
+        elif name == "allreduce.peer_exchange":
             parent = span.parent_id
             if parent:
                 attrs = span.attributes or {}
@@ -224,15 +251,20 @@ class RoundLedger:
         elif name == "averaging.matchmaking":
             attrs = span.attributes or {}
             with self._lock:
-                # keyed by peer id so multi-peer-in-one-process rounds cannot
-                # swap waits; two averagers of the SAME peer (grad + state)
-                # overlap only in DPU mode, where this stays best-effort
-                self._last_matchmaking[str(attrs.get("peer", "?"))] = {
+                # keyed by peer id so multi-peer-in-one-process rounds cannot swap
+                # waits, and by purpose because two averagers of ONE peer overlap in
+                # every epoch: the state round runs behind the next gradient round's
+                # matchmaking (a span without a purpose pairs with a round without one)
+                self._last_matchmaking[self._matchmaking_key(attrs)] = {
                     "wait_s": round(span.duration, 6),
                     "outcome": attrs.get("outcome"),
                 }
         elif name == "allreduce.round":
             self._close_round(span)
+
+    @staticmethod
+    def _matchmaking_key(attrs: Dict[str, Any]) -> Tuple[str, str]:
+        return str(attrs.get("peer", "?")), str(attrs.get("purpose") or "")
 
     def _counter_total(self, metric_name: str) -> float:
         metric = self._registry.get(metric_name)
@@ -248,7 +280,8 @@ class RoundLedger:
         with self._lock:
             exchanges = self._pending_exchanges.pop(span.span_id, [])
             local_reduce = self._pending_local.pop(span.span_id, None)
-            matchmaking = self._last_matchmaking.pop(str(attrs.get("peer", "?")), None)
+            work = self._pending_work.pop(span.span_id, None)
+            matchmaking = self._last_matchmaking.pop(self._matchmaking_key(attrs), None)
             self._round_index += 1
             record: Dict[str, Any] = {
                 "round": self._round_index,
@@ -265,6 +298,10 @@ class RoundLedger:
                 record["matchmaking_outcome"] = matchmaking["outcome"]
             if local_reduce is not None:
                 record["local_reduce_s"] = local_reduce
+            if work:
+                record.update({field: round(seconds, 6) for field, seconds in work.items()})
+            if attrs.get("loop_cpu_s") is not None:
+                record["loop_cpu_s"] = attrs["loop_cpu_s"]  # CPU seconds of the loop thread over the round
             if exchanges:
                 record["exchanges"] = exchanges
                 for exchange in exchanges:
@@ -510,7 +547,8 @@ class RoundLedger:
         says WHERE the regression lives, not just the headline number."""
         records = self.records()
         out: Dict[str, Any] = {"rounds": len(records), "epochs": len(self._epochs)}
-        for field in ("total_s", "matchmaking_wait_s", "local_reduce_s", "slowest_s"):
+        for field in ("total_s", "matchmaking_wait_s", "local_reduce_s", "slowest_s",
+                      "encode_s", "decode_s", "reduce_s", "loop_cpu_s"):
             values = [r[field] for r in records if field in r]
             if values:
                 out[field] = {
@@ -562,6 +600,7 @@ class RoundLedger:
             self._codec_events.clear()
             self._pending_exchanges.clear()
             self._pending_local.clear()
+            self._pending_work.clear()
             self._closed_rounds.clear()
             self._round_contrib.clear()
             self._last_matchmaking.clear()

@@ -519,20 +519,33 @@ class trace_sync(trace):
     transition's phases. One call site, two timelines: the telemetry span (ring,
     listeners, ledgers, ``/trace``) and, on the device trace's clock, the same
     interval as ``hivemind:<name>`` in the profiler's host plane, where it says
-    what the host did while the device sat idle.
+    what the host did while the device sat idle (``hivemind:<name>.<purpose>`` for a
+    span with a ``purpose`` attribute: a trace reader sees names only, and two
+    averagers of one peer work side by side).
 
     Only for blocks that open and close on ONE thread with no ``await`` in
-    between: the annotation is thread-scoped, and interleaved asyncio tasks
-    would mis-nest it. A span that crosses an ``await`` stays a plain
-    :class:`trace` and delivers its time as a ledger field or a counter. With
-    ``HIVEMIND_TRACE=0`` this is :class:`trace`'s disabled path: no span, no
-    annotation."""
+    between: the annotation is thread-scoped, and the profiler's converter re-nests
+    whatever one thread wrote. Two interleaved asyncio tasks — A entered at 0 ms
+    and left at 50 ms, B entered at 20 ms and left at 90 ms on the same thread —
+    come out as A 50 ms, correct, and B **90 ms starting with A** where it took
+    70 (jax 0.9.0, measured for ISSUE 37): the later span is reported from the
+    earlier one's start, silently. That is why :func:`start_span` /
+    :func:`finish_span` carry no annotation and must not get one. A span that
+    crosses an ``await`` stays a plain :class:`trace` and delivers its time as a
+    ledger field or a counter; the waits are read off the properly nested work
+    spans by subtraction. With ``HIVEMIND_TRACE=0`` this is :class:`trace`'s
+    disabled path: no span, no annotation."""
 
     __slots__ = ("_annotation",)
 
     def __enter__(self) -> Optional[Span]:
         span = super().__enter__()
-        self._annotation = _profiler_annotation(self._name) if span is not None else None
+        self._annotation = None
+        if span is not None:
+            # a span that says whose work it is (an averager's ``purpose``) says so on the
+            # device trace too, where attributes do not go: ``hivemind:wire.encode.grads``
+            purpose = self._attributes.get("purpose")
+            self._annotation = _profiler_annotation(f"{self._name}.{purpose}" if purpose else self._name)
         if self._annotation is not None:
             self._annotation.__enter__()
         return span
@@ -542,6 +555,57 @@ class trace_sync(trace):
             self._annotation.__exit__(exc_type, exc, tb)
             self._annotation = None
         return super().__exit__(exc_type, exc, tb)
+
+
+class trace_work:
+    """The lighter sibling of :class:`trace_sync`, for synchronous work on bytes that
+    is FREQUENT — a frame sealed, a tensor part decoded, a reducer's add: always
+    ``sink(seconds, nbytes)`` (the counters at the same boundary), and as much of a
+    span as ``level`` asks for:
+
+        SPAN        what trace_sync does (Span, listeners, annotation)
+        ANNOTATION  ``hivemind:<name>`` on the profiler's host plane, no Span object:
+                    some 170 frames of a streamed request would otherwise wash the
+                    4,096-span ring
+        COUNT       the sink alone: two clock reads and its increments
+
+    Same rule as ``trace_sync``: one thread, no ``await`` inside. ``parent`` is given
+    explicitly where the thread is an executor's (it inherits no contextvars from the
+    loop that sent it the work). With ``HIVEMIND_TRACE=0`` every level is COUNT."""
+
+    COUNT, ANNOTATION, SPAN = 0, 1, 2
+
+    __slots__ = ("_nbytes", "_sink", "_span", "_annotation", "_began")
+
+    def __init__(self, name: str, nbytes: int, sink, level: int = SPAN,
+                 parent: Optional[Span] = None, **attributes: Any):
+        self._nbytes, self._sink = nbytes, sink
+        self._span = self._annotation = None
+        if level and enabled:
+            if level == self.SPAN:
+                self._span = trace_sync(name, parent=parent, bytes=nbytes, **attributes)
+            else:
+                self._annotation = _profiler_annotation(name)
+
+    def __enter__(self) -> Optional[Span]:
+        if self._span is not None:
+            return self._span.__enter__()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._began = time.perf_counter()
+        return None
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._span is not None:
+            # one pair of clock reads, one truth: the counter takes the span's own length
+            self._span.__exit__(exc_type, exc, tb)
+            seconds = self._span.span.duration
+        else:
+            seconds = time.perf_counter() - self._began
+            if self._annotation is not None:
+                self._annotation.__exit__(exc_type, exc, tb)
+        self._sink(seconds, self._nbytes)
+        return False
 
 
 # ---------------------------------------------------------------------- export
