@@ -829,7 +829,9 @@ def run_serving_churn(
         # phase 3: restart under a fresh identity; it re-declares the same uids
         phase["name"] = "restarting"
         logger.warning(f"serving churn: restarting replica {victim_name}")
-        restarted_dht = DHT(initial_peers=maddrs, start=True)
+        # bootstrap from the SURVIVOR: `maddrs` are replica A's, and when A is the victim
+        # (whichever replica took more traffic) nobody answers there any more
+        restarted_dht = DHT(initial_peers=[str(m) for m in survivor_dht.get_visible_maddrs()], start=True)
         restarted_server = Server.create(
             expert_uids=uids, expert_cls="ffn", hidden_dim=16, dht=restarted_dht,
             start=True, max_batch_size=64, optim_factory=lambda: optax.sgd(1e-3),

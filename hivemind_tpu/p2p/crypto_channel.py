@@ -23,7 +23,11 @@ pool — ChaCha20-Poly1305 releases the GIL in OpenSSL, so on a multi-core host 
 connections (or k queued frames of one connection) use k cores. On a single-core
 host the pool is disabled (``HIVEMIND_AEAD_THREADS=0`` forces this; any other value
 overrides the default ``min(4, cpu_count)``) and the pipeline still batches socket
-writes. In-flight frames are bounded both ways (send semaphore / bounded prefetch
+writes. The CPU count behind that default is asked ONCE, at import (``_CPU_COUNT``):
+every frame sent and received consults the pool's size, and a system call a frame is
+what a server of small frames cannot afford (35 us each where a sandbox answers them:
+a quarter of a decode cell's rate, PERF.md §6); the environment variable is still read
+a frame, so a test or an operator may set it after import. In-flight frames are bounded both ways (send semaphore / bounded prefetch
 queue), so memory stays capped and TCP backpressure propagates to callers.
 """
 
@@ -70,13 +74,16 @@ _RECV_PREFETCH = 8  # frames unsealed ahead of recv(); bounds receiver memory
 
 _aead_executor: Optional[ThreadPoolExecutor] = None
 
+# asked once: every frame sent and received passes _aead_workers(), and where a sandbox
+# answers system calls os.cpu_count() is 35 us with the interpreter lock held (PERF.md §6, PR 37)
+_CPU_COUNT = os.cpu_count() or 1
+
 
 def _aead_workers() -> int:
-    configured = os.environ.get("HIVEMIND_AEAD_THREADS")
+    configured = os.environ.get("HIVEMIND_AEAD_THREADS")  # read a frame: tests set it after import
     if configured is not None:
         return max(0, int(configured))
-    count = os.cpu_count() or 1
-    return min(4, count) if count > 1 else 0
+    return min(4, _CPU_COUNT) if _CPU_COUNT > 1 else 0
 
 
 def _get_aead_executor() -> Optional[ThreadPoolExecutor]:

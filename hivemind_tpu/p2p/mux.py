@@ -7,12 +7,33 @@ encrypted TCP connection. Frames are whole messages (an RPC message = one frame)
 removes the reference's 8-byte-header + marker reframing layer entirely.
 
 Mux frame layout (inside the AEAD envelope): [u64 stream_id][u8 flags][payload].
-Flags: OPEN (payload = handler name utf-8, optionally followed by NUL + a 16-byte
-trace context — handler names never contain NUL), DATA (payload = message), CLOSE
-(graceful end-of-stream from that side), RESET (abort), ERROR (payload = msgpack
-error info). The trace context (telemetry/tracing.py pack_context) is how a
-server-side handler span becomes a child of the remote caller's span; absent
-when the caller has no active span, ignored when malformed.
+The flags are a bit mask, and one frame may carry several; the receiver handles them
+in the order OPEN, DATA, ERROR, CLOSE/RESET:
+
+    OPEN   the open section: handler name utf-8, optionally followed by NUL + a 16-byte
+           trace context (handler names never contain NUL). The trace context
+           (telemetry/tracing.py pack_context) is how a server-side handler span becomes
+           a child of the remote caller's span; absent when the caller has no active
+           span, ignored when malformed.
+    DATA   one message. In a frame that also says OPEN the payload is
+           [u16 length of the open section][open section][message].
+    ERROR  msgpack error info (sent as ERROR|CLOSE: an error is a side's last word).
+    CLOSE  graceful end-of-stream from that side, after the frame's message if any.
+    RESET  abort: the receiver stops sending and cancels the stream's handler mid-compute.
+
+A frame is what the event loop pays a fixed price for (one AEAD call, one write to the
+socket, one turn of the peer's read loop), so a unary call is TWO frames: the request
+as OPEN|DATA|CLOSE, the answer as DATA|CLOSE (ERROR|CLOSE from a failed handler). A
+stream whose caller knows its single request sends the same first frame; a request
+iterator sends OPEN, then DATA..., then CLOSE, and a streaming handler DATA... then
+CLOSE; every such frame alone stays valid input, there is no version to negotiate.
+
+The end of a stream: once BOTH sides have half-closed (each has sent CLOSE and seen the
+other's), the stream is complete and each peer forgets it on the spot — where it sees
+the second half-close or sends it — with no further frame: its entry in ``_streams``,
+its undrained credit, its handler-task entry. RESET is for a stream that is NOT
+complete (a timeout, a cancellation, a hedge's losing request, a failed request
+iterator); ``MuxStream.reset()`` on a complete stream sends nothing.
 Flow control: per-stream inboxes are unbounded (the read loop never head-of-line-blocks
 one stream on another), with a per-connection buffered-bytes cap as the memory backstop
 — a peer that overruns it loses the connection, not the process. TCP backpressure plus
@@ -40,6 +61,7 @@ from hivemind_tpu.utils.serializer import MSGPackSerializer
 logger = get_logger(__name__)
 
 _HEADER = struct.Struct(">QB")
+_OPEN_LENGTH = struct.Struct(">H")  # in an OPEN|DATA frame: the open section's length
 
 # one RPC message per frame; larger payloads must be chunked by the caller
 # (parity: reference DEFAULT_MAX_MSG_SIZE, p2p_daemon_bindings/control.py:36-39)
@@ -52,6 +74,12 @@ class Flags(IntFlag):
     CLOSE = 4
     RESET = 8
     ERROR = 16
+
+
+# the read loop tests a frame's bits as plain ints (an IntFlag's `&` builds an enum member: 0.75 us
+# each, five a frame), and the two composites a unary call sends are built once
+_OPEN, _DATA, _CLOSE, _RESET, _ERROR = (int(flag) for flag in (Flags.OPEN, Flags.DATA, Flags.CLOSE, Flags.RESET, Flags.ERROR))
+_DATA_CLOSE, _ERROR_CLOSE, _OPEN_DATA_CLOSE = Flags.DATA | Flags.CLOSE, Flags.ERROR | Flags.CLOSE, Flags.OPEN | Flags.DATA | Flags.CLOSE
 
 
 class StreamClosedError(ConnectionError):
@@ -70,6 +98,14 @@ class RemoteError(RuntimeError):
 _EOF = object()
 
 
+def _check_message_size(total: int) -> None:
+    if total > MAX_MESSAGE_SIZE:
+        raise ValueError(
+            f"message of {total} bytes exceeds MAX_MESSAGE_SIZE={MAX_MESSAGE_SIZE}; "
+            f"split large tensors with utils.streaming.split_for_streaming"
+        )
+
+
 class MuxStream:
     """One bidirectional message stream. ``send``/``receive`` whole byte messages.
 
@@ -85,7 +121,8 @@ class MuxStream:
         self.trace_context = None  # (trace_id, span_id) from the remote OPEN, if any
         self._inbox: asyncio.Queue = asyncio.Queue()
         self._recv_closed = False
-        self._send_closed = False
+        self._send_closed = False  # this side has sent CLOSE (or the stream is reset)
+        self._remote_closed = False  # the peer's CLOSE has been seen
         self._reset = False
         self._inbox_bytes = 0  # bytes currently debited against the connection cap
 
@@ -93,43 +130,65 @@ class MuxStream:
     def peer_id(self):
         return self._conn.peer_id
 
-    async def send(self, message: bytes, *extra: bytes) -> None:
+    async def send(self, message: bytes, *extra: bytes, close: bool = False) -> None:
         """Send one message; ``extra`` buffers travel scatter-gather with it as a
         single frame (a spliced protobuf's tensor buffers ride uncopied into the
-        AEAD — the serving-path analog of the averaging framing)."""
+        AEAD — the serving-path analog of the averaging framing). ``close``: this is
+        the side's last message, and the half-close rides the same frame."""
         if self._send_closed or self._reset:
             raise StreamClosedError(f"stream {self.stream_id} is closed for sending")
-        total = len(message) + sum(len(part) for part in extra)
-        if total > MAX_MESSAGE_SIZE:
-            raise ValueError(
-                f"message of {total} bytes exceeds MAX_MESSAGE_SIZE={MAX_MESSAGE_SIZE}; "
-                f"split large tensors with utils.streaming.split_for_streaming"
-            )
-        await self._conn.send_frame(self.stream_id, Flags.DATA, message, *extra)
+        _check_message_size(len(message) + sum(len(part) for part in extra))
+        if close:
+            await self._send_last(_DATA_CLOSE, message, *extra)
+        else:
+            await self._conn.send_frame(self.stream_id, Flags.DATA, message, *extra)
+
+    async def _send_last(self, flags: Flags, *payload: bytes) -> None:
+        """This side's last frame, whatever else it carries: the half-close is sent."""
+        self._send_closed = True
+        try:
+            await self._conn.send_frame(self.stream_id, flags, *payload)
+        finally:
+            self._half_closed()
 
     async def send_error(self, exc: BaseException) -> None:
+        """A failed handler's last word: the error and the half-close in one frame."""
         if self._send_closed or self._reset:
             return
         payload = MSGPackSerializer.dumps({"type": type(exc).__name__, "message": str(exc)})
-        await self._conn.send_frame(self.stream_id, Flags.ERROR, payload)
+        await self._send_last(_ERROR_CLOSE, payload)
 
     async def close_send(self) -> None:
         """Half-close: no more messages from this side."""
         if not self._send_closed and not self._reset:
-            self._send_closed = True
             try:
-                await self._conn.send_frame(self.stream_id, Flags.CLOSE, b"")
+                await self._send_last(Flags.CLOSE, b"")
             except (ConnectionError, StreamClosedError):
                 pass
 
+    @property
+    def is_complete(self) -> bool:
+        """Both sides have half-closed: nothing more can cross, in either direction."""
+        return self._send_closed and self._remote_closed and not self._reset
+
+    def _half_closed(self) -> None:
+        """One side's CLOSE has just been sent or seen: if it was the second, the stream
+        ends here, on this peer, with no frame (module docstring)."""
+        if self.is_complete:
+            self._conn._forget_stream(self.stream_id)
+
     async def reset(self) -> None:
+        """Abort a stream that is not complete: the peer stops sending and cancels its
+        handler. On a complete stream there is nobody left to tell."""
         if not self._reset:
+            complete = self.is_complete
             self._reset = True
             self._send_closed = True
-            try:
-                await self._conn.send_frame(self.stream_id, Flags.RESET, b"")
-            except (ConnectionError, StreamClosedError):
-                pass
+            if not complete:
+                try:
+                    await self._conn.send_frame(self.stream_id, Flags.RESET, b"")
+                except (ConnectionError, StreamClosedError):
+                    pass
             self._push_eof()
             self._conn._forget_stream(self.stream_id)
 
@@ -214,20 +273,27 @@ class MuxConnection:
         return self._closed
 
     async def open_stream(
-        self, handler_name: str, trace_context: Optional[bytes] = None
+        self, handler_name: str, trace_context: Optional[bytes] = None, request: Optional[tuple] = None
     ) -> MuxStream:
+        """Open a stream to the peer's handler. ``request``: the buffers of the caller's
+        ONE message — open section, message and half-close then leave as one frame,
+        OPEN|DATA|CLOSE, and the stream comes back closed for sending."""
         if self._closed:
             raise StreamClosedError(f"connection to {self.peer_id} is closed")
+        opening = [handler_name.encode("utf-8")]
+        if trace_context is not None:
+            opening += [b"\x00", trace_context]
+        flags = Flags.OPEN
+        if request is not None:
+            _check_message_size(sum(len(part) for part in request))  # before an id is taken
+            flags = _OPEN_DATA_CLOSE
+            opening = [_OPEN_LENGTH.pack(sum(len(part) for part in opening)), *opening, *request]
         stream_id = self._next_stream_id
         self._next_stream_id += 2
         stream = MuxStream(self, stream_id, handler_name)
+        stream._send_closed = request is not None
         self._streams[stream_id] = stream
-        if trace_context is not None:
-            await self.send_frame(
-                stream_id, Flags.OPEN, handler_name.encode("utf-8"), b"\x00", trace_context
-            )
-        else:
-            await self.send_frame(stream_id, Flags.OPEN, handler_name.encode("utf-8"))
+        await self.send_frame(stream_id, flags, *opening)
         return stream
 
     @property
@@ -255,7 +321,7 @@ class MuxConnection:
                 # zero-copy: DATA payloads ride to their consumer as a view of the
                 # decrypted frame instead of re-materializing frame[9:] per message
                 payload = memoryview(frame)[_HEADER.size :]
-                await self._dispatch(stream_id, Flags(flags), payload)
+                await self._dispatch(stream_id, flags, payload)
         except (ConnectionError, OSError, asyncio.IncompleteReadError, EOFError) as e:
             error = e
         except asyncio.CancelledError:
@@ -266,15 +332,18 @@ class MuxConnection:
         finally:
             await self._shutdown(error)
 
-    async def _dispatch(self, stream_id: int, flags: Flags, payload) -> None:
-        # ``payload`` is a memoryview into the decrypted frame; the rare control
-        # frames (OPEN/ERROR) materialize it, DATA frames pass the view through
+    async def _dispatch(self, stream_id: int, flags: int, payload) -> None:
+        # ``payload`` is a memoryview into the decrypted frame; the open section and an
+        # error materialize it, a message passes the view through. One frame may carry
+        # several flags: OPEN, then DATA, then ERROR, then CLOSE / RESET
         self.last_used = time.monotonic()
-        if flags & Flags.OPEN:
+        flags = int(flags)
+        if flags & _OPEN:
             # a remote OPEN must use the REMOTE side's id parity and a fresh id: a
             # misbehaving peer reusing a local-parity or existing id would silently
             # replace a live stream in _streams, misrouting its responses and
-            # orphaning its credit accounting
+            # orphaning its credit accounting. Refused before any payload it carries
+            # is delivered to anybody
             if stream_id % 2 == self._next_stream_id % 2 or stream_id in self._streams:
                 logger.warning(
                     f"connection to {self.peer_id}: rejecting OPEN with "
@@ -283,12 +352,58 @@ class MuxConnection:
                 )
                 await self.send_frame(stream_id, Flags.RESET, b"")
                 return
-            name_bytes, _nul, trace_raw = bytes(payload).partition(b"\x00")
-            handler_name = name_bytes.decode("utf-8", errors="replace")
-            stream = MuxStream(self, stream_id, handler_name)
+            opening = payload
+            if flags & _DATA:  # [u16 length][open section][message]
+                if len(payload) < _OPEN_LENGTH.size:
+                    raise ConnectionError("malformed OPEN|DATA frame: no length of the open section")
+                message_at = _OPEN_LENGTH.size + _OPEN_LENGTH.unpack_from(payload)[0]
+                if message_at > len(payload):
+                    raise ConnectionError("malformed OPEN|DATA frame: the open section overruns it")
+                opening, payload = payload[_OPEN_LENGTH.size : message_at], payload[message_at:]
+            name_bytes, _nul, trace_raw = bytes(opening).partition(b"\x00")
+            stream = MuxStream(self, stream_id, name_bytes.decode("utf-8", errors="replace"))
             if trace_raw:
                 stream.trace_context = unpack_context(trace_raw)
             self._streams[stream_id] = stream
+        else:
+            stream = self._streams.get(stream_id)
+            if stream is None:
+                return  # already complete, reset or forgotten
+        if flags & _DATA:
+            self._buffered_bytes += len(payload)
+            if self._buffered_bytes > self._max_buffered_bytes:
+                logger.warning(
+                    f"connection to {self.peer_id}: buffered {self._buffered_bytes} bytes "
+                    f"exceeds cap; closing connection"
+                )
+                raise ConnectionError("per-connection buffer cap exceeded")
+            stream._push(payload)
+        if flags & _ERROR:
+            try:
+                info = MSGPackSerializer.loads(bytes(payload))
+                stream._push(RemoteError(info.get("type", "RemoteError"), info.get("message", "")))
+            except Exception:
+                stream._push(RemoteError("RemoteError", "malformed error payload"))
+        if flags & _RESET:
+            stream._push_eof()
+            # peer aborted: local side must stop sending immediately
+            stream._reset = True
+            stream._send_closed = True
+            # ...and stop COMPUTING: a still-running inbound handler for
+            # this stream is work nobody will read (a hedge's losing
+            # request, an abandoned call). A handler that already finished
+            # is no longer in the map — its completed response stands.
+            handler_task = self._stream_handler_tasks.pop(stream_id, None)
+            self._forget_stream(stream_id)
+            if handler_task is not None and not handler_task.done():
+                handler_task.cancel()
+            return
+        if flags & _CLOSE:
+            stream._push_eof()
+            stream._remote_closed = True
+            stream._half_closed()  # the second half-close ends the stream here
+        if flags & _OPEN:
+            # the handler starts with its inbox already holding what the frame carried
             task = spawn(self._on_inbound_stream(stream), name="mux.inbound_stream")
             self._handler_tasks.add(task)
             self._stream_handler_tasks[stream_id] = task
@@ -299,44 +414,15 @@ class MuxConnection:
                     self._stream_handler_tasks.pop(stream_id, None)
 
             task.add_done_callback(_forget_handler)
-            return
-        stream = self._streams.get(stream_id)
-        if stream is None:
-            return  # already reset/forgotten
-        if flags & Flags.DATA:
-            self._buffered_bytes += len(payload)
-            if self._buffered_bytes > self._max_buffered_bytes:
-                logger.warning(
-                    f"connection to {self.peer_id}: buffered {self._buffered_bytes} bytes "
-                    f"exceeds cap; closing connection"
-                )
-                raise ConnectionError("per-connection buffer cap exceeded")
-            stream._push(payload)
-        if flags & Flags.ERROR:
-            try:
-                info = MSGPackSerializer.loads(bytes(payload))
-                stream._push(RemoteError(info.get("type", "RemoteError"), info.get("message", "")))
-            except Exception:
-                stream._push(RemoteError("RemoteError", "malformed error payload"))
-        if flags & (Flags.CLOSE | Flags.RESET):
-            stream._push_eof()
-            if flags & Flags.RESET:
-                # peer aborted: local side must stop sending immediately
-                stream._reset = True
-                stream._send_closed = True
-                self._forget_stream(stream_id)
-                # ...and stop COMPUTING: a still-running inbound handler for
-                # this stream is work nobody will read (a hedge's losing
-                # request, an abandoned call). A handler that already finished
-                # is no longer in the map — its completed response stands.
-                handler_task = self._stream_handler_tasks.pop(stream_id, None)
-                if handler_task is not None and not handler_task.done():
-                    handler_task.cancel()
 
     def _forget_stream(self, stream_id: int) -> None:
+        """The stream is complete or reset: its table entry, its undrained credit and its
+        handler's entry go (what its inbox still holds stays readable, and the handler of
+        a complete stream runs to its end)."""
         stream = self._streams.pop(stream_id, None)
         if stream is not None:
             stream._return_credit()
+            self._stream_handler_tasks.pop(stream_id, None)
 
     async def _shutdown(self, error: Optional[BaseException]) -> None:
         if self._closed:

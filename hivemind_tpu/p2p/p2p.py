@@ -122,14 +122,24 @@ def _serialize(message):
     return message.SerializeToString()
 
 
-async def _send_payload(stream, payload) -> int:
-    """Send one serialized payload (bytes or WireParts) on a stream; returns the
-    byte count for the RPC accounting."""
+def _payload_buffers(payload) -> tuple:
+    """The buffers of one serialized payload (bytes or WireParts) as they enter a frame."""
+    return tuple(payload.parts) if isinstance(payload, WireParts) else (payload,)
+
+
+def _payload_len(payload) -> int:
+    return payload.nbytes if isinstance(payload, WireParts) else len(payload)
+
+
+async def _send_payload(stream, payload, close: bool = False) -> int:
+    """Send one serialized payload (bytes or WireParts) on a stream, with the side's
+    half-close on the same frame if it is its last; returns the byte count for the RPC
+    accounting."""
     if isinstance(payload, WireParts):
-        await stream.send(b"", *payload.parts)
-        return payload.nbytes
-    await stream.send(payload)
-    return len(payload)
+        await stream.send(b"", *payload.parts, close=close)
+    else:
+        await stream.send(payload, close=close)
+    return _payload_len(payload)
 
 
 def _chaos_payload(payload):
@@ -767,7 +777,6 @@ class P2P:
             # forever — a peer cycling fake names must not grow the registry
             _RPC_ERRORS.inc(handler="<unknown>", side="server")
             await stream.send_error(P2PHandlerError(f"unknown handler {stream.handler_name!r}"))
-            await stream.close_send()
             return
         context = P2PContext(stream.handler_name, self.peer_id, stream.peer_id)
         started = time.perf_counter()
@@ -802,10 +811,10 @@ class P2P:
                     result = await result
                 async for response in result:
                     bytes_out += await _send_payload(stream, _serialize(response))
-            else:
+                await stream.close_send()
+            else:  # one response: the message and this side's half-close in one frame
                 response = await handler.fn(request, context)
-                bytes_out += await _send_payload(stream, _serialize(response))
-            await stream.close_send()
+                bytes_out += await _send_payload(stream, _serialize(response), close=True)
         except StreamClosedError:
             return  # peer reset/vanished mid-call: normal termination for a handler
         except asyncio.CancelledError:
@@ -816,8 +825,7 @@ class P2P:
                 handler_trace.span.add_event("error", type=type(e).__name__)
             logger.debug(f"handler {stream.handler_name} failed: {e!r}")
             try:
-                await stream.send_error(e)
-                await stream.close_send()
+                await stream.send_error(e)  # ERROR|CLOSE
             except StreamClosedError:
                 pass
         finally:
@@ -831,18 +839,21 @@ class P2P:
     # ------------------------------------------------------------------ calls
 
     async def _open_stream_with_redial(
-        self, peer_id: PeerID, name: str, trace_context: Optional[bytes] = None
+        self, peer_id: PeerID, name: str, trace_context: Optional[bytes] = None, request=None
     ) -> MuxStream:
         """Open a stream, re-dialing once if the cached connection died between
         lookup and use (e.g. the connection manager trimmed it, or the peer
         restarted) — a trimmed idle connection must look like a cache miss, not
-        an RPC failure."""
+        an RPC failure. ``request``: the caller's one serialized message, which then
+        leaves with the open as ONE frame (``MuxConnection.open_stream``); a failed
+        send of that frame precedes delivery, so the re-dial is safe for any RPC."""
+        buffers = None if request is None else _payload_buffers(request)
         conn = await self._get_connection(peer_id)
         try:
-            return await conn.open_stream(name, trace_context)
+            return await conn.open_stream(name, trace_context, buffers)
         except StreamClosedError:
             conn = await self._get_connection(peer_id)
-            return await conn.open_stream(name, trace_context)
+            return await conn.open_stream(name, trace_context, buffers)
 
     async def call_protobuf_handler(
         self,
@@ -853,11 +864,13 @@ class P2P:
         *,
         idempotent: bool = False,
     ):
-        """Unary call: one request, one response.
+        """Unary call: one request, one response, one frame each way (the request as
+        OPEN|DATA|CLOSE, the answer as DATA|CLOSE; both peers then forget the stream with
+        no further frame, p2p/mux.py).
 
-        A failure while opening the stream or sending the request provably precedes
-        delivery, so it is always retried once on a fresh connection (the LRU trim /
-        peer-restart race). A failure while *waiting for the response* does not prove
+        A failure while opening the stream and sending the request — one frame — provably
+        precedes delivery, so it is always retried once on a fresh connection (the LRU
+        trim / peer-restart race). A failure while *waiting for the response* does not prove
         the handler never ran — the connection can die after the handler executed but
         before the response arrived — so that retry is gated on ``idempotent``:
         side-effectful calls (rpc_backward, rpc_decode) must fail loudly rather than
@@ -875,34 +888,31 @@ class P2P:
                     payload = await _CHAOS.inject(
                         "p2p.unary.send", payload=_chaos_payload(payload), scope=str(self.peer_id)
                     )
+                payload_len = _payload_len(payload)
                 for attempt in range(2):
-                    stream = await self._open_stream_with_redial(
-                        peer_id, name, None if call_span is None else call_span.context_bytes()
-                    )
                     try:
-                        try:
-                            payload_len = await _send_payload(stream, payload)
-                            await stream.close_send()
-                        except StreamClosedError:
-                            # the request never left: safe to retry for any RPC
-                            if attempt == 0:
-                                continue
-                            raise P2PHandlerError(f"{name}: connection closed before request was sent") from None
-                        try:
-                            response = await stream.receive()
-                        except RemoteError as e:
-                            raise P2PHandlerError(str(e)) from e
-                        except StreamClosedError:
-                            # nothing was received, but the request WAS sent: the peer may
-                            # or may not have processed it. Only retry when the caller
-                            # declared the RPC idempotent (reads: rpc_info, DHT ping/find,
-                            # or set-semantics writes like rpc_store).
-                            if idempotent and attempt == 0 and stream._conn.is_closed:
-                                continue
-                            raise P2PHandlerError(
-                                f"{name}: stream closed before response"
-                                + ("" if idempotent else " (not retried: RPC not marked idempotent)")
-                            ) from None
+                        stream = await self._open_stream_with_redial(
+                            peer_id, name, None if call_span is None else call_span.context_bytes(), payload
+                        )
+                    except StreamClosedError:
+                        # the request never left (twice: the cached connection and a fresh one)
+                        raise P2PHandlerError(f"{name}: connection closed before request was sent") from None
+                    try:
+                        response = await stream.receive()
+                    except RemoteError as e:
+                        raise P2PHandlerError(str(e)) from e
+                    except StreamClosedError:
+                        # nothing was received, but the request WAS sent: the peer may
+                        # or may not have processed it. Only retry when the caller
+                        # declared the RPC idempotent (reads: rpc_info, DHT ping/find,
+                        # or set-semantics writes like rpc_store).
+                        if idempotent and attempt == 0 and stream._conn.is_closed:
+                            continue
+                        raise P2PHandlerError(
+                            f"{name}: stream closed before response"
+                            + ("" if idempotent else " (not retried: RPC not marked idempotent)")
+                        ) from None
+                    else:
                         if _CHAOS.enabled:  # injection point: lose/corrupt the response
                             response = await _CHAOS.inject(
                                 "p2p.unary.recv", payload=response, scope=str(self.peer_id)
@@ -911,7 +921,7 @@ class P2P:
                         _RPC_BYTES.inc(len(response), handler=name, direction="in")
                         return _parse(response, response_type)
                     finally:
-                        await stream.reset()
+                        await stream.reset()  # says nothing on a complete stream: an abandoned call's RESET
             except asyncio.CancelledError:
                 raise
             except BaseException:
@@ -936,24 +946,27 @@ class P2P:
         stream_span = _start_span(
             f"p2p.stream:{name}", peer=str(self.peer_id), remote=str(peer_id)
         )
-        stream = await self._open_stream_with_redial(
-            peer_id, name, None if stream_span is None else stream_span.context_bytes()
-        )
+        trace_context = None if stream_span is None else stream_span.context_bytes()
+        started = time.perf_counter()
+        bytes_in = bytes_out = 0
+        single = not hasattr(requests, "__aiter__")
+        if single:  # one request message: it leaves with the open, as a unary call's does
+            payload = _serialize(requests)
+            if _CHAOS.enabled:  # injection point: per streamed request message
+                payload = await _CHAOS.inject(
+                    "p2p.stream.send", payload=_chaos_payload(payload), scope=str(self.peer_id)
+                )
+            stream = await self._open_stream_with_redial(peer_id, name, trace_context, payload)
+            bytes_out += _payload_len(payload)
+        else:
+            stream = await self._open_stream_with_redial(peer_id, name, trace_context)
 
         async def _feed():
             nonlocal bytes_out
             try:
-                if hasattr(requests, "__aiter__"):
-                    async for request in requests:
-                        payload = _serialize(request)
-                        if _CHAOS.enabled:  # injection point: per streamed request message
-                            payload = await _CHAOS.inject(
-                                "p2p.stream.send", payload=_chaos_payload(payload), scope=str(self.peer_id)
-                            )
-                        bytes_out += await _send_payload(stream, payload)
-                else:
-                    payload = _serialize(requests)
-                    if _CHAOS.enabled:
+                async for request in requests:
+                    payload = _serialize(request)
+                    if _CHAOS.enabled:  # injection point: per streamed request message
                         payload = await _CHAOS.inject(
                             "p2p.stream.send", payload=_chaos_payload(payload), scope=str(self.peer_id)
                         )
@@ -967,15 +980,13 @@ class P2P:
                 await stream.reset()
                 raise
 
-        started = time.perf_counter()
-        bytes_in = bytes_out = 0
-        feeder = asyncio.create_task(_feed())
+        feeder = None if single else asyncio.create_task(_feed())
         try:
             while True:
                 try:
                     message = await stream.receive()
                 except StreamClosedError:
-                    if feeder.done() and not feeder.cancelled() and feeder.exception() is not None:
+                    if feeder is not None and feeder.done() and not feeder.cancelled() and feeder.exception() is not None:
                         _RPC_ERRORS.inc(handler=name, side="client")
                         raise feeder.exception()
                     return
@@ -989,7 +1000,8 @@ class P2P:
                 bytes_in += len(message)
                 yield _parse(message, response_type)
         finally:
-            feeder.cancel()
+            if feeder is not None:
+                feeder.cancel()
             _finish_span(stream_span)
             _RPC_LATENCY.observe(time.perf_counter() - started, handler=name, side="client")
             if bytes_in:

@@ -1,12 +1,15 @@
 """Counters at the boundaries where the wire's bytes are worked on (ISSUE 37), and
 the one call that opens a work span there.
 
-``hivemind_wire_seconds_total{phase}`` / ``hivemind_wire_bytes_total{phase}``:
+``hivemind_wire_seconds_total{phase}`` / ``hivemind_wire_bytes_total{phase}``, and for the
+two phases whose unit of work is a frame, ``hivemind_wire_frames_total{phase}``:
 
     encode     a tensor or tensor part serialized with its codec (executor threads);
                bytes: the array that went in
     decode     the inverse, chunk joins included; bytes: the buffer that went in
-    seal/open  one frame through the AEAD (the ``hmtpu-aead`` pool, or inline when small)
+    seal/open  one frame through the AEAD (the ``hmtpu-aead`` pool, or inline when small);
+               frames: one a call — what the event loop pays a fixed price for, whatever
+               the frame carries (a unary call is two frames, one each way)
     reduce     the reducer's numpy: accumulate, divide, delta, the sender's store
     send_wait  seconds a frame's sender stood waiting for the channel's in-flight
                credit, which the writer hands back; seconds only — it awaits
@@ -31,6 +34,7 @@ _SECONDS = REGISTRY.counter(
     "hivemind_wire_seconds_total", "seconds of work on the wire's bytes, summed over threads", ("phase",)
 )
 _BYTES = REGISTRY.counter("hivemind_wire_bytes_total", "bytes that work was done on", ("phase",))
+_FRAMES = REGISTRY.counter("hivemind_wire_frames_total", "frames sealed and opened by the channels", ("phase",))
 
 
 def _phase(phase: str):
@@ -38,9 +42,19 @@ def _phase(phase: str):
     not the codec's, and its span says so."""
     add_seconds, add_bytes = _SECONDS.labels(phase=phase).inc, _BYTES.labels(phase=phase).inc
 
-    def sink(elapsed: float, size: int) -> None:
-        add_seconds(elapsed)
-        add_bytes(size)
+    if phase in ("seal", "open"):
+        add_frames = _FRAMES.labels(phase=phase).inc
+
+        def sink(elapsed: float, size: int) -> None:
+            add_seconds(elapsed)
+            add_bytes(size)
+            add_frames()
+
+    else:
+
+        def sink(elapsed: float, size: int) -> None:
+            add_seconds(elapsed)
+            add_bytes(size)
 
     return "allreduce.reduce" if phase == "reduce" else "wire." + phase, sink
 
