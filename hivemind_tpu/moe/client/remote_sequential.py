@@ -109,7 +109,8 @@ class RemoteSequential:
         self._span_support: Dict[object, bool] = {}  # peer_id -> server groups spans
         # session_id -> {"route": pinned block handles, "chunks": list of input
         # chunks retained for failover re-prefill (None = over the retention cap),
-        # "positions": retained position count}
+        # "positions": retained position count, "chunked": the longest chunk of more
+        # than one position that CONTINUED the session (0: the prompt came whole)}
         self._decode_routes: Dict[str, dict] = {}
         self.max_decode_routes = 256  # oldest pinned routes drop beyond this
         # seeded replica choice across route resolutions (ISSUE 13): fresh
@@ -364,7 +365,9 @@ class RemoteSequential:
         call (``reset=True``) seeds each block's session with the prompt chunk
         [batch, prompt_len, hid], later calls advance a single token
         [batch, 1, hid] — O(context) per token vs the O(context²) right-padded
-        ``__call__`` decode. Sessions are STICKY to the peers resolved at prefill
+        ``__call__`` decode. A long prompt may arrive in CHUNKS: a later call of more
+        than one position continues the session where the last one ended, on servers
+        whose blocks take chunks (``decode_takes_chunks``; any other block raises). Sessions are STICKY to the peers resolved at prefill
         (the periodic DHT re-resolution must not silently move a session to a
         cache-less peer), but a dead pinned peer fails over TRANSPARENTLY
         (VERDICT r3 #3, Petals-class behavior): the client retains each session's
@@ -393,6 +396,7 @@ class RemoteSequential:
                     "route": route,
                     "chunks": [],
                     "positions": 0,
+                    "chunked": 0,
                     "lock": prior["lock"] if prior is not None else threading.Lock(),
                 }
                 self._decode_routes[session_id] = state
@@ -417,6 +421,8 @@ class RemoteSequential:
             # max_failover_history — past the cap, retention stops and a dead peer is
             # a hard error again (restart with reset=True), bounding client memory
             step_appended = False
+            if not reset and x.shape[1] > 1:
+                state["chunked"] = max(state["chunked"], x.shape[1])
             if reset:
                 if self.max_failover_history and x.shape[1] <= self.max_failover_history:
                     state["chunks"], state["positions"] = [x], x.shape[1]
@@ -480,19 +486,27 @@ class RemoteSequential:
     def _decode_failover(self, session_id: str, state: dict, history) -> "np.ndarray":
         """Re-resolve the pipeline and re-prefill EVERY group from the retained
         input history (surviving groups simply rebuild identical caches; the
-        replacement peer builds its first). Each group's full-history prefill
-        output is the next group's input history, so one sweep both recovers the
-        caches and computes the current step. Retries with forced re-resolution
+        replacement peer builds its first). Each group's prefill output is the
+        next group's input, so one sweep both recovers the caches and computes the
+        current step. Retries with forced re-resolution
         (a replacement server may take a moment to re-declare the uid)."""
         import numpy as np
 
+        # a prompt that arrived in chunks is re-sent in chunks (no longer than the
+        # longest the session sent: what its servers were shown to take), each chunk
+        # through every group before the next; a prompt that came whole goes whole
+        size = state["chunked"] or history.shape[1]
+        pieces = [history[:, start:start + size] for start in range(0, history.shape[1], size)]
+
         def one_attempt():
             route = self._grouped_range(0, self.num_blocks, force=True)
-            out = history
-            for block, span in route:
-                out = block.decode_np(out, session_id, reset=True, span=span)
+            outs = []
+            for index, out in enumerate(pieces):
+                for block, span in route:
+                    out = block.decode_np(out, session_id, reset=index == 0, span=span)
+                outs.append(np.asarray(out, np.float32))
             state["route"] = route
-            return np.asarray(out, np.float32)
+            return np.concatenate(outs, axis=1)
 
         def on_retry(retry_index: int, error: BaseException) -> None:
             logger.warning(

@@ -5,9 +5,12 @@ costs O(context) instead of the O(context²) right-padded recompute that
 `RemoteSequential.__call__` implies. This is the session layer for the same
 capability on the TPU stack: a client opens a session per block uid (a msgpack
 `{"session_id", "reset"}` rides `ExpertRequest.metadata` — no proto change), the
-first call prefills the prompt into fresh caches, and every later call advances one
-token. Caches live on-device in the block's compact kv-heads layout
-(`init_decode_cache` on the block class), the step function is jitted once per
+first call prefills the prompt (or its first chunk) into fresh caches, and every
+later call advances one token — or, on a chain whose blocks all take chunks, brings
+the prompt's next chunk. A session's cache lives on-device as whatever TREE of arrays
+the block's `init_decode_cache` returned (a `(cache_k, cache_v)` pair, a recurrent
+state, keys with values and compressed keys): the manager joins, splits, donates,
+places and counts it leaf by leaf and never looks inside. The step function is jitted once per
 (uid, batch, chunk-length) signature, and sessions expire by TTL / LRU cap so an
 abandoned client cannot pin device memory.
 
@@ -42,8 +45,9 @@ address order: correct, merely unmerged.
 What a block class owes this path (``index`` as a scalar in a session's own call, as
 a vector in a batched step) is written down in `moe/server/layers/__init__.py`. The
 session count is bucketed to powers of two so the jit cache stays small. Sessions
-keep their caches one array each; a block's program takes them as they are, joins
-them, steps, and hands the new caches back one array a session, so a block's batch
+keep their caches one tree each, as the tuple of its leaves; a block's program takes
+them as they are, joins them leaf by leaf (or hands them unjoined to a block that says
+`decode_rows_apart`), steps, and hands the new leaves back one array a session, so a block's batch
 is ONE dispatch whatever its rows: the host only collects handles before it
 (``assemble``: the activations — the last block's output as it is, or one
 `np.concatenate` of host rows through the upload program — and one array of write
@@ -69,7 +73,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hivemind_tpu.moe.server.routing_stats import ROUTING_COLLECTION, held_range, record_routing
+from hivemind_tpu.moe.server.routing_stats import (
+    ATTENDED_COLLECTION,
+    ROUTING_COLLECTION,
+    held_range,
+    record_attended,
+    record_routing,
+)
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 from hivemind_tpu.telemetry.device import record_transfer
 from hivemind_tpu.telemetry.serving import accrue_span_phase
@@ -145,8 +155,9 @@ _CALLS_BATCHED, _CALLS_DIRECT = _CALLS.labels("batched"), _CALLS.labels("direct"
 # every prefill cost the Mistral cell an eighth of its rate (PERF.md section 6, PR 34)
 _CACHE_BYTES = _TELEMETRY.gauge(
     "hivemind_moe_decode_cache_bytes",
-    "bytes of decode caches that the session table holds, by kind of cache (window = a ring "
-    "of a sliding-window block's last positions, full = every position of the session)",
+    "bytes of decode caches that the session table holds, by kind of cache as the block names it (window = a ring "
+    "of a sliding-window block's last positions, full = every position of the session, sparse = keys, values and "
+    "compressed keys of a block-sparse attention block, lightning = a linear-attention block's recurrent state)",
     ("kind",),
 )
 _CACHE_ENTRIES = _TELEMETRY.gauge(
@@ -164,6 +175,11 @@ _PREFILL_SECONDS = _TELEMETRY.counter(
 _PREFILL_POSITIONS = _TELEMETRY.counter(
     "hivemind_moe_decode_prefill_positions_total",
     "positions, padded as run, of the prefill programs, one count a block a prompt crosses",
+)
+_PREFILL_CHUNKS = _TELEMETRY.counter(
+    "hivemind_moe_decode_prefill_chunks_total",
+    "chunks of more than one position that CONTINUED a session (a prompt that arrives in chunks: every "
+    "chunk after its first), one count a chunk whatever the blocks it crosses",
 )
 _COHORTS = _TELEMETRY.counter(
     "hivemind_moe_decode_cohorts_total",
@@ -203,17 +219,39 @@ def _cohort_rows(waiting: int) -> int:
 
 
 class _Session:
-    __slots__ = ("cache_k", "cache_v", "nbytes", "index", "last_used", "lock", "batch_started")
+    __slots__ = ("leaves", "tree", "nbytes", "batch", "index", "last_used", "lock", "batch_started")
 
-    def __init__(self, cache_k, cache_v):
-        self.cache_k, self.cache_v = cache_k, cache_v
-        self.nbytes = cache_k.nbytes + cache_v.nbytes  # a step hands back caches of the same shapes
+    def __init__(self, cache):
+        # whatever the block's `init_decode_cache` returned: a tree of arrays, batch axis
+        # first (a `(cache_k, cache_v)` pair is a tree of two leaves); nothing here looks
+        # inside. It is kept FLAT, as the tuple of its leaves: that is what a block is handed
+        # and hands back, so no step and no batch walks a tree on the host
+        leaves, self.tree = jax.tree_util.tree_flatten(cache)
+        self.leaves = tuple(leaves)
+        self.nbytes = sum(leaf.nbytes for leaf in leaves)  # a step hands back leaves of the same shapes
+        self.batch = leaves[0].shape[0]
         self.index = 0
         self.last_used = time.monotonic()
         # perf_counter at which the batch carrying this session's pending step
         # began to run: the end of that step's queue wait (decode_span_async)
         self.batch_started = 0.0
         self.lock = threading.Lock()
+
+    @property
+    def cache(self):
+        """The tree as the block's `init_decode_cache` shaped it, of the leaves as they are now."""
+        return jax.tree_util.tree_unflatten(self.tree, self.leaves)
+
+    @property
+    def cache_k(self):
+        """A `(cache_k, cache_v)` pair's first leaf (a tree of other shape has none)."""
+        cache_k, _cache_v = self.leaves
+        return cache_k
+
+    @property
+    def cache_v(self):
+        _cache_k, cache_v = self.leaves
+        return cache_v
 
 
 class _Output:
@@ -222,10 +260,10 @@ class _Output:
     batch's live entries), the routing it sowed, and once somebody needed it on the
     host, that copy."""
 
-    __slots__ = ("y", "routing", "rows", "held", "on_host", "settled")
+    __slots__ = ("y", "routing", "attended", "rows", "held", "on_host", "settled")
 
-    def __init__(self, y, routing, rows: int, held=None):
-        self.y, self.routing, self.rows, self.held = y, routing, rows, held
+    def __init__(self, y, routing, attended, rows: int, held=None):
+        self.y, self.routing, self.attended, self.rows, self.held = y, routing, attended, rows, held
         self.on_host: Optional[np.ndarray] = None
         self.settled = False
 
@@ -241,6 +279,7 @@ class _Output:
         if not self.settled:
             self.settled = True
             record_routing(self.routing, "batched", span, rows=self.rows, held=self.held)
+            record_attended(self.attended, rows=self.rows)
             self.y.block_until_ready()
 
 
@@ -272,7 +311,7 @@ class DecodeSessionManager:
         self._sessions: Dict[Tuple[str, str], _Session] = {}
         self._step_fns: Dict[Tuple[str, int, int], callable] = {}
         self._batched_fns: Dict[Tuple[str, int], callable] = {}
-        self._dummy_caches: Dict[str, tuple] = {}  # per-uid padding rows for pow2 buckets
+        self._dummy_caches: Dict[str, tuple] = {}  # per-uid padding row (a cache's leaves) for pow2 buckets
         self._lock = threading.Lock()
         # both keyed by the span chain (the tuple of uids a request crosses)
         self._pending: Dict[Chain, List] = {}  # chain -> [(future, [the session of each uid], x), ...]
@@ -354,18 +393,24 @@ class DecodeSessionManager:
         position (a session's prefill or step) or a vector of them, one a row (a
         batch of sessions). ``length`` (at most one: the chunk's real positions,
         the rest is padding) goes to a block that asks for it (`_takes_length`).
-        Returns (y, cache_k, cache_v, routing): what the block sowed into
-        `ROUTING_COLLECTION` (empty for a block without experts)."""
+        ``leaves`` are the leaves of the session's cache tree; the block is handed them
+        and hands new ones back in the same order (in a batched step of a block that says
+        `decode_rows_apart`, each leaf is the tuple of the rows' own arrays, `_batched_fn`).
+        Returns (y, leaves, routing, attended): what the block sowed into
+        `ROUTING_COLLECTION` (empty for a block without experts) and into
+        `ATTENDED_COLLECTION` (empty for a block whose steps attend all they cached)."""
         backend = self.backends[uid]
 
-        def step(params, x, cache_k, cache_v, index, *length):
+        def step(params, x, leaves, index, *length):
             # int8 weight-only backends: materialize dense weights inside the jit
             # (identity for plain fp32 trees)
-            (y, cache_k, cache_v), routing = backend.module.apply(
-                {"params": backend.dense_params(params)}, x, cache_k, cache_v, index, *length,
-                mutable=[ROUTING_COLLECTION],
+            (y, *leaves), sown = backend.module.apply(
+                {"params": backend.dense_params(params)}, x, *leaves, index, *length,
+                mutable=[ROUTING_COLLECTION, ATTENDED_COLLECTION],
             )
-            return y, cache_k, cache_v, routing
+            sown = dict(sown)
+            attended = sown.pop(ATTENDED_COLLECTION, {})
+            return y, tuple(leaves), sown, attended
 
         return step
 
@@ -378,6 +423,12 @@ class DecodeSessionManager:
         if kind:
             program.__name__ = program.__qualname__ = name.format(kind=kind)
         return program
+
+    def _takes_chunks(self, chain: Chain) -> bool:
+        """Whether a chunk of more than one position may CONTINUE a session on this
+        chain: only if every block of it says so (``decode_takes_chunks``: a prompt
+        that arrives in chunks). Any other block's first chunk is its whole prompt."""
+        return all(getattr(self.backends[uid].module, "decode_takes_chunks", False) for uid in chain)
 
     def _takes_length(self, uid: str) -> bool:
         """Whether the block is told how many positions of a right-padded chunk are
@@ -394,31 +445,30 @@ class DecodeSessionManager:
             # buckets shows up as a recompile storm, not silent latency
             name = "step_{kind}" if new_len == 1 else f"prefill_{{kind}}_{new_len}"
             fn = self._step_fns[key] = tracked_jit(
-                self._named_by_kind(uid, self._raw_step(uid), name), site="decode_session.step", donate_argnums=(2, 3),
-                out_shardings=(None, *self._cache_shardings(uid), None),
+                self._named_by_kind(uid, self._raw_step(uid), name), site="decode_session.step", donate_argnums=(2,),
+                out_shardings=(None, self._cache_shardings(uid), None, None),
             )
         return fn
 
     def _cache_shardings(self, uid: str):
-        """Where `shard_decode_cache` places this block's (cache_k, cache_v), for a
+        """Where `shard_decode_cache` places this block's cache, leaf by leaf, for a
         step's `out_shardings`: left to the compiler, a mesh program's new caches
         come back under shardings of its choosing, and every mixture of those over
-        a batch's rows is another program. (None, None), i.e. nothing pinned, for
-        caches that no backend placed."""
+        a batch's rows is another program. None, i.e. nothing pinned, for caches
+        that no backend placed."""
         if not hasattr(self.backends[uid], "shard_decode_cache"):
-            return None, None
-        cache_k, cache_v = self._dummy_rows(uid)
-        return cache_k.sharding, cache_v.sharding
+            return None
+        return tuple(leaf.sharding for leaf in self._dummy_rows(uid))
 
     def _fresh_caches(self, backend, batch: int):
-        """Empty (cache_k, cache_v) for ``batch`` rows, placed as the backend serves them."""
-        cache_k, cache_v = backend.module.init_decode_cache(batch, self.max_len)
+        """An empty cache tree for ``batch`` rows, placed as the backend serves it."""
+        cache = backend.module.init_decode_cache(batch, self.max_len)
         if hasattr(backend, "shard_decode_cache"):
             # mesh-sharded serving: the session's KV lives distributed
             # over the backend's mesh (MeshModuleBackend), so a cache
             # that exceeds one chip's HBM still fits the slice
-            cache_k, cache_v = backend.shard_decode_cache(cache_k, cache_v)
-        return cache_k, cache_v
+            cache = backend.shard_decode_cache(*cache)
+        return cache
 
     def _advance(self, uid: str, session: _Session, backend, x, chunk_len: int, new_len: int):
         """Run the per-session jitted step on ``x`` (``new_len`` positions, already
@@ -435,11 +485,11 @@ class DecodeSessionManager:
         started = time.perf_counter()
         try:
             with _trace_sync("decode.direct", uid=uid, chunk_len=chunk_len) as span:
-                y, session.cache_k, session.cache_v, routing = step(
-                    backend.snapshot_params(), jnp.asarray(x), session.cache_k,
-                    session.cache_v, jnp.int32(session.index), *length,
+                y, session.leaves, routing, attended = step(
+                    backend.snapshot_params(), jnp.asarray(x), session.leaves, jnp.int32(session.index), *length,
                 )
                 record_routing(routing, "direct", span, positions=new_len, held=held_range(backend.module))
+                record_attended(attended, positions=new_len)
                 # the next block is dispatched when this one has finished: a cohort's
                 # program that arrives meanwhile waits for one block of a prefill, not
                 # for the chain
@@ -461,8 +511,9 @@ class DecodeSessionManager:
 
     def _decode_direct(self, chain: Chain, session_id: str, x: np.ndarray, reset: bool) -> np.ndarray:
         """One session's step through the span chain, ONE chain on the device: prefill
-        (``reset=True``, chunk = the prompt) or advance one token in an existing
-        session. The chunk is padded and uploaded once, each block's own program runs
+        (``reset=True``, chunk = the prompt or its first chunk), a further chunk of the
+        prompt (a chain whose blocks all take chunks, `_takes_chunks`), or advance one
+        token in an existing session. The chunk is padded and uploaded once, each block's own program runs
         on the output of the block before where it lies, and only the chain's last
         output comes to the host (a prompt of 4,096 positions at hidden 6,144 is 100 MB,
         which crossed the host twice a block). Returns the last block's output for the
@@ -484,6 +535,17 @@ class DecodeSessionManager:
         # first block the tail holds what the block before made of the padding:
         # finite, and as invisible).
         padded_len = new_len if new_len == 1 else min(_next_pow2(new_len), self.max_len)
+        continues = not reset and new_len > 1  # a further chunk of a prompt: it lands past what the session holds
+        if continues:
+            if not self._takes_chunks(chain):
+                raise ValueError(
+                    f"only 1-token steps may follow the prefill of session {session_id!r} (got chunk {new_len}): "
+                    f"a block of this chain does not take a prompt in chunks"
+                )
+            with self._lock:
+                held = self._sessions.get((chain[0], session_id))
+            if held is not None:  # the padded tail has to fit the cache too: a write past its end would be shifted
+                padded_len = max(min(padded_len, self.max_len - held.index), new_len)
         if padded_len != new_len:
             x = np.pad(x, ((0, 0), (0, padded_len - new_len), (0, 0)))
         record_transfer(x.nbytes, "host_to_device")
@@ -491,7 +553,7 @@ class DecodeSessionManager:
         for uid in chain:
             session = self._enter(uid, session_id, batch, reset)
             with session.lock:
-                self._check_step(session, session_id, batch, new_len)
+                self._check_step(session, session_id, batch, new_len, continues)
                 y = self._advance(uid, session, self.backends[uid], y, padded_len, new_len)
                 session.index += new_len
                 # re-stamp AFTER the device step: a step that hits a jit compile can
@@ -501,6 +563,8 @@ class DecodeSessionManager:
                 # Bare float store; concurrent readers just see one of two recent stamps.
                 session.last_used = time.monotonic()
                 _STEPS.inc(path="direct")
+        if continues:
+            _PREFILL_CHUNKS.inc()  # one that a block refused (an unknown session, a full cache) is not counted
         out = np.asarray(y)[:, :new_len]
         record_transfer(out.nbytes, "device_to_host")
         return out
@@ -514,7 +578,7 @@ class DecodeSessionManager:
             if reset:
                 if session is not None:
                     self._drop_locked(key)
-                session = self._sessions[key] = _Session(*self._fresh_caches(self.backends[uid], batch))
+                session = self._sessions[key] = _Session(self._fresh_caches(self.backends[uid], batch))
                 self._count_cache_locked(uid, session, +1)
                 _RESETS.inc()
                 self._sample_gauges_locked()
@@ -529,17 +593,18 @@ class DecodeSessionManager:
             session.last_used = time.monotonic()
         return session
 
-    def _check_step(self, session: _Session, session_id: str, batch: int, new_len: int) -> None:
-        """What a step must meet at a block (under ``session.lock``)."""
-        if session.index and new_len != 1:  # a prefill takes any chunk length (causal within the chunk)
+    def _check_step(self, session: _Session, session_id: str, batch: int, new_len: int, continues: bool = False) -> None:
+        """What a step must meet at a block (under ``session.lock``). ``continues``: the
+        chunk is a further chunk of a prompt, on a chain that takes those."""
+        if session.index and new_len != 1 and not continues:  # a prefill takes any chunk length (causal within the chunk)
             raise ValueError(
                 f"session {session_id!r} already holds {session.index} positions; "
                 f"only 1-token steps may follow the prefill (got chunk {new_len})"
             )
         if session.index + new_len > self.max_len:
             raise ValueError(f"session {session_id!r} is full ({session.index}/{self.max_len})")
-        if session.cache_k.shape[0] != batch:
-            raise ValueError(f"session {session_id!r} batch is {session.cache_k.shape[0]}, got {batch}")
+        if session.batch != batch:
+            raise ValueError(f"session {session_id!r} batch is {session.batch}, got {batch}")
 
     # ---- continuous batching of single-token steps across sessions ------------
 
@@ -792,40 +857,45 @@ class DecodeSessionManager:
 
     def _batched_fn(self, uid: str, stack: int):
         """The one program of a batch of ``stack`` rows: it takes the rows' caches
-        as they are kept, one array a session, joins them along the batch axis,
-        applies the block ONCE to all rows with the vector of their write positions
-        (`_raw_step`: the block runs its cache update and attention per row, all
-        else on the rows together) and hands the new caches back one array a
-        session, so that no operation per session runs outside it. Keyed by the
-        bucket alone; padding rows come in as arguments like live ones."""
+        as they are kept, leaf by leaf the tuple of the rows' arrays, joins each leaf
+        along the batch axis, applies the block ONCE to all rows with the vector of
+        their write positions (`_raw_step`: the block runs its cache update and
+        attention per row, all else on the rows together) and hands the new caches back
+        one array a leaf a session, so that no operation per session runs outside it.
+        A block that says `decode_rows_apart` is handed the tuples UNJOINED and hands
+        tuples back: one whose step reads a small part of a large cache updates and
+        reads each row's own arrays where they lie, and nothing joins, copies or splits
+        them. Keyed by the bucket alone; padding rows come in as arguments like live ones."""
         key = (uid, stack)
         fn = self._batched_fns.get(key)
         if fn is None:
             step = self._raw_step(uid)
+            apart = getattr(self.backends[uid].module, "decode_rows_apart", False)
 
-            def batched_step(params, xs, caches_k, caches_v, indices):
-                y, new_k, new_v, routing = step(
-                    params, xs, jnp.concatenate(caches_k), jnp.concatenate(caches_v), indices
-                )
-                return y, tuple(jnp.split(new_k, stack)), tuple(jnp.split(new_v, stack)), routing
+            def batched_step(params, xs, columns, indices):
+                leaves = columns if apart else tuple(jnp.concatenate(rows) for rows in columns)
+                y, new, routing, attended = step(params, xs, leaves, indices)
+                if not apart:  # back to one array a leaf a session
+                    new = tuple(tuple(jnp.split(leaf, stack)) for leaf in new)
+                return y, new, routing, attended
 
-            # the caches are NOT donated: the padding pair sits in several positions
+            # the caches are NOT donated: the padding row sits in several positions
             # of one call, and a step that fails leaves every session as it was
-            placed_k, placed_v = self._cache_shardings(uid)
+            placed = self._cache_shardings(uid)
             fn = self._batched_fns[key] = tracked_jit(
                 self._named_by_kind(uid, batched_step, "batched_step_{kind}"), site="decode_session.batched_step",
-                out_shardings=(None, (placed_k,) * stack, (placed_v,) * stack, None),
+                out_shardings=(None, placed and tuple((leaf,) * stack for leaf in placed), None, None),
             )
         return fn
 
-    def _dummy_rows(self, uid: str):
-        """A throwaway (cache_k, cache_v) pair used to pad batches to the bucket
-        size; its outputs and cache writes are discarded. Placed like a session's
-        caches, so that a padded call reaches the program a full bucket compiled."""
-        pair = self._dummy_caches.get(uid)
-        if pair is None:
-            pair = self._dummy_caches[uid] = self._fresh_caches(self.backends[uid], 1)
-        return pair
+    def _dummy_rows(self, uid: str) -> tuple:
+        """A throwaway cache (its leaves) used to pad batches to the bucket size; its
+        outputs and cache writes are discarded. Placed like a session's caches, so that
+        a padded call reaches the program a full bucket compiled."""
+        leaves = self._dummy_caches.get(uid)
+        if leaves is None:
+            leaves = self._dummy_caches[uid] = tuple(jax.tree_util.tree_leaves(self._fresh_caches(self.backends[uid], 1)))
+        return leaves
 
     def _decode_batch(self, uid: str, entries: List, fetch: bool = True) -> List:
         """Run one batched step over `entries` [(future, session, x)]; returns one
@@ -853,7 +923,7 @@ class DecodeSessionManager:
                     results[i] = KeyError(f"decode session for {uid!r} has no prefill yet")
                 elif session.index + 1 > self.max_len:
                     results[i] = ValueError(f"decode session is full ({session.index}/{self.max_len})")
-                elif session.cache_k.shape[0] != 1:
+                elif session.batch != 1:
                     results[i] = ValueError("batched decode requires session batch 1")
                 else:
                     live.append(i)
@@ -892,24 +962,23 @@ class DecodeSessionManager:
                 # host array through the upload program
                 sessions = [entries[i][1] for i in live]
                 padding = stack - len(live)
-                dummy_k, dummy_v = self._dummy_rows(uid)
                 xs = self._device_rows([entries[i][2] for i in live], stack)
                 # a padding row writes a valid mid-cache position; its output is discarded
                 indices = np.array([session.index for session in sessions] + [1] * padding, np.int32)
-                caches_k = tuple(session.cache_k for session in sessions) + (dummy_k,) * padding
-                caches_v = tuple(session.cache_v for session in sessions) + (dummy_v,) * padding
+                # leaf by leaf, the tuple of the rows' arrays (a pair: the keys' tuple and the values')
+                columns = tuple(zip(*[session.leaves for session in sessions] + [self._dummy_rows(uid)] * padding))
                 step = self._batched_fn(uid, stack)
             with _batch_phase("step"):
-                y, new_k, new_v, routing = step(backend.snapshot_params(), xs, caches_k, caches_v, indices)
-                output = _Output(y, routing, len(live), held_range(backend.module))
+                y, new, routing, attended = step(backend.snapshot_params(), xs, columns, indices)
+                output = _Output(y, routing, attended, len(live), held_range(backend.module))
                 if fetch:
                     output.host()
                     output.settle(span)
             _STEPS.inc(len(live), path="batched")
             with _batch_phase("scatter"):
                 now = time.monotonic()
-                for row, (i, session) in enumerate(zip(live, sessions)):
-                    session.cache_k, session.cache_v = new_k[row], new_v[row]
+                for row, (i, session, leaves) in enumerate(zip(live, sessions, zip(*new))):  # row by row, its new leaves
+                    session.leaves = leaves
                     session.index += 1
                     session.last_used = now
                     results[i] = output.host()[row:row + 1] if fetch else _Row(output, row)

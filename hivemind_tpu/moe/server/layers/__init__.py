@@ -4,13 +4,20 @@
 sample input (batch-size-agnostic) defines the expert's I/O schema.
 
 **Decode sessions: the contract of a block that keeps a cache.** A registered class
-is served through `DecodeSessionManager` if it has ``init_decode_cache(batch, max_len)
--> (cache_k, cache_v)`` (two arrays, batch axis first) and its ``__call__(x, cache_k,
-cache_v, index)`` returns ``(y, cache_k, cache_v)``. ``index`` is the write position,
-in one of two ranks:
+is served through `DecodeSessionManager` if it has ``init_decode_cache(batch, max_len)``
+and its ``__call__(x, *cache, index)`` returns ``(y, *cache)``. The cache is a TREE of
+arrays, batch axis first, as the block chooses: ``(cache_k, cache_v)`` (the four blocks
+of `common.py`), one recurrent state that a step UPDATES, or keys, values and compressed
+keys that a step appends to (`minicpm_sala_block`, both). The manager keeps a session's
+tree as the tuple of its leaves and never looks inside one: it joins the leaves of a
+batch's rows along the batch axis, splits the new leaves back one a row, donates a
+per-session call's leaves, places and counts them leaf by leaf (`shard_decode_cache` of
+a mesh backend takes a pair). The block is handed the leaves in the tree's order and
+hands new ones back in the same order and shapes. ``index`` is the write position, in
+one of two ranks:
 
-- a scalar: ONE session's prefill or step, ``x`` ``[batch, new_len, hidden]``, every
-  row at the same position;
+- a scalar: ONE session's prefill, prompt chunk or step, ``x`` ``[batch, new_len,
+  hidden]``, every row at the same position;
 - a vector ``[rows]``: a batched step of ``rows`` different sessions, ``x``
   ``[rows, 1, hidden]``, the caches joined along the batch axis, each row at its OWN
   position. The block is applied once to all the rows (it is not vmapped from
@@ -22,21 +29,42 @@ in one of two ranks:
   broadcasts wrongly there, and only in batched steps
   (`tests/test_moe.py::test_custom_cached_block_steps_batched` is the pattern).
 
-The two caches may have any shape with the batch axis first, and the blocks of one
-chain need not agree on it (`exaone_moe_block`: ``[batch, kv_heads, slots, head_dim]``,
-a ring of ``window`` slots for a sliding-window block beside ``max_len`` slots for a
-full-attention one): the manager joins, splits, donates and places each block's caches
-as that block's ``init_decode_cache`` made them; that a session is full stays the
-manager's to say (``max_len``). Two optional class attributes: ``decode_takes_length =
-True`` makes the manager pass a fifth argument to a per-session call, the number of
-REAL positions of the chunk (a prefill comes right-padded to a power of two; a cache
-that keeps every position needs no telling, its padded tail lies past ``index``; a
-ring must keep the padding out); ``decode_cache_kind`` (a short string) names the
-block's decode programs (`jit_batched_step_<kind>`, `jit_prefill_<kind>_<positions>`)
-and its caches in the telemetry (`hivemind_moe_decode_cache_bytes{kind}`). A block
-whose expert layer holds a share of the experts says which in ``held_experts``
-(``(lo, hi)``), and the routing counters tell the pairs it computed from the pairs
-it chose (`moe/server/routing_stats.py`).
+The blocks of one chain need not agree on the tree (`exaone_moe_block`: ``[batch,
+kv_heads, slots, head_dim]``, a ring of ``window`` slots for a sliding-window block
+beside ``max_len`` slots for a full-attention one; `minicpm_sala_block`: three arrays
+beside one); that a session is full stays the manager's to say (``max_len``). A step
+that fails must leave no half-updated state: a per-session call donates the tree, so
+the manager drops the session when it fails; a batched step does not donate, and its
+sessions stay as they were. Optional class attributes:
+
+- ``decode_takes_length = True``: the manager passes one more argument to a per-session
+  call, the number of REAL positions of the chunk (a chunk of more than one position
+  comes right-padded to a power of two; a cache that keeps every position needs no
+  telling, its padded tail lies past ``index``; a ring or a recurrent state must keep
+  the padding out);
+- ``decode_takes_chunks = True``: a chunk of MORE THAN ONE position may CONTINUE a
+  session (a long prompt arrives in chunks, each call continuing where the last one
+  ended; the chunk is padded as a prefill is, but never past the cache's end). A chain
+  takes such a chunk only if every block of it says so; any other block's first chunk
+  is its whole prompt, and a later one raises the ``ValueError`` it always raised;
+- ``decode_rows_apart = True``: in a batched step each leaf comes as the TUPLE of the
+  rows' own arrays (``[1, ...]`` each, ``rows`` of them) where it would come joined, and
+  goes back as such a tuple. For a block whose step touches a small part of a large
+  cache (a position written, some blocks gathered): it writes into and reads from each
+  row's own array, and nothing joins, copies and splits the whole caches around the
+  step (`minicpm_sala_block`'s sparse mixer: a third of the program's time and 2.3 GB of
+  its temporaries at 32 rows of 32,768 slots). A session's own call is handed arrays;
+- ``decode_cache_kind`` (a short string) names the block's decode programs
+  (`jit_batched_step_<kind>`, `jit_prefill_<kind>_<positions>`) and its caches in the
+  telemetry (`hivemind_moe_decode_cache_bytes{kind}`).
+
+A block whose expert layer holds a share of the experts says which in ``held_experts``
+(``(lo, hi)``), and the routing counters tell the pairs it computed from the pairs it
+chose (`moe/server/routing_stats.py`). A block whose steps attend a SELECTION of what
+they cached sows the positions attended and seen into `common.ATTENDED_COLLECTION` as
+``attended``, and the same module counts the live rows' (`hivemind_moe_sparse_positions_*_total`);
+what it sows there as ``chosen`` (the blocks each query selected) stays on the device
+unless a check against a reference taps it (`routing_stats.SELECTION_TAPS`).
 
 Which rows meet in a batched step is decided per SPAN CHAIN, not per block: the steps
 of the sessions that wait on the same chain of this server's blocks walk it together

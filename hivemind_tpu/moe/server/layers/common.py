@@ -267,6 +267,14 @@ class LlamaBlockExpert(nn.Module):
 # every block with this collection mutable and count the live rows on the host
 # (`moe/server/routing_stats.py`); a block without experts sows nothing.
 ROUTING_COLLECTION = "routing"
+# the collection a block whose steps attend a SELECTION of what they cached sows into,
+# by name: ``attended``, one ``[2, batch, seq]`` int32 leaf a call: the positions each
+# query attended (all it had seen, where it attended densely) and the positions it had
+# seen, its own included, which the decode paths count for the live rows onto
+# `hivemind_moe_sparse_positions_*_total`; ``chosen``, ``[batch, seq, kv_heads, k]`` int32:
+# the blocks each query selected (-1: none), which stay on the device unless a check
+# against a reference asks for them (`routing_stats.SELECTION_TAPS`).
+ATTENDED_COLLECTION = "attended"
 
 
 class OlmoeBlockExpert(nn.Module):
@@ -546,3 +554,12 @@ register_expert_class("llama_block", lambda batch, hid: np.zeros((batch, 64, hid
 register_expert_class("olmoe_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))(OlmoeBlockExpert)
 register_expert_class("exaone_moe_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))(ExaoneMoeBlockExpert)
 register_expert_class("nop", lambda batch, hid: np.zeros((batch, hid), np.float32))(NopExpert)
+
+
+@register_expert_class("minicpm_sala_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))
+def _minicpm_sala_block(hidden_dim: int, **kwargs):
+    """`layers/minicpm_sala.py`'s block, loaded when one is built: a process that only
+    reads the registry (a trainer) imports nothing of it."""
+    from hivemind_tpu.moe.server.layers.minicpm_sala import MiniCPMSalaBlockExpert
+
+    return MiniCPMSalaBlockExpert(hidden_dim, **kwargs)

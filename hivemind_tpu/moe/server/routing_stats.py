@@ -16,15 +16,15 @@ to be read)."""
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
 import jax
 import numpy as np
 
-from hivemind_tpu.moe.server.layers.common import ROUTING_COLLECTION
+from hivemind_tpu.moe.server.layers.common import ATTENDED_COLLECTION, ROUTING_COLLECTION
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 
-__all__ = ["ROUTING_COLLECTION", "held_range", "record_routing"]
+__all__ = ["ATTENDED_COLLECTION", "ROUTING_COLLECTION", "SELECTION_TAPS", "held_range", "record_attended", "record_routing"]
 
 _PATH_HELP = "by serving path (batched / direct = decode sessions, pool = TaskPool forward and backward)"
 _LAYER_CALLS = _TELEMETRY.counter(
@@ -82,3 +82,37 @@ def record_routing(routing, path: str, span=None, rows: Optional[int] = None,
         span.set("experts_hit", hit)
         span.set("pairs", pairs)
         span.set("held_pairs", held_pairs)
+
+
+_POSITIONS_ATTENDED = _TELEMETRY.counter(
+    "hivemind_moe_sparse_positions_attended_total",
+    "positions that the queries of blocks with selected (block-sparse) attention attended in decode sessions: "
+    "one query a live row a step a sparse block (a prompt chunk: one a real position), padding excluded; a query "
+    "that attended densely (a session short of the block's dense length) counts all it had seen")
+_POSITIONS_CACHED = _TELEMETRY.counter(
+    "hivemind_moe_sparse_positions_cached_total",
+    "positions that those queries had seen, their own included: what dense attention would have read")
+
+
+# whoever wants the blocks that the served programs' queries selected (a check against a
+# reference) appends a callable here and takes it off again: it is handed, for every call
+# of a sparse block on a decode path, the live part of what the block sowed as ``chosen``,
+# ``[rows, positions, kv_heads, topk]`` int32 on the host, in the order the calls settle.
+# While the list is empty nothing of it leaves the device
+SELECTION_TAPS: List[Callable[[np.ndarray], None]] = []
+
+
+def record_attended(attended, rows: Optional[int] = None, positions: Optional[int] = None) -> None:
+    """Count what a call's block sowed into `ATTENDED_COLLECTION` as ``attended``: ``[2,
+    batch, seq]`` leaves, the positions each query attended and the positions it had seen.
+    ``rows`` / ``positions``: the live leading rows and positions (None = all). What it
+    sowed as ``chosen`` goes to the `SELECTION_TAPS`, if there are any. A block that sows
+    nothing counts nothing."""
+    for leaf in attended.get("attended", ()):
+        live = np.asarray(leaf)[:, :rows, :positions]
+        _POSITIONS_ATTENDED.inc(int(live[0].sum()))
+        _POSITIONS_CACHED.inc(int(live[1].sum()))
+    if SELECTION_TAPS:
+        for leaf in attended.get("chosen", ()):
+            for tap in SELECTION_TAPS:
+                tap(np.asarray(leaf)[:rows, :positions])

@@ -84,12 +84,61 @@ def test_batched_decode_step_of_eight_sessions_fits_the_chip(one_chip, no_compil
     manager = DecodeSessionManager({"blk.0": SimpleNamespace(module=module, dense_params=lambda p: p)}, max_len=MAX_LEN)
 
     compiled = manager._batched_fn("blk.0", rows).jitted.lower(
-        params, _shape((rows, 1, HIDDEN), jnp.float32, one_chip), (cache,) * rows, (cache,) * rows,
+        params, _shape((rows, 1, HIDDEN), jnp.float32, one_chip), ((cache,) * rows,) * 2,  # leaf by leaf, the rows' arrays
         _shape((rows,), jnp.int32, one_chip)).compile()
     assert compiled.as_text().count("ragged-dot-none") >= 3
     memory = compiled.memory_analysis()
     # arguments: 1.68 GB of weights + 8 x 33.5 MB of caches; the program's own temporaries and outputs stay under 1.5 GB
     assert memory.temp_size_in_bytes + memory.output_size_in_bytes < 1.5 * 2**30
+
+
+@pytest.mark.parametrize("mixer, rows, temporaries_gb", [("minicpm4", 32, 1.0), ("lightning-attn", 32, 0.2)])
+def test_sala_batched_step_of_32_sessions_fits_the_chip(one_chip, no_compile_cache, mixer, rows, temporaries_gb):
+    """The batched program of a MiniCPM-SALA block at the published widths and 32,768 slots,
+    a bucket of 32, as `DecodeSessionManager._batched_fn` builds it over the caches' leaves
+    (three arrays a session, handed to the sparse block row by row, `decode_rows_apart`; or
+    one state, joined): it compiles, names its scopes and gathers where it is the sparse
+    block, and its temporaries stay under what 8.88 GB of weights and 2.62 GB of sessions
+    leave of the chip (ISSUE 41: 0.43 GB and 0.07 GB when written; 2.76 GB while the sparse
+    block's caches were joined, copied and split around the step)."""
+    from types import SimpleNamespace
+
+    from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len = 4096, 32768
+    module = name_to_block["minicpm_sala_block"](hidden, mixer=mixer)
+    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, hidden), jnp.float32))["params"])
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), tree)
+    cache = on_chip(jax.eval_shape(lambda: module.init_decode_cache(1, max_len)))
+    manager = DecodeSessionManager({"blk.0": SimpleNamespace(module=module, dense_params=lambda p: p)}, max_len=max_len)
+    compiled = manager._batched_fn("blk.0", rows).jitted.lower(
+        on_chip(params), _shape((rows, 1, hidden), jnp.float32, one_chip), tuple((leaf,) * rows for leaf in cache),
+        _shape((rows,), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    if mixer == "minicpm4":
+        assert "sparse_select" in text and "sparse_attend" in text and " gather(" in text
+    else:
+        assert "lightning_step" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries_gb * 1e9
+
+
+@pytest.mark.parametrize("mixer", ["minicpm4", "lightning-attn"])
+def test_sala_prompt_chunk_of_4096_positions_fits_the_chip(one_chip, no_compile_cache, mixer):
+    """A chunk of 4,096 positions continuing a session at 32,768 slots, at the published
+    widths: the block-sparse chunk attends in blocks of queries (dense scores of the chunk
+    against a 24k cache would be 15 GB), the lightning one scans sub-chunks."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len, chunk = 4096, 32768, 4096
+    module = name_to_block["minicpm_sala_block"](hidden, mixer=mixer)
+    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, hidden), jnp.float32))["params"])
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), tree)
+    cache = on_chip(jax.eval_shape(lambda: module.init_decode_cache(1, max_len)))
+    step = jax.jit(lambda p, x, cache, index, length: module.apply({"params": p}, x, *cache, index, length), donate_argnums=(2,))
+    scalar = _shape((), jnp.int32, one_chip)
+    compiled = step.lower(on_chip(params), _shape((1, chunk, hidden), jnp.float32, one_chip), cache, scalar, scalar).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9  # 1.23 GB and 0.24 GB when written
 
 
 @pytest.mark.parametrize(
