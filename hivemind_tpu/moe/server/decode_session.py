@@ -21,8 +21,8 @@ clients' sessions that wait on the same chain form a **cohort**, which walks the
 chain's blocks in ONE executor call: at each block ONE device call for all the
 rows — the block is applied once to ``[rows, 1, hidden]`` with a VECTOR of write
 positions, and only its cache update and the attention over the cache run per row
-(`layers.common._decode_attention` vmaps itself over the rows): the block's matmuls,
-and a sparse expert layer above all, see the cohort's rows together — and each row's
+(`layers.common._decode_attention`, one call a row on that row's own cache): the block's
+matmuls, and a sparse expert layer above all, see the cohort's rows together — and each row's
 output is the next block's input, left on the device: the next block's program is
 dispatched while this one runs (at most one program ahead), and only the chain's
 last output comes to the host. No event-loop turn, no future, no flush window and
@@ -46,9 +46,18 @@ What a block class owes this path (``index`` as a scalar in a session's own call
 a vector in a batched step) is written down in `moe/server/layers/__init__.py`. The
 session count is bucketed to powers of two so the jit cache stays small. Sessions
 keep their caches one tree each, as the tuple of its leaves; a block's program takes
-them as they are, joins them leaf by leaf (or hands them unjoined to a block that says
-`decode_rows_apart`), steps, and hands the new leaves back one array a session, so a block's batch
-is ONE dispatch whatever its rows: the host only collects handles before it
+them as they are and hands the new leaves back one array a session, so a block's batch is
+ONE dispatch whatever its rows. What the program does with them in between follows from what
+the block says of its own step (`decode_rows_apart`; counted by
+`hivemind_moe_decode_batched_rows_total{caches}`), by one rule. A cache of ``max_len``
+slots, of which a step writes one and reads the rest (`causal_transformer`, `llama_block`,
+`olmoe_block`, `exaone_moe_block` with ``window`` = 0, `minicpm_sala_block`'s sparse mixer:
+tens of MB a session): APART, the block updates and reads each row's own arrays where they
+lie, and the one copy a row that is left is the new array of an argument that is not
+donated. A ring of ``window`` slots or a recurrent state (`exaone_moe_block` with a
+window, the lightning mixer: 0.5 to 2 MB a session): JOINED leaf by leaf along the batch
+axis, stepped as one array and split again, which costs less than an operation a row.
+Around the program the host only collects handles before it
 (``assemble``: the activations — the last block's output as it is, or one
 `np.concatenate` of host rows through the upload program — and one array of write
 positions) and assigns them after it (``scatter``). That is what keeps a serving
@@ -148,6 +157,15 @@ _CALLS = _TELEMETRY.counter(
     ("path",),
 )
 _CALLS_BATCHED, _CALLS_DIRECT = _CALLS.labels("batched"), _CALLS.labels("direct")
+# how often a batched step leaves the rows' caches where they lie (ISSUE 42): a block says
+# `decode_rows_apart` of its own step, the manager reads it, and this counts what came of it
+_BATCHED_ROWS = _TELEMETRY.counter(
+    "hivemind_moe_decode_batched_rows_total",
+    "live rows of batched decode programs, by what the program did with their caches (apart = each row's own "
+    "arrays updated and read where they lie, a block that says decode_rows_apart; joined = the rows' caches "
+    "joined along the batch axis before the step and split after it)",
+    ("caches",),
+)
 # what the session table pins on the device, by the kind of cache a block keeps
 # (`decode_cache_kind` on the block class: a sliding-window block's ring is "window";
 # a block that does not say keeps every position, "full"); kept by addition as
@@ -210,8 +228,8 @@ def _next_pow2(n: int) -> int:
 def _cohort_rows(waiting: int) -> int:
     """How many of ``waiting`` rows the next cohort takes. A batched program costs
     by its bucket, the power of two its rows are padded to, almost as if every row
-    were live (each padding row has its caches joined, written and attended over
-    like a live one): 17 rows cost what 32 do, near twice what 16 do. So a cohort
+    were live (each padding row's caches are copied, written and attended over
+    like a live one's): 17 rows cost what 32 do, near twice what 16 do. So a cohort
     that would pad more than a quarter of its bucket takes the full bucket below
     instead; the rows left over are the first of the next cohort."""
     bucket = _next_pow2(waiting)
@@ -360,6 +378,12 @@ class DecodeSessionManager:
 
     def _cache_kind(self, uid: str) -> str:
         return getattr(self.backends[uid].module, "decode_cache_kind", "full")
+
+    def _rows_caches(self, uid: str) -> str:
+        """What a batched program of this block does with its rows' caches: ``apart`` (the
+        block says `decode_rows_apart`: it is handed each leaf as the tuple of the rows' own
+        arrays) or ``joined`` (`_batched_fn`)."""
+        return "apart" if getattr(self.backends[uid].module, "decode_rows_apart", False) else "joined"
 
     def _count_cache_locked(self, uid: str, session: _Session, entries: int) -> None:
         """A session enters (+1) or leaves (-1) the table at ``uid``: its bytes and
@@ -870,7 +894,7 @@ class DecodeSessionManager:
         fn = self._batched_fns.get(key)
         if fn is None:
             step = self._raw_step(uid)
-            apart = getattr(self.backends[uid].module, "decode_rows_apart", False)
+            apart = self._rows_caches(uid) == "apart"
 
             def batched_step(params, xs, columns, indices):
                 leaves = columns if apart else tuple(jnp.concatenate(rows) for rows in columns)
@@ -950,10 +974,11 @@ class DecodeSessionManager:
                 results[i] = np.asarray(y)[:, :1]
                 record_transfer(results[i].nbytes, "device_to_host")
                 return results
-            stack = _next_pow2(len(live))
+            stack, caches = _next_pow2(len(live)), self._rows_caches(uid)
             if span is not None:
                 span.set("bucket", stack)
                 span.set("cache", self._cache_kind(uid))
+                span.set("caches", caches)
             _CALLS_BATCHED.inc()
             with _batch_phase("assemble"):
                 # handles only: the rows' caches go in as they are, the write
@@ -975,6 +1000,7 @@ class DecodeSessionManager:
                     output.host()
                     output.settle(span)
             _STEPS.inc(len(live), path="batched")
+            _BATCHED_ROWS.inc(len(live), caches=caches)
             with _batch_phase("scatter"):
                 now = time.monotonic()
                 for row, (i, session, leaves) in enumerate(zip(live, sessions, zip(*new))):  # row by row, its new leaves

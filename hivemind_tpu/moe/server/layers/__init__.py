@@ -10,7 +10,8 @@ arrays, batch axis first, as the block chooses: ``(cache_k, cache_v)`` (the four
 of `common.py`), one recurrent state that a step UPDATES, or keys, values and compressed
 keys that a step appends to (`minicpm_sala_block`, both). The manager keeps a session's
 tree as the tuple of its leaves and never looks inside one: it joins the leaves of a
-batch's rows along the batch axis, splits the new leaves back one a row, donates a
+batch's rows along the batch axis and splits the new leaves back one a row (or hands a
+block that says ``decode_rows_apart`` the rows' arrays as they are), donates a
 per-session call's leaves, places and counts them leaf by leaf (`shard_decode_cache` of
 a mesh backend takes a pair). The block is handed the leaves in the tree's order and
 hands new ones back in the same order and shapes. ``index`` is the write position, in
@@ -19,14 +20,17 @@ one of two ranks:
 - a scalar: ONE session's prefill, prompt chunk or step, ``x`` ``[batch, new_len,
   hidden]``, every row at the same position;
 - a vector ``[rows]``: a batched step of ``rows`` different sessions, ``x``
-  ``[rows, 1, hidden]``, the caches joined along the batch axis, each row at its OWN
-  position. The block is applied once to all the rows (it is not vmapped from
+  ``[rows, 1, hidden]``, the caches joined along the batch axis (or, for a block that
+  says ``decode_rows_apart``, each leaf the tuple of the rows' arrays), each row at
+  its OWN position. The block is applied once to all the rows (it is not vmapped from
   outside since PR 27, so that a sparse expert layer sees the rows together), and
-  whatever it does with ``index`` must hold for a vector: hand it to
-  `common._decode_attention` / `common.apply_rope`, which take both ranks, or
-  ``jax.vmap`` the block's own per-row cache code when ``jnp.ndim(index) == 1``.
-  A ``dynamic_update_slice`` or a position lookup written for a scalar fails or
-  broadcasts wrongly there, and only in batched steps
+  whatever it does with ``index`` must hold for a vector: `common.apply_rope` takes
+  both ranks; a block that keeps its caches joined ``jax.vmap``s its own per-row cache
+  code when ``jnp.ndim(index) == 1`` (`common._grouped_cache_step`'s ring); one that
+  takes them apart steps row by row (`common._decode_attention` and
+  `common._grouped_cache_step` given tuples: one inner ``jax.jit`` of the one-row step,
+  called once a row). A ``dynamic_update_slice`` or a position lookup written for a
+  scalar fails or broadcasts wrongly there, and only in batched steps
   (`tests/test_moe.py::test_custom_cached_block_steps_batched` is the pattern).
 
 The blocks of one chain need not agree on the tree (`exaone_moe_block`: ``[batch,
@@ -47,13 +51,20 @@ sessions stay as they were. Optional class attributes:
   ended; the chunk is padded as a prefill is, but never past the cache's end). A chain
   takes such a chunk only if every block of it says so; any other block's first chunk
   is its whole prompt, and a later one raises the ``ValueError`` it always raised;
-- ``decode_rows_apart = True``: in a batched step each leaf comes as the TUPLE of the
-  rows' own arrays (``[1, ...]`` each, ``rows`` of them) where it would come joined, and
-  goes back as such a tuple. For a block whose step touches a small part of a large
-  cache (a position written, some blocks gathered): it writes into and reads from each
-  row's own array, and nothing joins, copies and splits the whole caches around the
-  step (`minicpm_sala_block`'s sparse mixer: a third of the program's time and 2.3 GB of
-  its temporaries at 32 rows of 32,768 slots). A session's own call is handed arrays;
+- ``decode_rows_apart = True`` (an attribute or a property of the module): in a batched
+  step each leaf comes as the TUPLE of the rows' own arrays (``[1, ...]`` each, ``rows``
+  of them) where it would come joined, and goes back as such a tuple. The block states a
+  fact about its own step, the manager reads it, and nothing else chooses. The rule: a
+  cache of ``max_len`` slots, of which a step writes a few KB and reads the rest where it
+  lies, goes APART: `causal_transformer`, `llama_block`, `olmoe_block`, `exaone_moe_block`
+  with ``window`` = 0 (ISSUE 42: the join, the copy and the split of 16 x 33.5 MB around
+  every step were a quarter of OLMoE's device time) and `minicpm_sala_block`'s sparse
+  mixer (ISSUE 41: a third of its program's time and 2.3 GB of its temporaries at 32 rows
+  of 32,768 slots). A ring of ``window`` slots (0.5 MB a session) or a recurrent state
+  (2.1 MB) stays JOINED: the join costs less than an operation a row. What stays of the
+  caches' traffic apart is one copy a row a step: the new array of an argument that is
+  not donated (below: a failed batched step leaves every session as it was). A session's
+  own call is handed arrays;
 - ``decode_cache_kind`` (a short string) names the block's decode programs
   (`jit_batched_step_<kind>`, `jit_prefill_<kind>_<positions>`) and its caches in the
   telemetry (`hivemind_moe_decode_cache_bytes{kind}`).

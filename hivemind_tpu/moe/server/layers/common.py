@@ -65,6 +65,20 @@ class TransformerExpert(nn.Module):
         return nn.LayerNorm(dtype=jnp.bfloat16)(x + h).astype(jnp.float32)
 
 
+def _each_row_apart(row_step, q, k_new, v_new, cache_k, cache_v, index):
+    """A batched step of a block that says `decode_rows_apart`, at its cache step:
+    ``cache_k`` / ``cache_v`` are the tuples of the rows' own arrays (``[1, ...]`` each),
+    ``q``, ``k_new``, ``v_new`` hold a row a session and ``index`` ``[rows]`` their write
+    positions. ``row_step(q, k_new, v_new, cache_k, cache_v, index=)`` is called once a
+    row on that row's slices (``index`` ``[1]``) and its own caches as they lie; the rows'
+    contexts are stacked (a few KB a row) and the caches go back as tuples, so that no
+    cache is joined or split around the step (what is left is the row's new array)."""
+    steps = [row_step(q[row:row + 1], k_new[row:row + 1], v_new[row:row + 1], cache_k[row], cache_v[row], index=index[row:row + 1])
+             for row in range(len(cache_k))]
+    contexts, cache_k, cache_v = zip(*steps)
+    return jnp.concatenate(contexts), cache_k, cache_v
+
+
 def _decode_attention(q, k_new, v_new, cache_k, cache_v, index, groups: int = 1):
     """Shared KV-cache attention step for decoder blocks.
 
@@ -74,22 +88,19 @@ def _decode_attention(q, k_new, v_new, cache_k, cache_v, index, groups: int = 1)
     causal within the chunk) and incremental (chunk length 1, attends everything
     ≤ index). ``groups`` > 1 repeats the (grouped-query) KV heads to match q at
     attention time — caches stay in the compact kv_heads layout.
-    ``index`` may be a vector, one write position a row: the rows are then
-    different sessions of one batched step, and this function is the boundary
-    between what a row does alone (write its cache at its own position, attend
-    over its own cache) and what the batch's rows do together (everything around
-    it in the block: projections, norms, the MLP or the expert layer).
+    In a batched step (`decode_rows_apart`) the rows are different sessions:
+    ``cache_k`` / ``cache_v`` are the TUPLES of the rows' own arrays (``[1, ...]``
+    each) and ``index`` the vector of their write positions. This function is then
+    the boundary between what a row does alone (write its own cache at its own
+    position, attend over that cache as it lies: `_decode_attention_row`, once a
+    row, `_each_row_apart`) and what the batch's rows do together (everything
+    around it in the block: projections, norms, the MLP or the expert layer).
     Returns (context, cache_k, cache_v)."""
+    if isinstance(cache_k, (tuple, list)):
+        return _each_row_apart(lambda *row, index: _decode_attention_row(*row, index[0], groups=groups),
+                               q, k_new, v_new, cache_k, cache_v, index)
     from hivemind_tpu.parallel.ring_attention import plain_attention
 
-    if jnp.ndim(index) == 1:
-        def one_row(q, k_new, v_new, cache_k, cache_v, index):
-            context, cache_k, cache_v = _decode_attention(
-                q[None], k_new[None], v_new[None], cache_k[None], cache_v[None], index, groups
-            )
-            return context[0], cache_k[0], cache_v[0]
-
-        return jax.vmap(one_row)(q, k_new, v_new, cache_k, cache_v, index)
     batch, new_len = q.shape[0], q.shape[1]
     max_len = cache_k.shape[1]
     cache_k = jax.lax.dynamic_update_slice(cache_k, k_new.astype(cache_k.dtype), (0, index, 0, 0))
@@ -108,6 +119,12 @@ def _decode_attention(q, k_new, v_new, cache_k, cache_v, index, groups: int = 1)
     return context, cache_k, cache_v
 
 
+# one row of a batched step: the scalar form above, traced ONCE for all the rows, buckets
+# and blocks of one shape (a bucket of 32 holds 32 calls of one function, not 32 copies of
+# its text: the programs are jitted per uid and bucket, and set-up pays their tracing)
+_decode_attention_row = jax.jit(_decode_attention, static_argnames=("groups",))
+
+
 class CausalTransformerExpert(nn.Module):
     """One pre-norm DECODER block on [batch, seq, hid]: causal attention + gelu ffn.
     The building block for pipelined autoregressive models over the swarm
@@ -121,6 +138,10 @@ class CausalTransformerExpert(nn.Module):
 
     hidden_dim: int
     num_heads: int = 8
+
+    # a batched step writes one position of a cache of ``max_len`` slots and reads the rest
+    # where it lies: the rows' caches come as tuples, unjoined (`_decode_attention`)
+    decode_rows_apart = True
 
     def init_decode_cache(self, batch: int, max_len: int):
         head_dim = self.hidden_dim // self.num_heads
@@ -244,6 +265,8 @@ class LlamaBlockExpert(nn.Module):
     # the fused attention kernel must then run per shard (mesh_attention_core)
     mesh: Optional[Any] = None
 
+    decode_rows_apart = True  # caches of ``max_len`` slots: a batched step takes the rows' own arrays (`_decode_attention`)
+
     def init_decode_cache(self, batch: int, max_len: int):
         return _empty_kv_cache(batch, max_len, self.num_kv_heads or self.num_heads, self.hidden_dim // self.num_heads)
 
@@ -304,6 +327,8 @@ class OlmoeBlockExpert(nn.Module):
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
     head_dim: int = 0  # 0 = hidden_dim // num_heads; anything else must equal it
+
+    decode_rows_apart = True  # as `LlamaBlockExpert`
 
     def init_decode_cache(self, batch: int, max_len: int):
         return _empty_kv_cache(batch, max_len, self.num_kv_heads or self.num_heads, self.hidden_dim // self.num_heads)
@@ -373,15 +398,22 @@ def _grouped_cache_step(q, k_new, v_new, cache_k, cache_v, index):
     index``, so ONE validity rule serves both: slot j is live iff ``j <= index``
     (a ring that has wrapped is live everywhere). ``q`` ``[rows, 1, heads, dim]``,
     ``k_new``, ``v_new`` ``[rows, 1, kv_heads, dim]``, ``index`` ``[rows]``.
-    Returns (context ``[rows, 1, heads * dim]``, cache_k, cache_v)."""
+    ``cache_k`` / ``cache_v`` as the TUPLES of the rows' own arrays (``[1, ...]`` each:
+    a batched step of a block that says `decode_rows_apart`) are stepped row by row
+    where they lie (`_each_row_apart` of `_grouped_cache_step_row`, traced once for all
+    of them) and go back as tuples. Returns (context ``[rows, 1, heads * dim]``, cache_k, cache_v)."""
+    if isinstance(cache_k, (tuple, list)):
+        return _each_row_apart(_grouped_cache_step_row, q, k_new, v_new, cache_k, cache_v, index)
     rows, _, heads, dim = q.shape
     kv_heads, slots = cache_k.shape[1], cache_k.shape[2]
 
-    def write(cache, new, slot):  # one row: [kv_heads, slots, dim] <- [1, kv_heads, dim]
-        return jax.lax.dynamic_update_slice(cache, jnp.swapaxes(new, 0, 1).astype(cache.dtype), (0, slot, 0))
+    def write(cache, new):  # [rows, kv_heads, slots, dim] <- [rows, 1, kv_heads, dim], row r at slot index[r] mod slots
+        new, slot = jnp.swapaxes(new, 1, 2).astype(cache.dtype), index % slots
+        if rows == 1:  # nothing to map over: a plain update at one slot, where the vmap below makes a scatter
+            return jax.lax.dynamic_update_slice(cache, new, (0, 0, slot[0], 0))
+        return jax.vmap(lambda cache, new, slot: jax.lax.dynamic_update_slice(cache, new, (0, slot, 0)))(cache, new, slot)
 
-    cache_k = jax.vmap(write)(cache_k, k_new, index % slots)
-    cache_v = jax.vmap(write)(cache_v, v_new, index % slots)
+    cache_k, cache_v = write(cache_k, k_new), write(cache_v, v_new)
     grouped = q.reshape(rows, kv_heads, heads // kv_heads, dim).astype(cache_k.dtype)
     scores = jnp.einsum("rkgd,rksd->rkgs", grouped, cache_k, preferred_element_type=jnp.float32) * dim**-0.5
     live = jnp.arange(slots)[None, :] <= index[:, None]
@@ -389,6 +421,9 @@ def _grouped_cache_step(q, k_new, v_new, cache_k, cache_v, index):
     probs = jax.nn.softmax(scores, axis=-1).astype(cache_v.dtype)
     context = jnp.einsum("rkgs,rksd->rkgd", probs, cache_v)
     return context.reshape(rows, 1, heads * dim), cache_k, cache_v
+
+
+_grouped_cache_step_row = jax.jit(_grouped_cache_step)  # one row of a batched step, as `_decode_attention_row`
 
 
 def _prefill_into_cache(cache, new, length):
@@ -463,6 +498,14 @@ class ExaoneMoeBlockExpert(nn.Module):
     def decode_cache_kind(self) -> str:
         """Names the block's decode programs and its caches in the telemetry."""
         return "window" if self.window else "full"
+
+    @property
+    def decode_rows_apart(self) -> bool:
+        """Whether a batched step takes each cache as the tuple of the rows' own arrays: a
+        full-attention block's ``max_len`` slots (33.5 MB a session at 4,096), of which a
+        step writes one and reads the rest where it lies. A ring of ``window`` slots
+        (0.5 MB) stays joined: the join costs less than an operation a row."""
+        return not self.window
 
     @property
     def held_experts(self):

@@ -44,6 +44,7 @@ KINDS = {  # name -> (the block's own sizes, what the reference is told of the k
     "dense/window": (dict(window=WINDOW, ffn_inner=DENSE), dict(window=WINDOW, rope=True)),
     "sparse/window": (dict(window=WINDOW), dict(window=WINDOW, rope=True)),
     "sparse/full": (dict(window=0), dict(window=0, rope=False)),
+    "dense/full": (dict(window=0, ffn_inner=DENSE), dict(window=0, rope=False)),  # in no span: the kinds are independent
 }
 SPAN = ["dense/window", "sparse/window", "sparse/window", "sparse/full", "sparse/window"]  # blocks 0-4: L L L G L
 COMMON = dict(num_heads=HEADS, num_kv_heads=KV, head_dim=DIM, num_experts=EXPERTS, experts_per_token=TOP_K,
@@ -146,8 +147,13 @@ def test_prefill_and_single_token_steps_against_full_forward(kind, prompt):
 def test_batched_step_with_rows_at_different_positions(kind):
     """7 sessions at different positions (some rings wrapped, some not yet full) in a
     bucket of 8, twenty batched steps: each row against the reference's full forward
-    over that row's own stream; the padding row is part of the program, not of the counts."""
+    over that row's own stream; the padding row is part of the program, not of the counts.
+    A full-attention block steps on the rows' own caches (`decode_rows_apart`: 64 slots
+    here, 33.5 MB a session in the cell), a window block on its rings joined."""
     from hivemind_tpu.telemetry.tracing import RECORDER
+
+    rows_counted = lambda caches: REGISTRY.get("hivemind_moe_decode_batched_rows_total").labels(caches).value
+    caches, other = ("joined", "apart") if "window" in kind else ("apart", "joined")
 
     backend = make_backend(kind)
     manager = DecodeSessionManager({backend.name: backend}, max_len=64)
@@ -157,7 +163,7 @@ def test_batched_step_with_rows_at_different_positions(kind):
         manager.decode(backend.name, f"row{row}", x[row:row + 1, :length], reset=True)
     sessions = [manager._sessions[(backend.name, f"row{row}")] for row in range(len(lengths))]
     want = np.asarray(want_of([backend], [kind], x)[0])
-    before = counters("batched")
+    before, rows_before = counters("batched"), (rows_counted(caches), rows_counted(other))
     got = [[] for _ in lengths]
     for step in range(20):
         entries = [(None, session, x[row:row + 1, length + step:length + step + 1])
@@ -174,6 +180,9 @@ def test_batched_step_with_rows_at_different_positions(kind):
     assert [key for key in manager._batched_fns] == [(backend.name, 8)]
     [span] = [s for s in RECORDER.snapshot() if s.name == "decode.batch" and (s.attributes or {}).get("uid") == backend.name][-1:]
     assert span.attributes["cache"] == kind.split("/")[1] and span.attributes["rows"] == 7
+    assert backend.module.decode_rows_apart == (caches == "apart") and span.attributes["caches"] == caches
+    assert (rows_counted(caches), rows_counted(other)) == (rows_before[0] + 20 * 7, rows_before[1]), "live rows, by the block's own word"
+    assert all(leaf.shape == (1, KV, WINDOW if "window" in kind else 64, DIM) for session in sessions for leaf in session.leaves)
     if sparse:
         assert 0 < counted["held_pairs"] < counted["routed_pairs"]
         assert span.attributes["pairs"] == 7 * TOP_K and 0 <= span.attributes["held_pairs"] <= span.attributes["pairs"]

@@ -188,12 +188,31 @@ def test_selection_counts_and_forced_blocks():
     assert rel_err(compressed[:, 7], cache_k[:, 14:18].astype(jnp.float32).mean(1)) <= 1e-2
 
 
-@pytest.mark.parametrize("kind", ["sparse", "lightning"])
+OLDER_BLOCKS = {
+    "causal_transformer": dict(num_heads=4),
+    "llama_block": dict(num_heads=4, num_kv_heads=2),
+    "olmoe_block": dict(num_heads=4, num_experts=4, experts_per_token=2, expert_inner=32),
+    "exaone_moe_block": dict(num_heads=4, num_kv_heads=2, head_dim=16, window=8, ffn_inner=64),
+}
+
+
+def older_backend(name: str, uid: str, **overrides) -> ModuleBackend:
+    return ModuleBackend(uid, name_to_block[name](HID, **{**OLDER_BLOCKS[name], **overrides}), optimizer=optax.sgd(0.0),
+                         sample_input=name_to_input[name](4, HID), max_batch_size=8, rng_seed=1)
+
+
+@pytest.mark.parametrize("kind", ["sparse", "lightning", *sorted(OLDER_BLOCKS), "exaone_moe_block/full"])
 def test_a_failed_step_leaves_no_half_updated_state(kind, monkeypatch):
     """A per-session step DONATES the cache tree: one that fails drops the session (the
     next continuation gets the unknown-session KeyError and re-prefills). A batched step
-    does not donate: one that fails leaves every session's tree and position as they were."""
-    backend = make_backend(kind)
+    does not donate, whether it joins the rows' caches or steps on them where they lie
+    (`decode_rows_apart`: the sparse block, and since ISSUE 42 the four blocks that keep
+    ``max_len`` slots): one that fails leaves every session's tree and position as they were."""
+    if kind in MIXERS:
+        backend = make_backend(kind)
+    else:
+        backend = older_backend(kind.split("/")[0], "older.0", **(dict(window=0) if kind.endswith("/full") else {}))
+    assert backend.module.decode_rows_apart == (kind not in ("lightning", "exaone_moe_block"))
     manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
     x = stream(7, 2, 80)
     for row in range(2):
@@ -221,19 +240,6 @@ def test_a_failed_step_leaves_no_half_updated_state(kind, monkeypatch):
     monkeypatch.delitem(manager._step_fns, (backend.name, 1, 1))
     with pytest.raises(KeyError):
         manager.decode(backend.name, "row0", x[:1, 71:72], reset=False)
-
-
-OLDER_BLOCKS = {
-    "causal_transformer": dict(num_heads=4),
-    "llama_block": dict(num_heads=4, num_kv_heads=2),
-    "olmoe_block": dict(num_heads=4, num_experts=4, experts_per_token=2, expert_inner=32),
-    "exaone_moe_block": dict(num_heads=4, num_kv_heads=2, head_dim=16, window=8, ffn_inner=64),
-}
-
-
-def older_backend(name: str, uid: str) -> ModuleBackend:
-    return ModuleBackend(uid, name_to_block[name](HID, **OLDER_BLOCKS[name]), optimizer=optax.sgd(0.0),
-                         sample_input=name_to_input[name](4, HID), max_batch_size=8, rng_seed=1)
 
 
 @pytest.mark.parametrize("name", sorted(OLDER_BLOCKS))

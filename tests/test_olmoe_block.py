@@ -167,19 +167,27 @@ def _prefilled_rows(manager, uid, x, lengths):
     return [manager._sessions[(uid, f"row{row}")] for row in range(len(lengths))]
 
 
-def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward():
+def _rows_by_caches():
+    rows = REGISTRY.get("hivemind_moe_decode_batched_rows_total")
+    return rows.labels("apart").value, rows.labels("joined").value
+
+
+@pytest.mark.parametrize("kv_heads", [HEADS, 2])  # as many key-value heads as heads (OLMoE-1B-7B), and grouped
+def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward(kv_heads):
     """7 sessions at different positions in a bucket of 8, two batched steps: each
     row against the reference's full forward over that row's own stream; the
-    padding row is part of the program, not of the counts."""
+    padding row is part of the program, not of the counts. The rows' caches are
+    stepped where they lie (`decode_rows_apart`): counted as such, and each session
+    keeps arrays of its own."""
     from hivemind_tpu.telemetry.tracing import RECORDER
 
-    backend = make_backend()
+    backend = make_backend(num_kv_heads=kv_heads)
     manager = DecodeSessionManager({backend.name: backend}, max_len=32)
     lengths = [3, 5, 8, 4, 11, 6, 9]
     x = stream(5, len(lengths), 16)
     sessions = _prefilled_rows(manager, backend.name, x, lengths)
-    want = np.asarray(reference.span([backend.params], jnp.asarray(x), **SIZES))
-    before = counters("batched")
+    want = np.asarray(reference.span([backend.params], jnp.asarray(x), **{**SIZES, "num_kv_heads": kv_heads}))
+    before, rows_before = counters("batched"), _rows_by_caches()
     for step in range(2):
         entries = [(None, session, x[row:row + 1, length + step:length + step + 1])
                    for row, (session, length) in enumerate(zip(sessions, lengths))]
@@ -193,17 +201,22 @@ def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward():
     assert counted["expert_layer_calls"] == 2
     assert counted["routed_pairs"] == 2 * 7 * TOP_K, "pairs are live rows x top-k: the padding row is not counted"
     assert TOP_K <= counted["experts_hit"] <= 2 * min(EXPERTS, 7 * TOP_K)
-    assert all(session.index == length + 2 and session.cache_k.shape[0] == 1 for session, length in zip(sessions, lengths))
+    assert all(session.index == length + 2 and session.cache_k.shape == (1, 32, kv_heads, HID // HEADS)
+               for session, length in zip(sessions, lengths))
+    assert len({id(leaf) for session in sessions for leaf in session.leaves}) == 2 * 7, "the padding row's arrays came back as a session's"
+    assert _rows_by_caches() == (rows_before[0] + 2 * 7, rows_before[1]), "live rows of programs that left the caches apart"
     [key] = [k for k in manager._batched_fns]
     assert key == (backend.name, 8), "the batch's program is keyed by (uid, bucket) alone"
     spans = [s for s in RECORDER.snapshot() if s.name == "decode.batch" and (s.attributes or {}).get("uid") == backend.name]
     assert spans and spans[-1].attributes["pairs"] == 7 * TOP_K and 1 <= spans[-1].attributes["experts_hit"] <= EXPERTS
+    assert spans[-1].attributes["caches"] == "apart" and spans[-1].attributes["bucket"] == 8
 
 
-def test_batched_step_equals_the_direct_step():
+@pytest.mark.parametrize("kv_heads", [HEADS, 2])
+def test_batched_step_equals_the_direct_step(kv_heads):
     """The same tokens through the batched program and through the per-session
     program: one block code, so the outputs agree to rounding."""
-    backend = make_backend()
+    backend = make_backend(num_kv_heads=kv_heads)
     manager = DecodeSessionManager({backend.name: backend}, max_len=32)
     lengths = [4, 7, 5]
     x = stream(6, 3, 12)

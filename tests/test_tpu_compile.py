@@ -68,28 +68,62 @@ def test_expert_layer_compiles_to_the_native_grouped_matmul(one_chip, no_compile
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * tokens * TOP_K * HIDDEN * 4 * 8 + 64 * 2**20
 
 
-def test_batched_decode_step_of_eight_sessions_fits_the_chip(one_chip, no_compile_cache):
-    """Lowers the program `DecodeSessionManager._batched_fn` itself builds for a
-    bucket of 8 (no copy of its body here), over a backend that holds shapes only."""
+def _compiled_batched_step(module, hidden: int, max_len: int, rows: int, one_chip):
+    """The program `DecodeSessionManager._batched_fn` itself builds for a bucket of ``rows``
+    (no copy of its body here), over a backend that holds shapes only, compiled for the chip;
+    beside it the shapes of one session's cache leaves."""
     from types import SimpleNamespace
 
     from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
+
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), tree)
+    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, hidden), jnp.float32))["params"])
+    cache = on_chip(jax.eval_shape(lambda: module.init_decode_cache(1, max_len)))
+    manager = DecodeSessionManager({"blk.0": SimpleNamespace(module=module, dense_params=lambda p: p)}, max_len=max_len)
+    compiled = manager._batched_fn("blk.0", rows).jitted.lower(
+        on_chip(params), _shape((rows, 1, hidden), jnp.float32, one_chip), tuple((leaf,) * rows for leaf in cache),  # leaf by leaf, the rows' arrays
+        _shape((rows,), jnp.int32, one_chip)).compile()
+    return compiled, [leaf.shape for leaf in cache]
+
+
+def _joined(text: str, rows: int, leaf_shape) -> int:
+    """How often an array of the rows' caches JOINED along the batch axis appears in a compiled program."""
+    return text.count("bf16[" + ",".join(map(str, (rows,) + tuple(leaf_shape[1:]))) + "]")
+
+
+def test_batched_decode_step_of_eight_sessions_fits_the_chip(one_chip, no_compile_cache):
+    """OLMoE's block at a bucket of 8 with 4,096-slot caches. Since ISSUE 42 the program steps on
+    the rows' own caches (`decode_rows_apart`): what it needs beside its arguments is its outputs,
+    8 x 2 x 16.8 MB = 256 MiB (the one copy a row that no donation leaves), and 2.8 MiB of
+    temporaries; while it joined the caches it held 386.5 MiB of temporaries beside them."""
     from hivemind_tpu.moe.server.layers import name_to_block
 
     module = name_to_block["olmoe_block"](HIDDEN, num_heads=HEADS, num_experts=EXPERTS, experts_per_token=TOP_K, expert_inner=INNER)
-    rows = 8
-    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, HIDDEN), jnp.float32))["params"])
-    params = jax.tree_util.tree_map(lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), params)
-    cache = _shape((1, MAX_LEN, HEADS, HIDDEN // HEADS), jnp.bfloat16, one_chip)
-    manager = DecodeSessionManager({"blk.0": SimpleNamespace(module=module, dense_params=lambda p: p)}, max_len=MAX_LEN)
-
-    compiled = manager._batched_fn("blk.0", rows).jitted.lower(
-        params, _shape((rows, 1, HIDDEN), jnp.float32, one_chip), ((cache,) * rows,) * 2,  # leaf by leaf, the rows' arrays
-        _shape((rows,), jnp.int32, one_chip)).compile()
-    assert compiled.as_text().count("ragged-dot-none") >= 3
+    compiled, leaves = _compiled_batched_step(module, HIDDEN, MAX_LEN, 8, one_chip)
+    text = compiled.as_text()
+    assert text.count("ragged-dot-none") >= 3
+    assert leaves == [(1, MAX_LEN, HEADS, HIDDEN // HEADS)] * 2
+    assert _joined(text, 8, leaves[0]) == 0, "an array of the joined caches' shape: the rows' caches are not stepped where they lie"
     memory = compiled.memory_analysis()
-    # arguments: 1.68 GB of weights + 8 x 33.5 MB of caches; the program's own temporaries and outputs stay under 1.5 GB
-    assert memory.temp_size_in_bytes + memory.output_size_in_bytes < 1.5 * 2**30
+    # arguments: 1.68 GB of weights + 8 x 33.5 MB of caches
+    assert memory.output_size_in_bytes < 257 * 2**20 and memory.temp_size_in_bytes < 16 * 2**20
+
+
+def test_batched_full_attention_step_of_sixteen_sessions_fits_the_chip(one_chip, no_compile_cache):
+    """K-EXAONE's full-attention block (sparse layer, 8 of 128 experts held) at a bucket of 16
+    with 8,192-slot caches, 33.5 MB a session: no array of the joined caches' shape, outputs
+    16 x 33.5 MB = 512 MiB and 100.9 MiB of temporaries (963.2 MiB while the caches were joined).
+    A window block's rings (0.5 MB a session) stay joined."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len, rows = 6144, 8192, 16
+    sizes = dict(num_heads=64, num_kv_heads=8, head_dim=128, num_experts=128, experts_per_token=8, expert_inner=2048, held=8)
+    compiled, leaves = _compiled_batched_step(name_to_block["exaone_moe_block"](hidden, window=0, **sizes), hidden, max_len, rows, one_chip)
+    assert leaves == [(1, 8, max_len, 128)] * 2 and _joined(compiled.as_text(), rows, leaves[0]) == 0
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes < 513 * 2**20 and memory.temp_size_in_bytes < 128 * 2**20
+    compiled, leaves = _compiled_batched_step(name_to_block["exaone_moe_block"](hidden, window=128, **sizes), hidden, max_len, rows, one_chip)
+    assert leaves == [(1, 8, 128, 128)] * 2 and _joined(compiled.as_text(), rows, leaves[0]) > 0
 
 
 @pytest.mark.parametrize("mixer, rows, temporaries_gb", [("minicpm4", 32, 1.0), ("lightning-attn", 32, 0.2)])
@@ -101,20 +135,10 @@ def test_sala_batched_step_of_32_sessions_fits_the_chip(one_chip, no_compile_cac
     block, and its temporaries stay under what 8.88 GB of weights and 2.62 GB of sessions
     leave of the chip (ISSUE 41: 0.43 GB and 0.07 GB when written; 2.76 GB while the sparse
     block's caches were joined, copied and split around the step)."""
-    from types import SimpleNamespace
-
-    from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
     from hivemind_tpu.moe.server.layers import name_to_block
 
     hidden, max_len = 4096, 32768
-    module = name_to_block["minicpm_sala_block"](hidden, mixer=mixer)
-    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, hidden), jnp.float32))["params"])
-    on_chip = lambda tree: jax.tree_util.tree_map(lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), tree)
-    cache = on_chip(jax.eval_shape(lambda: module.init_decode_cache(1, max_len)))
-    manager = DecodeSessionManager({"blk.0": SimpleNamespace(module=module, dense_params=lambda p: p)}, max_len=max_len)
-    compiled = manager._batched_fn("blk.0", rows).jitted.lower(
-        on_chip(params), _shape((rows, 1, hidden), jnp.float32, one_chip), tuple((leaf,) * rows for leaf in cache),
-        _shape((rows,), jnp.int32, one_chip)).compile()
+    compiled, _leaves = _compiled_batched_step(name_to_block["minicpm_sala_block"](hidden, mixer=mixer), hidden, max_len, rows, one_chip)
     text = compiled.as_text()
     if mixer == "minicpm4":
         assert "sparse_select" in text and "sparse_attend" in text and " gather(" in text
