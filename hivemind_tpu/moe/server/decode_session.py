@@ -34,7 +34,8 @@ program runs on the output of the block before, and the last output is fetched.
 
 Steps that arrive while a cohort is launched wait for the next one, which starts
 when this one's last block is dispatched, with all of them up to a full bucket
-(`_cohort_rows`: a program costs by the power of two its rows are padded to). This
+(`_cohort_rows`: a program costs by the power of two its rows are padded to; with
+more than 16 rows under way on the chain, up to the bucket that holds half). This
 cohort's output is awaited and answered beside the next one's launch, so the device
 finds the next cohort's first program queued behind this one's last. The cohort
 under way is the window; `FLUSH_WINDOW_S` is what a chain that was idle waits.
@@ -175,7 +176,8 @@ _CACHE_BYTES = _TELEMETRY.gauge(
     "hivemind_moe_decode_cache_bytes",
     "bytes of decode caches that the session table holds, by kind of cache as the block names it (window = a ring "
     "of a sliding-window block's last positions, full = every position of the session, sparse = keys, values and "
-    "compressed keys of a block-sparse attention block, lightning = a linear-attention block's recurrent state)",
+    "compressed keys of a block-sparse attention block, lightning = a linear-attention block's recurrent state, latent = the "
+    "normed latents and the shared rotated key of a latent-attention block)",
     ("kind",),
 )
 _CACHE_ENTRIES = _TELEMETRY.gauge(
@@ -198,6 +200,15 @@ _PREFILL_CHUNKS = _TELEMETRY.counter(
     "hivemind_moe_decode_prefill_chunks_total",
     "chunks of more than one position that CONTINUED a session (a prompt that arrives in chunks: every "
     "chunk after its first), one count a chunk whatever the blocks it crosses",
+)
+# a latent cache is read where it lies, all of it up to the write position: the work of a step grows with
+# the context, which the steps' count alone does not say (ISSUE 43); counted here, from the rows' indices
+_LATENT_POSITIONS = _TELEMETRY.counter(
+    "hivemind_moe_latent_positions_attended_total",
+    "positions that the steps of blocks with a latent cache (decode_cache_kind latent: multi-head latent attention) "
+    "attended: a live row a step a block, its write position + 1, padding rows excluded; by the step's path (batched "
+    "= a row of a cohort's program, direct = a session's own step); prompt chunks are not counted",
+    ("path",),
 )
 _COHORTS = _TELEMETRY.counter(
     "hivemind_moe_decode_cohorts_total",
@@ -225,15 +236,32 @@ def _next_pow2(n: int) -> int:
     return power
 
 
-def _cohort_rows(waiting: int) -> int:
+# with more rows than this under way on a chain, a cohort leaves half of them to the next one
+HALVED_ABOVE = 16
+
+
+def _cohort_rows(waiting: int, active: int = 0) -> int:
     """How many of ``waiting`` rows the next cohort takes. A batched program costs
     by its bucket, the power of two its rows are padded to, almost as if every row
     were live (each padding row's caches are copied, written and attended over
     like a live one's): 17 rows cost what 32 do, near twice what 16 do. So a cohort
     that would pad more than a quarter of its bucket takes the full bucket below
-    instead; the rows left over are the first of the next cohort."""
+    instead; the rows left over are the first of the next cohort.
+
+    ``active``: the rows under way on the chain, these and those of the cohorts
+    launched and not yet answered. With more than `HALVED_ABOVE` of them a cohort takes
+    at most the bucket that holds HALF: closed-loop sessions travel as two cohorts that
+    alternate, one's clients turning around while the other is on the device, and that
+    is steady only while neither takes the other's rows. Where a program's time is its
+    weights' and hardly its rows', a cohort of 24 to 31 of 32 rows beside one of 8 to 1
+    is as steady (the small one lasts long enough for every row of the large one to
+    come back) and a quarter slower: ISSUE 43 read 31 + 1 for seconds at a time in half
+    of its runs, 641 to 673 tokens/s where the others read 695 to 708. Below that many
+    rows a program's time is its weights' whatever the block, and halving a cohort
+    would double it."""
     bucket = _next_pow2(waiting)
-    return waiting if 4 * waiting >= 3 * bucket else bucket // 2
+    take = waiting if 4 * waiting >= 3 * bucket else bucket // 2
+    return min(take, _next_pow2(-(-active // 2))) if active > HALVED_ABOVE else take
 
 
 class _Session:
@@ -514,6 +542,8 @@ class DecodeSessionManager:
                 )
                 record_routing(routing, "direct", span, positions=new_len, held=held_range(backend.module))
                 record_attended(attended, positions=new_len)
+                if chunk_len == 1 and self._cache_kind(uid) == "latent":
+                    _LATENT_POSITIONS.inc(session.index + 1, path="direct")
                 # the next block is dispatched when this one has finished: a cohort's
                 # program that arrives meanwhile waits for one block of a prefill, not
                 # for the chain
@@ -729,7 +759,7 @@ class DecodeSessionManager:
         behind this one's last."""
         loop = asyncio.get_running_loop()
         held: List = []  # entries out of _pending whose sessions this drainer pins
-        resolving = set()  # the `_resolve` tasks of cohorts launched and not yet answered
+        resolving = {}  # the `_resolve` tasks of cohorts launched and not yet answered -> their rows
         try:
             # the flush window exists to merge OTHER clients' concurrent steps;
             # with a single actively-decoding session it is pure per-token
@@ -761,7 +791,7 @@ class DecodeSessionManager:
                     else:
                         seen |= ids
                         cohort.append(entry)
-                take = _cohort_rows(len(cohort))
+                take = _cohort_rows(len(cohort), len(held) + sum(resolving.values()))
                 cohort, rollover = cohort[:take], cohort[take:] + rollover
                 try:
                     finish = await loop.run_in_executor(None, self._launch_cohort, chain, cohort)
@@ -770,8 +800,8 @@ class DecodeSessionManager:
                     finish = lambda: failed  # noqa: E731
                 held = rollover  # the cohort's futures and pins are its `_resolve` task's from here
                 task = spawn(self._resolve(cohort, finish), name="decode_session.resolve")
-                resolving.add(task)
-                task.add_done_callback(resolving.discard)
+                resolving[task] = len(cohort)
+                task.add_done_callback(lambda done: resolving.pop(done, None))
         except asyncio.CancelledError:
             # drainer killed in its flush window or mid-cohort (loop shutdown,
             # server stop): nothing will ever resolve these futures — cancel them
@@ -1001,6 +1031,8 @@ class DecodeSessionManager:
                     output.settle(span)
             _STEPS.inc(len(live), path="batched")
             _BATCHED_ROWS.inc(len(live), caches=caches)
+            if self._cache_kind(uid) == "latent":
+                _LATENT_POSITIONS.inc(int(indices[:len(live)].sum()) + len(live), path="batched")
             with _batch_phase("scatter"):
                 now = time.monotonic()
                 for row, (i, session, leaves) in enumerate(zip(live, sessions, zip(*new))):  # row by row, its new leaves

@@ -8,7 +8,11 @@ is served through `DecodeSessionManager` if it has ``init_decode_cache(batch, ma
 and its ``__call__(x, *cache, index)`` returns ``(y, *cache)``. The cache is a TREE of
 arrays, batch axis first, as the block chooses: ``(cache_k, cache_v)`` (the four blocks
 of `common.py`), one recurrent state that a step UPDATES, or keys, values and compressed
-keys that a step appends to (`minicpm_sala_block`, both). The manager keeps a session's
+keys that a step appends to (`minicpm_sala_block`, both), or ONE array of compressed
+latents that every head's key and value are expanded from (`deepseek_v3_block`; a tree of
+one leaf is still handed over as ``*cache``: the block's ``__call__(x, cache, index)`` gets
+the array, or in a batched step of a block that says ``decode_rows_apart`` the tuple of the
+rows' arrays, and returns ``(y, cache)`` in the form it came in). The manager keeps a session's
 tree as the tuple of its leaves and never looks inside one: it joins the leaves of a
 batch's rows along the batch axis and splits the new leaves back one a row (or hands a
 block that says ``decode_rows_apart`` the rows' arrays as they are), donates a
@@ -45,7 +49,10 @@ sessions stay as they were. Optional class attributes:
   call, the number of REAL positions of the chunk (a chunk of more than one position
   comes right-padded to a power of two; a cache that keeps every position needs no
   telling, its padded tail lies past ``index``; a ring or a recurrent state must keep
-  the padding out);
+  the padding out). A block that does NOT ask still gets the padded chunk: it writes the
+  padded tail into its cache past the real positions, where the causal mask hides it from
+  every real query and the next chunk or step overwrites it (`deepseek_v3_block`; what it
+  computes for the padded queries is sliced off by the manager);
 - ``decode_takes_chunks = True``: a chunk of MORE THAN ONE position may CONTINUE a
   session (a long prompt arrives in chunks, each call continuing where the last one
   ended; the chunk is padded as a prefill is, but never past the cache's end). A chain
@@ -60,7 +67,8 @@ sessions stay as they were. Optional class attributes:
   with ``window`` = 0 (ISSUE 42: the join, the copy and the split of 16 x 33.5 MB around
   every step were a quarter of OLMoE's device time) and `minicpm_sala_block`'s sparse
   mixer (ISSUE 41: a third of its program's time and 2.3 GB of its temporaries at 32 rows
-  of 32,768 slots). A ring of ``window`` slots (0.5 MB a session) or a recurrent state
+  of 32,768 slots) and `deepseek_v3_block` (18.9 MB of latents a session at 16,384 slots).
+  A ring of ``window`` slots (0.5 MB a session) or a recurrent state
   (2.1 MB) stays JOINED: the join costs less than an operation a row. What stays of the
   caches' traffic apart is one copy a row a step: the new array of an argument that is
   not donated (below: a failed batched step leaves every session as it was). A session's
@@ -75,7 +83,16 @@ chose (`moe/server/routing_stats.py`). A block whose steps attend a SELECTION of
 they cached sows the positions attended and seen into `common.ATTENDED_COLLECTION` as
 ``attended``, and the same module counts the live rows' (`hivemind_moe_sparse_positions_*_total`);
 what it sows there as ``chosen`` (the blocks each query selected) stays on the device
-unless a check against a reference taps it (`routing_stats.SELECTION_TAPS`).
+unless a check against a reference taps it (`routing_stats.SELECTION_TAPS`); what a
+block's router saw and chose (``router_input``, ``router_choice``) is sown there the same
+way (`routing_stats.ROUTER_TAPS`), so that a check reads the router of the served programs
+and not of a pass of its own. A block whose steps attend ALL they cached but whose work
+grows with it (a latent cache read where it lies: ``decode_cache_kind`` ``latent``) sows
+nothing for that: the manager knows each live row's write position and counts the positions
+attended itself (`hivemind_moe_latent_positions_attended_total`). A block that
+takes a step and a chunk by different forms of the same attention (`deepseek_v3_block`: a
+step absorbs, a chunk expands) tells them apart by the call alone: one position with a
+cache is a step, anything else a chunk; nothing is configured.
 
 Which rows meet in a batched step is decided per SPAN CHAIN, not per block: the steps
 of the sessions that wait on the same chain of this server's blocks walk it together

@@ -606,3 +606,12 @@ def _minicpm_sala_block(hidden_dim: int, **kwargs):
     from hivemind_tpu.moe.server.layers.minicpm_sala import MiniCPMSalaBlockExpert
 
     return MiniCPMSalaBlockExpert(hidden_dim, **kwargs)
+
+
+@register_expert_class("deepseek_v3_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))
+def _deepseek_v3_block(hidden_dim: int, **kwargs):
+    """`layers/deepseek_v3.py`'s block (multi-head latent attention, then a dense MLP or a
+    group-limited sparse expert layer), loaded when one is built, as `minicpm_sala_block` is."""
+    from hivemind_tpu.moe.server.layers.deepseek_v3 import DeepseekV3BlockExpert
+
+    return DeepseekV3BlockExpert(hidden_dim, **kwargs)

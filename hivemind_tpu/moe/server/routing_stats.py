@@ -24,7 +24,7 @@ import numpy as np
 from hivemind_tpu.moe.server.layers.common import ATTENDED_COLLECTION, ROUTING_COLLECTION
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 
-__all__ = ["ATTENDED_COLLECTION", "ROUTING_COLLECTION", "SELECTION_TAPS", "held_range", "record_attended", "record_routing"]
+__all__ = ["ATTENDED_COLLECTION", "ROUTING_COLLECTION", "ROUTER_TAPS", "SELECTION_TAPS", "held_range", "record_attended", "record_routing"]
 
 _PATH_HELP = "by serving path (batched / direct = decode sessions, pool = TaskPool forward and backward)"
 _LAYER_CALLS = _TELEMETRY.counter(
@@ -100,14 +100,19 @@ _POSITIONS_CACHED = _TELEMETRY.counter(
 # ``[rows, positions, kv_heads, topk]`` int32 on the host, in the order the calls settle.
 # While the list is empty nothing of it leaves the device
 SELECTION_TAPS: List[Callable[[np.ndarray], None]] = []
+# the same for what the served programs' ROUTERS saw and chose: handed, for every call on a
+# decode path of a block that sows them (``router_input`` ``[rows, positions, hidden]`` and
+# ``router_choice`` ``[rows, positions, k]``), the live part of both on the host
+ROUTER_TAPS: List[Callable[[np.ndarray, np.ndarray], None]] = []
 
 
 def record_attended(attended, rows: Optional[int] = None, positions: Optional[int] = None) -> None:
     """Count what a call's block sowed into `ATTENDED_COLLECTION` as ``attended``: ``[2,
     batch, seq]`` leaves, the positions each query attended and the positions it had seen.
     ``rows`` / ``positions``: the live leading rows and positions (None = all). What it
-    sowed as ``chosen`` goes to the `SELECTION_TAPS`, if there are any. A block that sows
-    nothing counts nothing."""
+    sowed as ``chosen`` goes to the `SELECTION_TAPS`, and what it sowed as ``router_input`` and
+    ``router_choice`` to the `ROUTER_TAPS`, if there are any. A block that sows nothing counts
+    nothing."""
     for leaf in attended.get("attended", ()):
         live = np.asarray(leaf)[:, :rows, :positions]
         _POSITIONS_ATTENDED.inc(int(live[0].sum()))
@@ -116,3 +121,7 @@ def record_attended(attended, rows: Optional[int] = None, positions: Optional[in
         for leaf in attended.get("chosen", ()):
             for tap in SELECTION_TAPS:
                 tap(np.asarray(leaf)[:rows, :positions])
+    if ROUTER_TAPS:
+        for seen, chose in zip(attended.get("router_input", ()), attended.get("router_choice", ())):
+            for tap in ROUTER_TAPS:
+                tap(np.asarray(seen)[:rows, :positions], np.asarray(chose)[:rows, :positions])

@@ -78,16 +78,27 @@ def routed_swiglu(tokens: jax.Array, top_p: jax.Array, top_e: jax.Array,
 
 
 def route_sigmoid_top_k(tokens: jax.Array, router: jax.Array, bias: jax.Array, k: int,
-                        scale: float) -> Tuple[jax.Array, jax.Array]:
+                        scale: float, n_group: int = 1, topk_group: int = 1) -> Tuple[jax.Array, jax.Array]:
     """The DeepSeek-V3 family's router: sigmoid scores over all experts in float32,
     the k experts with the largest ``score + bias`` (the bias picks and does not
     weigh), each weighed by its own score over the sum of the k picked scores, times
     ``scale``: ``(weights [tokens, k], top_e [tokens, k])``. The matmul runs at the
-    highest precision, as in `route_top_k`."""
+    highest precision, as in `route_top_k`. With ``n_group`` > 1 the choice is
+    GROUP-LIMITED: the experts lie in ``n_group`` groups of consecutive numbers, a
+    group's score is the sum of its two largest ``score + bias``, the ``topk_group``
+    best groups are kept, and the k experts are chosen among theirs (a token's experts
+    then lie on at most ``topk_group`` of the hosts that hold a group each). At
+    ``n_group`` 1 nothing of it is traced: the program is the ungrouped one."""
     logits = jnp.dot(tokens.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, top_e = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        grouped = choice.reshape(choice.shape[0], n_group, -1)
+        _, kept = jax.lax.top_k(jax.lax.top_k(grouped, 2)[0].sum(-1), topk_group)
+        in_kept = (kept[:, :, None] == jnp.arange(n_group)).any(1)  # [tokens, n_group]
+        choice = jnp.where(in_kept[:, :, None], grouped, -jnp.inf).reshape(choice.shape)
+    _, top_e = jax.lax.top_k(choice, k)
     picked = jnp.take_along_axis(scores, top_e, axis=-1)
     return scale * picked / picked.sum(-1, keepdims=True), top_e
 

@@ -131,6 +131,35 @@ def test_a_cohort_fills_its_bucket_or_takes_the_one_below(waiting, cohorts):
     assert taken == cohorts
 
 
+@pytest.mark.parametrize("waiting, active, taken", [
+    (31, 32, 16), (32, 32, 16), (24, 32, 16), (26, 32, 16), (16, 32, 16), (15, 32, 15), (8, 32, 8), (1, 32, 1),  # 32 sessions: 16 + 16
+    (24, 24, 16), (17, 17, 16), (16, 16, 16), (12, 16, 12), (12, 12, 12), (3, 4, 3),  # 16 or fewer under way: as it was
+    (48, 64, 32), (60, 64, 32), (33, 33, 32), (31, 0, 31), (24, 0, 24)])
+def test_a_cohort_leaves_half_of_many_rows_to_the_next(waiting, active, taken):
+    """With more than 16 rows under way on a chain (waiting, or launched and not yet
+    answered) a cohort takes at most the bucket that holds half of them: 31 waiting beside 1
+    in flight become 16 + 15, where 31 + 1 would go on alternating (ISSUE 43)."""
+    from hivemind_tpu.moe.server.decode_session import _cohort_rows
+
+    assert _cohort_rows(waiting, active) == taken
+
+
+def test_twenty_four_sessions_travel_as_sixteen_and_eight():
+    """24 steps that wait together are two cohorts, 16 rows then 8, and no program of 32."""
+    manager, rng = _manager(CHAIN[:1]), np.random.RandomState(24)
+    chain, names = CHAIN[:1], [f"s{i}" for i in range(24)]
+    _prefill(manager, chain, names, rng)
+    tokens = {name: rng.randn(1, 1, HID).astype(np.float32) for name in names}
+    before = _counters()
+    with _Spans(chain) as seen:
+        outs = _step_together(manager, chain, tokens)
+    assert _moved(before) == {"steps": 24, "calls": 2, "direct_calls": 0, "cohorts": 2}
+    assert [(b.attributes["rows"], b.attributes["bucket"]) for b in seen.cohorts()[1]] == [(16, 16), (8, 8)]
+    for name, token in tokens.items():
+        np.testing.assert_allclose(outs[name], manager._decode_direct(chain, "twin-" + name, token, False), rtol=1e-5, atol=1e-5)
+    assert manager._in_flight == {} and not manager._pending.get(chain)
+
+
 def test_rows_past_a_full_bucket_are_the_next_cohort():
     manager, rng = _manager(), np.random.RandomState(7)
     names = [f"s{i}" for i in range(5)]

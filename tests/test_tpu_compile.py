@@ -165,6 +165,46 @@ def test_sala_prompt_chunk_of_4096_positions_fits_the_chip(one_chip, no_compile_
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9  # 1.23 GB and 0.24 GB when written
 
 
+@pytest.mark.parametrize("mlp", ["dense", "sparse"])
+def test_latent_batched_step_of_32_sessions_fits_the_chip(one_chip, no_compile_cache, mlp):
+    """The batched program of a GigaChat3.1 block (`deepseek_v3_block`) at the published widths
+    and 12,288 slots, a bucket of 32, as `DecodeSessionManager._batched_fn` builds it over the
+    rows' own arrays (`decode_rows_apart`: ONE array of 576 values a position a session): it
+    compiles, names its scopes, keeps each row's array in the layout that puts the positions
+    on the lanes (576 is no multiple of 128: neither the latent nor the shared key is padded),
+    expands no cache, and its temporaries stay small beside 10.61 GB of weights and 2.26 GB of
+    sessions (ISSUE 43: 0.03 GB when written; the 32 new arrays are outputs, 0.45 GB)."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len, rows = 7168, 12288, 32
+    module = name_to_block["deepseek_v3_block"](hidden, mlp=mlp, v_head_dim=192, rope_theta=100000.0, rope_factor=64.0, held=8)
+    compiled, leaves = _compiled_batched_step(module, hidden, max_len, rows, one_chip)
+    text = compiled.as_text()
+    assert leaves == [(1, max_len, 576)] and "latent_absorb" in text and "latent_attend" in text and "latent_expand" not in text
+    assert ("moe_experts" in text) == (mlp == "sparse")
+    assert f"bf16[1,{max_len},576]{{1,2,0:" in text  # the positions are the minor axis: 14.16 MB a session, as the gauge counts it
+    analysis = compiled.memory_analysis()
+    assert analysis.temp_size_in_bytes < 0.2e9 and analysis.output_size_in_bytes < rows * max_len * 576 * 2 + 0.05e9
+
+
+@pytest.mark.parametrize("mlp", ["dense", "sparse"])
+def test_latent_prompt_chunk_of_2048_positions_fits_the_chip(one_chip, no_compile_cache, mlp):
+    """A chunk of 2,048 positions continuing a session at 12,288 slots, at the published
+    widths, in the expanded form: keys in blocks of 1,024 and queries in blocks of 512 under a
+    running softmax (the chunk's scores against 10k cached positions whole would be 5 GB)."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len, chunk = 7168, 12288, 2048
+    module = name_to_block["deepseek_v3_block"](hidden, mlp=mlp, v_head_dim=192, rope_theta=100000.0, rope_factor=64.0, held=8)
+    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, hidden), jnp.float32))["params"])
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), tree)
+    cache = on_chip(jax.eval_shape(lambda: module.init_decode_cache(1, max_len)))
+    step = jax.jit(lambda p, x, cache, index: module.apply({"params": p}, x, *cache, index), donate_argnums=(2,))
+    compiled = step.lower(on_chip(params), _shape((1, chunk, hidden), jnp.float32, one_chip), cache, _shape((), jnp.int32, one_chip)).compile()
+    assert "latent_expand" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9  # 0.36 GB dense and 1.01 GB sparse when written
+
+
 @pytest.mark.parametrize(
     "shape,causal,backward",
     [
