@@ -130,6 +130,16 @@ _EVICTIONS = _TELEMETRY.counter(
     "decode sessions evicted, by reason (ttl = idle expiry, cap = LRU over max_sessions)",
     ("reason",),
 )
+# whether the table is walked for a step (ISSUE 44): every step and every add asks whether an eviction pass
+# could have an effect (`_evict_due_locked`), and a pass runs only then; in steady traffic `skipped` follows
+# the steps and `ran` the sessions added to a table at its cap
+_EVICT_PASSES = _TELEMETRY.counter(
+    "hivemind_moe_decode_evict_passes_total",
+    "times a decode step or a new session asked for an eviction pass over the session table, by outcome (skipped = "
+    "the table was within its cap and no session could have passed its TTL yet: no walk; ran = the table was walked)",
+    ("outcome",),
+)
+_PASS_SKIPPED, _PASS_RAN = _EVICT_PASSES.labels("skipped"), _EVICT_PASSES.labels("ran")
 _RESETS = _TELEMETRY.counter(
     "hivemind_moe_decode_session_resets_total",
     "decode sessions created or re-prefilled via reset=True",
@@ -348,7 +358,19 @@ class DecodeSessionManager:
     :param max_len: cache capacity per session (prompt + generated tokens)
     :param session_ttl: seconds of inactivity before a session is evicted
     :param max_sessions: LRU cap across all uids
-    """
+
+    **When eviction runs.** Lazily, at a call, and only at one that can evict: a step or a new
+    session asks `_evict_due_locked`, which walks the table (`_evict_locked`) when it is over its
+    cap — which only an ADD can bring about (`_enter` with ``reset``), so the pass follows the add
+    and the cap holds when the add returns — or when the earliest possible expiry has come
+    (`_oldest_use`: no session was last used before it, as of the last pass). Any other call skips
+    in O(1). So a session idle past ``session_ttl`` goes at the next step or add after its
+    deadline, whichever path it takes (`_enter` for the direct path, `_submit_step` for a cohort's
+    row), and a pinned one (a step enqueued or under way) never. What a step does under the
+    manager's lock does not grow with the table: whether a block has concurrent streams is kept as
+    its two latest stamps (`_recent`: a step is stamped under the lock when it is submitted and when
+    it has ended; in between, a cohort's programs note their block's use without a lock,
+    `_batched_at`), and the gauges are set where the table changes."""
 
     def __init__(self, backends, max_len: int = 256, session_ttl: float = 600.0,
                  max_sessions: int = 64):
@@ -366,43 +388,76 @@ class DecodeSessionManager:
         # host activations -> the device, one program a bucket (`_device_rows`)
         self._upload = tracked_jit(lambda xs: xs, site="decode_session.upload")
         self._cache_tally: Dict[str, List[int]] = {}  # kind of cache -> [bytes, entries] of the table (`_count_cache_locked`)
+        # no session of the table was last used before this, as of the last eviction pass: sessions are only
+        # used later, so until ``session_ttl`` past it no pass can find one expired (`_evict_due_locked`)
+        self._oldest_use = float("inf")
+        # uid -> [id, last_used, id, last_used] of its two most recently used sessions, the latest first
+        # (`_stamp_locked`): what `_concurrent_sessions` needs, kept so that no step walks the table for it
+        self._recent: Dict[str, List] = {}
+        # uid -> when its last batched program scattered: two or more of the block's sessions were used then.
+        # A cohort under way keeps its sessions recent block by block with this ONE store and no lock (the
+        # dispatching thread would wait for a lock the loop holds, and then for the interpreter); their
+        # stamps proper follow when it resolves
+        self._batched_at: Dict[str, float] = {}
 
     def supports(self, uid: str) -> bool:
         backend = self.backends.get(uid)
         return backend is not None and hasattr(backend.module, "init_decode_cache")
 
-    def _evict_locked(self) -> None:
-        now = time.monotonic()
-        # sessions with an enqueued-but-unresolved batched step are pinned: evicting
-        # one mid-flight would orphan its cache object — the step would "succeed"
-        # against the orphan and the client's next continuation would KeyError.
-        # _in_flight covers the window after _drain pops entries out of _pending but
-        # before their cohort finishes (the device calls themselves).
-        pinned = {
-            id(session)
-            for entries in self._pending.values()
-            for (_future, sessions, _x) in entries
-            for session in sessions
-        } | set(self._in_flight)
-        expired = [
-            k for k, s in self._sessions.items()
-            if now - s.last_used > self.session_ttl and id(s) not in pinned
-        ]
-        for key in expired:
-            self._drop_locked(key)
-        if expired:
-            _EVICTIONS.inc(len(expired), reason="ttl")
-        evictable = [k for k in self._sessions if id(self._sessions[k]) not in pinned]
-        while len(self._sessions) > self.max_sessions and evictable:
-            oldest = min(evictable, key=lambda k: self._sessions[k].last_used)
-            evictable.remove(oldest)
-            self._drop_locked(oldest)
-            _EVICTIONS.inc(reason="cap")
-        self._sample_gauges_locked()
+    def _evict_due_locked(self, keep: Optional[_Session] = None) -> None:
+        """The eviction a step or an add owes, when it can have an effect: the table is over its cap, or the
+        earliest possible expiry has come. Else nothing, in O(1). ``keep``: the session just added."""
+        if len(self._sessions) > self.max_sessions or time.monotonic() - self._oldest_use > self.session_ttl:
+            _PASS_RAN.inc()
+            self._evict_locked(keep)
+        else:
+            _PASS_SKIPPED.inc()
+
+    def _evict_locked(self, keep: Optional[_Session] = None) -> None:
+        """One pass over the table: the sessions idle past ``session_ttl`` go, then the oldest of those over
+        ``max_sessions``; never a pinned one, nor ``keep``."""
+        now, sessions = time.monotonic(), self._sessions
+        expired = [k for k, s in sessions.items() if now - s.last_used > self.session_ttl]
+        if expired or len(sessions) > self.max_sessions:
+            # sessions with an enqueued-but-unresolved batched step are pinned: evicting
+            # one mid-flight would orphan its cache object — the step would "succeed"
+            # against the orphan and the client's next continuation would KeyError.
+            # _in_flight covers the window after _drain pops entries out of _pending but
+            # before their cohort finishes (the device calls themselves).
+            pinned = {
+                id(session)
+                for entries in self._pending.values()
+                for (_future, entry_sessions, _x) in entries
+                for session in entry_sessions
+            } | set(self._in_flight) | {id(keep)}
+            expired = [k for k in expired if id(sessions[k]) not in pinned]
+            if expired:
+                self._drop_locked(expired)
+                _EVICTIONS.inc(len(expired), reason="ttl")
+            over = len(sessions) - self.max_sessions
+            if over > 0:  # oldest first (the sort is stable: of two used at once, the one that entered first)
+                oldest = sorted((k for k, s in sessions.items() if id(s) not in pinned), key=lambda k: sessions[k].last_used)[:over]
+                self._drop_locked(oldest)
+                _EVICTIONS.inc(len(oldest), reason="cap")
+        self._oldest_use = min((s.last_used for s in sessions.values()), default=float("inf"))
 
     def _sample_gauges_locked(self) -> None:
         _SESSIONS.set(len(self._sessions))
         _SESSION_OCCUPANCY.set(round(len(self._sessions) / max(self.max_sessions, 1), 4))
+
+    def _stamp_locked(self, uid: str, sessions, now: float) -> None:
+        """``sessions`` of ``uid`` (distinct ones) are used at ``now``, a `time.monotonic()` no older than any
+        stamp made before (read under the lock, or a session's own `last_used`): their `last_used`, and the
+        block's two latest sessions, which are the last two of these."""
+        for session in sessions:
+            session.last_used = now
+        recent = self._recent.get(uid)
+        if recent is None:
+            recent = self._recent[uid] = [0, float("-inf"), 0, float("-inf")]
+        for session in sessions[-2:]:
+            if recent[0] != id(session):
+                recent[2], recent[3], recent[0] = recent[0], recent[1], id(session)
+            recent[1] = now
 
     def _cache_kind(self, uid: str) -> str:
         return getattr(self.backends[uid].module, "decode_cache_kind", "full")
@@ -433,11 +488,29 @@ class DecodeSessionManager:
                 tally[:] = [0, 0]
                 _CACHE_BYTES.set(0, kind=kind)
                 _CACHE_ENTRIES.set(0, kind=kind)
+            self._recent.clear()
+            self._batched_at.clear()
             self._sample_gauges_locked()
 
-    def _drop_locked(self, key: Tuple[str, str]) -> None:
-        session = self._sessions.pop(key)
-        self._count_cache_locked(key[0], session, -1)
+    def _drop_locked(self, keys) -> None:
+        """The sessions under ``keys`` leave the table: the tallies, the gauges, and what is kept of the
+        latest use of a block that lost one of its two latest sessions or a row of its last batched program,
+        found again in ONE walk (a drop is rare; a step never walks)."""
+        stale = set()
+        for key in keys:
+            uid, session = key[0], self._sessions.pop(key)
+            self._count_cache_locked(uid, session, -1)
+            if id(session) in self._recent.get(uid, ())[::2] or session.last_used >= self._batched_at.get(uid, float("inf")):
+                stale.add(uid)
+        if stale:  # stamped again in the order they were used, each block's last two stay
+            for uid in stale:
+                self._recent.pop(uid, None)
+                self._batched_at.pop(uid, None)
+            for (uid, _name), session in sorted(self._sessions.items(), key=lambda item: item[1].last_used):
+                if uid in stale:
+                    self._stamp_locked(uid, (session,), session.last_used)
+        if keys:
+            self._sample_gauges_locked()
 
     def _raw_step(self, uid: str):
         """The un-jitted block step; shared by the direct and batched paths so a
@@ -554,9 +627,7 @@ class DecodeSessionManager:
                 return y
         except Exception:
             with self._lock:
-                for key in [k for k, s in self._sessions.items() if s is session]:
-                    self._drop_locked(key)
-                self._sample_gauges_locked()
+                self._drop_locked([k for k, s in self._sessions.items() if s is session])
             raise
 
     def decode(self, uid: str, session_id: str, x: np.ndarray, reset: bool) -> np.ndarray:
@@ -603,20 +674,25 @@ class DecodeSessionManager:
         if padded_len != new_len:
             x = np.pad(x, ((0, 0), (0, padded_len - new_len), (0, 0)))
         record_transfer(x.nbytes, "host_to_device")
-        y = x
+        y, entered = x, []
         for uid in chain:
             session = self._enter(uid, session_id, batch, reset)
             with session.lock:
                 self._check_step(session, session_id, batch, new_len, continues)
                 y = self._advance(uid, session, self.backends[uid], y, padded_len, new_len)
                 session.index += new_len
-                # re-stamp AFTER the device step: a step that hits a jit compile can
-                # outlast MERGE_RECENCY_S, and a session stamped only at entry would
-                # look stale to _concurrent_sessions the instant its own prefill
-                # returns — so two freshly-prefilled streams never engage batching.
-                # Bare float store; concurrent readers just see one of two recent stamps.
-                session.last_used = time.monotonic()
                 _STEPS.inc(path="direct")
+            entered.append(session)
+        # re-stamp AFTER the device steps: a step that hits a jit compile can
+        # outlast MERGE_RECENCY_S, and a session stamped only at entry would
+        # look stale to _concurrent_sessions the instant its own prefill
+        # returns — so two freshly-prefilled streams never engage batching.
+        # ONE hold for the chain: a lock that another thread holds costs this one the interpreter
+        with self._lock:
+            now = time.monotonic()
+            for uid, session in zip(chain, entered):
+                if self._sessions.get((uid, session_id)) is session:  # a direct step pins nothing: it may have been evicted
+                    self._stamp_locked(uid, (session,), now)
         if continues:
             _PREFILL_CHUNKS.inc()  # one that a block refused (an unknown session, a full cache) is not counted
         out = np.asarray(y)[:, :new_len]
@@ -627,25 +703,45 @@ class DecodeSessionManager:
         """The session of ``session_id`` at ``uid``: a fresh one for ``reset``."""
         key = (uid, session_id)
         with self._lock:
-            self._evict_locked()
-            session = self._sessions.get(key)
             if reset:
-                if session is not None:
-                    self._drop_locked(key)
+                if key in self._sessions:
+                    self._drop_locked([key])
                 session = self._sessions[key] = _Session(self._fresh_caches(self.backends[uid], batch))
                 self._count_cache_locked(uid, session, +1)
                 _RESETS.inc()
+                self._stamp_locked(uid, (session,), time.monotonic())
+                self._oldest_use = min(self._oldest_use, session.last_used)
+                # an add is the one moment the table can pass its cap: the pass follows it, the
+                # new session out of its reach, and the cap holds when the add returns
+                self._evict_due_locked(keep=session)
                 self._sample_gauges_locked()
-            elif session is None:
-                # NEVER silently prefill a continuation: an evicted/expired/unknown
-                # session would return semantically-garbage activations. The client
-                # must restart generation with reset=True.
-                raise KeyError(
-                    f"unknown or expired decode session {session_id!r} for {uid!r}; "
-                    f"restart generation with reset=True"
-                )
-            session.last_used = time.monotonic()
+                return session
+            session = self._known_locked((uid,), session_id)[0]
+            self._stamp_locked(uid, (session,), time.monotonic())
         return session
+
+    def _stamp_chain_locked(self, chain: Chain, rows: List) -> None:
+        """``rows`` (each the sessions of one client at the blocks of ``chain``) are used now: a step of
+        each is submitted, or has ended."""
+        now = time.monotonic()
+        for uid, sessions in zip(chain, zip(*rows)):
+            self._stamp_locked(uid, sessions, now)
+
+    def _known_locked(self, chain: Chain, session_id: str) -> List[_Session]:
+        """A continuation's sessions at the blocks of ``chain``, after the eviction the call owes (the ONE
+        rule of both paths, `_enter` for the direct one and `_submit_step` for a cohort's row: a session
+        idle past its TTL is gone before it is looked up). Raises ``KeyError`` for one that is not there."""
+        self._evict_due_locked()
+        sessions = [self._sessions.get((uid, session_id)) for uid in chain]
+        if None in sessions:
+            # NEVER silently prefill a continuation: an evicted/expired/unknown
+            # session would return semantically-garbage activations. The client
+            # must restart generation with reset=True.
+            raise KeyError(
+                f"unknown or expired decode session {session_id!r} for "
+                f"{chain[sessions.index(None)]!r}; restart generation with reset=True"
+            )
+        return sessions
 
     def _check_step(self, session: _Session, session_id: str, batch: int, new_len: int, continues: bool = False) -> None:
         """What a step must meet at a block (under ``session.lock``). ``continues``: the
@@ -691,10 +787,22 @@ class DecodeSessionManager:
         loop = asyncio.get_running_loop()
         x = np.asarray(x, np.float32)
         batchable = not reset and x.ndim == 3 and x.shape[0] == 1 and x.shape[1] == 1
+        enqueued = time.perf_counter()
         if batchable:
+            # ONE hold of the lock a step, and nothing under it that grows with the table. Lookup +
+            # enqueue under one hold: releasing in between would let an eviction pass delete a
+            # session while this step is pending, so the step would update an orphaned cache and
+            # the next continuation KeyErrors
             with self._lock:
                 # a chain's sessions are opened together: its first block speaks for it
                 batchable = self._concurrent_sessions(chain[0])
+                if batchable:
+                    sessions = self._known_locked(chain, session_id)
+                    self._stamp_chain_locked(chain, [sessions])
+                    future = loop.create_future()
+                    self._pending.setdefault(chain, []).append((future, sessions, x))
+                    if chain not in self._drainers or self._drainers[chain].done():
+                        self._drainers[chain] = spawn(self._drain(chain), name="decode_session.drain")
         if not batchable:
             # prefill, reset, session batch != 1, batching off — or a single
             # actively-decoding stream: the drainer/future/flush-window machinery
@@ -702,41 +810,19 @@ class DecodeSessionManager:
             # per-session path (same jitted step; same-session ordering is still
             # serialized by the session lock). ISSUE 10.
             return await loop.run_in_executor(None, self._decode_direct, chain, session_id, x, reset), 0.0
-
-        enqueued = time.perf_counter()
-        future = loop.create_future()
-        with self._lock:
-            # lookup + enqueue under ONE lock hold: releasing in between would let
-            # _evict_locked delete a session while this step is pending, so the
-            # step would update an orphaned cache and the next continuation KeyErrors
-            self._evict_locked()  # the direct path evicts in decode(); mirror it here
-            sessions = [self._sessions.get((uid, session_id)) for uid in chain]
-            if None in sessions:
-                raise KeyError(
-                    f"unknown or expired decode session {session_id!r} for "
-                    f"{chain[sessions.index(None)]!r}; restart generation with reset=True"
-                )
-            now = time.monotonic()
-            for session in sessions:
-                session.last_used = now
-            self._pending.setdefault(chain, []).append((future, sessions, x))
-            if chain not in self._drainers or self._drainers[chain].done():
-                self._drainers[chain] = spawn(self._drain(chain), name="decode_session.drain")
         out = await future
         return out, max(sessions[0].batch_started - enqueued, 0.0)
 
     def _concurrent_sessions(self, uid: str) -> bool:
         """True when MORE THAN ONE recently-active session exists on this uid
         (so waiting the flush window could actually merge steps). Called under
-        self._lock; the caller's own session is always recent."""
-        now = time.monotonic()
-        count = 0
-        for key, session in self._sessions.items():
-            if key[0] == uid and now - session.last_used < MERGE_RECENCY_S:
-                count += 1
-                if count > 1:
-                    return True
-        return False
+        self._lock; the caller's own session is always recent. No walk: the block's
+        second latest stamp says it (`_stamp_locked`: every step is stamped through it when
+        it is submitted and when it has ended, and `_drop_locked` finds the two again when
+        one of them leaves), or its last batched program, which used two sessions at least."""
+        recent = self._recent.get(uid)
+        second_latest = max(recent[3] if recent else float("-inf"), self._batched_at.get(uid, float("-inf")))
+        return time.monotonic() - second_latest < MERGE_RECENCY_S
 
     def _pin_locked(self, entries: List, step: int) -> None:
         """Move the eviction pins of ``entries``' sessions by ``step`` (+1 as they
@@ -799,7 +885,7 @@ class DecodeSessionManager:
                     failed = [e] * len(cohort)
                     finish = lambda: failed  # noqa: E731
                 held = rollover  # the cohort's futures and pins are its `_resolve` task's from here
-                task = spawn(self._resolve(cohort, finish), name="decode_session.resolve")
+                task = spawn(self._resolve(chain, cohort, finish), name="decode_session.resolve")
                 resolving[task] = len(cohort)
                 task.add_done_callback(lambda done: resolving.pop(done, None))
         except asyncio.CancelledError:
@@ -822,14 +908,20 @@ class DecodeSessionManager:
             with self._lock:
                 self._pin_locked(held, -1)
 
-    async def _resolve(self, cohort: List, finish) -> None:
+    async def _resolve(self, chain: Chain, cohort: List, finish) -> None:
         """Await a launched cohort's results (``finish``, on the executor) and
-        answer its futures; its pins drop here, on every exit path."""
+        answer its futures; its pins drop here, on every exit path, and in the same
+        hold of the lock the sessions of the rows that came through are stamped: the end
+        of their step (the dispatching thread takes no lock for it, `_batched_at`: a lock
+        that the loop holds would cost it the interpreter, 0.19 ms a program when the
+        scatter stamped under the lock, PERF.md §6 PR 44)."""
+        ended: List = []  # the sessions of the rows that came through: a row that failed may have lost its sessions
         try:
             try:
                 results = await asyncio.get_running_loop().run_in_executor(None, finish)
             except Exception as e:
                 results = [e] * len(cohort)
+            ended = [sessions for (_future, sessions, _x), result in zip(cohort, results) if not isinstance(result, Exception)]
             for (future, _sessions, _x), result in zip(cohort, results):
                 if future.done():
                     continue
@@ -845,6 +937,7 @@ class DecodeSessionManager:
         finally:
             with self._lock:
                 self._pin_locked(cohort, -1)
+                self._stamp_chain_locked(chain, ended)
 
     def _launch_cohort(self, chain: Chain, entries: List):
         """Walk ``entries`` [(future, [the session of each uid], x)] through the
@@ -872,9 +965,7 @@ class DecodeSessionManager:
             if launched:
                 doomed = {id(session) for i in alive for session in entries[i][1]}
                 with self._lock:
-                    for key in [k for k, session in self._sessions.items() if id(session) in doomed]:
-                        self._drop_locked(key)
-                    self._sample_gauges_locked()
+                    self._drop_locked([k for k, session in self._sessions.items() if id(session) in doomed])
             for i in alive:
                 results[i] = error
 
@@ -997,7 +1088,7 @@ class DecodeSessionManager:
                 record_transfer(int(x.nbytes), "host_to_device")
                 y = self._advance(uid, session, backend, x, 1, 1)
                 session.index += 1
-                session.last_used = time.monotonic()
+                session.last_used = time.monotonic()  # the stamp proper follows when the cohort resolves
                 # counted "direct": nothing was merged/vmapped (the catalog row
                 # defines `batched` as merged into a vmapped continuous batch)
                 _STEPS.inc(path="direct")
@@ -1038,8 +1129,9 @@ class DecodeSessionManager:
                 for row, (i, session, leaves) in enumerate(zip(live, sessions, zip(*new))):  # row by row, its new leaves
                     session.leaves = leaves
                     session.index += 1
-                    session.last_used = now
+                    session.last_used = now  # bare stores, no lock: `_batched_at`
                     results[i] = output.host()[row:row + 1] if fetch else _Row(output, row)
+                self._batched_at[uid] = now
             return results
         finally:
             for i in ordered:

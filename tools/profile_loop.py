@@ -19,6 +19,10 @@ thread every channel, mux and handler of the process shares (`utils/loop.get_loo
    ranks, not seconds a second. A thread that waits for the interpreter lock waits inside some
    function, which is charged.
 
+`--watch a,b` names functions to follow whatever their rank: the share of the samples with the function
+anywhere on the stack (what it HOLDS of the thread, its callees included), and under the timer its calls a
+second and its microseconds a call, own and with its callees.
+
 The run's result line is printed as ever, but a profiled run is not a measurement."""
 
 from __future__ import annotations
@@ -50,8 +54,9 @@ def _enters_kernel(key) -> bool:
     return key[1].rpartition(".")[2] in ENTERS_KERNEL or key[0] == "posix"
 
 
-def _sample(thread_id: int, seconds: float):
+def _sample(thread_id: int, seconds: float, watch=()):
     own, lines, callers, taken = collections.Counter(), collections.Counter(), collections.Counter(), 0
+    within = collections.Counter()  # a watched function anywhere on the stack: the samples it HOLDS, callees included
     until = time.monotonic() + seconds
     while time.monotonic() < until:
         time.sleep(0.001)
@@ -64,7 +69,13 @@ def _sample(thread_id: int, seconds: float):
         lines[f"{here} line {frame.f_lineno}"] += 1
         if back is not None:
             callers[f"{here} <- {_where(back)}"] += 1
-    return own, lines, callers, taken
+        held = set()
+        while frame is not None and watch:
+            if frame.f_code.co_name in watch:
+                held.add(frame.f_code.co_name)
+            frame = frame.f_back
+        within.update(held)
+    return own, lines, callers, within, taken
 
 
 class _Timer:
@@ -72,8 +83,9 @@ class _Timer:
 
     def __init__(self):
         self.own, self.calls = collections.Counter(), collections.Counter()  # key -> ns, key -> calls
+        self.whole = collections.Counter()  # key -> ns from call to return, callees included
         self._stack = []
-        clock, stack, own, calls = time.perf_counter_ns, self._stack, self.own, self.calls
+        clock, stack, own, calls, whole = time.perf_counter_ns, self._stack, self.own, self.calls, self.whole
 
         def on_event(frame, event, arg):
             now = clock()
@@ -85,6 +97,7 @@ class _Timer:
                 key, began, children = stack.pop()
                 elapsed = clock() - began
                 own[key] += elapsed - children
+                whole[key] += elapsed
                 calls[key] += 1
                 if stack:
                     stack[-1][2] += elapsed
@@ -117,7 +130,7 @@ def _count(runner, seconds: float):
     return timer
 
 
-def _profile(begin: float, out: str, sample_s: float, count_s: float) -> None:
+def _profile(begin: float, out: str, sample_s: float, count_s: float, watch=()) -> None:
     from hivemind_tpu.telemetry import REGISTRY
     from hivemind_tpu.utils.loop import get_loop_runner
 
@@ -129,7 +142,7 @@ def _profile(begin: float, out: str, sample_s: float, count_s: float) -> None:
         return sum(value for value in series.values() if isinstance(value, (int, float)))
 
     began, steps_before = time.monotonic(), steps()
-    own, lines, callers, taken = _sample(runner._thread.ident, sample_s)
+    own, lines, callers, within, taken = _sample(runner._thread.ident, sample_s, watch)
     sampled_s, steps_sampled = time.monotonic() - began, steps() - steps_before
     began, steps_before = time.monotonic(), steps()
     timer = _count(runner, count_s)
@@ -147,6 +160,10 @@ def _profile(begin: float, out: str, sample_s: float, count_s: float) -> None:
             say(f"\n{title}: share of samples, seconds a second of this thread")
             for where, count in table.most_common(top):
                 say(f"  {100 * count / taken:5.1f} %  {where}")
+        if watch:
+            say("\nwatched functions: share of samples with the function anywhere on the stack (its callees included)")
+            for name in watch:
+                say(f"  {100 * within[name] / max(taken, 1):5.1f} %  {name}")
         total_ns = sum(timer.own.values())
         say(f"\ntimed for {counted_s:.1f} s under sys.setprofile ({steps_counted / counted_s:.0f} block steps a second meanwhile); frames sealed "
             f"{by_name.get('_seal', 0)}, opened {by_name.get('_open', 0)}; own time accounted {total_ns / 1e9:.2f} s; a Python call costs "
@@ -156,6 +173,12 @@ def _profile(begin: float, out: str, sample_s: float, count_s: float) -> None:
             name, calls = timer.name(key), timer.calls[key]
             mark = "K" if isinstance(key, tuple) and _enters_kernel(key) else " "
             say(f"  {100 * ns / total_ns:5.1f} %  {ns / frames / 1e3:7.2f}  {calls / frames:6.2f}  {ns / max(calls, 1) / 1e3:8.2f}  {mark} {name}")
+        if watch:
+            say("\nwatched functions, timed:  calls a second | us a call own | us a call with its callees | share of the accounted time with its callees")
+            for key, calls in timer.calls.items():
+                if not isinstance(key, tuple) and key.co_name in watch:
+                    say(f"  {calls / counted_s:8.0f}  {timer.own[key] / calls / 1e3:8.2f}  {timer.whole[key] / calls / 1e3:8.2f}  "
+                        f"{100 * timer.whole[key] / total_ns:5.1f} %  {timer.name(key)}")
         say("\nbuilt-ins that enter the kernel:  calls a second (a lower bound: the thread is slowed) | calls a frame | us a call")
         for key, calls in timer.calls.most_common():
             if isinstance(key, tuple) and _enters_kernel(key):
@@ -169,6 +192,7 @@ def main() -> int:
     parser.add_argument("--after", type=float, default=12.0, help="seconds into the window")
     parser.add_argument("--sample", type=float, default=20.0, help="seconds of sampling")
     parser.add_argument("--count", type=float, default=6.0, help="seconds of counting after them")
+    parser.add_argument("--watch", default="", help="function names, comma-separated: each one's share of the samples and its time a call, callees included")
     args, rest = parser.parse_known_args()
     rest = [arg for arg in rest if arg != "--"]
 
@@ -178,7 +202,7 @@ def main() -> int:
     go = block_server.LoadGenerators.go
 
     def go_and_profile(self, begin, end):
-        threading.Thread(target=_profile, args=(begin + args.after, args.out, args.sample, args.count), name="profile-loop", daemon=True).start()
+        threading.Thread(target=_profile, args=(begin + args.after, args.out, args.sample, args.count, tuple(filter(None, args.watch.split(",")))), name="profile-loop", daemon=True).start()
         return go(self, begin, end)
 
     block_server.LoadGenerators.go = go_and_profile
