@@ -14,6 +14,11 @@ two phases whose unit of work is a frame, ``hivemind_wire_frames_total{phase}``:
     send_wait  seconds a frame's sender stood waiting for the channel's in-flight
                credit, which the writer hands back; seconds only — it awaits
 
+``hivemind_wire_half_elements_total{range}``: elements the fp16 codec encoded, ``tiny`` where
+0 < |x| < 2**-14 (the half is subnormal or underflows: the values numpy's own cast pays
+thirty times for, and which send an array through the codec's integer path) and ``other``,
+by the codec's own sampled estimate; arrays of :data:`WORK_SPAN_BYTES` and up.
+
 Seconds are work summed over threads; seconds over bytes is what a megabyte costs in
 that phase on this host (``docs/observability.md`` says what an operator reads off it).
 The counters always count; what else a site leaves behind follows its size
@@ -35,6 +40,10 @@ _SECONDS = REGISTRY.counter(
 )
 _BYTES = REGISTRY.counter("hivemind_wire_bytes_total", "bytes that work was done on", ("phase",))
 _FRAMES = REGISTRY.counter("hivemind_wire_frames_total", "frames sealed and opened by the channels", ("phase",))
+_HALF_ELEMENTS = REGISTRY.counter(
+    "hivemind_wire_half_elements_total", "elements the fp16 codec encoded, by the codec's sampled estimate", ("range",)
+)
+_TINY_ELEMENTS, _OTHER_ELEMENTS = _HALF_ELEMENTS.labels(range="tiny").inc, _HALF_ELEMENTS.labels(range="other").inc
 
 
 def _phase(phase: str):
@@ -86,3 +95,8 @@ def count_work(phase: str, seconds: float, nbytes: int) -> None:
 
 def add_send_wait(seconds: float) -> None:
     _SEND_WAIT.inc(seconds)
+
+
+def count_half_elements(tiny: float, other: float) -> None:
+    _TINY_ELEMENTS(tiny)
+    _OTHER_ELEMENTS(other)

@@ -7,6 +7,7 @@ calls that the chain is held to, bit for bit."""
 import asyncio
 import subprocess
 import sys
+import time
 from pathlib import Path
 from types import SimpleNamespace
 
@@ -133,6 +134,11 @@ def test_span_request_is_one_batch_that_walks_the_chain(served):
     add_span_listener(spans.append)
     try:
         served.forward(uids, np.ones((2, 4, HID), np.float32))
+        # the batch hands its callers their results inside its span: the answer can be
+        # here before the span has closed on the runtime's thread
+        deadline = time.monotonic() + 10.0
+        while not any(s.name == "pool.batch" for s in spans) and time.monotonic() < deadline:
+            time.sleep(0.01)
     finally:
         remove_span_listener(spans.append)
     assert _counter("hivemind_moe_batches_total", pool_name) == 1
