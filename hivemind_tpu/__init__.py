@@ -38,7 +38,6 @@ def __getattr__(name):  # lazy top-level API so `import hivemind_tpu` stays ligh
         "TrainingStateAverager": "hivemind_tpu.optim",
         "PowerSGDGradientAverager": "hivemind_tpu.optim",
         "GradScaler": "hivemind_tpu.optim",
-        "TrainingAverager": "hivemind_tpu.optim",
         "ProgressTracker": "hivemind_tpu.optim",
         "Server": "hivemind_tpu.moe",
         "ModuleBackend": "hivemind_tpu.moe",

@@ -177,8 +177,8 @@ def reconstruct_final_round(
 
 def _aggregate_device_frames(frames: List[Dict[str, Any]]) -> Dict[str, Any]:
     """``device`` frames rolled into one snapshot-shaped section: per-site
-    compile counts (recomputed by replay), the newest memory sample, storm /
-    leak counts, and the last overlap record. Empty dict when the spool holds
+    compile counts (recomputed by replay), the newest memory sample and storm /
+    leak counts. Empty dict when the spool holds
     no device telemetry (pre-ISSUE-19 spools stay readable)."""
     # sites carry {"count": ...} dicts — the SAME shape as the live
     # device_snapshot(), so hivemind-top's device board renders either
@@ -186,7 +186,6 @@ def _aggregate_device_frames(frames: List[Dict[str, Any]]) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     storms = leaks = 0
     last_compile = last_memory = None
-    ratios: List[float] = []
     for frame in frames:
         if frame["k"] != "device" or not isinstance(frame["d"], dict):
             continue
@@ -202,8 +201,6 @@ def _aggregate_device_frames(frames: List[Dict[str, Any]]) -> Dict[str, Any]:
             last_memory = data
         elif kind == "leak":
             leaks += 1
-        elif kind == "overlap":
-            ratios.append(float(data.get("overlap_ratio", 0.0)))
     if sites:
         out["compiles"] = {
             "total": sum(site["count"] for site in sites.values()),
@@ -216,12 +213,6 @@ def _aggregate_device_frames(frames: List[Dict[str, Any]]) -> Dict[str, Any]:
         out["memory"] = {k: v for k, v in last_memory.items() if k != "kind"}
     if leaks:
         out["leaks_suspected"] = leaks
-    if ratios:
-        out["overlap"] = {
-            "rounds": len(ratios),
-            "last": ratios[-1],
-            "mean": round(sum(ratios) / len(ratios), 4),
-        }
     return out
 
 
@@ -230,8 +221,8 @@ def render_spool_chrome_trace(merged: List[Dict[str, Any]]) -> Dict[str, Any]:
     per peer, finished spans as complete events, still-open spans as instants
     flagged ``in_flight`` — on a dead peer's row, the instant at the end IS
     the crash site. Comm/compute spans land on fixed named lanes per peer
-    (ISSUE 19, mirroring ``tracing.export_chrome_trace``) so the overlap the
-    StepTimeline scores is visible as two stacked rows."""
+    (ISSUE 19, mirroring ``tracing.export_chrome_trace``) so a round hidden
+    behind steps shows as two stacked rows."""
     from hivemind_tpu.telemetry.device import span_lane
 
     lane_tids = {"compute": 1, "comm": 2}

@@ -781,6 +781,24 @@ def run_serving_churn(
         except Exception as e:
             errors.append(f"traffic thread: {e!r}")
 
+    def phase_lasts(seconds: float, until) -> None:
+        """A phase lasts its seconds of the schedule and then, on a host too slow for
+        them, until what the phase exists to produce has been counted (at most the
+        duration again): the verdict reads counts, so the schedule waits for them."""
+        time.sleep(seconds)
+        deadline = time.monotonic() + duration
+        while not until() and time.monotonic() < deadline:
+            time.sleep(0.1)
+
+    def hedges_fired_so_far() -> float:
+        return hedge_counts().get("fired", 0) - hedges_before.get("fired", 0)
+
+    def tripped_against_the_living() -> List[str]:
+        return [
+            str(key) for key in EXPERT_BREAKERS.tripped_keys()
+            if not any(dead in str(key) for dead in dead_peer_ids)
+        ]
+
     traffic = threading.Thread(target=run_traffic)
     traffic.start()
     restarted_server = restarted_dht = None
@@ -788,7 +806,8 @@ def run_serving_churn(
     # cleans up one pair and leaves the other dangling, like the crash it is)
     survivor_server, survivor_dht = server_a, dht_a
     try:
-        time.sleep(duration * stall_fraction)
+        # enough answered requests for every expert's latency window to hold a p95
+        phase_lasts(duration * stall_fraction, lambda: stats["ok"] >= 10 * n_experts)
         # the client's routing turns deterministic once scorecards warm
         # (measured replicas sort by mean latency), so by now traffic has
         # concentrated on ONE replica — the victim must be THAT replica, or
@@ -816,7 +835,7 @@ def run_serving_churn(
             victim_server.runtime._task.cancel()
 
         victim_server._runner.run_coroutine(_stall(), return_future=True).result(5)
-        time.sleep(duration * (kill_fraction - stall_fraction))
+        phase_lasts(duration * (kill_fraction - stall_fraction), lambda: hedges_fired_so_far() >= 1)
 
         # phase 2: crash-kill the victim (transport yanked, no clean shutdown —
         # its declarations dangle in the DHT like a real dead process's)
@@ -838,26 +857,32 @@ def run_serving_churn(
         )
         time.sleep(2.0)
         phase["name"] = "restarted"
-        time.sleep(max(duration * (1.0 - restart_fraction) - 2.0, 5.0))
 
-        infos = get_experts(client_dht, uids)
-        live_peers = {
-            replica.peer_id.to_base58()
-            for info in infos if info is not None
-            for replica in info.replica_set
-        }
-        report["resolved_replicas"] = sorted(live_peers)
-        restarted_visible = str(restarted_dht.peer_id) in live_peers
+        def live_peers() -> set:
+            return {
+                replica.peer_id.to_base58()
+                for info in get_experts(client_dht, uids) if info is not None
+                for replica in info.replica_set
+            }
+
+        phase_lasts(
+            max(duration * (1.0 - restart_fraction) - 2.0, 5.0),
+            lambda: (
+                stats["post_restart_ok"] > 0
+                and not tripped_against_the_living()
+                and str(restarted_dht.peer_id) in live_peers()
+            ),
+        )
+        resolved = live_peers()
+        report["resolved_replicas"] = sorted(resolved)
+        restarted_visible = str(restarted_dht.peer_id) in resolved
     finally:
         stop_event.set()
         traffic.join(timeout=30)
 
         hedges_after = hedge_counts()
-        hedges_fired = hedges_after.get("fired", 0) - hedges_before.get("fired", 0)
-        tripped = [
-            str(key) for key in EXPERT_BREAKERS.tripped_keys()
-            if not any(dead in str(key) for dead in dead_peer_ids)
-        ]
+        hedges_fired = hedges_fired_so_far()
+        tripped = tripped_against_the_living()
 
         for component in (survivor_server, restarted_server):
             if component is not None:

@@ -16,10 +16,9 @@ standing questions without Prometheus or Perfetto:
   session occupancy), degraded client-side scorecards, and the slowest-request
   exemplars with their queue/assembly/compute/serialize decomposition;
 - **device board** (``--device``, ISSUE 19) — per-peer jit compiles (count,
-  storms, compile-seconds), HBM residency (live/peak bytes, buffer count),
-  host<->device transfer totals, and the comm/compute overlap efficiency from
-  the step timeline, plus the swarm's hottest compile sites. Recompile storms
-  and suspected HBM leaks surface as alerts.
+  storms, compile-seconds), HBM residency (live/peak bytes, buffer count)
+  and host<->device transfer totals, plus the swarm's hottest compile sites.
+  Recompile storms and suspected HBM leaks surface as alerts.
 
 Everything renders from the DHT-published snapshots (`--key` must match the
 swarm's ``TelemetryPublisher`` key), so the dashboard is a pure *reader*: it
@@ -331,10 +330,10 @@ def render_device_board(records: Dict[str, Dict[str, Any]], *, ansi: bool = True
     red = _RED if ansi else ""
     reset = _RESET if ansi else ""
 
-    lines: List[str] = [f"{bold}device board{reset} — jit compiles / HBM / transfers / overlap"]
+    lines: List[str] = [f"{bold}device board{reset} — jit compiles / HBM / transfers"]
     header = (
         f"{'peer':<18} {'compiles':>8} {'storms':>6} {'jit s':>7} {'HBM MiB':>8} "
-        f"{'peak MiB':>9} {'bufs':>5} {'h2d MiB':>8} {'d2h MiB':>8} {'ovl %':>6}"
+        f"{'peak MiB':>9} {'bufs':>5} {'h2d MiB':>8} {'d2h MiB':>8}"
     )
     lines.append(bold + header + reset)
     rows: List[str] = []
@@ -358,8 +357,6 @@ def render_device_board(records: Dict[str, Dict[str, Any]], *, ansi: bool = True
                 default=None,
             )
             transfers = device.get("transfer_bytes") or {}
-            overlap = device.get("overlap") or {}
-            mean_overlap = overlap.get("mean")
 
             storm_field = f"{storms:>6}"
             rows.append(
@@ -369,8 +366,7 @@ def render_device_board(records: Dict[str, Dict[str, Any]], *, ansi: bool = True
                 f"{(_mib(peak) if peak is not None else '-'):>9} "
                 f"{(memory.get('buffers') if memory.get('buffers') is not None else '-'):>5} "
                 f"{_mib(transfers.get('host_to_device')):>8} "
-                f"{_mib(transfers.get('device_to_host')):>8} "
-                f"{(f'{mean_overlap * 100:.1f}' if mean_overlap is not None else '-'):>6}"
+                f"{_mib(transfers.get('device_to_host')):>8}"
             )
             for site, stats in (compiles.get("sites") or {}).items():
                 entry = site_board.setdefault(str(site), [0, 0.0])
@@ -428,8 +424,7 @@ def main() -> None:
                              "saturation, scorecards, slowest-request exemplars")
     parser.add_argument("--device", action="store_true",
                         help="append the device board: jit compiles/storms, HBM "
-                             "live/peak bytes, host<->device transfer totals, "
-                             "comm/compute overlap efficiency")
+                             "live/peak bytes, host<->device transfer totals")
     parser.add_argument("--from-spool", nargs="+", default=None, dest="from_spool",
                         metavar="DIR",
                         help="replay mode for dead swarms: render one frame from "

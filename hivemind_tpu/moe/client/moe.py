@@ -97,10 +97,6 @@ class RemoteMixtureOfExperts:
         assert uid.startswith(prefix), (uid, prefix)
         return [int(c) for c in uid[len(prefix):].split(".")]
 
-    def _expert_logit(self, grid_scores: List[jax.Array], sample: int, uid: str) -> jax.Array:
-        coords = self._uid_coords(uid)
-        return sum(grid_scores[d][sample, c] for d, c in enumerate(coords))
-
     def __call__(self, x: jax.Array, proj: Optional[jax.Array] = None) -> jax.Array:
         """x: [batch, in_features]. Returns the expert mixture [batch, out_features].
         Eager-mode API (expert selection is data-dependent host orchestration)."""

@@ -50,7 +50,7 @@ class TransformerExpert(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from hivemind_tpu.ops.pallas_attention import attention_auto
+        from hivemind_tpu.ops.attention import attention_auto
 
         batch, seq, hid = x.shape
         head_dim = hid // self.num_heads
@@ -99,7 +99,7 @@ def _decode_attention(q, k_new, v_new, cache_k, cache_v, index, groups: int = 1)
     if isinstance(cache_k, (tuple, list)):
         return _each_row_apart(lambda *row, index: _decode_attention_row(*row, index[0], groups=groups),
                                q, k_new, v_new, cache_k, cache_v, index)
-    from hivemind_tpu.parallel.ring_attention import plain_attention
+    from hivemind_tpu.ops.attention import plain_attention
 
     batch, new_len = q.shape[0], q.shape[1]
     max_len = cache_k.shape[1]
@@ -150,7 +150,7 @@ class CausalTransformerExpert(nn.Module):
 
     @nn.compact
     def __call__(self, x, cache_k=None, cache_v=None, index=None):
-        from hivemind_tpu.ops.pallas_attention import attention_auto
+        from hivemind_tpu.ops.attention import attention_auto
 
         batch, seq, hid = x.shape
         head_dim = hid // self.num_heads
@@ -519,7 +519,7 @@ class ExaoneMoeBlockExpert(nn.Module):
         return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
 
     def _attention_half(self, x, cache_k, cache_v, index, length):
-        from hivemind_tpu.ops.pallas_attention import attention_auto
+        from hivemind_tpu.ops.attention import attention_auto
 
         batch, seq, _hid = x.shape
         heads, kv_heads, dim = self.num_heads, self.num_kv_heads, self.head_dim

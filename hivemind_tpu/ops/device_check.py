@@ -61,8 +61,8 @@ def _require(condition: bool, message: str) -> None:
 def check_flash_attention(shape: AttentionShape, interpret: bool) -> Dict[str, float]:
     """Flash forward, dQ, dK and dV in bf16 against `plain_attention` in float32.
     Returns the max error of each, relative to the reference's largest value."""
+    from hivemind_tpu.ops.attention import plain_attention
     from hivemind_tpu.ops.pallas_attention import flash_attention
-    from hivemind_tpu.parallel.ring_attention import plain_attention
 
     rng = np.random.RandomState(0)
     dims = (shape.batch, shape.seq, shape.heads, shape.head_dim)

@@ -3,7 +3,7 @@
 The reference has no attention kernel at all (its device math is plain torch ops;
 SURVEY §2.0); attention here is the TPU-first capability layer's hot op: MoE
 transformer/causal/llama experts and the flagship model all funnel through one
-attention core (`parallel/ring_attention.plain_attention`). This kernel fuses the
+attention core (`ops/attention.py`). This kernel fuses the
 whole softmax(QKᵀ)·V pipeline into VMEM-block passes with ONLINE softmax, so logits
 never round-trip through HBM and VMEM stays O(block_q·block_k) regardless of
 sequence length.
@@ -52,7 +52,7 @@ sweep, dQ in a whole-row float32 scratch over both sweeps. Probabilities are
 recomputed per tile from the saved log-sum-exp (`p = exp(s − lse)`, no max carry
 needed), once for all three gradients, so score matrices never materialize in HBM
 in either direction. On non-TPU backends the kernels run in interpret mode for the
-test suite; `attention_auto` dispatches per backend."""
+test suite; `ops.attention.attention_auto` dispatches per backend."""
 
 from __future__ import annotations
 
@@ -421,44 +421,3 @@ def _flash_bwd(causal, interpret, residuals, grad_out):
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
-
-
-def _flash_enabled() -> bool:
-    import os
-
-    return os.environ.get("HIVEMIND_TPU_FLASH_ATTENTION", "1") == "1"
-
-
-def _flash_forced() -> bool:
-    """HIVEMIND_TPU_FORCE_FLASH=1 selects the flash kernels regardless of the
-    CURRENT backend — for AOT workflows (jax.export platforms=["tpu"]) where the
-    trace happens on a CPU host but the artifact targets a TPU."""
-    import os
-
-    return os.environ.get("HIVEMIND_TPU_FORCE_FLASH", "0") == "1"
-
-
-def flash_applies(q, k, mask=None) -> bool:
-    """Whether the fused kernel serves this call: full unmasked sequences on a TPU
-    (or an AOT trace for one). q_len != k_len (cached incremental decode) needs
-    plain_attention's end-aligned causal mask; the kernel assumes square
-    self-attention."""
-    return (
-        mask is None
-        and q.shape[1] == k.shape[1]
-        and (jax.default_backend() == "tpu" or _flash_forced())
-        and _flash_enabled()
-    )
-
-
-def attention_auto(q, k, v, mask=None, causal: bool = False):
-    """Backend dispatch for the attention core on ONE device: fused Pallas kernel
-    where `flash_applies` (both directions are fused kernels — set
-    HIVEMIND_TPU_FLASH_ATTENTION=0 to force the einsum core for A/B runs),
-    reference einsum path elsewhere. Operands sharded over a mesh go through
-    `parallel.ring_attention.mesh_attention_core`, which runs the kernel per shard."""
-    if flash_applies(q, k, mask):
-        return flash_attention(q, k, v, causal)
-    from hivemind_tpu.parallel.ring_attention import plain_attention
-
-    return plain_attention(q, k, v, mask=mask, causal=causal)

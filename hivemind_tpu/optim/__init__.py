@@ -11,4 +11,3 @@ from hivemind_tpu.optim.progress_tracker import (
 )
 from hivemind_tpu.optim.slice_optimizer import SliceOptimizer
 from hivemind_tpu.optim.state_averager import TrainingStateAverager
-from hivemind_tpu.optim.training_averager import TrainingAverager

@@ -43,7 +43,6 @@ from hivemind_tpu.optim.progress_tracker import ProgressTracker
 from hivemind_tpu.optim.recovery import LocalCheckpointStore, restore_from_local
 from hivemind_tpu.optim.state_averager import TrainingStateAverager
 from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
-from hivemind_tpu.telemetry.device import STEP_TIMELINE as _STEP_TIMELINE
 from hivemind_tpu.telemetry.ledger import LEDGER as _LEDGER
 from hivemind_tpu.telemetry.ledger import EpochPhases as _EpochPhases
 from hivemind_tpu.telemetry.tracing import trace as _tracing_span
@@ -311,9 +310,8 @@ class Optimizer(ChronicFailureTracking):
         (reference use_local_updates, optimizer.py:143-145)."""
         assert self.state_averager is not None
         if grads is not None:
-            # the compute lane of the step timeline (ISSUE 19): a background
-            # state-averaging round overlapping these spans is the overlap
-            # efficiency being measured
+            # the compute lane of the Perfetto export: a background state round
+            # shows beside these spans on the comm lane
             with _sync_span("optimizer.update", peer=str(self.dht.peer_id)):
                 self.state_averager.apply_optimizer_step(grads)
         new_samples = self.tracker.local_progress.samples_accumulated + batch_size
@@ -377,9 +375,6 @@ class Optimizer(ChronicFailureTracking):
 
         averaged_ok: Optional[bool] = None  # None = no round attempted (solo swarm)
         phases = _EpochPhases(peer=str(self.dht.peer_id), epoch=next_epoch)
-        # step timeline (ISSUE 19): grads are ready HERE; everything between
-        # this mark and the update landing is communication to hide
-        _STEP_TIMELINE.note_grad_ready(str(self.dht.peer_id))
         with phases.phase("grad_round"):
             if self.tracker.global_progress.num_peers > 1:
                 averaged_ok = False
@@ -508,7 +503,6 @@ class Optimizer(ChronicFailureTracking):
         # into the in-flight round (shared buffers hold this epoch's local average,
         # which doubles as the fallback if swarm averaging fails)
         self.grad_averager.load_accumulators_into_averager_()
-        _STEP_TIMELINE.note_grad_ready(str(self.dht.peer_id))
         # weight 0 is correct for a peer with nothing accumulated: its zero buffers
         # must not dilute the group average (matches the synchronous path)
         weight = float(self.grad_averager.local_samples_accumulated)

@@ -101,7 +101,7 @@ class ServicerBase:
         self, p2p: P2P, wrapper: Optional[object] = None, *, namespace: Optional[str] = None
     ) -> None:
         """Register all rpc_* methods on the given p2p node. ``wrapper`` substitutes the
-        bound target (used by auth wrappers, reference utils/auth.py AuthRPCWrapper)."""
+        bound target (where the reference hangs its AuthRPCWrapper; nothing here passes one)."""
         target = wrapper if wrapper is not None else self
         for spec in type(self)._collect_rpc_specs():
             await p2p.add_protobuf_handler(

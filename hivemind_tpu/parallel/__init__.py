@@ -1,3 +1,4 @@
+from hivemind_tpu.ops.attention import plain_attention
 from hivemind_tpu.parallel.ici import MeshTensorBridge
 from hivemind_tpu.parallel.mesh import (
     batch_sharding,
@@ -6,4 +7,4 @@ from hivemind_tpu.parallel.mesh import (
     params_shardings,
     replicated,
 )
-from hivemind_tpu.parallel.ring_attention import plain_attention, ring_attention
+from hivemind_tpu.parallel.ring_attention import ring_attention

@@ -9,11 +9,12 @@ materializes on one chip. Designed for use inside shard_map over a Mesh axis."""
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from hivemind_tpu.ops.attention import attention_auto, flash_applies
 
 _NEG_INF = -1e30  # matches ops/pallas_attention: finite, so lse merges stay NaN-free
 
@@ -157,8 +158,6 @@ def mesh_attention_core(mesh, q, k, v, mask=None, causal: bool = False):
     the einsum core is selected, `attention_auto` decides (XLA partitions the
     einsum core by itself). ``mask`` (key-validity) is only supported off the
     ring: ring shards carry full sequences."""
-    from hivemind_tpu.ops.pallas_attention import attention_auto, flash_applies, flash_attention
-
     ring = mesh is not None and mesh.shape.get("sp", 1) > 1
     flash = flash_applies(q, k, mask)
     if ring:
@@ -172,6 +171,7 @@ def mesh_attention_core(mesh, q, k, v, mask=None, causal: bool = False):
         else:
             inner = partial(ring_attention, axis_name="sp", causal=causal)
     elif mesh is not None and mesh.size > 1 and flash:
+        from hivemind_tpu.ops.pallas_attention import flash_attention
 
         def inner(q, k, v):
             return flash_attention(q, k, v, causal)
@@ -194,31 +194,3 @@ def mesh_attention_core(mesh, q, k, v, mask=None, causal: bool = False):
         inner, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=not flash
     )
     return core(q, k, v)
-
-
-def plain_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    mask: Optional[jax.Array] = None,
-    causal: bool = False,
-) -> jax.Array:
-    """Single-device attention core with the same [B, T, H, D] convention.
-
-    :param mask: optional [B, T] key-validity mask
-    :param causal: lower-triangular masking (decoder blocks); position t attends
-        only to positions <= t, so right-padding never leaks into real positions
-    """
-    scale = q.shape[-1] ** -0.5
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    neg = jnp.finfo(scores.dtype).min
-    if mask is not None:
-        scores = jnp.where(mask[:, None, None, :], scores, neg)
-    if causal:
-        # offset so queries align to the END of the key sequence: incremental
-        # decode (q_len=1 vs cached k_len) sees all past keys, not just key 0
-        q_len, k_len = scores.shape[-2], scores.shape[-1]
-        tri = jnp.tril(jnp.ones((q_len, k_len), bool), k=k_len - q_len)
-        scores = jnp.where(tri[None, None], scores, neg)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

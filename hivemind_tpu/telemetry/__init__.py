@@ -29,8 +29,7 @@ distributed tracing with a per-process flight recorder.
 - :mod:`~hivemind_tpu.telemetry.device` — device-side observability
   (ISSUE 19): the jit compile tracker + recompile-storm detector, device
   memory/leak/transfer telemetry sampled by the watchdog tick, and the
-  StepTimeline's comm/compute overlap-efficiency scoring (ROADMAP item 2's
-  yardstick).
+  compute / comm lanes of the Perfetto exports.
 
 See docs/observability.md for the metric catalog and the span catalog.
 """
@@ -38,10 +37,8 @@ See docs/observability.md for the metric catalog and the span catalog.
 from hivemind_tpu.telemetry.device import (
     COMPILE_TRACKER,
     MEMORY_MONITOR,
-    STEP_TIMELINE,
     DeviceMemoryMonitor,
     JitCompileTracker,
-    StepTimeline,
     add_device_listener,
     arm_device_telemetry,
     device_snapshot,
@@ -109,10 +106,8 @@ __all__ = [
     "RECORDER",
     "COMPILE_TRACKER",
     "MEMORY_MONITOR",
-    "STEP_TIMELINE",
     "JitCompileTracker",
     "DeviceMemoryMonitor",
-    "StepTimeline",
     "add_device_listener",
     "remove_device_listener",
     "arm_device_telemetry",

@@ -21,7 +21,7 @@ Spool format — bounded, segment-rotated, torn-tail tolerant:
   model), ``span`` (finished), ``span_start`` (open — the only way a victim's
   last operation reaches disk), ``ledger_round``, ``ledger_epoch``,
   ``serving``, ``metrics``, ``device`` (ISSUE 19: compile / recompile-storm /
-  device-memory / leak / overlap events — device telemetry is process-scoped,
+  device-memory / leak events — device telemetry is process-scoped,
   so these frames bypass ``peer_filter`` and land in every co-resident box);
 - retention is a segment-count cap: the oldest ``.seg`` is deleted when the
   cap is exceeded, so a spool is O(retention × segment_bytes) forever.
@@ -371,20 +371,13 @@ class BlackBox:
     def _on_device_record(self, kind: str, record: Dict[str, Any]) -> None:
         # memory samples arrive on every watchdog tick — throttle them so a
         # long-lived box doesn't rotate its whole retention on gauge chatter;
-        # the rare kinds (compile/storm/leak/overlap) always spool
+        # the rare kinds (compile/storm/leak) always spool
         if kind == "memory":
             now = time.monotonic()
             if now - self._last_device_memory_frame < 5.0:
                 return
             self._last_device_memory_frame = now
-        frame = dict(record)
-        # overlap records carry their comm span's name under "kind" — keep it
-        # as "span" so the frame's own kind discriminator survives the merge
-        inner = frame.pop("kind", None)
-        if inner is not None:
-            frame["span"] = inner
-        frame["kind"] = kind
-        self.writer.append("device", frame)
+        self.writer.append("device", {**record, "kind": kind})
 
     def _metrics_loop(self, interval: float) -> None:
         while not self._stop.wait(interval):

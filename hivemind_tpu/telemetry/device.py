@@ -3,8 +3,7 @@ ledger/watchdog stack.
 
 Every telemetry layer before this one watches the *host* — asyncio loops, wire
 bytes, span trees — while the device was a black box: nothing counted jit
-recompiles, live HBM, host↔device transfer cost, or whether the averaging round
-actually overlaps compute. Three instruments fix that:
+recompiles, live HBM or host↔device transfer cost. Two instruments fix that:
 
 - :class:`JitCompileTracker` — fed by :func:`~hivemind_tpu.utils.profiling.tracked_jit`
   wrappers around every hot jit entry point (and by ``jax.monitoring`` compile
@@ -17,19 +16,12 @@ actually overlaps compute. Three instruments fix that:
   by the watchdog tick (never imports jax itself: a process that has not paid
   for a backend must not start paying because telemetry looked). A
   monotonic-growth heuristic flags suspected leaks across averaging rounds.
-- :class:`StepTimeline` — assembled from finished spans: comm wall-time
-  (``allreduce.round``, ``averaging.matchmaking``) intersected with compute
-  intervals (``optimizer.update``, ``device.compute``) yields an **overlap
-  efficiency** scalar — the fraction of comm hidden under compute, the
-  before/after yardstick for ROADMAP item 2. Ratios are stamped onto the
-  RoundLedger's round records and epoch rollups.
 
-Counting (tracked_jit, :func:`record_transfer`, span listeners) is always-on
-and hot-path cheap; :func:`arm_device_telemetry` additionally hooks the
-watchdog memory sampler and the ``jax.monitoring`` listener. Everything
-surfaces through :func:`device_snapshot` (DHT peer snapshot / hivemind-top
-device board) and through device listeners (the black-box spool's ``device``
-frames).
+Counting (tracked_jit, :func:`record_transfer`) is always-on and hot-path
+cheap; :func:`arm_device_telemetry` additionally hooks the watchdog memory
+sampler and the ``jax.monitoring`` listener. Everything surfaces through
+:func:`device_snapshot` (DHT peer snapshot / hivemind-top device board) and
+through device listeners (the black-box spool's ``device`` frames).
 """
 
 from __future__ import annotations
@@ -38,7 +30,7 @@ import math
 import sys
 import threading
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional
 
 from hivemind_tpu.telemetry.registry import REGISTRY
 from hivemind_tpu.telemetry import tracing as _tracing
@@ -92,11 +84,6 @@ _TRANSFER = REGISTRY.counter(
     "(expert batch upload/download, decode KV steps, state averaging mirrors)",
     ("direction",),
 )
-_OVERLAP = REGISTRY.gauge(
-    "hivemind_device_overlap_ratio",
-    "overlap efficiency of the most recent comm round: fraction of its wall "
-    "time hidden under recorded compute intervals (ROADMAP item 2 yardstick)",
-)
 
 # cached children: record_transfer sits on per-batch/per-token paths
 _TRANSFER_H2D = _TRANSFER.labels(direction="host_to_device")
@@ -111,13 +98,13 @@ _D2H = "device_to_host"
 _TRANSFER_BASELINE = {_H2D: 0, _D2H: 0}
 
 # device-record listeners: the black-box spool subscribes here so compile /
-# storm / leak / overlap / memory records survive a crash as ``device`` frames
+# storm / leak / memory records survive a crash as ``device`` frames
 _DEVICE_LISTENERS: List[Callable[[str, Dict[str, Any]], None]] = []
 
 
 def add_device_listener(listener: Callable[[str, Dict[str, Any]], None]) -> None:
     """Subscribe ``listener(kind, record)`` to device telemetry records. Kinds:
-    ``compile`` | ``storm`` | ``memory`` | ``leak`` | ``overlap``."""
+    ``compile`` | ``storm`` | ``memory`` | ``leak``."""
     if listener not in _DEVICE_LISTENERS:
         _DEVICE_LISTENERS.append(listener)
 
@@ -402,13 +389,10 @@ class DeviceMemoryMonitor:
 MEMORY_MONITOR = DeviceMemoryMonitor()
 
 
-# ------------------------------------------------------------------ timeline
+# -------------------------------------------------------------------- lanes
 
-# top-level comm spans only: peer_exchange / local_reduce are CHILDREN of
-# allreduce.round — counting them too would double-count comm wall time
-COMM_SPAN_NAMES = frozenset({"allreduce.round", "averaging.matchmaking", "averaging.aggregate"})
 COMPUTE_SPAN_NAMES = frozenset({"optimizer.update", "device.compute", "moe.forward", "moe.backward"})
-# child spans that still belong on the comm LANE in the Perfetto export
+# allreduce.round, averaging.matchmaking, averaging.aggregate and their children
 _COMM_LANE_PREFIXES = ("allreduce.", "averaging.")
 
 
@@ -417,167 +401,9 @@ def span_lane(name: str) -> Optional[str]:
     lane). Used by the chrome-trace exports to render compute-vs-comm rows."""
     if name in COMPUTE_SPAN_NAMES:
         return "compute"
-    if name in COMM_SPAN_NAMES or name.startswith(_COMM_LANE_PREFIXES):
+    if name.startswith(_COMM_LANE_PREFIXES):
         return "comm"
     return None
-
-
-class StepTimeline:
-    """Comm/compute correlation from finished spans (registered as a span
-    listener at import, like the RoundLedger).
-
-    Compute spans (``optimizer.update``, ``device.compute``, expert
-    forward/backward) append intervals to a bounded per-peer ring. When a
-    top-level comm span finishes, its wall window is intersected with the union
-    of that peer's recorded compute intervals: ``overlap_ratio`` = overlapped
-    seconds / comm seconds — 0.0 when the round ran bare, 1.0 when it hid
-    entirely under compute. Each ratio is stamped onto the RoundLedger (round
-    records + epoch rollups) and pushed to device listeners; ``optimizer.step``
-    spans additionally close per-step records carrying the grad-ready offset."""
-
-    def __init__(self, capacity: int = 256, step_capacity: int = 64):
-        self._lock = threading.Lock()
-        self._compute: Dict[str, deque] = {}  # peer -> deque[(start, end)]
-        self._records: deque = deque(maxlen=capacity)  # comm overlap records
-        self._steps: deque = deque(maxlen=step_capacity)
-        self._grad_ready: Dict[str, float] = {}
-        self._capacity = capacity
-        self._overlap_sum = 0.0
-        self._overlap_count = 0
-
-    # ------------------------------------------------------------ span intake
-
-    def on_span(self, span) -> None:
-        name = span.name
-        if name in COMPUTE_SPAN_NAMES:
-            self._on_compute(span)
-        elif name in COMM_SPAN_NAMES:
-            self._on_comm(span)
-        elif name == "optimizer.step":
-            self._on_step(span)
-
-    def _peer_of(self, span) -> str:
-        attrs = span.attributes or {}
-        return str(attrs.get("peer", ""))
-
-    def _on_compute(self, span) -> None:
-        peer = self._peer_of(span)
-        end = span.end if span.end is not None else _tracing.telemetry_time()
-        with self._lock:
-            ring = self._compute.get(peer)
-            if ring is None:
-                ring = self._compute[peer] = deque(maxlen=self._capacity)
-            ring.append((span.start, end))
-
-    def note_grad_ready(self, peer: str = "") -> None:
-        """Optimizers mark the moment gradients finished accumulating; the next
-        ``optimizer.step`` record carries the offset (backward → comm handoff)."""
-        with self._lock:
-            self._grad_ready[str(peer)] = _tracing.telemetry_time()
-
-    def _on_comm(self, span) -> None:
-        peer = self._peer_of(span)
-        end = span.end if span.end is not None else _tracing.telemetry_time()
-        start, dur = span.start, max(end - span.start, 0.0)
-        with self._lock:
-            intervals = [
-                iv
-                for iv in self._compute.get(peer, ())
-                if iv[1] > start and iv[0] < end
-            ]
-            overlapped = _union_overlap(intervals, start, end)
-            ratio = round(overlapped / dur, 4) if dur > 0 else 0.0
-            record = {
-                "kind": span.name,
-                "peer": peer,
-                "start": round(start, 6),
-                "dur_s": round(dur, 6),
-                "overlap_s": round(overlapped, 6),
-                "overlap_ratio": ratio,
-            }
-            self._records.append(record)
-            self._overlap_sum += ratio
-            self._overlap_count += 1
-        _OVERLAP.set(ratio)
-        if span.name == "allreduce.round":
-            # stamp the ledger lazily: device → ledger is a one-way dependency
-            from hivemind_tpu.telemetry.ledger import LEDGER
-
-            LEDGER.note_overlap(peer, ratio)
-        _notify("overlap", record)
-
-    def _on_step(self, span) -> None:
-        peer = self._peer_of(span)
-        end = span.end if span.end is not None else _tracing.telemetry_time()
-        record = {
-            "peer": peer,
-            "start": round(span.start, 6),
-            "dur_s": round(max(end - span.start, 0.0), 6),
-        }
-        attrs = span.attributes or {}
-        if "epoch" in attrs:
-            record["epoch"] = attrs["epoch"]
-        with self._lock:
-            grad_ready = self._grad_ready.get(peer)
-            if grad_ready is not None and span.start <= grad_ready <= end:
-                record["grad_ready_s"] = round(grad_ready - span.start, 6)
-            self._steps.append(record)
-
-    # ------------------------------------------------------------- inspection
-
-    def overlap_summary(self) -> Dict[str, Any]:
-        with self._lock:
-            if not self._overlap_count:
-                return {"rounds": 0}
-            return {
-                "rounds": self._overlap_count,
-                "last": self._records[-1]["overlap_ratio"] if self._records else None,
-                "mean": round(self._overlap_sum / self._overlap_count, 4),
-            }
-
-    def records(self) -> List[Dict[str, Any]]:
-        with self._lock:
-            return list(self._records)
-
-    def steps(self) -> List[Dict[str, Any]]:
-        with self._lock:
-            return list(self._steps)
-
-    def summary(self) -> Dict[str, Any]:
-        with self._lock:
-            records = list(self._records)[-5:]
-            steps = len(self._steps)
-        out = {"overlap": self.overlap_summary(), "steps": steps}
-        if records:
-            out["recent"] = records
-        return out
-
-    def clear(self) -> None:
-        with self._lock:
-            self._compute.clear()
-            self._records.clear()
-            self._steps.clear()
-            self._grad_ready.clear()
-            self._overlap_sum = 0.0
-            self._overlap_count = 0
-
-
-def _union_overlap(intervals: List[Tuple[float, float]], start: float, end: float) -> float:
-    """Seconds of [start, end] covered by the union of ``intervals``."""
-    total = 0.0
-    cursor = start
-    for iv_start, iv_end in sorted(intervals):
-        lo, hi = max(iv_start, cursor), min(iv_end, end)
-        if hi > lo:
-            total += hi - lo
-            cursor = hi
-        if cursor >= end:
-            break
-    return total
-
-
-STEP_TIMELINE = StepTimeline()
-_tracing.add_span_listener(STEP_TIMELINE.on_span)
 
 
 # ------------------------------------------------------------------ snapshot
@@ -585,9 +411,9 @@ _tracing.add_span_listener(STEP_TIMELINE.on_span)
 
 def device_snapshot() -> Dict[str, Any]:
     """The ``device`` section of the DHT peer snapshot / hivemind-top board:
-    compile totals per site, last memory sample, transfer totals, overlap
-    summary. Empty dict when nothing device-side has happened (lightweight
-    peers publish no device section at all)."""
+    compile totals per site, last memory sample, transfer totals. Empty dict
+    when nothing device-side has happened (lightweight peers publish no device
+    section at all)."""
     out: Dict[str, Any] = {}
     compiles = COMPILE_TRACKER.summary()
     if compiles["total"]:
@@ -600,9 +426,6 @@ def device_snapshot() -> Dict[str, Any]:
     transfers = transfer_totals()
     if any(transfers.values()):
         out["transfer_bytes"] = transfers
-    overlap = STEP_TIMELINE.overlap_summary()
-    if overlap.get("rounds"):
-        out["overlap"] = overlap
     return out
 
 
@@ -623,7 +446,7 @@ def compact_device_snapshot(section: Dict[str, Any]) -> Dict[str, Any]:
             "total_bytes": memory.get("total_bytes"),
             "buffers": memory.get("buffers"),
         }
-    for key in ("leaks_suspected", "transfer_bytes", "overlap"):
+    for key in ("leaks_suspected", "transfer_bytes"):
         if key in section:
             out[key] = section[key]
     return out
@@ -665,8 +488,8 @@ def _install_jax_monitoring() -> None:
 
 def arm_device_telemetry() -> None:
     """Turn on the sampled half of device telemetry: watchdog memory sampling +
-    jax.monitoring compile events. The counting half (tracked_jit, transfers,
-    the span timeline) is always-on. Idempotent."""
+    jax.monitoring compile events. The counting half (tracked_jit, transfers)
+    is always-on. Idempotent."""
     global _ARMED
     from hivemind_tpu.telemetry import watchdog as _watchdog
 
@@ -693,7 +516,6 @@ def reset_device_telemetry() -> None:
     disarm_device_telemetry()
     COMPILE_TRACKER.reset()
     MEMORY_MONITOR.reset()
-    STEP_TIMELINE.clear()
     del _DEVICE_LISTENERS[:]
     _TRANSFER_BASELINE[_H2D] = int(_TRANSFER_H2D.value)
     _TRANSFER_BASELINE[_D2H] = int(_TRANSFER_D2H.value)

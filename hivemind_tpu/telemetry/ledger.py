@@ -371,24 +371,8 @@ class RoundLedger:
     def _peer_epoch_window(self, peer: str) -> Dict[str, Any]:
         return self._epoch_window.setdefault(
             str(peer),
-            {"rounds": 0, "round_s": 0.0, "straggler": None,
-             "overlap_sum": 0.0, "overlap_n": 0},
+            {"rounds": 0, "round_s": 0.0, "straggler": None},
         )
-
-    def note_overlap(self, peer: str, ratio: float) -> None:
-        """Stamp a comm round's overlap efficiency (ISSUE 19: fraction of the
-        round's wall time hidden under compute, computed by the device
-        StepTimeline) onto this peer's newest round record and accrue it into
-        the rolling epoch window, so record_epoch rolls up a per-epoch mean."""
-        ratio = float(ratio)
-        with self._lock:
-            for record in reversed(self._records):
-                if record.get("peer") == str(peer):
-                    record["overlap_efficiency"] = round(ratio, 4)
-                    break
-            window = self._peer_epoch_window(str(peer))
-            window["overlap_sum"] += ratio
-            window["overlap_n"] += 1
 
     def _apply_round_attribution(self, round_id: int, record: Dict[str, Any]) -> None:
         """(Re)derive slowest/spread from ``record['exchanges']`` and move the
@@ -475,7 +459,6 @@ class RoundLedger:
             # consume THIS peer's rolling window only (see _epoch_window)
             window = self._epoch_window.pop(str(peer), None) or {
                 "rounds": 0, "round_s": 0.0, "straggler": None,
-                "overlap_sum": 0.0, "overlap_n": 0,
             }
             entry: Dict[str, Any] = {
                 "epoch": int(epoch),
@@ -484,10 +467,6 @@ class RoundLedger:
                 "rounds": window["rounds"],
                 "round_s": round(window["round_s"], 6),
             }
-            if window.get("overlap_n"):
-                entry["overlap_efficiency"] = round(
-                    window["overlap_sum"] / window["overlap_n"], 4
-                )
             if averaged_ok is not None:
                 entry["averaged_ok"] = bool(averaged_ok)
             if num_peers is not None:

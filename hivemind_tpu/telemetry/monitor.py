@@ -83,8 +83,8 @@ def build_peer_snapshot(
     watchdog = watchdog_summary()
     if watchdog.get("loops"):
         snapshot["watchdog"] = watchdog
-    # device-side observability (ISSUE 19): compile counts / HBM / transfer /
-    # overlap ride the snapshot so hivemind-top's device board renders from ONE
+    # device-side observability (ISSUE 19): compile counts / HBM / transfers
+    # ride the snapshot so hivemind-top's device board renders from ONE
     # DHT read; empty dict when the process never touched an accelerator
     from hivemind_tpu.telemetry.device import device_snapshot
 
@@ -120,7 +120,7 @@ def _shrink_to_fit(snapshot: Dict[str, Any], max_bytes: int = _MAX_SNAPSHOT_BYTE
         if len(MSGPackSerializer.dumps(candidate)) <= max_bytes:
             return candidate
         snapshot = candidate
-    # device section shrinks before it drops: headline compile/HBM/overlap
+    # device section shrinks before it drops: headline compile/HBM/transfer
     # numbers survive as a compact dict, per-site/per-device detail goes
     device = snapshot.get("device")
     if isinstance(device, dict) and device:

@@ -636,8 +636,8 @@ def render_chrome_trace(
     pid/tid mapping: each distinct ``peer`` span attribute becomes one pid row
     (named via ``process_name`` metadata); tids are the recording threads,
     EXCEPT comm/compute spans (ISSUE 19): those land on two fixed named lanes
-    per peer — ``compute`` (tid 1) and ``comm`` (tid 2) — so the overlap the
-    StepTimeline scores is visible as two stacked rows in Perfetto. Span
+    per peer — ``compute`` (tid 1) and ``comm`` (tid 2) — so a round hidden
+    behind steps shows as two stacked rows in Perfetto. Span
     events render as instant events on the same row, and every event carries
     its trace/span/parent ids in ``args`` so traces remain greppable."""
     spans = RECORDER.snapshot() if spans is None else list(spans)

@@ -1,11 +1,10 @@
 """Device-side observability (ISSUE 19): compile tracking via tracked_jit and
-recompile storms, watchdog-sampled device memory, the comm/compute step
-timeline with overlap efficiency, snapshot/spool integration, and the
-hivemind-top device board."""
+recompile storms, watchdog-sampled device memory, the compute / comm lanes of
+the Perfetto export, snapshot/spool integration, and the hivemind-top device
+board."""
 
 import threading
 import time
-from types import SimpleNamespace
 
 import numpy as np
 import optax
@@ -20,7 +19,6 @@ from hivemind_tpu.telemetry.blackbox import BlackBox
 from hivemind_tpu.telemetry.device import (
     COMPILE_TRACKER,
     MEMORY_MONITOR,
-    STEP_TIMELINE,
     JitCompileTracker,
     add_device_listener,
     arm_device_telemetry,
@@ -32,10 +30,10 @@ from hivemind_tpu.telemetry.device import (
     reset_device_telemetry,
     span_lane,
     transfer_totals,
-    _union_overlap,
 )
 from hivemind_tpu.telemetry.ledger import LEDGER
 from hivemind_tpu.telemetry.monitor import _shrink_to_fit
+from hivemind_tpu.telemetry.tracing import render_chrome_trace
 from hivemind_tpu.utils.profiling import tracked_jit
 from hivemind_tpu.utils.serializer import MSGPackSerializer
 
@@ -169,52 +167,7 @@ def test_record_transfer_accounts_both_directions():
         record_transfer(1, "sideways")
 
 
-# ------------------------------------------------------------- step timeline
-
-
-def _span(name, start, end, peer="p0", **attrs):
-    return SimpleNamespace(
-        name=name, start=start, end=end, attributes={"peer": peer, **attrs}
-    )
-
-
-def test_union_overlap_merges_overlapping_intervals():
-    assert _union_overlap([(0.0, 4.0), (2.0, 6.0)], 0.0, 10.0) == pytest.approx(6.0)
-    assert _union_overlap([(12.0, 14.0)], 0.0, 10.0) == 0.0
-    assert _union_overlap([], 0.0, 10.0) == 0.0
-
-
-def test_overlap_efficiency_on_scripted_spans():
-    timeline = STEP_TIMELINE
-    # compute covers [0, 10]; a fully hidden round and a half-exposed one
-    timeline.on_span(_span("optimizer.update", 0.0, 10.0))
-    timeline.on_span(_span("allreduce.round", 2.0, 6.0))
-    timeline.on_span(_span("allreduce.round", 8.0, 12.0))
-    records = timeline.records()
-    assert [r["overlap_ratio"] for r in records] == [1.0, 0.5]
-    summary = timeline.overlap_summary()
-    assert summary["rounds"] == 2
-    assert summary["mean"] == pytest.approx(0.75)
-    assert summary["last"] == 0.5
-    # allreduce.round ratios stamp the round ledger's overlap rollup
-    assert LEDGER is not None  # stamping is lazy; nothing to assert without records
-
-
-def test_overlap_ignores_other_peers_compute():
-    STEP_TIMELINE.on_span(_span("optimizer.update", 0.0, 10.0, peer="other"))
-    STEP_TIMELINE.on_span(_span("allreduce.round", 2.0, 6.0, peer="victim"))
-    assert STEP_TIMELINE.records()[-1]["overlap_ratio"] == 0.0
-
-
-def test_step_records_carry_the_grad_ready_offset():
-    from hivemind_tpu.telemetry.tracing import telemetry_time
-
-    STEP_TIMELINE.note_grad_ready("p0")
-    now = telemetry_time()
-    STEP_TIMELINE.on_span(_span("optimizer.step", now - 1.0, now + 1.0, epoch=3))
-    steps = STEP_TIMELINE.steps()
-    assert steps[-1]["epoch"] == 3
-    assert 0.0 <= steps[-1]["grad_ready_s"] <= 2.0
+# -------------------------------------------------------------------- lanes
 
 
 def test_span_lane_classification():
@@ -224,11 +177,18 @@ def test_span_lane_classification():
     assert span_lane("dht.store") is None
 
 
-def test_two_peer_round_produces_overlap_records():
-    """One real local-updates run: optimizer.update compute spans + the state
-    averaging round's allreduce.round span land in the timeline, producing
-    overlap records with sane ratios (the benchmark asserts nonzero-ness on
-    its longer, steadier run)."""
+def test_two_peer_round_fills_both_lanes_and_the_records_the_benchmark_reads():
+    """One real local-updates run: the optimizer.update spans land on the compute
+    lane and the state round's spans on the comm lane of the Perfetto export, and
+    the round and epoch records carry the fields the benchmark's ledger metrics
+    read (perf/layer_metrics: state_round_ms, state_round_wait_ms,
+    matchmaking_wait_ms, round_allreduce_ms)."""
+    closed = {"round": [], "epoch": []}
+
+    def on_record(kind, record):
+        closed[kind].append(record)
+
+    LEDGER.add_record_listener(on_record)
     rng = np.random.RandomState(0)
     features = rng.randn(128, 4).astype(np.float32)
     targets = features @ rng.randn(4).astype(np.float32)
@@ -239,7 +199,7 @@ def test_two_peer_round_produces_overlap_records():
     def run_peer(index, dht):
         try:
             opt = Optimizer(
-                dht=dht, run_id="overlap_test", target_batch_size=32,
+                dht=dht, run_id="lanes_test", target_batch_size=32,
                 params={"w": jnp.zeros(4, jnp.float32)}, optimizer=optax.sgd(0.1),
                 batch_size_per_step=16, matchmaking_time=1.0, averaging_timeout=30,
                 average_state_every=1, target_group_size=2, verbose=False,
@@ -270,12 +230,25 @@ def test_two_peer_round_produces_overlap_records():
         t.join(timeout=120)
     try:
         assert not errors, f"peer failures: {errors}"
-        steps = STEP_TIMELINE.steps()
-        assert steps, "optimizer.step spans must close step records"
-        summary = STEP_TIMELINE.overlap_summary()
-        assert summary["rounds"] >= 1, "state averaging rounds must land in the timeline"
-        assert all(0.0 <= r["overlap_ratio"] <= 1.0 for r in STEP_TIMELINE.records())
+        events = render_chrome_trace()["traceEvents"]
+        lanes = {}
+        for event in events:
+            lane = (event.get("args") or {}).get("lane")
+            if lane is not None:
+                lanes.setdefault(lane, set()).add(event["name"])
+        assert "optimizer.update" in lanes["compute"]
+        assert {"allreduce.round", "averaging.matchmaking"} <= lanes["comm"]
+        state_rounds = [r for r in closed["round"] if r.get("purpose") == "state"]
+        assert state_rounds, closed["round"]
+        for record in state_rounds:
+            assert record["group_size"] == 2 and record["total_s"] > 0
+        assert any("matchmaking_wait_s" in record for record in state_rounds)
+        # the epoch that launches a round hands it over; the next one carries its length
+        landed = [r for r in closed["epoch"] if r["state_round_s"] > 0]
+        assert landed, closed["epoch"]
+        assert all(record["state_round_wait_s"] >= 0 for record in landed)
     finally:
+        LEDGER.remove_record_listener(on_record)
         for dht in dhts:
             dht.shutdown()
 
@@ -302,7 +275,6 @@ def _fat_device_section():
             "buffers": 800,
         },
         "transfer_bytes": {"host_to_device": 123456, "device_to_host": 654321},
-        "overlap": {"rounds": 9, "last": 0.8, "mean": 0.7},
     }
 
 
@@ -332,7 +304,7 @@ def test_shrink_to_fit_compacts_then_drops_the_device_section():
     assert shrunk["device"]["compiles"]["total"] == 40
     assert "sites" not in shrunk["device"]["compiles"]
     assert shrunk["device"]["memory"] == {"total_bytes": 8 << 20, "buffers": 800}
-    assert shrunk["device"]["overlap"]["mean"] == 0.7
+    assert shrunk["device"]["transfer_bytes"]["device_to_host"] == 654321
     assert len(MSGPackSerializer.dumps(shrunk)) <= compact_budget
 
     # brutal budget: the device section goes before the core health record
@@ -378,8 +350,9 @@ def test_run_blackbox_aggregates_device_frames_into_postmortem_and_snapshot(tmp_
         box._on_device_record(
             "memory", {"total_bytes": 4096, "buffers": 3, "devices": {}}
         )
-        box._on_device_record("overlap", {"kind": "allreduce.round", "overlap_ratio": 0.6})
         box._on_device_record("storm", {"site": "test.victim_site", "count": 7})
+        # a frame as a peer from before PR 47 spooled it: the readers pass over it
+        box.writer.append("device", {"kind": "overlap", "span": "allreduce.round", "overlap_ratio": 0.6})
     finally:
         box.close()
 
@@ -390,12 +363,15 @@ def test_run_blackbox_aggregates_device_frames_into_postmortem_and_snapshot(tmp_
     assert post["device"]["compiles"]["storms"] == 1
     assert post["device"]["last_compile"]["site"] == "test.victim_site"
     assert post["device"]["memory"]["total_bytes"] == 4096
-    assert post["device"]["overlap"]["last"] == 0.6
+    assert "overlap" not in post["device"]
 
     snapshot = spool_snapshot(spools["p0"])
     assert snapshot["device"]["compiles"]["total"] >= 1
     board = render_device_board({"p0": snapshot}, ansi=False)
     assert "p0" in board and "test.victim_site" in board
+    # ... and so does the board, over such a peer's published section
+    snapshot["device"]["overlap"] = {"rounds": 9, "last": 0.8, "mean": 0.7}
+    assert render_device_board({"p0": snapshot}, ansi=False) == board
 
 
 def test_device_board_renders_live_snapshot_shape():

@@ -3,6 +3,7 @@ round, straggler scoring, the DHT snapshot size budget, the ``GET /ledger``
 round-trip, epoch rollups, and the ``hivemind-top`` / epoch-timeline renders."""
 
 import json
+import threading
 import time
 import urllib.request
 
@@ -47,6 +48,20 @@ def test_record_assembly_from_real_two_peer_round():
     """The global LEDGER assembles records from the spans a REAL two-peer
     all-reduce produces — phases, partner attribution, matchmaking wait."""
     LEDGER.clear()
+    # a round's record closes with its span, on the averager's loop, and an exchange that
+    # was mid-cancellation then is attached to it later: the test waits for the records
+    # it asserts on (counted as they are published), not for a time to have passed
+    published = threading.Event()
+
+    def on_record(kind, record):
+        published.set()
+
+    LEDGER.add_record_listener(on_record)
+
+    def both_rounds_closed_and_one_attributed():
+        records = LEDGER.records()
+        return len({record["peer"] for record in records}) == 2 and any("slowest_peer" in r for r in records)
+
     dhts = launch_dht_swarm(2)
     averagers = []
     for i, dht in enumerate(dhts):
@@ -63,6 +78,9 @@ def test_record_assembly_from_real_two_peer_round():
             control.result(timeout=60)
         with averagers[0].get_tensors() as tensors:
             assert np.allclose(tensors[0], 0.5)
+        while not both_rounds_closed_and_one_attributed():
+            assert published.wait(timeout=60), f"the ledger published no further record: {LEDGER.records()}"
+            published.clear()
         records = LEDGER.records()
         # both peers live in this process: one record per peer's round (an
         # exchange span may still be mid-cancellation when its round closes, so
@@ -85,6 +103,7 @@ def test_record_assembly_from_real_two_peer_round():
         assert set(scores) <= peer_ids and scores
         assert all(score["rounds_slowest"] >= 1 for score in scores.values())
     finally:
+        LEDGER.remove_record_listener(on_record)
         shutdown_all(averagers, dhts)
 
 

@@ -1,5 +1,5 @@
 """PowerSGD averaging (two chained phases, error feedback), GradScaler shim,
-TrainingAverager legacy, math utils."""
+math utils."""
 
 import time
 
@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from hivemind_tpu.dht import DHT
-from hivemind_tpu.optim import GradScaler, PowerSGDGradientAverager, TrainingAverager
+from hivemind_tpu.optim import GradScaler, PowerSGDGradientAverager
 from hivemind_tpu.utils.math_utils import get_flatten_greedy_dims, orthogonalize
 
 from swarm_utils import launch_dht_swarm
@@ -86,45 +86,6 @@ def test_grad_scaler_shim():
     assert called == [1]
     scaler.update()
     assert not scaler.found_inf
-
-
-def test_training_averager_legacy():
-    dhts = launch_dht_swarm(2)
-    try:
-        states = [
-            {"params": [np.full(10, float(i + 1), np.float32)]} for i in range(2)
-        ]
-        averagers = []
-        for i, dht in enumerate(dhts):
-            def getter(i=i):
-                return states[i]["params"]
-
-            def setter(tensors, i=i):
-                states[i]["params"] = tensors
-
-            averagers.append(
-                TrainingAverager(
-                    dht=dht, get_tensors_fn=getter, set_tensors_fn=setter,
-                    prefix="legacy", start=True, target_group_size=2,
-                    min_matchmaking_time=1.0,
-                )
-            )
-        import threading
-
-        threads = [
-            threading.Thread(target=lambda a=a: a.average_step(timeout=40)) for a in averagers
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=60)
-        for i in range(2):
-            assert np.allclose(states[i]["params"][0], 1.5, atol=1e-4)
-        for a in averagers:
-            a.shutdown()
-    finally:
-        for dht in dhts:
-            dht.shutdown()
 
 
 @pytest.mark.slow  # ~30 s; PowerSGD averaging is covered in ~1 s by

@@ -36,13 +36,23 @@ def _toy_problem(seed=0):
     return features, targets, loss_and_grad
 
 
+# The DPU tests train until four epochs have closed: a bound in epochs, with a deadline of
+# its own in steps. A round that a loaded host makes miss its 30 s `averaging_timeout`
+# falls back to local gradients and costs the test those seconds, not its verdict (80
+# steps of 0.25 s, as it was, ended the loop before one such round had).
+_MOST_STEPS = 400
+
+
 def test_dpu_overlapped_convergence():
     """delay_optimizer_step=True: step() must return while an epoch transition is
-    still in flight at least once, training must keep going, and the loss must drop."""
+    still in flight, training must keep going meanwhile, and the loss must drop.
+    The first transition of each peer is held at a gate the test owns until the
+    stepping thread has seen step() return and stepped once more: the order of the
+    two is the test's, not the scheduler's."""
     features, targets, loss_and_grad = _toy_problem()
     dhts = launch_dht_swarm(2)
     results, errors = {}, []
-    overlap_observed = threading.Event()
+    steps_beside_a_transition = [0, 0]
 
     def run_peer(index: int, dht: DHT):
         try:
@@ -55,18 +65,36 @@ def test_dpu_overlapped_convergence():
                 delay_optimizer_step=True,
                 tracker_opts=dict(min_refresh_period=0.3, default_refresh_period=0.5),
             )
+            gate = threading.Event()
+            transition = opt._delayed_epoch_update
+
+            def held_at_the_gate(*args):
+                gate.wait(timeout=60)
+                return transition(*args)
+
+            opt._delayed_epoch_update = held_at_the_gate
             rng_local = np.random.RandomState(index)
             first_loss = last_loss = None
-            for _ in range(80):
-                if opt.local_epoch >= 4:
-                    break
+
+            def one_step():
+                nonlocal first_loss, last_loss
                 idx = rng_local.choice(len(features), 16)
                 loss, grads = loss_and_grad(opt.params, features[idx], targets[idx])
                 first_loss = first_loss if first_loss is not None else float(loss)
                 last_loss = float(loss)
                 opt.step(grads)
-                if opt._pending_update is not None and not opt._pending_update.done():
-                    overlap_observed.set()  # training continued during an in-flight round
+
+            for _ in range(_MOST_STEPS):
+                if opt.local_epoch >= 4:
+                    break
+                one_step()
+                if opt._pending_update is not None and not gate.is_set():
+                    # step() returned with its transition in flight (it is at the gate),
+                    # and training goes on beside it
+                    assert not opt._pending_update.done()
+                    one_step()
+                    steps_beside_a_transition[index] += 1
+                    gate.set()
                 time.sleep(0.25)
             results[index] = (first_loss, last_loss, opt.local_epoch)
             opt.shutdown()
@@ -83,7 +111,7 @@ def test_dpu_overlapped_convergence():
     try:
         assert not errors, f"peer failures: {errors}"
         assert len(results) == 2
-        assert overlap_observed.is_set(), "no step() returned during an in-flight transition"
+        assert steps_beside_a_transition == [1, 1], "a peer never had a transition in flight"
         for index, (first_loss, last_loss, epoch) in results.items():
             assert epoch >= 2, f"peer {index} stuck at epoch {epoch}"
             assert last_loss < first_loss / 5, (
@@ -255,7 +283,7 @@ def test_local_updates_with_delayed_state_averaging():
             )
             rng_local = np.random.RandomState(index)
             first_loss = last_loss = None
-            for _ in range(80):
+            for _ in range(_MOST_STEPS):
                 if opt.local_epoch >= 4:
                     break
                 idx = rng_local.choice(len(features), 16)
@@ -317,7 +345,7 @@ def test_powersgd_with_dpu_convergence():
             )
             rng_local = np.random.RandomState(index)
             first_loss = last_loss = None
-            for _ in range(80):
+            for _ in range(_MOST_STEPS):
                 if opt.local_epoch >= 4:
                     break
                 idx = rng_local.choice(len(features), 16)

@@ -282,6 +282,37 @@ async def test_servicer_namespaces():
     await server.shutdown()
 
 
+async def test_servicer_wrapper_substitutes_the_bound_target():
+    """`add_p2p_handlers(wrapper=...)`: the handlers registered are the wrapper's, under
+    the servicer's names (the hook an authorizing wrapper would hang on)."""
+
+    class Refusing:
+        def __init__(self, servicer):
+            self._servicer = servicer
+
+        def __getattr__(self, name):
+            method = getattr(self._servicer, name)
+
+            async def guarded(request, context):
+                if request.number < 0:
+                    raise PermissionError("refused")
+                return await method(request, context)
+
+            return guarded
+
+    server = await P2P.create()
+    client = await P2P.create()
+    servicer = MathServicer()
+    await servicer.add_p2p_handlers(server, wrapper=Refusing(servicer))
+    await client.connect(server.get_visible_maddrs()[0])
+    stub = MathServicer.get_stub(client, server.peer_id)
+    assert (await stub.rpc_square(test_pb2.TestRequest(number=3))).number == 9
+    with pytest.raises(P2PHandlerError, match="refused"):
+        await stub.rpc_square(test_pb2.TestRequest(number=-3))
+    await client.shutdown()
+    await server.shutdown()
+
+
 async def test_mux_rejects_invalid_open_frames():
     """OPEN frames with local-parity or already-used stream ids must be RESET, not
     silently replace a live stream (ADVICE r1: stream hijack via id collision)."""

@@ -7,7 +7,7 @@ calls that the chain is held to, bit for bit."""
 import asyncio
 import subprocess
 import sys
-import time
+import threading
 from pathlib import Path
 from types import SimpleNamespace
 
@@ -127,20 +127,30 @@ def test_span_backward_equals_per_block_calls_and_steps_every_optimizer_once(ser
     assert np.array_equal(served.forward(list(served.backends), x), _per_block(twins, x))
 
 
+def _spans_of(request):
+    """The spans one request closes, its `pool.batch` among them. The batch hands its
+    callers their results INSIDE its span, so the answer can be here before the span has
+    closed on the runtime's thread: the listener says when it has."""
+    spans, batch_closed = [], threading.Event()
+
+    def listener(span):
+        spans.append(span)
+        if span.name == "pool.batch":
+            batch_closed.set()
+
+    add_span_listener(listener)
+    try:
+        request()
+        assert batch_closed.wait(timeout=60), "the request's pool.batch span never closed"
+    finally:
+        remove_span_listener(listener)
+    return spans
+
+
 def test_span_request_is_one_batch_that_walks_the_chain(served):
     uids = list(served.backends)
     pool_name = f"{uids[0]}..{uids[-1]}_forward"
-    spans = []
-    add_span_listener(spans.append)
-    try:
-        served.forward(uids, np.ones((2, 4, HID), np.float32))
-        # the batch hands its callers their results inside its span: the answer can be
-        # here before the span has closed on the runtime's thread
-        deadline = time.monotonic() + 10.0
-        while not any(s.name == "pool.batch" for s in spans) and time.monotonic() < deadline:
-            time.sleep(0.01)
-    finally:
-        remove_span_listener(spans.append)
+    spans = _spans_of(lambda: served.forward(uids, np.ones((2, 4, HID), np.float32)))
     assert _counter("hivemind_moe_batches_total", pool_name) == 1
     assert _counter("hivemind_moe_pool_blocks_total", pool_name) == len(uids)
     for uid in uids:  # no block's own pool saw anything
@@ -158,12 +168,8 @@ def test_span_request_is_one_batch_that_walks_the_chain(served):
 
 def test_backward_walk_is_a_forward_sweep_then_the_blocks_in_reverse(served):
     uids = list(served.backends)
-    spans = []
-    add_span_listener(spans.append)
-    try:
-        served.backward(uids, np.ones((2, 4, HID), np.float32), np.ones((2, 4, HID), np.float32))
-    finally:
-        remove_span_listener(spans.append)
+    ones = np.ones((2, 4, HID), np.float32)
+    spans = _spans_of(lambda: served.backward(uids, ones, ones))
     [batch] = [s for s in spans if s.name == "pool.batch"]
     assert batch.attributes["pool"] == f"{uids[0]}..{uids[-1]}_backward" and batch.attributes["blocks"] == 3
     walked = [(s.attributes["sweep"], s.attributes["uid"]) for s in spans if s.name == "backend.device"]

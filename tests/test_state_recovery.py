@@ -582,7 +582,7 @@ def store_nonempty(path) -> bool:
     return bool(LocalCheckpointStore(path).checkpoints())
 
 
-def test_epoch_adopted_without_state_is_loud_and_counted(tmp_path):
+def test_epoch_adopted_without_state_is_loud_and_counted(tmp_path, monkeypatch):
     """ISSUE 7 satellite: when the download fails, fast-forwarding the epoch
     number is an emergency, not business as usual — counted and logged at ERROR."""
     import optax
@@ -592,6 +592,7 @@ def test_epoch_adopted_without_state_is_loud_and_counted(tmp_path):
     from hivemind_tpu.dht import DHT
     from hivemind_tpu.optim import Optimizer
     from hivemind_tpu.optim.optimizer import _EPOCH_ADOPTED_WITHOUT_STATE
+    from hivemind_tpu.optim.progress_tracker import ProgressTracker
 
     dht = DHT(start=True)
     opt = Optimizer(
@@ -602,7 +603,10 @@ def test_epoch_adopted_without_state_is_loud_and_counted(tmp_path):
     )
     try:
         opt.state_averager.load_full_state_from_peers = lambda **kwargs: False
-        opt.tracker.global_progress.global_epoch = 5
+        # the swarm's epoch as the tracker reports it: set on the property, because the
+        # tracker's own thread replaces `global_progress` with what it fetched (this lone
+        # peer's epoch 0) every 0.2 s, between a write to it and the catch-up's read
+        monkeypatch.setattr(ProgressTracker, "global_epoch", property(lambda self: 5))
         before = _EPOCH_ADOPTED_WITHOUT_STATE.value()
         opt._catch_up_with_swarm()
         assert opt.local_epoch == 5, "the epoch number is still adopted (anti-livelock)"
