@@ -214,6 +214,7 @@ def run_soak(
     slots: Dict[int, _TrainerSlot] = {index: _TrainerSlot(index, dht) for index, dht in enumerate(dhts)}
     dead_peer_ids: List[str] = []  # breakers for these ids legitimately stay open
     retired_threads: List[threading.Thread] = []  # crash-killed trainers, still joined at exit
+    killed_slots: List[_TrainerSlot] = []  # their optimizers are reaped once the verdict's view is read
     victim_spools: List[Dict[str, object]] = []  # abandoned spool dirs, one per kill
 
     features, targets, loss_and_grad = _toy_problem(seed)
@@ -384,6 +385,7 @@ def run_soak(
                 time.sleep(0.05)
             logger.warning(f"churn: crash-killing trainer {index}")
             slot.kill.set()
+            killed_slots.append(slot)
             victim_peer_id = None
             try:
                 victim_peer_id = str(slot.dht.peer_id)  # unreadable once shut down
@@ -525,8 +527,24 @@ def run_soak(
         finally:
             stop_event.set()
             live_threads = [slot.thread for slot in slots.values() if slot.thread is not None]
+            # ONE minute for all of them: each is inside at most its last round
+            # (an averaging_timeout for the step, another for the state round its
+            # shutdown lets land), and they spend it side by side
+            join_deadline = time.monotonic() + 60
             for thread in threads + live_threads + retired_threads:
-                thread.join(timeout=60)
+                thread.join(timeout=max(0.0, join_deadline - time.monotonic()))
+            # the crash held while the verdict was being earned; now the corpse
+            # leaves the process. Its DHT is down, so this is the local half of a
+            # shutdown: the tracker's and the averagers' tasks on the shared loop,
+            # the background worker. A dead machine writes no last checkpoint, and
+            # what the dead transport raises is swallowed here and nowhere else.
+            for slot in killed_slots:
+                if slot.opt is not None:
+                    slot.opt.checkpoint_store = None
+                    try:
+                        slot.opt.shutdown()
+                    except Exception as e:
+                        logger.debug(f"reaping crash-killed trainer {slot.index}: {e!r}")
             if server is not None:
                 server.shutdown()
             for slot in slots.values():
@@ -884,7 +902,9 @@ def run_serving_churn(
         hedges_fired = hedges_fired_so_far()
         tripped = tripped_against_the_living()
 
-        for component in (survivor_server, restarted_server):
+        # the victim's server too: its DHT is down, so this is the local half (its
+        # declare loop, runtime and handlers would otherwise outlive the soak)
+        for component in (server_a, server_b, restarted_server):
             if component is not None:
                 component.shutdown()
         for component in (survivor_dht, restarted_dht, client_dht):

@@ -17,12 +17,54 @@ jax.config.update("jax_platforms", "cpu")
 jax.devices()
 
 import asyncio  # noqa: E402
+import contextlib  # noqa: E402
+import faulthandler  # noqa: E402
 import gc  # noqa: E402
 import inspect  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
 import threading  # noqa: E402
 import time  # noqa: E402
 
 import pytest  # noqa: E402
+from swarm_utils import start_relay_daemon, stop_process  # noqa: E402
+
+# The one bound on a test's set-up, its call and its tear-down: 2.4 times the longest
+# test of a whole run under six workers (125 s, ISSUE 48), and a healthy run plus one
+# test that runs into it still ends inside the driver's 1,470 s.
+TEST_LIMIT_S = 300.0
+
+
+@contextlib.contextmanager
+def time_limit(seconds: float, what: str):
+    """Fail ``what`` by name when the block outlasts ``seconds``: a timer of the main
+    thread (pytest and xdist run tests there, and only there) whose handler writes every
+    thread's stack to stderr and raises, which interrupts ``result()``, ``join()``,
+    ``communicate()``, ``readline()`` and ``run_until_complete()`` alike. It fires again
+    every tenth of the bound, for a wait that swallowed the first raise. Nests."""
+
+    def on_alarm(_signum, _frame):
+        faulthandler.dump_traceback(file=sys.__stderr__, all_threads=True)
+        pytest.fail(f"{what} was still running after the {seconds:g} s a test is held to")
+
+    previous_handler = signal.signal(signal.SIGALRM, on_alarm)
+    previous_timer = signal.setitimer(signal.ITIMER_REAL, seconds, seconds / 10)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, *previous_timer)
+        signal.signal(signal.SIGALRM, previous_handler)
+
+
+@pytest.hookimpl(hookwrapper=True)
+def _limited(item):
+    with time_limit(TEST_LIMIT_S, item.nodeid):
+        yield
+
+
+# each phase on its own (pytest's report names it); a module's or the session's fixtures
+# are set up inside the first test that asks for them, so they are bounded with it
+pytest_runtest_setup = pytest_runtest_call = pytest_runtest_teardown = _limited
 
 
 def _run_async_test(func, kwargs, allow_task_leaks: bool) -> None:
@@ -51,11 +93,16 @@ def _run_async_test(func, kwargs, allow_task_leaks: bool) -> None:
                 # reap them even when allowed, so nothing pollutes the next test
                 await asyncio.wait(leaked, timeout=3.0)
 
+    main = loop.create_task(_main())
     try:
-        loop.run_until_complete(_main())
+        loop.run_until_complete(main)
         loop.run_until_complete(loop.shutdown_asyncgens())
         loop.run_until_complete(loop.shutdown_default_executor())
     finally:
+        if not main.done():  # cut by the test's time limit: let its finally blocks run
+            main.cancel()
+            with contextlib.suppress(BaseException):
+                loop.run_until_complete(main)
         asyncio.set_event_loop(None)
         loop.close()
     # a failed task that was never awaited reports "exception was never
@@ -154,8 +201,19 @@ def cleanup_children(request):
             )
 
 
-@pytest.fixture
-def event_loop():
-    loop = asyncio.new_event_loop()
-    yield loop
-    loop.close()
+@pytest.fixture(scope="session")
+def relay_daemon():
+    """One relay daemon a worker: ``.port`` and ``.pubkey_hex`` (and ``.process``)."""
+    daemon = start_relay_daemon()
+    yield daemon
+    stop_process(daemon.process)
+
+
+@pytest.fixture(scope="session")
+def relay_daemon_unix(tmp_path_factory):
+    """A daemon ALSO listening on a 0600 AF_UNIX socket — the multi-user-safe
+    trust boundary for the data-plane proxy's 'K' key handoff (advisor r4)."""
+    socket_path = str(tmp_path_factory.mktemp("proxy") / "proxy.sock")
+    daemon = start_relay_daemon("", socket_path)
+    yield socket_path
+    stop_process(daemon.process)

@@ -2,7 +2,20 @@
 tests/test_utils/dht_swarms.py). All tests launch REAL localhost swarms — there is
 no fake network backend, so test and production code paths are identical."""
 
+import codecs
+import contextlib
+import os
+import re
+import selectors
+import socket
+import subprocess
+import sys
+import time
+from pathlib import Path
+from typing import NamedTuple
+
 from hivemind_tpu.dht import DHT
+from hivemind_tpu.p2p.native_transport import build_daemon_binary, read_daemon_banner
 
 
 def launch_dht_swarm(n: int):
@@ -18,3 +31,120 @@ def shutdown_all(components, dhts):
         component.shutdown()
     for dht in dhts:
         dht.shutdown()
+
+
+# -------------------------------------------- child processes, started one bounded way
+
+REPO_ROOT = Path(__file__).resolve().parent.parent
+
+
+def cpu_child_env() -> dict:
+    """Every process of a several-on-one-host run is pinned to the CPU and finds the repo."""
+    paths = [str(REPO_ROOT)] + [p for p in os.environ.get("PYTHONPATH", "").split(os.pathsep) if p]
+    return dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(paths))
+
+
+def stop_process(process: subprocess.Popen) -> None:
+    """Kill a child, if it still runs, and reap it; 10 s without its exit is an error."""
+    process.kill()
+    process.wait(10)
+
+
+def read_child_until(proc, marker: str, timeout: float = 60.0, stream: str = "stdout") -> str:
+    """Accumulate a child's stdout (or stderr) until the regex ``marker`` matches, EOF,
+    or the deadline.
+
+    Reads the RAW non-blocking fd in chunks: selecting on the fd and then calling
+    ``readline()`` silently strands any second line inside the TextIO buffer (the
+    fd shows no data, the selector never fires again) — a hang this helper exists
+    to avoid."""
+    fd = getattr(proc, stream).fileno()
+    os.set_blocking(fd, False)
+    decoder = codecs.getincrementaldecoder("utf-8")("replace")
+    deadline = time.monotonic() + timeout
+    seen = ""
+    with selectors.DefaultSelector() as sel:
+        sel.register(fd, selectors.EVENT_READ)
+        while time.monotonic() < deadline and not re.search(marker, seen):
+            if not sel.select(timeout=1.0):
+                if proc.poll() is not None:
+                    break
+                continue
+            chunk = os.read(fd, 65536)
+            if not chunk:
+                break  # EOF
+            seen += decoder.decode(chunk)
+    return seen
+
+
+def wait_for_children(processes, timeout: float) -> str:
+    """Wait against ONE deadline until every child has exited, or the first has exited
+    non-zero (its partners then wait for nobody). Returns what cut the wait short, or ""."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        codes = [process.poll() for process in processes]
+        failed = [(i, code) for i, code in enumerate(codes) if code]
+        if failed:
+            return "child %d exited %d" % failed[0]
+        if None not in codes:
+            return ""
+        time.sleep(0.1)
+    return f"the deadline of {timeout:g} s passed"
+
+
+class RelayDaemon(NamedTuple):
+    process: subprocess.Popen
+    port: int
+    pubkey_hex: str  # "" from a daemon built without libcrypto
+
+
+def start_relay_daemon(*args: str, banner_timeout: float = 30) -> RelayDaemon:
+    """A relay daemon on a free port, built first if it has to be: the library's own
+    build (serialized by its flock, so workers that all start without the binary are
+    safe) and its bounded banner read. A failed build or a missing banner is an error
+    with its text. ``args`` follow the port: identity file, unix socket path."""
+    binary, error = build_daemon_binary()
+    assert binary is not None, f"relay daemon: {error}"
+    process = subprocess.Popen([str(binary), "0", *args], stdout=subprocess.PIPE)
+    banner = read_daemon_banner(process, banner_timeout)
+    if banner is None:
+        stop_process(process)
+        raise AssertionError(f"relay daemon {binary} printed no banner within {banner_timeout:g} s")
+    listening, identity = banner
+    pubkey_hex = identity.rsplit(" ", 1)[-1] if identity.startswith("relay identity ") else ""
+    return RelayDaemon(process, int(listening.rsplit(" ", 1)[-1]), pubkey_hex)
+
+
+def run_jax_workers(script_text: str, tmp_path: Path, args=(), n: int = 2, timeout: float = 120):
+    """Run ``script_text`` as ``n`` children, each given its index, the ``jax.distributed``
+    coordinator's port and ``args``, against ONE deadline. The first child to exit
+    non-zero, or the deadline, kills the rest: a partner blocked in a collective never
+    outlives the worker it waits for. Returns ``[(returncode, output)]``.
+
+    The probe of the port stays bound (never listening) until the children are gone: the
+    coordinator's bind sets the same two options and is accepted beside it, while no
+    other process of the machine is handed that port."""
+    script = tmp_path / "worker.py"
+    script.write_text(script_text)
+    with contextlib.ExitStack() as stack:
+        probe = stack.enter_context(socket.socket())
+        probe.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+        probe.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEPORT, 1)
+        probe.bind(("127.0.0.1", 0))
+        port = str(probe.getsockname()[1])
+        # output goes to files, not pipes: nothing has to be read while the children run
+        logs = [stack.enter_context(open(tmp_path / f"worker{i}.log", "w+")) for i in range(n)]
+        workers = []
+        stack.callback(lambda: [stop_process(worker) for worker in workers])
+        for log in logs:
+            command = [sys.executable, str(script), str(len(workers)), port, *args]
+            workers.append(subprocess.Popen(command, stdout=log, stderr=subprocess.STDOUT, env=cpu_child_env()))
+        verdict = wait_for_children(workers, timeout)
+        results = []
+        for worker, log in zip(workers, logs):
+            if worker.poll() is None:
+                stop_process(worker)
+                log.write(f"\n[killed: {verdict}]")  # after the child's last byte: one offset
+            log.seek(0)
+            results.append((worker.returncode, log.read()))
+        return results

@@ -5,35 +5,17 @@ registers there, publishes its circuits — and a public peer dials it purely by
 peer id through the installed resolver."""
 
 import asyncio
-import subprocess
-from pathlib import Path
 
-import pytest
+from swarm_utils import start_relay_daemon, stop_process
 
 from hivemind_tpu.dht import DHT
 from hivemind_tpu.p2p import P2P, AutoRelay, P2PContext, advertise_relay
 from hivemind_tpu.p2p.autorelay import RELAY_DHT_KEY, RELAYED_PEER_PREFIX
 from hivemind_tpu.proto import test_pb2
 
-NATIVE_DIR = Path(__file__).parent.parent / "hivemind_tpu" / "native"
-RELAY_BIN = NATIVE_DIR / "relay_daemon"
-
-
-@pytest.fixture(scope="module")
-def relay_daemon():
-    if not RELAY_BIN.exists():
-        subprocess.run(["make"], cwd=NATIVE_DIR, check=True, capture_output=True)
-    proc = subprocess.Popen([str(RELAY_BIN), "0"], stdout=subprocess.PIPE, text=True)
-    port = int(proc.stdout.readline().strip().rsplit(" ", 1)[-1])
-    identity_line = proc.stdout.readline().strip()
-    pubkey_hex = identity_line.rsplit(" ", 1)[-1] if "identity" in identity_line else ""
-    yield port, pubkey_hex
-    proc.kill()
-    proc.wait()
-
 
 def test_advertise_and_parse_relay_records(relay_daemon):
-    port, pubkey_hex = relay_daemon
+    _, port, pubkey_hex = relay_daemon
     dht = DHT(start=True)
     try:
         assert advertise_relay(dht, "127.0.0.1", port, pubkey_hex)
@@ -48,7 +30,7 @@ def test_advertise_and_parse_relay_records(relay_daemon):
 
 
 def test_natted_peer_zero_config_becomes_dialable(relay_daemon):
-    port, pubkey_hex = relay_daemon
+    _, port, pubkey_hex = relay_daemon
 
     async def scenario():
         # swarm bootstrap + a PUBLIC peer that serves the AutoNAT dial-back
@@ -116,17 +98,11 @@ def test_maintenance_replaces_dead_relay(relay_daemon, tmp_path):
     pass detects the dropped control line and re-registers at another advertised
     relay, republishing circuits (reference auto-relay keeps peers dialable
     through relay churn)."""
-    import subprocess
-
-    port, pubkey_hex = relay_daemon
+    _, port, pubkey_hex = relay_daemon
 
     async def scenario():
         # a second, short-lived relay the peer will register at FIRST
-        victim = subprocess.Popen(
-            [str(RELAY_BIN), "0"], stdout=subprocess.PIPE, text=True
-        )
-        victim_port = int(victim.stdout.readline().strip().rsplit(" ", 1)[-1])
-        victim_key = victim.stdout.readline().strip().rsplit(" ", 1)[-1]
+        victim, victim_port, victim_key = start_relay_daemon()
         try:
             dht = DHT(start=True)
             assert advertise_relay(dht, "127.0.0.1", victim_port, victim_key)
@@ -135,8 +111,7 @@ def test_maintenance_replaces_dead_relay(relay_daemon, tmp_path):
             assert set(auto.relay_clients) == {("127.0.0.1", victim_port)}
 
             # the registered relay dies; the survivor is advertised in its place
-            victim.kill()
-            victim.wait()
+            stop_process(victim)
             assert advertise_relay(dht, "127.0.0.1", port, pubkey_hex)
 
             deadline = asyncio.get_event_loop().time() + 30
@@ -156,8 +131,6 @@ def test_maintenance_replaces_dead_relay(relay_daemon, tmp_path):
             await natted.shutdown()
             dht.shutdown()
         finally:
-            if victim.poll() is None:
-                victim.kill()
-                victim.wait()
+            stop_process(victim)
 
     asyncio.run(asyncio.wait_for(scenario(), timeout=120))

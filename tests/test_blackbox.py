@@ -57,7 +57,8 @@ def test_rotation_under_concurrent_writers(tmp_path):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(30)
+        assert not t.is_alive()
     writer.close()
 
     frames, stats = read_spool(tmp_path)

@@ -11,37 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-
-
-def read_child_until(proc, marker: str, timeout: float = 60.0) -> str:
-    """Accumulate a child's stdout until ``marker`` appears, EOF, or the deadline.
-
-    Reads the RAW non-blocking fd in chunks: selecting on the fd and then calling
-    ``readline()`` silently strands any second line inside the TextIO buffer (the
-    fd shows no data, the selector never fires again) — a hang this helper exists
-    to avoid. The child must be started with stdout=PIPE, stderr=STDOUT."""
-    import os
-    import selectors
-
-    import codecs
-
-    fd = proc.stdout.fileno()
-    os.set_blocking(fd, False)
-    decoder = codecs.getincrementaldecoder("utf-8")("replace")
-    deadline = time.monotonic() + timeout
-    seen = ""
-    with selectors.DefaultSelector() as sel:
-        sel.register(fd, selectors.EVENT_READ)
-        while time.monotonic() < deadline and marker not in seen:
-            if not sel.select(timeout=1.0):
-                if proc.poll() is not None:
-                    break
-                continue
-            chunk = os.read(fd, 65536)
-            if not chunk:
-                break  # EOF
-            seen += decoder.decode(chunk)
-    return seen
+from swarm_utils import read_child_until, stop_process
 
 
 def test_register_custom_expert_end_to_end():
@@ -108,8 +78,7 @@ def test_cli_starts_and_listens(module, extra):
             f"{module} never announced a listening address; output: {buffer[-500:]}"
         )
     finally:
-        proc.kill()
-        proc.wait()
+        stop_process(proc)
 
 
 def test_run_server_custom_module_path(tmp_path):
@@ -141,5 +110,4 @@ def test_run_server_custom_module_path(tmp_path):
         assert "serving 1 experts" in seen, f"server did not start: {seen[-2000:]}"
         assert "loaded custom expert module" in seen
     finally:
-        proc.kill()
-        proc.wait()
+        stop_process(proc)

@@ -6,10 +6,10 @@ import os
 import re
 import subprocess
 import sys
-import time
 
 import numpy as np
 import pytest
+from swarm_utils import read_child_until
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "examples", "albert"))
 
@@ -118,17 +118,9 @@ def test_run_trainer_two_peer_smoke():
         common + ["--seed", "0"], stderr=subprocess.PIPE, text=True, cwd=repo, env=env
     )
     try:
-        maddr = None
-        deadline = time.monotonic() + 120
-        lines = []
-        while time.monotonic() < deadline:
-            line = first.stderr.readline()
-            lines.append(line)
-            found = re.search(r"--initial_peers (\S+)", line)
-            if found:
-                maddr = found.group(1)
-                break
-        assert maddr, f"first peer never announced its address: {''.join(lines)[-2000:]}"
+        head = read_child_until(first, r"--initial_peers \S+\s", timeout=120, stream="stderr")
+        assert "--initial_peers" in head, f"first peer never announced its address: {head[-2000:]}"
+        maddr = re.search(r"--initial_peers (\S+)", head).group(1)
 
         # the monitor joins as a non-training observer and must see swarm progress
         monitor_script = os.path.join(repo, "examples", "albert", "run_training_monitor.py")
@@ -147,7 +139,7 @@ def test_run_trainer_two_peer_smoke():
             stderr=subprocess.PIPE, text=True, cwd=repo, timeout=420, env=env,
         )
         first_err = first.communicate(timeout=240)[1]
-        logs = "".join(lines) + (first_err or "") + (second.stderr or "")
+        logs = head + (first_err or "") + (second.stderr or "")
         assert second.returncode == 0, logs[-3000:]
         assert first.returncode == 0, logs[-3000:]
         finished = re.findall(r"training finished after 16 steps at epoch (\d+)", logs)

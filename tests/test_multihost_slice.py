@@ -9,12 +9,7 @@ swarm has two members; after the round BOTH processes must hold the exact
 cross-peer average in their device shards.
 """
 
-import os
-import socket
-import subprocess
-import sys
-
-import pytest
+from swarm_utils import run_jax_workers
 
 _WORKER = r"""
 import os, sys
@@ -116,31 +111,6 @@ print(f"SLICE_OK_{proc_id}", flush=True)
 
 
 def test_two_process_slice_is_one_swarm_peer(tmp_path):
-    with socket.socket() as probe:
-        probe.bind(("127.0.0.1", 0))
-        port = str(probe.getsockname()[1])
-    script = tmp_path / "slice_worker.py"
-    script.write_text(_WORKER)
-    # every process of these several-on-one-host runs is pinned to the CPU
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
-        [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
-        + [p for p in os.environ.get("PYTHONPATH", "").split(os.pathsep) if p]
-    ))
-    workers = [
-        subprocess.Popen(
-            [sys.executable, str(script), str(i), port],
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, env=env,
-        )
-        for i in range(2)
-    ]
-    outputs = []
-    try:
-        for i, worker in enumerate(workers):
-            out, _ = worker.communicate(timeout=420)
-            outputs.append(out)
-            assert worker.returncode == 0, f"worker {i} failed:\n{out[-3000:]}"
-            assert f"SLICE_OK_{i}" in out, out[-3000:]
-    finally:
-        for worker in workers:
-            if worker.poll() is None:
-                worker.kill()
+    for i, (code, out) in enumerate(run_jax_workers(_WORKER, tmp_path)):
+        assert code == 0, f"worker {i} exited {code}:\n{out[-3000:]}"
+        assert f"SLICE_OK_{i}" in out, out[-3000:]

@@ -3,34 +3,16 @@ and DCUtR-style hole punching upgrading a relayed connection to a direct one
 (scope: reference p2p_daemon.py:84-147 AutoNAT/AutoRelay/DCUtR flags)."""
 
 import asyncio
-import subprocess
-from pathlib import Path
-
-import pytest
 
 from hivemind_tpu.p2p import NATTraversal, P2P, P2PContext
 from hivemind_tpu.p2p.relay import RelayClient
 from hivemind_tpu.proto import test_pb2
 
-NATIVE_DIR = Path(__file__).parent.parent / "hivemind_tpu" / "native"
-RELAY_BIN = NATIVE_DIR / "relay_daemon"
 
-
-@pytest.fixture(scope="module")
-def relay_process():
-    if not RELAY_BIN.exists():
-        subprocess.run(["make"], cwd=NATIVE_DIR, check=True, capture_output=True)
-    proc = subprocess.Popen([str(RELAY_BIN), "0"], stdout=subprocess.PIPE, text=True)
-    port = int(proc.stdout.readline().strip().rsplit(" ", 1)[-1])
-    yield port
-    proc.kill()
-    proc.wait()
-
-
-async def test_relay_whoami(relay_process):
+async def test_relay_whoami(relay_daemon):
     p2p = await P2P.create()
     try:
-        relay = RelayClient(p2p, "127.0.0.1", relay_process)
+        relay = RelayClient(p2p, "127.0.0.1", relay_daemon.port)
         host, port = await relay.whoami()
         assert host == "127.0.0.1" and 0 < port < 65536
     finally:
@@ -58,7 +40,7 @@ async def test_reachability_probe():
         await bob.shutdown()
 
 
-async def test_hole_punch_upgrades_relayed_connection(relay_process):
+async def test_hole_punch_upgrades_relayed_connection(relay_daemon):
     """Two peers talk only through the relay; hole punching swaps in a direct
     connection that keeps serving RPCs."""
     server = await P2P.create()
@@ -72,8 +54,8 @@ async def test_hole_punch_upgrades_relayed_connection(relay_process):
         nat_client = NATTraversal(client)
         await nat_client.register_handlers()
 
-        server_relay = await RelayClient.create(server, "127.0.0.1", relay_process)
-        client_relay = RelayClient(client, "127.0.0.1", relay_process)
+        server_relay = await RelayClient.create(server, "127.0.0.1", relay_daemon.port)
+        client_relay = RelayClient(client, "127.0.0.1", relay_daemon.port)
         await client_relay.dial(server.peer_id)
         relayed_conn = client._connections[server.peer_id]
         response = await client.call_protobuf_handler(

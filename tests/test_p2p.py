@@ -396,7 +396,6 @@ async def test_many_concurrent_streams_one_connection():
         await server.shutdown()
 
 
-@pytest.mark.asyncio
 async def test_identity_file_collision_detected(tmp_path):
     """Two live P2P instances must not share one identity file (capability parity:
     reference is_identity_taken, p2p_daemon.py): the second create() fails fast,
@@ -413,7 +412,6 @@ async def test_identity_file_collision_detected(tmp_path):
     await second.shutdown()
 
 
-@pytest.mark.asyncio
 async def test_identity_file_readonly_and_failed_create(tmp_path):
     """A pre-provisioned read-only key file works (flock on a read-only fd), and a
     create() that fails AFTER taking the lock releases it for the next attempt."""

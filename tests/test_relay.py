@@ -3,53 +3,17 @@ encrypted (scope: reference tests/test_relays.py circuit-relay reachability)."""
 
 import asyncio
 import os
-import subprocess
-import sys
-from pathlib import Path
 
 import pytest
+from swarm_utils import start_relay_daemon, stop_process
 
 from hivemind_tpu.p2p import P2P, P2PContext
 from hivemind_tpu.p2p.relay import RelayClient
 from hivemind_tpu.proto import test_pb2
 
-NATIVE_DIR = Path(__file__).parent.parent / "hivemind_tpu" / "native"
-RELAY_BIN = NATIVE_DIR / "relay_daemon"
 
-
-@pytest.fixture(scope="module")
-def relay_process():
-    if not RELAY_BIN.exists():
-        subprocess.run(["make"], cwd=NATIVE_DIR, check=True, capture_output=True)
-    proc = subprocess.Popen(
-        [str(RELAY_BIN), "0"], stdout=subprocess.PIPE, text=True
-    )
-    line = proc.stdout.readline()
-    port = int(line.strip().rsplit(" ", 1)[-1])
-    yield port
-    proc.kill()
-    proc.wait()
-
-
-@pytest.fixture(scope="module")
-def relay_process_unix(tmp_path_factory):
-    """A daemon ALSO listening on a 0600 AF_UNIX socket — the multi-user-safe
-    trust boundary for the data-plane proxy's 'K' key handoff (advisor r4)."""
-    if not RELAY_BIN.exists():
-        subprocess.run(["make"], cwd=NATIVE_DIR, check=True, capture_output=True)
-    socket_path = str(tmp_path_factory.mktemp("proxy") / "proxy.sock")
-    proc = subprocess.Popen(
-        [str(RELAY_BIN), "0", "", socket_path], stdout=subprocess.PIPE, text=True
-    )
-    line = proc.stdout.readline()
-    assert "listening" in line, line
-    yield socket_path
-    proc.kill()
-    proc.wait()
-
-
-async def test_relayed_rpc_end_to_end(relay_process):
-    port = relay_process
+async def test_relayed_rpc_end_to_end(relay_daemon):
+    port = relay_daemon.port
     # "firewalled" peer: registers at the relay, never shares its direct address
     server = await P2P.create()
     client = await P2P.create()
@@ -80,8 +44,8 @@ async def test_relayed_rpc_end_to_end(relay_process):
     await server.shutdown()
 
 
-async def test_relay_dial_unknown_peer(relay_process):
-    port = relay_process
+async def test_relay_dial_unknown_peer(relay_daemon):
+    port = relay_daemon.port
     client = await P2P.create()
     from hivemind_tpu.utils.crypto import Ed25519PrivateKey
     from hivemind_tpu.p2p.peer_id import PeerID
@@ -97,7 +61,7 @@ async def _raw_conn(port):
     return await asyncio.open_connection("127.0.0.1", port)
 
 
-async def test_relay_register_requires_key_proof(relay_process):
+async def test_relay_register_requires_key_proof(relay_daemon):
     """Registration is authenticated: the daemon challenges every REGISTER and only
     an Ed25519 signature from the key the peer_id hashes is accepted. An attacker
     without the key cannot register the victim's id; the owner CAN re-register and
@@ -108,7 +72,7 @@ async def test_relay_register_requires_key_proof(relay_process):
     from hivemind_tpu.p2p.relay import RelayChannel, _recv_frame, _send_frame, register_control
     from hivemind_tpu.utils.crypto import Ed25519PrivateKey
 
-    port = relay_process
+    port = relay_daemon.port
     victim = Ed25519PrivateKey()
     victim_id = PeerID.from_private_key(victim).to_bytes()
 
@@ -157,13 +121,13 @@ async def test_relay_register_requires_key_proof(relay_process):
     w1.close()
 
 
-async def test_relay_encrypted_control_channel(relay_process):
+async def test_relay_encrypted_control_channel(relay_daemon):
     """The 'H' handshake gives an AEAD control channel bound to the relay's Ed25519
     identity: registration and a full relayed RPC work through it, a wrong pinned
     identity is refused before any control op, and TOFU pinning sticks."""
     from hivemind_tpu.p2p.relay import open_relay_channel
 
-    port = relay_process
+    port = relay_daemon.port
     channel = await open_relay_channel("127.0.0.1", port)
     if not channel.encrypted:
         pytest.skip("relay daemon running without libcrypto: no encrypted channel")
@@ -202,11 +166,11 @@ async def test_relay_encrypted_control_channel(relay_process):
     await server.shutdown()
 
 
-async def test_p2p_create_relays_kwarg(relay_process):
+async def test_p2p_create_relays_kwarg(relay_daemon):
     """P2P.create(relays=[...]) registers at the relay on startup (reference parity:
     use_relay/use_auto_relay) — a peer started this way is dialable through the
     relay with no direct address exchange."""
-    port = relay_process
+    port = relay_daemon.port
     server = await P2P.create(relays=[f"127.0.0.1:{port}"])
     assert len(server._relays) == 1
     client = await P2P.create()
@@ -230,25 +194,18 @@ def test_relay_identity_persists_across_restarts(tmp_path):
     identity_file = tmp_path / "relay.key"
 
     def start_and_read_identity():
-        proc = subprocess.Popen(
-            [str(RELAY_BIN), "0", str(identity_file)], stdout=subprocess.PIPE, text=True
-        )
-        try:
-            proc.stdout.readline()  # listening line
-            line = proc.stdout.readline().strip()
-        finally:
-            proc.kill()
-            proc.wait()
-        if not line.startswith("relay identity "):
+        daemon = start_relay_daemon(str(identity_file))
+        stop_process(daemon.process)
+        if not daemon.pubkey_hex:
             pytest.skip("relay daemon running without libcrypto: no identity")
-        return line.rsplit(" ", 1)[-1]
+        return daemon.pubkey_hex
 
     first = start_and_read_identity()
     assert identity_file.exists() and len(identity_file.read_bytes()) == 32
     assert start_and_read_identity() == first
 
 
-async def test_relay_reregister_different_id_no_stale_route(relay_process):
+async def test_relay_reregister_different_id_no_stale_route(relay_daemon):
     """One control line re-registering under a NEW peer_id must drop the route to its
     old id: a later DIAL for the old id gets a clean refusal (regression: the stale
     g_control entry used to deref a dangling conn and crash the daemon)."""
@@ -256,7 +213,7 @@ async def test_relay_reregister_different_id_no_stale_route(relay_process):
     from hivemind_tpu.p2p.relay import RelayChannel, _recv_frame, _send_frame, register_control
     from hivemind_tpu.utils.crypto import Ed25519PrivateKey
 
-    port = relay_process
+    port = relay_daemon.port
     key_a, key_b = Ed25519PrivateKey(), Ed25519PrivateKey()
     id_a = PeerID.from_private_key(key_a).to_bytes()
     id_b = PeerID.from_private_key(key_b).to_bytes()
@@ -283,7 +240,7 @@ async def test_relay_reregister_different_id_no_stale_route(relay_process):
         w.close()
 
 
-async def test_relay_backpressure_bounds_memory(relay_process):
+async def test_relay_backpressure_bounds_memory(relay_daemon):
     """Fast sender + slow receiver: the daemon must PAUSE reading (epoll interest
     drop) instead of buffering at line rate; memory stays bounded and every byte
     still arrives once the receiver drains (ADVICE r1: level-triggered EPOLLIN)."""
@@ -291,7 +248,7 @@ async def test_relay_backpressure_bounds_memory(relay_process):
     from hivemind_tpu.p2p.relay import RelayChannel, _recv_frame, _send_frame, register_control
     from hivemind_tpu.utils.crypto import Ed25519PrivateKey
 
-    port = relay_process
+    port = relay_daemon.port
     total = 32 * 1024 * 1024
     server_key = Ed25519PrivateKey()
     peer_id = PeerID.from_private_key(server_key).to_bytes()
@@ -309,10 +266,7 @@ async def test_relay_backpressure_bounds_memory(relay_process):
     assert await _recv_frame(ra) == b"O"
     assert await _recv_frame(rd) == b"O"
 
-    # daemon RSS before the blast
-    daemon_pid = None
-    for line in subprocess.run(["pgrep", "-f", "relay_daemon"], capture_output=True, text=True).stdout.split():
-        daemon_pid = int(line)
+    daemon_pid = relay_daemon.process.pid  # this worker's daemon, not a neighbour's
 
     def daemon_rss_kib() -> int:
         with open(f"/proc/{daemon_pid}/status") as f:
@@ -379,7 +333,7 @@ def test_plaintext_control_refused_by_default():
     asyncio.run(scenario())
 
 
-async def test_data_plane_proxy_dial(relay_process):
+async def test_data_plane_proxy_dial(relay_daemon):
     """Native data-plane proxy (VERDICT r3 #6): a client dials through the local
     daemon's 'X' mode — the daemon terminates the channel AEAD in C++ (Python
     ships plaintext frames over loopback), and unary + multi-megabyte streaming
@@ -390,7 +344,7 @@ async def test_data_plane_proxy_dial(relay_process):
     from hivemind_tpu.compression import serialize_tensor, split_tensor_for_streaming
     from hivemind_tpu.proto import runtime_pb2
 
-    port = relay_process
+    port = relay_daemon.port
     server = await P2P.create()
     client = await P2P.create(data_proxy_port=port)
     try:
@@ -434,12 +388,12 @@ async def test_data_plane_proxy_dial(relay_process):
         await server.shutdown()
 
 
-async def test_data_plane_proxy_over_unix_socket(relay_process_unix):
+async def test_data_plane_proxy_over_unix_socket(relay_daemon_unix):
     """The proxy hop over the daemon's AF_UNIX listener: the socket file is 0600
     (kernel-enforced same-user trust boundary for the 'K' key handoff — the
     reference confines its daemon hop to a unix socket the same way,
     p2p_daemon.py:84-147), and dials through it carry RPCs end to end."""
-    socket_path = relay_process_unix
+    socket_path = relay_daemon_unix
     assert (os.stat(socket_path).st_mode & 0o777) == 0o600, oct(os.stat(socket_path).st_mode)
 
     server = await P2P.create()
@@ -462,13 +416,13 @@ async def test_data_plane_proxy_over_unix_socket(relay_process_unix):
         await server.shutdown()
 
 
-async def test_inbound_data_plane_proxy(relay_process):
+async def test_inbound_data_plane_proxy(relay_daemon):
     """VERDICT r4 next-round #7: the daemon owns the SERVER's public listener
     ('Y' mode) and terminates the inbound direction's AEAD too — a plain client
     dials the advertised (daemon-owned) port and RPCs work end to end, while the
     server's Python loop only ever sees plaintext frames on loopback. Combined
     with a proxied client dial, BOTH directions' cipher work is native."""
-    port = relay_process
+    port = relay_daemon.port
     server = await P2P.create(data_proxy_port=port, inbound_data_proxy=True)
     client = await P2P.create(data_proxy_port=port)  # outbound proxied too
     try:
@@ -533,18 +487,13 @@ async def test_inbound_proxy_daemon_death_falls_back_to_direct_listening():
     while outbound dials keep working and mask the loss."""
     import time
 
-    if not RELAY_BIN.exists():
-        subprocess.run(["make"], cwd=NATIVE_DIR, check=True, capture_output=True)
-    proc = subprocess.Popen([str(RELAY_BIN), "0"], stdout=subprocess.PIPE, text=True)
-    port = int(proc.stdout.readline().strip().rsplit(" ", 1)[-1])
-    proc.stdout.readline()
+    proc, port, _ = start_relay_daemon()
     server = await P2P.create(data_proxy_port=port, inbound_data_proxy=True)
     client = None
     try:
         assert server._inbound_proxy_active
         dead_public_port = server.get_visible_maddrs()[0].port
-        proc.kill()
-        proc.wait()
+        stop_process(proc)
         deadline = time.monotonic() + 20
         while server._inbound_proxy_active and time.monotonic() < deadline:
             await asyncio.sleep(0.2)
@@ -566,19 +515,17 @@ async def test_inbound_proxy_daemon_death_falls_back_to_direct_listening():
         if client is not None:
             await client.shutdown()
         await server.shutdown()
-        if proc.poll() is None:
-            proc.kill()
-            proc.wait()
+        stop_process(proc)
 
 
-async def test_inbound_proxy_survives_malformed_wire_frames(relay_process):
+async def test_inbound_proxy_survives_malformed_wire_frames(relay_daemon):
     """Adversarial bytes at the daemon-owned PUBLIC listener (the inbound fuzz
     half of the r4 ask): oversized frames, garbage ciphertext after a fake
     hello, and raw junk each kill at most their own pair — a well-formed peer
     still handshakes and RPCs afterwards."""
     import struct
 
-    port = relay_process
+    port = relay_daemon.port
     server = await P2P.create(data_proxy_port=port, inbound_data_proxy=True)
     client = None
     try:
@@ -626,7 +573,7 @@ async def test_inbound_proxy_survives_malformed_wire_frames(relay_process):
         await server.shutdown()
 
 
-async def test_data_plane_proxy_survives_malformed_frames(relay_process):
+async def test_data_plane_proxy_survives_malformed_frames(relay_daemon):
     """Adversarial input to the daemon's proxy parser must kill at most the
     offending pair, never the daemon: bad 'K' frames, oversized frames, and
     garbage ciphertext each get their connection closed, and a well-formed
@@ -634,7 +581,7 @@ async def test_data_plane_proxy_survives_malformed_frames(relay_process):
     import asyncio
     import struct
 
-    port = relay_process
+    port = relay_daemon.port
 
     async def frame(writer, payload: bytes):
         writer.write(struct.pack(">I", len(payload)) + payload)

@@ -6,6 +6,7 @@ Everything here is seeded and CPU-only; the multi-minute soak lives behind the
 ``slow`` marker (the ``chaos`` marker alone stays tier-1-safe)."""
 
 import asyncio
+import threading
 import time
 
 import numpy as np
@@ -407,8 +408,11 @@ async def test_dht_store_get_under_rpc_drops():
 
 @pytest.mark.chaos
 def test_chaos_soak_smoke():
-    """Tier-1-safe soak (seeded, CPU-only, ~30 s): 2 trainers + an MoE pair under
-    the full default schedule; steps advance, breakers recover."""
+    """Tier-1-safe soak (seeded, CPU-only): 2 trainers + an MoE pair under the full
+    default schedule; steps advance, breakers recover. Under six workers 30-52 s (PR 48's
+    whole runs): 5 s of start-up, the 18 s schedule, and up to 29 s of tear-down, in which
+    a trainer whose partner has stopped waits out its last gradient round and the state
+    round its shutdown lets land (an ``averaging_timeout`` of 20 s each)."""
     from hivemind_tpu.hivemind_cli.run_chaos_soak import run_soak
 
     report = run_soak(n_peers=2, duration=18.0, seed=0, chaos_fraction=0.55, include_moe=True)
@@ -421,7 +425,9 @@ def test_chaos_soak_smoke():
 
 @pytest.mark.chaos
 def test_churn_soak_smoke():
-    """Tier-1-safe churn soak (ISSUE 7, ~35 s): 3 trainers under the default
+    """Tier-1-safe churn soak (ISSUE 7; under six workers 36-72 s, PR 48's whole runs:
+    the 32 s schedule and 1-39 s of tear-down, by where in their last rounds the
+    trainers are when they are told to stop): 3 trainers under the default
     fault schedule (including state.download corruption/drops), one crash-killed
     mid-chaos — DHT yanked, no shutdown — and restarted against its crash-safe
     checkpoint directory. The verdict requires every restarted peer back at the
@@ -438,6 +444,18 @@ def test_churn_soak_smoke():
     assert report["checks"]["digest_failures_adopted_zero"], report
     assert report["checks"]["steps_advanced_after_chaos"], report
     assert report["checks"]["no_thread_errors"], report
+
+    # the crash held while the verdict was earned; once run_soak has returned, nothing of
+    # the killed peer (nor of any other) runs here: no optimizer's worker thread, and on
+    # the shared loop no averager's declare loop and no tracker's reporter or fetcher
+    from hivemind_tpu.utils.loop import get_loop_runner
+
+    async def pending_task_names():
+        return sorted(task.get_name() for task in asyncio.all_tasks() if not task.done())
+
+    names = get_loop_runner().run_coroutine(pending_task_names(), return_future=True).result(10)
+    names += [thread.name for thread in threading.enumerate()]
+    assert not [n for n in names if n.startswith(("averager.", "progress_tracker.", "hm_dpu", "hm_ckpt"))], names
 
 
 @pytest.mark.slow

@@ -10,11 +10,11 @@ Only process 0 owns any networking; process 1 asserts it never constructs a DHT.
 """
 
 import os
-import socket
 import subprocess
 import sys
 
 import pytest
+from swarm_utils import run_jax_workers
 
 _WORKER = r"""
 import os, sys, threading, time
@@ -206,42 +206,15 @@ print(f"SLICE_OPT_OK_{proc_id}", flush=True)
 """
 
 
-def _run_two_process_slice_workers(tmp_path, mode: str = "sync"):
-    with socket.socket() as probe:
-        probe.bind(("127.0.0.1", 0))
-        port = str(probe.getsockname()[1])
-    script = tmp_path / "slice_opt_worker.py"
-    script.write_text(_WORKER)
-    # every process of these several-on-one-host runs is pinned to the CPU
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
-        [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
-        + [p for p in os.environ.get("PYTHONPATH", "").split(os.pathsep) if p]
-    ))
-    return [
-        subprocess.Popen(
-            [sys.executable, str(script), str(i), port, mode],
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, env=env,
-        )
-        for i in range(2)
-    ]
-
-
-def _assert_two_process_workers_ok(workers):
-    try:
-        for i, worker in enumerate(workers):
-            out, _ = worker.communicate(timeout=540)
-            assert worker.returncode == 0, f"worker {i} failed:\n{out[-4000:]}"
-            assert f"TRAIN_OK_{i}" in out, out[-4000:]
-            assert f"JOIN_OK_{i}" in out, out[-4000:]
-            assert f"SLICE_OPT_OK_{i}" in out, out[-4000:]
-    finally:
-        for worker in workers:
-            if worker.poll() is None:
-                worker.kill()
+def _assert_two_process_slice_trains(tmp_path, mode: str):
+    for i, (code, out) in enumerate(run_jax_workers(_WORKER, tmp_path, [mode])):
+        assert code == 0, f"worker {i} exited {code}:\n{out[-4000:]}"
+        for marker in ("TRAIN_OK", "JOIN_OK", "SLICE_OPT_OK"):
+            assert f"{marker}_{i}" in out, out[-4000:]
 
 
 def test_full_optimizer_on_two_process_slice(tmp_path):
-    _assert_two_process_workers_ok(_run_two_process_slice_workers(tmp_path, "sync"))
+    _assert_two_process_slice_trains(tmp_path, "sync")
 
 
 def test_full_optimizer_on_two_process_slice_dpu(tmp_path):
@@ -251,7 +224,7 @@ def test_full_optimizer_on_two_process_slice_dpu(tmp_path):
     hold with a genuinely separate follower process (the single-process DPU
     tests cannot catch a cross-process collective-ordering divergence). Both
     workers additionally assert steps ran while a round was in flight."""
-    _assert_two_process_workers_ok(_run_two_process_slice_workers(tmp_path, "dpu"))
+    _assert_two_process_slice_trains(tmp_path, "dpu")
 
 
 def test_slice_collaborative_example_single_process():

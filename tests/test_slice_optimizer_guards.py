@@ -106,7 +106,8 @@ def test_timed_out_discard_poisons_grad_averager(slice_opt):
 def test_clean_discard_does_not_poison(slice_opt):
     done = threading.Thread(target=lambda: None)
     done.start()
-    done.join()
+    done.join(30)
+    assert not done.is_alive()
     slice_opt._pending = {"scratch": [], "num_peers": 2}
     slice_opt._bg_thread = done
     before = _poison_counter()

@@ -8,7 +8,8 @@ import re
 import socket
 import subprocess
 import sys
-import time
+
+from swarm_utils import cpu_child_env, read_child_until, stop_process, wait_for_children
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _EXAMPLE = os.path.join(_REPO, "examples", "slice_training.py")
@@ -30,7 +31,7 @@ avg = DecentralizedAverager(
     target_group_size=2, min_matchmaking_time=1.0,
 )
 joined = 0
-deadline = time.monotonic() + 90  # must stay under the parent's communicate timeout
+deadline = time.monotonic() + 90  # must stay under the parent's deadline
 while joined < 2 and time.monotonic() < deadline:
     try:
         if avg.step(timeout=45) is not None:
@@ -48,10 +49,7 @@ def test_two_process_slice_trains_and_averages_with_swarm(tmp_path):
     with socket.socket() as probe:
         probe.bind(("127.0.0.1", 0))
         coord = f"127.0.0.1:{probe.getsockname()[1]}"
-    # every process of these several-on-one-host runs is pinned to the CPU
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
-        [_REPO] + [p for p in os.environ.get("PYTHONPATH", "").split(os.pathsep) if p]
-    ))
+    env = cpu_child_env()
     common = [
         sys.executable, _EXAMPLE, "--platform", "cpu", "--devices_per_proc", "2",
         "--num_processes", "2", "--coordinator", coord,
@@ -68,19 +66,9 @@ def test_two_process_slice_trains_and_averages_with_swarm(tmp_path):
     companion = None
     try:
         # process 0 prints its DHT address once its dht_factory runs
-        maddr = None
-        deadline = time.monotonic() + 180
-        lines = []
-        while time.monotonic() < deadline:
-            line = procs[0].stdout.readline()
-            if not line:
-                break
-            lines.append(line)
-            match = re.search(r"--initial_peers (\S+)", line)
-            if match:
-                maddr = match.group(1)
-                break
-        assert maddr, "".join(lines[-30:])
+        head = read_child_until(procs[0], r"--initial_peers \S+\s", timeout=180)
+        assert "--initial_peers" in head, head[-3000:]
+        maddr = re.search(r"--initial_peers (\S+)", head).group(1)
 
         script = tmp_path / "companion.py"
         script.write_text(_COMPANION)
@@ -89,12 +77,12 @@ def test_two_process_slice_trains_and_averages_with_swarm(tmp_path):
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, env=env,
         )
 
-        outs = ["".join(lines), ""]
-        out0, _ = procs[0].communicate(timeout=420)
-        outs[0] += out0
-        out1, _ = procs[1].communicate(timeout=120)
-        outs[1] = out1
-        comp_out, _ = companion.communicate(timeout=180)  # > companion's own 90s deadline
+        children = procs + [companion]
+        wait_for_children(children, timeout=240)  # > the companion's own 90 s deadline
+        for child in children:
+            stop_process(child)
+        out0, out1, comp_out = (child.communicate(timeout=10)[0] for child in children)
+        outs = [head + out0, out1]
 
         for i, out in enumerate(outs):
             assert procs[i].returncode == 0, f"slice proc {i} failed:\n{out[-3000:]}"
@@ -112,5 +100,4 @@ def test_two_process_slice_trains_and_averages_with_swarm(tmp_path):
         assert abs(finals[0] - finals[1]) < 1e-4, finals  # SPMD: same global loss
     finally:
         for proc in procs + ([companion] if companion else []):
-            if proc.poll() is None:
-                proc.kill()
+            stop_process(proc)

@@ -93,7 +93,8 @@ def test_concurrent_increments_are_lossless():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(30)
+        assert not t.is_alive()
     assert c.value(worker="shared") == 8 * 5000
     assert sum(c.value(worker=str(i)) for i in range(8)) == 8 * 5000
     assert h.labels().count == 8 * 5000
