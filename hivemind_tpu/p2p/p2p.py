@@ -44,6 +44,7 @@ from hivemind_tpu.p2p.peer_id import Multiaddr, PeerID
 from hivemind_tpu.resilience import CHAOS as _CHAOS
 from hivemind_tpu.resilience import Deadline
 from hivemind_tpu.utils.crypto import Ed25519PrivateKey
+from hivemind_tpu.utils.limits import keep_large_blocks_on_heap
 from hivemind_tpu.utils.logging import get_logger
 from hivemind_tpu.utils.asyncio_utils import spawn
 from hivemind_tpu.utils.streaming import WireParts
@@ -185,6 +186,10 @@ class P2P:
         (stream-less) connections are closed least-recently-used-first down to
         90% of the cap; a trimmed peer is simply re-dialed on next use. This is
         what bounds fd usage for large swarms (hundreds of DHT peers)."""
+        # the one place every process that puts tensors on the wire passes (a server, a
+        # trainer peer, a client through its DHT): from here on its allocator keeps a
+        # request's arrays on the heap
+        keep_large_blocks_on_heap()
         self = object.__new__(cls)
         self._identity_lock_fd: Optional[int] = None
         if identity is None:

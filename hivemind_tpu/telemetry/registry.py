@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 _LabelKey = Tuple[str, ...]
 
@@ -36,15 +36,16 @@ _VALID_METRIC_TYPES = ("counter", "gauge", "histogram")
 class _Child:
     """One labeled time series of a Counter or Gauge."""
 
-    __slots__ = ("_lock", "_value")
+    __slots__ = ("_lock", "_value", "_read")
 
     def __init__(self, lock: threading.Lock):
         self._lock = lock
         self._value = 0.0
+        self._read: Optional[Callable[[], float]] = None  # a gauge read where it is kept
 
     @property
     def value(self) -> float:
-        return self._value
+        return self._value if self._read is None else self._read()
 
     def inc(self, amount: float = 1.0) -> None:
         with self._lock:
@@ -177,6 +178,11 @@ class Gauge(Metric):
 
     def dec(self, amount: float = 1.0, **labels) -> None:
         (self.labels(**labels) if labels else self._no_labels()).dec(amount)
+
+    def set_function(self, read: Callable[[], float], **labels) -> None:
+        """The series reads ``read()`` whenever its value is taken (a scrape, a snapshot):
+        for a quantity something else keeps, which costs nothing until somebody looks."""
+        (self.labels(**labels) if labels else self._no_labels())._read = read
 
     def value(self, **labels) -> float:
         return (self.labels(**labels) if labels else self._no_labels()).value

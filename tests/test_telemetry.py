@@ -53,6 +53,16 @@ def test_gauge_set_inc_dec():
     assert g.value() == 4.0
 
 
+def test_gauge_reads_its_function_when_looked_at():
+    reg = MetricsRegistry()
+    kept = [3.0]
+    reg.gauge("kept_elsewhere", "read where it is kept").set_function(lambda: kept[0])
+    assert reg.gauge("kept_elsewhere").value() == 3.0
+    kept[0] = 7.0  # nobody told the gauge
+    assert reg.snapshot()["kept_elsewhere"]["series"]["_"] == 7.0
+    assert "kept_elsewhere 7" in render_prometheus(reg)
+
+
 def test_histogram_buckets_are_cumulative():
     reg = MetricsRegistry()
     h = reg.histogram("lat", "latency", ("op",), buckets=(0.01, 0.1, 1.0))
