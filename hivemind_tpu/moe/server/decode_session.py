@@ -10,7 +10,10 @@ later call advances one token — or, on a chain whose blocks all take chunks, b
 the prompt's next chunk. A session's cache lives on-device as whatever TREE of arrays
 the block's `init_decode_cache` returned (a `(cache_k, cache_v)` pair, a recurrent
 state, keys with values and compressed keys): the manager joins, splits, donates,
-places and counts it leaf by leaf and never looks inside. The step function is jitted once per
+places and counts it leaf by leaf and never looks inside. EVERY step donates the leaves it
+is handed, a session's own call and a batched program alike: the new leaves take the old
+ones' buffers, nothing is copied or allocated for them, and a block must not keep a
+reference to a cache argument. The step function is jitted once per
 (uid, batch, chunk-length) signature, and sessions expire by TTL / LRU cap so an
 abandoned client cannot pin device memory.
 
@@ -35,7 +38,10 @@ program runs on the output of the block before, and the last output is fetched.
 Steps that arrive while a cohort is launched wait for the next one, which starts
 when this one's last block is dispatched, with all of them up to a full bucket
 (`_cohort_rows`: a program costs by the power of two its rows are padded to; with
-more than 16 rows under way on the chain, up to the bucket that holds half). This
+more than 16 rows under way on the chain, up to the bucket that holds half; and with
+that many under way, a cohort SHORT of that bucket waits for the cohort still on the
+device to be answered before it is launched: a walk that outruns its clients would
+else run a bucket for a few rows). This
 cohort's output is awaited and answered beside the next one's launch, so the device
 finds the next cohort's first program queued behind this one's last. The cohort
 under way is the window; `FLUSH_WINDOW_S` is what a chain that was idle waits.
@@ -54,10 +60,14 @@ the block says of its own step (`decode_rows_apart`; counted by
 slots, of which a step writes one and reads the rest (`causal_transformer`, `llama_block`,
 `olmoe_block`, `exaone_moe_block` with ``window`` = 0, `minicpm_sala_block`'s sparse mixer:
 tens of MB a session): APART, the block updates and reads each row's own arrays where they
-lie, and the one copy a row that is left is the new array of an argument that is not
-donated. A ring of ``window`` slots or a recurrent state (`exaone_moe_block` with a
-window, the lightning mixer: 0.5 to 2 MB a session): JOINED leaf by leaf along the batch
-axis, stepped as one array and split again, which costs less than an operation a row.
+lie, in the donated buffers themselves. A ring of ``window`` slots or a recurrent state
+(`exaone_moe_block` with a window, the lightning mixer: 0.5 to 2 MB a session): JOINED leaf
+by leaf along the batch axis, stepped as one array and split again, which costs less than
+an operation a row (the split's outputs may take the donated inputs' buffers). A bucket's
+padding positions each get a throwaway cache of their own (`_padding`: a buffer is donated
+once a call) and keep the new one. The price of donation is what a FAILED step leaves: the
+sessions whose leaves it took are dropped (`_drop_failed`, by either path), and the
+client's next continuation gets the unknown-session ``KeyError`` and re-prefills.
 Around the program the host only collects handles before it
 (``assemble``: the activations — the last block's output as it is, or one
 `np.concatenate` of host rows through the upload program — and one array of write
@@ -127,8 +137,23 @@ _SESSION_OCCUPANCY = _TELEMETRY.gauge(
 )
 _EVICTIONS = _TELEMETRY.counter(
     "hivemind_moe_decode_session_evictions_total",
-    "decode sessions evicted, by reason (ttl = idle expiry, cap = LRU over max_sessions)",
+    "decode sessions evicted, by reason (ttl = idle expiry, cap = LRU over max_sessions, failed_step = a step that "
+    "had taken the session's caches failed: a step donates them)",
     ("reason",),
+)
+# every step donates the cache leaves it is handed (ISSUE 50): the new leaves take their buffers, so these are
+# the bytes a step neither copies nor allocates anew; counted from the shapes, on the host
+_DONATED_BYTES = _TELEMETRY.counter(
+    "hivemind_moe_decode_cache_bytes_donated_total",
+    "bytes of cache leaves handed to a donating decode program, by path (batched = every position of a batched "
+    "program's bucket, its padding positions' throwaway caches included; direct = a session's own step or prefill)",
+    ("path",),
+)
+_DONATED_BATCHED, _DONATED_DIRECT = _DONATED_BYTES.labels("batched"), _DONATED_BYTES.labels("direct")
+_PADDING_BYTES = _TELEMETRY.gauge(
+    "hivemind_moe_decode_padding_cache_bytes",
+    "bytes of the throwaway caches that pad batched decode programs to their bucket: per block as many rows as "
+    "the largest padding a call has needed, each a cache of its own (a buffer is donated once a call)",
 )
 # whether the table is walked for a step (ISSUE 44): every step and every add asks whether an eviction pass
 # could have an effect (`_evict_due_locked`), and a pass runs only then; in steady traffic `skipped` follows
@@ -239,6 +264,10 @@ def _batch_phase(phase: str):
         _PHASE_SECONDS.inc(time.perf_counter() - started, phase=phase)
 
 
+def _row_bytes(leaves) -> int:
+    return sum(leaf.nbytes for leaf in leaves)
+
+
 def _next_pow2(n: int) -> int:
     power = 1
     while power < n:
@@ -271,7 +300,12 @@ def _cohort_rows(waiting: int, active: int = 0) -> int:
     would double it."""
     bucket = _next_pow2(waiting)
     take = waiting if 4 * waiting >= 3 * bucket else bucket // 2
-    return min(take, _next_pow2(-(-active // 2))) if active > HALVED_ABOVE else take
+    return min(take, _half_bucket(active)) if active > HALVED_ABOVE else take
+
+
+def _half_bucket(active: int) -> int:
+    """The bucket that holds half of ``active`` rows: what each of two alternating cohorts carries."""
+    return _next_pow2(-(-active // 2))
 
 
 class _Session:
@@ -284,7 +318,7 @@ class _Session:
         # and hands back, so no step and no batch walks a tree on the host
         leaves, self.tree = jax.tree_util.tree_flatten(cache)
         self.leaves = tuple(leaves)
-        self.nbytes = sum(leaf.nbytes for leaf in leaves)  # a step hands back leaves of the same shapes
+        self.nbytes = _row_bytes(leaves)  # a step hands back leaves of the same shapes
         self.batch = leaves[0].shape[0]
         self.index = 0
         self.last_used = time.monotonic()
@@ -379,7 +413,11 @@ class DecodeSessionManager:
         self._sessions: Dict[Tuple[str, str], _Session] = {}
         self._step_fns: Dict[Tuple[str, int, int], callable] = {}
         self._batched_fns: Dict[Tuple[str, int], callable] = {}
-        self._dummy_caches: Dict[str, tuple] = {}  # per-uid padding row (a cache's leaves) for pow2 buckets
+        # uid -> the throwaway caches (each a cache's leaves) that pad a batch to its pow2 bucket, those not in a
+        # program right now (`_padding`); their lock is the dispatching threads' alone, never the loop's
+        self._padding_rows: Dict[str, List[tuple]] = {}
+        self._padding_bytes = 0
+        self._padding_lock = threading.Lock()
         self._lock = threading.Lock()
         # both keyed by the span chain (the tuple of uids a request crosses)
         self._pending: Dict[Chain, List] = {}  # chain -> [(future, [the session of each uid], x), ...]
@@ -480,8 +518,13 @@ class DecodeSessionManager:
         _CACHE_ENTRIES.set(tally[1], kind=kind)
 
     def clear_sessions(self) -> None:
-        """Empty the session table and its gauges (a warm-up's or a check's sessions
-        leave the device before the traffic comes). Steps under way are not waited for."""
+        """Empty the session table and its gauges, and release the throwaway caches that padded
+        the batches (a warm-up's or a check's sessions leave the device before the traffic
+        comes). Steps under way are not waited for."""
+        with self._padding_lock:  # a throwaway cache inside a program right now comes back later, and stays counted
+            self._padding_bytes -= sum(_row_bytes(row) for rows in self._padding_rows.values() for row in rows)
+            self._padding_rows.clear()
+            _PADDING_BYTES.set(self._padding_bytes)
         with self._lock:
             self._sessions.clear()
             for kind, tally in self._cache_tally.items():
@@ -511,6 +554,19 @@ class DecodeSessionManager:
                     self._stamp_locked(uid, (session,), session.last_used)
         if keys:
             self._sample_gauges_locked()
+
+    def _drop_failed(self, sessions) -> None:
+        """A step that had taken ``sessions``' caches failed: every step DONATES the leaves it is handed, so
+        what these sessions point at is deleted buffers, or outputs of a program that failed. They leave the
+        table, and their clients' next continuations get the unknown-session ``KeyError`` and re-prefill."""
+        doomed = {id(session) for session in sessions}
+        if not doomed:
+            return
+        with self._lock:
+            keys = [key for key, session in self._sessions.items() if id(session) in doomed]
+            self._drop_locked(keys)
+        if keys:
+            _EVICTIONS.inc(len(keys), reason="failed_step")
 
     def _raw_step(self, uid: str):
         """The un-jitted block step; shared by the direct and batched paths so a
@@ -607,6 +663,7 @@ class DecodeSessionManager:
         step = self._step_fn(uid, x.shape[0], chunk_len)
         length = (jnp.int32(new_len),) if self._takes_length(uid) else ()
         _CALLS_DIRECT.inc()
+        _DONATED_DIRECT.inc(session.nbytes)
         started = time.perf_counter()
         try:
             with _trace_sync("decode.direct", uid=uid, chunk_len=chunk_len) as span:
@@ -626,8 +683,7 @@ class DecodeSessionManager:
                     _PREFILL_POSITIONS.inc(x.shape[0] * chunk_len)
                 return y
         except Exception:
-            with self._lock:
-                self._drop_locked([k for k, s in self._sessions.items() if s is session])
+            self._drop_failed([session])
             raise
 
     def decode(self, uid: str, session_id: str, x: np.ndarray, reset: bool) -> np.ndarray:
@@ -842,7 +898,9 @@ class DecodeSessionManager:
         the next cohort takes all of them the moment this one's last block is
         dispatched — its output is awaited and its futures resolved beside that
         (`_resolve`), so the device finds the next cohort's first program queued
-        behind this one's last."""
+        behind this one's last. The one cohort that is held back: with more than
+        `HALVED_ABOVE` rows under way, one short of the bucket that holds half of
+        them waits until a cohort still unanswered has been answered."""
         loop = asyncio.get_running_loop()
         held: List = []  # entries out of _pending whose sessions this drainer pins
         resolving = {}  # the `_resolve` tasks of cohorts launched and not yet answered -> their rows
@@ -877,7 +935,16 @@ class DecodeSessionManager:
                     else:
                         seen |= ids
                         cohort.append(entry)
-                take = _cohort_rows(len(cohort), len(held) + sum(resolving.values()))
+                active = len(held) + sum(resolving.values())
+                take = _cohort_rows(len(cohort), active)
+                if resolving and active > HALVED_ABOVE and take < _half_bucket(active):
+                    # short of the bucket that one of two alternating cohorts fills, while a cohort is still on
+                    # the device: that one's rows cannot come back before it is answered and the others' are on
+                    # their way, so this one waits for it instead of costing the device a bucket for a few rows.
+                    # Only a walk that outruns its clients gets here (ISSUE 50: a dispatch is a third of what it
+                    # was); one that the host paces finds a full bucket waiting and launches as ever
+                    await asyncio.wait(resolving, return_when=asyncio.FIRST_COMPLETED)
+                    continue
                 cohort, rollover = cohort[:take], cohort[take:] + rollover
                 try:
                     finish = await loop.run_in_executor(None, self._launch_cohort, chain, cohort)
@@ -945,8 +1012,8 @@ class DecodeSessionManager:
         row's output there is its input at the next block — left on the device
         (`_Row`), so that the next block's program is dispatched while this one's
         runs. The walk runs at most one program ahead of the device: before block
-        k+1 is dispatched, block k-1 has finished (its new caches are the only ones
-        in flight beside block k's). A row that fails at a block keeps that
+        k+1 is dispatched, block k-1 has finished (its program is the only one at
+        work beside block k's). A row that fails at a block keeps that
         exception and leaves the cohort; the blocks after it never see it. Returns
         ``finish``: called once, from any thread, it waits for the chain's last
         program and returns one result (ndarray or Exception) per entry, in order."""
@@ -954,18 +1021,19 @@ class DecodeSessionManager:
         results: List = [x for _future, _sessions, x in entries]
         alive = list(range(len(entries)))
         launched: List[_Output] = []
+        dispatched = 0  # the blocks of the chain whose batch has been dispatched and scattered
 
         def fail(error: Exception) -> None:
-            # a block's program failed: every row still under way fails with it. If
-            # it failed at its own dispatch, its sessions are as they were; but the
-            # sessions of the blocks before it were handed outputs of programs that
-            # were still running, and what they point at may be unreadable now.
-            # Drop those (the clients' next continuations get the unknown-session
-            # KeyError and re-prefill)
-            if launched:
-                doomed = {id(session) for i in alive for session in entries[i][1]}
-                with self._lock:
-                    self._drop_locked([k for k, session in self._sessions.items() if id(session) in doomed])
+            # a block's program failed: every row still under way fails with it. The
+            # batch that raised at its own dispatch has dropped the sessions whose
+            # caches it had taken (`_decode_batch_traced`). The sessions of the blocks
+            # dispatched before were handed outputs of programs that were still
+            # running, and a program that fails when it is awaited is one of those:
+            # what they point at may be unreadable now, and a step that ended at some
+            # blocks and not at others is no step. They go too (`_drop_failed`: the
+            # clients' next continuations get the unknown-session KeyError and
+            # re-prefill); the sessions of blocks never reached stay as they were
+            self._drop_failed([session for i in alive for session in entries[i][1][:dispatched]])
             for i in alive:
                 results[i] = error
 
@@ -986,6 +1054,7 @@ class DecodeSessionManager:
                 for depth, uid in enumerate(chain):
                     batch = [(entries[i][0], entries[i][1][depth], results[i]) for i in alive]
                     outs = self._decode_batch(uid, batch, fetch=False)
+                    dispatched = depth + 1
                     for i, out in zip(alive, outs):
                         results[i] = out
                     alive[:] = [i for i in alive if not isinstance(results[i], Exception)]
@@ -1010,7 +1079,11 @@ class DecodeSessionManager:
         A block that says `decode_rows_apart` is handed the tuples UNJOINED and hands
         tuples back: one whose step reads a small part of a large cache updates and
         reads each row's own arrays where they lie, and nothing joins, copies or splits
-        them. Keyed by the bucket alone; padding rows come in as arguments like live ones."""
+        them. The caches are DONATED, as a session's own step donates them (`_step_fn`):
+        every new leaf takes the buffer of the leaf it replaces, so a step allocates
+        and copies nothing for them, and a caller hands each position a cache of its
+        own (`_padding`) and keeps no leaf across the call. Keyed by the bucket alone;
+        padding rows come in as arguments like live ones."""
         key = (uid, stack)
         fn = self._batched_fns.get(key)
         if fn is None:
@@ -1024,23 +1097,50 @@ class DecodeSessionManager:
                     new = tuple(tuple(jnp.split(leaf, stack)) for leaf in new)
                 return y, new, routing, attended
 
-            # the caches are NOT donated: the padding row sits in several positions
-            # of one call, and a step that fails leaves every session as it was
             placed = self._cache_shardings(uid)
             fn = self._batched_fns[key] = tracked_jit(
                 self._named_by_kind(uid, batched_step, "batched_step_{kind}"), site="decode_session.batched_step",
+                donate_argnums=(2,),
                 out_shardings=(None, placed and tuple((leaf,) * stack for leaf in placed), None, None),
             )
         return fn
 
+    def _padding(self, uid: str, count: int) -> List[tuple]:
+        """``count`` throwaway caches (each its leaves) for the padding positions of one
+        batched program, taken OUT of the block's store: the program donates them, and a
+        buffer is donated once a call, so every position has a cache of its own and no
+        other call finds these meanwhile. Their outputs and cache writes are discarded;
+        the caller puts the new leaves back (`_keep_padding`). The store grows to the
+        largest padding a call has needed, made as a session's first prefill makes its
+        cache (`_fresh_caches`: placed like a session's, so that a padded call reaches
+        the program a full bucket compiled, and nothing new compiles for it)."""
+        if not count:  # a full bucket, which is what the cohort rule aims at
+            return []
+        with self._padding_lock:
+            store = self._padding_rows.setdefault(uid, [])
+            taken = [store.pop() for _ in range(min(count, len(store)))]
+        fresh = [tuple(jax.tree_util.tree_leaves(self._fresh_caches(self.backends[uid], 1))) for _ in range(count - len(taken))]
+        if fresh:
+            with self._padding_lock:
+                self._padding_bytes += sum(map(_row_bytes, fresh))
+                _PADDING_BYTES.set(self._padding_bytes)
+        return taken + fresh
+
+    def _keep_padding(self, uid: str, rows, lost: int = 0) -> None:
+        """Back into the block's store: ``rows``, the throwaway caches a program handed
+        back (or did not take); ``lost`` bytes of them went with a program that failed."""
+        with self._padding_lock:
+            self._padding_rows.setdefault(uid, []).extend(rows)
+            if lost:
+                self._padding_bytes -= lost
+                _PADDING_BYTES.set(self._padding_bytes)
+
     def _dummy_rows(self, uid: str) -> tuple:
-        """A throwaway cache (its leaves) used to pad batches to the bucket size; its
-        outputs and cache writes are discarded. Placed like a session's caches, so that
-        a padded call reaches the program a full bucket compiled."""
-        leaves = self._dummy_caches.get(uid)
-        if leaves is None:
-            leaves = self._dummy_caches[uid] = tuple(jax.tree_util.tree_leaves(self._fresh_caches(self.backends[uid], 1)))
-        return leaves
+        """ONE throwaway cache's leaves, as the block's padding positions hold them: what
+        a session's cache looks like (shapes, bytes, placement) to whoever lowers a program."""
+        [row] = self._padding(uid, 1)
+        self._keep_padding(uid, [row])
+        return row
 
     def _decode_batch(self, uid: str, entries: List, fetch: bool = True) -> List:
         """Run one batched step over `entries` [(future, session, x)]; returns one
@@ -1100,6 +1200,7 @@ class DecodeSessionManager:
                 span.set("bucket", stack)
                 span.set("cache", self._cache_kind(uid))
                 span.set("caches", caches)
+                span.set("donated", True)
             _CALLS_BATCHED.inc()
             with _batch_phase("assemble"):
                 # handles only: the rows' caches go in as they are, the write
@@ -1107,26 +1208,39 @@ class DecodeSessionManager:
                 # device: the last block's output where these are its rows, else one
                 # host array through the upload program
                 sessions = [entries[i][1] for i in live]
-                padding = stack - len(live)
                 xs = self._device_rows([entries[i][2] for i in live], stack)
                 # a padding row writes a valid mid-cache position; its output is discarded
-                indices = np.array([session.index for session in sessions] + [1] * padding, np.int32)
+                padding = self._padding(uid, stack - len(live))
+                indices = np.array([session.index for session in sessions] + [1] * len(padding), np.int32)
                 # leaf by leaf, the tuple of the rows' arrays (a pair: the keys' tuple and the values')
-                columns = tuple(zip(*[session.leaves for session in sessions] + [self._dummy_rows(uid)] * padding))
+                columns = tuple(zip(*[session.leaves for session in sessions] + padding))
                 step = self._batched_fn(uid, stack)
-            with _batch_phase("step"):
-                y, new, routing, attended = step(backend.snapshot_params(), xs, columns, indices)
-                output = _Output(y, routing, attended, len(live), held_range(backend.module))
-                if fetch:
-                    output.host()
-                    output.settle(span)
+            _DONATED_BATCHED.inc(stack * sessions[0].nbytes)  # every position's cache is one row of this block's, padding too
+            try:
+                with _batch_phase("step"):
+                    y, new, routing, attended = step(backend.snapshot_params(), xs, columns, indices)
+                    output = _Output(y, routing, attended, len(live), held_range(backend.module))
+                    if fetch:
+                        output.host()
+                        output.settle(span)
+            except Exception:
+                # the program took what it was handed (a step that raised before it donated anything, at its
+                # tracing, took nothing): those sessions go, and those throwaway caches
+                taken = lambda leaves: any(leaf.is_deleted() for leaf in leaves)  # noqa: E731
+                self._drop_failed([session for session in sessions if taken(session.leaves)])
+                kept = [row for row in padding if not taken(row)]
+                self._keep_padding(uid, kept, lost=(len(padding) - len(kept)) * sessions[0].nbytes)
+                raise
+            new = list(zip(*new))  # row by row, its new leaves
+            if padding:
+                self._keep_padding(uid, new[len(live):])
             _STEPS.inc(len(live), path="batched")
             _BATCHED_ROWS.inc(len(live), caches=caches)
             if self._cache_kind(uid) == "latent":
                 _LATENT_POSITIONS.inc(int(indices[:len(live)].sum()) + len(live), path="batched")
             with _batch_phase("scatter"):
                 now = time.monotonic()
-                for row, (i, session, leaves) in enumerate(zip(live, sessions, zip(*new))):  # row by row, its new leaves
+                for row, (i, session, leaves) in enumerate(zip(live, sessions, new)):
                     session.leaves = leaves
                     session.index += 1
                     session.last_used = now  # bare stores, no lock: `_batched_at`

@@ -15,9 +15,11 @@ the array, or in a batched step of a block that says ``decode_rows_apart`` the t
 rows' arrays, and returns ``(y, cache)`` in the form it came in). The manager keeps a session's
 tree as the tuple of its leaves and never looks inside one: it joins the leaves of a
 batch's rows along the batch axis and splits the new leaves back one a row (or hands a
-block that says ``decode_rows_apart`` the rows' arrays as they are), donates a
-per-session call's leaves, places and counts them leaf by leaf (`shard_decode_cache` of
-a mesh backend takes a pair). The block is handed the leaves in the tree's order and
+block that says ``decode_rows_apart`` the rows' arrays as they are), DONATES the
+leaves to every step, a per-session call's and a batched program's alike (the new leaves
+take the old ones' buffers: a block must not keep a reference to a cache argument, and
+what it hands back for a leaf has that leaf's shape and dtype), places and counts them
+leaf by leaf (`shard_decode_cache` of a mesh backend takes a pair). The block is handed the leaves in the tree's order and
 hands new ones back in the same order and shapes. ``index`` is the write position, in
 one of two ranks:
 
@@ -41,9 +43,13 @@ The blocks of one chain need not agree on the tree (`exaone_moe_block`: ``[batch
 kv_heads, slots, head_dim]``, a ring of ``window`` slots for a sliding-window block
 beside ``max_len`` slots for a full-attention one; `minicpm_sala_block`: three arrays
 beside one); that a session is full stays the manager's to say (``max_len``). A step
-that fails must leave no half-updated state: a per-session call donates the tree, so
-the manager drops the session when it fails; a batched step does not donate, and its
-sessions stay as they were. Optional class attributes:
+that fails must leave no half-updated state: both paths donate the tree, so the manager
+drops the sessions whose caches a failed step had taken, a session's own call's and every
+row's of a batched program's (and, in a cohort, those of the blocks dispatched before it);
+their clients' next continuations get the unknown-session ``KeyError`` and re-prefill
+(`hivemind_moe_decode_session_evictions_total{reason="failed_step"}`). A step that raises
+before anything was donated (a bad shape at tracing) leaves its sessions as they were.
+Optional class attributes:
 
 - ``decode_takes_length = True``: the manager passes one more argument to a per-session
   call, the number of REAL positions of the chunk (a chunk of more than one position
@@ -69,10 +75,10 @@ sessions stay as they were. Optional class attributes:
   mixer (ISSUE 41: a third of its program's time and 2.3 GB of its temporaries at 32 rows
   of 32,768 slots) and `deepseek_v3_block` (18.9 MB of latents a session at 16,384 slots).
   A ring of ``window`` slots (0.5 MB a session) or a recurrent state
-  (2.1 MB) stays JOINED: the join costs less than an operation a row. What stays of the
-  caches' traffic apart is one copy a row a step: the new array of an argument that is
-  not donated (below: a failed batched step leaves every session as it was). A session's
-  own call is handed arrays;
+  (2.1 MB) stays JOINED: the join costs less than an operation a row. Apart, nothing is
+  left of the caches' traffic but the step's own write and read: the arrays are donated
+  (ISSUE 50), so a ``dynamic_update_slice`` on a row's array writes into that array, and a
+  block that copied it first would pay the copy itself. A session's own call is handed arrays;
 - ``decode_cache_kind`` (a short string) names the block's decode programs
   (`jit_batched_step_<kind>`, `jit_prefill_<kind>_<positions>`) and its caches in the
   telemetry (`hivemind_moe_decode_cache_bytes{kind}`).

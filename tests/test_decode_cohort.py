@@ -160,6 +160,65 @@ def test_twenty_four_sessions_travel_as_sixteen_and_eight():
     assert manager._in_flight == {} and not manager._pending.get(chain)
 
 
+@pytest.mark.parametrize("late, waits", [(4, True), (12, True), (16, False)])
+def test_a_short_cohort_waits_for_the_one_on_the_device(late, waits, monkeypatch):
+    """With more than 16 rows under way, a cohort short of the bucket that holds half of them is
+    not launched while another cohort is still unanswered: that one's rows cannot come back before
+    it is, the others' are on their way, and a program costs by its bucket (ISSUE 50: a walk whose
+    dispatches got three times shorter outran its clients and ran buckets of 16 for 8 to 12 rows).
+    A full half bucket that waits is launched beside the cohort on the device, as ever."""
+    from hivemind_tpu.moe.server import decode_session
+
+    chain = CHAIN[:1]
+    manager, rng = _manager(chain), np.random.RandomState(late)
+    names = [f"s{i}" for i in range(16 + late)]
+    _prefill(manager, chain, names, rng)
+    for group in (names[:16], names[16:]):  # both buckets' programs compiled before anything is timed
+        warm = {name: rng.randn(1, 1, HID).astype(np.float32) for name in group}
+        assert not any(isinstance(out, Exception) for out in _step_together(manager, chain, warm).values())
+        for name, token in warm.items():  # the twins keep step
+            manager._decode_direct(chain, "twin-" + name, token, False)
+    tokens = {name: rng.randn(1, 1, HID).astype(np.float32) for name in names}
+    launching, launch, answering, answer = threading.Event(), threading.Event(), threading.Event(), threading.Event()
+    real_batch, real_host = manager._decode_batch, decode_session._Output.host
+
+    def first_launch_held(uid, entries, **how):
+        if len(entries) == 16 and not launch.is_set():
+            launching.set()
+            launch.wait(10)
+        return real_batch(uid, entries, **how)
+
+    def first_answer_held(self):
+        if self.rows == 16 and not answer.is_set():
+            answering.set()
+            answer.wait(10)
+        return real_host(self)
+
+    manager._decode_batch = first_launch_held
+    monkeypatch.setattr(decode_session._Output, "host", first_answer_held)
+    before = _counters()
+
+    async def scenario():
+        loop = asyncio.get_running_loop()
+        steps = [asyncio.create_task(manager.decode_span_async(chain, name, tokens[name], False)) for name in names[:16]]
+        await loop.run_in_executor(None, launching.wait, 10)
+        steps += [asyncio.create_task(manager.decode_span_async(chain, name, tokens[name], False)) for name in names[16:]]
+        await asyncio.sleep(0.01)  # they find a live drainer and only enqueue
+        launch.set()
+        await loop.run_in_executor(None, answering.wait, 10)
+        await asyncio.sleep(0.3)  # the first cohort is launched and unanswered: what has the drainer done with the rest?
+        launched_meanwhile = _moved(before)["cohorts"]
+        answer.set()
+        return launched_meanwhile, await asyncio.wait_for(asyncio.gather(*steps), 60.0)
+
+    launched_meanwhile, outs = asyncio.run(scenario())
+    assert launched_meanwhile == (1 if waits else 2)
+    assert _moved(before) == {"steps": 16 + late, "calls": 2, "direct_calls": 0, "cohorts": 2}
+    for name, out in zip(names, outs):
+        np.testing.assert_allclose(out, manager._decode_direct(chain, "twin-" + name, tokens[name], False), rtol=1e-5, atol=1e-5)
+    assert manager._in_flight == {} and not manager._pending.get(chain)
+
+
 def test_rows_past_a_full_bucket_are_the_next_cohort():
     manager, rng = _manager(), np.random.RandomState(7)
     names = [f"s{i}" for i in range(5)]
@@ -272,6 +331,94 @@ def test_a_program_that_fails_on_the_device_drops_the_sessions_it_was_handed_to(
         asyncio.run(manager.decode_span_async(CHAIN, "s0", rng.randn(1, 1, HID).astype(np.float32), False))
 
 
+def _failed_steps():
+    return REGISTRY.get("hivemind_moe_decode_session_evictions_total").labels("failed_step").value
+
+
+def test_a_program_that_fails_at_its_result_drops_its_batch_and_the_blocks_before_it(monkeypatch):
+    """Every batched step donates the rows' caches (ISSUE 50), so a failed one cannot give them
+    back. Block 0's program fails when its result is awaited, which the walk does once block 1
+    is dispatched: exactly the cohort's sessions at blocks 0 and 1 leave the table (block 1's
+    were handed outputs of a program fed by the one that failed), their futures get the error,
+    each is counted `reason="failed_step"`; their sessions at block 2, which no program was
+    handed, stay as they were, the other clients' sessions step on through the same programs,
+    and a dropped client's next continuation gets the unknown-session KeyError and re-prefills."""
+    from hivemind_tpu.moe.server import decode_session
+
+    manager, rng = _manager(), np.random.RandomState(50)
+    cohort, others = ["s0", "s1", "s2"], ["o0", "o1", "o2"]
+    _prefill(manager, CHAIN, cohort + others, rng)
+    untouched = {name: manager._sessions[(CHAIN[2], name)] for name in cohort}
+    leaves_before = {name: session.leaves for name, session in untouched.items()}
+    settle, calls = decode_session._Output.settle, []
+
+    def lost_on_the_first_wait(self, span=None):
+        calls.append(self)
+        if len(calls) == 1:
+            raise RuntimeError("device lost")
+        return settle(self, span)
+
+    before = _failed_steps()
+    monkeypatch.setattr(decode_session._Output, "settle", lost_on_the_first_wait)
+    outs = _step_together(manager, CHAIN, {name: rng.randn(1, 1, HID).astype(np.float32) for name in cohort})
+    monkeypatch.undo()
+    assert all(isinstance(out, RuntimeError) and "device lost" in str(out) for out in outs.values())
+    assert _failed_steps() - before == 2 * len(cohort)
+    gone = {(uid, name) for uid in CHAIN[:2] for name in cohort}
+    assert not gone & set(manager._sessions)
+    for name, session in untouched.items():  # never reached: as they were, and readable
+        assert manager._sessions[(CHAIN[2], name)] is session and session.index == 3
+        assert session.leaves is leaves_before[name] and not any(leaf.is_deleted() for leaf in session.leaves)
+    assert {name for _uid, name in manager._sessions} >= set(others) | {"twin-" + name for name in cohort + others}
+    assert manager._in_flight == {} and not any(session.lock.locked() for session in manager._sessions.values())
+    tokens = {name: rng.randn(1, 1, HID).astype(np.float32) for name in others}
+    outs = _step_together(manager, CHAIN, tokens)  # the same bucket's programs, other rows
+    for name, token in tokens.items():
+        np.testing.assert_allclose(outs[name], manager._decode_direct(CHAIN, "twin-" + name, token, False), rtol=1e-5, atol=1e-5)
+    token = rng.randn(1, 1, HID).astype(np.float32)
+    with pytest.raises(KeyError, match="unknown or expired"):
+        asyncio.run(manager.decode_span_async(CHAIN, "s0", token, False))
+    prompt = rng.randn(1, 3, HID).astype(np.float32)
+    for name in ("s0", "twin-s0"):  # the client re-prefills the whole chain, block 2's stale session replaced with the rest
+        manager._decode_direct(CHAIN, name, prompt, True)
+    assert manager._sessions[(CHAIN[2], "s0")] is not untouched["s0"]
+    np.testing.assert_allclose(manager._decode_direct(CHAIN, "s0", token, False), manager._decode_direct(CHAIN, "twin-s0", token, False),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_a_program_that_fails_at_its_dispatch_after_it_took_the_caches_drops_them_too():
+    """The program of block 1 is handed its rows' caches and then raises at the dispatch itself:
+    its batch's sessions hold deleted buffers and go, and so do the same rows' sessions at
+    block 0, whose step has ended while the chain's has not; block 2 is never reached. A
+    session's own step that fails counts the same reason."""
+    manager, rng = _manager(), np.random.RandomState(51)
+    names = ["s0", "s1"]
+    _prefill(manager, CHAIN, names, rng)
+    real = manager._batched_fn(CHAIN[1], 2)
+
+    def lost_after_the_dispatch(*args):
+        real(*args)
+        raise RuntimeError("device lost")
+
+    before = _failed_steps()
+    manager._batched_fns[(CHAIN[1], 2)] = lost_after_the_dispatch
+    outs = _step_together(manager, CHAIN, {name: rng.randn(1, 1, HID).astype(np.float32) for name in names})
+    manager._batched_fns[(CHAIN[1], 2)] = real
+    assert all(isinstance(out, RuntimeError) and "device lost" in str(out) for out in outs.values())
+    assert _failed_steps() - before == 2 * len(names)
+    assert {(uid, name) for uid, name in manager._sessions if name in names} == {(CHAIN[2], name) for name in names}
+    assert manager._in_flight == {} and not any(session.lock.locked() for session in manager._sessions.values())
+
+    def broken(*_args):
+        raise RuntimeError("device fault")
+
+    before = _failed_steps()
+    manager._step_fns[(CHAIN[0], 1, 1)] = broken
+    with pytest.raises(RuntimeError, match="device fault"):
+        manager.decode(CHAIN[0], "twin-s0", rng.randn(1, 1, HID).astype(np.float32), reset=False)
+    assert _failed_steps() - before == 1 and (CHAIN[0], "twin-s0") not in manager._sessions
+
+
 def test_cancelling_the_drainer_mid_cohort_cancels_every_pending_future():
     chain = CHAIN[:2]
     manager, rng = _manager(chain), np.random.RandomState(4)
@@ -331,6 +478,44 @@ def test_chains_that_share_blocks_neither_deadlock_nor_mix_rows():
             np.testing.assert_allclose(outs[name], want, rtol=1e-5, atol=1e-5)
     assert set(manager._drainers) == {whole, tail}
     assert _moved(before) == {"steps": 3 * (3 * 3 + 2 * 2), "calls": 3 * (3 + 2), "direct_calls": 3 * (3 * 3 + 2 * 2), "cohorts": 6}
+    assert manager._in_flight == {} and not any(session.lock.locked() for session in manager._sessions.values())
+
+
+def test_chains_that_share_blocks_never_hand_one_throwaway_cache_to_two_programs():
+    """[b0, b1, b2] beside [b1, b2], three rows each, so that BOTH chains' programs pad their
+    bucket of four at the shared blocks, from threads of their own and under a short switch
+    interval: a throwaway cache is out of the block's store while a program holds it (it is
+    donated), so no two calls can be handed the same one; every output equals its twin's, and
+    afterwards the store holds live arrays only, as many bytes as its gauge says."""
+    import sys
+
+    whole, tail, steps = CHAIN, CHAIN[1:], 5
+    manager, rng = _manager(), np.random.RandomState(52)
+    _prefill(manager, whole, ["w0", "w1", "w2"], rng)
+    _prefill(manager, tail, ["t0", "t1", "t2"], rng, length=4)
+    chain_of = {"w0": whole, "w1": whole, "w2": whole, "t0": tail, "t1": tail, "t2": tail}
+    tokens = {name: rng.randn(steps, 1, 1, HID).astype(np.float32) for name in chain_of}
+
+    async def stream(name):
+        return [await manager.decode_span_async(chain_of[name], name, tokens[name][step], False) for step in range(steps)]
+
+    async def scenario():
+        return await asyncio.wait_for(asyncio.gather(*(stream(name) for name in chain_of)), 120.0)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        outs = dict(zip(chain_of, asyncio.run(scenario())))
+    finally:
+        sys.setswitchinterval(interval)
+    for name, chain in chain_of.items():
+        for step in range(steps):
+            want = manager._decode_direct(chain, "twin-" + name, tokens[name][step], False)
+            np.testing.assert_allclose(outs[name][step], want, rtol=1e-5, atol=1e-5)
+    kept = [row for rows in manager._padding_rows.values() for row in rows]
+    assert kept and not any(leaf.is_deleted() for row in kept for leaf in row)
+    assert len({id(leaf) for row in kept for leaf in row}) == sum(len(row) for row in kept)
+    assert manager._padding_bytes == sum(leaf.nbytes for row in kept for leaf in row)
     assert manager._in_flight == {} and not any(session.lock.locked() for session in manager._sessions.values())
 
 

@@ -8,10 +8,17 @@ block itself, and the batched program against the per-session one. Then, for eve
 block of `layers/common.py`, what the program's text says: which blocks join their
 rows' caches, and that a row's step is one function the rows share. Small sizes,
 seeded weights; `tests/test_exaone_block.py` holds the same for K-EXAONE's two kinds
-against its reference, `tests/test_tpu_compile.py` the program at published widths."""
+against its reference, `tests/test_tpu_compile.py` the program at published widths.
+
+Since ISSUE 50 the batched program DONATES the rows' cache leaves, whatever the block: what
+that means for a session's leaves, for the padding positions of a bucket (a throwaway cache
+each, kept and counted by `hivemind_moe_decode_padding_cache_bytes`) and for the compiled
+program's aliases is held here for every kind of cache the repo serves; what a FAILED step
+leaves behind in a cohort is `tests/test_decode_cohort.py`'s."""
 
 import re
 import sys
+import warnings
 from pathlib import Path
 
 import jax.numpy as jnp
@@ -44,9 +51,9 @@ EVERY = {  # name -> (class, sizes, what a batched program does with its rows' c
 SERVED_TOL = 2e-2  # bf16 activations on both sides; the cache path sums its scores in another order
 
 
-def make_backend(block: str, sizes: dict, uid="blk.0", seed=3) -> ModuleBackend:
-    return ModuleBackend(uid, name_to_block[block](HID, **sizes), optimizer=optax.sgd(0.0),
-                         sample_input=name_to_input[block](4, HID), max_batch_size=8, rng_seed=seed)
+def make_backend(block: str, sizes: dict, uid="blk.0", seed=3, hidden=HID) -> ModuleBackend:
+    return ModuleBackend(uid, name_to_block[block](hidden, **sizes), optimizer=optax.sgd(0.0),
+                         sample_input=name_to_input[block](4, hidden), max_batch_size=8, rng_seed=seed)
 
 
 def stream(seed: int, batch: int, length: int) -> np.ndarray:
@@ -146,3 +153,169 @@ def test_what_the_batched_programs_text_joins(name):
     assert not joins, joins
     assert len(re.findall(rf"func\.func private @{row_step}\(", text)) == 1, "the rows do not share one traced step"
     assert len(re.findall(rf"call @{row_step}\(", text)) == rows
+
+
+# ---- ISSUE 50: the batched program donates the rows' cache leaves -----------------------------------------
+
+SALA = dict(num_heads=4, num_kv_heads=2, head_dim=16, ffn_inner=96, kernel_size=4, kernel_stride=2, block_size=8, topk=6,
+            init_blocks=1, window_size=16, dense_len=64)
+LATENT = dict(mlp="dense", num_heads=4, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=12,
+              rope_theta=1e5, rope_factor=8.0, rope_original=32, ffn_inner=96)
+CACHE_KINDS = {  # what a session keeps -> (class, hidden, sizes, what a batched program does with the rows' caches, leaves a session)
+    "pair": ("llama_block", HID, DENSE["llama_block"], "apart", 2),
+    "ring": ("exaone_moe_block", HID, dict(window=8, **EXAONE), "joined", 2),
+    "sparse_tree": ("minicpm_sala_block", 64, dict(mixer="minicpm4", **SALA), "apart", 3),
+    "lightning_state": ("minicpm_sala_block", 64, dict(mixer="lightning-attn", **SALA), "joined", 1),
+    "latent": ("deepseek_v3_block", 64, LATENT, "apart", 1),
+}
+
+
+def kind_backend(kind: str, uid="blk.0"):
+    block, hidden, sizes, caches, leaves = CACHE_KINDS[kind]
+    return make_backend(block, sizes, uid, hidden=hidden), hidden, caches, leaves
+
+
+def donated_bytes(path: str) -> float:
+    return REGISTRY.get("hivemind_moe_decode_cache_bytes_donated_total").labels(path).value
+
+
+def padding_gauge() -> float:
+    return REGISTRY.get("hivemind_moe_decode_padding_cache_bytes").value()
+
+
+def alias_count(compiled_text: str) -> int:
+    """How many outputs the compiled program's `input_output_alias` ties to a parameter."""
+    header = next(line for line in compiled_text.splitlines() if line.startswith("HloModule"))
+    found = re.search(r"input_output_alias=\{(.*?)\}, entry_computation_layout", header)
+    return len(re.findall(r"\{[\d, ]*\}: \(\d+, \{[\d, ]*\}, (?:may|must)-alias\)", found.group(1))) if found else 0
+
+
+@pytest.mark.parametrize("kind", sorted(CACHE_KINDS))
+def test_a_batched_step_takes_every_rows_leaves_and_hands_back_readable_ones(kind):
+    """After a batched step every live session's former leaves are deleted buffers (the program
+    was handed them for good) and its new ones can be read; the bytes handed over are counted
+    from the shapes, the padding position's throwaway cache among them; the span says so."""
+    from hivemind_tpu.telemetry.tracing import RECORDER
+
+    backend, hidden, caches, leaves = kind_backend(kind)
+    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    assert manager._rows_caches(backend.name) == caches
+    lengths = [5, 9, 7]
+    x = np.random.default_rng(1).standard_normal((3, 16, hidden)).astype(np.float32)
+    before_direct = donated_bytes("direct")
+    sessions = prefilled_rows(manager, backend.name, x, lengths)
+    assert donated_bytes("direct") - before_direct == sum(session.nbytes for session in sessions), "a prefill donates a fresh cache"
+    for step in range(2):
+        former = [session.leaves for session in sessions]
+        assert all(len(row) == leaves and not any(leaf.is_deleted() for leaf in row) for row in former)
+        before = donated_bytes("batched")
+        outs = manager._decode_batch(backend.name, [(None, session, x[row:row + 1, length + step:length + step + 1])
+                                                    for row, (session, length) in enumerate(zip(sessions, lengths))])
+        assert not any(isinstance(out, Exception) for out in outs) and all(np.isfinite(out).all() for out in outs)
+        assert all(leaf.is_deleted() for row in former for leaf in row), "a leaf outlived the program that was handed it"
+        for session, was in zip(sessions, former):
+            assert [(leaf.shape, leaf.dtype) for leaf in session.leaves] == [(leaf.shape, leaf.dtype) for leaf in was]
+            assert all(np.isfinite(np.asarray(leaf, np.float32)).all() for leaf in session.leaves)
+        # three live rows in a bucket of four: the fourth position's throwaway cache is handed over like a session's
+        assert donated_bytes("batched") - before == 4 * sessions[0].nbytes
+    [span] = [s for s in RECORDER.snapshot() if s.name == "decode.batch" and (s.attributes or {}).get("uid") == backend.name][-1:]
+    assert span.attributes["donated"] is True and span.attributes["caches"] == caches
+    assert not any(leaf.is_deleted() for leaf in manager._dummy_rows(backend.name)), "the throwaway row that is kept is the new one"
+
+
+@pytest.mark.parametrize("kind", ["pair", "ring"])
+def test_every_padding_position_has_a_cache_of_its_own(kind):
+    """Five sessions in a bucket of eight: THREE padding positions, each a throwaway cache of
+    its own (one array in two positions of a call cannot be donated). The batch runs, every row
+    equals the same token through the per-session program, the throwaway rows are distinct
+    arrays before the step and after it (the new ones: the old were handed over), the gauge
+    holds three rows' bytes and no more after a second step, and `clear_sessions()` releases them."""
+    backend, hidden, _caches, leaves = kind_backend(kind)
+    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    twins = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    lengths = [4, 7, 5, 9, 6]
+    x = np.random.default_rng(2).standard_normal((5, 16, hidden)).astype(np.float32)
+    sessions = prefilled_rows(manager, backend.name, x, lengths)
+    prefilled_rows(twins, backend.name, x, lengths)
+    row_bytes = sessions[0].nbytes
+    seen = []
+    for step in range(2):
+        outs = manager._decode_batch(backend.name, [(None, session, x[row:row + 1, length + step:length + step + 1])
+                                                    for row, (session, length) in enumerate(zip(sessions, lengths))])
+        for row, (out, length) in enumerate(zip(outs, lengths)):
+            want = twins.decode(backend.name, f"row{row}", x[row:row + 1, length + step:length + step + 1], reset=False)
+            np.testing.assert_allclose(out, want, rtol=2e-2, atol=2e-2)
+        kept = manager._padding_rows[backend.name]
+        assert len(kept) == 3 and len({id(leaf) for row in kept for leaf in row}) == 3 * leaves
+        assert not any(leaf.is_deleted() for row in kept for leaf in row)
+        assert manager._padding_bytes == 3 * row_bytes
+        seen.append(kept[:])
+    assert all(leaf.is_deleted() for row in seen[0] for leaf in row), "the second step was handed the first one's throwaway rows"
+    assert not {id(leaf) for row in seen[1] for leaf in row} & {id(leaf) for session in sessions for leaf in session.leaves}
+    assert list(manager._batched_fns) == [(backend.name, 8)], "the batch's program is keyed by (uid, bucket) alone"
+    manager._decode_batch(backend.name, [(None, session, x[row:row + 1, 12:13]) for row, session in enumerate(sessions[:3])])  # pads by one
+    assert manager._padding_bytes == 3 * row_bytes and padding_gauge() == 3 * row_bytes, "the store keeps the largest padding a call has needed"
+    manager.clear_sessions()
+    assert manager._padding_rows == {} and manager._padding_bytes == 0 and padding_gauge() == 0
+    assert manager._dummy_rows(backend.name)[0].shape == sessions[0].leaves[0].shape  # whoever lowers a program is handed a fresh row
+
+
+@pytest.mark.parametrize("kind", sorted(CACHE_KINDS))
+def test_the_compiled_batched_program_aliases_every_cache_leaf_of_every_row(kind):
+    """The program `_batched_fn` builds for a bucket of 4, compiled (here by the CPU's compiler,
+    which donates as the chip's does): every cache leaf of every row is aliased to an output,
+    whether the block takes the rows' caches apart or joins them, and jax has no donated buffer
+    that it could not use. A block whose step copied its cache argument before writing would
+    show here as an alias short."""
+    backend, hidden, _caches, leaves = kind_backend(kind)
+    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    rows, row = 4, manager._dummy_rows(backend.name)
+    assert len(row) == leaves
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        compiled = manager._batched_fn(backend.name, rows).jitted.lower(
+            backend.params, jnp.zeros((rows, 1, hidden), jnp.float32), tuple((leaf,) * rows for leaf in row), jnp.ones((rows,), jnp.int32)).compile()
+    assert not [str(w.message) for w in caught if "donated" in str(w.message).lower()]
+    assert alias_count(compiled.as_text()) == leaves * rows
+    assert alias_count(manager._step_fn(backend.name, 1, 1).jitted.lower(
+        backend.params, jnp.zeros((1, 1, hidden), jnp.float32), row, jnp.int32(1),
+        *((jnp.int32(1),) if manager._takes_length(backend.name) else ())).compile().as_text()) == leaves, "the per-session step"
+
+
+def test_a_program_that_fails_after_it_took_the_caches_drops_its_sessions_and_its_padding():
+    """`_decode_batch` alone (a benchmark's check, a chain of one): a program that was handed
+    three sessions' caches and a throwaway row and then fails leaves deleted buffers behind.
+    Those sessions leave the table, counted `reason="failed_step"`, the lost throwaway row
+    leaves the gauge, sessions outside the batch step on, and the next batch of the same
+    bucket runs with a fresh throwaway row."""
+    backend = make_backend("llama_block", DENSE["llama_block"])
+    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    lengths = [4, 6, 5, 7, 3, 8]
+    x = stream(9, len(lengths), 16)
+    sessions = prefilled_rows(manager, backend.name, x, lengths)
+    entries = lambda rows, step: [(None, sessions[row], x[row:row + 1, lengths[row] + step:lengths[row] + step + 1]) for row in rows]  # noqa: E731
+    assert not any(isinstance(out, Exception) for out in manager._decode_batch(backend.name, entries([0, 1, 2], 0)))
+    assert manager._padding_bytes == sessions[0].nbytes
+    real = manager._batched_fns[(backend.name, 4)]
+
+    def lost_after_the_dispatch(*args):
+        real(*args)
+        raise RuntimeError("device lost")
+
+    failed = REGISTRY.get("hivemind_moe_decode_session_evictions_total").labels("failed_step")
+    before = failed.value
+    manager._batched_fns[(backend.name, 4)] = lost_after_the_dispatch
+    with pytest.raises(RuntimeError, match="device lost"):
+        manager._decode_batch(backend.name, entries([0, 1, 2], 1))
+    manager._batched_fns[(backend.name, 4)] = real
+    assert failed.value - before == 3
+    assert sorted(name for _uid, name in manager._sessions) == ["row3", "row4", "row5"]
+    assert manager._padding_bytes == 0 and padding_gauge() == 0 and manager._padding_rows[backend.name] == []
+    assert not any(session.lock.locked() for session in sessions)
+    with pytest.raises(KeyError, match="unknown or expired"):
+        manager.decode(backend.name, "row0", x[:1, 6:7], reset=False)
+    outs = manager._decode_batch(backend.name, entries([3, 4, 5], 0))
+    assert not any(isinstance(out, Exception) for out in outs) and [sessions[row].index for row in (3, 4, 5)] == [8, 4, 9]
+    assert manager._padding_bytes == sessions[3].nbytes
+    manager.decode(backend.name, "row0", x[:1, :4], reset=True)  # the client re-prefills, and steps on
+    assert manager.decode(backend.name, "row0", x[:1, 4:5], reset=False).shape == (1, 1, HID)

@@ -337,8 +337,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
 def test_a_failed_step_leaves_no_half_updated_state(monkeypatch):
     """A per-session step DONATES the latent cache: one that fails drops the session (the next
     continuation gets the unknown-session KeyError and re-prefills). A batched step steps on
-    the rows' own arrays and does not donate: one that fails leaves every session's array and
-    position as they were."""
+    the rows' own arrays and donates them too (ISSUE 50); one that raises before it took
+    anything (the stand-in here raises at once) leaves every session's array and position
+    as they were."""
     backend = make_backend("sparse")
     assert backend.module.decode_rows_apart and backend.module.decode_takes_chunks and backend.module.decode_cache_kind == "latent"
     manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)

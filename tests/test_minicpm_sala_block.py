@@ -205,9 +205,11 @@ def older_backend(name: str, uid: str, **overrides) -> ModuleBackend:
 def test_a_failed_step_leaves_no_half_updated_state(kind, monkeypatch):
     """A per-session step DONATES the cache tree: one that fails drops the session (the
     next continuation gets the unknown-session KeyError and re-prefills). A batched step
-    does not donate, whether it joins the rows' caches or steps on them where they lie
-    (`decode_rows_apart`: the sparse block, and since ISSUE 42 the four blocks that keep
-    ``max_len`` slots): one that fails leaves every session's tree and position as they were."""
+    donates too since ISSUE 50, whether it joins the rows' caches or steps on them where
+    they lie (`decode_rows_apart`: the sparse block, and since ISSUE 42 the four blocks that
+    keep ``max_len`` slots); one that raises BEFORE it took anything (the stand-in here
+    raises at once, as a bad shape at tracing does) leaves every session's tree and position
+    as they were. One that fails after it took them: `tests/test_decode_rows_apart.py`."""
     if kind in MIXERS:
         backend = make_backend(kind)
     else:

@@ -339,7 +339,10 @@ def _batched_compiles() -> int:
 def test_warmed_decode_batch_dispatches_nothing_per_session(rows, monkeypatch):
     """A warmed vmapped batch is ONE dispatched program: stacking the rows' caches
     and handing the new ones out happen inside it, so the eager primitives around
-    it (each a dispatch of its own) number the same, none, whatever the rows."""
+    it (each a dispatch of its own) number the same, none, whatever the rows. The
+    one exception is not a step's: the first batch that pads its bucket by more
+    positions than any before it makes the throwaway caches it lacks (ISSUE 50: a
+    position each, the program donates them), as a prefill makes a session's."""
     from jax._src import core as jax_core
 
     uid = "lim.0"
@@ -356,12 +359,18 @@ def test_warmed_decode_batch_dispatches_nothing_per_session(rows, monkeypatch):
     steps = REGISTRY.get("hivemind_moe_decode_steps_total")
     batched_before = steps.labels("batched").value
     monkeypatch.setattr(jax_core.EvalTrace, "process_primitive", counting)
+    manager._fresh_caches(manager.backends[uid], 1)
+    a_cache, eager[:] = len(eager), []
+    padding = {2: 0, 5: 3, 8: 0}[rows]  # the warm-up padded by one
+    manager._decode_batch(uid, [(None, session, token) for session in sessions])
+    assert len(eager) == a_cache * max(padding - 1, 0), f"{len(eager)} eager primitives around a first batch of {rows} rows"
+    eager[:] = []
     results = manager._decode_batch(uid, [(None, session, token) for session in sessions])
     monkeypatch.undo()
     assert eager == [], f"{len(eager)} eager primitives around a batch of {rows} rows: {sorted(set(eager))}"
-    assert steps.labels("batched").value == batched_before + rows
+    assert steps.labels("batched").value == batched_before + 2 * rows
     assert all(isinstance(out, np.ndarray) and out.shape == (1, 1, HID) for out in results)
-    assert all(session.index == 4 and session.cache_k.shape[0] == 1 for session in sessions)
+    assert all(session.index == 5 and session.cache_k.shape[0] == 1 for session in sessions)
 
 
 def test_decode_batch_program_is_keyed_by_the_bucket_alone():

@@ -83,6 +83,10 @@ def _compiled_batched_step(module, hidden: int, max_len: int, rows: int, one_chi
     compiled = manager._batched_fn("blk.0", rows).jitted.lower(
         on_chip(params), _shape((rows, 1, hidden), jnp.float32, one_chip), tuple((leaf,) * rows for leaf in cache),  # leaf by leaf, the rows' arrays
         _shape((rows,), jnp.int32, one_chip)).compile()
+    # every cache leaf of every row is aliased to an output (ISSUE 50: the program donates them), by the chip's
+    # compiler's own account; a block whose step copied a cache argument first would come out short here
+    cache_bytes = rows * sum(leaf.size * leaf.dtype.itemsize for leaf in cache)
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes, (compiled.memory_analysis().alias_size_in_bytes, cache_bytes)
     return compiled, [leaf.shape for leaf in cache]
 
 
@@ -93,9 +97,10 @@ def _joined(text: str, rows: int, leaf_shape) -> int:
 
 def test_batched_decode_step_of_eight_sessions_fits_the_chip(one_chip, no_compile_cache):
     """OLMoE's block at a bucket of 8 with 4,096-slot caches. Since ISSUE 42 the program steps on
-    the rows' own caches (`decode_rows_apart`): what it needs beside its arguments is its outputs,
-    8 x 2 x 16.8 MB = 256 MiB (the one copy a row that no donation leaves), and 2.8 MiB of
-    temporaries; while it joined the caches it held 386.5 MiB of temporaries beside them."""
+    the rows' own caches (`decode_rows_apart`), and since ISSUE 50 in the donated arrays themselves:
+    its outputs, 8 x 2 x 16.8 MB = 256 MiB, are all aliased to arguments (`_compiled_batched_step`
+    holds that), so beside its arguments it needs 2.8 MiB of temporaries; while it joined the
+    caches it held 386.5 MiB of temporaries beside them."""
     from hivemind_tpu.moe.server.layers import name_to_block
 
     module = name_to_block["olmoe_block"](HIDDEN, num_heads=HEADS, num_experts=EXPERTS, experts_per_token=TOP_K, expert_inner=INNER)
@@ -112,7 +117,8 @@ def test_batched_decode_step_of_eight_sessions_fits_the_chip(one_chip, no_compil
 def test_batched_full_attention_step_of_sixteen_sessions_fits_the_chip(one_chip, no_compile_cache):
     """K-EXAONE's full-attention block (sparse layer, 8 of 128 experts held) at a bucket of 16
     with 8,192-slot caches, 33.5 MB a session: no array of the joined caches' shape, outputs
-    16 x 33.5 MB = 512 MiB and 100.9 MiB of temporaries (963.2 MiB while the caches were joined).
+    16 x 33.5 MB = 512 MiB, aliased to the donated arguments, and 100.9 MiB of temporaries
+    (963.2 MiB while the caches were joined).
     A window block's rings (0.5 MB a session) stay joined."""
     from hivemind_tpu.moe.server.layers import name_to_block
 
@@ -173,7 +179,8 @@ def test_latent_batched_step_of_32_sessions_fits_the_chip(one_chip, no_compile_c
     compiles, names its scopes, keeps each row's array in the layout that puts the positions
     on the lanes (576 is no multiple of 128: neither the latent nor the shared key is padded),
     expands no cache, and its temporaries stay small beside 10.61 GB of weights and 2.26 GB of
-    sessions (ISSUE 43: 0.03 GB when written; the 32 new arrays are outputs, 0.45 GB)."""
+    sessions (ISSUE 43: 0.03 GB when written; the 32 new arrays are outputs, 0.45 GB, since ISSUE 50
+    in the donated arguments' buffers)."""
     from hivemind_tpu.moe.server.layers import name_to_block
 
     hidden, max_len, rows = 7168, 12288, 32
