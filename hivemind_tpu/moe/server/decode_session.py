@@ -9,8 +9,10 @@ first call prefills the prompt (or its first chunk) into fresh caches, and every
 later call advances one token — or, on a chain whose blocks all take chunks, brings
 the prompt's next chunk. A session's cache lives on-device as whatever TREE of arrays
 the block's `init_decode_cache` returned (a `(cache_k, cache_v)` pair, a recurrent
-state, keys with values and compressed keys): the manager joins, splits, donates,
-places and counts it leaf by leaf and never looks inside. EVERY step donates the leaves it
+state, keys with values and compressed keys, a convolution window beside a state, or
+NOTHING: a block that keeps no cache has a tree of no leaf and still a session at every block
+of its chain): the manager joins, splits, donates, places and counts it leaf by leaf and never
+looks inside. EVERY step donates the leaves it
 is handed, a session's own call and a batched program alike: the new leaves take the old
 ones' buffers, nothing is copied or allocated for them, and a block must not keep a
 reference to a cache argument. The step function is jitted once per
@@ -60,8 +62,9 @@ the block says of its own step (`decode_rows_apart`; counted by
 slots, of which a step writes one and reads the rest (`causal_transformer`, `llama_block`,
 `olmoe_block`, `exaone_moe_block` with ``window`` = 0, `minicpm_sala_block`'s sparse mixer:
 tens of MB a session): APART, the block updates and reads each row's own arrays where they
-lie, in the donated buffers themselves. A ring of ``window`` slots or a recurrent state
-(`exaone_moe_block` with a window, the lightning mixer: 0.5 to 2 MB a session): JOINED leaf
+lie, in the donated buffers themselves; so does a state-space state of 4 MB a session that a
+step rewrites whole (`nemotron_h_block`'s mixer: measured both ways, ISSUE 51). A ring of ``window``
+slots or a recurrent state of 2 MB (`exaone_moe_block` with a window, the lightning mixer): JOINED leaf
 by leaf along the batch axis, stepped as one array and split again, which costs less than
 an operation a row (the split's outputs may take the donated inputs' buffers). A bucket's
 padding positions each get a throwaway cache of their own (`_padding`: a buffer is donated
@@ -199,7 +202,7 @@ _BATCHED_ROWS = _TELEMETRY.counter(
     "hivemind_moe_decode_batched_rows_total",
     "live rows of batched decode programs, by what the program did with their caches (apart = each row's own "
     "arrays updated and read where they lie, a block that says decode_rows_apart; joined = the rows' caches "
-    "joined along the batch axis before the step and split after it)",
+    "joined along the batch axis before the step and split after it; none = a block that keeps no cache)",
     ("caches",),
 )
 # what the session table pins on the device, by the kind of cache a block keeps
@@ -212,7 +215,8 @@ _CACHE_BYTES = _TELEMETRY.gauge(
     "bytes of decode caches that the session table holds, by kind of cache as the block names it (window = a ring "
     "of a sliding-window block's last positions, full = every position of the session, sparse = keys, values and "
     "compressed keys of a block-sparse attention block, lightning = a linear-attention block's recurrent state, latent = the "
-    "normed latents and the shared rotated key of a latent-attention block)",
+    "normed latents and the shared rotated key of a latent-attention block, ssm = a state-space block's recurrent state and "
+    "its convolution window; a block that keeps nothing has no series)",
     ("kind",),
 )
 _CACHE_ENTRIES = _TELEMETRY.gauge(
@@ -243,6 +247,16 @@ _LATENT_POSITIONS = _TELEMETRY.counter(
     "positions that the steps of blocks with a latent cache (decode_cache_kind latent: multi-head latent attention) "
     "attended: a live row a step a block, its write position + 1, padding rows excluded; by the step's path (batched "
     "= a row of a cohort's program, direct = a session's own step); prompt chunks are not counted",
+    ("path",),
+)
+# a state-space block's step rewrites its whole state, whatever the context: the bytes it must move are the
+# program's own count (ISSUE 51), from the shapes and the live rows, on the host
+_SSM_STATE_BYTES = _TELEMETRY.counter(
+    "hivemind_moe_ssm_state_bytes_total",
+    "bytes of recurrent state and convolution window that the steps of blocks with a state-space cache "
+    "(decode_cache_kind ssm) rewrote: a live row a step a block, the row's cache as the session holds it, padding rows "
+    "excluded; by the step's path (batched = a row of a cohort's program, direct = a session's own step); prompt "
+    "chunks are not counted",
     ("path",),
 )
 _COHORTS = _TELEMETRY.counter(
@@ -311,15 +325,17 @@ def _half_bucket(active: int) -> int:
 class _Session:
     __slots__ = ("leaves", "tree", "nbytes", "batch", "index", "last_used", "lock", "batch_started")
 
-    def __init__(self, cache):
+    def __init__(self, cache, batch: int):
         # whatever the block's `init_decode_cache` returned: a tree of arrays, batch axis
-        # first (a `(cache_k, cache_v)` pair is a tree of two leaves); nothing here looks
-        # inside. It is kept FLAT, as the tuple of its leaves: that is what a block is handed
-        # and hands back, so no step and no batch walks a tree on the host
+        # first (a `(cache_k, cache_v)` pair is a tree of two leaves; a block that keeps
+        # nothing between calls returns a tree of NONE, and its session is a position and a
+        # batch); nothing here looks inside. It is kept FLAT, as the tuple of its leaves:
+        # that is what a block is handed and hands back, so no step and no batch walks a
+        # tree on the host
         leaves, self.tree = jax.tree_util.tree_flatten(cache)
         self.leaves = tuple(leaves)
         self.nbytes = _row_bytes(leaves)  # a step hands back leaves of the same shapes
-        self.batch = leaves[0].shape[0]
+        self.batch = batch
         self.index = 0
         self.last_used = time.monotonic()
         # perf_counter at which the batch carrying this session's pending step
@@ -510,6 +526,8 @@ class DecodeSessionManager:
         """A session enters (+1) or leaves (-1) the table at ``uid``: its bytes and
         its entry onto the gauges of that block's kind of cache. One addition a
         change of the table, and never a walk of it at a step or a prefill."""
+        if not session.leaves:  # a block that keeps nothing pins nothing: its kind has no series
+            return
         kind = self._cache_kind(uid)
         tally = self._cache_tally.setdefault(kind, [0, 0])
         tally[0] += entries * session.nbytes
@@ -672,8 +690,11 @@ class DecodeSessionManager:
                 )
                 record_routing(routing, "direct", span, positions=new_len, held=held_range(backend.module))
                 record_attended(attended, positions=new_len)
-                if chunk_len == 1 and self._cache_kind(uid) == "latent":
+                kind = self._cache_kind(uid) if chunk_len == 1 else None  # a step's work that the model's sizes alone do not give
+                if kind == "latent":
                     _LATENT_POSITIONS.inc(session.index + 1, path="direct")
+                elif kind == "ssm":
+                    _SSM_STATE_BYTES.inc(session.nbytes, path="direct")
                 # the next block is dispatched when this one has finished: a cohort's
                 # program that arrives meanwhile waits for one block of a prefill, not
                 # for the chain
@@ -762,7 +783,7 @@ class DecodeSessionManager:
             if reset:
                 if key in self._sessions:
                     self._drop_locked([key])
-                session = self._sessions[key] = _Session(self._fresh_caches(self.backends[uid], batch))
+                session = self._sessions[key] = _Session(self._fresh_caches(self.backends[uid], batch), batch)
                 self._count_cache_locked(uid, session, +1)
                 _RESETS.inc()
                 self._stamp_locked(uid, (session,), time.monotonic())
@@ -1195,10 +1216,13 @@ class DecodeSessionManager:
                 results[i] = np.asarray(y)[:, :1]
                 record_transfer(results[i].nbytes, "device_to_host")
                 return results
-            stack, caches = _next_pow2(len(live)), self._rows_caches(uid)
+            sessions = [entries[i][1] for i in live]
+            # a block that keeps nothing has no caches to take apart or join: "none"
+            stack, caches = _next_pow2(len(live)), self._rows_caches(uid) if sessions[0].leaves else "none"
+            kind = self._cache_kind(uid)
             if span is not None:
                 span.set("bucket", stack)
-                span.set("cache", self._cache_kind(uid))
+                span.set("cache", kind)
                 span.set("caches", caches)
                 span.set("donated", True)
             _CALLS_BATCHED.inc()
@@ -1207,7 +1231,6 @@ class DecodeSessionManager:
                 # positions as one host array, the activations as one array on the
                 # device: the last block's output where these are its rows, else one
                 # host array through the upload program
-                sessions = [entries[i][1] for i in live]
                 xs = self._device_rows([entries[i][2] for i in live], stack)
                 # a padding row writes a valid mid-cache position; its output is discarded
                 padding = self._padding(uid, stack - len(live))
@@ -1231,13 +1254,15 @@ class DecodeSessionManager:
                 kept = [row for row in padding if not taken(row)]
                 self._keep_padding(uid, kept, lost=(len(padding) - len(kept)) * sessions[0].nbytes)
                 raise
-            new = list(zip(*new))  # row by row, its new leaves
+            new = list(zip(*new)) if new else [()] * stack  # row by row, its new leaves (of a tree of none: none)
             if padding:
                 self._keep_padding(uid, new[len(live):])
             _STEPS.inc(len(live), path="batched")
             _BATCHED_ROWS.inc(len(live), caches=caches)
-            if self._cache_kind(uid) == "latent":
+            if kind == "latent":
                 _LATENT_POSITIONS.inc(int(indices[:len(live)].sum()) + len(live), path="batched")
+            elif kind == "ssm":
+                _SSM_STATE_BYTES.inc(len(live) * sessions[0].nbytes, path="batched")
             with _batch_phase("scatter"):
                 now = time.monotonic()
                 for row, (i, session, leaves) in enumerate(zip(live, sessions, new)):

@@ -12,7 +12,15 @@ keys that a step appends to (`minicpm_sala_block`, both), or ONE array of compre
 latents that every head's key and value are expanded from (`deepseek_v3_block`; a tree of
 one leaf is still handed over as ``*cache``: the block's ``__call__(x, cache, index)`` gets
 the array, or in a batched step of a block that says ``decode_rows_apart`` the tuple of the
-rows' arrays, and returns ``(y, cache)`` in the form it came in). The manager keeps a session's
+rows' arrays, and returns ``(y, cache)`` in the form it came in), or a window of the last few
+inputs of a short convolution (a state with a TIME AXIS of the kernel's width, which a step
+rolls by one) beside a recurrent state (`nemotron_h_block`'s Mamba-2 mixer), or NOTHING: a
+block that keeps nothing between calls (`nemotron_h_block`'s expert layer: one residual a
+block, so a feed-forward part is a block of its own) returns a tree of ZERO leaves, ``()``,
+is called ``(x, index)`` and returns ``(y,)``. Such a block still sits in a decode chain: it
+has a session at every block like any other (a position and a batch; no byte, no gauge
+series, nothing to join, donate, pad or drop but the entry itself), because a chain is
+served only if every block of it is. The manager keeps a session's
 tree as the tuple of its leaves and never looks inside one: it joins the leaves of a
 batch's rows along the batch axis and splits the new leaves back one a row (or hands a
 block that says ``decode_rows_apart`` the rows' arrays as they are), DONATES the
@@ -74,8 +82,14 @@ Optional class attributes:
   every step were a quarter of OLMoE's device time) and `minicpm_sala_block`'s sparse
   mixer (ISSUE 41: a third of its program's time and 2.3 GB of its temporaries at 32 rows
   of 32,768 slots) and `deepseek_v3_block` (18.9 MB of latents a session at 16,384 slots).
-  A ring of ``window`` slots (0.5 MB a session) or a recurrent state
-  (2.1 MB) stays JOINED: the join costs less than an operation a row. Apart, nothing is
+  A state that a step REWRITES WHOLE goes by its size, re-read on a trace in ISSUE 51 and not
+  assumed: `nemotron_h_block`'s state-space state of 4.19 MB a session goes APART (joined, 16 rows
+  are 67 MB copied in, stepped and copied out: the mixer's batched program took 0.99 ms, and 0.81
+  with each row's state stepped where it lies by one jitted call a row, a fifth of `copy-done`'s
+  time left; its 61 KB window is joined for one convolution and split again inside the block). A
+  ring of ``window`` slots (0.5 MB a session) or the lightning state (2.1 MB: not measured apart)
+  stays JOINED: the join costs less than an operation a row. A block that keeps nothing has
+  nothing to take apart (`hivemind_moe_decode_batched_rows_total{caches="none"}`). Apart, nothing is
   left of the caches' traffic but the step's own write and read: the arrays are donated
   (ISSUE 50), so a ``dynamic_update_slice`` on a row's array writes into that array, and a
   block that copied it first would pay the copy itself. A session's own call is handed arrays;

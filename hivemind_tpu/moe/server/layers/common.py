@@ -615,3 +615,13 @@ def _deepseek_v3_block(hidden_dim: int, **kwargs):
     from hivemind_tpu.moe.server.layers.deepseek_v3 import DeepseekV3BlockExpert
 
     return DeepseekV3BlockExpert(hidden_dim, **kwargs)
+
+
+@register_expert_class("nemotron_h_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))
+def _nemotron_h_block(hidden_dim: int, **kwargs):
+    """`layers/nemotron_h.py`'s block (ONE residual a block: a Mamba-2 mixer, a grouped-query attention
+    without position embedding, or a LatentMoE layer that keeps no cache, by its ``kind``), loaded when
+    one is built, as `minicpm_sala_block` is."""
+    from hivemind_tpu.moe.server.layers.nemotron_h import NemotronHBlockExpert
+
+    return NemotronHBlockExpert(hidden_dim, **kwargs)

@@ -1,6 +1,6 @@
 """A sparse expert layer whose work follows the tokens routed: float32 router,
-top-k, and SwiGLU experts computed as grouped matmuls over the (token, slot) pairs
-sorted by expert (`jax.lax.ragged_dot`).
+top-k, and experts (SwiGLU, or non-gated ``down(relu(up x)^2)``: `ACTIVATIONS`) computed as
+grouped matmuls over the (token, slot) pairs sorted by expert (`jax.lax.ragged_dot`).
 
 The layer sees all tokens of a call together, ``[tokens, hidden]``: the pairs are
 sorted once, every expert that was chosen is one contiguous group of rows, and its
@@ -17,7 +17,7 @@ On a TPU `ragged_dot` is the compiler's own grouped-matmul kernel
 (`ragged-dot-none`, with `ragged-dot-metadata` before it, in a device trace); it is
 differentiable (its transposes are ragged dots again), so `jax.vjp` goes through.
 
-A layer may hold a SHARE of the experts (`routed_swiglu_held`: expert parallelism's
+A layer may hold a SHARE of the experts (`routed_mlp_held`, `routed_swiglu_held`: expert parallelism's
 layer without its exchange): it routes over all of them, computes the pairs whose
 expert it holds and leaves the others out."""
 
@@ -39,22 +39,33 @@ def route_top_k(tokens: jax.Array, router: jax.Array, k: int) -> Tuple[jax.Array
     return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
 
 
-def _grouped_swiglu(tokens: jax.Array, weights: jax.Array, flat: jax.Array, w_gate: jax.Array,
-                    w_up: jax.Array, w_down: jax.Array, elsewhere: bool) -> jax.Array:
-    """The body of both entry points. ``flat``: each (token, slot) pair's group, the
-    index of its expert among ``w_gate``'s; with ``elsewhere``, the index one past
-    the last for a pair whose expert is not among them. The pairs are sorted by group
-    once, every group is one contiguous run of rows for the three grouped matmuls, and
-    the rows past the last group (``elsewhere``) add zero."""
+# what an expert does between its input projections and its output projection: name -> (how many
+# input projections it has, the function of their outputs)
+ACTIVATIONS = {
+    "swiglu": (2, lambda gate, up: jax.nn.silu(gate) * up),  # down(silu(gate x) * up x)
+    "relu2": (1, lambda up: jnp.square(jax.nn.relu(up))),  # down(relu(up x)^2): a non-gated expert
+}
+
+
+def _grouped_mlp(tokens: jax.Array, weights: jax.Array, flat: jax.Array, w_in: Tuple[jax.Array, ...],
+                 w_down: jax.Array, elsewhere: bool, activation: str) -> jax.Array:
+    """The body of every entry point, gated experts and non-gated alike. ``flat``: each
+    (token, slot) pair's group, the index of its expert among ``w_down``'s; with
+    ``elsewhere``, the index one past the last for a pair whose expert is not among them.
+    ``w_in``: the experts' input projections, as many as ``activation`` takes
+    (`ACTIVATIONS`). The pairs are sorted by group once, every group is one contiguous run
+    of rows for the grouped matmuls (one an input projection, one for the output), and the
+    rows past the last group (``elsewhere``) add zero."""
     count, k = weights.shape
-    groups = w_gate.shape[0]
+    groups = w_down.shape[0]
+    projections, inner = ACTIVATIONS[activation]
+    assert len(w_in) == projections, (activation, len(w_in))
     order = jnp.argsort(flat, stable=True)  # pairs sorted by expert: one group each
     sizes = jnp.bincount(flat, length=groups + elsewhere)[:groups].astype(jnp.int32)
     rounded = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
     rows = rounded(tokens)[order // k]
-    gate = jax.lax.ragged_dot(rows, w_gate, sizes)
-    up = jax.lax.ragged_dot(rows, w_up, sizes)
-    down = jax.lax.ragged_dot(rounded(jax.nn.silu(gate) * up), w_down, sizes)
+    projected = [jax.lax.ragged_dot(rows, w, sizes) for w in w_in]
+    down = jax.lax.ragged_dot(rounded(inner(*projected)), w_down, sizes)
     if elsewhere:  # rows past the held groups belong to no group: whatever the kernel left there is not read
         down = jnp.where((jnp.arange(count * k) < sizes.sum())[:, None], down, 0.0)
     per_pair = down[jnp.argsort(order)].reshape(count, k, -1)  # back to [token, slot]
@@ -74,7 +85,7 @@ def routed_swiglu(tokens: jax.Array, top_p: jax.Array, top_e: jax.Array,
     operands in one bf16 pass, so this is the block's other matmuls' arithmetic
     without a bf16 copy of every expert's weights written to memory at each call
     (the CPU multiplies the same operands in float32)."""
-    return _grouped_swiglu(tokens, top_p, top_e.reshape(-1), w_gate, w_up, w_down, elsewhere=False)
+    return _grouped_mlp(tokens, top_p, top_e.reshape(-1), (w_gate, w_up), w_down, elsewhere=False, activation="swiglu")
 
 
 def route_sigmoid_top_k(tokens: jax.Array, router: jax.Array, bias: jax.Array, k: int,
@@ -103,15 +114,28 @@ def route_sigmoid_top_k(tokens: jax.Array, router: jax.Array, bias: jax.Array, k
     return scale * picked / picked.sum(-1, keepdims=True), top_e
 
 
+def routed_mlp_held(tokens: jax.Array, weights: jax.Array, top_e: jax.Array, w_up, w_down: jax.Array, lo: int,
+                    activation: str) -> jax.Array:
+    """The routed experts of a layer that holds the experts ``[lo, lo + held)`` of those the
+    router chooses among, whatever an expert is (`ACTIVATIONS`): ``w_up`` ``[held, input,
+    width]`` for a non-gated expert (``relu2``), the pair ``(w_gate, w_up)`` of such arrays for
+    a gated one (``swiglu``); ``w_down`` ``[held, width, output]``; ``top_e`` counts over ALL
+    experts. The experts' input width is ``tokens``' own: the hidden size, or a latent
+    narrower than it (LatentMoE: the caller projects down before and up after). The pairs are
+    sorted once, held experts first and in order, the pairs routed elsewhere last; the grouped
+    matmuls run over the held groups only, and a pair routed elsewhere adds zero (what its
+    expert would add is another chip's to compute: nothing stands in for it)."""
+    held = w_down.shape[0]
+    local = top_e.reshape(-1) - lo
+    flat = jnp.where((local >= 0) & (local < held), local, held)  # routed elsewhere: past the last held group
+    w_in = tuple(w_up) if isinstance(w_up, (tuple, list)) else (w_up,)
+    return _grouped_mlp(tokens, weights, flat, w_in, w_down, elsewhere=True, activation=activation)
+
+
 def routed_swiglu_held(tokens: jax.Array, weights: jax.Array, top_e: jax.Array, w_gate: jax.Array,
                        w_up: jax.Array, w_down: jax.Array, lo: int) -> jax.Array:
     """`routed_swiglu` for a layer that holds the experts ``[lo, lo + held)`` of those
     the router chooses among: ``w_gate, w_up`` are ``[held, hidden, width]``, ``w_down``
-    ``[held, width, hidden]``, ``top_e`` counts over ALL experts. The pairs are sorted
-    once, held experts first and in order, the pairs routed elsewhere last; the grouped
-    matmuls run over the held groups only, and a pair routed elsewhere adds zero (what
-    its expert would add is another chip's to compute: nothing stands in for it)."""
-    held = w_gate.shape[0]
-    local = top_e.reshape(-1) - lo
-    flat = jnp.where((local >= 0) & (local < held), local, held)  # routed elsewhere: past the last held group
-    return _grouped_swiglu(tokens, weights, flat, w_gate, w_up, w_down, elsewhere=True)
+    ``[held, width, hidden]``, ``top_e`` counts over ALL experts (`routed_mlp_held` with
+    gated experts)."""
+    return routed_mlp_held(tokens, weights, top_e, (w_gate, w_up), w_down, lo, "swiglu")

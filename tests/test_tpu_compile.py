@@ -212,6 +212,53 @@ def test_latent_prompt_chunk_of_2048_positions_fits_the_chip(one_chip, no_compil
     assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9  # 0.36 GB dense and 1.01 GB sparse when written
 
 
+NEMOTRON = dict(held=64)  # every other size is the published one, the block's default
+
+
+@pytest.mark.parametrize("kind,leaves,temporaries_gb", [("mamba", 2, 0.2), ("attention", 2, 0.05), ("experts", 0, 0.05)])
+def test_nemotron_batched_step_of_16_sessions_fits_the_chip(one_chip, no_compile_cache, kind, leaves, temporaries_gb):
+    """The batched program of each kind of `nemotron_h_block` at the published widths and 12,288 slots, a bucket
+    of 16, as `DecodeSessionManager._batched_fn` builds it: a mixer's over the rows' own windows and states
+    (`decode_rows_apart`: no array of 16 states joined, every leaf aliased to an output), the attention's over the
+    rows' own caches, an expert layer's over NO cache at all (a tree of zero leaves: nothing donated, nothing
+    handed back), two grouped matmuls a call."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len, rows = 4096, 12288, 16
+    module = name_to_block["nemotron_h_block"](hidden, kind=kind, **NEMOTRON)
+    compiled, shapes = _compiled_batched_step(module, hidden, max_len, rows, one_chip)
+    text = compiled.as_text()
+    assert len(shapes) == leaves and compiled.memory_analysis().temp_size_in_bytes < temporaries_gb * 1e9
+    if kind == "mamba":
+        assert shapes == [(1, 3, 10240), (1, 128, 64, 128)] and "ssm_step" in text and "ssm_conv" in text and "ssm_scan" not in text
+        assert f"f32[{rows},128,64,128]" not in text  # the rows' states are never joined
+    elif kind == "attention":
+        assert shapes == [(1, 2, max_len, 128)] * 2 and _joined(text, rows, shapes[0]) == 0
+    else:
+        assert "moe_experts" in text and text.count("ragged-dot-none") >= 2 and compiled.memory_analysis().alias_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("kind,scope,temporaries_gb", [("mamba", "ssm_scan", 0.7), ("attention", None, 0.3), ("experts", "moe_experts", 1.0)])
+def test_nemotron_prompt_chunk_of_2048_positions_fits_the_chip(one_chip, no_compile_cache, kind, scope, temporaries_gb):
+    """A chunk of 2,048 positions continuing a session at 12,288 slots: the mixer's scan in sub-chunks of 128
+    (0.43 GB of temporaries when written), the attention's chunk against its cache a block of 512 keys at a time
+    (0.13 GB: the chunk's scores against 10k cached positions whole would be 3 GB), an expert layer's 45,056 pairs
+    (0.68 GB)."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len, chunk = 4096, 12288, 2048
+    module = name_to_block["nemotron_h_block"](hidden, kind=kind, **NEMOTRON)
+    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, hidden), jnp.float32))["params"])
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), tree)
+    cache = on_chip(jax.eval_shape(lambda: module.init_decode_cache(1, max_len)))
+    scalar = _shape((), jnp.int32, one_chip)
+    step = jax.jit(lambda p, x, cache, *rest: module.apply({"params": p}, x, *cache, *rest), donate_argnums=(2,))
+    compiled = step.lower(on_chip(params), _shape((1, chunk, hidden), jnp.float32, one_chip), cache, scalar,
+                          *((scalar,) if module.decode_takes_length else ())).compile()
+    assert scope is None or scope in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries_gb * 1e9
+
+
 @pytest.mark.parametrize(
     "shape,causal,backward",
     [
