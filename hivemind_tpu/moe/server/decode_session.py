@@ -26,7 +26,8 @@ clients' sessions that wait on the same chain form a **cohort**, which walks the
 chain's blocks in ONE executor call: at each block ONE device call for all the
 rows — the block is applied once to ``[rows, 1, hidden]`` with a VECTOR of write
 positions, and only its cache update and the attention over the cache run per row
-(`layers.common._decode_attention`, one call a row on that row's own cache): the block's
+(`layers.common._grouped_cache_step`, one call a row on that row's own cache, kept
+``[1, kv_heads, slots, head_dim]``: the queries of a KV head against that head's slots as they lie): the block's
 matmuls, and a sparse expert layer above all, see the cohort's rows together — and each row's
 output is the next block's input, left on the device: the next block's program is
 dispatched while this one runs (at most one program ahead), and only the chain's

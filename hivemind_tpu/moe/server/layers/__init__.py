@@ -7,7 +7,8 @@ sample input (batch-size-agnostic) defines the expert's I/O schema.
 is served through `DecodeSessionManager` if it has ``init_decode_cache(batch, max_len)``
 and its ``__call__(x, *cache, index)`` returns ``(y, *cache)``. The cache is a TREE of
 arrays, batch axis first, as the block chooses: ``(cache_k, cache_v)`` (the four blocks
-of `common.py`), one recurrent state that a step UPDATES, or keys, values and compressed
+of `common.py`, all of them ``[batch, kv_heads, slots, head_dim]`` bf16, a head's slots
+together: ``max_len`` slots, or a ring of ``window``), one recurrent state that a step UPDATES, or keys, values and compressed
 keys that a step appends to (`minicpm_sala_block`, both), or ONE array of compressed
 latents that every head's key and value are expanded from (`deepseek_v3_block`; a tree of
 one leaf is still handed over as ``*cache``: the block's ``__call__(x, cache, index)`` gets
@@ -41,15 +42,15 @@ one of two ranks:
   whatever it does with ``index`` must hold for a vector: `common.apply_rope` takes
   both ranks; a block that keeps its caches joined ``jax.vmap``s its own per-row cache
   code when ``jnp.ndim(index) == 1`` (`common._grouped_cache_step`'s ring); one that
-  takes them apart steps row by row (`common._decode_attention` and
-  `common._grouped_cache_step` given tuples: one inner ``jax.jit`` of the one-row step,
+  takes them apart steps row by row (`common._grouped_cache_step` given tuples, the one
+  cache step of every block of `common.py`: one inner ``jax.jit`` of the one-row step,
   called once a row). A ``dynamic_update_slice`` or a position lookup written for a
   scalar fails or broadcasts wrongly there, and only in batched steps
   (`tests/test_moe.py::test_custom_cached_block_steps_batched` is the pattern).
 
-The blocks of one chain need not agree on the tree (`exaone_moe_block`: ``[batch,
-kv_heads, slots, head_dim]``, a ring of ``window`` slots for a sliding-window block
-beside ``max_len`` slots for a full-attention one; `minicpm_sala_block`: three arrays
+The blocks of one chain need not agree on the tree (`exaone_moe_block`: a ring of
+``window`` slots for a sliding-window block beside ``max_len`` slots for a
+full-attention one; `minicpm_sala_block`: three arrays
 beside one); that a session is full stays the manager's to say (``max_len``). A step
 that fails must leave no half-updated state: both paths donate the tree, so the manager
 drops the sessions whose caches a failed step had taken, a session's own call's and every

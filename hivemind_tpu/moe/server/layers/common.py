@@ -79,50 +79,95 @@ def _each_row_apart(row_step, q, k_new, v_new, cache_k, cache_v, index):
     return jnp.concatenate(contexts), cache_k, cache_v
 
 
-def _decode_attention(q, k_new, v_new, cache_k, cache_v, index, groups: int = 1):
-    """Shared KV-cache attention step for decoder blocks.
-
-    Writes ``k_new``/``v_new`` into the caches at ``index`` (dynamic), then attends
-    the chunk's queries over every cached position the session has produced so far.
-    Valid for the two session shapes: prefill (``index == 0``, chunk length L,
-    causal within the chunk) and incremental (chunk length 1, attends everything
-    ≤ index). ``groups`` > 1 repeats the (grouped-query) KV heads to match q at
-    attention time — caches stay in the compact kv_heads layout.
-    In a batched step (`decode_rows_apart`) the rows are different sessions:
-    ``cache_k`` / ``cache_v`` are the TUPLES of the rows' own arrays (``[1, ...]``
-    each) and ``index`` the vector of their write positions. This function is then
-    the boundary between what a row does alone (write its own cache at its own
-    position, attend over that cache as it lies: `_decode_attention_row`, once a
-    row, `_each_row_apart`) and what the batch's rows do together (everything
-    around it in the block: projections, norms, the MLP or the expert layer).
-    Returns (context, cache_k, cache_v)."""
+def _grouped_cache_step(q, k_new, v_new, cache_k, cache_v, index):
+    """One position a row through caches kept ``[rows, kv_heads, slots, dim]``: row
+    r writes its key and value at slot ``index[r] mod slots`` and attends over the
+    slots written so far, the queries of a KV head grouped against that head's
+    cache as it lies (no copy of the cache at query width). With as many slots as
+    the session may have positions the cache is the whole past; with ``window``
+    slots it is a ring that holds exactly the positions ``index - window < s <=
+    index``, so ONE validity rule serves both: slot j is live iff ``j <= index``
+    (a ring that has wrapped is live everywhere). ``q`` ``[rows, 1, heads, dim]``,
+    ``k_new``, ``v_new`` ``[rows, 1, kv_heads, dim]``, ``index`` ``[rows]``.
+    ``cache_k`` / ``cache_v`` as the TUPLES of the rows' own arrays (``[1, ...]`` each:
+    a batched step of a block that says `decode_rows_apart`) are stepped row by row
+    where they lie (`_each_row_apart` of `_grouped_cache_step_row`, traced once for all
+    of them) and go back as tuples. Returns (context ``[rows, 1, heads * dim]``, cache_k, cache_v)."""
     if isinstance(cache_k, (tuple, list)):
-        return _each_row_apart(lambda *row, index: _decode_attention_row(*row, index[0], groups=groups),
-                               q, k_new, v_new, cache_k, cache_v, index)
+        return _each_row_apart(_grouped_cache_step_row, q, k_new, v_new, cache_k, cache_v, index)
+    rows, _, heads, dim = q.shape
+    kv_heads, slots = cache_k.shape[1], cache_k.shape[2]
+
+    def write(cache, new):  # [rows, kv_heads, slots, dim] <- [rows, 1, kv_heads, dim], row r at slot index[r] mod slots
+        new, slot = jnp.swapaxes(new, 1, 2).astype(cache.dtype), index % slots
+        if rows == 1:  # nothing to map over: a plain update at one slot, where the vmap below makes a scatter
+            return jax.lax.dynamic_update_slice(cache, new, (0, 0, slot[0], 0))
+        return jax.vmap(lambda cache, new, slot: jax.lax.dynamic_update_slice(cache, new, (0, slot, 0)))(cache, new, slot)
+
+    cache_k, cache_v = write(cache_k, k_new), write(cache_v, v_new)
+    grouped = q.reshape(rows, kv_heads, heads // kv_heads, dim).astype(cache_k.dtype)
+    scores = jnp.einsum("rkgd,rksd->rkgs", grouped, cache_k, preferred_element_type=jnp.float32) * dim**-0.5
+    live = jnp.arange(slots)[None, :] <= index[:, None]
+    scores = jnp.where(live[:, None, None, :], scores, jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cache_v.dtype)
+    context = jnp.einsum("rkgs,rksd->rkgd", probs, cache_v)
+    return context.reshape(rows, 1, heads * dim), cache_k, cache_v
+
+
+# one row of a batched step, traced ONCE for all the rows, buckets and blocks of one shape (a bucket
+# of 32 holds 32 calls of one function, not 32 copies of its text: the programs are jitted per uid
+# and bucket, and set-up pays their tracing)
+_grouped_cache_step_row = jax.jit(_grouped_cache_step)
+
+
+def _prefill_into_cache(cache, new, length):
+    """A session's first chunk into its cache: ``new`` ``[batch, seq, kv_heads, dim]``
+    (right-padded; ``length`` positions are real) into ``cache`` ``[batch, kv_heads,
+    slots, dim]``. A cache that holds the chunk takes all of it (the padded tail
+    lies past ``index`` and is overwritten by the steps). A ring shorter than the
+    chunk takes the last ``slots`` REAL positions, each at its position mod slots:
+    slot j gets the largest position p < length with p = j (mod slots)."""
+    seq, slots = new.shape[1], cache.shape[2]
+    new = jnp.swapaxes(new, 1, 2).astype(cache.dtype)
+    if seq <= slots:
+        return jax.lax.dynamic_update_slice(cache, new, (0, 0, 0, 0))
+    slot = jnp.arange(slots)
+    position = slot + slots * ((length - 1 - slot) // slots)  # negative where no real position lands on the slot
+    taken = jnp.take(new, jnp.clip(position, 0, seq - 1), axis=2)
+    return jnp.where((position >= 0)[None, None, :, None], taken, cache)
+
+
+def _empty_kv_cache(batch: int, slots: int, kv_heads: int, head_dim: int):
+    """(cache_k, cache_v) of the blocks of this file: bf16, one head's slots together
+    (``[batch, kv_heads, slots, head_dim]``), so that a step attends a KV head's queries
+    over that head's cache as it lies (`_grouped_cache_step`)."""
+    shape = (batch, kv_heads, slots, head_dim)
+    return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+
+
+def _cache_attention(q, k_new, v_new, cache_k, cache_v, index):
+    """The cache half of a decoder block that keeps every position it has seen
+    (`causal_transformer` and the Llama family): caches ``[batch, kv_heads, max_len,
+    dim]``, ``q`` ``[batch, seq, heads, dim]``, ``k_new``, ``v_new`` ``[batch, seq,
+    kv_heads, dim]``; how many queries a KV head serves is read from those shapes.
+    Valid for the two session shapes. One position (``index`` a scalar, or a vector
+    of the rows' own positions beside their caches as tuples) is a
+    `_grouped_cache_step`. A chunk is the session's prefill (``index == 0``: the
+    cache holds nothing before it): it is written with `_prefill_into_cache`, its
+    padded tail where the steps will overwrite it, and plain causal attention within
+    the chunk is exact. Returns (context ``[batch, seq, heads * dim]``,
+    cache_k, cache_v)."""
+    batch, seq, heads, dim = q.shape
+    if seq == 1:
+        rows = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (batch,))
+        return _grouped_cache_step(q, k_new, v_new, cache_k, cache_v, rows)
     from hivemind_tpu.ops.attention import plain_attention
 
-    batch, new_len = q.shape[0], q.shape[1]
-    max_len = cache_k.shape[1]
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k_new.astype(cache_k.dtype), (0, index, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v_new.astype(cache_v.dtype), (0, index, 0, 0))
+    cache_k, cache_v = _prefill_into_cache(cache_k, k_new, seq), _prefill_into_cache(cache_v, v_new, seq)
+    groups = heads // k_new.shape[2]
     expand = (lambda t: jnp.repeat(t, groups, axis=2)) if groups > 1 else (lambda t: t)
-    if new_len == 1:
-        mask = (jnp.arange(max_len) <= index)[None, :]  # [1, max_len] key-validity
-        context = plain_attention(
-            q, expand(cache_k), expand(cache_v),
-            mask=jnp.broadcast_to(mask, (batch, max_len)),
-        )
-    else:
-        # prefill chunk at the session start: plain causal attention over the chunk
-        # is exact (the cache holds nothing before index 0)
-        context = plain_attention(q, expand(k_new), expand(v_new), causal=True)
-    return context, cache_k, cache_v
-
-
-# one row of a batched step: the scalar form above, traced ONCE for all the rows, buckets
-# and blocks of one shape (a bucket of 32 holds 32 calls of one function, not 32 copies of
-# its text: the programs are jitted per uid and bucket, and set-up pays their tracing)
-_decode_attention_row = jax.jit(_decode_attention, static_argnames=("groups",))
+    context = plain_attention(q, expand(k_new), expand(v_new), causal=True)
+    return context.reshape(batch, seq, heads * dim), cache_k, cache_v
 
 
 class CausalTransformerExpert(nn.Module):
@@ -140,13 +185,11 @@ class CausalTransformerExpert(nn.Module):
     num_heads: int = 8
 
     # a batched step writes one position of a cache of ``max_len`` slots and reads the rest
-    # where it lies: the rows' caches come as tuples, unjoined (`_decode_attention`)
+    # where it lies: the rows' caches come as tuples, unjoined (`_grouped_cache_step`)
     decode_rows_apart = True
 
     def init_decode_cache(self, batch: int, max_len: int):
-        head_dim = self.hidden_dim // self.num_heads
-        shape = (batch, max_len, self.num_heads, head_dim)
-        return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+        return _empty_kv_cache(batch, max_len, self.num_heads, self.hidden_dim // self.num_heads)
 
     @nn.compact
     def __call__(self, x, cache_k=None, cache_v=None, index=None):
@@ -162,8 +205,7 @@ class CausalTransformerExpert(nn.Module):
         if cache_k is None:
             attn = attention_auto(q, k, v, causal=True).reshape(batch, seq, hid)
         else:
-            context, cache_k, cache_v = _decode_attention(q, k, v, cache_k, cache_v, index)
-            attn = context.reshape(batch, seq, hid)
+            attn, cache_k, cache_v = _cache_attention(q, k, v, cache_k, cache_v, index)
         x = x + dense(hid, "attention_out")(attn)
         normed = nn.LayerNorm(dtype=jnp.bfloat16, name="ffn_norm")(x)
         h = dense(4 * hid, "ffn_up")(normed)
@@ -189,12 +231,6 @@ def apply_rope(x: jax.Array, theta: float = 10000.0, offset=0) -> jax.Array:
     cos = jnp.cos(angles)[..., :, None, :].astype(x.dtype)
     sin = jnp.sin(angles)[..., :, None, :].astype(x.dtype)
     return x * cos + _rotate_half(x) * sin
-
-
-def _empty_kv_cache(batch: int, max_len: int, kv_heads: int, head_dim: int):
-    """(cache_k, cache_v) of the Llama-family blocks: bf16, compact kv-heads layout."""
-    shape = (batch, max_len, kv_heads, head_dim)
-    return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
 
 
 def _plain_dense(features: int, name: str) -> nn.Dense:
@@ -238,10 +274,7 @@ def _rope_attention_half(x, cache_k, cache_v, index, *, heads: int, kv_heads: in
             v = jnp.repeat(v, heads // kv_heads, axis=2)
         attn = mesh_attention_core(mesh, q, k, v, causal=True).reshape(batch, seq, hid)
     else:
-        context, cache_k, cache_v = _decode_attention(
-            q, k, v, cache_k, cache_v, index, groups=heads // kv_heads
-        )
-        attn = context.reshape(batch, seq, hid)
+        attn, cache_k, cache_v = _cache_attention(q, k, v, cache_k, cache_v, index)
     return x + _plain_dense(hid, "attention_out")(attn), cache_k, cache_v
 
 
@@ -265,7 +298,7 @@ class LlamaBlockExpert(nn.Module):
     # the fused attention kernel must then run per shard (mesh_attention_core)
     mesh: Optional[Any] = None
 
-    decode_rows_apart = True  # caches of ``max_len`` slots: a batched step takes the rows' own arrays (`_decode_attention`)
+    decode_rows_apart = True  # caches of ``max_len`` slots: a batched step takes the rows' own arrays (`_grouped_cache_step`)
 
     def init_decode_cache(self, batch: int, max_len: int):
         return _empty_kv_cache(batch, max_len, self.num_kv_heads or self.num_heads, self.hidden_dim // self.num_heads)
@@ -388,61 +421,6 @@ def _banded_attention(q, k, v, window: int):
     return context.reshape(batch, blocks * window, heads, dim)[:, :seq]
 
 
-def _grouped_cache_step(q, k_new, v_new, cache_k, cache_v, index):
-    """One position a row through caches kept ``[rows, kv_heads, slots, dim]``: row
-    r writes its key and value at slot ``index[r] mod slots`` and attends over the
-    slots written so far, the queries of a KV head grouped against that head's
-    cache as it lies (no copy of the cache at query width). With as many slots as
-    the session may have positions the cache is the whole past; with ``window``
-    slots it is a ring that holds exactly the positions ``index - window < s <=
-    index``, so ONE validity rule serves both: slot j is live iff ``j <= index``
-    (a ring that has wrapped is live everywhere). ``q`` ``[rows, 1, heads, dim]``,
-    ``k_new``, ``v_new`` ``[rows, 1, kv_heads, dim]``, ``index`` ``[rows]``.
-    ``cache_k`` / ``cache_v`` as the TUPLES of the rows' own arrays (``[1, ...]`` each:
-    a batched step of a block that says `decode_rows_apart`) are stepped row by row
-    where they lie (`_each_row_apart` of `_grouped_cache_step_row`, traced once for all
-    of them) and go back as tuples. Returns (context ``[rows, 1, heads * dim]``, cache_k, cache_v)."""
-    if isinstance(cache_k, (tuple, list)):
-        return _each_row_apart(_grouped_cache_step_row, q, k_new, v_new, cache_k, cache_v, index)
-    rows, _, heads, dim = q.shape
-    kv_heads, slots = cache_k.shape[1], cache_k.shape[2]
-
-    def write(cache, new):  # [rows, kv_heads, slots, dim] <- [rows, 1, kv_heads, dim], row r at slot index[r] mod slots
-        new, slot = jnp.swapaxes(new, 1, 2).astype(cache.dtype), index % slots
-        if rows == 1:  # nothing to map over: a plain update at one slot, where the vmap below makes a scatter
-            return jax.lax.dynamic_update_slice(cache, new, (0, 0, slot[0], 0))
-        return jax.vmap(lambda cache, new, slot: jax.lax.dynamic_update_slice(cache, new, (0, slot, 0)))(cache, new, slot)
-
-    cache_k, cache_v = write(cache_k, k_new), write(cache_v, v_new)
-    grouped = q.reshape(rows, kv_heads, heads // kv_heads, dim).astype(cache_k.dtype)
-    scores = jnp.einsum("rkgd,rksd->rkgs", grouped, cache_k, preferred_element_type=jnp.float32) * dim**-0.5
-    live = jnp.arange(slots)[None, :] <= index[:, None]
-    scores = jnp.where(live[:, None, None, :], scores, jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores, axis=-1).astype(cache_v.dtype)
-    context = jnp.einsum("rkgs,rksd->rkgd", probs, cache_v)
-    return context.reshape(rows, 1, heads * dim), cache_k, cache_v
-
-
-_grouped_cache_step_row = jax.jit(_grouped_cache_step)  # one row of a batched step, as `_decode_attention_row`
-
-
-def _prefill_into_cache(cache, new, length):
-    """A session's first chunk into its cache: ``new`` ``[batch, seq, kv_heads, dim]``
-    (right-padded; ``length`` positions are real) into ``cache`` ``[batch, kv_heads,
-    slots, dim]``. A cache that holds the chunk takes all of it (the padded tail
-    lies past ``index`` and is overwritten by the steps). A ring shorter than the
-    chunk takes the last ``slots`` REAL positions, each at its position mod slots:
-    slot j gets the largest position p < length with p = j (mod slots)."""
-    seq, slots = new.shape[1], cache.shape[2]
-    new = jnp.swapaxes(new, 1, 2).astype(cache.dtype)
-    if seq <= slots:
-        return jax.lax.dynamic_update_slice(cache, new, (0, 0, 0, 0))
-    slot = jnp.arange(slots)
-    position = slot + slots * ((length - 1 - slot) // slots)  # negative where no real position lands on the slot
-    taken = jnp.take(new, jnp.clip(position, 0, seq - 1), axis=2)
-    return jnp.where((position >= 0)[None, None, :, None], taken, cache)
-
-
 class ExaoneMoeBlockExpert(nn.Module):
     """One K-EXAONE decoder block on [batch, seq, hid] (`model_type: exaone_moe`,
     LG AI Research 2026; the equations are in `perf/reference/k_exaone_block.py`):
@@ -515,8 +493,7 @@ class ExaoneMoeBlockExpert(nn.Module):
         return self.held_lo, self.held_lo + self.held
 
     def init_decode_cache(self, batch: int, max_len: int):
-        shape = (batch, self.num_kv_heads, self.window or max_len, self.head_dim)
-        return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+        return _empty_kv_cache(batch, self.window or max_len, self.num_kv_heads, self.head_dim)
 
     def _attention_half(self, x, cache_k, cache_v, index, length):
         from hivemind_tpu.ops.attention import attention_auto

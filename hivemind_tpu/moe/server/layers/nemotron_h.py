@@ -108,7 +108,7 @@ def attend_chunk(q, cache_k, cache_v, index, key_block: int = 512):
     return jnp.moveaxis(context, 3, 1).reshape(batch, seq, heads * dim)
 
 
-# one row of a batched step, traced ONCE for all the rows, buckets and blocks of one shape (as `common._decode_attention_row`)
+# one row of a batched step, traced ONCE for all the rows, buckets and blocks of one shape (as `common._grouped_cache_step_row`)
 _ssd_step_row = jax.jit(ssm.ssd_step)
 
 

@@ -217,8 +217,8 @@ def predict_block_param_bytes(
 
 
 def decode_cache_bytes(config: LlamaCheckpointConfig, batch: int, max_len: int) -> int:
-    """KV-cache bytes ONE session costs for ONE block (bf16 K + V in the compact
-    kv-heads layout — see LlamaBlockExpert.init_decode_cache)."""
+    """KV-cache bytes ONE session costs for ONE block (bf16 K + V at kv-heads width,
+    ``[batch, kv_heads, max_len, head_dim]`` — see LlamaBlockExpert.init_decode_cache)."""
     head_dim = config.hidden_size // config.num_attention_heads
     return 2 * 2 * batch * max_len * config.num_key_value_heads * head_dim
 

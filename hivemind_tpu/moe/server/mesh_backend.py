@@ -20,8 +20,9 @@ depends on the rule for what XLA compiles — GSPMD resolves any placement, the
 rule just keeps the big matmuls distributed — but the Pallas kernels are not
 GSPMD's to partition: they run per shard under shard_map (attention through
 ``mesh_attention_core``, the int8 codec through ``dense_params``). KV caches
-shard over the kv-heads axis the same way (``shard_decode_cache``, consulted by
-the decode-session manager)."""
+(``[batch, kv_heads, slots, head_dim]``) shard over their kv-heads axis, so that a
+decode step's attention stays on the shard that holds the head
+(``shard_decode_cache``, consulted by the decode-session manager)."""
 
 from __future__ import annotations
 
@@ -91,21 +92,19 @@ class MeshModuleBackend(ModuleBackend):
         )
 
     def shard_decode_cache(self, cache_k, cache_v):
-        """Distribute a session's KV caches: shard the kv-heads axis (second to
-        last in the compact [batch, len, kv_heads, head_dim] layout) when it
-        divides the mesh axis, else the head_dim axis, else replicate."""
+        """Distribute a session's KV caches, ``[batch, kv_heads, slots, head_dim]``
+        as every block of `layers/common.py` keeps them: shard the kv-heads axis
+        when the mesh axis divides it (a step attends a KV head's queries over
+        that head's slots: nothing crosses shards), else the head_dim axis, else
+        replicate. Never the slots: a step's softmax runs over them."""
+        size = self._axis_size()
 
         def cache_sharding(cache):
-            shape = cache.shape
-            size = self._axis_size()
-            if len(shape) >= 2 and shape[-2] % size == 0 and shape[-2] >= size:
-                spec = [None] * len(shape)
-                spec[-2] = self.shard_axis
-            elif len(shape) >= 1 and shape[-1] % size == 0 and shape[-1] >= size:
-                spec = [None] * len(shape)
-                spec[-1] = self.shard_axis
-            else:
-                spec = [None] * len(shape)
+            spec = [None] * cache.ndim
+            for axis in (1, cache.ndim - 1):  # the kv heads, then a head's values
+                if cache.shape[axis] % size == 0 and cache.shape[axis] >= size:
+                    spec[axis] = self.shard_axis
+                    break
             return NamedSharding(self.mesh, PartitionSpec(*spec))
 
         return (

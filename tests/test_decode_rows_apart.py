@@ -1,14 +1,21 @@
 """The batched decode step on the rows' own caches (ISSUE 42): a dense-attention block
 whose cache holds ``max_len`` slots says `decode_rows_apart`, and its batched program
-updates and reads each row's own arrays, joining and splitting nothing. Here the two
-blocks that have no reference of their own (`llama_block` with grouped key-value heads,
-8 of 32, and `causal_transformer`) get the pair of tests that `tests/test_olmoe_block.py`
+updates and reads each row's own arrays, joining and splitting nothing. Here
+`llama_block` (with grouped key-value heads, 8 of 32, and with as many as query heads)
+and `causal_transformer` get the pair of tests that `tests/test_olmoe_block.py`
 holds for `olmoe_block`: a padded bucket against each row's full forward through the
 block itself, and the batched program against the per-session one. Then, for every
 block of `layers/common.py`, what the program's text says: which blocks join their
 rows' caches, and that a row's step is one function the rows share. Small sizes,
 seeded weights; `tests/test_exaone_block.py` holds the same for K-EXAONE's two kinds
 against its reference, `tests/test_tpu_compile.py` the program at published widths.
+
+Since ISSUE 52 every block of `layers/common.py` that keeps keys and values keeps them
+``[batch, kv_heads, max_len, head_dim]`` and steps through `_grouped_cache_step`: the
+queries of a key-value head are held against that head's cache as it lies. Held here for
+`llama_block`: a prompt and steps through the cache against the float32 reference
+(`perf/reference/mistral_block.py`), and that the batched program makes no array of a cache's
+length at QUERY width (the copy that `jnp.repeat` of the key-value heads made a row a step).
 
 Since ISSUE 50 the batched program DONATES the rows' cache leaves, whatever the block: what
 that means for a session's leaves, for the padding positions of a bucket (a throwaway cache
@@ -21,6 +28,7 @@ import sys
 import warnings
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -33,18 +41,20 @@ from hivemind_tpu.moe.server.decode_session import DecodeSessionManager  # noqa:
 from hivemind_tpu.moe.server.layers import name_to_block, name_to_input  # noqa: E402
 from hivemind_tpu.moe.server.module_backend import ModuleBackend  # noqa: E402
 from hivemind_tpu.telemetry import REGISTRY  # noqa: E402
+from perf.reference import mistral_block as llama_reference  # noqa: E402
 from perf.runtime import rel_err  # noqa: E402
 
 HID, MAX_LEN = 128, 32
-DENSE = {  # the blocks without a reference of their own
-    "llama_block": dict(num_heads=32, num_kv_heads=8),  # Mistral's grouping: four query heads a key-value head
-    "causal_transformer": dict(num_heads=4),
+DENSE = {  # name -> (class, sizes): the blocks whose batched step is held against their own forward
+    "llama_block": ("llama_block", dict(num_heads=32, num_kv_heads=8)),  # Mistral's grouping: four query heads a key-value head
+    "llama_block_ungrouped": ("llama_block", dict(num_heads=4)),  # as many key-value heads as query heads: a group of one
+    "causal_transformer": ("causal_transformer", dict(num_heads=4)),
 }
 EXAONE = dict(num_heads=4, num_kv_heads=2, head_dim=16, ffn_inner=64)
 EVERY = {  # name -> (class, sizes, what a batched program does with its rows' caches, the row step's name)
-    "llama_block": ("llama_block", DENSE["llama_block"], "apart", "_decode_attention"),
-    "causal_transformer": ("causal_transformer", DENSE["causal_transformer"], "apart", "_decode_attention"),
-    "olmoe_block": ("olmoe_block", dict(num_heads=4, num_experts=4, experts_per_token=2, expert_inner=32), "apart", "_decode_attention"),
+    "llama_block": (*DENSE["llama_block"], "apart", "_grouped_cache_step"),
+    "causal_transformer": (*DENSE["causal_transformer"], "apart", "_grouped_cache_step"),
+    "olmoe_block": ("olmoe_block", dict(num_heads=4, num_experts=4, experts_per_token=2, expert_inner=32), "apart", "_grouped_cache_step"),
     "exaone_full": ("exaone_moe_block", dict(window=0, **EXAONE), "apart", "_grouped_cache_step"),
     "exaone_window": ("exaone_moe_block", dict(window=8, **EXAONE), "joined", None),
 }
@@ -71,15 +81,16 @@ def prefilled_rows(manager, uid, x, lengths):
     return [manager._sessions[(uid, f"row{row}")] for row in range(len(lengths))]
 
 
-@pytest.mark.parametrize("block", sorted(DENSE))
-def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward(block):
+@pytest.mark.parametrize("name", sorted(DENSE))
+def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward(name):
     """7 sessions at different positions in a bucket of 8, three batched steps: each
     row against the block's own forward over that row's whole stream (no cache: causal
     attention over the chunk). The rows are counted as stepped apart, every session keeps
     arrays of its own, and the padding row's never become a session's."""
     from hivemind_tpu.telemetry.tracing import RECORDER
 
-    backend = make_backend(block, DENSE[block])
+    block, sizes = DENSE[name]
+    backend = make_backend(block, sizes)
     manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
     lengths = [3, 5, 8, 4, 11, 6, 9]
     x = stream(5, len(lengths), 16)
@@ -95,8 +106,8 @@ def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward(block):
             assert rel_err(out, want[row:row + 1, length + step:length + step + 1]) <= SERVED_TOL * (
                 np.abs(want).max() / np.abs(want[row, length + step]).max())
     assert rows_by_caches() == (before[0] + 3 * 7, before[1])
-    kv_heads = DENSE[block].get("num_kv_heads", DENSE[block]["num_heads"])
-    assert all(session.index == length + 3 and session.cache_k.shape == (1, MAX_LEN, kv_heads, HID // DENSE[block]["num_heads"])
+    kv_heads = sizes.get("num_kv_heads", sizes["num_heads"])
+    assert all(session.index == length + 3 and session.cache_k.shape == (1, kv_heads, MAX_LEN, HID // sizes["num_heads"])
                for session, length in zip(sessions, lengths))
     held = {id(leaf) for session in sessions for leaf in session.leaves}
     assert len(held) == 2 * 7 and not held & {id(leaf) for leaf in manager._dummy_rows(backend.name)}
@@ -105,12 +116,12 @@ def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward(block):
     assert (span.attributes["caches"], span.attributes["bucket"], span.attributes["rows"]) == ("apart", 8, 7)
 
 
-@pytest.mark.parametrize("block", sorted(DENSE))
-def test_batched_step_equals_the_direct_step(block):
+@pytest.mark.parametrize("name", sorted(DENSE))
+def test_batched_step_equals_the_direct_step(name):
     """The same tokens through the batched program and through the per-session
-    program: one cache step (`_decode_attention`'s scalar form, once a row), so the
+    program: one cache step (`_grouped_cache_step` on one row, once a row), so the
     outputs and the caches agree to rounding."""
-    backend = make_backend(block, DENSE[block])
+    backend = make_backend(*DENSE[name])
     manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
     lengths = [4, 7, 5]
     x = stream(6, 3, 12)
@@ -155,6 +166,59 @@ def test_what_the_batched_programs_text_joins(name):
     assert len(re.findall(rf"call @{row_step}\(", text)) == rows
 
 
+# ---- ISSUE 52: the Llama-family blocks step through `_grouped_cache_step` ----------------------------------
+
+
+@pytest.mark.parametrize("name", ["llama_block", "llama_block_ungrouped"])  # four query heads a key-value head, and one
+def test_a_prompt_and_steps_through_the_cache_against_the_float32_reference(name):
+    """A prompt of 11 positions (padded to 16: the tail lies in the cache past the session's end,
+    where the steps overwrite it) and nine single steps through a session's cache, against the plain
+    float32 reference's forward over the whole stream: the rotary offset, the head-major write of a
+    chunk and of a position, and the grouping of the queries all show here."""
+    block, sizes = DENSE[name]
+    backend = make_backend(block, sizes)
+    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    x = stream(4, 1, 20)
+    chunks = [manager.decode(backend.name, "s", x[:, :11], reset=True)]
+    chunks += [manager.decode(backend.name, "s", x[:, t:t + 1], reset=False) for t in range(11, 20)]
+    want = llama_reference.span([backend.params], jnp.asarray(x), num_heads=sizes["num_heads"],
+                                num_kv_heads=sizes.get("num_kv_heads", sizes["num_heads"]), rope_theta=10000.0, rms_eps=1e-6)
+    assert rel_err(np.concatenate(chunks, axis=1), want) <= SERVED_TOL
+    session = manager._sessions[(backend.name, "s")]
+    assert session.index == 20 and session.cache_k.dtype == session.cache_v.dtype == jnp.bfloat16
+
+
+def every_array_made(jaxpr):
+    """The shapes of all the arrays a jaxpr's equations produce, those of the functions it calls among them."""
+    for equation in jaxpr.eqns:
+        yield from (tuple(var.aval.shape) for var in equation.outvars if hasattr(var.aval, "shape"))
+        for value in equation.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)  # a ClosedJaxpr holds one
+                if hasattr(inner, "eqns"):
+                    yield from every_array_made(inner)
+
+
+def test_a_batched_step_makes_no_array_of_a_caches_length_at_query_width():
+    """What a CPU run can count of ISSUE 52's copy: in the batched program of a `llama_block` with four
+    query heads a key-value head, traced for a bucket of 4, NO equation produces an array of
+    ``heads x max_len x head_dim`` values (the keys or values of a row repeated to query width: 2 x 16.8 MB a
+    row a step at Mistral's sizes); the largest thing made from a cache is a cache (``kv_heads x max_len x
+    head_dim``) or its scores. 48 slots, so that no weight matrix of the block has as many values."""
+    block, sizes = DENSE["llama_block"]
+    heads, kv_heads, max_len, rows = sizes["num_heads"], sizes["num_kv_heads"], 48, 4
+    backend = make_backend(block, sizes)
+    manager = DecodeSessionManager({backend.name: backend}, max_len=max_len)
+    leaves = manager._dummy_rows(backend.name)
+    assert [leaf.shape for leaf in leaves] == [(1, kv_heads, max_len, HID // heads)] * 2
+    traced = jax.make_jaxpr(manager._batched_fn(backend.name, rows).jitted)(
+        backend.params, jnp.zeros((rows, 1, HID), jnp.float32), tuple((leaf,) * rows for leaf in leaves), jnp.ones((rows,), jnp.int32))
+    made = list(every_array_made(traced.jaxpr))
+    at_query_width = heads * max_len * (HID // heads)
+    assert any(int(np.prod(shape)) == kv_heads * max_len * (HID // heads) for shape in made), "the walk does not reach the rows' cache step"
+    assert not [shape for shape in made if int(np.prod(shape)) >= at_query_width and max_len in shape], "a cache was copied at query width"
+
+
 # ---- ISSUE 50: the batched program donates the rows' cache leaves -----------------------------------------
 
 SALA = dict(num_heads=4, num_kv_heads=2, head_dim=16, ffn_inner=96, kernel_size=4, kernel_stride=2, block_size=8, topk=6,
@@ -162,7 +226,7 @@ SALA = dict(num_heads=4, num_kv_heads=2, head_dim=16, ffn_inner=96, kernel_size=
 LATENT = dict(mlp="dense", num_heads=4, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=12,
               rope_theta=1e5, rope_factor=8.0, rope_original=32, ffn_inner=96)
 CACHE_KINDS = {  # what a session keeps -> (class, hidden, sizes, what a batched program does with the rows' caches, leaves a session)
-    "pair": ("llama_block", HID, DENSE["llama_block"], "apart", 2),
+    "pair": ("llama_block", HID, DENSE["llama_block"][1], "apart", 2),
     "ring": ("exaone_moe_block", HID, dict(window=8, **EXAONE), "joined", 2),
     "sparse_tree": ("minicpm_sala_block", 64, dict(mixer="minicpm4", **SALA), "apart", 3),
     "lightning_state": ("minicpm_sala_block", 64, dict(mixer="lightning-attn", **SALA), "joined", 1),
@@ -288,7 +352,7 @@ def test_a_program_that_fails_after_it_took_the_caches_drops_its_sessions_and_it
     Those sessions leave the table, counted `reason="failed_step"`, the lost throwaway row
     leaves the gauge, sessions outside the batch step on, and the next batch of the same
     bucket runs with a fresh throwaway row."""
-    backend = make_backend("llama_block", DENSE["llama_block"])
+    backend = make_backend(*DENSE["llama_block"])
     manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
     lengths = [4, 6, 5, 7, 3, 8]
     x = stream(9, len(lengths), 16)

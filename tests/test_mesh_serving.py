@@ -8,6 +8,7 @@ import time
 
 import jax
 import numpy as np
+import pytest
 from jax.sharding import Mesh, PartitionSpec
 
 from hivemind_tpu.dht import DHT
@@ -48,14 +49,37 @@ def test_mesh_backend_shards_params_and_caches(tmp_path):
         assert shard.data.size == leaf.size // len(mesh.devices.flat)
     assert backend.param_bytes_per_device() < backend.param_bytes()
 
-    # KV decode caches shard through the session-manager hook
+    # KV decode caches shard through the session-manager hook: 2 kv heads do not go round
+    # 8 devices, so a head's 32 values do (and never the 32 slots, which a step's softmax spans)
     cache_k, cache_v = backend.module.init_decode_cache(2, 32)
+    assert cache_k.shape == (2, 2, 32, 32)  # [batch, kv_heads, slots, head_dim]
     sharded_k, sharded_v = backend.shard_decode_cache(cache_k, cache_v)
-    assert sharded_k.sharding.spec != PartitionSpec(*([None] * sharded_k.ndim)) or (
-        sharded_k.shape[-2] % len(mesh.devices.flat) != 0
-    )
+    assert sharded_k.sharding.spec == sharded_v.sharding.spec == PartitionSpec(None, None, None, "tp")
     info = backend.get_info()
     assert info["mesh_devices"] == len(mesh.devices.flat)
+
+
+@pytest.mark.parametrize("devices, block, sizes, spec", [
+    (2, "llama_block", dict(num_heads=4, num_kv_heads=2), PartitionSpec(None, "tp", None, None)),  # the kv heads go round
+    (4, "llama_block", dict(num_heads=4, num_kv_heads=2), PartitionSpec(None, None, None, "tp")),  # they do not: a head's values
+    (4, "olmoe_block", dict(num_heads=4, num_experts=4, experts_per_token=2, expert_inner=32), PartitionSpec(None, "tp", None, None)),
+    (2, "exaone_moe_block", dict(num_heads=4, num_kv_heads=2, head_dim=16, ffn_inner=64), PartitionSpec(None, "tp", None, None)),
+    (8, "causal_transformer", dict(num_heads=4), PartitionSpec(None, None, None, "tp")),
+])
+def test_decode_caches_shard_over_the_kv_heads_axis(devices, block, sizes, spec):
+    """`shard_decode_cache` on the caches each KV-cache block of `layers/common.py` makes (one
+    layout since ISSUE 52: ``[batch, kv_heads, slots, head_dim]``, 48 slots here): the kv-heads
+    axis where the mesh divides it, else a head's values, and the slots in no case (a step's
+    softmax runs over them)."""
+    import optax
+
+    from hivemind_tpu.moe.server.layers import name_to_block, name_to_input
+
+    mesh = Mesh(np.array(jax.devices()[:devices]), ("tp",))
+    backend = MeshModuleBackend("m.0", name_to_block[block](HID, **sizes), mesh=mesh, optimizer=optax.sgd(0.0),
+                                sample_input=name_to_input[block](2, HID), max_batch_size=4)
+    for leaf in backend.shard_decode_cache(*backend.module.init_decode_cache(1, 48)):
+        assert leaf.shape[2] == 48 and leaf.sharding.spec == spec
 
 
 def test_mesh_sharded_server_is_token_identical_over_rpc(tmp_path):

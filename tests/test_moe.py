@@ -29,6 +29,19 @@ from hivemind_tpu.utils.timed_storage import get_dht_time
 HID = 32
 
 
+def assert_same_to_bf16_rounding(got, want, err_msg=""):
+    """A position STEPPED through a decode cache against the same position computed from a chunk
+    (a full forward, a prefill, a re-prefill after a failover). Since ISSUE 52 a step of every
+    block that keeps keys and values sums its scores in float32 (`common._grouped_cache_step`)
+    where a chunk's attention rounds them to bf16, so the two agree to the rounding of the bf16
+    activations and no longer bit for bit: within 2e-2 of the largest value, the tolerance the
+    other decode tests hold a served block to (3e-3 to 1e-2 read here, at 16 hidden values; a
+    wrong mask, slot or rotary offset shows at 1e-1 and more). Chunk against chunk stays exact."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape and np.abs(got - want).max() <= 2e-2 * np.abs(want).max(), (
+        err_msg, float(np.abs(got - want).max()), float(np.abs(want).max()))
+
+
 def test_expert_uid_utils():
     assert is_valid_uid("ffn.0.3") and is_valid_uid("expert.5")
     assert not is_valid_uid("ffn.") and not is_valid_uid("ffn") and not is_valid_uid("ffn.01")
@@ -553,8 +566,9 @@ def test_beam_search_negative_caching():
 
 
 def test_decode_cache_matches_full_forward():
-    """KV-cache decode (prefill + per-token steps) is bit-identical to the full
-    causal forward for both decoder block families (GQA caches stay compact)."""
+    """KV-cache decode (prefill + per-token steps) against the full causal forward for
+    both decoder block families (GQA caches stay compact): the prefill bit-identical, the
+    steps to bf16 rounding (`assert_same_to_bf16_rounding`)."""
     from hivemind_tpu.moe.server.layers.common import CausalTransformerExpert, LlamaBlockExpert
 
     rng = np.random.RandomState(0)
@@ -569,11 +583,11 @@ def test_decode_cache_matches_full_forward():
 
         cache_k, cache_v = block.init_decode_cache(batch=2, max_len=32)
         y, cache_k, cache_v = block.apply(params, x[:, :5], cache_k, cache_v, 0)
-        outs = [np.asarray(y)]
+        np.testing.assert_array_equal(np.asarray(y), full[:, :5])
+        assert cache_k.shape == cache_v.shape == (2, kwargs.get("num_kv_heads", 4), 32, 4)
         for t in range(5, 12):
             y, cache_k, cache_v = block.apply(params, x[:, t:t + 1], cache_k, cache_v, t)
-            outs.append(np.asarray(y))
-        np.testing.assert_array_equal(np.concatenate(outs, axis=1), full)
+            assert_same_to_bf16_rounding(y, full[:, t:t + 1], f"position {t}")
 
 
 def test_decode_sessions_over_rpc():
@@ -612,7 +626,7 @@ def test_decode_sessions_over_rpc():
 
         np.testing.assert_allclose(out_prefill, full[:, :6], rtol=1e-5, atol=1e-5)
         for offset, out in enumerate(step_outs):
-            np.testing.assert_allclose(out, full[:, 6 + offset:7 + offset], rtol=1e-5, atol=1e-5)
+            assert_same_to_bf16_rounding(out, full[:, 6 + offset:7 + offset])
 
         # a fresh session with the same id on ANOTHER input must reset cleanly
         out_reset = pipe.decode_step(hidden[:, :6], session, reset=True)
@@ -671,7 +685,7 @@ def test_decode_span_execution_across_two_servers():
         full = np.asarray(pipe(jnp.asarray(padded)))
         np.testing.assert_allclose(out_prefill, full[:, :5], rtol=1e-5, atol=1e-5)
         for offset, out in enumerate(step_outs):
-            np.testing.assert_allclose(out, full[:, 5 + offset:6 + offset], rtol=1e-5, atol=1e-5)
+            assert_same_to_bf16_rounding(out, full[:, 5 + offset:6 + offset])
 
         # training across the span boundary: gradients flow through both servers'
         # spans (client recovers the boundary activation with one forward sweep)
@@ -747,8 +761,10 @@ def test_decode_failover_mid_generation_matches_uninterrupted_run():
         outs += [pipe.decode_step(hidden[:, t:t + 1], session) for t in (prompt + 2, prompt + 3)]
 
         for i, (expected, got) in enumerate(zip(ref, outs)):
-            np.testing.assert_allclose(got, expected, rtol=1e-5, atol=1e-5,
-                                       err_msg=f"position group {i} diverged after failover")
+            if i < 3:  # before the failover both runs took the same path: exact
+                np.testing.assert_allclose(got, expected, rtol=1e-5, atol=1e-5, err_msg=f"position group {i} differs before the failover")
+            else:  # after it the stepped positions were re-prefilled as a chunk
+                assert_same_to_bf16_rounding(got, expected, f"position group {i} diverged after failover")
         # the route really did move to the replacement peer
         new_route = pipe._decode_routes[session]["route"]
         assert any(
@@ -811,8 +827,10 @@ def test_decode_failover_with_span_groups():
         outs += [pipe.decode_step(hidden[:, t:t + 1], session) for t in (prompt + 1, prompt + 2)]
 
         for i, (expected, got) in enumerate(zip(ref, outs)):
-            np.testing.assert_allclose(got, expected, rtol=1e-5, atol=1e-5,
-                                       err_msg=f"position group {i} diverged after span failover")
+            if i < 2:  # before the failover both runs took the same path: exact
+                np.testing.assert_allclose(got, expected, rtol=1e-5, atol=1e-5, err_msg=f"position group {i} differs before the failover")
+            else:  # after it the stepped position was re-prefilled as a chunk
+                assert_same_to_bf16_rounding(got, expected, f"position group {i} diverged after span failover")
         assert [len(span) for _b, span in pipe._decode_routes[session]["route"]] == [2, 2]
     finally:
         if client_dht is not None:
@@ -1041,7 +1059,7 @@ def test_decode_prefill_streams_over_unary_cap():
 
 
 def test_custom_cached_block_steps_batched():
-    """A registered block with cache code of its own (not `_decode_attention`) served
+    """A registered block with cache code of its own (not `_grouped_cache_step`) served
     through the batched step: `layers/__init__.py`'s contract. In a session's own
     call ``index`` is a scalar, in a batched step a vector, one write position a row;
     the block vmaps its per-row cache code over that vector itself. Its output is a

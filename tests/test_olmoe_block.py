@@ -201,7 +201,7 @@ def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward(kv_heads)
     assert counted["expert_layer_calls"] == 2
     assert counted["routed_pairs"] == 2 * 7 * TOP_K, "pairs are live rows x top-k: the padding row is not counted"
     assert TOP_K <= counted["experts_hit"] <= 2 * min(EXPERTS, 7 * TOP_K)
-    assert all(session.index == length + 2 and session.cache_k.shape == (1, 32, kv_heads, HID // HEADS)
+    assert all(session.index == length + 2 and session.cache_k.shape == (1, kv_heads, 32, HID // HEADS)
                for session, length in zip(sessions, lengths))
     assert len({id(leaf) for session in sessions for leaf in session.leaves}) == 2 * 7, "the padding row's arrays came back as a session's"
     assert _rows_by_caches() == (rows_before[0] + 2 * 7, rows_before[1]), "live rows of programs that left the caches apart"

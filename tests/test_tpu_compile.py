@@ -107,11 +107,31 @@ def test_batched_decode_step_of_eight_sessions_fits_the_chip(one_chip, no_compil
     compiled, leaves = _compiled_batched_step(module, HIDDEN, MAX_LEN, 8, one_chip)
     text = compiled.as_text()
     assert text.count("ragged-dot-none") >= 3
-    assert leaves == [(1, MAX_LEN, HEADS, HIDDEN // HEADS)] * 2
+    assert leaves == [(1, HEADS, MAX_LEN, HIDDEN // HEADS)] * 2  # one head's slots together (ISSUE 52), the bytes as before
     assert _joined(text, 8, leaves[0]) == 0, "an array of the joined caches' shape: the rows' caches are not stepped where they lie"
     memory = compiled.memory_analysis()
     # arguments: 1.68 GB of weights + 8 x 33.5 MB of caches
     assert memory.output_size_in_bytes < 257 * 2**20 and memory.temp_size_in_bytes < 16 * 2**20
+
+
+def test_batched_mistral_step_of_sixteen_sessions_copies_no_cache_at_query_width(one_chip, no_compile_cache):
+    """Mistral-7B's block (32 query heads on 8 key-value heads, float32 weights) at a bucket of 16 with 2,048-slot
+    caches, 8.4 MB a session: since ISSUE 52 a row's step holds the four queries of a key-value head against that
+    head's cache as it lies (`_grouped_cache_step`), so the chip's program holds no array of a cache's length at
+    query width, in either order of its axes (2 x 16.8 MB a row a step while the key-value heads were repeated),
+    none of the joined caches' shape, and 16 x 8.4 MB of outputs, all aliased to the donated arguments."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, heads, kv_heads, max_len, rows = 4096, 32, 8, 2048, 16
+    module = name_to_block["llama_block"](hidden, num_heads=heads, num_kv_heads=kv_heads, ffn_inner=14336, rope_theta=1000000.0, rms_eps=1e-5)
+    compiled, leaves = _compiled_batched_step(module, hidden, max_len, rows, one_chip)
+    text = compiled.as_text()
+    assert leaves == [(1, kv_heads, max_len, hidden // heads)] * 2 and _joined(text, rows, leaves[0]) == 0
+    at_query_width = [f"[1,{heads},{max_len},{hidden // heads}]", f"[1,{max_len},{heads},{hidden // heads}]",
+                      f"[1,{kv_heads},{heads // kv_heads},{max_len},{hidden // heads}]", f"[1,{max_len},{kv_heads},{heads // kv_heads},{hidden // heads}]"]
+    assert not [shape for shape in at_query_width if shape in text], "a row's keys or values were copied at query width"
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes < 135 * 2**20 and memory.temp_size_in_bytes < 16 * 2**20
 
 
 def test_batched_full_attention_step_of_sixteen_sessions_fits_the_chip(one_chip, no_compile_cache):
