@@ -14,7 +14,11 @@ import time
 from pathlib import Path
 from typing import NamedTuple
 
+import jax
+
 from hivemind_tpu.dht import DHT
+from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
+from hivemind_tpu.moe.server.module_backend import ModuleBackend
 from hivemind_tpu.p2p.native_transport import build_daemon_binary, read_daemon_banner
 
 
@@ -31,6 +35,67 @@ def shutdown_all(components, dhts):
         component.shutdown()
     for dht in dhts:
         dht.shutdown()
+
+
+class OneProgramBackend(ModuleBackend):
+    """A `ModuleBackend` whose state is drawn by ONE program (through `_init_state`, the
+    hook `MeshModuleBackend` has for the same). Eager, a block's init compiles a program an
+    operation and shape, 80 to 190 a block: a quarter of the block files' seconds (ISSUE 53).
+    The same keys draw the same values."""
+
+    def _init_state(self, samples, rng_seed: int):
+        def make():
+            params = self.module.init(jax.random.PRNGKey(rng_seed), *samples)["params"]
+            return params, (self.optimizer.init(params) if self.weight_quantization is None else None)
+
+        return jax.jit(make)()
+
+
+class ManagerSharingPrograms(DecodeSessionManager):
+    """A fresh manager (sessions, pools, padding and counters of its own) that takes a
+    jitted program from the managers that served the SAME backend object before it: a
+    step, a prefill or a batched step is a function of its backend alone (`_raw_step`;
+    ``max_len`` reaches it as the shape of an argument), so the file's tests compile each
+    once a process and not once a test. A test that counts compilations, or what
+    `_step_fns` / `_batched_fns` hold before its first call, builds a `DecodeSessionManager`."""
+
+    _programs: dict = {}
+
+    def _shared(self, own: dict, key: tuple, build):
+        shared = (self.backends[key[0]], *key[1:])
+        if key not in own:  # else: its own from before, or what a test put in its place
+            if shared not in self._programs:
+                self._programs[shared] = build(*key)
+            own[key] = self._programs[shared]
+        return own[key]
+
+    def _step_fn(self, uid: str, batch: int, new_len: int):
+        return self._shared(self._step_fns, (uid, batch, new_len), super()._step_fn)
+
+    def _batched_fn(self, uid: str, stack: int):
+        return self._shared(self._batched_fns, (uid, stack), super()._batched_fn)
+
+
+def wait_until(condition, timeout: float):
+    """Poll ``condition`` until it holds or ``timeout`` passes; what it returned last."""
+    deadline = time.monotonic() + timeout
+    while not (held := condition()) and time.monotonic() < deadline:
+        time.sleep(0.05)
+    return held
+
+
+def wait_for_experts(dht, uids, served_by=None, timeout: float = 10.0):
+    """Until ``dht`` resolves every one of ``uids``, with the peer ``served_by`` among its servers
+    if given (a replacement's record has then joined the dead server's): a server declares
+    its experts in the background, after `Server.create(start=True)` has returned."""
+    from hivemind_tpu.moe.server.dht_handler import get_experts
+
+    def declared():
+        infos = get_experts(dht, list(uids))
+        servers = lambda info: [replica.peer_id for replica in info.replica_set]
+        return all(info is not None and (served_by is None or served_by in servers(info)) for info in infos)
+
+    assert wait_until(declared, timeout), f"{list(uids)} were not declared within {timeout:g} s"
 
 
 # -------------------------------------------- child processes, started one bounded way

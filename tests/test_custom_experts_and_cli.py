@@ -3,7 +3,6 @@
 
 import subprocess
 import sys
-import time
 
 import flax.linen as nn
 import jax
@@ -11,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from swarm_utils import read_child_until, stop_process
+from swarm_utils import read_child_until, stop_process, wait_for_experts
 
 
 def test_register_custom_expert_end_to_end():
@@ -33,7 +32,7 @@ def test_register_custom_expert_end_to_end():
         start=True, optim_factory=lambda: optax.sgd(1e-3),
     )
     try:
-        time.sleep(1.0)
+        wait_for_experts(server.dht, ["gated_test_grid.0"])
         info = get_experts(server.dht, ["gated_test_grid.0"])[0]
         assert info is not None
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)

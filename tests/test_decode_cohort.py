@@ -3,6 +3,7 @@ wait on one chain of blocks form a cohort, which walks the chain in one executor
 call, one batched device call a block. `DecodeSessionManager` alone, no network."""
 
 import asyncio
+import functools
 import threading
 
 import numpy as np
@@ -11,6 +12,7 @@ import pytest
 
 from hivemind_tpu.telemetry import REGISTRY
 from hivemind_tpu.telemetry.tracing import add_span_listener, remove_span_listener
+from swarm_utils import ManagerSharingPrograms, OneProgramBackend
 
 HID = 16
 CHAIN = ("coh.0", "coh.1", "coh.2")
@@ -18,15 +20,17 @@ CHAIN = ("coh.0", "coh.1", "coh.2")
 
 def _manager(uids=CHAIN, block=None, hidden=HID, **kwargs):
     """A manager over one backend a uid; ``block()`` makes a uid's module (default: the dense causal block)."""
-    from hivemind_tpu.moe import ModuleBackend
-    from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
     from hivemind_tpu.moe.server.layers.common import CausalTransformerExpert
 
     block = block or (lambda: CausalTransformerExpert(hidden_dim=hidden, num_heads=4))
-    backends = {uid: ModuleBackend(uid, block(), optimizer=optax.sgd(1e-3),
-                                   sample_input=np.zeros((1, 4, hidden), np.float32), max_batch_size=8, rng_seed=seed)
-                for seed, uid in enumerate(uids)}
-    return DecodeSessionManager(backends, **{"max_len": 32, "max_sessions": 256, **kwargs})
+    backends = {uid: _backend(uid, block(), hidden, seed) for seed, uid in enumerate(uids)}
+    return ManagerSharingPrograms(backends, **{"max_len": 32, "max_sessions": 256, **kwargs})
+
+
+@functools.cache  # no test trains a block: each is built once a process, and its programs compiled once (`ManagerSharingPrograms`)
+def _backend(uid, module, hidden, seed):
+    return OneProgramBackend(uid, module, optimizer=optax.sgd(1e-3), sample_input=np.zeros((1, 4, hidden), np.float32),
+                             max_batch_size=8, rng_seed=seed)
 
 
 def _prefill(manager, chain, names, rng, length=3, hidden=HID):

@@ -4,6 +4,7 @@ has concurrent streams is kept as its two latest stamps. `DecodeSessionManager` 
 on a clock the test moves (`time` as `decode_session` sees it; asyncio keeps the real one)."""
 
 import asyncio
+import functools
 import random
 import time as real_time
 
@@ -12,6 +13,7 @@ import optax
 import pytest
 
 from hivemind_tpu.telemetry import REGISTRY
+from swarm_utils import ManagerSharingPrograms, OneProgramBackend
 
 HID = 16
 CHAIN = ("evi.0", "evi.1")
@@ -42,14 +44,15 @@ def clock(monkeypatch):
 
 
 def _manager(uids=CHAIN, **kwargs):
-    from hivemind_tpu.moe import ModuleBackend
-    from hivemind_tpu.moe.server.decode_session import DecodeSessionManager
+    return ManagerSharingPrograms({uid: _backend(uid, seed) for seed, uid in enumerate(uids)}, **{"max_len": 32, "max_sessions": 64, **kwargs})
+
+
+@functools.cache  # no test trains a block: each is built once a process, and its programs compiled once (`ManagerSharingPrograms`)
+def _backend(uid, seed):
     from hivemind_tpu.moe.server.layers.common import CausalTransformerExpert
 
-    backends = {uid: ModuleBackend(uid, CausalTransformerExpert(hidden_dim=HID, num_heads=4), optimizer=optax.sgd(1e-3),
-                                   sample_input=np.zeros((1, 4, HID), np.float32), max_batch_size=8, rng_seed=seed)
-                for seed, uid in enumerate(uids)}
-    return DecodeSessionManager(backends, **{"max_len": 32, "max_sessions": 64, **kwargs})
+    return OneProgramBackend(uid, CausalTransformerExpert(hidden_dim=HID, num_heads=4), optimizer=optax.sgd(1e-3),
+                             sample_input=np.zeros((1, 4, HID), np.float32), max_batch_size=8, rng_seed=seed)
 
 
 def _prompt(length=3):

@@ -23,6 +23,7 @@ each, kept and counted by `hivemind_moe_decode_padding_cache_bytes`) and for the
 program's aliases is held here for every kind of cache the repo serves; what a FAILED step
 leaves behind in a cohort is `tests/test_decode_cohort.py`'s."""
 
+import functools
 import re
 import sys
 import warnings
@@ -37,12 +38,12 @@ import pytest
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
-from hivemind_tpu.moe.server.decode_session import DecodeSessionManager  # noqa: E402
 from hivemind_tpu.moe.server.layers import name_to_block, name_to_input  # noqa: E402
 from hivemind_tpu.moe.server.module_backend import ModuleBackend  # noqa: E402
 from hivemind_tpu.telemetry import REGISTRY  # noqa: E402
 from perf.reference import mistral_block as llama_reference  # noqa: E402
 from perf.runtime import rel_err  # noqa: E402
+from swarm_utils import ManagerSharingPrograms, OneProgramBackend  # noqa: E402
 
 HID, MAX_LEN = 128, 32
 DENSE = {  # name -> (class, sizes): the blocks whose batched step is held against their own forward
@@ -61,9 +62,15 @@ EVERY = {  # name -> (class, sizes, what a batched program does with its rows' c
 SERVED_TOL = 2e-2  # bf16 activations on both sides; the cache path sums its scores in another order
 
 
+_BACKENDS = {}  # read-only in every test (the optimizer's rate is 0): each built once a process
+
+
 def make_backend(block: str, sizes: dict, uid="blk.0", seed=3, hidden=HID) -> ModuleBackend:
-    return ModuleBackend(uid, name_to_block[block](hidden, **sizes), optimizer=optax.sgd(0.0),
-                         sample_input=name_to_input[block](4, hidden), max_batch_size=8, rng_seed=seed)
+    key = (block, repr(sizes), uid, seed, hidden)
+    if key not in _BACKENDS:
+        _BACKENDS[key] = OneProgramBackend(uid, name_to_block[block](hidden, **sizes), optimizer=optax.sgd(0.0),
+                                           sample_input=name_to_input[block](4, hidden), max_batch_size=8, rng_seed=seed)
+    return _BACKENDS[key]
 
 
 def stream(seed: int, batch: int, length: int) -> np.ndarray:
@@ -91,11 +98,11 @@ def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward(name):
 
     block, sizes = DENSE[name]
     backend = make_backend(block, sizes)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     lengths = [3, 5, 8, 4, 11, 6, 9]
     x = stream(5, len(lengths), 16)
     sessions = prefilled_rows(manager, backend.name, x, lengths)
-    want = np.asarray(backend.module.apply({"params": backend.params}, jnp.asarray(x)))
+    want = np.asarray(jax.jit(backend.module.apply)({"params": backend.params}, x))
     before = rows_by_caches()
     for step in range(3):
         entries = [(None, session, x[row:row + 1, length + step:length + step + 1])
@@ -122,11 +129,11 @@ def test_batched_step_equals_the_direct_step(name):
     program: one cache step (`_grouped_cache_step` on one row, once a row), so the
     outputs and the caches agree to rounding."""
     backend = make_backend(*DENSE[name])
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     lengths = [4, 7, 5]
     x = stream(6, 3, 12)
     sessions = prefilled_rows(manager, backend.name, x, lengths)
-    twins = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    twins = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     twin_sessions = prefilled_rows(twins, backend.name, x, lengths)
     for step in range(2):
         results = manager._decode_batch(backend.name, [(None, session, x[row:row + 1, length + step:length + step + 1])
@@ -148,7 +155,7 @@ def test_what_the_batched_programs_text_joins(name):
     joined as before. Its outputs are one array a leaf a session either way."""
     block, sizes, caches, row_step = EVERY[name]
     backend = make_backend(block, sizes)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     assert manager._rows_caches(backend.name) == caches
     rows, leaves = 4, manager._dummy_rows(backend.name)
     lowered = manager._batched_fn(backend.name, rows).jitted.lower(
@@ -177,12 +184,12 @@ def test_a_prompt_and_steps_through_the_cache_against_the_float32_reference(name
     chunk and of a position, and the grouping of the queries all show here."""
     block, sizes = DENSE[name]
     backend = make_backend(block, sizes)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     x = stream(4, 1, 20)
     chunks = [manager.decode(backend.name, "s", x[:, :11], reset=True)]
     chunks += [manager.decode(backend.name, "s", x[:, t:t + 1], reset=False) for t in range(11, 20)]
-    want = llama_reference.span([backend.params], jnp.asarray(x), num_heads=sizes["num_heads"],
-                                num_kv_heads=sizes.get("num_kv_heads", sizes["num_heads"]), rope_theta=10000.0, rms_eps=1e-6)
+    want = jax.jit(functools.partial(llama_reference.span, num_heads=sizes["num_heads"], num_kv_heads=sizes.get("num_kv_heads", sizes["num_heads"]),
+                                     rope_theta=10000.0, rms_eps=1e-6))([backend.params], x)
     assert rel_err(np.concatenate(chunks, axis=1), want) <= SERVED_TOL
     session = manager._sessions[(backend.name, "s")]
     assert session.index == 20 and session.cache_k.dtype == session.cache_v.dtype == jnp.bfloat16
@@ -208,7 +215,7 @@ def test_a_batched_step_makes_no_array_of_a_caches_length_at_query_width():
     block, sizes = DENSE["llama_block"]
     heads, kv_heads, max_len, rows = sizes["num_heads"], sizes["num_kv_heads"], 48, 4
     backend = make_backend(block, sizes)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=max_len)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=max_len)
     leaves = manager._dummy_rows(backend.name)
     assert [leaf.shape for leaf in leaves] == [(1, kv_heads, max_len, HID // heads)] * 2
     traced = jax.make_jaxpr(manager._batched_fn(backend.name, rows).jitted)(
@@ -262,7 +269,7 @@ def test_a_batched_step_takes_every_rows_leaves_and_hands_back_readable_ones(kin
     from hivemind_tpu.telemetry.tracing import RECORDER
 
     backend, hidden, caches, leaves = kind_backend(kind)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     assert manager._rows_caches(backend.name) == caches
     lengths = [5, 9, 7]
     x = np.random.default_rng(1).standard_normal((3, 16, hidden)).astype(np.float32)
@@ -295,8 +302,8 @@ def test_every_padding_position_has_a_cache_of_its_own(kind):
     arrays before the step and after it (the new ones: the old were handed over), the gauge
     holds three rows' bytes and no more after a second step, and `clear_sessions()` releases them."""
     backend, hidden, _caches, leaves = kind_backend(kind)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
-    twins = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
+    twins = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     lengths = [4, 7, 5, 9, 6]
     x = np.random.default_rng(2).standard_normal((5, 16, hidden)).astype(np.float32)
     sessions = prefilled_rows(manager, backend.name, x, lengths)
@@ -332,7 +339,7 @@ def test_the_compiled_batched_program_aliases_every_cache_leaf_of_every_row(kind
     that it could not use. A block whose step copied its cache argument before writing would
     show here as an alias short."""
     backend, hidden, _caches, leaves = kind_backend(kind)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     rows, row = 4, manager._dummy_rows(backend.name)
     assert len(row) == leaves
     with warnings.catch_warnings(record=True) as caught:
@@ -353,7 +360,7 @@ def test_a_program_that_fails_after_it_took_the_caches_drops_its_sessions_and_it
     leaves the gauge, sessions outside the batch step on, and the next batch of the same
     bucket runs with a fresh throwaway row."""
     backend = make_backend(*DENSE["llama_block"])
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     lengths = [4, 6, 5, 7, 3, 8]
     x = stream(9, len(lengths), 16)
     sessions = prefilled_rows(manager, backend.name, x, lengths)

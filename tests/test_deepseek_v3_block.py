@@ -16,6 +16,7 @@ sizes on a dense block; a near-tie of the router that the bf16 rounding of its I
 moves ONE position by a whole expert, so a sparse block's stream is held to `SERVED_TOL` on
 all but a few positions (`positions_beyond`)."""
 
+import functools
 import os
 import subprocess
 import sys
@@ -33,6 +34,7 @@ sys.path.insert(0, str(ROOT))
 from hivemind_tpu.moe.server.decode_session import DecodeSessionManager  # noqa: E402
 from hivemind_tpu.moe.server.layers import name_to_block, name_to_input  # noqa: E402
 from hivemind_tpu.moe.server.module_backend import ModuleBackend  # noqa: E402
+from swarm_utils import ManagerSharingPrograms, OneProgramBackend  # noqa: E402
 from hivemind_tpu.telemetry import REGISTRY  # noqa: E402
 from perf.reference import gigachat_block as reference  # noqa: E402
 from perf.runtime import rel_err  # noqa: E402
@@ -50,9 +52,21 @@ MAX_LEN = 256
 NAME = "deepseek_v3_block"
 
 
+@functools.cache  # read-only in every test (the optimizer's rate is 0): built once a process
 def make_backend(mlp: str, uid="giga.0", seed=3, **overrides) -> ModuleBackend:
     module = name_to_block[NAME](HID, mlp=mlp, **{**KWARGS, **overrides})
-    return ModuleBackend(uid, module, optimizer=optax.sgd(0.0), sample_input=name_to_input[NAME](4, HID), max_batch_size=8, rng_seed=seed)
+    return OneProgramBackend(uid, module, optimizer=optax.sgd(0.0), sample_input=name_to_input[NAME](4, HID), max_batch_size=8, rng_seed=seed)
+
+
+@functools.cache
+def reference_program(entry: str = "span", **changed):
+    """The reference's ``entry`` as ONE program a shape, not one an operation."""
+    return jax.jit(functools.partial(getattr(reference, entry), **{**SIZES, **changed}))
+
+
+@functools.cache
+def forward_program(module):
+    return jax.jit(module.apply)
 
 
 def stream(seed: int, rows: int, length: int) -> np.ndarray:
@@ -77,8 +91,8 @@ def test_forward_matches_the_reference(mlp):
     keys and queries) against the reference's: 120 positions, past the original context of 32."""
     backend = make_backend(mlp)
     x = stream(1, 2, 120)
-    want = reference.span([backend.params], jnp.asarray(x), **SIZES)
-    got = backend.module.apply({"params": backend.params}, jnp.asarray(x))
+    want = reference_program()([backend.params], x)
+    got = forward_program(backend.module)({"params": backend.params}, x)
     assert positions_beyond(got, want, SERVED_TOL) <= (0.0 if mlp == "dense" else 0.05)
 
 
@@ -89,7 +103,7 @@ def test_chunked_prompt_then_steps_equal_the_full_forward(mlp):
     through the manager with a scalar ``index``: the same positions as the reference's one
     forward of 141. The session keeps ONE array of 16 + 8 values a position."""
     backend = make_backend(mlp)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     x = stream(2, 1, 141)
     chunks, at = [], 0
     for length in (48, 37, 16):
@@ -97,7 +111,7 @@ def test_chunked_prompt_then_steps_equal_the_full_forward(mlp):
         at += length
     chunks += [manager.decode(backend.name, "s", x[:, t:t + 1], reset=False) for t in range(at, 141)]
     got = np.concatenate(chunks, axis=1)
-    want = reference.span([backend.params], jnp.asarray(x), **SIZES)
+    want = reference_program()([backend.params], x)
     assert got.shape == want.shape and positions_beyond(got, want, SERVED_TOL) <= (0.0 if mlp == "dense" else 0.05)
     session = manager._sessions[(backend.name, "s")]
     [leaf] = jax.tree_util.tree_leaves(session.cache)
@@ -117,7 +131,7 @@ def test_router_taps_get_what_the_served_routers_saw_and_chose():
     tap = lambda seen, chose: taken.append((seen, chose))
     for mlp, calls in (("dense", 0), ("sparse", 3)):
         backend = make_backend(mlp)
-        manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+        manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
         x = stream(11, 3, 40)
         ROUTER_TAPS.append(tap)
         try:
@@ -141,7 +155,7 @@ def test_router_taps_get_what_the_served_routers_saw_and_chose():
     chose = np.concatenate([chunk_e, step_e, rows_e[:1]], axis=1)
     own = np.asarray(reference.chosen_experts(router, jnp.asarray(seen), PICKS, GROUPS, KEPT))
     assert (np.sort(own, -1) == np.sort(chose, -1)).all()  # the served router is the float32 one
-    _out, [(_m, want)] = reference.span_with_routing([backend.params], jnp.asarray(x[:1, :39]), **SIZES)
+    _out, [(_m, want)] = reference_program("span_with_routing")([backend.params], x[:1, :39])
     assert (np.sort(np.asarray(want), -1) != np.sort(chose, -1)).any(-1).mean() <= 0.1
 
 
@@ -152,7 +166,7 @@ def test_batched_rows_at_different_positions(mlp):
     context and two past it: every row equals the reference's full forward of its stream,
     the rows are counted `caches=apart`, and each session keeps an array of its own."""
     backend = make_backend(mlp)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     lengths, steps = [90, 20, 70], 30
     x = stream(3, 3, max(lengths) + steps)
     got = [[manager.decode(backend.name, f"row{row}", x[row:row + 1, :length], reset=True)] for row, length in enumerate(lengths)]
@@ -170,7 +184,7 @@ def test_batched_rows_at_different_positions(mlp):
     # a row a step: its write position + 1; the padding row of the bucket of four is not counted
     assert counter("hivemind_moe_latent_positions_attended_total", path="batched") - attended == sum(
         length + step + 1 for length in lengths for step in range(steps))
-    want = np.asarray(reference.span([backend.params], jnp.asarray(x), **SIZES))
+    want = np.asarray(reference_program()([backend.params], x))
     for row, length in enumerate(lengths):
         served = np.concatenate(got[row], axis=1)
         assert positions_beyond(served, want[row:row + 1, :length + steps], SERVED_TOL) <= (0.0 if mlp == "dense" else 0.05), row
@@ -187,24 +201,31 @@ def test_the_absorbed_step_equals_expanded_attention_over_the_same_cache():
     from hivemind_tpu.ops import latent_attention as ops
 
     rng = np.random.default_rng(4)
-    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    draw = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+    latent_step = jax.jit(ops.latent_step)  # each side of a comparison ONE program, not one an operation
+    latent_chunk = jax.jit(ops.latent_chunk, static_argnames="key_block")
     rows, slots, held = 2, 100, 73
-    cache = draw(rows, slots, RANK + ROPED).at[:, held:].set(7.0)  # what lies past the session's end is never read
+    cache = draw(rows, slots, RANK + ROPED)
+    cache[:, held:] = 7.0  # what lies past the session's end is never read
     q_nope, q_pe, new = draw(rows, HEADS, NOPE), draw(rows, HEADS, ROPED), draw(rows, 1, RANK + ROPED)
     w_k, w_v = draw(RANK, HEADS, NOPE), draw(RANK, HEADS, V_DIM)
     with jax.default_matmul_precision("highest"):
-        absorbed, written = ops.latent_step(q_nope, q_pe, new, cache, jnp.int32(held), w_k, w_v, 0.3)
+        absorbed, written = latent_step(q_nope, q_pe, new, cache, jnp.int32(held), w_k, w_v, 0.3)
         for key_block in (1024, 32):  # the whole cache at once; four blocks, the last taken from the cache's end
-            expanded = ops.latent_chunk(q_nope[:, None], q_pe[:, None], written, jnp.int32(held), w_k, w_v, 0.3, key_block=key_block)
+            expanded = latent_chunk(q_nope[:, None], q_pe[:, None], written, jnp.int32(held), w_k, w_v, 0.3, key_block=key_block)
             assert rel_err(absorbed, expanded[:, 0]) <= 1e-5, key_block
         # against the definition: every head's keys and values from the latents, plain softmax
-        c, k_pe = written[:, :held + 1, :RANK], written[:, :held + 1, RANK:]
-        scores = (jnp.einsum("rhd,rshd->rhs", q_nope, jnp.einsum("rsc,chd->rshd", c, w_k)) + jnp.einsum("rhd,rsd->rhs", q_pe, k_pe)) * 0.3
-        plain = jnp.einsum("rhs,rshv->rhv", jax.nn.softmax(scores, -1), jnp.einsum("rsc,chv->rshv", c, w_v))
+        @jax.jit
+        def by_the_definition(written):
+            c, k_pe = written[:, :held + 1, :RANK], written[:, :held + 1, RANK:]
+            scores = (jnp.einsum("rhd,rshd->rhs", q_nope, jnp.einsum("rsc,chd->rshd", c, w_k)) + jnp.einsum("rhd,rsd->rhs", q_pe, k_pe)) * 0.3
+            return jnp.einsum("rhs,rshv->rhv", jax.nn.softmax(scores, -1), jnp.einsum("rsc,chv->rshv", c, w_v))
+
+        plain = by_the_definition(written)
     assert rel_err(absorbed, plain) <= 1e-5
     assert np.array_equal(np.asarray(written[:, held]), np.asarray(new[:, 0])) and float(written[0, held + 1, 0]) == 7.0
     # the rows' arrays apart, each at its own position: the same numbers row by row
-    apart, arrays = ops.latent_step(q_nope, q_pe, new, (cache[:1], cache[1:]), jnp.array([held, held], jnp.int32), w_k, w_v, 0.3)
+    apart, arrays = latent_step(q_nope, q_pe, new, (cache[:1], cache[1:]), np.array([held, held], np.int32), w_k, w_v, 0.3)
     assert isinstance(arrays, tuple) and rel_err(apart, absorbed) <= 1e-6
 
 
@@ -288,8 +309,8 @@ def test_one_group_is_the_ungrouped_router_and_lowers_to_the_same_text():
     def batched_text():
         module = name_to_block["exaone_moe_block"](HID, num_heads=4, num_kv_heads=2, head_dim=16, window=8, num_experts=16,
                                                    experts_per_token=4, expert_inner=32, held_lo=4, held=4)
-        backend = ModuleBackend("exa.0", module, optimizer=optax.sgd(0.0), sample_input=name_to_input["exaone_moe_block"](4, HID),
-                                max_batch_size=8, rng_seed=1)
+        backend = OneProgramBackend("exa.0", module, optimizer=optax.sgd(0.0), sample_input=name_to_input["exaone_moe_block"](4, HID),
+                                    max_batch_size=8, rng_seed=1)
         manager = DecodeSessionManager({"exa.0": backend}, max_len=64)
         shape = lambda tree: jax.tree_util.tree_map(lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype), tree)
         columns = tuple((leaf,) * 4 for leaf in shape(manager._dummy_rows("exa.0")))
@@ -311,26 +332,38 @@ def test_the_shares_add_up_to_the_uncut_layer():
     (attention, the shared expert) counted once, add up to what the uncut reference gives
     for the whole layer: in the reference exactly, in the program to the served rounding."""
     experts = 32
-    sizes = dict(SIZES, experts_per_token=4, n_group=4, topk_group=2)
+    sizes = dict(experts_per_token=4, n_group=4, topk_group=2)
     whole = make_backend("sparse", num_experts=experts, held_lo=0, held=0)
-    params = jax.tree_util.tree_map(jnp.asarray, whole.params)
-    x = jnp.asarray(stream(6, 1, 40))
-    uncut = reference.span([params], x, **{**sizes, "held_lo": 0})
+    params = jax.tree_util.tree_map(np.asarray, whole.params)  # a share is cut in numpy: no program a slice
+    x = stream(6, 1, 40)
+    uncut = reference_program(**sizes, held_lo=0)([params], x)
     # what every share computes alike: the layer with no routed expert's output
-    common = reference.span([{**params, "experts_down": jnp.zeros_like(params["experts_down"])}], x, **{**sizes, "held_lo": 0})
+    no_routed = {**params, "experts_down": np.zeros_like(params["experts_down"])}
+    common = reference_program(**sizes, held_lo=0)([no_routed], x)
     share_of = lambda lo: {**params, **{name: params[name][lo:lo + 1] for name in ("experts_gate", "experts_up", "experts_down")}}
-    parts_reference = sum(reference.span([share_of(lo)], x, **{**sizes, "held_lo": lo}) - common for lo in range(experts))
-    assert rel_err(common + parts_reference, uncut) <= 1e-5
+    shares = [share_of(lo) for lo in range(experts)]
+
+    @jax.jit  # the 32 shares' layers in one program
+    def parts_reference(shares, x, common):
+        return sum(reference.span([share], x, **{**SIZES, **sizes, "held_lo": lo}) - common for lo, share in enumerate(shares))
+
+    assert rel_err(common + parts_reference(shares, x, common), uncut) <= 1e-5
     assert float(jnp.abs(uncut - common).max() / jnp.abs(uncut).max()) > 0.05  # the routed experts are a real part of the layer
     # the program's shares: what they compute alike is taken from the program too (its rounding would count 32 times)
-    alike = whole.module.apply({"params": {**params, "experts_down": jnp.zeros_like(params["experts_down"])}}, x)
-    parts_program = 0.0
-    for lo in range(experts):
-        module = name_to_block[NAME](HID, mlp="sparse", **{**KWARGS, "num_experts": experts, "held_lo": lo, "held": 1})
-        assert module.held_experts == (lo, lo + 1)
-        parts_program = parts_program + (module.apply({"params": share_of(lo)}, x) - alike)
-    assert positions_beyond(alike + parts_program, uncut, 3e-2) <= 0.05
-    assert rel_err(alike + parts_program, whole.module.apply({"params": params}, x)) <= 1e-2  # and to the program's own uncut layer
+    alike = forward_program(whole.module)({"params": no_routed}, x)
+
+    @jax.jit
+    def parts_program(shares, x, alike):
+        total = 0.0
+        for lo, share in enumerate(shares):
+            module = name_to_block[NAME](HID, mlp="sparse", **{**KWARGS, "num_experts": experts, "held_lo": lo, "held": 1})
+            assert module.held_experts == (lo, lo + 1)
+            total = total + (module.apply({"params": share}, x) - alike)
+        return total
+
+    served = alike + parts_program(shares, x, alike)
+    assert positions_beyond(served, uncut, 3e-2) <= 0.05
+    assert rel_err(served, forward_program(whole.module)({"params": params}, x)) <= 1e-2  # and to the program's own uncut layer
     assert whole.module.held_experts is None and make_backend("dense").module.held_experts is None
 
 
@@ -342,7 +375,7 @@ def test_a_failed_step_leaves_no_half_updated_state(monkeypatch):
     as they were."""
     backend = make_backend("sparse")
     assert backend.module.decode_rows_apart and backend.module.decode_takes_chunks and backend.module.decode_cache_kind == "latent"
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     x = stream(7, 2, 80)
     for row in range(2):
         manager.decode(backend.name, f"row{row}", x[row:row + 1, :70], reset=True)
@@ -376,7 +409,7 @@ def test_gauges_counters_and_program_names_say_latent():
     attended counted `path=direct` (chunks are not counted), the held-share routing counters
     of the sparse block, and the kind in the programs' names."""
     backends = {"g.0": make_backend("dense", uid="g.0"), "g.1": make_backend("sparse", uid="g.1")}
-    manager = DecodeSessionManager(backends, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms(backends, max_len=MAX_LEN)
     manager.clear_sessions()
     x = stream(11, 1, 100)
     direct, pairs, held = (counter(name, path="direct") for name in (
@@ -423,7 +456,7 @@ def test_parameter_counts_by_hand():
     assert caches == [12288 * 576 * 2] * 5 and round(caches[0] / 1e6, 2) == 14.16 and round(32 * sum(caches) / 1e9, 2) == 2.26
 
 
-def test_span_through_server_and_remote_sequential():
+def test_span_through_server_and_remote_sequential(one_program_backends):
     """The rehearsal configuration's span (the dense block 2 and the sparse blocks 3-6, 4 of 16
     experts held inside one group), built as the runner builds it: a client's prompt in
     chunks and single-token steps over the wire against the reference, past the original
@@ -448,7 +481,7 @@ def test_span_through_server_and_remote_sequential():
         chunks += [pipe.decode_step(x[:, t:t + 1], "e2e") for t in range(150, 170)]
         got = np.concatenate(chunks, axis=1)
         params = [server.backends[f"{config['serving']['uid_prefix']}{i}"].snapshot_params() for i in range(blocks)]
-        want = reference.span(params, jnp.asarray(x), **runner.reference_sizes(config))
+        want = jax.jit(functools.partial(reference.span, **runner.reference_sizes(config)))(params, x)
         assert positions_beyond(got, want, 5e-2) <= 0.1  # five blocks, four routers
         pipe.close_decode_session("e2e")
     finally:

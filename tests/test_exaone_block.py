@@ -37,6 +37,7 @@ from hivemind_tpu.telemetry import REGISTRY  # noqa: E402
 from perf.reference import k_exaone_block as reference  # noqa: E402
 from perf.runners import hybrid_moe_block_server as runner  # noqa: E402
 from perf.runtime import rel_err  # noqa: E402
+from swarm_utils import ManagerSharingPrograms, OneProgramBackend  # noqa: E402
 
 HID, HEADS, KV, DIM, WINDOW, DENSE, INNER = 96, 4, 2, 16, 8, 160, 48
 EXPERTS, TOP_K, HELD, LO, SCALE = 16, 4, 4, 4, 2.5
@@ -56,10 +57,18 @@ COUNTERS = ("hivemind_moe_expert_layer_calls_total", "hivemind_moe_routed_pairs_
             "hivemind_moe_experts_hit_total", "hivemind_moe_expert_max_pairs_total")
 
 
+@functools.cache  # read-only in every test (the optimizer's rate is 0): built once a process
 def make_backend(kind: str, uid="exa.0", seed=3, **overrides) -> ModuleBackend:
     module = name_to_block["exaone_moe_block"](HID, **{**COMMON, **KINDS[kind][0], **overrides})
-    return ModuleBackend(uid, module, optimizer=optax.sgd(0.0), sample_input=name_to_input["exaone_moe_block"](4, HID),
-                         max_batch_size=8, rng_seed=seed)
+    return OneProgramBackend(uid, module, optimizer=optax.sgd(0.0), sample_input=name_to_input["exaone_moe_block"](4, HID),
+                             max_batch_size=8, rng_seed=seed)
+
+
+@functools.cache
+def reference_program(kinds: tuple, entry: str = "span_with_routing", **changed):
+    """The reference over blocks of ``kinds`` as ONE program a shape, not one an operation."""
+    layers = [KINDS[kind][1] for kind in kinds]
+    return jax.jit(lambda params, x: getattr(reference, entry)(params, x, layers, **{**SIZES, **changed}))
 
 
 def stream(seed: int, batch: int, length: int) -> np.ndarray:
@@ -67,8 +76,7 @@ def stream(seed: int, batch: int, length: int) -> np.ndarray:
 
 
 def want_of(backends, kinds, x):
-    layers = [KINDS[kind][1] for kind in kinds]
-    return reference.span_with_routing([b.params for b in backends], jnp.asarray(x), layers, **SIZES)
+    return reference_program(tuple(kinds))([b.params for b in backends], x)
 
 
 def routes_as_the_reference(backends, kinds, x) -> bool:
@@ -133,7 +141,7 @@ def test_prefill_and_single_token_steps_against_full_forward(kind, prompt):
     """The prefill is padded to a power of two (32, 8): the ring must take the last
     8 REAL positions, not the padding. 20 steps after 21 wrap the ring twice more."""
     backend = make_backend(kind)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=64)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=64)
     x = clean_stream([backend], [kind], 4, 1, prompt + 20)
     chunks = [manager.decode(backend.name, "s", x[:, :prompt], reset=True)]
     chunks += [manager.decode(backend.name, "s", x[:, t:t + 1], reset=False) for t in range(prompt, prompt + 20)]
@@ -156,7 +164,7 @@ def test_batched_step_with_rows_at_different_positions(kind):
     caches, other = ("joined", "apart") if "window" in kind else ("apart", "joined")
 
     backend = make_backend(kind)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=64)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=64)
     lengths = [3, 21, 8, 13, 11, 6, 9]
     x = clean_stream([backend], [kind], 50, len(lengths), 41)
     for row, length in enumerate(lengths):
@@ -192,7 +200,7 @@ def test_programs_and_cache_gauges_carry_the_kind():
     """Two cache shapes in one manager: each block's programs are named by its kind, and
     the table's bytes and entries are told apart by it."""
     backends = {f"exa.{i}": make_backend(kind, uid=f"exa.{i}", seed=i) for i, kind in enumerate(["sparse/window", "sparse/full"])}
-    manager = DecodeSessionManager(backends, max_len=64)
+    manager = ManagerSharingPrograms(backends, max_len=64)
     x = stream(6, 1, 12)
     for session in ("a", "b"):
         for uid in backends:
@@ -221,7 +229,7 @@ def test_other_blocks_programs_keep_their_names(block_cls, name):
     """`llama_block` and `olmoe_block` name no kind: their decode programs are `step`
     and `batched_step` as before this block came, and they are handed no length."""
     module = name_to_block[block_cls](HID, num_heads=HEADS)
-    backend = ModuleBackend("other.0", module, optimizer=optax.sgd(0.0), sample_input=name_to_input[block_cls](4, HID), max_batch_size=8)
+    backend = OneProgramBackend("other.0", module, optimizer=optax.sgd(0.0), sample_input=name_to_input[block_cls](4, HID), max_batch_size=8)
     manager = DecodeSessionManager({"other.0": backend}, max_len=32)
     assert manager._step_fn("other.0", 1, 16).jitted.__name__ == manager._step_fn("other.0", 1, 1).jitted.__name__ == name
     assert manager._batched_fn("other.0", 4).jitted.__name__ == "batched_step"
@@ -236,17 +244,18 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
     (each share a block of its own, fed the uncut block's weights for its experts)
     and from the reference told each share."""
     whole = make_backend("sparse/window", held=EXPERTS, held_lo=0)
+    params = jax.tree_util.tree_map(np.asarray, whole.params)  # a share is cut in numpy: no program a slice
     x = stream(7, 2, 19)
-    layers, sizes = [KINDS["sparse/window"][1]], {**SIZES, "held_lo": 0}
-    uncut = np.asarray(reference.span([whole.params], jnp.asarray(x), layers, **sizes))
-    common = np.asarray(reference.span([{**whole.params, **{f"experts_{n}": whole.params[f"experts_{n}"][:0] for n in ("gate", "up", "down")}}],
-                                       jnp.asarray(x), layers, **sizes))  # no expert held: all but the routed part
+    kinds = ("sparse/window",)
+    uncut = np.asarray(reference_program(kinds, "span", held_lo=0)([params], x))
+    common = np.asarray(reference_program(kinds, "span", held_lo=0)(
+        [{**params, **{f"experts_{n}": params[f"experts_{n}"][:0] for n in ("gate", "up", "down")}}], x))  # no expert held: all but the routed part
     routed_by_reference, routed_by_program = 0.0, 0.0
     for lo in range(0, EXPERTS, HELD):
-        share = {**whole.params, **{f"experts_{n}": whole.params[f"experts_{n}"][lo:lo + HELD] for n in ("gate", "up", "down")}}
-        routed_by_reference += np.asarray(reference.span([share], jnp.asarray(x), layers, **{**sizes, "held_lo": lo})) - common
+        share = {**params, **{f"experts_{n}": params[f"experts_{n}"][lo:lo + HELD] for n in ("gate", "up", "down")}}
+        routed_by_reference += np.asarray(reference_program(kinds, "span", held_lo=lo)([share], x)) - common
         module = name_to_block["exaone_moe_block"](HID, **{**COMMON, **KINDS["sparse/window"][0], "held_lo": lo})
-        routed_by_program += np.asarray(module.apply({"params": share}, jnp.asarray(x)), np.float32) - common
+        routed_by_program += np.asarray(jax.jit(module.apply)({"params": share}, x), np.float32) - common
     assert rel_err(common + routed_by_reference, uncut) <= 1e-5
     assert rel_err(common + routed_by_program, uncut) <= 4 * SERVED_TOL  # four shares' rounding, each against the float32 common part
     assert np.abs(routed_by_reference).max() > 0.1 * np.abs(uncut).max(), "the routed part is too small to tell"
@@ -305,7 +314,7 @@ def test_reference_tells_a_wrong_layer_apart(fault):
     want, _ = runner.reference_span(params, jnp.asarray(x), layers, SIZES)
     got, _ = runner.reference_span(params, jnp.asarray(x), sizes=SIZES, **{"layers": layers, **variant})
     assert rel_err(got, want) > 2 * SERVED_TOL
-    plain, _ = reference.span_with_routing(params, jnp.asarray(x), layers, **SIZES)
+    plain, _ = reference_program(tuple(SPAN))(params, x)
     assert rel_err(want, plain) <= 1e-5, "the runner's block-by-block reference is not the reference's span"
 
 
@@ -315,7 +324,7 @@ def test_departure_share_finds_a_program_with_the_window_off_by_one(window, told
     with a window of 7 or 9 holds nearly all of the matching wrong reference's
     departure (and the rounding noise does not hide it), the right block nearly none."""
     backend = make_backend("sparse/window", window=window)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=64)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=64)
     x = stream(9, 1, 40)
     chunks = [manager.decode(backend.name, "s", x[:, :19], reset=True)]
     chunks += [manager.decode(backend.name, "s", x[:, t:t + 1], reset=False) for t in range(19, 40)]
@@ -352,7 +361,7 @@ def test_parameter_counts_by_hand():
     assert [round(count / 1e6, 1) for count in (attention, counts[0], counts[1], sum(counts))] == [113.2, 453.0, 453.8, 2268.1]
 
 
-def test_five_block_span_through_server_and_remote_sequential():
+def test_five_block_span_through_server_and_remote_sequential(one_program_backends):
     """The rehearsal configuration's span (dense/window, sparse/window x2, sparse/full,
     sparse/window), built as the runner builds it, a client's prefill and single-token
     steps over the wire against the reference with the same held share."""
@@ -375,7 +384,8 @@ def test_five_block_span_through_server_and_remote_sequential():
         chunks += [pipe.decode_step(x[:, t:t + 1], "e2e") for t in range(21, 38)]
         pipe.close_decode_session("e2e")
         params = [server.backends[f"{config['serving']['uid_prefix']}{i}"].snapshot_params() for i in range(blocks)]
-        want, routing = reference.span_with_routing(params, jnp.asarray(x), runner.reference_layers(config), **runner.reference_sizes(config))
+        want, routing = jax.jit(lambda params, x: reference.span_with_routing(
+            params, x, runner.reference_layers(config), **runner.reference_sizes(config)))(params, x)
         assert [top_e is None for _, top_e in routing] == [True, False, False, False, False]
         assert positions_beyond(np.concatenate(chunks, axis=1), want, 2 * SERVED_TOL) <= 0.1  # five blocks; a flip moves a position
         counted = delta(before, counters("direct"))

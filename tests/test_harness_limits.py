@@ -3,6 +3,8 @@ every thread's stack instead of costing the run its clock, and a child process i
 started one bounded way. Driven with a 1 s bound passed to the function under test."""
 
 import asyncio
+import os
+import re
 import shutil
 import signal
 import subprocess
@@ -10,8 +12,14 @@ import sys
 import time
 
 import pytest
+import conftest
 from conftest import TEST_LIMIT_S, _run_async_test, time_limit
-from swarm_utils import REPO_ROOT, run_jax_workers, start_relay_daemon, stop_process
+from swarm_utils import REPO_ROOT, cpu_child_env, read_child_until, run_jax_workers, start_relay_daemon, stop_process
+
+
+# the line a test at its bound writes past the capture starts so (an xdist worker says which it is)
+WORKER = os.environ.get("PYTEST_XDIST_WORKER", "")
+NAMED = f"TEST_LIMIT {WORKER and f'[{WORKER}] '}"
 
 
 def _sleeps_past_it():
@@ -35,7 +43,9 @@ def _blocks_in_set_up():
 
 
 @pytest.mark.parametrize("hang", [_sleeps_past_it, _awaits_forever, _blocks_in_set_up])
-def test_a_hang_is_failed_inside_the_bound_with_every_threads_stack(hang, capfd):
+def test_a_hang_is_failed_inside_the_bound_with_every_threads_stack(hang, capfd, monkeypatch):
+    past_capture, terminal = os.pipe()  # stands in for the run's real stderr
+    monkeypatch.setattr(conftest, "_real_stderr", terminal)
     started = time.monotonic()
     with pytest.raises(pytest.fail.Exception, match="the hanging one was still running after the 1 s"):
         with time_limit(1.0, "the hanging one"):
@@ -43,6 +53,9 @@ def test_a_hang_is_failed_inside_the_bound_with_every_threads_stack(hang, capfd)
     assert time.monotonic() - started < 1.0 + 5.0
     stacks = capfd.readouterr().err
     assert "Current thread" in stacks and hang.__name__ in stacks, stacks[-2000:]
+    os.close(terminal)
+    with open(past_capture) as said:
+        assert said.read() == f"\n{NAMED}the hanging one was still running after 1 s\n"
 
     # the next one in the same process runs clean: its own bound is armed and taken off
     # again, no alarm of the failed one is left to fire, and this test's bound is back
@@ -125,3 +138,90 @@ def test_a_daemon_that_prints_no_banner_is_an_error_not_a_hang(tmp_path, monkeyp
     monkeypatch.setattr("hivemind_tpu.p2p.native_transport.NATIVE_DIR", tmp_path)
     with pytest.raises(AssertionError, match="printed no banner within 1 s"):
         start_relay_daemon(banner_timeout=1)
+
+
+# ------------------------------------------------- one budget a test, a hang named at once
+
+_CHILD_TESTS = r"""
+import time
+
+import conftest
+import pytest
+
+conftest.TEST_LIMIT_S = 3.0  # read when a test's set-up starts: these tests' one budget
+conftest.SPENT_BUDGET_GRACE_S = 1.0
+
+
+@pytest.fixture
+def hangs_in_tear_down():
+    yield
+    time.sleep(60)
+
+
+def test_hangs_in_call_and_tear_down(hangs_in_tear_down):
+    time.sleep(60)
+
+
+@pytest.fixture
+def tear_down_reads_what_is_left():
+    yield
+    print("LEFT_IN_TEAR_DOWN=%.2f" % conftest.signal.getitimer(conftest.signal.ITIMER_REAL)[0])
+    assert False, "shown with what it printed"
+
+
+def test_call_uses_most_of_the_budget(tear_down_reads_what_is_left):
+    time.sleep(1.5)
+
+
+def test_ends_in_time():
+    print("a test that ends in time says this to the captured stdout only")
+"""
+
+
+@pytest.fixture(scope="module")
+def child_run(tmp_path_factory):
+    """One child pytest run under this harness with capture on, a budget of 3 s a test and
+    1 s for a phase that starts with it spent: (its stderr up to the hung tear-down's line,
+    whether it still ran then, the seconds until then, all of its stderr, its stdout)."""
+    directory = tmp_path_factory.mktemp("child_run")
+    (directory / "test_child.py").write_text(_CHILD_TESTS)
+    env = cpu_child_env()
+    env["PYTHONPATH"] = os.pathsep.join([str(REPO_ROOT / "tests"), env["PYTHONPATH"]])
+    command = [sys.executable, "-m", "pytest", "test_child.py", "-p", "conftest", "-p", "no:cacheprovider"]
+    command += ["-p", "no:xdist", "-p", "no:randomly", "-q", "--rootdir", str(directory)]
+    started = time.monotonic()
+    child = subprocess.Popen(command, cwd=directory, env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE)
+    try:
+        named_at_once = read_child_until(child, r"\(tear-down\) was still running.*\n", 90, stream="stderr")
+        still_running = child.poll() is None
+        hang_failed_after = time.monotonic() - started
+        out, rest = child.communicate(timeout=90)
+    finally:
+        stop_process(child)
+    return named_at_once, still_running, hang_failed_after, named_at_once + rest.decode(), out.decode()
+
+
+def test_a_test_hung_in_call_and_tear_down_costs_one_budget_and_is_named_before_the_run_ends(child_run):
+    named_at_once, still_running, hang_failed_after, _stderr, out = child_run
+    node = "test_child.py::test_hangs_in_call_and_tear_down"
+    assert f"{NAMED}{node} (call) was still running after 3 s\n" in named_at_once
+    assert f"{NAMED}{node} (tear-down) was still running after 1 s\n" in named_at_once
+    assert still_running, "the lines were read from a run that had two tests to go"
+    # one budget and the tear-down's second, plus the child's start (jax, 8 devices)
+    assert hang_failed_after < 3.0 + 1.0 + 30.0
+    assert f"FAILED {node}" in out and f"ERROR {node}" in out, out[-3000:]
+    assert re.search(r"1 failed, 2 passed,( \d+ warnings?,)? 2 errors", out), out[-3000:]  # a warning: a raise that a callback swallowed
+
+
+def test_a_tear_down_has_what_the_call_left_of_the_budget(child_run):
+    out = child_run[-1]
+    (left,) = re.findall(r"LEFT_IN_TEAR_DOWN=([0-9.]+)", out)
+    assert 1.0 < float(left) <= 3.0 - 1.5, out[-3000:]  # more than a spent budget's second
+
+
+def test_only_a_test_at_its_bound_reaches_the_real_stderr_and_its_stacks_stay_captured(child_run):
+    _, _, _, stderr, out = child_run
+    lines = [line for line in stderr.splitlines() if line]
+    assert lines and all(line.startswith(f"{NAMED}test_child.py::test_hangs_in_call") for line in lines), stderr
+    assert "Current thread" not in stderr and "Current thread" in out  # with the test, in the summary
+    assert "a test that ends in time" not in stderr

@@ -22,7 +22,7 @@ def test_sharded_training_with_swarm_averaging():
     model, train_step = make_train_step(config, optimizer, masked_loss_fraction=0.25)
 
     batch = make_synthetic_mlm_batch(jax.random.PRNGKey(0), config, batch_size=4, seq_len=32)
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), batch["input_ids"])["params"]
     params = jax.device_put(params, params_shardings(params, mesh))
     opt_state = optimizer.init(params)
     batch = jax.device_put(batch, batch_sharding(mesh))

@@ -20,6 +20,7 @@ from hivemind_tpu.moe.server.llama_loader import (
     plan_block_capacity,
 )
 from hivemind_tpu.moe.server.server import Server
+from swarm_utils import wait_for_experts
 
 HID, HEADS, KV_HEADS, INNER, LAYERS = 128, 4, 2, 352, 2
 
@@ -124,7 +125,7 @@ def test_int8_blocks_serve_decode_sessions_over_rpc(tmp_path):
     client_dht = None
     try:
         server.run_in_background(await_ready=True)
-        time.sleep(1.0)
+        wait_for_experts(dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "ls.", LAYERS)
 
@@ -258,7 +259,7 @@ def test_greedy_generation_from_checkpoint_over_rpc(tmp_path):
     client_dht = None
     try:
         server.run_in_background(await_ready=True)
-        time.sleep(1.0)
+        wait_for_experts(dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "gen.", LAYERS)
 
@@ -323,7 +324,7 @@ def test_generation_across_two_servers(tmp_path):
     try:
         server_a.run_in_background(await_ready=True)
         server_b.run_in_background(await_ready=True)
-        time.sleep(1.0)
+        wait_for_experts(dht_a, [*server_a.backends, *server_b.backends])
         client_dht = DHT(initial_peers=[str(m) for m in dht_a.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "sp.", LAYERS)
         head = LlamaClientHead.load(tmp_path)

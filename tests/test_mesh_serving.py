@@ -4,7 +4,6 @@ UNCHANGED Server/RemoteSequential path — clients get token-identical generatio
 whether one device or the whole mesh answers. Re-designed reference role: the
 single-CUDA-device executor of hivemind/moe/server/runtime.py:22-199."""
 
-import time
 
 import jax
 import numpy as np
@@ -23,6 +22,7 @@ from hivemind_tpu.moe.server.mesh_backend import MeshModuleBackend
 from hivemind_tpu.moe.server.server import Server
 
 from test_llama_loader import HID, LAYERS, _write_checkpoint
+from swarm_utils import wait_for_experts
 
 
 def _tp_mesh() -> Mesh:
@@ -98,7 +98,7 @@ def test_mesh_sharded_server_is_token_identical_over_rpc(tmp_path):
     client_dht = None
     try:
         server.run_in_background(await_ready=True)
-        time.sleep(1.0)
+        wait_for_experts(dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in dht.get_visible_maddrs()], start=True)
         rng = np.random.RandomState(5)
         prompt_len, steps = 6, 6

@@ -17,6 +17,7 @@ and k flips moves ONE position by a sixth of its attention (2e-2), so a stream i
 to `SERVED_TOL` on all but a few positions (`positions_beyond`)."""
 
 import math
+import functools
 import sys
 from pathlib import Path
 
@@ -35,6 +36,7 @@ from hivemind_tpu.moe.server.module_backend import ModuleBackend  # noqa: E402
 from hivemind_tpu.telemetry import REGISTRY  # noqa: E402
 from perf.reference import minicpm_sala_block as reference  # noqa: E402
 from perf.runtime import rel_err  # noqa: E402
+from swarm_utils import ManagerSharingPrograms, OneProgramBackend  # noqa: E402
 
 HID, HEADS, KV, DIM, INNER = 64, 4, 2, 16, 96
 SPARSE = dict(kernel_size=4, kernel_stride=2, block_size=8, topk=6, init_blocks=1, window_size=16, dense_len=64)
@@ -47,10 +49,14 @@ SERVED_TOL = 1.2e-2
 MAX_LEN = 256
 
 
+@functools.cache  # read-only in every test (the optimizer's rate is 0): built once a process
 def make_backend(kind: str, uid="sala.0", seed=3, **overrides) -> ModuleBackend:
     module = name_to_block["minicpm_sala_block"](HID, mixer=MIXERS[kind], **{**COMMON, **overrides})
-    return ModuleBackend(uid, module, optimizer=optax.sgd(0.0), sample_input=name_to_input["minicpm_sala_block"](4, HID),
-                         max_batch_size=8, rng_seed=seed)
+    return OneProgramBackend(uid, module, optimizer=optax.sgd(0.0), sample_input=name_to_input["minicpm_sala_block"](4, HID),
+                             max_batch_size=8, rng_seed=seed)
+
+
+reference_span = jax.jit(functools.partial(reference.span, **SIZES))  # ONE program a shape, not one an operation
 
 
 def stream(seed: int, rows: int, length: int) -> np.ndarray:
@@ -75,8 +81,8 @@ def test_forward_matches_the_reference(kind):
     120 positions, past `dense_len` 64 and past the 48 positions that 6 blocks of 8 cover."""
     backend = make_backend(kind)
     x = stream(1, 2, 120)
-    want = reference.span([backend.params], jnp.asarray(x), **SIZES)
-    got = backend.module.apply({"params": backend.params}, jnp.asarray(x))
+    want = reference_span([backend.params], x)
+    got = jax.jit(backend.module.apply)({"params": backend.params}, x)
     assert rel_err(got, want) <= (5e-3 if kind == "lightning" else 5e-2)
     assert positions_beyond(got, want, SERVED_TOL) <= 0.05
 
@@ -87,7 +93,7 @@ def test_chunked_prompt_then_steps_equal_the_full_forward(kind):
     `dense_len` and is padded to 64, the first to 64 too), then 40 single steps, through
     the manager: the same positions as the reference's one forward of 141."""
     backend = make_backend(kind)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     x = stream(2, 1, 141)
     chunks, at = [], 0
     for length in (48, 37, 16):
@@ -95,7 +101,7 @@ def test_chunked_prompt_then_steps_equal_the_full_forward(kind):
         at += length
     chunks += [manager.decode(backend.name, "s", x[:, t:t + 1], reset=False) for t in range(at, 141)]
     got = np.concatenate(chunks, axis=1)
-    want = reference.span([backend.params], jnp.asarray(x), **SIZES)
+    want = reference_span([backend.params], x)
     assert got.shape == want.shape and positions_beyond(got, want, SERVED_TOL) <= 0.05
     session = manager._sessions[(backend.name, "s")]
     assert session.index == 141 and len(jax.tree_util.tree_leaves(session.cache)) == (3 if kind == "sparse" else 1)
@@ -108,7 +114,7 @@ def test_batched_rows_at_different_positions(kind):
     one program holds a row in the dense mode beside rows in the sparse mode; every
     row equals the reference's full forward of its own stream."""
     backend = make_backend(kind)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     lengths, steps = [90, 50, 70], 30
     x = stream(3, 3, max(lengths) + steps)
     got = [[manager.decode(backend.name, f"row{row}", x[row:row + 1, :length], reset=True)] for row, length in enumerate(lengths)]
@@ -120,7 +126,7 @@ def test_batched_rows_at_different_positions(kind):
             assert not isinstance(out, Exception), out
             got[row].append(out)
     assert counter("hivemind_moe_decode_calls_total", path="batched") - before == steps
-    want = np.asarray(reference.span([backend.params], jnp.asarray(x), **SIZES))
+    want = np.asarray(reference_span([backend.params], x))
     for row, length in enumerate(lengths):
         served = np.concatenate(got[row], axis=1)
         assert positions_beyond(served, want[row:row + 1, :length + steps], SERVED_TOL) <= 0.05, row
@@ -132,23 +138,25 @@ def test_chunked_scan_equals_the_recurrence():
     outputs and the state it leaves; and two chunks in a row equal one."""
     from hivemind_tpu.ops.linear_attention import lightning_log_decay, lightning_scan, lightning_step
 
+    lightning_scan = jax.jit(lightning_scan, static_argnames=("length", "sub_chunk"))  # a program a shape, not one an operation
+    lightning_step = jax.jit(lightning_step)
     rng = np.random.default_rng(4)
-    q, k, v = (jnp.asarray(rng.standard_normal((2, 40, HEADS, DIM)), jnp.float32) for _ in range(3))
+    q, k, v = (rng.standard_normal((2, 40, HEADS, DIM)).astype(np.float32) for _ in range(3))
     log_decay = lightning_log_decay(HEADS)
-    state, outs = jnp.zeros((2, HEADS, DIM, DIM), jnp.float32), []
+    state, outs = np.zeros((2, HEADS, DIM, DIM), np.float32), []
     for t in range(29):
         o, state = lightning_step(q[:, t], k[:, t], v[:, t], state, log_decay)
         outs.append(o)
-    want = jnp.stack(outs, axis=1)
+    want = np.stack(outs, axis=1)
     with jax.default_matmul_precision("highest"):
         got, got_state = lightning_scan(q, k, v, jnp.zeros_like(state), log_decay, length=29, sub_chunk=8)
         first, mid = lightning_scan(q[:, :16], k[:, :16], v[:, :16], jnp.zeros_like(state), log_decay, sub_chunk=8)
         second, end = lightning_scan(q[:, 16:32], k[:, 16:32], v[:, 16:32], mid, log_decay, length=13, sub_chunk=8)
     assert rel_err(got[:, :29], want) <= 1e-5 and rel_err(got_state, state) <= 1e-5
-    assert rel_err(jnp.concatenate([first, second], 1)[:, :29], want) <= 1e-5 and rel_err(end, state) <= 1e-5
+    assert rel_err(np.concatenate([first, second], 1)[:, :29], want) <= 1e-5 and rel_err(end, state) <= 1e-5
     # the fastest head's decay over a chunk of 4,096 underflows to 0 and never overflows: nothing here forms lambda^(-C)
-    far, _ = lightning_scan(*(jnp.tile(t, (1, 8, 1, 1)) for t in (q, k, v)), jnp.zeros_like(state), log_decay, sub_chunk=64)
-    assert bool(jnp.isfinite(far).all())
+    far, _ = lightning_scan(*(np.tile(t, (1, 8, 1, 1)) for t in (q, k, v)), jnp.zeros_like(state), log_decay, sub_chunk=64)
+    assert bool(np.isfinite(far).all())
 
 
 def test_sparse_mode_equals_dense_mode_while_the_blocks_cover_the_context():
@@ -157,9 +165,9 @@ def test_sparse_mode_equals_dense_mode_while_the_blocks_cover_the_context():
     modes agree; beyond, positions are left out and the outputs part."""
     backend = make_backend("sparse", dense_len=8)
     dense = name_to_block["minicpm_sala_block"](HID, mixer="minicpm4", **{**COMMON, "dense_len": 10**6})
-    x = jnp.asarray(stream(5, 1, 120))
-    sparse_out = backend.module.apply({"params": backend.params}, x)
-    dense_out = dense.apply({"params": backend.params}, x)
+    x = stream(5, 1, 120)
+    sparse_out = jax.jit(backend.module.apply)({"params": backend.params}, x)
+    dense_out = jax.jit(dense.apply)({"params": backend.params}, x)
     error = np.abs(np.asarray(sparse_out - dense_out)).max(-1)[0] / float(np.abs(dense_out).max())
     assert error[:48].max() <= 2e-3, error[:48].max()
     assert error[64:].max() >= 4 * error[:48].max() and (error[64:] > 2e-3).mean() > 0.5
@@ -176,13 +184,14 @@ def test_selection_counts_and_forced_blocks():
     rng = np.random.default_rng(6)
     q = jnp.asarray(rng.standard_normal((KV, HEADS // KV, DIM)), jnp.bfloat16)
     cache_k, cache_v = (jnp.asarray(rng.standard_normal((KV, MAX_LEN, DIM)), jnp.bfloat16) for _ in range(2))
-    compressed = ops.write_compressed(jnp.zeros((KV, MAX_LEN // 2, DIM), jnp.bfloat16), cache_k, 0, MAX_LEN // 2, config)
-    chosen, exists = ops.sparse_select(q, compressed, jnp.int32(100), config)
+    compressed = jax.jit(lambda into, keys: ops.write_compressed(into, keys, 0, MAX_LEN // 2, config))(
+        jnp.zeros((KV, MAX_LEN // 2, DIM), jnp.bfloat16), cache_k)  # each op ONE program, not one an operation
+    chosen, exists = jax.jit(lambda *args: ops.sparse_select(*args, config))(q, compressed, jnp.int32(100))
     chosen = np.asarray(chosen)
     assert chosen.shape == (KV, 6) and bool(np.asarray(exists).all())
     for head in range(KV):
         assert {0, 11, 12} <= set(chosen[head]) and chosen[head].max() == 12 and len(set(chosen[head])) == 6
-    _context, attended = ops.sparse_attend(q, cache_k, cache_v, jnp.asarray(chosen), exists, jnp.int32(100), config)
+    _context, attended = jax.jit(lambda *args: ops.sparse_attend(*args, config))(q, cache_k, cache_v, chosen, exists, jnp.int32(100))
     assert int(attended) == 5 * 8 + 5  # five whole blocks and positions 96..100 of the query's own
     # a kernel's compressed key is the mean of its positions
     assert rel_err(compressed[:, 7], cache_k[:, 14:18].astype(jnp.float32).mean(1)) <= 1e-2
@@ -197,8 +206,8 @@ OLDER_BLOCKS = {
 
 
 def older_backend(name: str, uid: str, **overrides) -> ModuleBackend:
-    return ModuleBackend(uid, name_to_block[name](HID, **{**OLDER_BLOCKS[name], **overrides}), optimizer=optax.sgd(0.0),
-                         sample_input=name_to_input[name](4, HID), max_batch_size=8, rng_seed=1)
+    return OneProgramBackend(uid, name_to_block[name](HID, **{**OLDER_BLOCKS[name], **overrides}), optimizer=optax.sgd(0.0),
+                             sample_input=name_to_input[name](4, HID), max_batch_size=8, rng_seed=1)
 
 
 @pytest.mark.parametrize("kind", ["sparse", "lightning", *sorted(OLDER_BLOCKS), "exaone_moe_block/full"])
@@ -215,7 +224,7 @@ def test_a_failed_step_leaves_no_half_updated_state(kind, monkeypatch):
     else:
         backend = older_backend(kind.split("/")[0], "older.0", **(dict(window=0) if kind.endswith("/full") else {}))
     assert backend.module.decode_rows_apart == (kind not in ("lightning", "exaone_moe_block"))
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     x = stream(7, 2, 80)
     for row in range(2):
         manager.decode(backend.name, f"row{row}", x[row:row + 1, :70], reset=True)
@@ -263,7 +272,7 @@ def test_blocks_that_keep_a_pair_refuse_a_continuation_chunk_as_before(name):
 
 def test_a_chain_takes_chunks_only_if_all_its_blocks_do():
     sala, old = make_backend("lightning", uid="mix.0"), older_backend("llama_block", "mix.1")
-    manager = DecodeSessionManager({"mix.0": sala, "mix.1": old}, max_len=64)
+    manager = ManagerSharingPrograms({"mix.0": sala, "mix.1": old}, max_len=64)
     x = stream(9, 1, 24)
     before = counter("hivemind_moe_decode_prefill_chunks_total")
     manager._decode_direct(("mix.0", "mix.1"), "s", x[:, :8], reset=True)
@@ -281,11 +290,11 @@ def test_a_chunk_near_the_end_of_the_cache_is_padded_to_fit():
     """A continuation chunk comes padded to a power of two, but never past the cache's
     end: a padded tail that did not fit would be written shifted back over real positions."""
     backend = make_backend("sparse")
-    manager = DecodeSessionManager({backend.name: backend}, max_len=128)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=128)
     x = stream(10, 1, 128)
     chunks = [manager.decode(backend.name, "s", x[:, :96], reset=True), manager.decode(backend.name, "s", x[:, 96:119], reset=False)]
     chunks += [manager.decode(backend.name, "s", x[:, t:t + 1], reset=False) for t in range(119, 128)]
-    want = reference.span([backend.params], jnp.asarray(x), **SIZES)
+    want = reference_span([backend.params], x)
     assert positions_beyond(np.concatenate(chunks, axis=1), want, SERVED_TOL) <= 0.05
     assert (backend.name, 1, 32) in manager._step_fns  # 23 positions: 32 slots were left, so padded to 32
 
@@ -296,7 +305,7 @@ def test_gauges_counters_and_program_names_by_kind():
     block (dense mode: all it had seen; sparse mode: 6 blocks' positions up to the
     query), and the kinds in the programs' names."""
     backends = {"k.0": make_backend("sparse", uid="k.0"), "k.1": make_backend("lightning", uid="k.1")}
-    manager = DecodeSessionManager(backends, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms(backends, max_len=MAX_LEN)
     manager.clear_sessions()
     x = stream(11, 1, 100)
     attended, cached = (counter(f"hivemind_moe_sparse_positions_{name}_total") for name in ("attended", "cached"))
@@ -345,7 +354,7 @@ def test_parameter_counts_by_hand():
     assert round((2 * caches[0] + 6 * caches[1]) * 32 / 1e9, 2) == 2.62
 
 
-def test_span_through_server_and_remote_sequential_and_failover_in_chunks():
+def test_span_through_server_and_remote_sequential_and_failover_in_chunks(one_program_backends):
     """The rehearsal configuration's span (sparse, six lightning, sparse), built as the
     runner builds it: a client's prompt in chunks and single-token steps over the wire
     against the reference; then the client's failover path re-sends the retained history
@@ -370,7 +379,7 @@ def test_span_through_server_and_remote_sequential_and_failover_in_chunks():
         chunks += [pipe.decode_step(x[:, t:t + 1], "e2e") for t in range(150, 170)]
         got = np.concatenate(chunks, axis=1)
         params = [server.backends[f"{config['serving']['uid_prefix']}{i}"].snapshot_params() for i in range(blocks)]
-        want = reference.span(params, jnp.asarray(x), **runner.reference_sizes(config))
+        want = jax.jit(functools.partial(reference.span, **runner.reference_sizes(config)))(params, x)
         assert positions_beyond(got, want, 2 * SERVED_TOL) <= 0.05  # eight blocks
 
         state = pipe._decode_routes["e2e"]

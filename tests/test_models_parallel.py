@@ -11,20 +11,23 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from hivemind_tpu.models import AlbertConfig, AlbertForMaskedLM, make_synthetic_mlm_batch, make_train_step, mlm_loss
 from hivemind_tpu.parallel import make_mesh, params_shardings, plain_attention, ring_attention
 
+# the comparisons' own side as ONE program a shape, not one an operation (ISSUE 53)
+plain_attention = jax.jit(plain_attention, static_argnames="causal")
+
 
 def test_albert_forward_and_shapes():
     config = AlbertConfig.tiny()
     model = AlbertForMaskedLM(config)
     batch = make_synthetic_mlm_batch(jax.random.PRNGKey(0), config, batch_size=2, seq_len=16)
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    logits = model.apply({"params": params}, batch["input_ids"])
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    logits = jax.jit(model.apply)({"params": params}, batch["input_ids"])
     assert logits.shape == (2, 16, config.vocab_size)
     assert logits.dtype == jnp.float32
-    loss = mlm_loss(logits, batch["labels"], batch["mlm_mask"])
+    loss = jax.jit(mlm_loss)(logits, batch["labels"], batch["mlm_mask"])
     assert np.isfinite(float(loss)) and float(loss) > 0
     # parameter sharing: one layer's worth of encoder params regardless of depth
     deep = AlbertForMaskedLM(AlbertConfig.tiny(num_layers=6))
-    deep_params = deep.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    deep_params = jax.jit(deep.init)(jax.random.PRNGKey(1), batch["input_ids"])["params"]
     count = lambda p: sum(x.size for x in jax.tree_util.tree_leaves(p))
     assert count(deep_params) == count(params)
 
@@ -34,8 +37,8 @@ def test_albert_training_reduces_loss():
     optimizer = optax.adam(1e-3)
     model, train_step = make_train_step(config, optimizer)
     batch = make_synthetic_mlm_batch(jax.random.PRNGKey(0), config, batch_size=4, seq_len=32)
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    opt_state = optimizer.init(params)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    opt_state = jax.jit(optimizer.init)(params)
     step = jax.jit(train_step)
     first_loss = None
     for _ in range(30):
@@ -103,8 +106,8 @@ def test_causal_lm_trains_and_shards():
     optimizer = optax.adam(1e-3)
     model, train_step = make_causal_train_step(config, optimizer)
     batch = make_synthetic_lm_batch(jax.random.PRNGKey(0), config, 4, 32)
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    opt_state = optimizer.init(params)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    opt_state = jax.jit(optimizer.init)(params)
     step = jax.jit(train_step)
     first_loss = None
     for _ in range(25):
@@ -116,8 +119,8 @@ def test_causal_lm_trains_and_shards():
     sharded_config = CausalLMConfig.tiny(mesh=mesh)
     model, train_step = make_causal_train_step(sharded_config, optimizer)
     batch = make_synthetic_lm_batch(jax.random.PRNGKey(0), sharded_config, 4, 32)
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    opt_state = optimizer.init(params)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    opt_state = jax.jit(optimizer.init)(params)
     params = jax.device_put(params, params_shardings(params, mesh))
     batch = jax.device_put(batch, NamedSharding(mesh, P("dp", "sp")))
     with mesh:
@@ -189,7 +192,7 @@ def test_ring_flash_attention_matches_plain():
 
     with mesh:
         ring_grads = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
-    plain_grads = jax.grad(plain_loss, argnums=(0, 1, 2))(q, k, v)
+    plain_grads = jax.jit(jax.grad(plain_loss, argnums=(0, 1, 2)))(q, k, v)
     for rg, pg in zip(ring_grads, plain_grads):
         np.testing.assert_allclose(np.asarray(rg), np.asarray(pg), rtol=1e-3, atol=1e-4)
 
@@ -202,8 +205,8 @@ def test_sharded_training_step_8_devices():
     optimizer = optax.sgd(1e-2)
     model, train_step = make_train_step(config, optimizer)
     batch = make_synthetic_mlm_batch(jax.random.PRNGKey(0), config, batch_size=4, seq_len=32)
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    opt_state = optimizer.init(params)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    opt_state = jax.jit(optimizer.init)(params)
 
     shardings = params_shardings(params, mesh)
     params = jax.device_put(params, shardings)
@@ -229,15 +232,15 @@ def test_masked_only_loss_equals_full_loss():
     config = AlbertConfig.tiny(max_position=64)
     model = AlbertForMaskedLM(config)
     batch = make_synthetic_mlm_batch(jax.random.PRNGKey(0), config, 4, 64)
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"][:1, :8])["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), batch["input_ids"][:1, :8])["params"]
 
-    full = mlm_loss(
+    full = jax.jit(lambda params, batch: mlm_loss(
         model.apply({"params": params}, batch["input_ids"]), batch["labels"], batch["mlm_mask"]
-    )
-    masked = model.apply(
+    ))(params, batch)
+    masked = jax.jit(lambda params, batch: model.apply(
         {"params": params}, batch["input_ids"], batch["labels"], batch["mlm_mask"], 32,
         method=AlbertForMaskedLM.loss_masked_only,
-    )
+    ))(params, batch)
     np.testing.assert_allclose(float(masked), float(full), rtol=1e-5)
 
     # gradients agree too (the actual training signal), across EVERY parameter
@@ -247,7 +250,7 @@ def test_masked_only_loss_equals_full_loss():
     updated = {}
     for fraction in (0.5, None):
         _model, step = make_train_step(config, optax.sgd(0.1), masked_loss_fraction=fraction)
-        opt_state = optax.sgd(0.1).init(params)
+        opt_state = jax.jit(optax.sgd(0.1).init)(params)
         loss, new_params, _ = jax.jit(step)(params, opt_state, batch)
         updated[fraction] = new_params
     for masked_leaf, full_leaf in zip(
@@ -272,8 +275,8 @@ def test_remat_training_step_matches_plain():
         config = AlbertConfig.tiny(max_position=64, remat=remat)
         model, step = make_train_step(config, optax.sgd(0.1))
         batch = make_synthetic_mlm_batch(jax.random.PRNGKey(0), config, 4, 64)
-        params = model.init(jax.random.PRNGKey(1), batch["input_ids"][:1, :8])["params"]
-        opt_state = optax.sgd(0.1).init(params)
+        params = jax.jit(model.init)(jax.random.PRNGKey(1), batch["input_ids"][:1, :8])["params"]
+        opt_state = jax.jit(optax.sgd(0.1).init)(params)
         loss, new_params, _ = jax.jit(step)(params, opt_state, batch)
         results[remat] = (float(loss), new_params)
 
@@ -294,8 +297,8 @@ def test_pallas_flash_attention_matches_plain():
     gradients flow through the custom_vjp recompute path."""
     import numpy as np
     from hivemind_tpu.ops.pallas_attention import flash_attention
-    from hivemind_tpu.ops.attention import plain_attention
 
+    flash_attention = jax.jit(flash_attention, static_argnums=(3, 4))
     rng = np.random.RandomState(0)
     for seq in (128, 192, 320):  # 192/320: padded tail blocks + multi-block carry
         q, k, v = (
@@ -310,7 +313,7 @@ def test_pallas_flash_attention_matches_plain():
     loss_fused = lambda q: flash_attention(q, k, v, True, True).sum()
     loss_exact = lambda q: plain_attention(q, k, v, causal=True).sum()
     np.testing.assert_allclose(
-        np.asarray(jax.grad(loss_fused)(q)), np.asarray(jax.grad(loss_exact)(q)),
+        np.asarray(jax.jit(jax.grad(loss_fused))(q)), np.asarray(jax.jit(jax.grad(loss_exact))(q)),
         rtol=2e-5, atol=2e-5,
     )
 
@@ -328,7 +331,7 @@ def _flash_against_float32(seq, head_dim, causal, dtype):
 
     def results(attention, *operands):
         loss = lambda q, k, v: (attention(q, k, v).astype(jnp.float32) * weight).sum()
-        return (attention(*operands), *jax.grad(loss, argnums=(0, 1, 2))(*operands))
+        return jax.jit(lambda *operands: (attention(*operands), *jax.grad(loss, argnums=(0, 1, 2))(*operands)))(*operands)
 
     exact = results(lambda q, k, v: plain_attention(q, k, v, causal=causal),
                     *(x.astype(jnp.float32) for x in (q, k, v)))
@@ -400,7 +403,6 @@ def test_pallas_flash_backward_kernels_match_plain_grads():
     terms are actually exercised (VERDICT r2 item 7)."""
     import numpy as np
     from hivemind_tpu.ops.pallas_attention import flash_attention
-    from hivemind_tpu.ops.attention import plain_attention
 
     rng = np.random.RandomState(1)
     w = jnp.asarray(np.cos(np.arange(16)), jnp.float32)  # non-uniform cotangent
@@ -411,8 +413,8 @@ def test_pallas_flash_backward_kernels_match_plain_grads():
             )
             loss_fused = lambda q, k, v: (flash_attention(q, k, v, causal, True) * w).sum()
             loss_exact = lambda q, k, v: (plain_attention(q, k, v, causal=causal) * w).sum()
-            grads_fused = jax.grad(loss_fused, argnums=(0, 1, 2))(q, k, v)
-            grads_exact = jax.grad(loss_exact, argnums=(0, 1, 2))(q, k, v)
+            grads_fused = jax.jit(jax.grad(loss_fused, argnums=(0, 1, 2)))(q, k, v)
+            grads_exact = jax.jit(jax.grad(loss_exact, argnums=(0, 1, 2)))(q, k, v)
             for name, gf, ge in zip("qkv", grads_fused, grads_exact):
                 np.testing.assert_allclose(
                     np.asarray(gf), np.asarray(ge), rtol=2e-4, atol=2e-5,

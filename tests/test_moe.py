@@ -25,6 +25,7 @@ from hivemind_tpu.moe import (
 from hivemind_tpu.moe.client.beam_search import MoEBeamSearcher
 from hivemind_tpu.moe.server.layers import FeedforwardExpert, name_to_block
 from hivemind_tpu.utils.timed_storage import get_dht_time
+from swarm_utils import wait_for_experts
 
 HID = 32
 
@@ -56,7 +57,7 @@ def test_module_backend_numerics():
     )
     x = np.random.RandomState(0).randn(5, HID).astype(np.float32)
     out = backend.forward(x)[0]
-    expected = module.apply({"params": backend.params}, jnp.asarray(x))
+    expected = jax.jit(module.apply)({"params": backend.params}, x)
     assert np.allclose(out, np.asarray(expected), atol=2e-2)  # bf16 compute tolerance
 
     # backward returns input grads AND trains the expert
@@ -85,8 +86,7 @@ def make_server(dht=None, uids=("ffn_test.0.0", "ffn_test.0.1", "ffn_test.1.0", 
 def test_remote_expert_forward_backward():
     server = make_server()
     try:
-        import time
-        time.sleep(1.0)  # let experts declare
+        wait_for_experts(server.dht, ["ffn_test.0.0"])
         infos = get_experts(server.dht, ["ffn_test.0.0"])
         assert infos[0] is not None
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)
@@ -97,7 +97,7 @@ def test_remote_expert_forward_backward():
         x = jnp.asarray(np.random.RandomState(0).randn(3, HID), jnp.float32)
         out = expert(x)
         backend = server.backends["ffn_test.0.0"]
-        expected = backend.module.apply({"params": backend.params}, x)
+        expected = jax.jit(backend.module.apply)({"params": backend.params}, x)
         assert np.allclose(np.asarray(out), np.asarray(expected), atol=2e-2)
 
         # gradients flow through the RPC (and train the server-side expert)
@@ -218,7 +218,7 @@ def test_multi_tensor_expert_backend_and_remote():
     rng = np.random.RandomState(0)
     x, y = rng.randn(3, HID).astype(np.float32), rng.randn(3, HID).astype(np.float32)
     out1, out2 = backend.forward(x, y)
-    ref1, ref2 = backend.module.apply({"params": backend.params}, jnp.asarray(x), jnp.asarray(y))
+    ref1, ref2 = jax.jit(backend.module.apply)({"params": backend.params}, x, y)
     assert np.allclose(out1, np.asarray(ref1), atol=1e-4)
     assert np.allclose(out2, np.asarray(ref2), atol=1e-4)
     grads = backend.backward(x, y, np.ones_like(out1), np.ones_like(out2))
@@ -262,8 +262,7 @@ def test_call_many_masks_dead_experts():
 
     server = make_server()
     try:
-        import time
-        time.sleep(1.0)
+        wait_for_experts(server.dht, ["ffn_test.0.0", "ffn_test.0.1"])
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)
         infos = get_experts(server.dht, ["ffn_test.0.0", "ffn_test.0.1"])
         good = [RemoteExpert(info, client_dht.node.p2p) for info in infos]
@@ -311,7 +310,7 @@ def test_deterministic_dropout_expert():
         mask = jnp.asarray((rng.rand(3, 16) > 0.2), jnp.float32)
         out = expert(x, mask)
         backend = server.backends["drop.0"]
-        expected = backend.module.apply({"params": backend.params}, x, mask)
+        expected = jax.jit(backend.module.apply)({"params": backend.params}, x, mask)
         assert np.allclose(np.asarray(out), np.asarray(expected), atol=2e-2)
 
         # gradient wrt x must be zero exactly where the mask dropped the input
@@ -344,7 +343,7 @@ def test_remote_sequential_pipeline():
     client_dht = None
     try:
         import time
-        time.sleep(1.0)
+        wait_for_experts(server_a.dht, ["blk.0", "blk.1", "blk.2"])
         client_dht = DHT(initial_peers=[str(m) for m in server_a.dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "blk.", 3, update_period=2.0)
         x = jnp.asarray(np.random.RandomState(0).randn(2, 64, 16), jnp.float32)
@@ -355,7 +354,7 @@ def test_remote_sequential_pipeline():
         expected = x
         for uid, backend_server in (("blk.0", server_a), ("blk.1", server_b), ("blk.2", server_a)):
             backend = backend_server.backends[uid]
-            expected = backend.module.apply({"params": backend.params}, expected)
+            expected = jax.jit(backend.module.apply)({"params": backend.params}, expected)
         assert np.allclose(np.asarray(out), np.asarray(expected), atol=5e-2)
 
         # gradients flow through the WHOLE pipeline (and train every block server)
@@ -434,8 +433,7 @@ def test_causal_block_pipeline_decode():
     )
     client_dht = None
     try:
-        import time
-        time.sleep(1.0)
+        wait_for_experts(server.dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "cblk.", 2)
 
@@ -465,8 +463,9 @@ def test_llama_block_gqa_causality_and_rope():
     block = LlamaBlockExpert(hidden_dim=16, num_heads=4, num_kv_heads=2)
     rng = np.random.RandomState(0)
     x = rng.randn(2, 32, 16).astype(np.float32)
-    params = block.init(jax.random.PRNGKey(0), jnp.asarray(x))
-    out = np.asarray(block.apply(params, jnp.asarray(x)))
+    apply = jax.jit(block.apply)
+    params = jax.jit(block.init)(jax.random.PRNGKey(0), x)
+    out = np.asarray(apply(params, x))
     assert out.shape == x.shape and np.isfinite(out).all()
 
     # GQA params: key/value project to kv_heads*head_dim = 8, query to 16
@@ -477,7 +476,7 @@ def test_llama_block_gqa_causality_and_rope():
     # causality: perturbing the suffix leaves the prefix outputs bit-identical
     y = x.copy()
     y[:, 20:] = rng.randn(2, 12, 16)
-    out_y = np.asarray(block.apply(params, jnp.asarray(y)))
+    out_y = np.asarray(apply(params, y))
     np.testing.assert_array_equal(out[:, :20], out_y[:, :20])
     assert np.abs(out[:, 20:] - out_y[:, 20:]).max() > 0
 
@@ -510,8 +509,7 @@ def test_llama_block_pipeline_decode():
     )
     client_dht = None
     try:
-        import time
-        time.sleep(1.0)
+        wait_for_experts(server.dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "lblk.", 2)
 
@@ -578,15 +576,16 @@ def test_decode_cache_matches_full_forward():
     ):
         block = cls(hidden_dim=16, **kwargs)
         x = jnp.asarray(rng.randn(2, 12, 16).astype(np.float32))
-        params = block.init(jax.random.PRNGKey(0), x)
-        full = np.asarray(block.apply(params, x))
+        apply = jax.jit(block.apply)  # a program a shape, not one an operation
+        params = jax.jit(block.init)(jax.random.PRNGKey(0), x)
+        full = np.asarray(apply(params, x))
 
         cache_k, cache_v = block.init_decode_cache(batch=2, max_len=32)
-        y, cache_k, cache_v = block.apply(params, x[:, :5], cache_k, cache_v, 0)
+        y, cache_k, cache_v = apply(params, x[:, :5], cache_k, cache_v, 0)
         np.testing.assert_array_equal(np.asarray(y), full[:, :5])
         assert cache_k.shape == cache_v.shape == (2, kwargs.get("num_kv_heads", 4), 32, 4)
         for t in range(5, 12):
-            y, cache_k, cache_v = block.apply(params, x[:, t:t + 1], cache_k, cache_v, t)
+            y, cache_k, cache_v = apply(params, x[:, t:t + 1], cache_k, cache_v, t)
             assert_same_to_bf16_rounding(y, full[:, t:t + 1], f"position {t}")
 
 
@@ -606,8 +605,7 @@ def test_decode_sessions_over_rpc():
     )
     client_dht = None
     try:
-        import time
-        time.sleep(1.0)
+        wait_for_experts(server.dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "dblk.", 2)
 
@@ -667,8 +665,7 @@ def test_decode_span_execution_across_two_servers():
     )
     client_dht = None
     try:
-        import time
-        time.sleep(1.5)
+        wait_for_experts(server_a.dht, [f"span.{i}" for i in range(4)])
         client_dht = DHT(initial_peers=[str(m) for m in server_a.dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "span.", 4)
 
@@ -717,7 +714,6 @@ def test_decode_failover_mid_generation_matches_uninterrupted_run():
     weights) takes over; the client re-prefills it from the retained input history
     and the emitted positions are IDENTICAL to an uninterrupted run — the caller
     never passes reset=True."""
-    import time
     import uuid
     from hivemind_tpu.moe import RemoteSequential
 
@@ -732,7 +728,7 @@ def test_decode_failover_mid_generation_matches_uninterrupted_run():
     )
     client_dht = server_b2 = None
     try:
-        time.sleep(1.5)
+        wait_for_experts(server_a.dht, ["fo.0", "fo.1"])
         client_dht = DHT(initial_peers=maddrs, start=True)
         pipe = RemoteSequential(client_dht, "fo.", 2, max_retries=4)
 
@@ -756,7 +752,7 @@ def test_decode_failover_mid_generation_matches_uninterrupted_run():
             expert_uids=["fo.1"], expert_cls="causal_transformer", hidden_dim=16,
             dht=None, start=True, optim_factory=lambda: optax.sgd(1e-4), initial_peers=maddrs,
         )
-        time.sleep(1.5)  # let the replacement declare fo.1
+        wait_for_experts(client_dht, ["fo.1"], served_by=server_b2.dht.peer_id)  # the replacement's record
 
         outs += [pipe.decode_step(hidden[:, t:t + 1], session) for t in (prompt + 2, prompt + 3)]
 
@@ -784,7 +780,6 @@ def test_decode_failover_with_span_groups():
     span; the second dies mid-generation and the replacement (same uids, seed-0
     weights) is re-prefilled THROUGH the span RPC — emitted positions identical
     to the uninterrupted run, and the recovered route still groups 2+2."""
-    import time
     import uuid
     from hivemind_tpu.moe import RemoteSequential
 
@@ -799,7 +794,7 @@ def test_decode_failover_with_span_groups():
     )
     client_dht = server_b2 = None
     try:
-        time.sleep(1.5)
+        wait_for_experts(server_a.dht, [f"fs.{i}" for i in range(4)])
         client_dht = DHT(initial_peers=maddrs, start=True)
         pipe = RemoteSequential(client_dht, "fs.", 4, max_retries=4)
 
@@ -823,7 +818,7 @@ def test_decode_failover_with_span_groups():
             expert_uids=["fs.2", "fs.3"], expert_cls="causal_transformer", hidden_dim=16,
             dht=None, start=True, optim_factory=lambda: optax.sgd(1e-4), initial_peers=maddrs,
         )
-        time.sleep(1.5)
+        wait_for_experts(client_dht, ["fs.2", "fs.3"], served_by=server_b2.dht.peer_id)
         outs += [pipe.decode_step(hidden[:, t:t + 1], session) for t in (prompt + 1, prompt + 2)]
 
         for i, (expected, got) in enumerate(zip(ref, outs)):
@@ -853,8 +848,7 @@ def test_span_fallback_for_span_unaware_server():
     )
     client_dht = None
     try:
-        import time
-        time.sleep(1.0)
+        wait_for_experts(server.dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "nospan.", 2)
 
@@ -980,8 +974,7 @@ def test_decode_continuous_batching_many_clients():
     )
     client_dht = None
     try:
-        import time
-        time.sleep(1.0)
+        wait_for_experts(server.dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "cbat.", 1)
 
@@ -1039,8 +1032,7 @@ def test_decode_prefill_streams_over_unary_cap():
     )
     client_dht = None
     try:
-        import time
-        time.sleep(1.0)
+        wait_for_experts(server.dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "big.", 1)
         rng = np.random.RandomState(0)

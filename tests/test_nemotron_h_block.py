@@ -14,6 +14,7 @@ Tolerances, as a share of the largest value of the reference's output: the serve
 near-tie of the router that bf16 flips moves ONE position by an expert's whole output, so an
 expert block is held to `SERVED_TOL` on all but a few positions (`positions_beyond`)."""
 
+import functools
 import sys
 from pathlib import Path
 
@@ -33,6 +34,7 @@ from hivemind_tpu.ops import sparse_experts  # noqa: E402
 from hivemind_tpu.telemetry import REGISTRY  # noqa: E402
 from perf.reference import nemotron_h_block as reference  # noqa: E402
 from perf.runtime import rel_err  # noqa: E402
+from swarm_utils import ManagerSharingPrograms, OneProgramBackend  # noqa: E402
 
 HID, MAX_LEN = 64, 256
 SMALL = dict(mamba_heads=8, mamba_head_dim=16, ssm_groups=2, ssm_state=16, conv_kernel=4, chunk_size=16, num_heads=4, num_kv_heads=2,
@@ -43,10 +45,19 @@ KINDS = ("mamba", "attention", "experts")
 SERVED_TOL = 2e-2
 
 
+@functools.cache  # read-only in every test (the optimizer's rate is 0): built once a process
 def make_backend(kind: str, uid="nh.0", seed=3, **overrides) -> ModuleBackend:
     module = name_to_block["nemotron_h_block"](HID, kind=kind, **{**SMALL, **overrides})
-    return ModuleBackend(uid, module, optimizer=optax.sgd(0.0), sample_input=name_to_input["nemotron_h_block"](4, HID),
-                         max_batch_size=8, rng_seed=seed)
+    return OneProgramBackend(uid, module, optimizer=optax.sgd(0.0), sample_input=name_to_input["nemotron_h_block"](4, HID),
+                             max_batch_size=8, rng_seed=seed)
+
+
+reference_span = jax.jit(functools.partial(reference.span, **SIZES))  # ONE program a shape, not one an operation
+
+
+@functools.cache
+def applied(module):
+    return jax.jit(module.apply)
 
 
 def stream(seed: int, rows: int, length: int) -> np.ndarray:
@@ -66,7 +77,7 @@ def counter(name: str, **labels) -> float:
 
 
 def full_forward(backend, x):
-    return np.asarray(reference.span([backend.snapshot_params()], jnp.asarray(x), **SIZES))
+    return np.asarray(reference_span([backend.snapshot_params()], x))
 
 
 def held_to_the_reference(got, want):
@@ -77,7 +88,7 @@ def held_to_the_reference(got, want):
 def test_forward_matches_the_reference(kind):
     backend = make_backend(kind)
     x = stream(1, 2, 50)
-    got = backend.module.apply({"params": backend.snapshot_params()}, jnp.asarray(x))
+    got = applied(backend.module)({"params": backend.snapshot_params()}, x)
     held_to_the_reference(got, full_forward(backend, x))
 
 
@@ -86,7 +97,7 @@ def test_chunked_prompt_then_batched_steps_at_mixed_positions_equal_the_full_for
     """Three sessions whose prompts (50, 37, 20) arrive in chunks of 16, the last padded to a power
     of two, then ten steps in ONE batched program a step (a bucket of 4: one padding row)."""
     backend = make_backend(kind)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=MAX_LEN)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=MAX_LEN)
     prompts, steps = (50, 37, 20), 10
     x = stream(2, 3, 60)
     want = full_forward(backend, x)
@@ -114,12 +125,14 @@ def test_a_right_padded_chunk_equals_the_unpadded_one_in_output_and_in_both_stat
     state: bit for bit what the unpadded chunk leaves; the attention's caches up to the real positions)."""
     module = make_backend(kind).module
     params = make_backend(kind).snapshot_params()
-    x = jnp.asarray(stream(4, 1, 16 + padded))
+    x = stream(4, 1, 16 + padded)
     cache = module.init_decode_cache(1, MAX_LEN)
     takes = (lambda n: (jnp.int32(n),)) if module.decode_takes_length else (lambda n: ())
-    _y, *cache = module.apply({"params": params}, x[:, :16], *cache, jnp.int32(0), *takes(16))
-    plain, *plain_cache = module.apply({"params": params}, x[:, 16:16 + length], *cache, jnp.int32(16), *takes(length))
-    got, *got_cache = module.apply({"params": params}, x[:, 16:].at[:, length:].set(7.0), *cache, jnp.int32(16), *takes(length))
+    padded_chunk = x[:, 16:].copy()
+    padded_chunk[:, length:] = 7.0
+    _y, *cache = applied(module)({"params": params}, x[:, :16], *cache, jnp.int32(0), *takes(16))
+    plain, *plain_cache = applied(module)({"params": params}, x[:, 16:16 + length], *cache, jnp.int32(16), *takes(length))
+    got, *got_cache = applied(module)({"params": params}, padded_chunk, *cache, jnp.int32(16), *takes(length))
     np.testing.assert_allclose(got[:, :length], plain, rtol=2e-2, atol=2e-2)
     assert len(got_cache) == len(plain_cache) == len(module.init_decode_cache(1, MAX_LEN))
     if kind == "mamba":
@@ -155,30 +168,35 @@ def test_shares_add_up():
     ``W_up`` to its own partial sum), with the shared expert and nothing else counted once, add up to the uncut
     layer: in the reference exactly, in the served block to its rounding."""
     whole = make_backend("experts", held=0, held_lo=0)
-    params = whole.snapshot_params()
+    params = jax.tree_util.tree_map(np.asarray, whole.snapshot_params())  # a share is cut in numpy: no program a slice
     x = stream(6, 2, 24)
     sizes = {**SIZES, "held_lo": 0}
-    u = reference._rms_norm(jnp.asarray(x), params["norm"]["scale"], 1e-5)
+    shares = [{**params, "experts_up": params["experts_up"][lo:lo + 2], "experts_down": params["experts_down"][lo:lo + 2]} for lo in range(0, 16, 2)]
+    experts = functools.partial(reference.experts, experts_per_token=4, routed_scale=5.0)
+
+    @jax.jit  # the reference's uncut layer, its shared expert and its eight shares: one program
+    def by_the_reference(params, shares, x):
+        with jax.default_matmul_precision("highest"):
+            u = reference._rms_norm(x, params["norm"]["scale"], 1e-5)
+            parts = [experts(own, u, held_lo=2 * share, shared=False)[0] for share, own in enumerate(shares)]
+            return experts(params, u, held_lo=0)[0], experts(params, u, held_lo=0, routed=False)[0], parts
+
+    uncut, shared_once, parts = by_the_reference(params, shares, x)
+    served_parts = []
     with jax.default_matmul_precision("highest"):
-        uncut, _ = reference.experts(params, u, experts_per_token=4, routed_scale=5.0, held_lo=0)
-        shared_once, _ = reference.experts(params, u, experts_per_token=4, routed_scale=5.0, held_lo=0, routed=False)
-        parts, served_parts = [], []
-        for share in range(8):
-            lo = 2 * share
-            own = {**params, "experts_up": params["experts_up"][lo:lo + 2], "experts_down": params["experts_down"][lo:lo + 2]}
-            parts.append(reference.experts(own, u, experts_per_token=4, routed_scale=5.0, held_lo=lo, shared=False)[0])
-            module = name_to_block["nemotron_h_block"](HID, kind="experts", **{**SMALL, "held_lo": lo, "held": 2})
-            assert module.held_experts == (lo, lo + 2)
-            served_parts.append(np.asarray(module.apply({"params": own}, jnp.asarray(x))) - x - np.asarray(shared_once))
+        for share, own in enumerate(shares):
+            module = name_to_block["nemotron_h_block"](HID, kind="experts", **{**SMALL, "held_lo": 2 * share, "held": 2})
+            assert module.held_experts == (2 * share, 2 * share + 2)
+            served_parts.append(np.asarray(jax.jit(module.apply)({"params": own}, x)) - x - np.asarray(shared_once))
     np.testing.assert_allclose(sum(parts) + shared_once, uncut, rtol=1e-4, atol=1e-4)
     assert float(np.abs(np.asarray(parts[0])).max()) > 0 and float(np.abs(np.asarray(sum(parts[1:]))).max()) > 0  # no share is the layer
     held_to_the_reference(sum(served_parts) + np.asarray(shared_once), np.asarray(uncut))
-    assert sizes["held_lo"] == 0 and np.asarray(reference.span([params], jnp.asarray(x), **sizes)).shape == x.shape
+    assert sizes["held_lo"] == 0 and np.asarray(jax.jit(functools.partial(reference.span, **sizes))([params], x)).shape == x.shape
 
 
 def chain_manager(**kwargs):
     backends = {f"nh.{at}": make_backend(kind, uid=f"nh.{at}", seed=at) for at, kind in enumerate(("mamba", "experts", "attention"))}
-    return DecodeSessionManager(backends, max_len=MAX_LEN, **kwargs), tuple(backends)
+    return ManagerSharingPrograms(backends, max_len=MAX_LEN, **kwargs), tuple(backends)
 
 
 def test_a_chain_with_an_empty_tree_in_the_middle():
@@ -191,7 +209,7 @@ def test_a_chain_with_an_empty_tree_in_the_middle():
     assert all(manager.supports(uid) for uid in chain) and manager._takes_chunks(chain)
     x = stream(8, 2, 60)
     all_params = [manager.backends[uid].snapshot_params() for uid in chain]
-    want = np.asarray(reference.span(all_params, jnp.asarray(x), **SIZES))
+    want = np.asarray(reference_span(all_params, x))
     none, rewritten, evicted = counter("hivemind_moe_decode_batched_rows_total", caches="none"), counter("hivemind_moe_ssm_state_bytes_total"), counter(
         "hivemind_moe_decode_session_evictions_total")
     got = [[manager._decode_direct(chain, f"s{row}", x[row:row + 1, :33], reset=True),
@@ -289,8 +307,8 @@ OLDER = {
 
 
 def _batched_text(name: str) -> str:
-    backend = ModuleBackend("older.0", name_to_block[name](HID, **OLDER[name]), optimizer=optax.sgd(0.0),
-                            sample_input=name_to_input[name](4, HID), max_batch_size=8, rng_seed=1)
+    backend = OneProgramBackend("older.0", name_to_block[name](HID, **OLDER[name]), optimizer=optax.sgd(0.0),
+                                sample_input=name_to_input[name](4, HID), max_batch_size=8, rng_seed=1)
     manager = DecodeSessionManager({"older.0": backend}, max_len=64)
     shape = lambda tree: jax.tree_util.tree_map(lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype), tree)
     columns = tuple((leaf,) * 4 for leaf in shape(manager._dummy_rows("older.0")))

@@ -19,8 +19,8 @@ Tolerances, as a share of the largest value of the reference's output:
   served block routes as the reference does (`routes_as_the_reference`, asserted
   where a flip would decide the test)."""
 
+import functools
 import sys
-import time
 from pathlib import Path
 
 import jax
@@ -32,7 +32,6 @@ import pytest
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
-from hivemind_tpu.moe.server.decode_session import DecodeSessionManager  # noqa: E402
 from hivemind_tpu.moe.server.layers import name_to_block, name_to_input  # noqa: E402
 from hivemind_tpu.moe.server.layers.common import ROUTING_COLLECTION  # noqa: E402
 from hivemind_tpu.moe.server.module_backend import ModuleBackend  # noqa: E402
@@ -40,6 +39,7 @@ from hivemind_tpu.telemetry import REGISTRY  # noqa: E402
 from perf.reference import olmoe_block as reference  # noqa: E402
 from perf.runners.moe_block_server import _program_routing, _router_mismatch_share  # noqa: E402
 from perf.runtime import rel_err  # noqa: E402
+from swarm_utils import ManagerSharingPrograms, OneProgramBackend, wait_for_experts  # noqa: E402
 
 HID, HEADS, EXPERTS, TOP_K, INNER = 128, 4, 8, 2, 64
 KWARGS = dict(num_heads=HEADS, num_experts=EXPERTS, experts_per_token=TOP_K, expert_inner=INNER)
@@ -49,10 +49,24 @@ COUNTERS = ("hivemind_moe_expert_layer_calls_total", "hivemind_moe_routed_pairs_
             "hivemind_moe_experts_hit_total", "hivemind_moe_expert_max_pairs_total")
 
 
-def make_backend(uid="olmoe.0", seed=3, **overrides) -> ModuleBackend:
+def fresh_backend(uid="olmoe.0", seed=3, **overrides) -> ModuleBackend:
     module = name_to_block["olmoe_block"](HID, **{**KWARGS, **overrides})
-    return ModuleBackend(uid, module, optimizer=optax.sgd(0.0), sample_input=name_to_input["olmoe_block"](4, HID),
-                         max_batch_size=8, rng_seed=seed)
+    return OneProgramBackend(uid, module, optimizer=optax.sgd(0.0), sample_input=name_to_input["olmoe_block"](4, HID),
+                             max_batch_size=8, rng_seed=seed)
+
+
+make_backend = functools.cache(fresh_backend)  # for the tests that only read it: built once a process
+
+
+@functools.cache
+def reference_program(entry: str = "span", **changed):
+    """The reference's ``entry`` as ONE program a shape, not one an operation."""
+    return jax.jit(functools.partial(getattr(reference, entry), **{**SIZES, **changed}))
+
+
+@functools.cache
+def sown_routing(module):
+    return jax.jit(functools.partial(module.apply, mutable=[ROUTING_COLLECTION]))
 
 
 def stream(seed: int, batch: int, length: int) -> np.ndarray:
@@ -60,13 +74,13 @@ def stream(seed: int, batch: int, length: int) -> np.ndarray:
 
 
 def chosen_by(backend, x) -> np.ndarray:
-    _, sown = backend.module.apply({"params": backend.params}, jnp.asarray(x), mutable=[ROUTING_COLLECTION])
+    _, sown = sown_routing(backend.module)({"params": backend.params}, x)
     [chosen] = jax.tree_util.tree_leaves(sown)
     return np.asarray(chosen)
 
 
 def routes_as_the_reference(backend, x) -> bool:
-    _, [(_, want)] = reference.span_with_routing([backend.params], jnp.asarray(x), **SIZES)
+    _, [(_, want)] = reference_program("span_with_routing")([backend.params], x)
     return bool((np.sort(chosen_by(backend, x), -1) == np.sort(np.asarray(want), -1)).all())
 
 
@@ -83,7 +97,7 @@ def test_forward_against_reference():
     x = stream(0, 3, 20)
     before = counters("pool")
     got = backend.forward(x)[0]
-    assert rel_err(got, reference.span([backend.params], jnp.asarray(x), **SIZES)) <= SERVED_TOL
+    assert rel_err(got, reference_program()([backend.params], x)) <= SERVED_TOL
     counted = delta(before, counters("pool"))
     # 3 rows padded to the bucket of 4: the padding row is computed and not counted
     assert counted["expert_layer_calls"] == 1 and counted["routed_pairs"] == 3 * 20 * TOP_K
@@ -116,12 +130,12 @@ def test_route_top_k_on_float32_inputs_equals_the_references_exactly(seed):
 
 
 def test_input_gradient_through_module_backend():
-    backend = make_backend()
+    backend = fresh_backend()  # its update is counted
     x, grad = stream(3, 2, 16), stream(13, 2, 16)
     assert routes_as_the_reference(backend, x), "this stream holds a near-tie that bf16 flips: take another seed"
     before = counters("pool")
     got = backend.backward(x, grad)[0]
-    _, want = reference.span_input_grad([backend.params], jnp.asarray(x), jnp.asarray(grad), **SIZES)
+    _, want = reference_program("span_input_grad")([backend.params], x, grad)
     assert rel_err(got, want) <= 2 * SERVED_TOL  # a gradient passes every rounding twice
     assert delta(before, counters("pool"))["routed_pairs"] == 2 * 16 * TOP_K
     assert backend.update_count == 1
@@ -133,7 +147,7 @@ def test_span_chain_counts_every_blocks_routing_once():
     per-block calls' own."""
     from hivemind_tpu.moe.server.module_backend import backward_chain, forward_chain
 
-    chain = [make_backend("olmoe.0", seed=3), make_backend("olmoe.1", seed=4)]
+    chain = [fresh_backend("olmoe.0", seed=3), fresh_backend("olmoe.1", seed=4)]  # their updates are counted
     x, grad = stream(3, 3, 16), stream(13, 3, 16)
     before = counters("pool")
     [got] = forward_chain(chain, x)
@@ -149,12 +163,12 @@ def test_span_chain_counts_every_blocks_routing_once():
 
 def test_prefill_and_single_token_steps_against_full_forward():
     backend = make_backend()
-    manager = DecodeSessionManager({backend.name: backend}, max_len=32)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=32)
     x = stream(4, 1, 20)
     before = counters("direct")
     chunks = [manager.decode(backend.name, "s", x[:, :11], reset=True)]  # 11 pads to 16
     chunks += [manager.decode(backend.name, "s", x[:, t:t + 1], reset=False) for t in range(11, 20)]
-    want = reference.span([backend.params], jnp.asarray(x), **SIZES)
+    want = reference_program()([backend.params], x)
     assert rel_err(np.concatenate(chunks, axis=1), want) <= SERVED_TOL
     counted = delta(before, counters("direct"))
     assert counted["expert_layer_calls"] == 10
@@ -182,11 +196,11 @@ def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward(kv_heads)
     from hivemind_tpu.telemetry.tracing import RECORDER
 
     backend = make_backend(num_kv_heads=kv_heads)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=32)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=32)
     lengths = [3, 5, 8, 4, 11, 6, 9]
     x = stream(5, len(lengths), 16)
     sessions = _prefilled_rows(manager, backend.name, x, lengths)
-    want = np.asarray(reference.span([backend.params], jnp.asarray(x), **{**SIZES, "num_kv_heads": kv_heads}))
+    want = np.asarray(reference_program(num_kv_heads=kv_heads)([backend.params], x))
     before, rows_before = counters("batched"), _rows_by_caches()
     for step in range(2):
         entries = [(None, session, x[row:row + 1, length + step:length + step + 1])
@@ -217,11 +231,11 @@ def test_batched_step_equals_the_direct_step(kv_heads):
     """The same tokens through the batched program and through the per-session
     program: one block code, so the outputs agree to rounding."""
     backend = make_backend(num_kv_heads=kv_heads)
-    manager = DecodeSessionManager({backend.name: backend}, max_len=32)
+    manager = ManagerSharingPrograms({backend.name: backend}, max_len=32)
     lengths = [4, 7, 5]
     x = stream(6, 3, 12)
     sessions = _prefilled_rows(manager, backend.name, x, lengths)
-    twins = DecodeSessionManager({backend.name: backend}, max_len=32)
+    twins = ManagerSharingPrograms({backend.name: backend}, max_len=32)
     _prefilled_rows(twins, backend.name, x, lengths)
     results = manager._decode_batch(
         backend.name, [(None, session, x[row:row + 1, length:length + 1]) for row, (session, length) in enumerate(zip(sessions, lengths))])
@@ -257,10 +271,10 @@ def test_reference_tells_a_wrong_layer_apart(fault):
     tolerance), and the whole block in bf16 (far over float32's rounding, 2e-5)."""
     backend = make_backend()
     x = jnp.asarray(stream(8, 2, 24))
-    want = np.asarray(reference.span([backend.params], x, **SIZES))
+    want = np.asarray(reference_program()([backend.params], x))
     params = backend.params
     if fault == "all_bf16":
-        got = reference.block(jax.tree_util.tree_map(lambda leaf: leaf.astype(jnp.bfloat16), params), x.astype(jnp.bfloat16), **SIZES)
+        got = reference_program("block")(jax.tree_util.tree_map(lambda leaf: leaf.astype(jnp.bfloat16), params), x.astype(jnp.bfloat16))
         assert rel_err(got, want) > 50 * 2e-5
         return
     def wrong_route(params, m, experts_per_token):
@@ -270,16 +284,16 @@ def test_reference_tells_a_wrong_layer_apart(fault):
         weakest = jnp.where(weights > 0, weights, jnp.inf).min(-1, keepdims=True)
         return jnp.where(weights == weakest, 0.0, weights), top_e
 
-    got = reference.span([params], x, route=wrong_route, **SIZES)
+    got = jax.jit(functools.partial(reference.span, route=wrong_route, **SIZES))([params], x)
     assert rel_err(got, want) > SERVED_TOL
 
 
 @pytest.mark.parametrize("block_cls, kwargs", [("olmoe_block", KWARGS), ("llama_block", dict(num_heads=HEADS))])
 def test_a_head_size_that_is_not_hidden_over_heads_fails_loudly(block_cls, kwargs):
     x = jnp.zeros((1, 4, HID), jnp.float32)
-    name_to_block[block_cls](HID, **kwargs, head_dim=HID // HEADS).init(jax.random.PRNGKey(0), x)  # 32: as derived
+    jax.jit(name_to_block[block_cls](HID, **kwargs, head_dim=HID // HEADS).init)(jax.random.PRNGKey(0), x)  # 32: as derived
     with pytest.raises(AssertionError, match="hidden / heads"):
-        name_to_block[block_cls](HID, **kwargs, head_dim=64).init(jax.random.PRNGKey(0), x)
+        jax.jit(name_to_block[block_cls](HID, **kwargs, head_dim=64).init)(jax.random.PRNGKey(0), x)
 
 
 def test_served_end_to_end_through_server_and_remote_sequential():
@@ -294,7 +308,7 @@ def test_served_end_to_end_through_server_and_remote_sequential():
     )
     client_dht = None
     try:
-        time.sleep(1.0)
+        wait_for_experts(server.dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)
         pipe = RemoteSequential(client_dht, "olmoe.", 2)
         x = stream(9, 1, 14)
@@ -303,7 +317,7 @@ def test_served_end_to_end_through_server_and_remote_sequential():
         chunks += [pipe.decode_step(x[:, t:t + 1], "e2e") for t in range(9, 14)]
         pipe.close_decode_session("e2e")
         params = [server.backends[f"olmoe.{i}"].snapshot_params() for i in range(2)]
-        want = reference.span(params, jnp.asarray(x), **SIZES)
+        want = reference_program()(params, x)
         assert rel_err(np.concatenate(chunks, axis=1), want) <= SERVED_TOL
         assert delta(before, counters("direct"))["routed_pairs"] == 2 * 14 * TOP_K
     finally:

@@ -26,6 +26,7 @@ from hivemind_tpu.telemetry.serving import (
     is_overload_error,
 )
 from hivemind_tpu.telemetry.tracing import Span
+from swarm_utils import wait_for_experts
 
 HID = 16
 
@@ -462,7 +463,7 @@ def test_two_peer_serving_attribution_shed_breaker_and_board(capsys):
     )
     client_dht = None
     try:
-        time.sleep(1.0)
+        wait_for_experts(server.dht, server.backends)
         client_dht = DHT(initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True)
         rng = np.random.RandomState(0)
 

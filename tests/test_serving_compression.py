@@ -4,7 +4,6 @@ compression swarm interop, and shed/breaker/scorecard behavior under
 compression. The serving wire dtype defaults to fp16 (``none`` = bit-identical
 fp32); every assertion here pins the contract the default relies on."""
 
-import time
 import uuid
 
 import numpy as np
@@ -23,6 +22,7 @@ from hivemind_tpu.compression import (
     split_tensor_for_streaming,
 )
 from hivemind_tpu.proto import runtime_pb2
+from swarm_utils import wait_for_experts
 
 HID = 16
 
@@ -94,7 +94,7 @@ def serving_pair():
     )
     client_dht = None
     try:
-        time.sleep(1.0)
+        wait_for_experts(server.dht, server.backends)
         client_dht = DHT(
             initial_peers=[str(m) for m in server.dht.get_visible_maddrs()], start=True
         )

@@ -740,7 +740,8 @@ def test_one_swarm_all_four_roles():
     LR, TARGET, EPOCHS = 0.1, 72, 2
     common = dict(
         run_id="four_roles", target_batch_size=TARGET,
-        target_group_size=4, matchmaking_time=2.5, averaging_timeout=40.0,
+        # the last peer to reach EPOCHS finds no partner for its state round and waits this out (ROADMAP D13)
+        target_group_size=4, matchmaking_time=2.5, averaging_timeout=20.0,
     )
     boot = DHT(start=True)
     maddrs = [str(m) for m in boot.get_visible_maddrs()]
@@ -876,7 +877,8 @@ def test_slice_degrades_to_local_grads_and_recovers_on_groupmate_churn():
         host_opt = Optimizer(
             dht=host_dht, run_id="churn_slice", params={"w": jnp.asarray(w)},
             optimizer=optax.sgd(LR), target_batch_size=TARGET, batch_size_per_step=8,
-            target_group_size=2, matchmaking_time=1.5, averaging_timeout=30.0,
+            # as the slice's: the host's last round, after the slice has stopped, waits this out (ROADMAP D13)
+            target_group_size=2, matchmaking_time=1.5, averaging_timeout=10.0,
         )
         target_epoch = slice_opt.local_epoch + 1
         stop = threading.Event()

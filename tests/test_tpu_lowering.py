@@ -110,8 +110,8 @@ def test_sharded_albert_train_step_lowers_for_tpu():
     optimizer = optax.adamw(1e-4)
     model, train_step = make_train_step(config, optimizer, masked_loss_fraction=0.25)
     batch = make_synthetic_mlm_batch(jax.random.PRNGKey(0), config, 8, 64)
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    opt_state = optimizer.init(params)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    opt_state = jax.jit(optimizer.init)(params)
 
     shardings = params_shardings(params, mesh)
     params = jax.device_put(params, shardings)
@@ -148,8 +148,8 @@ def test_sharded_train_step_with_flash_core_lowers_for_tpu(monkeypatch):
     optimizer = optax.adamw(1e-4)
     model, train_step = make_train_step(config, optimizer, masked_loss_fraction=0.25)
     batch = make_synthetic_mlm_batch(jax.random.PRNGKey(0), config, 8, 256)
-    params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
-    opt_state = optimizer.init(params)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), batch["input_ids"])["params"]
+    opt_state = jax.jit(optimizer.init)(params)
     params = jax.device_put(params, params_shardings(params, mesh))
     batch = jax.device_put(batch, NamedSharding(mesh, P("dp", "sp")))
     # force the flash core only for the export TRACE (init above runs eagerly on
