@@ -613,7 +613,7 @@ class RemoteExpert:
         )
 
     def decode_np(
-        self, x: np.ndarray, session_id: str, reset: bool = False, span: Optional[list] = None
+        self, x: np.ndarray, session_id: str, reset: bool = False, span: Optional[list] = None, loop_pass: int = 0
     ) -> np.ndarray:
         """One KV-cache decode-session step on the serving peer (rpc_decode):
         the prefill call (``reset=True``) seeds the session with the prompt chunk,
@@ -625,14 +625,20 @@ class RemoteExpert:
         :param span: uids of CONSECUTIVE pipeline blocks co-located on this peer
             (first must be this expert's uid): the server chains their session
             steps in one RPC, so a pipeline's per-token round-trips drop from
-            #blocks to #servers (Petals serves block spans the same way)."""
+            #blocks to #servers (Petals serves block spans the same way)
+        :param loop_pass: which pass of a looped model's loop this call is (the blocks run
+            ``decode_passes`` times a token, each pass on a cache of its own); 0 is not sent,
+            so a server that knows no passes is served as ever"""
         meta = {"session_id": session_id, "reset": reset}
+        if loop_pass:
+            meta["loop_pass"] = int(loop_pass)
         if span is not None:
             assert span[0] == self.uid, (span, self.uid)
             meta["uids"] = list(span)
         metadata = MSGPackSerializer.dumps(meta)
         [output] = RemoteExpertWorker.run_coroutine(
-            self._call("decode", [x], metadata, session=session_id, session_reset=reset)
+            # a reset at a later pass starts that pass over INSIDE the session: it goes where the session is
+            self._call("decode", [x], metadata, session=session_id, session_reset=reset and not loop_pass)
         )
         return output
 

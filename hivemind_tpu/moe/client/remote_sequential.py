@@ -109,8 +109,11 @@ class RemoteSequential:
         self._span_support: Dict[object, bool] = {}  # peer_id -> server groups spans
         # session_id -> {"route": pinned block handles, "chunks": list of input
         # chunks retained for failover re-prefill (None = over the retention cap),
-        # "positions": retained position count, "chunked": the longest chunk of more
-        # than one position that CONTINUED the session (0: the prompt came whole)}
+        # "later": the same for the passes after the first of a looped model's loop,
+        # pass -> list (a model of one pass has none), "positions": retained position
+        # count, all passes together, "chunked": the longest chunk of more than one
+        # position that CONTINUED the session (0: the prompt came whole), "passes": the
+        # blocks' `decode_passes`, asked of the servers when a call first names a pass}
         self._decode_routes: Dict[str, dict] = {}
         self.max_decode_routes = 256  # oldest pinned routes drop beyond this
         # seeded replica choice across route resolutions (ISSUE 13): fresh
@@ -360,7 +363,7 @@ class RemoteSequential:
         remote_span.defvjp(fwd, bwd)
         return remote_span(x)
 
-    def decode_step(self, x, session_id: str, reset: bool = False):
+    def decode_step(self, x, session_id: str, reset: bool = False, loop_pass: int = 0):
         """Chain one KV-cache decode-session step through every block: the prefill
         call (``reset=True``) seeds each block's session with the prompt chunk
         [batch, prompt_len, hid], later calls advance a single token
@@ -374,11 +377,22 @@ class RemoteSequential:
         full input history, re-resolves the route, re-prefills every group on the
         replacement peers from that history, and continues the stream — the caller
         never sees a reset, and emitted positions are identical to an
-        uninterrupted run (the re-prefill is deterministic)."""
+        uninterrupted run (the re-prefill is deterministic).
+
+        ``loop_pass``: which pass of a LOOPED model's loop the call is. Such a model's
+        blocks run ``decode_passes`` times a token (what the servers' ``rpc_info`` says; a
+        pass beyond it raises here), pass u on the cache that pass u wrote, and a token
+        is that many walks of the route, each taking what the caller made of the walk
+        before (the norm between the passes is the CALLER's: this class does not learn
+        the model). The route is pinned at the reset of pass 0 and shared by the passes;
+        a ``reset`` at a later pass starts that pass over inside the session. The
+        failover history is kept A PASS (``max_failover_history`` bounds the positions of
+        all of them together), and a failover rebuilds every pass's cache on the
+        replacement peers from it, in pass order."""
         import numpy as np
 
         x = np.asarray(x, np.float32)
-        if reset:
+        if reset and not loop_pass:
             # pin the route with FRESH immutable handles: _ResilientBlock objects
             # are shared and re-pointed in place by the periodic re-resolution, so
             # pinning them would let the route silently move to a cache-less peer.
@@ -395,8 +409,10 @@ class RemoteSequential:
                 state = {
                     "route": route,
                     "chunks": [],
+                    "later": {},
                     "positions": 0,
                     "chunked": 0,
+                    "passes": None,
                     "lock": prior["lock"] if prior is not None else threading.Lock(),
                 }
                 self._decode_routes[session_id] = state
@@ -416,30 +432,34 @@ class RemoteSequential:
         # KV positions are inherently ordered, so serializing is the only sound
         # semantics for same-session concurrency anyway.
         with state["lock"]:
-            # history retention: a LIST of chunks (concatenated only at failover, so a
+            if loop_pass:  # a call that names no pass asks nothing: it is served as it always was
+                if state["passes"] is None:
+                    state["passes"] = int(state["route"][0][0].info.get("decode_passes", 1))
+                if not 0 <= loop_pass < state["passes"]:
+                    raise ValueError(f"pass {loop_pass} of a pipeline whose blocks hold {state['passes']} pass(es) a session")
+            # history retention: a LIST of chunks a pass (concatenated only at failover, so a
             # long generation costs O(1) per step, not an O(context) recopy), capped by
             # max_failover_history — past the cap, retention stops and a dead peer is
             # a hard error again (restart with reset=True), bounding client memory
             step_appended = False
             if not reset and x.shape[1] > 1:
                 state["chunked"] = max(state["chunked"], x.shape[1])
-            if reset:
-                if self.max_failover_history and x.shape[1] <= self.max_failover_history:
-                    state["chunks"], state["positions"] = [x], x.shape[1]
-                else:  # retention disabled (cap 0) or the prompt alone exceeds the cap
-                    state["chunks"], state["positions"] = None, 0
-            elif state["chunks"] is not None:
-                if state["positions"] + x.shape[1] <= self.max_failover_history:
-                    state["chunks"].append(x)
+            if state["chunks"] is not None:
+                if reset and loop_pass:  # this pass starts over, and with it the passes that take it as their input
+                    for later in [u for u in state["later"] if u >= loop_pass]:
+                        state["positions"] -= sum(chunk.shape[1] for chunk in state["later"].pop(later))
+                if self.max_failover_history and state["positions"] + x.shape[1] <= self.max_failover_history:
+                    retained = state["later"].setdefault(loop_pass, []) if loop_pass else state["chunks"]
+                    retained.append(x)
                     state["positions"] += x.shape[1]
-                    step_appended = True
-                else:
-                    state["chunks"] = None  # over the cap: failover disabled for this session
+                    step_appended = not reset
+                else:  # retention disabled (cap 0), or over the cap: no failover for this session from here
+                    state["chunks"], state["later"], state["positions"] = None, {}, 0
             try:
                 out = x
                 groups_advanced = 0
                 for block, span in state["route"]:
-                    out = block.decode_np(out, session_id, reset=reset, span=span)
+                    out = block.decode_np(out, session_id, reset=reset, span=span, loop_pass=loop_pass)
                     groups_advanced += 1
             except Exception as e:
                 from hivemind_tpu.telemetry.serving import is_overload_error
@@ -457,19 +477,18 @@ class RemoteSequential:
                     # full re-prefill failover below, which rebuilds every
                     # group's cache consistently (or fails loudly).
                     if step_appended:
-                        state["chunks"].pop()
+                        retained.pop()
                         state["positions"] -= x.shape[1]
                     raise
                 if state["chunks"] is None:
                     raise  # history over the retention cap (or disabled): no failover
-                history = np.concatenate(state["chunks"], axis=1)
                 logger.warning(
                     f"decode session {session_id!r} lost a pinned peer ({e!r}); "
                     f"failing over: re-resolving the route and re-prefilling from "
-                    f"{history.shape[1]} retained positions"
+                    f"{state['positions']} retained positions of {1 + len(state['later'])} pass(es)"
                 )
                 try:
-                    out = self._decode_failover(session_id, state, history)
+                    out = self._decode_failover(session_id, state, np.concatenate(state["chunks"], axis=1), loop_pass)
                 except Exception:
                     # a FAILED failover leaves surviving servers' caches re-prefilled to
                     # an unknown point and this chunk already in the history: the
@@ -483,28 +502,34 @@ class RemoteSequential:
                     out = out[:, -x.shape[1]:]  # the caller expects this step's positions only
         return out
 
-    def _decode_failover(self, session_id: str, state: dict, history) -> "np.ndarray":
+    def _decode_failover(self, session_id: str, state: dict, history, loop_pass: int = 0) -> "np.ndarray":
         """Re-resolve the pipeline and re-prefill EVERY group from the retained
         input history (surviving groups simply rebuild identical caches; the
-        replacement peer builds its first). Each group's prefill output is the
-        next group's input, so one sweep both recovers the caches and computes the
-        current step. Retries with forced re-resolution
-        (a replacement server may take a moment to re-declare the uid)."""
+        replacement peer builds its first): ``history``, and then the later passes'
+        of a looped model (``state["later"]``), pass by pass in the loop's order (a pass
+        may not run ahead of the one before it). Each
+        group's prefill output is the next group's input, so one sweep a pass both
+        recovers the caches and computes the current step: what comes back is the
+        output of ``loop_pass``, the pass of the call that failed. Retries with forced
+        re-resolution (a replacement server may take a moment to re-declare the uid)."""
         import numpy as np
 
-        # a prompt that arrived in chunks is re-sent in chunks (no longer than the
-        # longest the session sent: what its servers were shown to take), each chunk
-        # through every group before the next; a prompt that came whole goes whole
-        size = state["chunked"] or history.shape[1]
-        pieces = [history[:, start:start + size] for start in range(0, history.shape[1], size)]
+        histories = {0: history, **{u: np.concatenate(chunks, axis=1) for u, chunks in sorted(state.get("later", {}).items())}}
 
         def one_attempt():
             route = self._grouped_range(0, self.num_blocks, force=True)
             outs = []
-            for index, out in enumerate(pieces):
-                for block, span in route:
-                    out = block.decode_np(out, session_id, reset=index == 0, span=span)
-                outs.append(np.asarray(out, np.float32))
+            for u, history in histories.items():
+                # a prompt that arrived in chunks is re-sent in chunks (no longer than the
+                # longest the session sent: what its servers were shown to take), each chunk
+                # through every group before the next; a prompt that came whole goes whole
+                size = state["chunked"] or history.shape[1]
+                for start in range(0, history.shape[1], size):
+                    out = history[:, start:start + size]
+                    for block, span in route:
+                        out = block.decode_np(out, session_id, reset=start == 0, span=span, loop_pass=u)
+                    if u == loop_pass:
+                        outs.append(np.asarray(out, np.float32))
             state["route"] = route
             return np.concatenate(outs, axis=1)
 

@@ -216,6 +216,9 @@ class ConnectionHandler(ServicerBase):
         info["activation_compression"] = self.activation_compression
         if self.decode_sessions.supports(request.uid):
             info["decode_max_len"] = self.decode_sessions.max_len
+            # how many times a token runs this block, each pass on a cache of its own (a looped model's
+            # `loop_pass`; 1 for every other block): a client reads it before it names a pass
+            info["decode_passes"] = self.decode_sessions.block_passes(request.uid)
         return runtime_pb2.ExpertInfoResponse(serialized_info=MSGPackSerializer.dumps(info))
 
     def _span_uids(self, uid: str, metadata: bytes) -> List[str]:
@@ -329,7 +332,7 @@ class ConnectionHandler(ServicerBase):
             grads = await self._run_span("backward", uids, inputs)
             return await self._respond(grads)
 
-    async def _run_decode(self, uid: str, metadata: bytes, tensors: List[np.ndarray]) -> np.ndarray:
+    async def _run_decode(self, uid: str, metadata: bytes, tensors: List[np.ndarray], span=None) -> np.ndarray:
         meta = MSGPackSerializer.loads(metadata) if metadata else {}
         session_id = meta.get("session_id")
         if not session_id:
@@ -343,17 +346,23 @@ class ConnectionHandler(ServicerBase):
         # flush window or the cohort before it) and compute onto the serving span
         uids = self._span_uids(uid, metadata)
         reset = bool(meta.get("reset", False))
-        return await self.decode_sessions.decode_span_async(uids, str(session_id), x, reset)
+        # which pass of a looped model's loop the step is (its blocks run `decode_passes` times a token, each
+        # pass on a cache of its own); a client that knows no passes sends none and is served the first
+        loop_pass = int(meta.get("loop_pass", 0))
+        if span is not None:
+            span.set("loop_pass", loop_pass)  # onto the ServingLedger's record of the request
+        return await self.decode_sessions.decode_span_async(uids, str(session_id), x, reset, loop_pass)
 
     async def rpc_decode(self, request: runtime_pb2.ExpertRequest, context: P2PContext) -> runtime_pb2.ExpertResponse:
         """One KV-cache session step (decode_session.py). Metadata carries
-        ``{"session_id": str, "reset": bool}``; sessions bypass the batching
-        pools — each holds its own per-client device cache."""
+        ``{"session_id": str, "reset": bool}`` and, from a client that walks a looped
+        model's loop, ``"loop_pass": int`` (absent: 0); sessions bypass the batching
+        pools — each holds its own per-client device cache, one a pass."""
         _SERVER_BYTES_RECEIVED.inc(request.ByteSize())
         tensors, deserialize_s = await self._deserialize_request(request.tensors)
-        with self._serving_trace("decode", request.uid, context, tensors, deserialize_s):
+        with self._serving_trace("decode", request.uid, context, tensors, deserialize_s) as span:
             self._admit(context, tensors, "decode")
-            output = await self._run_decode(request.uid, request.metadata, tensors)
+            output = await self._run_decode(request.uid, request.metadata, tensors, span)
             return await self._respond([output])
 
     async def rpc_replica_state(
@@ -415,7 +424,7 @@ class ConnectionHandler(ServicerBase):
                 if tensors and getattr(tensors[0], "ndim", 0):
                     span.set("batch", int(tensors[0].shape[0]))
             self._admit(context, tensors, "decode")
-            output = await self._run_decode(uid, metadata, tensors)
+            output = await self._run_decode(uid, metadata, tensors, span)
             head = await self._serialize_head([output])
         async for message in self._stream_response(head, []):
             yield message

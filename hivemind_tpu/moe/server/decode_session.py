@@ -19,6 +19,19 @@ reference to a cache argument. The step function is jitted once per
 (uid, batch, chunk-length) signature, and sessions expire by TTL / LRU cap so an
 abandoned client cannot pin device memory.
 
+**A pass axis** (ISSUE 56). A LOOPED model runs its stack of blocks several times a token with
+the same weights, pass u on the keys and values that pass u wrote. A block class says how
+many passes its sessions hold (``decode_passes``; 1 where it says nothing: every block but
+such a model's), a request names its pass (``loop_pass`` in the metadata, 0 where it names
+none), and the manager reads both: a session is still ONE entry of the table a block — one
+to the LRU cap, the TTL, `clear_sessions`, a failed step's drop and the eviction counters —
+holding a cache tree and a position A PASS (`_Session.trees`, `.positions`), made together
+at the reset of pass 0 and dropped together; the byte gauges count every tree. A step reads,
+donates and advances the tree of its own pass only, the program is the same at every pass
+(the block never learns which it is), and a cohort takes the waiting rows whatever their
+pass. The loop's order is held here as "a session is full" is: pass u may not run ahead of
+pass u-1. The norm between two passes is the client's.
+
 **Continuous batching, per span chain** (`decode_span_async`; `decode_async` is the
 chain of one): a request names the chain of this server's blocks it crosses, and the
 chain, not the block, is the unit of batching. Single-token steps of different
@@ -265,6 +278,29 @@ _COHORTS = _TELEMETRY.counter(
     "cohorts of decode steps run: the steps that waited on one span chain, walked through "
     "the chain's blocks in one executor call (one batched device call a block)",
 )
+# a looped model's blocks run several times a token, each pass on a cache of its own (ISSUE 56): a request names
+# its pass, and these say which passes the steps were and how many of them met in one cohort's programs
+_PASS_STEPS = _TELEMETRY.counter(
+    "hivemind_moe_decode_pass_steps_total",
+    "single-position decode steps served (a live row a block, batched and direct alike), by the pass of a looped "
+    "model's loop that the request named (0 for a block of one pass, and for a request that names none)",
+    ("pass",),
+)
+_PASS_STEPS_FIRST = _PASS_STEPS.labels("0")
+_COHORT_PASSES = _TELEMETRY.counter(
+    "hivemind_moe_decode_cohort_passes_total",
+    "the number of DISTINCT passes among a cohort's rows, summed over the cohorts: over "
+    "hivemind_moe_decode_cohorts_total it reads 1 where the passes never meet in a program, up to the blocks' decode_passes",
+)
+# a looped block attends, at pass u, what pass u cached: the work of a step grows with the context, as a latent
+# cache's does, and is counted the same way, from the rows' positions
+_LOOPED_POSITIONS = _TELEMETRY.counter(
+    "hivemind_moe_looped_positions_attended_total",
+    "positions that the steps of blocks of several passes (decode_cache_kind looped) attended in the cache of the step's "
+    "own pass: a live row a step a block, its write position + 1, padding rows excluded; by the step's path (batched = "
+    "a row of a cohort's program, direct = a session's own step); prompt chunks are not counted",
+    ("path",),
+)
 
 
 @contextlib.contextmanager
@@ -324,20 +360,26 @@ def _half_bucket(active: int) -> int:
 
 
 class _Session:
-    __slots__ = ("leaves", "tree", "nbytes", "batch", "index", "last_used", "lock", "batch_started")
+    """One client's session at one block: ONE entry of the table, to the cap, the TTL, the pins and the locks,
+    whatever the passes of the block (`decode_passes`): it holds a cache tree and a position A PASS, made
+    together and dropped together."""
 
-    def __init__(self, cache, batch: int):
-        # whatever the block's `init_decode_cache` returned: a tree of arrays, batch axis
+    __slots__ = ("trees", "positions", "tree", "nbytes", "row_bytes", "batch", "last_used", "lock", "batch_started")
+
+    def __init__(self, caches, batch: int):
+        # ``caches``: what the block's `init_decode_cache` returned, once a pass: a tree of arrays, batch axis
         # first (a `(cache_k, cache_v)` pair is a tree of two leaves; a block that keeps
         # nothing between calls returns a tree of NONE, and its session is a position and a
-        # batch); nothing here looks inside. It is kept FLAT, as the tuple of its leaves:
+        # batch); nothing here looks inside. Each is kept FLAT, as the tuple of its leaves:
         # that is what a block is handed and hands back, so no step and no batch walks a
-        # tree on the host
-        leaves, self.tree = jax.tree_util.tree_flatten(cache)
-        self.leaves = tuple(leaves)
-        self.nbytes = _row_bytes(leaves)  # a step hands back leaves of the same shapes
+        # tree on the host. A step is handed the leaves of ITS pass, and never learns which it was
+        flat = [jax.tree_util.tree_flatten(cache) for cache in caches]
+        self.tree = flat[0][1]
+        self.trees = [tuple(leaves) for leaves, _tree in flat]
+        self.positions = [0] * len(flat)  # a pass's write position: what that pass has cached
+        self.row_bytes = _row_bytes(self.trees[0])  # one pass's tree: what a row of a program holds; a step hands back leaves of the same shapes
+        self.nbytes = self.row_bytes * len(flat)  # what the entry pins on the device
         self.batch = batch
-        self.index = 0
         self.last_used = time.monotonic()
         # perf_counter at which the batch carrying this session's pending step
         # began to run: the end of that step's queue wait (decode_span_async)
@@ -345,20 +387,49 @@ class _Session:
         self.lock = threading.Lock()
 
     @property
+    def leaves(self):
+        """The first pass's leaves: all there is of a block of one pass."""
+        return self.trees[0]
+
+    @property
+    def index(self) -> int:
+        """The first pass's write position."""
+        return self.positions[0]
+
+    @index.setter
+    def index(self, position: int) -> None:
+        self.positions[0] = position
+
+    @property
     def cache(self):
-        """The tree as the block's `init_decode_cache` shaped it, of the leaves as they are now."""
-        return jax.tree_util.tree_unflatten(self.tree, self.leaves)
+        """The tree as the block's `init_decode_cache` shaped it, of the first pass's leaves as they are now."""
+        return jax.tree_util.tree_unflatten(self.tree, self.trees[0])
 
     @property
     def cache_k(self):
         """A `(cache_k, cache_v)` pair's first leaf (a tree of other shape has none)."""
-        cache_k, _cache_v = self.leaves
+        cache_k, _cache_v = self.trees[0]
         return cache_k
 
     @property
     def cache_v(self):
-        _cache_k, cache_v = self.leaves
+        _cache_k, cache_v = self.trees[0]
         return cache_v
+
+
+def _out_of_order(loop_pass: int, upto: int, before: int) -> str:
+    return (f"pass {loop_pass} of the session would reach position {upto}, past the {before} that pass {loop_pass - 1} "
+            f"holds: a pass takes the pass before it as its input")
+
+
+def _count_pass_steps(loop_pass: int, rows: int = 1) -> None:
+    (_PASS_STEPS.labels(str(loop_pass)) if loop_pass else _PASS_STEPS_FIRST).inc(rows)
+
+
+def _entry_pass(entry) -> int:
+    """The pass of a pending step ``(future, sessions, x, loop_pass)``; one handed over without (a caller that
+    knows no passes) is of the first."""
+    return entry[3] if len(entry) > 3 else 0
 
 
 class _Output:
@@ -443,6 +514,7 @@ class DecodeSessionManager:
         # host activations -> the device, one program a bucket (`_device_rows`)
         self._upload = tracked_jit(lambda xs: xs, site="decode_session.upload")
         self._cache_tally: Dict[str, List[int]] = {}  # kind of cache -> [bytes, entries] of the table (`_count_cache_locked`)
+        self._passes_of: Dict[Chain, int] = {}  # a chain that was made -> the passes its blocks' sessions hold (`_chain_passes`)
         # no session of the table was last used before this, as of the last eviction pass: sessions are only
         # used later, so until ``session_ttl`` past it no pass can find one expired (`_evict_due_locked`)
         self._oldest_use = float("inf")
@@ -482,8 +554,8 @@ class DecodeSessionManager:
             pinned = {
                 id(session)
                 for entries in self._pending.values()
-                for (_future, entry_sessions, _x) in entries
-                for session in entry_sessions
+                for entry in entries
+                for session in entry[1]
             } | set(self._in_flight) | {id(keep)}
             expired = [k for k in expired if id(sessions[k]) not in pinned]
             if expired:
@@ -523,11 +595,38 @@ class DecodeSessionManager:
         arrays) or ``joined`` (`_batched_fn`)."""
         return "apart" if getattr(self.backends[uid].module, "decode_rows_apart", False) else "joined"
 
+    def block_passes(self, uid: str) -> int:
+        """What the block says of its sessions' passes (`decode_passes` on the module; 1 where it says nothing)."""
+        return int(getattr(self.backends[uid].module, "decode_passes", 1))
+
+    def _chain_passes(self, chain: Chain) -> int:
+        """How many passes a session of ``chain`` holds: what its blocks say (`decode_passes`, an int on the
+        module; 1 where a block says nothing). A looped model's blocks run that many times a token, pass u on
+        the cache that pass u wrote, so a session holds that many trees and positions a block. The blocks of a
+        chain walk together, so they have to agree: a chain that does not is refused here, when it is made
+        (``ValueError``), and at every later step of it."""
+        passes = self._passes_of.get(chain)
+        if passes is None:
+            for uid in chain:
+                if not self.supports(uid):
+                    raise KeyError(f"expert {uid!r} does not support decode sessions")
+            said = [self.block_passes(uid) for uid in chain]
+            if len(set(said)) != 1 or said[0] < 1:
+                raise ValueError(f"the blocks of the chain {chain!r} disagree on decode_passes ({said}): they cannot walk together")
+            passes = self._passes_of[chain] = said[0]
+        return passes
+
+    def _check_pass(self, chain: Chain, loop_pass: int) -> None:
+        """A request's pass against its chain's, before anything is looked up or donated."""
+        passes = self._chain_passes(chain)
+        if not 0 <= loop_pass < passes:
+            raise ValueError(f"pass {loop_pass} of a chain whose sessions hold {passes} pass(es) ({chain[0]!r} ..)")
+
     def _count_cache_locked(self, uid: str, session: _Session, entries: int) -> None:
         """A session enters (+1) or leaves (-1) the table at ``uid``: its bytes and
         its entry onto the gauges of that block's kind of cache. One addition a
         change of the table, and never a walk of it at a step or a prefill."""
-        if not session.leaves:  # a block that keeps nothing pins nothing: its kind has no series
+        if not session.trees[0]:  # a block that keeps nothing pins nothing: its kind has no series
             return
         kind = self._cache_kind(uid)
         tally = self._cache_tally.setdefault(kind, [0, 0])
@@ -670,10 +769,11 @@ class DecodeSessionManager:
             cache = backend.shard_decode_cache(*cache)
         return cache
 
-    def _advance(self, uid: str, session: _Session, backend, x, chunk_len: int, new_len: int):
+    def _advance(self, uid: str, session: _Session, backend, x, chunk_len: int, new_len: int, loop_pass: int = 0):
         """Run the per-session jitted step on ``x`` (``new_len`` positions, already
         padded to ``chunk_len``; on the host or, mid-chain, where the block before
-        left it) under ``session.lock``, store the new caches and return the output
+        left it) under ``session.lock`` on the tree and at the position of ``loop_pass``
+        (the ONE program of every pass), store the new caches and return the output
         ON THE DEVICE, its program finished. The step DONATES the caches: if it
         fails (at dispatch or when its result is awaited), what the session still
         points at may be deleted buffers, so the session is dropped and the client's
@@ -682,20 +782,23 @@ class DecodeSessionManager:
         step = self._step_fn(uid, x.shape[0], chunk_len)
         length = (jnp.int32(new_len),) if self._takes_length(uid) else ()
         _CALLS_DIRECT.inc()
-        _DONATED_DIRECT.inc(session.nbytes)
+        _DONATED_DIRECT.inc(session.row_bytes)
         started = time.perf_counter()
         try:
-            with _trace_sync("decode.direct", uid=uid, chunk_len=chunk_len) as span:
-                y, session.leaves, routing, attended = step(
-                    backend.snapshot_params(), jnp.asarray(x), session.leaves, jnp.int32(session.index), *length,
+            with _trace_sync("decode.direct", uid=uid, chunk_len=chunk_len, **{"pass": loop_pass}) as span:
+                index = session.positions[loop_pass]
+                y, session.trees[loop_pass], routing, attended = step(
+                    backend.snapshot_params(), jnp.asarray(x), session.trees[loop_pass], jnp.int32(index), *length,
                 )
                 record_routing(routing, "direct", span, positions=new_len, held=held_range(backend.module))
                 record_attended(attended, positions=new_len)
                 kind = self._cache_kind(uid) if chunk_len == 1 else None  # a step's work that the model's sizes alone do not give
                 if kind == "latent":
-                    _LATENT_POSITIONS.inc(session.index + 1, path="direct")
+                    _LATENT_POSITIONS.inc(index + 1, path="direct")
                 elif kind == "ssm":
-                    _SSM_STATE_BYTES.inc(session.nbytes, path="direct")
+                    _SSM_STATE_BYTES.inc(session.row_bytes, path="direct")
+                elif kind == "looped":
+                    _LOOPED_POSITIONS.inc(index + 1, path="direct")
                 # the next block is dispatched when this one has finished: a cohort's
                 # program that arrives meanwhile waits for one block of a prefill, not
                 # for the chain
@@ -708,15 +811,17 @@ class DecodeSessionManager:
             self._drop_failed([session])
             raise
 
-    def decode(self, uid: str, session_id: str, x: np.ndarray, reset: bool) -> np.ndarray:
+    def decode(self, uid: str, session_id: str, x: np.ndarray, reset: bool, loop_pass: int = 0) -> np.ndarray:
         """One session step at one block: the span chain of one (`_decode_direct`)."""
-        return self._decode_direct((uid,), session_id, x, reset)
+        return self._decode_direct((uid,), session_id, x, reset, loop_pass)
 
-    def _decode_direct(self, chain: Chain, session_id: str, x: np.ndarray, reset: bool) -> np.ndarray:
+    def _decode_direct(self, chain: Chain, session_id: str, x: np.ndarray, reset: bool, loop_pass: int = 0) -> np.ndarray:
         """One session's step through the span chain, ONE chain on the device: prefill
         (``reset=True``, chunk = the prompt or its first chunk), a further chunk of the
         prompt (a chain whose blocks all take chunks, `_takes_chunks`), or advance one
-        token in an existing session. The chunk is padded and uploaded once, each block's own program runs
+        token in an existing session, at the pass ``loop_pass`` of a chain whose blocks run several times a
+        token (`_chain_passes`; ``reset`` at the first pass makes the session with every pass's cache, at a later
+        one it starts that pass's position over). The chunk is padded and uploaded once, each block's own program runs
         on the output of the block before where it lies, and only the chain's last
         output comes to the host (a prompt of 4,096 positions at hidden 6,144 is 100 MB,
         which crossed the host twice a block). Returns the last block's output for the
@@ -724,6 +829,7 @@ class DecodeSessionManager:
         for uid in chain:
             if not self.supports(uid):
                 raise KeyError(f"expert {uid!r} does not support decode sessions")
+        self._check_pass(chain, loop_pass)
         x = np.asarray(x, np.float32)
         assert x.ndim == 3, f"decode input must be [batch, chunk, hid], got {x.shape}"
         batch, new_len = x.shape[0], x.shape[1]
@@ -748,18 +854,22 @@ class DecodeSessionManager:
             with self._lock:
                 held = self._sessions.get((chain[0], session_id))
             if held is not None:  # the padded tail has to fit the cache too: a write past its end would be shifted
-                padded_len = max(min(padded_len, self.max_len - held.index), new_len)
+                padded_len = max(min(padded_len, self.max_len - held.positions[loop_pass]), new_len)
         if padded_len != new_len:
             x = np.pad(x, ((0, 0), (0, padded_len - new_len), (0, 0)))
         record_transfer(x.nbytes, "host_to_device")
         y, entered = x, []
         for uid in chain:
-            session = self._enter(uid, session_id, batch, reset)
+            session = self._enter(uid, session_id, batch, reset, loop_pass)
             with session.lock:
-                self._check_step(session, session_id, batch, new_len, continues)
-                y = self._advance(uid, session, self.backends[uid], y, padded_len, new_len)
-                session.index += new_len
+                if reset and loop_pass:
+                    self._start_pass_over(uid, session, loop_pass)
+                self._check_step(session, session_id, batch, new_len, continues, loop_pass)
+                y = self._advance(uid, session, self.backends[uid], y, padded_len, new_len, loop_pass)
+                session.positions[loop_pass] += new_len
                 _STEPS.inc(path="direct")
+                if new_len == 1:
+                    _count_pass_steps(loop_pass)
             entered.append(session)
         # re-stamp AFTER the device steps: a step that hits a jit compile can
         # outlast MERGE_RECENCY_S, and a session stamped only at entry would
@@ -777,14 +887,17 @@ class DecodeSessionManager:
         record_transfer(out.nbytes, "device_to_host")
         return out
 
-    def _enter(self, uid: str, session_id: str, batch: int, reset: bool) -> _Session:
-        """The session of ``session_id`` at ``uid``: a fresh one for ``reset``."""
+    def _enter(self, uid: str, session_id: str, batch: int, reset: bool, loop_pass: int = 0) -> _Session:
+        """The session of ``session_id`` at ``uid``: a fresh one for ``reset`` at the first pass, with a cache
+        tree for every pass the block says (`decode_passes`). ``reset`` at a LATER pass leaves the entry in
+        place: it is looked up as a continuation's is, and the caller starts that pass over (`_start_pass_over`)."""
         key = (uid, session_id)
         with self._lock:
-            if reset:
+            if reset and not loop_pass:
                 if key in self._sessions:
                     self._drop_locked([key])
-                session = self._sessions[key] = _Session(self._fresh_caches(self.backends[uid], batch), batch)
+                caches = [self._fresh_caches(self.backends[uid], batch) for _ in range(self.block_passes(uid))]
+                session = self._sessions[key] = _Session(caches, batch)
                 self._count_cache_locked(uid, session, +1)
                 _RESETS.inc()
                 self._stamp_locked(uid, (session,), time.monotonic())
@@ -797,6 +910,15 @@ class DecodeSessionManager:
             session = self._known_locked((uid,), session_id)[0]
             self._stamp_locked(uid, (session,), time.monotonic())
         return session
+
+    def _start_pass_over(self, uid: str, session: _Session, loop_pass: int) -> None:
+        """``reset`` at a pass after the first (under ``session.lock``): that pass's position starts over, on a
+        fresh tree where the pass held anything (a state that a step updates has to start empty; a session's
+        first prompt finds the tree its entry was made with)."""
+        if session.positions[loop_pass]:
+            session.trees[loop_pass] = tuple(jax.tree_util.tree_leaves(self._fresh_caches(self.backends[uid], session.batch)))
+            session.positions[loop_pass] = 0
+        _RESETS.inc()
 
     def _stamp_chain_locked(self, chain: Chain, rows: List) -> None:
         """``rows`` (each the sessions of one client at the blocks of ``chain``) are used now: a step of
@@ -821,28 +943,35 @@ class DecodeSessionManager:
             )
         return sessions
 
-    def _check_step(self, session: _Session, session_id: str, batch: int, new_len: int, continues: bool = False) -> None:
+    def _check_step(self, session: _Session, session_id: str, batch: int, new_len: int, continues: bool = False,
+                    loop_pass: int = 0) -> None:
         """What a step must meet at a block (under ``session.lock``). ``continues``: the
-        chunk is a further chunk of a prompt, on a chain that takes those."""
-        if session.index and new_len != 1 and not continues:  # a prefill takes any chunk length (causal within the chunk)
+        chunk is a further chunk of a prompt, on a chain that takes those. The loop's ORDER is held here, as
+        "a session is full" is: pass u of a position takes pass u-1 of it as its input, so a call that would
+        carry a later pass's position past the pass before it is refused, and nothing is donated."""
+        index = session.positions[loop_pass]
+        if index and new_len != 1 and not continues:  # a prefill takes any chunk length (causal within the chunk)
             raise ValueError(
-                f"session {session_id!r} already holds {session.index} positions; "
+                f"session {session_id!r} already holds {index} positions; "
                 f"only 1-token steps may follow the prefill (got chunk {new_len})"
             )
-        if session.index + new_len > self.max_len:
-            raise ValueError(f"session {session_id!r} is full ({session.index}/{self.max_len})")
+        if index + new_len > self.max_len:
+            raise ValueError(f"session {session_id!r} is full ({index}/{self.max_len})")
         if session.batch != batch:
             raise ValueError(f"session {session_id!r} batch is {session.batch}, got {batch}")
+        if loop_pass and index + new_len > session.positions[loop_pass - 1]:
+            raise ValueError(_out_of_order(loop_pass, index + new_len, session.positions[loop_pass - 1]))
 
     # ---- continuous batching of single-token steps across sessions ------------
 
-    async def decode_async(self, uid: str, session_id: str, x: np.ndarray, reset: bool):
+    async def decode_async(self, uid: str, session_id: str, x: np.ndarray, reset: bool, loop_pass: int = 0):
         """One block's step: the span chain of one (`decode_span_async`)."""
-        return await self.decode_span_async((uid,), session_id, x, reset)
+        return await self.decode_span_async((uid,), session_id, x, reset, loop_pass)
 
-    async def decode_span_async(self, uids, session_id: str, x: np.ndarray, reset: bool):
+    async def decode_span_async(self, uids, session_id: str, x: np.ndarray, reset: bool, loop_pass: int = 0):
         """Asyncio entrypoint: one session's step through the span chain ``uids``
-        (this server's blocks that the request crosses, in order). Batchable steps
+        (this server's blocks that the request crosses, in order), at the pass ``loop_pass`` of the
+        sessions' passes (`_chain_passes`: a looped model's blocks run several times a token). Batchable steps
         (continuation, chunk 1, session batch 1) join the chain's next cohort, which
         takes every block of the chain as one batched device call over the steps
         that waited together; everything else takes the direct per-session path,
@@ -854,14 +983,16 @@ class DecodeSessionManager:
         window or the cohort before it; a direct step has none), ``compute_s`` the
         rest."""
         started = time.perf_counter()
-        out, queue_wait = await self._submit_step(tuple(uids), session_id, x, reset)
+        out, queue_wait = await self._submit_step(tuple(uids), session_id, x, reset, loop_pass)
         if queue_wait:
             accrue_span_phase("queue_wait_s", queue_wait)
         accrue_span_phase("compute_s", time.perf_counter() - started - queue_wait)
         return out
 
-    async def _submit_step(self, chain: Chain, session_id: str, x: np.ndarray, reset: bool):
-        """`decode_span_async` without the attribution: (output, seconds queued)."""
+    async def _submit_step(self, chain: Chain, session_id: str, x: np.ndarray, reset: bool, loop_pass: int = 0):
+        """`decode_span_async` without the attribution: (output, seconds queued). A cohort takes the steps that
+        wait on the chain WHATEVER their pass: the program is the same at every pass, each row on its own pass's
+        arrays."""
         loop = asyncio.get_running_loop()
         x = np.asarray(x, np.float32)
         batchable = not reset and x.ndim == 3 and x.shape[0] == 1 and x.shape[1] == 1
@@ -875,10 +1006,11 @@ class DecodeSessionManager:
                 # a chain's sessions are opened together: its first block speaks for it
                 batchable = self._concurrent_sessions(chain[0])
                 if batchable:
+                    self._check_pass(chain, loop_pass)
                     sessions = self._known_locked(chain, session_id)
                     self._stamp_chain_locked(chain, [sessions])
                     future = loop.create_future()
-                    self._pending.setdefault(chain, []).append((future, sessions, x))
+                    self._pending.setdefault(chain, []).append((future, sessions, x, loop_pass))
                     if chain not in self._drainers or self._drainers[chain].done():
                         self._drainers[chain] = spawn(self._drain(chain), name="decode_session.drain")
         if not batchable:
@@ -887,7 +1019,7 @@ class DecodeSessionManager:
             # has nothing to merge and costs ~ms per token, so it takes the direct
             # per-session path (same jitted step; same-session ordering is still
             # serialized by the session lock). ISSUE 10.
-            return await loop.run_in_executor(None, self._decode_direct, chain, session_id, x, reset), 0.0
+            return await loop.run_in_executor(None, self._decode_direct, chain, session_id, x, reset, loop_pass), 0.0
         out = await future
         return out, max(sessions[0].batch_started - enqueued, 0.0)
 
@@ -905,8 +1037,8 @@ class DecodeSessionManager:
     def _pin_locked(self, entries: List, step: int) -> None:
         """Move the eviction pins of ``entries``' sessions by ``step`` (+1 as they
         leave `_pending` for a cohort, -1 when it has resolved). Under self._lock."""
-        for _future, sessions, _x in entries:
-            for session in sessions:
+        for entry in entries:
+            for session in entry[1]:
                 count = self._in_flight.get(id(session), 0) + step
                 if count > 0:
                     self._in_flight[id(session)] = count
@@ -985,7 +1117,7 @@ class DecodeSessionManager:
             # live drainer), so they are swept too or they strand and pin forever.
             with self._lock:
                 stranded = self._pending.pop(chain, [])
-            for future, _sessions, _x in held + stranded:
+            for future, *_step in held + stranded:
                 if not future.done():
                     future.cancel()
             for task in resolving:
@@ -1010,8 +1142,8 @@ class DecodeSessionManager:
                 results = await asyncio.get_running_loop().run_in_executor(None, finish)
             except Exception as e:
                 results = [e] * len(cohort)
-            ended = [sessions for (_future, sessions, _x), result in zip(cohort, results) if not isinstance(result, Exception)]
-            for (future, _sessions, _x), result in zip(cohort, results):
+            ended = [entry[1] for entry, result in zip(cohort, results) if not isinstance(result, Exception)]
+            for (future, *_step), result in zip(cohort, results):
                 if future.done():
                     continue
                 if isinstance(result, Exception):
@@ -1019,7 +1151,7 @@ class DecodeSessionManager:
                 else:
                     future.set_result(result)
         except asyncio.CancelledError:
-            for future, _sessions, _x in cohort:
+            for future, *_step in cohort:
                 if not future.done():
                     future.cancel()
             raise
@@ -1029,8 +1161,8 @@ class DecodeSessionManager:
                 self._stamp_chain_locked(chain, ended)
 
     def _launch_cohort(self, chain: Chain, entries: List):
-        """Walk ``entries`` [(future, [the session of each uid], x)] through the
-        chain: at each block the rows still alive are one `_decode_batch`, and a
+        """Walk ``entries`` [(future, [the session of each uid], x, loop_pass)] (the pass may be left out: the
+        first) through the chain: at each block the rows still alive are one `_decode_batch`, whatever their passes, and a
         row's output there is its input at the next block — left on the device
         (`_Row`), so that the next block's program is dispatched while this one's
         runs. The walk runs at most one program ahead of the device: before block
@@ -1040,7 +1172,10 @@ class DecodeSessionManager:
         ``finish``: called once, from any thread, it waits for the chain's last
         program and returns one result (ndarray or Exception) per entry, in order."""
         _COHORTS.inc()
-        results: List = [x for _future, _sessions, x in entries]
+        # a chain of one pass (every chain but a looped model's) holds one pass by definition: nothing is read off its rows
+        passes = len({_entry_pass(entry) for entry in entries}) if self._chain_passes(chain) > 1 else 1
+        _COHORT_PASSES.inc(passes)
+        results: List = [entry[2] for entry in entries]
         alive = list(range(len(entries)))
         launched: List[_Output] = []
         dispatched = 0  # the blocks of the chain whose batch has been dispatched and scattered
@@ -1071,10 +1206,10 @@ class DecodeSessionManager:
                     fail(e)
             return results
 
-        with _trace_sync("decode.cohort", rows=len(entries), chain_len=len(chain)):
+        with _trace_sync("decode.cohort", rows=len(entries), chain_len=len(chain), passes=passes):
             try:
                 for depth, uid in enumerate(chain):
-                    batch = [(entries[i][0], entries[i][1][depth], results[i]) for i in alive]
+                    batch = [(entries[i][0], entries[i][1][depth], results[i], *entries[i][3:]) for i in alive]
                     outs = self._decode_batch(uid, batch, fetch=False)
                     dispatched = depth + 1
                     for i, out in zip(alive, outs):
@@ -1165,14 +1300,16 @@ class DecodeSessionManager:
         return row
 
     def _decode_batch(self, uid: str, entries: List, fetch: bool = True) -> List:
-        """Run one batched step over `entries` [(future, session, x)]; returns one
+        """Run one batched step over `entries` [(future, session, x, loop_pass)] (the pass may be left out: the
+        first), each row on the arrays and at the position of ITS pass: the rows of one program may be at
+        different passes, the program is the same; returns one
         result (ndarray or Exception) per entry, in order. An ``x`` is a host array
         ``[1, 1, hidden]`` or a `_Row` (a cohort's row, still on the device); with
         ``fetch=False`` (a cohort, mid-chain) the live rows come back as `_Row`s of
         a program that may still be running."""
         started = time.perf_counter()
-        for _future, session, _x in entries:
-            session.batch_started = started  # ends these steps' queue wait (decode_span_async)
+        for entry in entries:
+            entry[1].batch_started = started  # ends these steps' queue wait (decode_span_async)
         with _trace_sync("decode.batch", uid=uid) as span:
             return self._decode_batch_traced(uid, entries, span, fetch)
 
@@ -1185,17 +1322,24 @@ class DecodeSessionManager:
         try:
             results: List = [None] * len(entries)
             live = []
-            for i, (_future, session, x) in enumerate(entries):
-                if session.index == 0:
+            # each row's pass: read off the entries of a block of several passes only
+            passes = [_entry_pass(entry) for entry in entries] if self.block_passes(uid) > 1 else [0] * len(entries)
+            for i, (entry, loop_pass) in enumerate(zip(entries, passes)):
+                session = entry[1]
+                index = session.positions[loop_pass]
+                if index == 0:
                     results[i] = KeyError(f"decode session for {uid!r} has no prefill yet")
-                elif session.index + 1 > self.max_len:
-                    results[i] = ValueError(f"decode session is full ({session.index}/{self.max_len})")
+                elif index + 1 > self.max_len:
+                    results[i] = ValueError(f"decode session is full ({index}/{self.max_len})")
                 elif session.batch != 1:
                     results[i] = ValueError("batched decode requires session batch 1")
+                elif loop_pass and index + 1 > session.positions[loop_pass - 1]:
+                    results[i] = ValueError(_out_of_order(loop_pass, index + 1, session.positions[loop_pass - 1]))
                 else:
                     live.append(i)
             if span is not None:
                 span.set("rows", len(live))
+                span.set("passes", len({passes[i] for i in live}))
             if not live:
                 return results
             if len(live) == 1:
@@ -1205,21 +1349,22 @@ class DecodeSessionManager:
                 # step directly (shared with decode(), so signatures can't
                 # diverge); ISSUE 10 copy-free batching applied to decode
                 [i] = live
-                _future, session, x = entries[i]
+                session, x, loop_pass = entries[i][1], entries[i][2], passes[i]
                 x = x.host() if isinstance(x, _Row) else x
                 record_transfer(int(x.nbytes), "host_to_device")
-                y = self._advance(uid, session, backend, x, 1, 1)
-                session.index += 1
+                y = self._advance(uid, session, backend, x, 1, 1, loop_pass)
+                session.positions[loop_pass] += 1
                 session.last_used = time.monotonic()  # the stamp proper follows when the cohort resolves
                 # counted "direct": nothing was merged/vmapped (the catalog row
                 # defines `batched` as merged into a vmapped continuous batch)
                 _STEPS.inc(path="direct")
+                _count_pass_steps(loop_pass)
                 results[i] = np.asarray(y)[:, :1]
                 record_transfer(results[i].nbytes, "device_to_host")
                 return results
-            sessions = [entries[i][1] for i in live]
+            sessions, passes = [entries[i][1] for i in live], [passes[i] for i in live]
             # a block that keeps nothing has no caches to take apart or join: "none"
-            stack, caches = _next_pow2(len(live)), self._rows_caches(uid) if sessions[0].leaves else "none"
+            stack, caches = _next_pow2(len(live)), self._rows_caches(uid) if sessions[0].trees[0] else "none"
             kind = self._cache_kind(uid)
             if span is not None:
                 span.set("bucket", stack)
@@ -1235,11 +1380,12 @@ class DecodeSessionManager:
                 xs = self._device_rows([entries[i][2] for i in live], stack)
                 # a padding row writes a valid mid-cache position; its output is discarded
                 padding = self._padding(uid, stack - len(live))
-                indices = np.array([session.index for session in sessions] + [1] * len(padding), np.int32)
-                # leaf by leaf, the tuple of the rows' arrays (a pair: the keys' tuple and the values')
-                columns = tuple(zip(*[session.leaves for session in sessions] + padding))
+                indices = np.array([session.positions[loop_pass] for session, loop_pass in zip(sessions, passes)] + [1] * len(padding), np.int32)
+                # leaf by leaf, the tuple of the rows' arrays (a pair: the keys' tuple and the values'), each row's of its own pass
+                columns = tuple(zip(*[session.trees[loop_pass] for session, loop_pass in zip(sessions, passes)] + padding))
                 step = self._batched_fn(uid, stack)
-            _DONATED_BATCHED.inc(stack * sessions[0].nbytes)  # every position's cache is one row of this block's, padding too
+            row_bytes = sessions[0].row_bytes  # every position's cache is one row of this block's, padding too
+            _DONATED_BATCHED.inc(stack * row_bytes)
             try:
                 with _batch_phase("step"):
                     y, new, routing, attended = step(backend.snapshot_params(), xs, columns, indices)
@@ -1251,9 +1397,9 @@ class DecodeSessionManager:
                 # the program took what it was handed (a step that raised before it donated anything, at its
                 # tracing, took nothing): those sessions go, and those throwaway caches
                 taken = lambda leaves: any(leaf.is_deleted() for leaf in leaves)  # noqa: E731
-                self._drop_failed([session for session in sessions if taken(session.leaves)])
+                self._drop_failed([session for session, loop_pass in zip(sessions, passes) if taken(session.trees[loop_pass])])
                 kept = [row for row in padding if not taken(row)]
-                self._keep_padding(uid, kept, lost=(len(padding) - len(kept)) * sessions[0].nbytes)
+                self._keep_padding(uid, kept, lost=(len(padding) - len(kept)) * row_bytes)
                 raise
             new = list(zip(*new)) if new else [()] * stack  # row by row, its new leaves (of a tree of none: none)
             if padding:
@@ -1263,12 +1409,19 @@ class DecodeSessionManager:
             if kind == "latent":
                 _LATENT_POSITIONS.inc(int(indices[:len(live)].sum()) + len(live), path="batched")
             elif kind == "ssm":
-                _SSM_STATE_BYTES.inc(len(live) * sessions[0].nbytes, path="batched")
+                _SSM_STATE_BYTES.inc(len(live) * row_bytes, path="batched")
+            elif kind == "looped":
+                _LOOPED_POSITIONS.inc(int(indices[:len(live)].sum()) + len(live), path="batched")
+            if any(passes):
+                for loop_pass in set(passes):
+                    _count_pass_steps(loop_pass, passes.count(loop_pass))
+            else:
+                _count_pass_steps(0, len(live))
             with _batch_phase("scatter"):
                 now = time.monotonic()
-                for row, (i, session, leaves) in enumerate(zip(live, sessions, new)):
-                    session.leaves = leaves
-                    session.index += 1
+                for row, (i, session, loop_pass, leaves) in enumerate(zip(live, sessions, passes, new)):
+                    session.trees[loop_pass] = leaves
+                    session.positions[loop_pass] += 1
                     session.last_used = now  # bare stores, no lock: `_batched_at`
                     results[i] = output.host()[row:row + 1] if fetch else _Row(output, row)
                 self._batched_at[uid] = now

@@ -96,7 +96,30 @@ Optional class attributes:
   block that copied it first would pay the copy itself. A session's own call is handed arrays;
 - ``decode_cache_kind`` (a short string) names the block's decode programs
   (`jit_batched_step_<kind>`, `jit_prefill_<kind>_<positions>`) and its caches in the
-  telemetry (`hivemind_moe_decode_cache_bytes{kind}`).
+  telemetry (`hivemind_moe_decode_cache_bytes{kind}`);
+- ``decode_passes`` (an int, an attribute or a property of the module; 1 where a block
+  says nothing, which is every block but a LOOPED model's): the block runs that many times
+  a token with the same weights, pass u taking what the client made of pass u-1's output,
+  and pass u of a position attends what pass u cached, never another pass's
+  (`ouro_block`: its ``total_ut_steps``). What it promises is that its step is the SAME at
+  every pass: the manager makes a session with ``decode_passes`` trees from
+  ``init_decode_cache`` (each call returns ONE pass's tree) and as many positions, under
+  ONE entry of the table (one to the cap, the TTL, `clear_sessions`, a failed step's drop
+  and the eviction counters; the byte gauges count every tree), and hands a step the
+  leaves of the pass its request NAMED (``loop_pass`` in the request's metadata, 0 where it
+  names none). The block never sees the pass: it is handed that pass's leaves and hands
+  them back, so every pass runs the one program of its bucket and nothing compiles for a
+  pass. The loop's order is the manager's to hold (a call that would carry pass u's position
+  past pass u-1's is a ``ValueError`` before anything is donated, as is a pass outside
+  ``[0, decode_passes)``); ``reset`` at pass 0 makes the session, at a later pass it starts
+  that pass over inside it. A cohort takes the waiting rows WHATEVER their pass: a block's
+  ``[rows, 1, hidden]`` input may hold rows of different passes of different positions, each
+  beside the arrays of its own pass (`decode_rows_apart` makes that free; a block that keeps
+  its caches joined gets them joined row by row all the same), and a block may rely on
+  nothing about which passes share its step. Padding rows keep one tree. The blocks of one
+  chain have to agree on ``decode_passes`` (they walk together): a chain that does not is
+  refused when it is made. The norm between two passes, and whether a token runs every
+  pass, are the client's (`RemoteSequential.decode_step(.., loop_pass=u)`), not a block's.
 
 A block whose expert layer holds a share of the experts says which in ``held_experts``
 (``(lo, hi)``), and the routing counters tell the pairs it computed from the pairs it

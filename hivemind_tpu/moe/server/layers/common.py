@@ -238,13 +238,14 @@ def _plain_dense(features: int, name: str) -> nn.Dense:
 
 
 def _rope_attention_half(x, cache_k, cache_v, index, *, heads: int, kv_heads: int, rope_theta: float,
-                         rms_eps: float, mesh=None, qk_norm: bool = False, head_dim: int = 0):
+                         rms_eps: float, mesh=None, qk_norm: bool = False, head_dim: int = 0, out_norm: bool = False):
     """The attention half of the Llama-family blocks, called inside a block's
     compact ``__call__`` (its submodules become the block's): pre-RMSNorm, q / k / v
     projections without bias, optional RMS norms over the whole projected query and
     key widths (``qk_norm``: OLMoE), rotary embedding, causal attention with
     grouped KV heads (over the decode cache when one is given), output projection,
-    residual. The head size is hidden / heads; a ``head_dim`` that says otherwise
+    an RMS norm on that projection's output where the family has one (``out_norm``: the
+    sandwich norm of `ouro_block`), residual. The head size is hidden / heads; a ``head_dim`` that says otherwise
     (a checkpoint whose heads are not hidden / heads wide) fails here, loudly,
     and is not served at another shape. Returns (x + attention, cache_k, cache_v)."""
     from hivemind_tpu.parallel.ring_attention import mesh_attention_core
@@ -275,7 +276,10 @@ def _rope_attention_half(x, cache_k, cache_v, index, *, heads: int, kv_heads: in
         attn = mesh_attention_core(mesh, q, k, v, causal=True).reshape(batch, seq, hid)
     else:
         attn, cache_k, cache_v = _cache_attention(q, k, v, cache_k, cache_v, index)
-    return x + _plain_dense(hid, "attention_out")(attn), cache_k, cache_v
+    out = _plain_dense(hid, "attention_out")(attn)
+    if out_norm:
+        out = nn.RMSNorm(epsilon=rms_eps, dtype=jnp.bfloat16, name="attention_out_norm")(out)
+    return x + out, cache_k, cache_v
 
 
 class LlamaBlockExpert(nn.Module):
@@ -602,3 +606,13 @@ def _nemotron_h_block(hidden_dim: int, **kwargs):
     from hivemind_tpu.moe.server.layers.nemotron_h import NemotronHBlockExpert
 
     return NemotronHBlockExpert(hidden_dim, **kwargs)
+
+
+@register_expert_class("ouro_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))
+def _ouro_block(hidden_dim: int, **kwargs):
+    """`layers/ouro.py`'s block (a looped model's: the Llama family's attention and SwiGLU between sandwich
+    norms, its decode sessions holding a cache pair for each of ``total_ut_steps`` passes), loaded when one is
+    built, as `minicpm_sala_block` is."""
+    from hivemind_tpu.moe.server.layers.ouro import OuroBlockExpert
+
+    return OuroBlockExpert(hidden_dim, **kwargs)

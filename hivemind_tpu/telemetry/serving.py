@@ -226,7 +226,7 @@ class ServingLedger:
             value = attrs.get(field)
             if value is not None:
                 record[field] = round(float(value), 6)
-        for field in ("batch", "occupancy", "pool", "span_len"):
+        for field in ("batch", "occupancy", "pool", "span_len", "loop_pass"):
             if field in attrs:
                 record[field] = attrs[field]
         if error_type is not None:

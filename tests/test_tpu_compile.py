@@ -279,6 +279,38 @@ def test_nemotron_prompt_chunk_of_2048_positions_fits_the_chip(one_chip, no_comp
     assert compiled.memory_analysis().temp_size_in_bytes < temporaries_gb * 1e9
 
 
+OURO = dict(num_heads=16, ffn_inner=5632, rope_theta=1e6, rms_eps=1e-6, total_ut_steps=4)  # Ouro-2.6B's published widths at hidden 2,048
+
+
+def test_looped_batched_step_of_16_sessions_fits_the_chip(one_chip, no_compile_cache):
+    """Ouro-2.6B's block at a bucket of 16 with 1,792-slot caches (14.7 MB a pass a session): the program is handed ONE
+    pass's pair a row, whatever the passes of the rows (the manager picks them), steps on the rows' own arrays where they
+    lie, aliases every one to an output, and splits under the two named scopes a trace reads."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len, rows = 2048, 1792, 16
+    compiled, leaves = _compiled_batched_step(name_to_block["ouro_block"](hidden, **OURO), hidden, max_len, rows, one_chip)
+    text = compiled.as_text()
+    assert leaves == [(1, 16, max_len, 128)] * 2 and _joined(text, rows, leaves[0]) == 0
+    assert "loop_attention" in text and "loop_mlp" in text
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes < 236 * 2**20 and memory.temp_size_in_bytes < 16 * 2**20  # 16 x 14.7 MB of caches, aliased
+
+
+def test_looped_prompt_of_1024_positions_fits_the_chip(one_chip, no_compile_cache):
+    """One pass's prefill of the cell's longest prompt: the chunk into the pass's pair, plain causal attention within the chunk (`_cache_attention`)."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len, chunk = 2048, 1792, 1024
+    module = name_to_block["ouro_block"](hidden, **OURO)
+    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, hidden), jnp.float32))["params"])
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), tree)
+    cache = on_chip(jax.eval_shape(lambda: module.init_decode_cache(1, max_len)))
+    step = jax.jit(lambda p, x, cache, index: module.apply({"params": p}, x, *cache, index), donate_argnums=(2,))
+    compiled = step.lower(on_chip(params), _shape((1, chunk, hidden), jnp.float32, one_chip), cache, _shape((), jnp.int32, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
 @pytest.mark.parametrize(
     "shape,causal,backward",
     [
