@@ -25,9 +25,16 @@ Serving data path (ISSUE 10, the PR 5 playbook applied to this layer):
 - **Off-loop codecs**: request deserialization and response serialization run
   on the shared executor past a small inline threshold — the event-loop
   watchdog proved inline codecs stall RPC dispatch under load (the evidence
-  was multi-MB payloads; a ~4 KB decode step stays inline, where the executor
+  was multi-MB payloads; a ~4 KB payload stays inline, where the executor
   hop would dominate). The ``serialize_s`` phase accrues the executor
   round-trip when off-loop (queue time included; see docs/observability.md).
+- **A batched decode step converts nothing here** (ISSUE 57): under the fp16
+  codec an ``rpc_decode`` request's row goes to the session manager as a float16
+  VIEW of its buffer and a cohort's answer comes back as a float16 row, so the
+  rows of a cohort change dtype together, once each way, on the threads that
+  upload and fetch them (`decode_session._device_rows`, `_Output.wire`); this
+  thread only frames bytes. ``hivemind_moe_decode_responses_total`` says how often,
+  against the answers this thread still converts inline.
 - **Scatter-gather responses**: responses leave as spliced
   :class:`~hivemind_tpu.utils.streaming.WireParts` frames — the tensor buffer
   rides into the AEAD as its own buffer instead of being copied into one
@@ -45,6 +52,7 @@ import numpy as np
 
 from hivemind_tpu.compression import (
     CompressionType,
+    Float16Compression,
     codec_name,
     deserialize_tensor,
     deserialize_tensor_stream,
@@ -59,6 +67,7 @@ from hivemind_tpu.moe.server.task_pool import TaskPool
 from hivemind_tpu.p2p import P2P, P2PContext, ServicerBase
 from hivemind_tpu.proto import runtime_pb2
 from hivemind_tpu.telemetry.serving import (
+    DECODE_RESPONSES,
     SERVING_SPAN,
     WIRE_BYTES_RECEIVED,
     WIRE_BYTES_SENT,
@@ -76,9 +85,13 @@ logger = get_logger(__name__)
 _STREAM_CHUNK = 2**20  # 1 MiB chunks inside stream replies
 
 # payloads below this encode/decode inline: the executor hop would dominate a
-# ~4 KB decode step (same rationale and threshold as the client's
-# _OFF_LOOP_CODEC_BYTES in moe/client/expert.py — the loop-stall evidence that
-# motivated off-loop codecs came from MULTI-MB payloads)
+# few KB (same rationale and threshold as the client's _OFF_LOOP_CODEC_BYTES in
+# moe/client/expert.py — the loop-stall evidence that motivated off-loop codecs
+# came from MULTI-MB payloads). What still converts inline under it: the pools'
+# small requests and, of the decode path, what `_decode_direct` answers (a short
+# prefill, a reset, a lone stream) and every step of a server whose codec is not
+# plain fp16. A BATCHED decode step under the fp16 codec converts nothing on the
+# loop at all: its rows cross the wire's dtype with their cohort (ISSUE 57)
 _OFF_LOOP_CODEC_BYTES = 256 * 1024
 
 # what a pool's batch runs over its chain's backends, by the pool's direction
@@ -87,6 +100,24 @@ _CHAIN_WALKS = {"forward": forward_chain, "backward": backward_chain}
 # cached metric children (one label value per role on this path)
 _SERVER_BYTES_SENT = WIRE_BYTES_SENT.labels("server")
 _SERVER_BYTES_RECEIVED = WIRE_BYTES_RECEIVED.labels("server")
+_DTYPE_AT_COHORT, _DTYPE_AT_HANDLER = DECODE_RESPONSES.labels("cohort"), DECODE_RESPONSES.labels("handler")
+
+
+def _is_half_of_float32(tensor: runtime_pb2.Tensor) -> bool:
+    return tensor.compression == CompressionType.FLOAT16 and (tensor.dtype or "float32") == "float32"
+
+
+def _half_view(tensor: runtime_pb2.Tensor) -> np.ndarray:
+    """The halves of a FLOAT16 tensor as they lie in its buffer, in the tensor's shape: no copy, no cast."""
+    return np.frombuffer(tensor.buffer, dtype=np.float16).reshape(tuple(tensor.size))
+
+
+def _framed(serialized: List[runtime_pb2.Tensor], serialize_s: float) -> WireParts:
+    """A unary response of ``serialized``, scatter-gather; the seconds they took onto the serving span."""
+    accrue_span_phase("serialize_s", serialize_s)
+    response = expert_response_parts(serialized)
+    _SERVER_BYTES_SENT.inc(response.nbytes)
+    return response
 
 
 class ConnectionHandler(ServicerBase):
@@ -103,6 +134,9 @@ class ConnectionHandler(ServicerBase):
 
         self.backends = backends
         self.activation_codec = resolve_activation_codec(activation_compression)
+        # plain fp16 (no subclass: a scaled codec ships statistics beside its halves): the one wire dtype whose
+        # rows a decode cohort converts together, where this handler only frames their bytes
+        self._wire_is_half = type(self.activation_codec) is Float16Compression
         # one pool per direction and span chain, made on the chain's first request
         # (a single block is a chain of one, made with its backend)
         self._pools: Dict[Tuple[str, Tuple[str, ...]], TaskPool] = {}
@@ -259,18 +293,23 @@ class ConnectionHandler(ServicerBase):
 
     # ------------------------------------------------------------------ codecs
 
-    async def _deserialize_request(self, tensors) -> Tuple[List[np.ndarray], float]:
+    async def _deserialize_request(self, tensors, half_as_is: bool = False) -> Tuple[List[np.ndarray], float]:
         """Parse request tensors; big payloads decode off the event loop (the
         watchdog showed inline deserialization stalling dispatch under load),
         small ones inline (the executor hop would dominate them). Returns the
-        arrays and the seconds it took (the serving span's ``deserialize_s``)."""
+        arrays and the seconds it took (the serving span's ``deserialize_s``).
+        ``half_as_is`` (``rpc_decode``, whose session manager widens a row where
+        it joins it with its cohort's, or on the executor thread of its own step):
+        where this server's wire is plain fp16, a small float32 tensor that came
+        in it is handed on as a float16 VIEW of its buffer, unconverted."""
         started = time.perf_counter()
         tensor_list = list(tensors)
         nbytes = sum(len(t.buffer) for t in tensor_list)
         if nbytes < _OFF_LOOP_CODEC_BYTES:
             # a decode token's 8 KB, inline: the wire's counters take the seconds this
             # handler measures anyway, with no clock read of their own
-            arrays = [deserialize_tensor(t) for t in tensor_list]
+            as_is = half_as_is and self._wire_is_half
+            arrays = [_half_view(t) if as_is and _is_half_of_float32(t) else deserialize_tensor(t) for t in tensor_list]
             elapsed = time.perf_counter() - started
             count_work("decode", elapsed, nbytes)
             return arrays, elapsed
@@ -305,10 +344,28 @@ class ConnectionHandler(ServicerBase):
         else:
             serialized = await run_in_executor(self._serialize_traced, outputs, nbytes)
             elapsed = time.perf_counter() - start
-        accrue_span_phase("serialize_s", elapsed)
-        response = expert_response_parts(serialized)
-        _SERVER_BYTES_SENT.inc(response.nbytes)
-        return response
+        return _framed(serialized, elapsed)
+
+    async def _respond_decode(self, output: np.ndarray) -> WireParts:
+        """``rpc_decode``'s `_respond`. A row that a cohort answered is in the wire's
+        dtype already (float16 under the plain fp16 codec: `_Output.wire` made it with
+        its cohort's, the codec's own clip and cast): its bytes are framed as the
+        codec frames them, a float32 tensor in FLOAT16, and nothing is converted on
+        this thread. Any other answer (a prefill's, a reset's, a lone stream's; any
+        other codec's) is serialized as every response is, and counted where that
+        is inline, on this thread: a long prompt's answer was never converted here."""
+        if not (self._wire_is_half and output.dtype == np.float16):
+            if output.nbytes < _OFF_LOOP_CODEC_BYTES:
+                _DTYPE_AT_HANDLER.inc()
+            return await self._respond([output])
+        _DTYPE_AT_COHORT.inc()
+        start = time.perf_counter()
+        serialized = runtime_pb2.Tensor(
+            buffer=output.tobytes(), size=output.shape, dtype="float32", compression=CompressionType.FLOAT16
+        )
+        elapsed = time.perf_counter() - start
+        count_work("encode", elapsed, 2 * output.nbytes)  # the float32 bytes the codec was handed before
+        return _framed([serialized], elapsed)
 
     async def rpc_forward(self, request: runtime_pb2.ExpertRequest, context: P2PContext) -> runtime_pb2.ExpertResponse:
         _SERVER_BYTES_RECEIVED.inc(request.ByteSize())
@@ -359,11 +416,11 @@ class ConnectionHandler(ServicerBase):
         model's loop, ``"loop_pass": int`` (absent: 0); sessions bypass the batching
         pools — each holds its own per-client device cache, one a pass."""
         _SERVER_BYTES_RECEIVED.inc(request.ByteSize())
-        tensors, deserialize_s = await self._deserialize_request(request.tensors)
+        tensors, deserialize_s = await self._deserialize_request(request.tensors, half_as_is=True)
         with self._serving_trace("decode", request.uid, context, tensors, deserialize_s) as span:
             self._admit(context, tensors, "decode")
             output = await self._run_decode(request.uid, request.metadata, tensors, span)
-            return await self._respond([output])
+            return await self._respond_decode(output)
 
     async def rpc_replica_state(
         self, request: runtime_pb2.ExpertUID, context: P2PContext

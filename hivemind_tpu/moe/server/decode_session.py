@@ -110,6 +110,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from hivemind_tpu.compression.floating import to_half
 from hivemind_tpu.moe.server.routing_stats import (
     ATTENDED_COLLECTION,
     ROUTING_COLLECTION,
@@ -121,6 +122,7 @@ from hivemind_tpu.telemetry import REGISTRY as _TELEMETRY
 from hivemind_tpu.telemetry.device import record_transfer
 from hivemind_tpu.telemetry.serving import accrue_span_phase
 from hivemind_tpu.telemetry.tracing import trace_sync as _trace_sync
+from hivemind_tpu.telemetry.wire import count_work
 from hivemind_tpu.utils.logging import get_logger
 from hivemind_tpu.utils.asyncio_utils import spawn
 from hivemind_tpu.utils.profiling import tracked_jit
@@ -436,13 +438,15 @@ class _Output:
     """What one batched program handed back: its output ``y`` ``[bucket, 1, hidden]``
     still on the device (the leading ``rows`` rows are live, in the order of the
     batch's live entries), the routing it sowed, and once somebody needed it on the
-    host, that copy."""
+    host, that copy; and of a chain's last output, once a row was asked for in the wire's
+    half precision, the live rows in it."""
 
-    __slots__ = ("y", "routing", "attended", "rows", "held", "on_host", "settled")
+    __slots__ = ("y", "routing", "attended", "rows", "held", "on_host", "in_half", "settled")
 
     def __init__(self, y, routing, attended, rows: int, held=None):
         self.y, self.routing, self.attended, self.rows, self.held = y, routing, attended, rows, held
         self.on_host: Optional[np.ndarray] = None
+        self.in_half: Optional[np.ndarray] = None
         self.settled = False
 
     def host(self) -> np.ndarray:
@@ -450,6 +454,19 @@ class _Output:
             self.on_host = np.asarray(self.y)
             record_transfer(self.on_host.nbytes, "device_to_host")
         return self.on_host
+
+    def wire(self) -> np.ndarray:
+        """The live rows ``[rows, 1, hidden]`` in float16 as the fp16 codec makes them (`to_half`: its clip and
+        its cast, bit for bit what `Float16Compression.compress` makes of a row alone): ONE pass for all the
+        rows of a cohort, on the thread that fetched them, where the handlers made one a row on the loop
+        thread. A second array: the float32 copy stays as it was fetched, for whoever reads a row of it
+        (`_Row.host`). The seconds are the wire's encode work; the bytes are counted where a row is framed."""
+        if self.in_half is None:
+            started = time.perf_counter()
+            live = self.host()[:self.rows]
+            self.in_half = to_half(live, False).reshape(live.shape)
+            count_work("encode", time.perf_counter() - started, 0)
+        return self.in_half
 
     def settle(self, span=None) -> None:
         """Wait for the program and count its routing (once): the routing's values
@@ -472,6 +489,9 @@ class _Row:
 
     def host(self) -> np.ndarray:
         return self.output.host()[self.row:self.row + 1]
+
+    def wire(self) -> np.ndarray:
+        return self.output.wire()[self.row:self.row + 1]
 
 
 class DecodeSessionManager:
@@ -994,7 +1014,9 @@ class DecodeSessionManager:
         wait on the chain WHATEVER their pass: the program is the same at every pass, each row on its own pass's
         arrays."""
         loop = asyncio.get_running_loop()
-        x = np.asarray(x, np.float32)
+        # a row in the wire's half precision (the handler's view of an fp16 request's buffer) stays as it came:
+        # a cohort widens its rows together, off this thread (`_device_rows`), the direct path its own
+        x = x if getattr(x, "dtype", None) == np.float16 else np.asarray(x, np.float32)
         batchable = not reset and x.ndim == 3 and x.shape[0] == 1 and x.shape[1] == 1
         enqueued = time.perf_counter()
         if batchable:
@@ -1170,7 +1192,10 @@ class DecodeSessionManager:
         work beside block k's). A row that fails at a block keeps that
         exception and leaves the cohort; the blocks after it never see it. Returns
         ``finish``: called once, from any thread, it waits for the chain's last
-        program and returns one result (ndarray or Exception) per entry, in order."""
+        program and returns one result (ndarray or Exception) per entry, in order:
+        a step is answered in the precision it was asked in, float32, or float16 for a
+        row that came in the wire's half precision, cut from the ONE pass the last output's
+        rows take together (`_Output.wire`)."""
         _COHORTS.inc()
         # a chain of one pass (every chain but a looped model's) holds one pass by definition: nothing is read off its rows
         passes = len({_entry_pass(entry) for entry in entries}) if self._chain_passes(chain) > 1 else 1
@@ -1201,7 +1226,7 @@ class DecodeSessionManager:
                         output.settle()
                     for i in alive:
                         if isinstance(results[i], _Row):
-                            results[i] = results[i].host()
+                            results[i] = results[i].wire() if entries[i][2].dtype == np.float16 else results[i].host()
                 except Exception as e:
                     fail(e)
             return results
@@ -1350,7 +1375,7 @@ class DecodeSessionManager:
                 # diverge); ISSUE 10 copy-free batching applied to decode
                 [i] = live
                 session, x, loop_pass = entries[i][1], entries[i][2], passes[i]
-                x = x.host() if isinstance(x, _Row) else x
+                x = x.host() if isinstance(x, _Row) else np.asarray(x, np.float32)  # the program's one input dtype
                 record_transfer(int(x.nbytes), "host_to_device")
                 y = self._advance(uid, session, backend, x, 1, 1, loop_pass)
                 session.positions[loop_pass] += 1
@@ -1438,12 +1463,17 @@ class DecodeSessionManager:
         zero rows as padding, through
         the upload program, so that a block's program meets its activations on the
         device whoever calls it: a cohort mid-chain and the first block of a chain
-        reach the same compiled entry."""
+        reach the same compiled entry. The join is also where rows that came in the
+        wire's half precision widen, all in the one numpy call and to numpy's bits (what
+        the fp16 codec's `from_half` gives a row): the upload is float32 whatever the
+        rows were, so no program sees another dtype."""
         first = rows[0]
         if (isinstance(first, _Row) and first.output.rows == len(rows) and first.output.y.shape[0] == stack
                 and all(isinstance(x, _Row) and x.output is first.output and x.row == at for at, x in enumerate(rows))):
             return first.output.y
         rows = [x.host() if isinstance(x, _Row) else x for x in rows]
-        xs = np.concatenate(rows + [np.zeros_like(rows[0])] * (stack - len(rows)))
+        if stack > len(rows):
+            rows.append(np.zeros((stack - len(rows), *rows[0].shape[1:]), np.float32))
+        xs = np.concatenate(rows, dtype=np.float32)
         record_transfer(int(xs.nbytes), "host_to_device")  # the caches are already resident
         return self._upload(xs)

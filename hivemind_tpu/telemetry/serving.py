@@ -100,6 +100,19 @@ WIRE_BYTES_RECEIVED = REGISTRY.counter(
     ("direction",),
 )
 
+# where a decode response's row reached the wire's dtype (ISSUE 57): the rows of a batched
+# decode step change dtype once a COHORT, on the thread that fetched them, and the handler
+# frames their bytes; any other answer under the inline threshold is converted by the handler
+# that sends it, on the loop thread: the two are what that thread could be spared, and was
+DECODE_RESPONSES = REGISTRY.counter(
+    "hivemind_moe_decode_responses_total",
+    "rpc_decode responses sent, by where their row reached the wire's dtype (cohort = with the rows of its batched step, one "
+    "fp16 pass a cohort off the loop thread, the handler framing bytes; handler = serialized inline by the handler that sent "
+    "it, on the loop thread: a short prefill's, a reset's or a lone stream's answer, any codec but plain fp16); an answer "
+    "past the inline threshold (a long prompt's) is serialized on the executor as ever and counted under neither",
+    ("wire_dtype_at",),
+)
+
 # replica robustness accounting (ISSUE 13): hedges fired when an in-flight
 # request crossed the expert's scorecard p95, who won the race, and failovers
 # onto another replica after a shed / connection loss. Client-side counters

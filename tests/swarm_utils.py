@@ -76,6 +76,14 @@ class ManagerSharingPrograms(DecodeSessionManager):
         return self._shared(self._batched_fns, (uid, stack), super()._batched_fn)
 
 
+def decode_compiles() -> int:
+    """Compilations of the decode path's jit sites so far in this process (`COMPILE_TRACKER`)."""
+    from hivemind_tpu.telemetry.device import COMPILE_TRACKER
+
+    counts = COMPILE_TRACKER.counts()
+    return sum(counts.get("decode_session." + site, 0) for site in ("batched_step", "step", "upload"))
+
+
 def wait_until(condition, timeout: float):
     """Poll ``condition`` until it holds or ``timeout`` passes; what it returned last."""
     deadline = time.monotonic() + timeout

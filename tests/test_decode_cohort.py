@@ -12,7 +12,7 @@ import pytest
 
 from hivemind_tpu.telemetry import REGISTRY
 from hivemind_tpu.telemetry.tracing import add_span_listener, remove_span_listener
-from swarm_utils import ManagerSharingPrograms, OneProgramBackend
+from swarm_utils import ManagerSharingPrograms, OneProgramBackend, decode_compiles as _compiles
 
 HID = 16
 CHAIN = ("coh.0", "coh.1", "coh.2")
@@ -50,13 +50,6 @@ def _counters():
 
 def _moved(before):
     return {key: value - before[key] for key, value in _counters().items()}
-
-
-def _compiles():
-    from hivemind_tpu.telemetry.device import COMPILE_TRACKER
-
-    counts = COMPILE_TRACKER.counts()
-    return sum(counts.get("decode_session." + site, 0) for site in ("batched_step", "step", "upload"))
 
 
 class _Spans:
