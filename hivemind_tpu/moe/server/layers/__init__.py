@@ -15,7 +15,8 @@ one leaf is still handed over as ``*cache``: the block's ``__call__(x, cache, in
 the array, or in a batched step of a block that says ``decode_rows_apart`` the tuple of the
 rows' arrays, and returns ``(y, cache)`` in the form it came in), or a window of the last few
 inputs of a short convolution (a state with a TIME AXIS of the kernel's width, which a step
-rolls by one) beside a recurrent state (`nemotron_h_block`'s Mamba-2 mixer), or NOTHING: a
+rolls by one) beside a recurrent state (`nemotron_h_block`'s Mamba-2 mixer, and `granite_h_block`'s, which is
+that mixer's body under a block that is a mixer AND a gated MLP), or NOTHING: a
 block that keeps nothing between calls (`nemotron_h_block`'s expert layer: one residual a
 block, so a feed-forward part is a block of its own) returns a tree of ZERO leaves, ``()``,
 is called ``(x, index)`` and returns ``(y,)``. Such a block still sits in a decode chain: it

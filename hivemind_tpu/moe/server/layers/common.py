@@ -616,3 +616,13 @@ def _ouro_block(hidden_dim: int, **kwargs):
     from hivemind_tpu.moe.server.layers.ouro import OuroBlockExpert
 
     return OuroBlockExpert(hidden_dim, **kwargs)
+
+
+@register_expert_class("granite_h_block", lambda batch, hid: np.zeros((batch, 64, hid), np.float32))
+def _granite_h_block(hidden_dim: int, **kwargs):
+    """`layers/granite_h.py`'s block (a Mamba-2 mixer or a grouped-query attention without position embedding, by
+    its ``kind``, AND a gated MLP, under two residuals scaled by ``residual_multiplier``; the mixer's body is
+    `nemotron_h_block`'s), loaded when one is built, as `minicpm_sala_block` is."""
+    from hivemind_tpu.moe.server.layers.granite_h import GraniteHBlockExpert
+
+    return GraniteHBlockExpert(hidden_dim, **kwargs)

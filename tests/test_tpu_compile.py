@@ -311,6 +311,51 @@ def test_looped_prompt_of_1024_positions_fits_the_chip(one_chip, no_compile_cach
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
+@pytest.mark.parametrize("rows", [16, 32])
+@pytest.mark.parametrize("kind,shapes,temporaries_gb", [("mamba", [(1, 3, 4352), (1, 64, 64, 128)], 0.05), ("attention", [(1, 8, 12288, 64)] * 2, 0.05)])
+def test_granite_batched_step_of_a_cohort_fits_the_chip(one_chip, no_compile_cache, kind, shapes, temporaries_gb, rows):
+    """The batched program of each kind of `granite_h_block` at the published widths (the block's defaults) and 12,288
+    slots, at the bucket of 16 that the cell's 32 sessions travel in and the 32 that 64 sessions would (the issue's first
+    size, measured in PR 59): a mixer's over the rows' own windows and states (no array of the rows' states joined), the attention's over the rows' own caches; every leaf aliased to an output; the
+    MLP's and the attention's scopes in the text beside the mixer's. THE CACHE'S LAYOUT, read off the chip's compiler:
+    an array `[1, 8, 12288, 64]` bf16 lies with its slots as the minor axis (`{2,3,1,0:T(8,128)(2,1)}`), so the
+    64-wide heads are NOT padded to a lane row of 128 and the arguments hold the rows' caches at their logical bytes."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len = 2048, 12288
+    compiled, leaves = _compiled_batched_step(name_to_block["granite_h_block"](hidden, kind=kind), hidden, max_len, rows, one_chip)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert leaves == shapes and memory.temp_size_in_bytes < temporaries_gb * 1e9 and "shared_mlp" in text
+    weights = 4 * (76_182_976 if kind == "mamba" else 60_821_504)
+    if kind == "mamba":
+        assert "ssm_step" in text and "ssm_conv" in text and "ssm_scan" not in text and f"f32[{rows},64,64,128]" not in text
+        assert memory.argument_size_in_bytes < weights + rows * (2_097_152 + 4 * 4352 * 2) + 2**20  # a window's 3 rows lie in a tile of 4
+    else:
+        assert "nope_attend" in text and _joined(text, rows, leaves[0]) == 0
+        header = next(line for line in text.splitlines() if "entry_computation_layout" in line)
+        assert "bf16[1,8,12288,64]{2,3,1,0:T(8,128)(2,1)}" in header and "bf16[1,8,12288,64]{3,2,1,0" not in header
+        assert memory.argument_size_in_bytes < weights + rows * 2 * 12288 * 8 * 64 * 2 + 2**20  # 25.2 MB a row of caches (805.3 MB at 32 rows): unpadded
+
+
+@pytest.mark.parametrize("kind,scope,temporaries_gb", [("mamba", "ssm_scan", 0.2), ("attention", "nope_attend", 0.3)])
+def test_granite_prompt_chunk_of_2048_positions_fits_the_chip(one_chip, no_compile_cache, kind, scope, temporaries_gb):
+    """A chunk of 2,048 positions continuing a session at 12,288 slots: the mixer's scan in sub-chunks of 256 (0.07 GB of
+    temporaries when written), the attention's chunk against its cache a block of 512 keys at a time (0.14 GB)."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len, chunk = 2048, 12288, 2048
+    module = name_to_block["granite_h_block"](hidden, kind=kind)
+    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, hidden), jnp.float32))["params"])
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), tree)
+    cache = on_chip(jax.eval_shape(lambda: module.init_decode_cache(1, max_len)))
+    scalar = _shape((), jnp.int32, one_chip)
+    step = jax.jit(lambda p, x, cache, *rest: module.apply({"params": p}, x, *cache, *rest), donate_argnums=(2,))
+    compiled = step.lower(on_chip(params), _shape((1, chunk, hidden), jnp.float32, one_chip), cache, scalar,
+                          *((scalar,) if module.decode_takes_length else ())).compile()
+    assert scope in compiled.as_text() and "shared_mlp" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries_gb * 1e9
+
+
 @pytest.mark.parametrize(
     "shape,causal,backward",
     [

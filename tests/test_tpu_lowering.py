@@ -166,6 +166,26 @@ def test_sharded_train_step_with_flash_core_lowers_for_tpu(monkeypatch):
     )
 
 
+@pytest.mark.parametrize("kind", ["mamba", "attention"])
+def test_granite_decode_step_lowers_for_tpu(kind):
+    """One position a row of a `granite_h_block` of each kind, as a batched decode program hands it over (the rows' cache
+    leaves as tuples of their own arrays, a position a row), exported for the TPU target at the published widths and two
+    rows: the block's own step, its jitted one-row functions inside, lowers without the chip."""
+    from hivemind_tpu.moe.server.layers import name_to_block
+
+    hidden, max_len, rows = 2048, 256, 2
+    module = name_to_block["granite_h_block"](hidden, kind=kind)
+    params = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, hidden), jnp.float32))["params"])
+    cache = jax.eval_shape(lambda: module.init_decode_cache(1, max_len))
+    step = lambda p, x, columns, index: module.apply({"params": p}, x, *columns, index)
+    exported = _export_for_tpu(step, params, jax.ShapeDtypeStruct((rows, 1, hidden), jnp.float32), tuple((leaf,) * rows for leaf in cache),
+                               jax.ShapeDtypeStruct((rows,), jnp.int32))
+    assert "tpu" in [p.lower() for p in exported.platforms]
+    text = exported.mlir_module()
+    assert len(exported.out_avals) == 1 + 2 * rows and exported.out_avals[0].shape == (rows, 1, hidden)
+    assert ("4352" in text) == (kind == "mamba")  # the mixer's convolution channels; the attention's text holds none
+
+
 def test_lowering_rejects_non_tpu_execution():
     """Executing a TPU-exported artifact on this CPU host must fail loudly (the
     artifact is for the TPU target) — guards against silently grading CPU
