@@ -16,7 +16,8 @@ looks inside. EVERY step donates the leaves it
 is handed, a session's own call and a batched program alike: the new leaves take the old
 ones' buffers, nothing is copied or allocated for them, and a block must not keep a
 reference to a cache argument. The step function is jitted once per
-(uid, batch, chunk-length) signature, and sessions expire by TTL / LRU cap so an
+(kind of block, batch, chunk-length) signature (`DecodeSessionManager._kind`: equal
+blocks share their programs), and sessions expire by TTL / LRU cap so an
 abandoned client cannot pin device memory.
 
 **A pass axis** (ISSUE 56). A LOOPED model runs its stack of blocks several times a token with
@@ -303,6 +304,16 @@ _LOOPED_POSITIONS = _TELEMETRY.counter(
     "a row of a cohort's program, direct = a session's own step); prompt chunks are not counted",
     ("path",),
 )
+# a decode program is a function of what its block IS (ISSUE 61): the blocks of one kind (`DecodeSessionManager._kind`)
+# share the one jitted object, so a span of eight equal blocks makes a shape ready once, not eight times
+_PROGRAMS = _TELEMETRY.counter(
+    "hivemind_moe_decode_programs_total",
+    "decode programs (a step, a prefill of one length or a batched bucket) that a block was handed, by origin (built = no "
+    "block of its kind had this shape yet, or the block's module cannot be hashed and keeps programs of its own: a new "
+    "jitted program, traced and compiled at its first call; shared = the program a block of the same kind built)",
+    ("origin",),
+)
+_PROGRAMS_BUILT, _PROGRAMS_SHARED = _PROGRAMS.labels("built"), _PROGRAMS.labels("shared")
 
 
 @contextlib.contextmanager
@@ -501,6 +512,14 @@ class DecodeSessionManager:
     :param session_ttl: seconds of inactivity before a session is evicted
     :param max_sessions: LRU cap across all uids
 
+    **A program is made once for each KIND of block, not once for each uid** (ISSUE 61). A step, a prefill of one
+    length and a batched bucket are functions of what a block IS and of the shapes (the weights come in as an
+    argument), so each is looked up by the block's kind — the flax module, how the backend makes dense parameters of
+    its stored ones, where its caches are placed: `_kind` — and built only when no block of that kind has built it
+    (`_of_kind`). Equal blocks share the ONE jitted object: the second to the last of them meet jax's in-memory cache
+    at their first call, and trace, lower, read and load nothing. `_step_fns` / `_batched_fns` are each uid's view
+    onto that table, which is all a step looks at; `hivemind_moe_decode_programs_total{origin}` counts both.
+
     **When eviction runs.** Lazily, at a call, and only at one that can evict: a step or a new
     session asks `_evict_due_locked`, which walks the table (`_evict_locked`) when it is over its
     cap — which only an ADD can bring about (`_enter` with ``reset``), so the pass follows the add
@@ -519,6 +538,9 @@ class DecodeSessionManager:
         self.backends = backends
         self.max_len, self.session_ttl, self.max_sessions = max_len, session_ttl, max_sessions
         self._sessions: Dict[Tuple[str, str], _Session] = {}
+        # (kind, shape) -> the ONE program of the blocks of that kind (`_of_kind`), and each uid's view onto it: what
+        # a step looks up, so that nothing hashes a module on the way to a program it already has
+        self._programs: Dict[tuple, callable] = {}
         self._step_fns: Dict[Tuple[str, int, int], callable] = {}
         self._batched_fns: Dict[Tuple[str, int], callable] = {}
         # uid -> the throwaway caches (each a cache's leaves) that pad a batch to its pow2 bucket, those not in a
@@ -717,14 +739,16 @@ class DecodeSessionManager:
         `decode_rows_apart`, each leaf is the tuple of the rows' own arrays, `_batched_fn`).
         Returns (y, leaves, routing, attended): what the block sowed into
         `ROUTING_COLLECTION` (empty for a block without experts) and into
-        `ATTENDED_COLLECTION` (empty for a block whose steps attend all they cached)."""
-        backend = self.backends[uid]
+        `ATTENDED_COLLECTION` (empty for a block whose steps attend all they cached).
+        It closes over the parts of the block's kind and over NO backend: the blocks of a kind share
+        it, and a jitted closure pins what it captures for the life of the process (`module_backend.py`)."""
+        module, dense_params = self.backends[uid].module, self.backends[uid].dense_params
 
         def step(params, x, leaves, index, *length):
             # int8 weight-only backends: materialize dense weights inside the jit
             # (identity for plain fp32 trees)
-            (y, *leaves), sown = backend.module.apply(
-                {"params": backend.dense_params(params)}, x, *leaves, index, *length,
+            (y, *leaves), sown = module.apply(
+                {"params": dense_params(params)}, x, *leaves, index, *length,
                 mutable=[ROUTING_COLLECTION, ATTENDED_COLLECTION],
             )
             sown = dict(sown)
@@ -763,10 +787,44 @@ class DecodeSessionManager:
             # under one site — a client cycling prompt lengths past the pow2
             # buckets shows up as a recompile storm, not silent latency
             name = "step_{kind}" if new_len == 1 else f"prefill_{{kind}}_{new_len}"
-            fn = self._step_fns[key] = tracked_jit(
+            fn = self._step_fns[key] = self._of_kind(uid, ("step", batch, new_len), lambda: tracked_jit(
                 self._named_by_kind(uid, self._raw_step(uid), name), site="decode_session.step", donate_argnums=(2,),
                 out_shardings=(None, self._cache_shardings(uid), None, None),
-            )
+            ))
+        return fn
+
+    def _kind(self, uid: str) -> Optional[tuple]:
+        """What the block IS to a decode program: everything the program's text depends on and nothing else.
+        The flax module (a frozen dataclass: equal, and hashing equal, when its class and every field are, the
+        flags this manager reads off it among them), how the backend makes dense parameters of the stored ones
+        (the quantization, and `dense_params` as its function and the codec's placement: a `functools.partial`
+        compares by identity), and where the block's caches are placed (`_cache_shardings`). ``max_len``, the
+        bucket and the parameters' shapes reach a program as the shapes of its arguments. None for a module
+        that cannot be hashed: its block keeps programs of its own."""
+        backend = self.backends[uid]
+        dense = backend.dense_params
+        kind = (backend.module, getattr(backend, "weight_quantization", None), getattr(dense, "func", dense),
+                tuple(sorted(getattr(dense, "keywords", {}).items())), self._cache_shardings(uid))
+        try:
+            hash(kind)
+        except TypeError:
+            return None
+        return kind
+
+    def _of_kind(self, uid: str, shape: tuple, build):
+        """The program of ``shape`` of the block's kind: what a block of that kind built before, else
+        ``build()``, kept for the next block of the kind. No lock: two threads that miss together both build,
+        each keeps its own and the later entry stays, which is a program more and never a wrong one (the views' rule)."""
+        kind = self._kind(uid)
+        key = kind and (kind, *shape)
+        fn = self._programs.get(key)
+        if fn is None:
+            fn = build()
+            _PROGRAMS_BUILT.inc()
+            if key:
+                self._programs[key] = fn
+        else:
+            _PROGRAMS_SHARED.inc()
         return fn
 
     def _cache_shardings(self, uid: str):
@@ -1269,22 +1327,25 @@ class DecodeSessionManager:
         key = (uid, stack)
         fn = self._batched_fns.get(key)
         if fn is None:
-            step = self._raw_step(uid)
-            apart = self._rows_caches(uid) == "apart"
+            def build():
+                step = self._raw_step(uid)
+                apart = self._rows_caches(uid) == "apart"
+                placed = self._cache_shardings(uid)
 
-            def batched_step(params, xs, columns, indices):
-                leaves = columns if apart else tuple(jnp.concatenate(rows) for rows in columns)
-                y, new, routing, attended = step(params, xs, leaves, indices)
-                if not apart:  # back to one array a leaf a session
-                    new = tuple(tuple(jnp.split(leaf, stack)) for leaf in new)
-                return y, new, routing, attended
+                def batched_step(params, xs, columns, indices):
+                    leaves = columns if apart else tuple(jnp.concatenate(rows) for rows in columns)
+                    y, new, routing, attended = step(params, xs, leaves, indices)
+                    if not apart:  # back to one array a leaf a session
+                        new = tuple(tuple(jnp.split(leaf, stack)) for leaf in new)
+                    return y, new, routing, attended
 
-            placed = self._cache_shardings(uid)
-            fn = self._batched_fns[key] = tracked_jit(
-                self._named_by_kind(uid, batched_step, "batched_step_{kind}"), site="decode_session.batched_step",
-                donate_argnums=(2,),
-                out_shardings=(None, placed and tuple((leaf,) * stack for leaf in placed), None, None),
-            )
+                return tracked_jit(
+                    self._named_by_kind(uid, batched_step, "batched_step_{kind}"), site="decode_session.batched_step",
+                    donate_argnums=(2,),
+                    out_shardings=(None, placed and tuple((leaf,) * stack for leaf in placed), None, None),
+                )
+
+            fn = self._batched_fns[key] = self._of_kind(uid, ("batched", stack), build)
         return fn
 
     def _padding(self, uid: str, count: int) -> List[tuple]:

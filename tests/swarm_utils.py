@@ -52,28 +52,18 @@ class OneProgramBackend(ModuleBackend):
 
 
 class ManagerSharingPrograms(DecodeSessionManager):
-    """A fresh manager (sessions, pools, padding and counters of its own) that takes a
-    jitted program from the managers that served the SAME backend object before it: a
-    step, a prefill or a batched step is a function of its backend alone (`_raw_step`;
-    ``max_len`` reaches it as the shape of an argument), so the file's tests compile each
-    once a process and not once a test. A test that counts compilations, or what
-    `_step_fns` / `_batched_fns` hold before its first call, builds a `DecodeSessionManager`."""
+    """A fresh manager (sessions, pools, padding, counters and per-uid views of its own) that takes a
+    jitted program from the managers before it. The library shares a program among the blocks of one
+    KIND within a manager (`DecodeSessionManager._of_kind`: ``max_len`` reaches a program as the shape of
+    an argument); all this adds is that the managers of a process keep ONE such table, so the file's
+    tests compile each program once a process and not once a test. A test that counts compilations
+    builds a `DecodeSessionManager`."""
 
-    _programs: dict = {}
+    _shared: dict = {}
 
-    def _shared(self, own: dict, key: tuple, build):
-        shared = (self.backends[key[0]], *key[1:])
-        if key not in own:  # else: its own from before, or what a test put in its place
-            if shared not in self._programs:
-                self._programs[shared] = build(*key)
-            own[key] = self._programs[shared]
-        return own[key]
-
-    def _step_fn(self, uid: str, batch: int, new_len: int):
-        return self._shared(self._step_fns, (uid, batch, new_len), super()._step_fn)
-
-    def _batched_fn(self, uid: str, stack: int):
-        return self._shared(self._batched_fns, (uid, stack), super()._batched_fn)
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self._programs = self._shared
 
 
 def decode_compiles() -> int:

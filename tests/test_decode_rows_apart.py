@@ -118,7 +118,8 @@ def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward(name):
                for session, length in zip(sessions, lengths))
     held = {id(leaf) for session in sessions for leaf in session.leaves}
     assert len(held) == 2 * 7 and not held & {id(leaf) for leaf in manager._dummy_rows(backend.name)}
-    assert list(manager._batched_fns) == [(backend.name, 8)], "the batch's program is keyed by (uid, bucket) alone"
+    assert list(manager._batched_fns) == [(backend.name, 8)], "the uid's view holds the bucket alone"
+    assert manager._batched_fns[(backend.name, 8)] is manager._programs[(manager._kind(backend.name), "batched", 8)], "its program is its kind's"
     [span] = [s for s in RECORDER.snapshot() if s.name == "decode.batch" and (s.attributes or {}).get("uid") == backend.name][-1:]
     assert (span.attributes["caches"], span.attributes["bucket"], span.attributes["rows"]) == ("apart", 8, 7)
 
@@ -323,7 +324,8 @@ def test_every_padding_position_has_a_cache_of_its_own(kind):
         seen.append(kept[:])
     assert all(leaf.is_deleted() for row in seen[0] for leaf in row), "the second step was handed the first one's throwaway rows"
     assert not {id(leaf) for row in seen[1] for leaf in row} & {id(leaf) for session in sessions for leaf in session.leaves}
-    assert list(manager._batched_fns) == [(backend.name, 8)], "the batch's program is keyed by (uid, bucket) alone"
+    assert list(manager._batched_fns) == [(backend.name, 8)], "the uid's view holds the bucket alone"
+    assert manager._batched_fns[(backend.name, 8)] is manager._programs[(manager._kind(backend.name), "batched", 8)], "its program is its kind's"
     manager._decode_batch(backend.name, [(None, session, x[row:row + 1, 12:13]) for row, session in enumerate(sessions[:3])])  # pads by one
     assert manager._padding_bytes == 3 * row_bytes and padding_gauge() == 3 * row_bytes, "the store keeps the largest padding a call has needed"
     manager.clear_sessions()

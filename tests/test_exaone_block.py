@@ -185,7 +185,8 @@ def test_batched_step_with_rows_at_different_positions(kind):
     counted = delta(before, counters("batched"))
     sparse = kind.startswith("sparse")
     assert counted["expert_layer_calls"] == 20 * sparse and counted["routed_pairs"] == 20 * 7 * TOP_K * sparse
-    assert [key for key in manager._batched_fns] == [(backend.name, 8)]
+    assert [key for key in manager._batched_fns] == [(backend.name, 8)]  # the uid's view onto its kind's program
+    assert manager._batched_fns[(backend.name, 8)] is manager._programs[(manager._kind(backend.name), "batched", 8)]
     [span] = [s for s in RECORDER.snapshot() if s.name == "decode.batch" and (s.attributes or {}).get("uid") == backend.name][-1:]
     assert span.attributes["cache"] == kind.split("/")[1] and span.attributes["rows"] == 7
     assert backend.module.decode_rows_apart == (caches == "apart") and span.attributes["caches"] == caches

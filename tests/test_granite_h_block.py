@@ -247,7 +247,8 @@ def test_forty_sessions_walk_a_chain_of_twenty_as_two_cohorts():
     mixer_row = manager._sessions[(chain[0], "s0")].nbytes
     assert mixer_row == 3 * (8 * 16 + 2 * 16) * 2 + 8 * 16 * 16 * 4
     assert counter("hivemind_moe_ssm_state_bytes_total", path="batched") - rewritten == 40 * kinds.count("mamba") * mixer_row
-    assert sorted(rows for (_uid, rows) in manager._batched_fns) == sorted([8, 32] * 20)
+    assert sorted(rows for (_uid, rows) in manager._batched_fns) == sorted([8, 32] * 20)  # a view a uid a bucket ...
+    assert len({id(program) for program in manager._batched_fns.values()}) == 2 * len(set(kinds)), "... onto ONE program a kind of block a bucket"
     want = np.asarray(reference_span([backends[uid].snapshot_params() for uid in chain], x))
     held_to_the_reference(np.concatenate(outs), want[:, 8:9])
     manager.clear_sessions()

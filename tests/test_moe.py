@@ -1102,4 +1102,5 @@ def test_custom_cached_block_steps_batched():
         for row, (out, length) in enumerate(zip(manager._decode_batch("mean.0", entries), lengths)):
             assert not isinstance(out, Exception), out
             np.testing.assert_allclose(out, want[row:row + 1, length + step:length + step + 1], rtol=1e-5, atol=1e-6)
-    assert list(manager._batched_fns) == [("mean.0", 4)]
+    assert list(manager._batched_fns) == [("mean.0", 4)]  # the uid's view; the program behind it is its kind's
+    assert [key[1:] for key in manager._programs if key[1] == "batched"] == [("batched", 4)]

@@ -220,7 +220,8 @@ def test_batched_step_pads_a_bucket_and_matches_each_rows_full_forward(kv_heads)
     assert len({id(leaf) for session in sessions for leaf in session.leaves}) == 2 * 7, "the padding row's arrays came back as a session's"
     assert _rows_by_caches() == (rows_before[0] + 2 * 7, rows_before[1]), "live rows of programs that left the caches apart"
     [key] = [k for k in manager._batched_fns]
-    assert key == (backend.name, 8), "the batch's program is keyed by (uid, bucket) alone"
+    assert key == (backend.name, 8), "the uid's view holds the bucket alone"
+    assert manager._batched_fns[key] is manager._programs[(manager._kind(backend.name), "batched", 8)], "its program is its kind's"
     spans = [s for s in RECORDER.snapshot() if s.name == "decode.batch" and (s.attributes or {}).get("uid") == backend.name]
     assert spans and spans[-1].attributes["pairs"] == 7 * TOP_K and 1 <= spans[-1].attributes["experts_hit"] <= EXPERTS
     assert spans[-1].attributes["caches"] == "apart" and spans[-1].attributes["bucket"] == 8

@@ -7,7 +7,8 @@ From the root of a checkout. Runs `perf.run` in this process (everything after `
 every series of the process's telemetry registry whose metric's name matches `--match` (default: the decode
 path's, `hivemind_moe_decode_`), as they stand when the run has ended. What it is for: a program counter that
 says whether a mechanism engaged — `hivemind_moe_decode_padding_cache_bytes`,
-`hivemind_moe_decode_cache_bytes_donated_total{path}`, `hivemind_moe_decode_session_evictions_total{reason}` —
+`hivemind_moe_decode_cache_bytes_donated_total{path}`, `hivemind_moe_decode_session_evictions_total{reason}`,
+`hivemind_moe_decode_programs_total{origin}` (the decode programs built, and those a block was handed by another of its kind) —
 read inside a cell's real traffic without an edit to the benchmark. A tree without a metric leaves it out."""
 
 import argparse
